@@ -25,14 +25,14 @@ RUN apt-get update && apt-get install -y --no-install-recommends \
     rm -rf /var/lib/apt/lists/*
 WORKDIR /horovod_tpu
 COPY . .
-# Separate resolutions: mxnet's final release pins numpy<2.0, and a
-# single joint resolve could backtrack jax to an ancient version missing
-# the APIs the framework needs (jax.shard_map, vma) — install modern
-# jax first with the numpy<2 constraint mxnet will need, then mxnet
-# alone (it only needs numpy at runtime).
-RUN pip install --no-cache-dir "numpy<2.0" "jax>=0.4.35" flax optax \
+# Separate resolutions: the framework is pinned to jax 0.9.0 (numpy>=2)
+# while mxnet's final release pins numpy<2.0, so no joint resolve exists —
+# install the pinned jax first, then mxnet alone without its pins.
+# Whether that mxnet imports under numpy 2 is unverified: this stage has
+# never been built (ROADMAP D12).
+RUN pip install --no-cache-dir "jax==0.9.0" "jaxlib==0.9.0" flax optax \
         chex pytest pyyaml && \
-    pip install --no-cache-dir mxnet && \
+    pip install --no-cache-dir --no-deps mxnet && \
     pip install --no-cache-dir --no-deps -e . && \
     python -m horovod_tpu.native.build
 CMD ["sh", "-c", "JAX_PLATFORMS=cpu PYTHONPATH=/horovod_tpu \
@@ -49,9 +49,10 @@ RUN apt-get update && apt-get install -y --no-install-recommends \
 WORKDIR /horovod_tpu
 COPY . .
 
-# CPU jax by default (CI); on TPU hosts use: pip install 'jax[tpu]' \
+# CPU jax by default (CI); on TPU hosts use: pip install 'jax[tpu]==0.9.0' \
 #   -f https://storage.googleapis.com/jax-releases/libtpu_releases.html
-RUN pip install --no-cache-dir jax flax optax orbax-checkpoint chex \
+RUN pip install --no-cache-dir "jax==0.9.0" "jaxlib==0.9.0" flax optax \
+        orbax-checkpoint chex \
         einops numpy pytest pyyaml && \
     pip install --no-cache-dir -e .
 

@@ -36,6 +36,7 @@ from horovod_tpu.callbacks import (LearningRateScheduleCallback,
                                    LearningRateWarmupCallback)
 from horovod_tpu.models import get_model
 from horovod_tpu.topology import data_axis, mesh_size
+from horovod_tpu.utils.compile_cache import enable_compile_cache
 
 
 def synthetic_batch(rng, global_bs, image_size, num_classes):
@@ -61,6 +62,7 @@ def main():
                    help="optional dir of images.npy/labels.npy shards")
     args = p.parse_args()
 
+    enable_compile_cache()
     hvd.init()
     mesh = hvd.mesh()
     ax = data_axis(mesh)

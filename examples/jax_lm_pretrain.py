@@ -34,6 +34,7 @@ import horovod_tpu as hvd
 from horovod_tpu import checkpoint
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.topology import build_mesh
+from horovod_tpu.utils.compile_cache import enable_compile_cache
 
 
 def synthetic_tokens(rng, batch, seq, vocab):
@@ -89,6 +90,7 @@ def main():
     p.add_argument("--log-every", type=int, default=10)
     args = p.parse_args()
 
+    enable_compile_cache()
     hvd.init()
     if args.pp > 1 and (args.tp > 1 or args.sp > 1):
         raise SystemExit("--pp composes with --dp only; TP/SP ride the "

@@ -22,6 +22,7 @@ import optax
 from flax import linen as nn
 
 import horovod_tpu as hvd
+from horovod_tpu.utils.compile_cache import enable_compile_cache
 
 
 class ConvNet(nn.Module):
@@ -59,6 +60,7 @@ def main():
     p.add_argument("--checkpoint-dir", default=None)
     args = p.parse_args()
 
+    enable_compile_cache()
     hvd.init()
     mesh = hvd.mesh()
     n_dev = mesh.devices.size

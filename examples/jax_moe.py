@@ -27,6 +27,7 @@ import horovod_tpu as hvd
 from horovod_tpu.parallel.expert import (load_balancing_loss, moe_layer,
                                          moe_layer_ragged)
 from horovod_tpu.topology import build_mesh
+from horovod_tpu.utils.compile_cache import enable_compile_cache
 
 
 def synthetic_clusters(rng, n, d, n_classes):
@@ -68,6 +69,7 @@ def main():
     cap_factor = (args.capacity_factor if args.capacity_factor is not None
                   else (2.5 if args.router == "top2" else 1.25))
 
+    enable_compile_cache()
     hvd.init()
     mesh = build_mesh(axes=("data", "expert"),
                       shape=(args.dp, args.experts))

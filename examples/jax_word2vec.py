@@ -24,6 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import horovod_tpu as hvd
 from horovod_tpu.ops.fusion import fused_pytree_mean
 from horovod_tpu.topology import data_axis, mesh_size
+from horovod_tpu.utils.compile_cache import enable_compile_cache
 
 
 def synthetic_corpus(rng, n_pairs, vocab, n_topics=8):
@@ -46,6 +47,7 @@ def main():
     p.add_argument("--lr", type=float, default=1.0)
     args = p.parse_args()
 
+    enable_compile_cache()
     hvd.init()
     mesh = hvd.mesh()
     ax = data_axis(mesh)

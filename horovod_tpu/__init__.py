@@ -28,7 +28,6 @@ Quick start (single host, all local TPU chips)::
     step = hvd.make_training_step(loss_fn, optimizer, mesh)
 """
 
-from horovod_tpu import _jax_compat  # noqa: F401  (must run before SPMD imports)
 from horovod_tpu import basics as _basics
 from horovod_tpu.basics import (
     init,

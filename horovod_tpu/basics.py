@@ -147,8 +147,14 @@ def init(comm=None, ranks: Optional[Sequence[int]] = None) -> None:
             _state.cross_rank = _state.rank // max(_state.local_size, 1)
             _state.cross_size = -(-_state.size // max(_state.local_size, 1))
         else:
-            _state.rank = _env_int("HOROVOD_RANK", jax.process_index())
-            _state.size = _env_int("HOROVOD_SIZE", jax.process_count())
+            # The launcher's env contract wins; jax.process_index() is
+            # consulted only without it, because the call initialises the
+            # default backend — which, on a host with chips, claims them
+            # for this process (docs/running.md, "Ranks and chips").
+            rank = _env_int("HOROVOD_RANK", None)
+            size = _env_int("HOROVOD_SIZE", None)
+            _state.rank = jax.process_index() if rank is None else rank
+            _state.size = jax.process_count() if size is None else size
             _state.local_rank = _env_int("HOROVOD_LOCAL_RANK", _state.rank)
             _state.local_size = _env_int("HOROVOD_LOCAL_SIZE", _state.size)
             _state.cross_rank = _env_int("HOROVOD_CROSS_RANK",
@@ -187,9 +193,9 @@ def init(comm=None, ranks: Optional[Sequence[int]] = None) -> None:
             _state.runtime = runtime
 
         _state.initialized = True
-        log.debug("initialized: rank=%d size=%d local_rank=%d local_size=%d "
-                  "devices=%d", _state.rank, _state.size, _state.local_rank,
-                  _state.local_size, len(jax.local_devices()))
+        log.debug("initialized: rank=%d size=%d local_rank=%d local_size=%d",
+                  _state.rank, _state.size, _state.local_rank,
+                  _state.local_size)
 
     # Record the coordination epoch this rank is operating under — after a
     # failover the merged metrics must show every rank on the new epoch

@@ -357,8 +357,6 @@ _var("HOROVOD_FLASH_INTERPRET", "bool", False,
 _var("HOROVOD_FLASH_AUTO_MIN_T", "int", 1024,
      "Sequence length above which attention='auto' picks the flash "
      "kernel")
-_var("HOROVOD_FUSED_STEM_INTERPRET", "bool", False,
-     "1 runs the fused conv-stem Pallas kernel in interpret mode")
 _var("HOROVOD_TF1_ASYNC", "bool", False,
      "1 enables TF1-session async collectives with pruned-sync reaping")
 _var("HOROVOD_TF_SYNC_COLLECTIVES", "bool", False,

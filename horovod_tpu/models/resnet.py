@@ -134,56 +134,6 @@ class BasicBlock(nn.Module):
         return _act(self.act, residual + y)
 
 
-class FusedStemNorm(nn.Module):
-    """BatchNorm whose APPLY is folded into the fused stem tail
-    (``ops/fused_stem.fused_bn_relu_maxpool``): statistics exactly as
-    flax's BatchNorm (f32 fast-variance, clip, pmean-synced mean+E[x²]
-    over ``axis_name``, 0.9-momentum running update, same param/stat
-    names so checkpoints interchange with ``stem="s2d"``), then the
-    BN-scale/offset, relu and 3x3/s2 maxpool run as ONE pass.  The apply
-    itself computes in x.dtype with f32-folded coefficients (the
-    strict-bf16 recipe from the LM work, docs/benchmarks.md)."""
-
-    use_running_average: bool
-    axis_name: Optional[str] = None
-    momentum: float = 0.9
-    epsilon: float = 1e-5
-
-    @nn.compact
-    def __call__(self, x):
-        from jax import lax as _lax
-
-        from horovod_tpu.ops.fused_stem import fused_bn_relu_maxpool
-
-        c = x.shape[-1]
-        ra_mean = self.variable("batch_stats", "mean",
-                                lambda: jnp.zeros(c, jnp.float32))
-        ra_var = self.variable("batch_stats", "var",
-                               lambda: jnp.ones(c, jnp.float32))
-        gamma = self.param("scale", nn.initializers.ones, (c,),
-                           jnp.float32)
-        beta = self.param("bias", nn.initializers.zeros, (c,),
-                          jnp.float32)
-        if self.use_running_average:
-            mean, var = ra_mean.value, ra_var.value
-        else:
-            xf = x.astype(jnp.float32)
-            mean = xf.mean(axis=(0, 1, 2))
-            mean2 = (xf * xf).mean(axis=(0, 1, 2))
-            if self.axis_name is not None and not self.is_initializing():
-                con = _lax.pmean(jnp.concatenate([mean, mean2]),
-                                 self.axis_name)
-                mean, mean2 = jnp.split(con, 2)
-            var = jnp.maximum(mean2 - mean * mean, 0.0)
-            if not self.is_initializing():
-                m = self.momentum
-                ra_mean.value = m * ra_mean.value + (1 - m) * mean
-                ra_var.value = m * ra_var.value + (1 - m) * var
-        a = gamma * _lax.rsqrt(var + self.epsilon)
-        b = beta - mean * a
-        return fused_bn_relu_maxpool(x, a, b)
-
-
 class ResNet(nn.Module):
     """ResNet v1.5 over NHWC inputs."""
 
@@ -214,30 +164,18 @@ class ResNet(nn.Module):
             axis_name=self.axis_name if train else None)
 
         x = x.astype(self.dtype)
-        if self.stem not in ("conv7", "s2d", "s2d_fused"):
+        if self.stem not in ("conv7", "s2d"):
             raise ValueError(
-                f"stem={self.stem!r}: expected 'conv7', 's2d' or "
-                f"'s2d_fused'")
-        if self.stem in ("s2d", "s2d_fused"):
+                f"stem={self.stem!r}: expected 'conv7' or 's2d'")
+        if self.stem == "s2d":
             x = conv(self.num_filters, (4, 4), (1, 1),
                      padding=[(2, 1), (2, 1)], name="conv_init")(x)
         else:
             x = conv(self.num_filters, (7, 7), (2, 2),
                      padding=[(3, 3), (3, 3)], name="conv_init")(x)
-        if self.stem == "s2d_fused":
-            # One fused VMEM pass for BN-apply+relu+maxpool (Pallas on
-            # TPU meshes, exact lax twin elsewhere) — checkpoint-
-            # compatible with the flax BN above (same param/stat names).
-            x = FusedStemNorm(use_running_average=not train,
-                              axis_name=self.axis_name if train else None,
-                              momentum=0.9, epsilon=1e-5,  # keep in
-                              # lockstep with the flax norm partial above
-                              name="norm_init")(x)
-        else:
-            x = norm(name="norm_init")(x)
-            x = nn.relu(x)
-            x = nn.max_pool(x, (3, 3), strides=(2, 2),
-                            padding=((1, 1), (1, 1)))
+        x = norm(name="norm_init")(x)
+        x = nn.relu(x)
+        x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)))
         block_cls = self.block_cls
         if self.remat is not None:
             if self.remat not in ("lean", "full"):

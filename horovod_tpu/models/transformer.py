@@ -354,9 +354,21 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
 
     def _one_step(params, opt_state, tokens, labels, segment_ids=None):
         from horovod_tpu import resilience
+        from horovod_tpu.parallel._vma import pin_to
+        # Differentiate with respect to a copy of the params typed as
+        # varying over the gradient axes.  Under check_vma=True, autodiff
+        # with respect to a replicated (unvarying) param already sums the
+        # per-device gradients (the transpose of the unvarying->varying
+        # promotion is a psum), and the explicit mean below would then
+        # average N identical sums: a step N times too large.  Pinned,
+        # the gradients stay local and the fused pmean is the one
+        # reduction.  The model axis is left alone: there the automatic
+        # sum IS the Megatron "f" operator (parallel/tensor.py).
+        local_params = jax.tree_util.tree_map(pin_to(set(grad_axes)),
+                                              params)
         loss, grads = jax.value_and_grad(loss_fn)(
-            params, tokens, labels, cfg, model_axis, seq_axis, attention,
-            segment_ids, remat)
+            local_params, tokens, labels, cfg, model_axis, seq_axis,
+            attention, segment_ids, remat)
 
         def do_update():
             if zopt is not None:

@@ -5,7 +5,7 @@
 // arbitrary processes cannot inject commands.  Here the same trust
 // boundary exists at the controller rendezvous and the data-plane mesh:
 // without auth, any process that can reach the port can claim a rank
-// (VERDICT round-1 finding).  The handshake is mutual challenge-response
+// (an early review finding).  The handshake is mutual challenge-response
 // with HMAC-SHA256 over fresh nonces, run once per connection at connect
 // time; after it succeeds the connection is trusted.
 //

@@ -1,5 +1,4 @@
-// Convergence-quality gate for the Bayesian optimizer (VERDICT r4 weak
-// #5): on known smooth objectives over the unit box, the GP/EI search at
+// Convergence-quality gate for the Bayesian optimizer: on known smooth objectives over the unit box, the GP/EI search at
 // the PRODUCTION trial budget (20 observations, the
 // HOROVOD_AUTOTUNE_BAYES_TRIALS default) must land within a fixed
 // fraction of the dense-grid maximum.  The optimizer is deterministic

@@ -941,9 +941,9 @@ def alltoall_ragged(tensor, splits, output_size: int, axis_name=None,
     Differentiation: the dense twin has full AD support with the
     expected semantics (rows that land somewhere receive their
     cotangent, dropped/slack rows receive zero — gated by
-    ``test_alltoall_ragged_gradient``); the primitive path's AD follows
-    jax's ``lax.ragged_all_to_all`` — pass ``use_primitive=False`` under
-    ``grad`` if your jax version lacks its transpose rule.
+    ``test_alltoall_ragged_gradient``); the primitive path's AD is
+    ``lax.ragged_all_to_all``'s own (jax 0.9.0 registers its JVP and
+    transpose rules; never differentiated on a chip — ROADMAP R2).
     """
     ax = _default_axis(axis_name)
     if not _axis_bound(ax):

@@ -52,19 +52,14 @@ def _exec_on_tpu(x) -> bool:
     return exec_on_tpu(x)
 
 
-def _interpret_default(x=None) -> bool:
+def _interpret_default(x) -> bool:
     """Interpret-mode default for the kernel: the explicit debug env
-    knob wins; otherwise interpret iff the computation does NOT execute
-    on TPU — judged from the operand's executing mesh when one is given
-    (see :func:`_exec_on_tpu`), else from the host's default backend."""
+    knob wins; otherwise interpret iff the mesh executing ``x`` is not a
+    TPU (see :func:`_exec_on_tpu`).  A failure of that query is raised:
+    a TPU mesh must never end in the interpreter by accident."""
     if os.environ.get("HOROVOD_FLASH_INTERPRET") == "1":
         return True
-    if x is not None:
-        return not _exec_on_tpu(x)
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # pragma: no cover
-        return True
+    return not _exec_on_tpu(x)
 
 
 # ---------------------------------------------------------------------------

@@ -2,9 +2,7 @@
 
 A scan carry's vma type must be stable across iterations: after one step
 the online state varies over every axis the inputs vary over, so initial
-zeros must be pcast up to the union of the inputs' vma sets.  Kept in one
-place because the probe (jax.typeof) and the no-mesh fallback are
-JAX-version-sensitive.
+zeros must be pcast up to the union of the inputs' vma sets.
 """
 
 from __future__ import annotations
@@ -14,22 +12,17 @@ from jax import lax
 
 
 def vma_of(x) -> set:
-    """The value's varying-manual-axes set ({} outside shard_map)."""
-    try:
-        return set(jax.typeof(x).vma)
-    except AttributeError:   # outside shard_map / old tracer
-        return set()
+    """The value's varying-manual-axes set (empty outside shard_map and
+    under ``check_vma=False``)."""
+    return set(jax.typeof(x).vma)
 
 
 def pin_to(target: set):
     """Returns f(x) that pcasts ``x`` up to vary over ``target`` (no-op on
-    axes it already varies over; tolerant of running without a mesh)."""
+    axes it already varies over, and outside a mesh)."""
     def _pin(x):
         missing = tuple(sorted(target - vma_of(x)))
         if not missing:
             return x
-        try:
-            return lax.pcast(x, missing, to="varying")
-        except ValueError:   # no surrounding mesh context (vma untracked)
-            return x
+        return lax.pcast(x, missing, to="varying")
     return _pin

@@ -165,9 +165,7 @@ def moe_layer_ragged(x, router_w, expert_fn: Callable, expert_params,
     x: [T_local, D]; router_w: [D, E_total]; expert_params: this chip's
     expert parameters; expert_fn(params, tokens[N, D]) -> [N, D]
     (position-independent per row — it sees padded zero rows).
-    ``use_primitive`` forwards to :func:`alltoall_ragged` (pass False
-    under ``grad`` on a jax whose ragged primitive lacks a transpose
-    rule — the dense twin differentiates everywhere).
+    ``use_primitive`` forwards to :func:`alltoall_ragged`.
     Returns [T_local, D].
     """
     from horovod_tpu.ops.collective import alltoall_ragged

@@ -16,9 +16,8 @@ schedule; the explicit reduce-scatter/psum/all-gather decomposition below
 pins the bandwidth-optimal pattern: each DCN link carries only 1/ici_size
 of the payload.
 
-The gather legs use ``lax.all_gather`` (each-byte-once ring traffic, not
-the O(global)-bytes-per-link masked psum this module used to carry) and
-repair the vma annotation explicitly — see :func:`_gather_replicated`.
+The gather legs go through :func:`_gather_replicated`, which keeps the
+result typed replicated under ``check_vma=True``.
 """
 
 from __future__ import annotations
@@ -35,29 +34,20 @@ def _gather_replicated(v, axis: str):
     ``axis``, so it can flow out of a ``check_vma=True`` shard_map through
     a replicated ``P()`` out_spec.
 
-    The bandwidth story: all_gather's ring moves each byte once
-    ((n-1)/n of the output per link), while the masked-psum spelling —
-    reduce a zero-padded full-size buffer — moves O(output) bytes per
-    link per step unless XLA pattern-matches the one-hot away.  The typing
-    story is the hard part: on vma-tracking JAX an all_gather output is
-    "possibly varying over {axis}" even though every shard is bitwise
-    identical.  We repair that with ``lax.pcast(..., to="unvarying")``
-    where the primitive exists; if neither vma tracking nor pcast is
-    present (jax 0.4.x, where check_vma is shimmed off) the raw all_gather
-    is already fine; only when vma is tracked but unvarying-pcast is
-    refused do we fall back to the masked psum, the one collective whose
-    output vma inference marks unvarying.
+    all_gather's ring moves each byte once ((n-1)/n of the output per
+    link), but under vma tracking its output is typed "varying over
+    {axis}" even though every shard is bitwise identical, and jax 0.9.0
+    has no public cast back (``lax.pcast`` accepts ``to="varying"``,
+    ``"reduced"`` and ``"unreduced"`` only).  So the all_gather is used
+    where vma is not tracked (``check_vma=False``), and under
+    ``check_vma=True`` the gather is spelled as a masked psum — the one
+    collective whose output the checker infers as unvarying — at about
+    twice the ring's ICI bytes unless XLA folds the one-hot away.
     """
-    n = lax.axis_size(axis)
     out = lax.all_gather(v, axis, axis=0, tiled=True)
     if axis not in vma_of(out):
-        return out  # not varying (or vma untracked): already replicated
-    try:
-        return lax.pcast(out, (axis,), to="unvarying")
-    except (TypeError, ValueError, NotImplementedError):
-        pass
-    # Fallback: masked psum — unvarying by construction, at ICI-bandwidth
-    # cost (~2x the all_gather ring if XLA keeps the reduction).
+        return out
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     buf = jnp.zeros((n,) + v.shape, v.dtype).at[idx].set(v)
     return lax.psum(buf, axis).reshape((n * v.shape[0],) + v.shape[1:])
@@ -137,10 +127,9 @@ def hierarchical_allgather(x, ici_axis: str, dcn_axis: str):
     Concatenation order is (dcn, ici, local dim 0), matching a flat
     allgather over a mesh whose ICI axis is minor.
 
-    Both legs are ``lax.all_gather`` rings (each byte crosses each link
-    once) with the replication annotation handled by
-    :func:`_gather_replicated` — the O(global)-bytes-per-link masked-psum
-    caveat this function used to document is gone.
+    Both legs go through :func:`_gather_replicated`: ``lax.all_gather``
+    rings under ``check_vma=False``, the masked psum under
+    ``check_vma=True``.
     """
     esize = x.dtype.itemsize
     local = _gather_replicated(x, ici_axis)

@@ -304,6 +304,34 @@ def wipe_shm_dir(path: str) -> None:
             pass
 
 
+def chip_contention(infos, env) -> Optional[str]:
+    """Why this job's local ranks would contend for the host's TPU chips,
+    or None when they would not (docs/running.md, "Ranks and chips").
+
+    A chip belongs to one process.  Ranks inherit the launcher's
+    environment and are given no chip of their own, so unless
+    ``JAX_PLATFORMS`` keeps them on the CPU, every rank that touches JAX
+    claims all local chips and the second one fails or hangs.  Chips are
+    detected the way JAX itself decides to load libtpu (a PCI scan that
+    initialises no backend); only this machine can be inspected, remote
+    hosts are not checked.
+    """
+    if env.get("JAX_PLATFORMS", "").split(",")[0].strip().lower() == "cpu":
+        return None
+    local = sum(1 for i in infos if launch.is_local(i.hostname))
+    if local < 2:
+        return None
+    from jax._src import hardware_utils
+    chips, _ = hardware_utils.num_available_tpu_chips_and_device_id()
+    if not chips:
+        return None
+    return (f"{local} ranks on this host would each initialise JAX on its "
+            f"{chips} TPU chip(s), and a chip belongs to one process. Run "
+            f"one process per host and drive all of its chips through "
+            f"hvd.mesh() (the SPMD plane), or set JAX_PLATFORMS=cpu for "
+            f"ranks that only use the host-side eager plane.")
+
+
 def run_command(args) -> int:
     """Resolved-args entry, shared with tests."""
     if args.hostfile:
@@ -318,6 +346,10 @@ def run_command(args) -> int:
 
     infos = hosts.allocate(host_list, np_)
     extra_env = config_parser.env_from_args(args)
+    refusal = chip_contention(infos, {**os.environ, **extra_env})
+    if refusal:
+        print(f"hvdrun: refusing to launch: {refusal}", file=sys.stderr)
+        return 1
     # One shared secret per job unless the caller pinned one (e.g. to join
     # an externally coordinated job).
     extra_env.setdefault(

@@ -27,9 +27,11 @@ def build_mesh(axes: Sequence[str] = ("data",),
     """Build a :class:`jax.sharding.Mesh` over ``devices``.
 
     * 1 axis, no shape: all devices on one axis (GLOBAL communicator).
-    * N axes + shape: reshape devices into that grid.  For real multi-slice
-      TPU jobs ``mesh_utils.create_hybrid_device_mesh`` is used so the
-      leading axis maps to DCN and trailing axes to ICI.
+    * N axes + shape: lay devices out on that grid.  On chips
+      ``mesh_utils`` maps the axes onto the physical topology (for
+      devices spanning several slices the leading axis maps to DCN and
+      the trailing axes to ICI) and any failure there is raised; only
+      CPU devices are reshaped in rank order.
     """
     devices = list(jax.devices()) if devices is None else list(devices)
     n = len(devices)
@@ -73,19 +75,24 @@ def build_mesh(axes: Sequence[str] = ("data",),
         raise ValueError(
             f"mesh shape {shape} does not cover {n} devices")
 
-    if len(axes) > 1 and jax.process_count() > 1:
-        try:
-            dev_array = mesh_utils.create_hybrid_device_mesh(
-                mesh_shape=shape[1:], dcn_mesh_shape=(shape[0],) + (1,) * (len(shape) - 1))
-            return Mesh(dev_array, axes)
-        except Exception:  # heterogeneous/virtual platforms: fall through
-            pass
-    try:
+    if devices[0].platform == "cpu":
+        # Virtual CPU devices (forced host platform count) carry no
+        # topology; a plain reshape preserves the launcher's rank order.
+        return Mesh(np.asarray(devices).reshape(shape), axes)
+    # Real chips: let mesh_utils lay the axes out on the physical
+    # topology, and let its errors surface — a rank-order reshape here
+    # would silently put a mesh axis on the wrong links.
+    if len(axes) > 1 and len({getattr(d, "slice_index", 0)
+                              for d in devices}) > 1:
+        # Devices span several slices: the leading axis rides DCN.
+        # (Never run on multi-slice hardware; the previous call passed
+        # shapes of unequal rank and its error was swallowed.)
+        dev_array = mesh_utils.create_hybrid_device_mesh(
+            mesh_shape=(1,) + tuple(shape[1:]),
+            dcn_mesh_shape=(shape[0],) + (1,) * (len(shape) - 1),
+            devices=devices)
+    else:
         dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
-        # Virtual CPU meshes (forced host platform count) lack topology
-        # info; a plain reshape preserves the launcher's rank order.
-        dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, axes)
 
 
@@ -103,9 +110,6 @@ def mesh_size(mesh: Mesh, axis=None) -> int:
     return mesh.shape[axis]
 
 
-_warned_no_abstract_device = False
-
-
 def exec_on_tpu(x) -> bool:
     """Whether the mesh actually EXECUTING this computation is TPU.
 
@@ -116,26 +120,15 @@ def exec_on_tpu(x) -> bool:
     sharding carries the real device kind of the mesh the shard_map runs
     on.  Shared by the flash-attention kernel gates and
     ``alltoall_ragged``'s primitive/dense-twin routing.
+
+    The attribute chain is internal JAX surface (pinned by
+    ``tests/test_basics.py::test_exec_on_tpu_attribute_chain`` against
+    the jax version ``pyproject.toml`` pins); a JAX that renames a link
+    raises ``AttributeError`` here rather than routing on a guess.
     """
-    global _warned_no_abstract_device
-    try:
-        # abstract_device is None on eager/concrete arrays (normal: fall
-        # through to the backend answer, silently); it is internal
-        # surface, so a MISSING attribute means a JAX upgrade renamed it
-        # — say so once instead of silently reverting to the
-        # host-backend answer this helper exists to avoid.
-        ad = jax.typeof(x).sharding.mesh.abstract_device
-        if ad is not None and ad.device_kind is not None:
-            return "tpu" in str(ad.device_kind).lower()
-    except AttributeError:
-        if not _warned_no_abstract_device:
-            _warned_no_abstract_device = True
-            import logging
-            logging.getLogger(__name__).warning(
-                "AbstractMesh.abstract_device.device_kind unavailable on "
-                "this JAX; falling back to jax.default_backend() for the "
-                "executing-mesh platform gate")
-    try:  # outside shard_map / no mesh info: fall back to the backend
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    ad = jax.typeof(x).sharding.mesh.abstract_device
+    if ad is not None:
+        return "tpu" in ad.device_kind.lower()
+    # Outside shard_map the abstract mesh is empty and the computation
+    # runs on the default backend.
+    return jax.default_backend() == "tpu"

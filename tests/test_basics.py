@@ -92,63 +92,35 @@ def test_mesh_bad_shape(hvd):
         hvd.mesh(axes=("a", "b"), shape=(3, 4))
 
 
-def test_exec_on_tpu_attribute_chain(hvd, monkeypatch):
+def test_exec_on_tpu_attribute_chain(hvd):
     """Pin the JAX-internal chain ``jax.typeof(x).sharding.mesh
     .abstract_device.device_kind`` that ``topology.exec_on_tpu`` routes
-    on.  The chain is internal surface, so the contract this test pins
-    is: either the WHOLE chain resolves on a shard_map tracer, or the
-    one-shot fallback notice fires at WARNING — a JAX upgrade that
-    breaks a link can never silently degrade kernel routing to the
-    host-backend answer.
+    on, against the jax version pyproject.toml pins: inside shard_map the
+    whole chain resolves to the executing mesh's device kind, outside it
+    ``abstract_device`` is None and the default backend answers.  There
+    is no fallback — a JAX that renames a link fails here (and raises in
+    ``exec_on_tpu``) instead of silently degrading kernel routing.
     """
     import importlib
-    import logging
 
     import jax
+    from jax.sharding import PartitionSpec as P
 
     # The package exports basics.topology() under the same name; the
     # module itself must come from the module registry.
     topo = importlib.import_module("horovod_tpu.topology")
-
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # newer JAX
-        shard_map = jax.shard_map
-    from jax.sharding import PartitionSpec as P
-
-    monkeypatch.setattr(topo, "_warned_no_abstract_device", False)
-    m = hvd.mesh()
     seen = {}
 
     def body(x):
-        try:
-            ad = jax.typeof(x).sharding.mesh.abstract_device
-            # None is the legitimate "no device info" answer; a present
-            # object must still carry device_kind.
-            seen["chain"] = ad is None or hasattr(ad, "device_kind")
-        except AttributeError:
-            seen["chain"] = False
+        seen["kind"] = jax.typeof(x).sharding.mesh.abstract_device.device_kind
         seen["exec_on_tpu"] = topo.exec_on_tpu(x)
         return x
 
-    # The horovod_tpu root logger does not propagate (utils/logging), so
-    # capture with a handler on the module's own logger, not caplog.
-    records = []
-    handler = logging.Handler()
-    handler.emit = records.append
-    logger = logging.getLogger("horovod_tpu.topology")
-    logger.addHandler(handler)
-    try:
-        shard_map(body, mesh=m, in_specs=P("data"), out_specs=P("data"))(
-            np.zeros(8, np.float32))
-    finally:
-        logger.removeHandler(handler)
+    jax.shard_map(body, mesh=hvd.mesh(), in_specs=P("data"),
+                  out_specs=P("data"))(np.zeros(8, np.float32))
+    assert seen["kind"] == jax.devices()[0].device_kind
+    assert seen["exec_on_tpu"] is False          # CPU mesh
 
-    # CPU mesh either way: the platform gate must answer False.
-    assert seen["exec_on_tpu"] is False
-    warned = any("abstract_device" in r.getMessage() and
-                 r.levelno >= logging.WARNING for r in records)
-    assert seen["chain"] or warned, (
-        "the jax.typeof(...).sharding.mesh.abstract_device chain is "
-        "broken on this JAX and exec_on_tpu fell back WITHOUT its "
-        "one-shot WARNING — silent routing degradation (topology.py)")
+    x = np.zeros(8, np.float32)
+    assert jax.typeof(x).sharding.mesh.abstract_device is None
+    assert topo.exec_on_tpu(x) is False

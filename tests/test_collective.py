@@ -317,7 +317,7 @@ def _ragged_oracle(xs, splits, cap):
 
 
 def test_alltoall_ragged_matches_oracle(hvd, mesh8):
-    """SPMD uneven alltoall (VERDICT r4 weak #4): static-capacity ragged
+    """SPMD uneven alltoall: static-capacity ragged
     exchange inside shard_map, dense-twin route (CPU mesh), vs a numpy
     oracle.  Row payloads encode (sender, dest, i) so misrouting is
     detected, not just miscounting."""

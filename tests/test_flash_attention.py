@@ -358,7 +358,7 @@ def test_attention_auto_dispatch(hvd, monkeypatch):
 def test_attention_auto_never_raises_on_shape(hvd):
     """T=1992 is above the auto threshold but not 128-divisible: the
     flash kernel cannot tile it, so ``attention="auto"`` must silently
-    take the lax path (VERDICT r3 #4: no shape may make ``auto`` fail;
+    take the lax path (no shape may make ``auto`` fail;
     only an explicit ``attention="flash"`` may raise)."""
     from horovod_tpu.models import transformer as tfm
 

@@ -445,6 +445,57 @@ def test_transformer_train_step_dp_tp_sp(hvd):
     assert losses[-1] < losses[0], losses
 
 
+@pytest.mark.parametrize("axes,shape,kw", [
+    (("data",), (4,), {"attention": "local"}),
+    (("data", "model", "seq"), (2, 2, 2),
+     {"model_axis": "model", "seq_axis": "seq", "attention": "ring"}),
+])
+def test_transformer_train_step_matches_single_device(hvd, axes, shape, kw):
+    """The sharded training step takes the SAME steps as one device on
+    the same global batch.  "Loss finite and decreasing" cannot see a
+    gradient that is N times too large — which is what autodiff under
+    check_vma=True produced for replicated params before the step pinned
+    them to vary over the gradient axes (models/transformer.py)."""
+    import warnings
+
+    import optax
+
+    from horovod_tpu.models import transformer as tfm
+
+    cfg = _tiny_cfg()
+    toks = np.random.default_rng(9).integers(0, cfg.vocab_size, (4, 33),
+                                             dtype=np.int32)
+
+    def losses(axes, shape, model_axis=None, seq_axis=None,
+               attention="local"):
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")      # underfilled-mesh notice
+            mesh = _mesh(hvd, axes, shape)
+        opt = optax.sgd(0.1, momentum=0.9)
+        step, specs, opt_specs = tfm.make_train_step(
+            cfg, opt, mesh, model_axis=model_axis, seq_axis=seq_axis,
+            attention=attention)
+        params = jax.device_put(
+            tfm.init_params(jax.random.PRNGKey(0), cfg),
+            jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), specs))
+        opt_state = jax.device_put(
+            opt.init(params), jax.tree_util.tree_map(
+                lambda s: NamedSharding(mesh, s), opt_specs,
+                is_leaf=lambda x: isinstance(x, P)))
+        data = NamedSharding(mesh, P("data", seq_axis))
+        tokens = jax.device_put(toks[:, :-1], data)
+        labels = jax.device_put(toks[:, 1:], data)
+        out = []
+        for _ in range(3):
+            params, opt_state, loss = step(params, opt_state, tokens,
+                                           labels)
+            out.append(float(np.asarray(loss)))
+        return out
+
+    np.testing.assert_allclose(losses(axes, shape, **kw),
+                               losses(("data",), (1,)), rtol=1e-5)
+
+
 def test_sharding_aware_clip_matches_unsharded_oracle(hvd):
     """parallel.tensor.clip_by_global_norm under a 2-way TP shard_map must
     reproduce optax's single-device global-norm clip exactly."""
@@ -797,7 +848,7 @@ def test_interleaved_pipeline_matches_oracle(hvd):
     """Interleaved (virtual-stage) schedule at P=4, v=2, M=8: loss AND
     every gradient (base + all 8 round-robin chunks) equal the plain
     forward's — the same exact-gradient gate the GPipe/1F1B schedules
-    pass (VERDICT r3 #7)."""
+    pass."""
     from horovod_tpu.models import transformer as tfm
 
     cfg = tfm.TransformerConfig(vocab_size=32, d_model=16, n_heads=2,

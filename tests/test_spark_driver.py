@@ -208,7 +208,7 @@ def test_connect_with_retry_backoff_and_exhaustion():
 def test_driver_fails_fast_on_lost_task():
     """A task that registers and then falls silent (executor OOM-killed,
     node gone) must fail the job at the keepalive timeout, not after the
-    full result timeout (VERDICT: wired dead_tasks into the wait loop)."""
+    full result timeout (dead_tasks is wired into the wait loop)."""
     driver = JobDriver(2, KEY, keepalive_timeout=0.2)
     try:
         for idx in (0, 1):
