@@ -27,6 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 import horovod_tpu as hvd
 from horovod_tpu.ops.fusion import fused_pytree_mean
+from horovod_tpu.telemetry import scopes
 from horovod_tpu.topology import build_mesh, data_axis, mesh_size
 
 # Peak dense bf16 FLOP/s per chip by device kind (public TPU spec sheet
@@ -127,8 +128,9 @@ def make_train_step(model, optimizer, mesh, axis_name: Optional[str] = None,
             logits, mutated = model.apply(
                 {"params": p, "batch_stats": batch_stats}, images,
                 train=True, mutable=["batch_stats"])
-            loss = optax.softmax_cross_entropy_with_integer_labels(
-                logits, labels).mean()
+            with jax.named_scope(scopes.LOSS):
+                loss = optax.softmax_cross_entropy_with_integer_labels(
+                    logits, labels).mean()
             return loss, mutated["batch_stats"]
 
         (loss, new_stats), grads = jax.value_and_grad(
@@ -139,10 +141,11 @@ def make_train_step(model, optimizer, mesh, axis_name: Optional[str] = None,
             # psum — reference fusion_buffer_manager + NCCLAllreduce,
             # here one bf16-safe bucketed pmean riding ICI).
             g = fused_pytree_mean(grads, ax)
-            updates, new_opt_state = optimizer.update(g, opt_state,
-                                                      params)
-            return (optax.apply_updates(params, updates), new_stats,
-                    new_opt_state)
+            with jax.named_scope(scopes.OPTIMIZER):
+                updates, new_opt_state = optimizer.update(g, opt_state,
+                                                          params)
+                new_params = optax.apply_updates(params, updates)
+            return new_params, new_stats, new_opt_state
 
         from horovod_tpu import resilience
         ((new_params, out_stats, new_opt_state),
@@ -171,7 +174,8 @@ def make_train_step(model, optimizer, mesh, axis_name: Optional[str] = None,
         in_specs=(repl, repl, repl, shard, shard),
         out_specs=(repl, repl, repl, repl),
         check_vma=False)
-    return jax.jit(smapped, donate_argnums=(0, 1, 2))
+    return jax.jit(scopes.named(smapped, scopes.TRAIN_STEP),
+                   donate_argnums=(0, 1, 2))
 
 
 def make_bench_state(model_name: str = "resnet50", batch_size: int = 64,
@@ -179,11 +183,11 @@ def make_bench_state(model_name: str = "resnet50", batch_size: int = 64,
                      input_dtype: str = "float32", stem: str = "conv7",
                      remat: Optional[str] = None, mesh=None,
                      learning_rate: float = 0.01):
-    """The ONE benchmark-state recipe, shared by the throughput run, the
-    --profile path and the standalone profiling tools so they always
-    measure the same program.  Returns ``(mesh, ax, model, optimizer,
-    s2d, (params, batch_stats, opt_state), (images, labels))`` with the
-    batch sharded over the data axis and state replicated.
+    """The ONE benchmark-state recipe, shared by the throughput run and
+    ``chip_smoke.py`` so they always measure the same program.  Returns
+    ``(mesh, ax, model, optimizer, s2d, (params, batch_stats, opt_state),
+    (images, labels))`` with the batch sharded over the data axis and
+    state replicated.
     """
     from horovod_tpu.models import get_model
 
@@ -720,41 +724,6 @@ def run_scaling_efficiency(model_name: str = "resnet50",
         "img_sec_n": res_n["img_sec_total"],
         "scaling_efficiency": efficiency,
     }
-
-
-def run_profile(model_name: str = "resnet50", batch_size: int = 64,
-                image_size: int = 224, steps: int = 10,
-                input_dtype: str = "bfloat16", stem: str = "conv7",
-                remat: Optional[str] = None, mesh=None) -> None:
-    """Trace ``steps`` scanned training steps with jax.profiler and print
-    the per-fusion-category and per-layer device-time breakdown — the
-    device-side complement of the native runtime's chrome timeline
-    (docs/benchmarks.md's roofline section was produced with this).
-    Same state recipe as the throughput benchmark (make_bench_state), so
-    the profile explains exactly the program the benchmark measures."""
-    from horovod_tpu.utils import profiling
-
-    (mesh, ax, model, optimizer, _s2d,
-     (params, batch_stats, opt_state),
-     (images, labels)) = make_bench_state(
-        model_name, batch_size, image_size=image_size,
-        input_dtype=input_dtype, stem=stem, remat=remat, mesh=mesh)
-
-    step = make_train_step(model, optimizer, mesh, ax,
-                           steps_per_call=steps)
-    compiled = step.lower(params, batch_stats, opt_state, images,
-                          labels).compile()
-    # The step donates its state buffers — rethread them through each call.
-    state = compiled(params, batch_stats, opt_state, images, labels)
-    float(np.asarray(state[3]))    # warm + real barrier
-
-    def run():
-        nonlocal state
-        state = compiled(state[0], state[1], state[2], images, labels)
-        float(np.asarray(state[3]))
-
-    trace = profiling.trace_once(run)
-    profiling.print_profile(trace, compiled.as_text(), steps=steps)
 
 
 def run_step_guard_benchmark(model_name: str = "resnet50",
@@ -1453,9 +1422,6 @@ def _main():
     parser.add_argument("--num-iters", type=int, default=10)
     parser.add_argument("--efficiency", action="store_true",
                         help="weak-scaling efficiency: 1 device vs all")
-    parser.add_argument("--profile", action="store_true",
-                        help="trace one round and print the per-op/"
-                             "per-layer device-time breakdown")
     parser.add_argument("--stem", default="conv7",
                         choices=("conv7", "s2d"))
     parser.add_argument("--lm", action="store_true",
@@ -1576,9 +1542,6 @@ def _main():
                                  args.num_batches_per_iter, 2),
                              num_iters=min(args.num_iters, 3))
         run_step_guard_benchmark(model, bs, **sg_kwargs)
-    elif args.profile:
-        run_profile(args.model, args.batch_size, args.image_size,
-                    steps=args.num_batches_per_iter, stem=args.stem)
     elif args.efficiency:
         run_scaling_efficiency(args.model, args.batch_size, **kwargs)
     else:

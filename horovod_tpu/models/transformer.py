@@ -30,6 +30,7 @@ from jax.sharding import PartitionSpec as P
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel import sequence as seq_mod
 from horovod_tpu.parallel import tensor as tp
+from horovod_tpu.telemetry import scopes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -170,6 +171,7 @@ def _flash_profitable(t: int) -> bool:
     return t >= min_t
 
 
+@jax.named_scope(scopes.HEAD)
 def _logits_head(x, params, dt):
     """Final rmsnorm + tied-embedding projection (shared fwd/decode)."""
     x = _rmsnorm(x, params["ln_f_scale"])
@@ -216,14 +218,16 @@ def forward(params, tokens, cfg: TransformerConfig,
     """
     dt = cfg.dtype
     t_local = tokens.shape[1]
-    pos_offset = (lax.axis_index(seq_axis) * t_local) if seq_axis else 0
-    x = (params["embed"][tokens] +
-         lax.dynamic_slice_in_dim(params["pos"], pos_offset, t_local,
-                                  axis=0)[None]).astype(dt)
+    with jax.named_scope(scopes.EMBED):
+        pos_offset = (lax.axis_index(seq_axis) * t_local) if seq_axis else 0
+        x = (params["embed"][tokens] +
+             lax.dynamic_slice_in_dim(params["pos"], pos_offset, t_local,
+                                      axis=0)[None]).astype(dt)
 
     def layer_block(x, layer, segment_ids):
-        # --- attention block ---
-        q, k, v, dh = _qkv_proj(x, layer, dt, model_axis, cfg.head_dim)
+        # --- attention block (each route opens its own attn/<route>) ---
+        with jax.named_scope(scopes.ATTN_QKV):
+            q, k, v, dh = _qkv_proj(x, layer, dt, model_axis, cfg.head_dim)
         b, t = q.shape[:2]
         if seq_axis is not None:
             if attention == "ring_flash" or (attention == "auto" and
@@ -259,16 +263,20 @@ def forward(params, tokens, cfg: TransformerConfig,
         else:
             o = seq_mod.local_attention(q, k, v, causal=True,
                                         segment_ids=segment_ids)
-        x = _attn_out(o.reshape(b, t, dh), x, layer, dt, model_axis)
-        return _mlp_block(x, layer, dt, model_axis)
+        with jax.named_scope(scopes.ATTN_OUT):
+            x = _attn_out(o.reshape(b, t, dh), x, layer, dt, model_axis)
+        with jax.named_scope(scopes.MLP):
+            return _mlp_block(x, layer, dt, model_axis)
 
     layer_block = _remat_wrap(layer_block, remat)
-    for layer in params["layers"]:
-        x = layer_block(x, layer, segment_ids)
+    for i, layer in enumerate(params["layers"]):
+        with jax.named_scope(scopes.LAYER % i):
+            x = layer_block(x, layer, segment_ids)
 
     return _logits_head(x, params, dt)
 
 
+@jax.named_scope(scopes.LOSS)
 def xent(logits, labels):
     """Mean next-token cross-entropy (the one loss formula — shared by
     the plain and pipelined training steps and the oracle tests)."""
@@ -380,9 +388,12 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
                 # DP gradient averaging (fused psum) over data (+seq)
                 # axes; TP/f-op already settled the model axis.
                 g = fused_pytree_mean(grads, grad_axes)
-                updates, new_opt = optimizer.update(g, opt_state, params)
-            new_params = jax.tree_util.tree_map(lambda p, u: p + u,
-                                                params, updates)
+                with jax.named_scope(scopes.OPTIMIZER):
+                    updates, new_opt = optimizer.update(g, opt_state,
+                                                        params)
+            with jax.named_scope(scopes.OPTIMIZER):
+                new_params = jax.tree_util.tree_map(lambda p, u: p + u,
+                                                    params, updates)
             return new_params, new_opt
 
         (new_params, new_opt), mean_loss = resilience.apply_step_guard(
@@ -433,7 +444,8 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
         # The ZeRO path's axis_index-dependent slicing + psum_scatter do
         # not type under the vma checker; the plain path keeps it on.
         check_vma=zopt is None)
-    jitted = jax.jit(step, donate_argnums=(0, 1) if donate else ())
+    jitted = jax.jit(scopes.named(step, scopes.LM_TRAIN_STEP),
+                     donate_argnums=(0, 1) if donate else ())
     if zopt is not None:
         @functools.wraps(jitted)
         def wrapped(*a, **kw):
@@ -636,8 +648,9 @@ def _embed_microbatches(base, tokens, cfg: TransformerConfig,
     if b % n_microbatches:
         raise ValueError(f"batch {b} not divisible by "
                          f"{n_microbatches} microbatches")
-    x = (base["embed"][tokens] +
-         base["pos"][None, :t]).astype(cfg.dtype)          # [B, T, D]
+    with jax.named_scope(scopes.EMBED):
+        x = (base["embed"][tokens] +
+             base["pos"][None, :t]).astype(cfg.dtype)      # [B, T, D]
     return x.reshape(n_microbatches, b // n_microbatches, t, cfg.d_model)
 
 
@@ -647,11 +660,14 @@ def _pipe_stage_fn(cfg: TransformerConfig):
     dt, hd = cfg.dtype, cfg.head_dim
 
     def one_layer(x, lp):
-        q, k, v, dh = _qkv_proj(x, lp, dt, None, hd)
+        with jax.named_scope(scopes.ATTN_QKV):
+            q, k, v, dh = _qkv_proj(x, lp, dt, None, hd)
         bb, tt = q.shape[:2]
         o = seq_mod.local_attention(q, k, v, causal=True)
-        x = _attn_out(o.reshape(bb, tt, dh), x, lp, dt, None)
-        x = _mlp_block(x, lp, dt, None)
+        with jax.named_scope(scopes.ATTN_OUT):
+            x = _attn_out(o.reshape(bb, tt, dh), x, lp, dt, None)
+        with jax.named_scope(scopes.MLP):
+            x = _mlp_block(x, lp, dt, None)
         # attention computes in f32; pin the carried activation to the
         # model dtype so the layer scan (and the pipeline's microbatch
         # buffers) keep a stable, bf16-safe type
@@ -792,9 +808,10 @@ def make_train_step_pipelined(cfg: TransformerConfig, optimizer, mesh,
 
     def _step(params, opt_state, tokens, labels):
         loss, grads = jax.value_and_grad(_loss)(params, tokens, labels)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = jax.tree_util.tree_map(lambda p, u: p + u, params,
-                                        updates)
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = jax.tree_util.tree_map(lambda p, u: p + u, params,
+                                            updates)
         return params, opt_state, loss
 
     def shardings(params):
@@ -817,5 +834,6 @@ def make_train_step_pipelined(cfg: TransformerConfig, optimizer, mesh,
             transform_non_params=lambda _l: NamedSharding(mesh, P()))
         return p_sh, o_sh
 
-    step = jax.jit(_step, donate_argnums=(0, 1) if donate else ())
+    step = jax.jit(scopes.named(_step, scopes.LM_PIPELINED_TRAIN_STEP),
+                   donate_argnums=(0, 1) if donate else ())
     return step, shardings

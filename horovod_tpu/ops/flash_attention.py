@@ -41,6 +41,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu.telemetry import scopes
+
 NEG_INF = float("-inf")
 
 
@@ -371,6 +373,7 @@ def _fwd_parts(qf, kf, vf, qsegf, ksegf, h, causal, scale, block_q,
         ],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name=scopes.FLASH_FWD,
     )(*operands)
 
 
@@ -430,6 +433,7 @@ def _bwd_parts(qf, kf, vf, of, dof, m, l, qsegf, ksegf, h, causal, scale,
         out_shape=jax.ShapeDtypeStruct((bh, t, d), qf.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name=scopes.FLASH_BWD_DQ,
     )(*dq_operands)
 
     kernel_dkv = functools.partial(_bwd_dkv_kernel, block_q=block_q,
@@ -467,6 +471,7 @@ def _bwd_parts(qf, kf, vf, of, dof, m, l, qsegf, ksegf, h, causal, scale,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name=scopes.FLASH_BWD_DKV,
     )(*dkv_operands)
     return dq, dk, dv
 
@@ -556,6 +561,7 @@ def _eff_blocks(t, block_q, block_k, head_dim=None):
     return bq, bk
 
 
+@jax.named_scope(scopes.ATTN_FLASH)
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                segment_ids=None):
     d = q.shape[-1]
@@ -565,6 +571,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
     return _fwd(q, k, v, segment_ids, causal, scale_, bq, bk, interp)
 
 
+@jax.named_scope(scopes.ATTN_FLASH)
 def _flash_bwd(causal, scale, block_q, block_k, interpret, res, do):
     t, d = res[0].shape[1], res[0].shape[-1]
     scale_ = (d ** -0.5) if scale is None else scale

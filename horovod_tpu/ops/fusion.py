@@ -38,6 +38,7 @@ import numpy as np
 from jax import lax
 
 from horovod_tpu import telemetry
+from horovod_tpu.telemetry import scopes
 from horovod_tpu.utils.logging import get_logger
 
 log = get_logger(__name__)
@@ -328,7 +329,9 @@ def fused_pytree_mean(tree, axis_name, threshold: int | None = None):
     """Average a gradient pytree across ``axis_name`` with fusion — the core
     of :class:`horovod_tpu.parallel.data.DistributedOptimizer`'s jit path."""
     leaves, treedef = jax.tree_util.tree_flatten(tree)
-    reduced = fused_psum(leaves, axis_name, mean=True, threshold=threshold)
+    with jax.named_scope(scopes.GRAD_MEAN):
+        reduced = fused_psum(leaves, axis_name, mean=True,
+                             threshold=threshold)
     return jax.tree_util.tree_unflatten(treedef, reduced)
 
 

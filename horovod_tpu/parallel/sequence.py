@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu.telemetry import scopes
+
 
 def _block_attention(q, k, v, m, l, o, *, q_offset, k_offset, causal, scale,
                      q_seg=None, k_seg=None):
@@ -59,6 +61,7 @@ def _block_attention(q, k, v, m, l, o, *, q_offset, k_offset, causal, scale,
     return m_new, l_new, o_new
 
 
+@jax.named_scope(scopes.ATTN_RING)
 def ring_attention(q, k, v, axis_name: str = "seq", causal: bool = True,
                    scale: Optional[float] = None, segment_ids=None):
     """Exact attention over a sequence sharded across ``axis_name``.
@@ -254,6 +257,7 @@ def ring_flash_attention(q, k, v, axis_name: str = "seq",
     return out
 
 
+@jax.named_scope(scopes.ATTN_RING_FLASH)
 def _ring_flash_fwd(q, k, v, axis_name, causal, scale, interpret,
                     segment_ids):
     from horovod_tpu.ops import flash_attention as fa
@@ -335,6 +339,7 @@ def _ring_flash_fwd(q, k, v, axis_name, causal, scale, interpret,
     return fa._unfold(of, b, h), (qf, kf, vf, segf, of, m, l, b, h)
 
 
+@jax.named_scope(scopes.ATTN_RING_FLASH)
 def _ring_flash_bwd(axis_name, causal, scale, interpret, res, do):
     from horovod_tpu.ops import flash_attention as fa
 
@@ -407,6 +412,7 @@ def _ring_flash_bwd(axis_name, causal, scale, interpret, res, do):
 ring_flash_attention.defvjp(_ring_flash_fwd, _ring_flash_bwd)
 
 
+@jax.named_scope(scopes.ATTN_ULYSSES)
 def ulysses_attention(q, k, v, axis_name: str = "seq", causal: bool = True,
                       scale: Optional[float] = None, segment_ids=None,
                       use_flash: Optional[bool] = None):
@@ -498,6 +504,7 @@ def ulysses_attention(q, k, v, axis_name: str = "seq", causal: bool = True,
     return gather_heads(out)
 
 
+@jax.named_scope(scopes.ATTN_LOCAL)
 def local_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None, segment_ids=None):
     """Plain single-device attention (the no-SP reference path; also the

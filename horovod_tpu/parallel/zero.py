@@ -52,6 +52,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from horovod_tpu import telemetry
 from horovod_tpu.ops import compression as compression_mod
 from horovod_tpu.ops import fusion
+from horovod_tpu.telemetry import scopes
 
 
 @jax.tree_util.register_pytree_node_class
@@ -206,34 +207,40 @@ class ShardedOptimizer:
                 f"re-init (or re-shard the checkpoint) for this mesh")
         self._record(plan)
 
-        if self.cross_axis_name is not None:
-            # Two-level: intra-slice RS (unscaled) -> per-shard DCN psum
-            # (with the cross codec) -> one hoisted 1/(ici*dcn) multiply
-            # on the shard.  The all-gather below stays intra-slice.
-            grad_shards, wire = compression_mod.compressed_reduce_scatter(
-                gleaves, self.axis_name, self.codec, plan=plan,
-                state=state.wire, mean=False)
-            dcn = lax.axis_size(self.cross_axis_name)
-            grad_shards = [
-                compression_mod.cross_level_psum(
-                    s, self.cross_axis_name, self.cross_codec)
-                for s in grad_shards]
-            if self.mean:
+        with jax.named_scope(scopes.GRAD_REDUCE_SCATTER):
+            if self.cross_axis_name is not None:
+                # Two-level: intra-slice RS (unscaled) -> per-shard DCN
+                # psum (with the cross codec) -> one hoisted 1/(ici*dcn)
+                # multiply on the shard.  The all-gather below stays
+                # intra-slice.
+                grad_shards, wire = (
+                    compression_mod.compressed_reduce_scatter(
+                        gleaves, self.axis_name, self.codec, plan=plan,
+                        state=state.wire, mean=False))
+                dcn = lax.axis_size(self.cross_axis_name)
                 grad_shards = [
-                    s * jnp.asarray(1.0 / (plan.axis_size * dcn), s.dtype)
+                    compression_mod.cross_level_psum(
+                        s, self.cross_axis_name, self.cross_codec)
                     for s in grad_shards]
-        else:
-            grad_shards, wire = compression_mod.compressed_reduce_scatter(
-                gleaves, self.axis_name, self.codec, plan=plan,
-                state=state.wire, mean=self.mean)
-        idx = lax.axis_index(self.axis_name)
-        param_shards = [plan.shard_slice(b, flat, idx)
-                        for b, flat in enumerate(
-                            plan.concat(jax.tree_util.tree_leaves(params)))]
-        upd_shards, new_inner = self.inner.update(
-            grad_shards, state.inner, param_shards)
-        upd_leaves, wire = compression_mod.compressed_all_gather(
-            upd_shards, plan, self.axis_name, self.codec, state=wire)
+                if self.mean:
+                    inv = 1.0 / (plan.axis_size * dcn)
+                    grad_shards = [s * jnp.asarray(inv, s.dtype)
+                                   for s in grad_shards]
+            else:
+                grad_shards, wire = (
+                    compression_mod.compressed_reduce_scatter(
+                        gleaves, self.axis_name, self.codec, plan=plan,
+                        state=state.wire, mean=self.mean))
+        with jax.named_scope(scopes.OPTIMIZER):
+            idx = lax.axis_index(self.axis_name)
+            flats = plan.concat(jax.tree_util.tree_leaves(params))
+            param_shards = [plan.shard_slice(b, flat, idx)
+                            for b, flat in enumerate(flats)]
+            upd_shards, new_inner = self.inner.update(
+                grad_shards, state.inner, param_shards)
+        with jax.named_scope(scopes.PARAM_ALL_GATHER):
+            upd_leaves, wire = compression_mod.compressed_all_gather(
+                upd_shards, plan, self.axis_name, self.codec, state=wire)
         updates = jax.tree_util.tree_unflatten(state.treedef, upd_leaves)
         return updates, ZeroShardedState(new_inner, plan, state.treedef,
                                          self.inner, wire=wire,

@@ -92,6 +92,7 @@ from jax import lax
 from horovod_tpu import basics, faults, telemetry
 from horovod_tpu.native.runtime import MembershipChangedError  # noqa: F401
 from horovod_tpu.ops import collective as _c
+from horovod_tpu.telemetry import scopes
 from horovod_tpu.utils.logging import get_logger
 
 log = get_logger(__name__)
@@ -200,7 +201,8 @@ def apply_step_guard(do_update, *, loss, grads, old_state, axes=(),
     axes = tuple(a for a in (axes or ()) if a)
     agree_axes = (axes if agree_axes is None
                   else tuple(a for a in agree_axes if a))
-    mean_loss = lax.pmean(loss, axes) if axes else loss
+    with jax.named_scope(scopes.LOSS_MEAN):
+        mean_loss = lax.pmean(loss, axes) if axes else loss
     policy = guard_policy()
     if policy == "off":
         return do_update(), mean_loss
@@ -209,12 +211,14 @@ def apply_step_guard(do_update, *, loss, grads, old_state, axes=(),
             "hvd_guard_traces_total",
             "training-step traces built with the step guard enabled",
             policy=policy).inc()
-    ok = all_finite(agree_axes, loss, grads)
+    with jax.named_scope(scopes.STEP_GUARD):
+        ok = all_finite(agree_axes, loss, grads)
     new_state = do_update()
-    guarded = jax.tree_util.tree_map(
-        lambda new, old: jnp.where(ok, new, old), new_state, old_state)
-    bad = jnp.asarray(jnp.nan, dtype=jnp.result_type(mean_loss))
-    return guarded, jnp.where(ok, mean_loss, bad)
+    with jax.named_scope(scopes.STEP_GUARD):
+        guarded = jax.tree_util.tree_map(
+            lambda new, old: jnp.where(ok, new, old), new_state, old_state)
+        bad = jnp.asarray(jnp.nan, dtype=jnp.result_type(mean_loss))
+        return guarded, jnp.where(ok, mean_loss, bad)
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,50 @@
+"""The names the compiled SPMD step carries: scopes, kernels, modules.
+
+Every ``jax.named_scope(...)`` of the program takes its name from here,
+so a trace or ``compiled.as_text()`` of any step reads in one vocabulary
+(docs/timeline.md, "The compiled step in XProf/Perfetto").  A scope is
+``op_name`` metadata of the HLO and costs nothing at run time; there is
+no recorder and no switch.  Metadata is not part of JAX's
+compilation-cache key: after renaming a scope, empty the cache or the old
+names come back from it.
+"""
+
+from __future__ import annotations
+
+# Model scopes.  A layer's parts nest under LAYER % i.
+EMBED = "embed"
+LAYER = "layer_%d"
+ATTN_QKV = "attn/qkv"
+ATTN_OUT = "attn/out"
+ATTN_LOCAL = "attn/local_attention"
+ATTN_FLASH = "attn/flash_attention"
+ATTN_RING = "attn/ring_attention"
+ATTN_RING_FLASH = "attn/ring_flash_attention"
+ATTN_ULYSSES = "attn/ulysses_attention"
+MLP = "mlp"
+HEAD = "head"
+LOSS = "loss"
+
+# Step scopes: what the step does with the gradients.
+GRAD_MEAN = "grad_mean"
+OPTIMIZER = "optimizer"
+GRAD_REDUCE_SCATTER = "grad_reduce_scatter"
+PARAM_ALL_GATHER = "param_all_gather"
+LOSS_MEAN = "loss_mean"
+STEP_GUARD = "step_guard"
+
+# The three flash-attention Pallas kernels.
+FLASH_FWD = "flash_fwd"
+FLASH_BWD_DQ = "flash_bwd_dq"
+FLASH_BWD_DKV = "flash_bwd_dkv"
+
+# The functions handed to jax.jit: the XLA module is jit_<name>.
+LM_TRAIN_STEP = "hvd_lm_train_step"
+LM_PIPELINED_TRAIN_STEP = "hvd_lm_pipelined_train_step"
+TRAIN_STEP = "hvd_train_step"
+
+
+def named(fn, name: str):
+    """``fn`` under ``name``, for ``jax.jit`` to name the module by."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn

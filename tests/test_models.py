@@ -264,20 +264,3 @@ def test_s2d_stem_exact_equivalence(hvd):
     y4 = m4.apply(v4, xp, train=False)
     np.testing.assert_allclose(np.asarray(y4), np.asarray(y7),
                                rtol=1e-5, atol=1e-5)
-
-
-def test_profiling_trace_and_cpu_error(hvd):
-    """trace_once produces a trace file; the per-op parser refuses the
-    CPU trace with an actionable message (XLA:CPU has no device track —
-    per-op breakdowns need an accelerator)."""
-    import pytest
-
-    from horovod_tpu.utils import profiling
-
-    def run():
-        jax.block_until_ready(
-            jnp.ones((64, 64)) @ jnp.ones((64, 64)))
-
-    trace = profiling.trace_once(run)
-    with pytest.raises(RuntimeError, match="no device track"):
-        profiling.device_op_durations(trace)

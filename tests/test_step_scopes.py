@@ -1,0 +1,227 @@
+"""The names the compiled SPMD step carries (docs/timeline.md, "The
+compiled step in XProf/Perfetto"): module name, scopes of the one
+vocabulary (``horovod_tpu/telemetry/scopes.py``) under forward, backward
+and recomputation, and no executed instruction without one.  Read from
+``compiled.as_text()`` of tiny steps on 4 of the 8 virtual CPU devices.
+"""
+
+import itertools
+import pathlib
+import re
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+from horovod_tpu.telemetry import scopes
+from perfbench import scope_reduce
+
+OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+# Compiler-made plumbing is not held to a scope; nor is a fusion that
+# holds nothing else.
+PLUMBING = {"parameter", "constant", "broadcast", "copy", "bitcast",
+            "tuple", "get-tuple-element", "iota"}
+HELD = {"dot", "convolution", "fusion", "custom-call"}
+
+
+def _op_names(text):
+    return set(OP_NAME.findall(text))
+
+
+def _under(names, scope, *marks, without=()):
+    """Some op_name holds ``scope`` as a whole component, every mark, and
+    none of ``without``."""
+    part = re.compile(r"(?:^|[/(])" + re.escape(scope) + r"(?:$|[/)])")
+    return any(part.search(n) and all(m in n for m in marks)
+               and not any(w in n for w in without) for n in names)
+
+
+def _unplaced(text):
+    """The executed dots, convolutions, fusions, custom calls and
+    collectives of the step that the benchmark's rules
+    (``perfbench/scope_reduce.py``) cannot give a phase and a scope: the
+    program-side twin of ``unattributed_ms_per_step``."""
+    hlo = scope_reduce.parse_hlo(text)
+    instructions, computations, _ = hlo
+    fused = {c for i in instructions.values() if i.opcode == "fusion"
+             for c in i.calls}
+    out = []
+    for computation, names in computations.items():
+        if computation in fused:
+            continue
+        for name in names:
+            i = instructions[name]
+            if not (i.opcode in HELD
+                    or scope_reduce.trace_reduce.COLLECTIVE.match(i.opcode)):
+                continue
+            inside = {instructions[n].opcode
+                      for c in i.calls for n in computations.get(c, ())}
+            if i.opcode == "fusion" and inside <= PLUMBING:
+                continue
+            phase, scope, _, _ = scope_reduce.classify(name, hlo)
+            if phase == "unattributed" or not scope:
+                out.append((name, i.opcode, i.op_name))
+    return out
+
+
+def _lm_step_text(attention, remat, shard_optimizer, seq_axis=None):
+    from horovod_tpu.models import transformer as tfm
+    from horovod_tpu.topology import build_mesh
+
+    cfg = tfm.TransformerConfig(vocab_size=256, d_model=64, n_heads=2,
+                                n_layers=2, d_ff=128, max_seq=128,
+                                dtype=jnp.bfloat16)
+    axes = ("data", seq_axis) if seq_axis else ("data",)
+    mesh = build_mesh(axes=axes, shape=(2, 2) if seq_axis else None,
+                      devices=jax.devices()[:4])
+    optimizer = optax.sgd(0.01, momentum=0.9)
+    step, _, _ = tfm.make_train_step(
+        cfg, optimizer, mesh, attention=attention, remat=remat,
+        seq_axis=seq_axis, shard_optimizer=shard_optimizer)
+    params = tfm.init_abstract(cfg)
+    opt_state = jax.eval_shape(
+        step.init if shard_optimizer else optimizer.init, params)
+    tokens = jax.ShapeDtypeStruct((8, 128), jnp.int32)
+    return step.lower(params, opt_state, tokens, tokens).compile().as_text()
+
+
+ROUTE = {"local": scopes.ATTN_LOCAL, "flash": scopes.ATTN_FLASH,
+         "ring": scopes.ATTN_RING, "ulysses": scopes.ATTN_ULYSSES}
+
+
+def _check_lm(text, attention, remat, shard_optimizer):
+    assert text.startswith(f"HloModule jit_{scopes.LM_TRAIN_STEP},")
+    names = _op_names(text)
+    assert all(n.startswith(f"jit({scopes.LM_TRAIN_STEP})")
+               for n in names if n.startswith("jit("))
+    model = [scopes.EMBED, scopes.ATTN_QKV, scopes.ATTN_OUT,
+             ROUTE[attention], scopes.MLP, scopes.HEAD, scopes.LOSS]
+    for scope in model:
+        assert _under(names, scope, "jvp(", without=("transpose(",)), scope
+        assert _under(names, scope, "transpose("), scope
+    for i in range(2):
+        assert _under(names, scopes.LAYER % i, "jvp("), i
+    if remat == "full":
+        for scope in (scopes.ATTN_QKV, scopes.ATTN_OUT, ROUTE[attention],
+                      scopes.MLP):
+            assert _under(names, scope, "rematted_computation"), scope
+    else:
+        assert not any("rematted_computation" in n for n in names)
+    step = ([scopes.GRAD_REDUCE_SCATTER, scopes.OPTIMIZER,
+             scopes.PARAM_ALL_GATHER] if shard_optimizer
+            else [scopes.GRAD_MEAN, scopes.OPTIMIZER])
+    for scope in step + [scopes.LOSS_MEAN]:
+        assert _under(names, scope), scope
+    assert not _under(names, scopes.STEP_GUARD)
+    if attention == "flash":
+        for kernel in (scopes.FLASH_FWD, scopes.FLASH_BWD_DQ,
+                       scopes.FLASH_BWD_DKV):
+            assert _under(names, kernel, scopes.ATTN_FLASH), kernel
+    assert _unplaced(text) == []
+
+
+@pytest.mark.parametrize(
+    "attention,remat,shard_optimizer",
+    list(itertools.product(("local", "flash"), ("none", "full"),
+                           (False, True))))
+def test_lm_step_carries_the_vocabulary(hvd, attention, remat,
+                                        shard_optimizer):
+    _check_lm(_lm_step_text(attention, remat, shard_optimizer), attention,
+              remat, shard_optimizer)
+
+
+@pytest.mark.parametrize("attention", ("ring", "ulysses"))
+def test_sequence_routes_open_their_own_scope(hvd, attention):
+    text = _lm_step_text(attention, "none", False, seq_axis="seq")
+    _check_lm(text, attention, "none", False)
+
+
+def test_step_guard_scope_only_with_the_guard_on(hvd, monkeypatch):
+    monkeypatch.setenv("HOROVOD_STEP_GUARD", "skip")
+    names = _op_names(_lm_step_text("local", "none", False))
+    assert _under(names, scopes.STEP_GUARD)
+    assert _under(names, scopes.LOSS_MEAN)
+
+
+def test_resnet_step_carries_flax_names_and_the_step_scopes(hvd):
+    from horovod_tpu.benchmark import make_train_step
+    from horovod_tpu.models import ResNet18
+    from horovod_tpu.topology import build_mesh
+
+    mesh = build_mesh(axes=("data",), devices=jax.devices()[:4])
+    model = ResNet18(num_classes=4)
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3)),
+                           train=False))
+    params, batch_stats = variables["params"], variables["batch_stats"]
+    optimizer = optax.sgd(0.05, momentum=0.9)
+    step = make_train_step(model, optimizer, mesh)
+    text = step.lower(
+        params, batch_stats, jax.eval_shape(optimizer.init, params),
+        jax.ShapeDtypeStruct((8, 32, 32, 3), jnp.float32),
+        jax.ShapeDtypeStruct((8,), jnp.int32)).compile().as_text()
+    assert text.startswith(f"HloModule jit_{scopes.TRAIN_STEP},")
+    names = _op_names(text)
+    for scope in (scopes.LOSS, scopes.GRAD_MEAN, scopes.OPTIMIZER,
+                  scopes.LOSS_MEAN):
+        assert _under(names, scope), scope
+    assert any("jvp(ResNet)/" in n for n in names)
+    assert any("transpose(jvp(ResNet))/" in n for n in names)
+    assert _unplaced(text) == []
+
+
+def test_pipelined_step_has_its_module_name(hvd):
+    from horovod_tpu.models import transformer as tfm
+    from horovod_tpu.topology import build_mesh
+
+    cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
+                                n_layers=2, d_ff=64, max_seq=16,
+                                dtype=jnp.float32)
+    mesh = build_mesh(axes=("data", "pipe"), shape=(2, 2),
+                      devices=jax.devices()[:4])
+    optimizer = optax.sgd(0.01)
+    step, _ = tfm.make_train_step_pipelined(cfg, optimizer, mesh,
+                                            n_microbatches=2)
+    params = jax.eval_shape(
+        lambda: tfm.split_pipeline_params(
+            tfm.init_params(jax.random.PRNGKey(0), cfg), 2))
+    tokens = jax.ShapeDtypeStruct((4, 16), jnp.int32)
+    lowered = step.lower(params, jax.eval_shape(optimizer.init, params),
+                         tokens, tokens)
+    text = lowered.as_text()
+    assert f"jit_{scopes.LM_PIPELINED_TRAIN_STEP}" in text
+    names = set(re.findall(r'loc\("([^"]*)"', lowered.as_text(
+        debug_info=True)))
+    for scope in (scopes.EMBED, scopes.ATTN_QKV, scopes.MLP, scopes.HEAD,
+                  scopes.LOSS, scopes.OPTIMIZER):
+        assert _under(names, scope), scope
+
+
+def test_every_named_scope_takes_its_name_from_the_vocabulary():
+    root = pathlib.Path(scopes.__file__).resolve().parents[1]
+    calls = [(path, line)
+             for path in root.rglob("*.py") if path.name != "scopes.py"
+             for line in path.read_text().splitlines()
+             if "named_scope(" in line and not line.lstrip().startswith("#")]
+    assert calls
+    stray = [(str(p), l.strip()) for p, l in calls
+             if not re.search(r"named_scope\(scopes\.[A-Z_]+[ )%]", l)]
+    assert stray == []
+
+
+def test_the_benchmark_reads_the_same_vocabulary():
+    """``perfbench/scope_reduce.py`` keeps its own copy of the names (it
+    also runs over programs from before them): hold the two together."""
+    program = {v for k, v in vars(scopes).items()
+               if k.isupper() and isinstance(v, str)}
+    kernels = {scopes.FLASH_FWD, scopes.FLASH_BWD_DQ, scopes.FLASH_BWD_DKV}
+    modules = {scopes.LM_TRAIN_STEP, scopes.LM_PIPELINED_TRAIN_STEP,
+               scopes.TRAIN_STEP}
+    assert set(scope_reduce.KERNEL_NAMES) == kernels
+    assert (set(scope_reduce.MODEL_SCOPES + scope_reduce.GRAD_MEAN_SCOPES
+                + scope_reduce.OPTIMIZER_SCOPES)
+            == program - kernels - modules - {scopes.LAYER})
+    assert scope_reduce.scope_of(
+        f"jit(x)/jvp({scopes.LAYER % 3})/{scopes.MLP}/dot_general"
+    ) == scopes.MLP
