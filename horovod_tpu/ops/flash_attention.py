@@ -16,11 +16,15 @@ Layout: ``[B, T, H, D]`` (the repo convention) is folded to
 the innermost grid dimension streams one K/V tile at a time through
 VMEM (Mosaic double-buffers the fetches), while fp32 accumulators and
 the online-softmax m/l state persist across the inner dimension in VMEM
-scratch.  Causal masking skips the compute of key blocks strictly above
-the diagonal (``pl.when``).  The backward pass is the standard flash
-recomputation: a per key-block kernel for dK/dV streaming query tiles,
-and a per query-block kernel for dQ streaming key tiles, using the saved
-row max/denominator.
+scratch.  Under a causal mask each kernel specialises a block by where
+it lies relative to the diagonal, which the grid indices say: blocks
+above it are neither computed nor fetched, blocks wholly under it run
+without any mask arithmetic, and only the blocks the diagonal crosses
+are masked — as 2x2 sub-tiles without the upper-right one where the
+blocks are square and at least 256 (``_by_class``).  The backward pass is
+the standard flash recomputation: a per key-block kernel for dK/dV
+streaming query tiles, and a per query-block kernel for dQ streaming key
+tiles, using the saved row max/denominator.
 
 ``interpret=True`` (or ``HOROVOD_FLASH_INTERPRET=1``) runs the kernels
 in the Pallas interpreter — exact same code path, CPU-executable — which
@@ -41,6 +45,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu import telemetry
 from horovod_tpu.telemetry import scopes
 
 NEG_INF = float("-inf")
@@ -80,25 +85,154 @@ def _out_vma(*arrays):
     return frozenset(out)
 
 
-def _mask_scores(s, qi, kj, block_q, block_k, causal, qseg_ref,
-                 kseg_ref=None):
-    """Apply causal and/or segment (sequence-packing) masks to a score
-    block.  Segment ids ride a [B, 1, T] layout like the m/l rows; tokens
-    attend only within their own segment.  ``kseg_ref`` defaults to the
-    q-side ref (self-attention); ring attention passes the ROTATED
-    K-side ids separately."""
-    if causal:
-        qpos = qi * block_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        kpos = kj * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(qpos >= kpos, s, NEG_INF)
+# ---------------------------------------------------------------------------
+# Blocks by where they lie relative to the causal diagonal
+#
+# The grid indices say which of three classes a (query block, key block)
+# pair belongs to, and each class pays only for what its position needs:
+#   above     every key after every query: not computed, not fetched (the
+#             index maps clamp to the last live block, so Mosaic elides
+#             the DMA);
+#   interior  every key at or before every query: no causal iota, compare
+#             or select;
+#   diagonal  the diagonal crosses the block: masked.  Square blocks of at
+#             least 256 are worked as 2x2 sub-tiles and the upper-right
+#             one, wholly above the diagonal, is not computed.
+# Without ``causal`` every block is interior.  Segment ids add their
+# compare to every class: a document boundary can fall anywhere.
+# ---------------------------------------------------------------------------
+
+def _splits_diagonal(block_q: int, block_k: int) -> bool:
+    return block_q == block_k and block_q >= 256
+
+
+def _diagonal_tiles(block_q: int, block_k: int):
+    """``(row0, rows, col0, cols, masked)`` rectangles a diagonal block
+    computes, in the order the online softmax takes them."""
+    if not _splits_diagonal(block_q, block_k):
+        return ((0, block_q, 0, block_k, True),)
+    # Upper rows against the left keys, lower rows against all of them:
+    # each row is taken once, so the per-row work (running max and sum,
+    # the accumulator's rescaling) is that of a whole block.
+    h = block_q // 2
+    return ((0, h, 0, h, True), (h, h, 0, block_k, True))
+
+
+def _block_class(qi, kj, block_q, block_k):
+    """``(interior, diagonal)`` of block (qi, kj) under a causal mask, for
+    grid indices in the kernels and plain ints in ``block_classes``:
+    interior where every key is at or before every query, diagonal where
+    only some are; neither above the diagonal."""
+    interior = (kj + 1) * block_k <= qi * block_q + 1
+    live = kj * block_k < (qi + 1) * block_q
+    return interior, live ^ interior          # interior implies live
+
+
+def _by_class(causal, qi, kj, block_q, block_k, tile_body):
+    """Run ``tile_body(row0, rows, col0, cols, masked)`` over what block
+    (qi, kj) needs: nothing above the diagonal, the whole block unmasked
+    under it, the masked tiles on it."""
+    if not causal:
+        tile_body(0, block_q, 0, block_k, False)
+        return
+    interior, diagonal = _block_class(qi, kj, block_q, block_k)
+
+    @pl.when(interior)
+    def _interior():
+        tile_body(0, block_q, 0, block_k, False)
+
+    @pl.when(diagonal)
+    def _diagonal():
+        for tile in _diagonal_tiles(block_q, block_k):
+            tile_body(*tile)
+
+
+def block_classes(t: int, block_q: int, block_k: int, causal: bool) -> dict:
+    """Grid steps of one head by class, and the score elements they
+    compute against the elements attention needs — what
+    ``hvd_flash_blocks_total`` and ``hvd_flash_computed_over_needed``
+    report (shapes are static, so this is counted when a call is traced)."""
+    num_q, num_k = t // block_q, t // block_k
+    if not causal:
+        return {"skipped": 0, "interior": num_q * num_k, "diagonal": 0,
+                "computed": t * t, "needed": t * t}
+    classes = [_block_class(qi, kj, block_q, block_k)
+               for qi in range(num_q) for kj in range(num_k)]
+    interior = sum(c[0] for c in classes)
+    diagonal = sum(c[1] for c in classes)
+    per_diagonal = sum(rows * cols for _, rows, _, cols, _
+                       in _diagonal_tiles(block_q, block_k))
+    return {"skipped": num_q * num_k - interior - diagonal,
+            "interior": interior, "diagonal": diagonal,
+            "computed": interior * block_q * block_k
+            + diagonal * per_diagonal,
+            "needed": t * (t + 1) // 2}
+
+
+def _record_blocks(kernel: str, bh: int, t: int, block_q: int,
+                   block_k: int, causal: bool) -> None:
+    """Trace-time counters of one ``pallas_call`` (like ``hvd_fusion_*``:
+    they count what was compiled into the step, not per-step traffic)."""
+    if not telemetry.enabled():
+        return
+    classes = block_classes(t, block_q, block_k, causal)
+    for name in ("skipped", "interior", "diagonal"):
+        telemetry.counter(
+            "hvd_flash_blocks_total",
+            "Grid steps of the traced flash kernels by where the block "
+            "lies relative to the causal diagonal",
+            kernel=kernel, **{"class": name}).inc(bh * classes[name])
+    telemetry.gauge(
+        "hvd_flash_computed_over_needed",
+        "Score elements the most recently traced flash kernel computes "
+        "over the elements attention needs (1.0 = no masked work)",
+        kernel=kernel).set(classes["computed"] / classes["needed"])
+
+
+def _dot(a, b, contract):
+    """MXU matmul with float32 accumulation.  Operands go in as they are
+    stored (bf16 tiles are not upcast: a bf16 x bf16 product is exact in
+    float32); a float32 ``p`` or ``ds`` takes the other operand's dtype,
+    which is the rounding the MXU applies to float32 operands at default
+    precision anyway."""
+    return jax.lax.dot_general(a.astype(b.dtype), b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))      # a @ b.T
+_NN = ((1,), (0,))      # a @ b
+
+
+def _scores(q, k, scale, qi, kj, tile, block_q, block_k, qseg_ref,
+            kseg_ref, keys_by_rows: bool = False):
+    """Scores of one tile, masked as its class needs: ``[rows, cols]``
+    (queries by keys), or its transpose ``[cols, rows]`` for the dK/dV
+    kernel, whose matmuls then contract without transposing a score-sized
+    operand.  Segment ids ride a [B, 1, T] layout like the m/l rows;
+    tokens attend only within their own segment.  ``kseg_ref`` is the
+    q-side ref for self-attention; ring attention passes the ROTATED
+    K-side ids."""
+    r0, nr, c0, nc, masked = tile
+    s = (_dot(k, q, _NT) if keys_by_rows else _dot(q, k, _NT)) * scale
+    q_axis, k_axis = (1, 0) if keys_by_rows else (0, 1)
+    if masked:
+        # Visible where query position >= key position.  Query index minus
+        # key index is a constant of the tile shape; only the offset
+        # between the tile's first key and first query depends on the grid
+        # step, and not even that where a split block's tiles sit on the
+        # diagonal block qi == kj (a constant mask there is worth 0.7% of
+        # the kernels' time at T=8192: measured, PERF.md PR 25).
+        off = (c0 - r0 if _splits_diagonal(block_q, block_k)
+               else (kj * block_k + c0) - (qi * block_q + r0))
+        ahead = (lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+                 - lax.broadcasted_iota(jnp.int32, s.shape, k_axis))
+        s = jnp.where(ahead >= off, s, NEG_INF)
     if qseg_ref is not None:
-        if kseg_ref is None:
-            kseg_ref = qseg_ref
-        qseg = qseg_ref[0, 0, pl.dslice(qi * block_q, block_q)]
-        kseg = kseg_ref[0, 0, pl.dslice(kj * block_k, block_k)]
-        s = jnp.where(qseg[:, None] == kseg[None, :], s, NEG_INF)
+        qseg = qseg_ref[0, 0, pl.dslice(qi * block_q + r0, nr)]
+        kseg = kseg_ref[0, 0, pl.dslice(kj * block_k + c0, nc)]
+        same = (kseg[:, None] == qseg[None, :] if keys_by_rows
+                else qseg[:, None] == kseg[None, :])
+        s = jnp.where(same, s, NEG_INF)
     return s
 
 
@@ -106,75 +240,94 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
                 block_q: int, block_k: int, num_k: int, causal: bool,
                 scale: float, segments: bool):
     if segments:
-        qseg_ref, kseg_ref, o_ref, m_ref, l_ref, acc_ref = rest
+        (qseg_ref, kseg_ref, o_ref, m_ref, l_ref, acc_ref, m_scr,
+         l_scr) = rest
     else:
-        o_ref, m_ref, l_ref, acc_ref = rest
+        o_ref, m_ref, l_ref, acc_ref, m_scr, l_scr = rest
         qseg_ref = kseg_ref = None
     qi = pl.program_id(1)
     kj = pl.program_id(2)
-    rows = pl.dslice(qi * block_q, block_q)
 
+    # The running max and sum live as [block_q, 1] columns, the layout a
+    # row reduction produces and a broadcast over scores consumes; the
+    # lane-major m/l rows are written once per query tile, at the end.
     @pl.when(kj == 0)
     def _init():
-        m_ref[0, 0, rows] = jnp.full((block_q,), NEG_INF, jnp.float32)
-        l_ref[0, 0, rows] = jnp.zeros((block_q,), jnp.float32)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def compute():
-        q = q_ref[0].astype(jnp.float32)                 # [bq, D]
-        k_blk = k_ref[0].astype(jnp.float32)             # [bk, D]
-        v_blk = v_ref[0].astype(jnp.float32)
-        m = m_ref[0, 0, rows]
-        l = l_ref[0, 0, rows]
-        acc = acc_ref[...]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        s = _mask_scores(s, qi, kj, block_q, block_k, causal, qseg_ref,
-                         kseg_ref)
-        m_blk = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m, m_blk)
-        safe_m = jnp.where(m_new == NEG_INF, 0.0, m_new)
-        p = jnp.exp(s - safe_m[:, None])
-        p = jnp.where(s == NEG_INF, 0.0, p)
-        corr = jnp.where(m == NEG_INF, 0.0, jnp.exp(m - safe_m))
-        m_ref[0, 0, rows] = m_new
-        l_ref[0, 0, rows] = l * corr + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc * corr[:, None] + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def tile_body(*tile):
+        r0, nr, c0, nc, _ = tile
+        q = q_ref[0, r0:r0 + nr, :]                      # [nr, D]
+        k = k_ref[0, c0:c0 + nc, :]                      # [nc, D]
+        v = v_ref[0, c0:c0 + nc, :]
+        m = m_scr[r0:r0 + nr, :]                         # [nr, 1]
+        s = _scores(q, k, scale, qi, kj, tile, block_q, block_k, qseg_ref,
+                    kseg_ref)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        if segments:
+            # A row may have met no key of its segment yet (m_new = -inf).
+            safe_m = jnp.where(m_new == NEG_INF, 0.0, m_new)
+            p = jnp.where(s == NEG_INF, 0.0, jnp.exp(s - safe_m))
+            corr = jnp.where(m == NEG_INF, 0.0, jnp.exp(m - safe_m))
+        else:
+            # Every row has seen key 0 after its first block, so m_new is
+            # finite and exp(-inf - m_new) is the zero a select would give
+            # (also for m = -inf on the first block).
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m - m_new)
+        m_scr[r0:r0 + nr, :] = m_new
+        l_scr[r0:r0 + nr, :] = (l_scr[r0:r0 + nr, :] * corr
+                                + jnp.sum(p, axis=-1, keepdims=True))
+        acc_ref[r0:r0 + nr, :] = (acc_ref[r0:r0 + nr, :] * corr
+                                  + _dot(p, v, _NN))
 
-    if causal:
-        # Key blocks strictly above the diagonal contribute nothing.
-        pl.when(kj * block_k < (qi + 1) * block_q)(compute)
-    else:
-        compute()
+    _by_class(causal, qi, kj, block_q, block_k, tile_body)
 
     @pl.when(kj == num_k - 1)
     def _finalize():
-        l = l_ref[0, 0, rows]
-        denom = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+        rows = pl.dslice(qi * block_q, block_q)
+        l = l_scr[...]
+        o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+                    ).astype(o_ref.dtype)
+        m_ref[0, 0, rows] = m_scr[...][:, 0]
+        l_ref[0, 0, rows] = l[:, 0]
 
 
 # ---------------------------------------------------------------------------
 # Backward — standard flash recomputation
 #   D_i  = rowsum(dO ⊙ O)
-#   P    = exp(QKᵀ·scale − m) / l          (recomputed per block)
+#   P    = exp(QKᵀ·scale − lse),  lse = m + log l   (recomputed per tile)
 #   dV  += Pᵀ dO
 #   dP   = dO Vᵀ
 #   dS   = P ⊙ (dP − D_i)
-#   dQ  += dS K · scale ;  dK += dSᵀ Q · scale
+#   dQ  += dS K · scale ;  dK += dSᵀ Q · scale   (scale once, at the end)
 # ---------------------------------------------------------------------------
+
+def _row_lse(m, l, segments: bool):
+    """log of the softmax denominator per row, from the saved max and
+    sum: one subtract per score in place of a subtract and a divide."""
+    if segments:
+        # A fully masked row saved m = -inf, l = 0; its p is zeroed below.
+        m = jnp.where(m == NEG_INF, 0.0, m)
+        l = jnp.where(l == 0.0, 1.0, l)
+    return m + jnp.log(l)
+
+
+def _probs(s, lse, segments: bool):
+    p = jnp.exp(s - lse)
+    return jnp.where(s == NEG_INF, 0.0, p) if segments else p
+
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, m_ref, l_ref,
                    *rest, block_q: int, block_k: int,
                    num_k: int, causal: bool, scale: float,
                    segments: bool):
     if segments:
-        qseg_ref, kseg_ref, dq_ref, acc_ref = rest
+        qseg_ref, kseg_ref, dq_ref, acc_ref, lse_ref, di_ref = rest
     else:
-        dq_ref, acc_ref = rest
+        dq_ref, acc_ref, lse_ref, di_ref = rest
         qseg_ref = kseg_ref = None
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -182,42 +335,32 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, m_ref, l_ref,
 
     @pl.when(kj == 0)
     def _init():
+        # Once per query tile, not per key block: the row statistics.
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        lse_ref[...] = _row_lse(m_ref[0, 0, rows], l_ref[0, 0, rows],
+                                segments)[:, None]
+        di_ref[...] = jnp.sum(
+            do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32),
+            axis=-1, keepdims=True)                      # [bq, 1]
 
-    def compute():
-        q = q_ref[0].astype(jnp.float32)
-        o = o_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        m = m_ref[0, 0, rows]
-        l = l_ref[0, 0, rows]
-        safe_m = jnp.where(m == NEG_INF, 0.0, m)
-        denom = jnp.where(l == 0.0, 1.0, l)
-        di = jnp.sum(do * o, axis=-1)                    # [bq]
-        k_blk = k_ref[0].astype(jnp.float32)             # [bk, D]
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        s = _mask_scores(s, qi, kj, block_q, block_k, causal, qseg_ref,
-                         kseg_ref)
-        p = jnp.where(s == NEG_INF, 0.0,
-                      jnp.exp(s - safe_m[:, None])) / denom[:, None]
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bq, bk]
-        ds = p * (dp - di[:, None])
-        acc_ref[...] += jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+    def tile_body(*tile):
+        r0, nr, c0, nc, _ = tile
+        q = q_ref[0, r0:r0 + nr, :]
+        do = do_ref[0, r0:r0 + nr, :]
+        k = k_ref[0, c0:c0 + nc, :]                      # [nc, D]
+        v = v_ref[0, c0:c0 + nc, :]
+        s = _scores(q, k, scale, qi, kj, tile, block_q, block_k, qseg_ref,
+                    kseg_ref)
+        p = _probs(s, lse_ref[r0:r0 + nr, :], segments)
+        dp = _dot(do, v, _NT)                            # [nr, nc]
+        ds = p * (dp - di_ref[r0:r0 + nr, :])
+        acc_ref[r0:r0 + nr, :] += _dot(ds, k, _NN)
 
-    if causal:
-        pl.when(kj * block_k < (qi + 1) * block_q)(compute)
-    else:
-        compute()
+    _by_class(causal, qi, kj, block_q, block_k, tile_body)
 
     @pl.when(kj == num_k - 1)
     def _finalize():
-        dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
+        dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, m_ref, l_ref,
@@ -231,51 +374,40 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, m_ref, l_ref,
         qseg_ref = kseg_ref = None
     ki = pl.program_id(1)
     qi = pl.program_id(2)
-    rows = pl.dslice(qi * block_q, block_q)
 
     @pl.when(qi == 0)
     def _init():
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
-    def compute():
-        k = k_ref[0].astype(jnp.float32)                 # [bk, D]
-        v = v_ref[0].astype(jnp.float32)
-        q_blk = q_ref[0].astype(jnp.float32)             # [bq, D]
-        o_blk = o_ref[0].astype(jnp.float32)
-        do_blk = do_ref[0].astype(jnp.float32)
-        m_blk = m_ref[0, 0, rows]
-        l_blk = l_ref[0, 0, rows]
-        safe_m = jnp.where(m_blk == NEG_INF, 0.0, m_blk)
-        denom = jnp.where(l_blk == 0.0, 1.0, l_blk)
-        di = jnp.sum(do_blk * o_blk, axis=-1)
-        s = jax.lax.dot_general(
-            q_blk, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        s = _mask_scores(s, qi, ki, block_q, block_k, causal, qseg_ref,
-                         kseg_ref)
-        p = jnp.where(s == NEG_INF, 0.0,
-                      jnp.exp(s - safe_m[:, None])) / denom[:, None]
-        dv_acc_ref[...] += jax.lax.dot_general(
-            p, do_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bk, D]
-        dp = jax.lax.dot_general(
-            do_blk, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - di[:, None])
-        dk_acc_ref[...] += jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+    def tile_body(*tile):
+        # Keys by rows: p and ds come out as [nc, nr], the shape dV and dK
+        # contract over without a transpose, and the row statistics are
+        # used lane-major, as the m/l rows store them.
+        r0, nr, c0, nc, _ = tile
+        trows = pl.dslice(qi * block_q + r0, nr)
+        k = k_ref[0, c0:c0 + nc, :]                      # [nc, D]
+        v = v_ref[0, c0:c0 + nc, :]
+        q = q_ref[0, r0:r0 + nr, :]                      # [nr, D]
+        do = do_ref[0, r0:r0 + nr, :]
+        lse = _row_lse(m_ref[0, 0, trows], l_ref[0, 0, trows], segments)
+        di = jnp.sum(do.astype(jnp.float32)
+                     * o_ref[0, r0:r0 + nr, :].astype(jnp.float32),
+                     axis=-1)                            # [nr]
+        s = _scores(q, k, scale, qi, ki, tile, block_q, block_k, qseg_ref,
+                    kseg_ref, keys_by_rows=True)
+        p = _probs(s, lse[None, :], segments)            # [nc, nr]
+        dv_acc_ref[c0:c0 + nc, :] += _dot(p, do, _NN)    # [nc, D]
+        dp = _dot(v, do, _NT)                            # [nc, nr]
+        ds = p * (dp - di[None, :])
+        dk_acc_ref[c0:c0 + nc, :] += _dot(ds, q, _NN)
 
-    if causal:
-        # Query blocks strictly left of this key block see none of it.
-        pl.when((qi + 1) * block_q > ki * block_k)(compute)
-    else:
-        compute()
+    # Query blocks strictly left of this key block see none of it.
+    _by_class(causal, qi, ki, block_q, block_k, tile_body)
 
     @pl.when(qi == num_q - 1)
     def _finalize():
-        dk_ref[0] = dk_acc_ref[...].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc_ref[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
 
 
@@ -324,18 +456,18 @@ def _seg_spec(t, h):
     return pl.BlockSpec((1, 1, t), lambda bh_, i, j: (bh_ // h, 0, 0))
 
 
-def _fwd_parts(qf, kf, vf, qsegf, ksegf, h, causal, scale, block_q,
-               block_k, interpret):
-    """Folded-layout forward: (of, m, l) with m/l the [bh, 1, T] online
-    softmax state — the raw pieces ring attention merges across steps.
-    ``qsegf``/``ksegf`` are [B, 1, T] (pass the same array for
-    self-attention)."""
-    bh, t, d = qf.shape
+# One ``pallas_call`` object per static configuration: every layer of a model
+# then calls the same jitted object and the kernel is traced once per step
+# program, not once per layer (the kernels are most of what lowering a
+# step costs).
+
+@functools.cache
+def _fwd_call(bh, t, d, dtype, h, causal, scale, block_q, block_k,
+              interpret, segments, vma):
     num_k = t // block_k
-    grid = (bh, t // block_q, num_k)
     kernel = functools.partial(_fwd_kernel, block_q=block_q,
                                block_k=block_k, num_k=num_k, causal=causal,
-                               scale=scale, segments=qsegf is not None)
+                               scale=scale, segments=segments)
     # Causal: masked steps (above the diagonal) clamp the K/V block index
     # to the last live block — same index as the preceding step, so Mosaic
     # elides the DMA instead of fetching a tile whose work pl.when skips.
@@ -346,35 +478,50 @@ def _fwd_parts(qf, kf, vf, qsegf, ksegf, h, causal, scale, block_q,
         pl.BlockSpec((1, block_k, d), kv_map),
         pl.BlockSpec((1, block_k, d), kv_map),
     ]
-    operands = [qf, kf, vf]
-    if qsegf is not None:
+    if segments:
         in_specs += [_seg_spec(t, h), _seg_spec(t, h)]
-        operands += [qsegf, ksegf]
-    vma = _out_vma(*operands)
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bh, t // block_q, num_k),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh_, i, j: (bh_, i, 0)),
             # TPU tiling: the last two block dims must be (8k, 128k) or
             # equal the array dims — a [bh, 1, T] layout with full
-            # (1, 1, T) blocks satisfies that for any block_q.  The m/l
-            # rows double as the online-softmax running state across the
-            # key-block grid dimension (the block is revisited, so it
-            # stays resident in VMEM).
+            # (1, 1, T) blocks satisfies that for any block_q.  The block
+            # is revisited by every query tile of a head, so it stays
+            # resident in VMEM until the head is done.
             pl.BlockSpec((1, 1, t), lambda bh_, i, j: (bh_, 0, 0)),
             pl.BlockSpec((1, 1, t), lambda bh_, i, j: (bh_, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), qf.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, t, d), dtype, vma=vma),
             jax.ShapeDtypeStruct((bh, 1, t), jnp.float32, vma=vma),
             jax.ShapeDtypeStruct((bh, 1, t), jnp.float32, vma=vma),
         ],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        # Output accumulator, and the running max and sum as columns.
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32)],
         interpret=interpret,
         name=scopes.FLASH_FWD,
-    )(*operands)
+    )
+
+
+def _fwd_parts(qf, kf, vf, qsegf, ksegf, h, causal, scale, block_q,
+               block_k, interpret):
+    """Folded-layout forward: (of, m, l) with m/l the [bh, 1, T] online
+    softmax state — the raw pieces ring attention merges across steps.
+    ``qsegf``/``ksegf`` are [B, 1, T] (pass the same array for
+    self-attention)."""
+    bh, t, d = qf.shape
+    operands = [qf, kf, vf]
+    if qsegf is not None:
+        operands += [qsegf, ksegf]
+    _record_blocks(scopes.FLASH_FWD, bh, t, block_q, block_k, causal)
+    return _fwd_call(bh, t, d, qf.dtype, h, causal, scale, block_q, block_k,
+                     interpret, qsegf is not None,
+                     _out_vma(*operands))(*operands)
 
 
 def _fwd(q, k, v, seg, causal, scale, block_q, block_k, interpret):
@@ -394,6 +541,81 @@ def _fwd(q, k, v, seg, causal, scale, block_q, block_k, interpret):
     return _unfold(o, b, h), (qf, kf, vf, o, m, l, seg, b, h)
 
 
+@functools.cache
+def _bwd_dq_call(bh, t, d, dtype, h, causal, scale, block_q, block_k,
+                 interpret, segments, vma):
+    num_k = t // block_k
+    kernel = functools.partial(_bwd_dq_kernel, block_q=block_q,
+                               block_k=block_k, num_k=num_k, causal=causal,
+                               scale=scale, segments=segments)
+    kv_map = (_causal_kv_map(block_q, block_k) if causal
+              else (lambda bh_, i, j: (bh_, j, 0)))
+    in_specs = [
+        pl.BlockSpec((1, block_q, d), lambda bh_, i, j: (bh_, i, 0)),
+        pl.BlockSpec((1, block_k, d), kv_map),
+        pl.BlockSpec((1, block_k, d), kv_map),
+        pl.BlockSpec((1, block_q, d), lambda bh_, i, j: (bh_, i, 0)),
+        pl.BlockSpec((1, block_q, d), lambda bh_, i, j: (bh_, i, 0)),
+        pl.BlockSpec((1, 1, t), lambda bh_, i, j: (bh_, 0, 0)),
+        pl.BlockSpec((1, 1, t), lambda bh_, i, j: (bh_, 0, 0)),
+    ]
+    if segments:
+        in_specs += [_seg_spec(t, h), _seg_spec(t, h)]
+    return pl.pallas_call(
+        kernel,
+        grid=(bh, t // block_q, num_k),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, block_q, d),
+                               lambda bh_, i, j: (bh_, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((bh, t, d), dtype, vma=vma),
+        # dQ accumulator, and the query tile's lse and D_i as columns.
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32)],
+        interpret=interpret,
+        name=scopes.FLASH_BWD_DQ,
+    )
+
+
+@functools.cache
+def _bwd_dkv_call(bh, t, d, dtype, h, causal, scale, block_q, block_k,
+                  interpret, segments, vma):
+    num_q = t // block_q
+    kernel = functools.partial(_bwd_dkv_kernel, block_q=block_q,
+                               block_k=block_k, num_q=num_q, causal=causal,
+                               scale=scale, segments=segments)
+    q_map = (_causal_q_map(block_q, block_k) if causal
+             else (lambda bh_, j, i: (bh_, i, 0)))
+    in_specs = [
+        pl.BlockSpec((1, block_q, d), q_map),
+        pl.BlockSpec((1, block_k, d), lambda bh_, j, i: (bh_, j, 0)),
+        pl.BlockSpec((1, block_k, d), lambda bh_, j, i: (bh_, j, 0)),
+        pl.BlockSpec((1, block_q, d), q_map),
+        pl.BlockSpec((1, block_q, d), q_map),
+        pl.BlockSpec((1, 1, t), lambda bh_, j, i: (bh_, 0, 0)),
+        pl.BlockSpec((1, 1, t), lambda bh_, j, i: (bh_, 0, 0)),
+    ]
+    if segments:
+        in_specs += [_seg_spec(t, h), _seg_spec(t, h)]
+    return pl.pallas_call(
+        kernel,
+        grid=(bh, t // block_k, num_q),
+        in_specs=in_specs,
+        out_specs=[
+            pl.BlockSpec((1, block_k, d), lambda bh_, j, i: (bh_, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda bh_, j, i: (bh_, j, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, t, d), dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, t, d), dtype, vma=vma),
+        ],
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
+        interpret=interpret,
+        name=scopes.FLASH_BWD_DKV,
+    )
+
+
 def _bwd_parts(qf, kf, vf, of, dof, m, l, qsegf, ksegf, h, causal, scale,
                block_q, block_k, interpret):
     """Folded-layout backward: (dqf, dkf, dvf) from the GLOBAL (m, l)
@@ -401,78 +623,15 @@ def _bwd_parts(qf, kf, vf, of, dof, m, l, qsegf, ksegf, h, causal, scale,
     accumulated m/l — the per-block contributions are then the exact
     global-softmax gradients (p recomputed as exp(s − m)/l)."""
     bh, t, d = qf.shape
-    num_k = t // block_k
-    num_q = t // block_q
-    segments = qsegf is not None
-    kernel_dq = functools.partial(_bwd_dq_kernel, block_q=block_q,
-                                  block_k=block_k, num_k=num_k,
-                                  causal=causal, scale=scale,
-                                  segments=segments)
-    kv_map = (_causal_kv_map(block_q, block_k) if causal
-              else (lambda bh_, i, j: (bh_, j, 0)))
-    dq_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh_, i, j: (bh_, i, 0)),
-        pl.BlockSpec((1, block_k, d), kv_map),
-        pl.BlockSpec((1, block_k, d), kv_map),
-        pl.BlockSpec((1, block_q, d), lambda bh_, i, j: (bh_, i, 0)),
-        pl.BlockSpec((1, block_q, d), lambda bh_, i, j: (bh_, i, 0)),
-        pl.BlockSpec((1, 1, t), lambda bh_, i, j: (bh_, 0, 0)),
-        pl.BlockSpec((1, 1, t), lambda bh_, i, j: (bh_, 0, 0)),
-    ]
-    dq_operands = [qf, kf, vf, of, dof, m, l]
-    if segments:
-        dq_specs += [_seg_spec(t, h), _seg_spec(t, h)]
-        dq_operands += [qsegf, ksegf]
-    vma = _out_vma(*dq_operands)
-    dq = pl.pallas_call(
-        kernel_dq,
-        grid=(bh, num_q, num_k),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, block_q, d),
-                               lambda bh_, i, j: (bh_, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), qf.dtype, vma=vma),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-        name=scopes.FLASH_BWD_DQ,
-    )(*dq_operands)
-
-    kernel_dkv = functools.partial(_bwd_dkv_kernel, block_q=block_q,
-                                   block_k=block_k, num_q=num_q,
-                                   causal=causal, scale=scale,
-                                   segments=segments)
-    q_map = (_causal_q_map(block_q, block_k) if causal
-             else (lambda bh_, j, i: (bh_, i, 0)))
-    dkv_specs = [
-        pl.BlockSpec((1, block_q, d), q_map),
-        pl.BlockSpec((1, block_k, d), lambda bh_, j, i: (bh_, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bh_, j, i: (bh_, j, 0)),
-        pl.BlockSpec((1, block_q, d), q_map),
-        pl.BlockSpec((1, block_q, d), q_map),
-        pl.BlockSpec((1, 1, t), lambda bh_, j, i: (bh_, 0, 0)),
-        pl.BlockSpec((1, 1, t), lambda bh_, j, i: (bh_, 0, 0)),
-    ]
-    dkv_operands = [qf, kf, vf, of, dof, m, l]
-    if segments:
-        dkv_specs += [_seg_spec(t, h), _seg_spec(t, h)]
-        dkv_operands += [qsegf, ksegf]
-    vma = _out_vma(*dkv_operands)
-    dk, dv = pl.pallas_call(
-        kernel_dkv,
-        grid=(bh, num_k, num_q),
-        in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh_, j, i: (bh_, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh_, j, i: (bh_, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), qf.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, t, d), qf.dtype, vma=vma),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        interpret=interpret,
-        name=scopes.FLASH_BWD_DKV,
-    )(*dkv_operands)
+    operands = [qf, kf, vf, of, dof, m, l]
+    if qsegf is not None:
+        operands += [qsegf, ksegf]
+    config = (bh, t, d, qf.dtype, h, causal, scale, block_q, block_k,
+              interpret, qsegf is not None, _out_vma(*operands))
+    _record_blocks(scopes.FLASH_BWD_DQ, bh, t, block_q, block_k, causal)
+    dq = _bwd_dq_call(*config)(*operands)
+    _record_blocks(scopes.FLASH_BWD_DKV, bh, t, block_q, block_k, causal)
+    dk, dv = _bwd_dkv_call(*config)(*operands)
     return dq, dk, dv
 
 
@@ -503,14 +662,14 @@ def flash_attention(q, k, v, causal: bool = True,
     accumulation tolerance, forward and backward.
 
     ``block_q``/``block_k`` default to AUTO: the largest power of two
-    ≤ 1024 dividing ``T`` (≤ 512 when ``D > 128`` — the 1024 sweep only
+    ≤ 1024 dividing ``T`` (≤ 512 when ``D > 128`` — the sweeps only
     covered head dims ≤ 128, and bigger heads roughly double the bwd
-    kernel's VMEM pressure).  Swept on a real v5e (docs/kernels.md): 512
-    blocks run the fwd+bwd pair 2.7× faster than 128 blocks at T=2048
-    and 4.2× at T=8192, and 1024 another 1.13–1.33× over 512 (r4 sweep;
-    bigger tiles amortize the grid/DMA overhead and feed the MXU longer
-    contractions; 1024×1024 f32 scores ≈ 4 MB of the ~16 MB VMEM, still
-    comfortable next to the tile operands).
+    kernel's VMEM pressure).  Swept on a v5e (docs/kernels.md, PR 25):
+    1024 blocks run the three kernels 1.40× faster than 512 blocks at
+    T=8192 and 1.32× at T=2048, and faster than every mixed shape tried
+    (bigger tiles amortize the grid/DMA overhead and the per-row work;
+    1024×1024 f32 scores ≈ 4 MB of the ~16 MB VMEM; 2048 on either side
+    is refused for VMEM).
 
     ``segment_ids`` ([B, T] int32) enables sequence packing: tokens
     attend only within their own segment (composes with ``causal``) —
@@ -534,10 +693,10 @@ def _auto_block(t: int, head_dim: Optional[int] = None) -> int:
     # Floor at 128: tinier auto blocks (e.g. 8 for T=1992) would explode
     # the grid and run orders of magnitude slower than the error is
     # annoying — same contract as the old fixed-128 default.
-    # 1024 preferred over 512 since r4: measured fwd+bwd 1.33x at T=2048
-    # (B4 H32 D128), 1.13x at T=4096/8192 (docs/kernels.md table);
-    # 1024x1024 f32 scores = 4 MB of VMEM, still comfortable.  The 1024
-    # preference was swept at head_dim<=128 only; larger head dims
+    # 1024 preferred over 512: measured 1.32x at T=2048 (B4 H32 D128)
+    # and 1.40x at T=8192 (B1 H32) over the three kernels
+    # (docs/kernels.md, PR 25); 1024x1024 f32 scores = 4 MB of VMEM.  The
+    # 1024 preference was swept at head_dim<=128 only; larger head dims
     # roughly double the dkv kernel's operand + f32 score/p VMEM
     # pressure, so cap the auto choice at 512 there (explicit
     # block_q/block_k still override).

@@ -399,3 +399,170 @@ def test_auto_blocks_default_path():
     refs = local_attention(qs, ks, vs, causal=True)
     np.testing.assert_allclose(np.asarray(outs), np.asarray(refs),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# Block classes: above the diagonal (skipped), interior (no causal mask),
+# diagonal (masked; square blocks >= 256 as 2x2 sub-tiles without the
+# upper-right one).  Explicit blocks so one call holds every class.
+# ---------------------------------------------------------------------------
+
+def _packed_ids(t, b=1):
+    """Three documents; the first boundary (at 0.39 T) falls inside the
+    interior block under the diagonal for every blocking used below."""
+    cuts = [0, int(0.39 * t), int(0.59 * t), t]
+    ids = np.zeros((b, t), np.int32)
+    for i in range(3):
+        ids[:, cuts[i]:cuts[i + 1]] = i
+    return jnp.asarray(ids)
+
+
+_CLASS_BLOCKINGS = [
+    pytest.param(512, 128, 128, id="t512-b128"),           # whole diagonal
+    pytest.param(512, 256, 256, id="t512-b256-subtiles"),  # 2x2 sub-tiles
+    pytest.param(512, 256, 128, id="t512-bq256-bk128"),    # sub-tiles off
+    pytest.param(512, 128, 256, id="t512-bq128-bk256"),
+    pytest.param(256, 256, 256, id="one-block-subtiles"),  # all diagonal
+    pytest.param(128, 128, 128, id="one-block"),
+]
+
+
+@pytest.mark.parametrize("segments", [False, True],
+                         ids=["plain", "segment_ids"])
+@pytest.mark.parametrize("causal", [True, False],
+                         ids=["causal", "full"])
+@pytest.mark.parametrize("t,block_q,block_k", _CLASS_BLOCKINGS)
+def test_block_classes_match_oracle(t, block_q, block_k, causal, segments):
+    """Forward and dQ, dK, dV of every block class against the lax
+    oracle."""
+    rs = np.random.default_rng(30)
+    q, k, v = _make_qkv(rs, b=1, t=t, h=2, d=32)
+    seg = _packed_ids(t) if segments else None
+
+    def loss(attn):
+        def f(q, k, v):
+            o = attn(q, k, v)
+            return jnp.sum(o * (o + 1.0)), o
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    (_, out), gf = loss(lambda q, k, v: flash_attention(
+        q, k, v, causal, None, block_q, block_k, True, seg))(q, k, v)
+    (_, ref), gr = loss(lambda q, k, v: local_attention(
+        q, k, v, causal=causal, segment_ids=seg))(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    for a, b, nm in zip(gf, gr, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4,
+                                   err_msg=f"d{nm} mismatch")
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("block", [128, 256])
+def test_rotated_k_parts_match_lax_twin(block, causal):
+    """The ring route's per-step call: K/V and the K-side segment ids of
+    another shard (``causal=False`` off the diagonal step), rows that meet
+    no key of their document in this block (m = -inf, l = 0, zero output
+    and zero gradients), and the global (m, l) handed to the backward."""
+    from horovod_tpu.ops import flash_attention as fa
+    from horovod_tpu.parallel import sequence as sp
+
+    rs = np.random.default_rng(31)
+    h, t, d = 2, 512, 32
+    qf, kf, vf, dof = (jnp.asarray(rs.standard_normal((h, t, d)),
+                                   jnp.float32) for _ in range(4))
+    # q side: documents 0 | 1 | 2; the arriving K block holds 1 | 2 | 3,
+    # cut elsewhere: document 0's rows are fully masked.
+    qseg = np.zeros((1, 1, t), np.int32)
+    qseg[..., 150:330] = 1
+    qseg[..., 330:] = 2
+    kseg = np.full((1, 1, t), 1, np.int32)
+    kseg[..., 200:420] = 2
+    kseg[..., 420:] = 3
+    qseg, kseg = jnp.asarray(qseg), jnp.asarray(kseg)
+    scale = d ** -0.5
+
+    got = fa._fwd_parts(qf, kf, vf, qseg, kseg, h, causal, scale, block,
+                        block, True)
+    want = sp._lax_fwd_parts(qf, kf, vf, qseg, kseg, h, causal, scale,
+                             block, block, True)
+    o, m, l = got
+    assert np.isneginf(np.asarray(m)[:, 0, :150]).all()
+    assert (np.asarray(l)[:, 0, :150] == 0).all()
+    assert (np.asarray(o)[:, :150] == 0).all()
+    for a, b, nm in zip(got, want, ("o", "m", "l")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-5, atol=2e-5, err_msg=nm)
+
+    grads = fa._bwd_parts(qf, kf, vf, o, dof, m, l, qseg, kseg, h, causal,
+                          scale, block, block, True)
+    twin = sp._lax_bwd_parts(qf, kf, vf, o, dof, m, l, qseg, kseg, h,
+                             causal, scale, block, block, True)
+    assert (np.asarray(grads[0])[:, :150] == 0).all()
+    for a, b, nm in zip(grads, twin, ("dq", "dk", "dv")):
+        assert np.isfinite(np.asarray(a)).all(), nm
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4, err_msg=nm)
+
+
+@pytest.mark.parametrize("t,per_head,ratio", [
+    # gpt67_t8192: 28 interior + 8 diagonal of 64, 34 block-equivalents.
+    (8192, {"skipped": 28, "interior": 28, "diagonal": 8}, 34 / 32),
+    # gpt67_t2048: 1 + 2 of 4, 2.5 block-equivalents for 2.0.
+    (2048, {"skipped": 1, "interior": 1, "diagonal": 2}, 2.5 / 2.0),
+])
+def test_block_class_counts_at_the_cell_shapes(t, per_head, ratio):
+    from horovod_tpu.ops import flash_attention as fa
+
+    block = fa._auto_block(t, 128)
+    assert block == 1024
+    got = fa.block_classes(t, block, block, True)
+    assert {k: got[k] for k in per_head} == per_head
+    assert got["needed"] == t * (t + 1) // 2
+    # The issue's figures take the needed elements as T^2 / 2.
+    assert got["computed"] / (t * t / 2) == pytest.approx(ratio)
+    assert got["computed"] / got["needed"] == pytest.approx(ratio, rel=1e-3)
+    # Without the causal flag nothing is skipped or masked.
+    full = fa.block_classes(t, block, block, False)
+    assert (full["skipped"], full["diagonal"]) == (0, 0)
+    assert full["computed"] == full["needed"] == t * t
+
+
+def test_block_class_counters_are_recorded_at_trace_time():
+    """``hvd_flash_blocks_total{kernel,class}`` and
+    ``hvd_flash_computed_over_needed{kernel}`` for one traced
+    forward + backward (B*H = 2, T = 512, blocks 256: per head 1 skipped,
+    1 interior, 2 diagonal of 3/4 block each)."""
+    from horovod_tpu import telemetry
+    from horovod_tpu.telemetry import aggregate
+
+    rs = np.random.default_rng(32)
+    q, k, v = _make_qkv(rs, b=1, t=512, h=2, d=16)
+    telemetry.registry().clear()
+    telemetry.configure(enabled_flag=True)
+    try:
+        jax.grad(lambda q: jnp.sum(flash_attention(
+            q, k, v, True, None, 256, 256, True)))(q)
+        snap = telemetry.metrics_snapshot()
+        for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+            for cls, n in (("skipped", 2), ("interior", 2),
+                           ("diagonal", 4)):
+                assert aggregate.counter_total(
+                    snap, "hvd_flash_blocks_total",
+                    {"kernel": kernel, "class": cls}) == n, (kernel, cls)
+        ratios = {e["labels"]["kernel"]: e["value"] for e in
+                  snap["hvd_flash_computed_over_needed"]["values"]}
+        assert ratios == pytest.approx(dict.fromkeys(
+            ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
+            2.5 * 256 * 256 / (512 * 513 // 2)))
+    finally:
+        telemetry.configure(enabled_flag=False)
+        telemetry.registry().clear()
+
+
+def test_metrics_doc_names_the_flash_series():
+    import os
+    doc = open(os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "docs", "metrics.md")).read()
+    assert "`hvd_flash_blocks_total`" in doc
+    assert "`hvd_flash_computed_over_needed`" in doc

@@ -1,0 +1,66 @@
+"""The three flash kernels compiled at the benchmark's attention shapes for
+a v5e that is described and not attached (rehearsal 3 of the
+on-chip-measurement guide; the recipe of
+``perfbench/tests/test_chip_compile.py``).
+
+Nothing runs, so nothing here is a measurement: Mosaic either takes the
+kernels or refuses them (a slice off the tiling, more VMEM than a kernel
+may use — what refused ``ops/fused_stem.py`` in PR 21), and the compiled
+program names them as the per-layer metrics read them.  The topology is
+described inside a fixture, never at import, and everything compiles in
+this process (a child could not load libtpu beside it).
+"""
+
+import os
+
+import pytest
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    # A program compiled for a described chip cannot be read back from
+    # the persistent cache without the chip: keep these out of it.
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# [B, T, H, D] of gpt67_t8192 (B*H = 32) and gpt67_t2048 (B*H = 128).
+@pytest.mark.parametrize("segments", [False, True],
+                         ids=["causal", "segment_ids"])
+@pytest.mark.parametrize("shape", [(1, 8192, 32, 128), (4, 2048, 32, 128)],
+                         ids=["t8192", "t2048"])
+def test_kernels_compile_for_the_v5e(one_chip, shape, segments):
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.flash_attention import flash_attention
+    from horovod_tpu.telemetry import scopes
+
+    b, t, _, _ = shape
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    seg = (jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip)
+           if segments else None)
+
+    def fwd_and_grads(q, k, v, do, seg):
+        out, pull = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, True, None, None, None,
+                                            False, seg), q, k, v)
+        return (out,) + pull(do)
+
+    text = jax.jit(fwd_and_grads).lower(x, x, x, x, seg).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in (scopes.FLASH_FWD, scopes.FLASH_BWD_DQ,
+                 scopes.FLASH_BWD_DKV):
+        assert f"%{name}." in text or f"%{name} " in text, name
