@@ -13,6 +13,14 @@ param pytree, manual-SPMD forward) so it drops straight into ``shard_map``:
   (:mod:`horovod_tpu.parallel.sequence`)
 
 bf16 matmuls / fp32 params+softmax, MXU-friendly dims.
+
+The block is config-driven (:class:`TransformerConfig`): the defaults are
+the GPT-2-style block (learned positions, GELU MLP, tied head); rotary
+positions, QK-norm, SwiGLU, an untied head and a dropless
+mixture-of-experts MLP (:mod:`horovod_tpu.models.moe`) are fields of the
+same config through the same ``forward`` and ``make_train_step`` — OLMoE's
+block is ``positions="rope", qk_norm=True, mlp="swiglu",
+tie_embeddings=False, n_experts=64, experts_per_token=8``.
 """
 
 from __future__ import annotations
@@ -27,6 +35,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from horovod_tpu.models import moe
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel import sequence as seq_mod
 from horovod_tpu.parallel import tensor as tp
@@ -42,10 +51,74 @@ class TransformerConfig:
     d_ff: int = 2048
     max_seq: int = 2048
     dtype: object = jnp.bfloat16
+    # --- the block; the defaults are the GPT-2-style one -----------------
+    # "learned": a [max_seq, d_model] table added to the embedding;
+    # "rope": rotary embedding of q and k (rotate-half convention,
+    # positions from 0), no table.
+    positions: str = "learned"
+    rope_theta: float = 10000.0
+    # RMSNorm, each with its own scale, over the whole q and the whole k
+    # projection before the head split (OLMoE).
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
+    # False: an untied ``head`` leaf [d_model, vocab] instead of embed.T.
+    tie_embeddings: bool = True
+    # "gelu": w2 gelu(w1 h); "swiglu": w_down (silu(w_gate h) * (w_up h)).
+    mlp: str = "gelu"
+    # n_experts > 0: the MLP is ``n_experts`` SwiGLU experts of width
+    # ``d_expert`` with softmax-then-top-``experts_per_token`` routing
+    # that drops nothing (models/moe.py); d_ff is then unused.
+    n_experts: int = 0
+    experts_per_token: int = 0
+    d_expert: int = 0
+    norm_topk_prob: bool = False
+    # Added to the cross-entropy: coefficient of the load-balancing loss
+    # and of the router z-loss (moe.router_losses).
+    router_aux_coef: float = 0.0
+    router_z_coef: float = 0.0
+
+    def __post_init__(self):
+        if self.positions not in ("learned", "rope"):
+            raise ValueError(f"positions={self.positions!r}: expected "
+                             f"'learned' or 'rope'")
+        if self.mlp not in ("gelu", "swiglu"):
+            raise ValueError(f"mlp={self.mlp!r}: expected 'gelu' or "
+                             f"'swiglu'")
+        if self.positions == "rope" and self.head_dim % 2:
+            raise ValueError(f"positions='rope' needs an even head_dim, "
+                             f"got {self.head_dim}")
+        if self.n_experts:
+            if self.mlp != "swiglu":
+                raise ValueError("n_experts > 0: the experts are SwiGLU; "
+                                 "set mlp='swiglu'")
+            if not 0 < self.experts_per_token <= self.n_experts:
+                raise ValueError(
+                    f"experts_per_token={self.experts_per_token} must lie "
+                    f"in 1..n_experts={self.n_experts}")
+            if self.d_expert <= 0:
+                raise ValueError("n_experts > 0 needs d_expert, one "
+                                 "expert's width")
+        elif (self.experts_per_token or self.d_expert or self.norm_topk_prob
+              or self.router_aux_coef or self.router_z_coef):
+            raise ValueError("experts_per_token, d_expert, norm_topk_prob "
+                             "and the router loss coefficients mean "
+                             "nothing without n_experts")
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+
+def _refuse(cfg: TransformerConfig, where: str, fields) -> None:
+    """Raise for config fields ``where`` does not implement — never a
+    silent fall back to the default block.  ``fields``: names to check
+    against the dataclass defaults."""
+    defaults = TransformerConfig()
+    for name in fields:
+        if getattr(cfg, name) != getattr(defaults, name):
+            raise NotImplementedError(
+                f"{where} does not implement TransformerConfig.{name}="
+                f"{getattr(cfg, name)!r}")
 
 
 def init_params(rng, cfg: TransformerConfig):
@@ -58,25 +131,48 @@ def init_params(rng, cfg: TransformerConfig):
         scale = scale if scale is not None else (shape[0] ** -0.5)
         return (jax.random.normal(key, shape, jnp.float32) * scale)
 
+    def experts(key, shape):
+        # [E, in, out]: each expert a dense matrix of its own fan-in.
+        return dense(key, (cfg.n_experts,) + shape, scale=shape[0] ** -0.5)
+
     layers = []
     for i in range(cfg.n_layers):
         k = jax.random.split(keys[2 + i], 6)
-        layers.append({
+        k_up, k_router = jax.random.split(jax.random.fold_in(k[4], 1))
+        layer = {
             "ln1_scale": jnp.ones((d,), jnp.float32),
             "ln2_scale": jnp.ones((d,), jnp.float32),
             "wq": dense(k[0], (d, d)),
             "wk": dense(k[1], (d, d)),
             "wv": dense(k[2], (d, d)),
             "wo": dense(k[3], (d, d)),
-            "w1": dense(k[4], (d, f)),
-            "w2": dense(k[5], (f, d)),
-        })
-    return {
+        }
+        if cfg.qk_norm:
+            layer["q_norm_scale"] = jnp.ones((d,), jnp.float32)
+            layer["k_norm_scale"] = jnp.ones((d,), jnp.float32)
+        if cfg.n_experts:
+            e = cfg.d_expert
+            layer.update(router=dense(k_router, (d, cfg.n_experts)),
+                         w_gate=experts(k[4], (d, e)),
+                         w_up=experts(k_up, (d, e)),
+                         w_down=experts(k[5], (e, d)))
+        elif cfg.mlp == "swiglu":
+            layer.update(w_gate=dense(k[4], (d, f)),
+                         w_up=dense(k_up, (d, f)),
+                         w_down=dense(k[5], (f, d)))
+        else:
+            layer.update(w1=dense(k[4], (d, f)), w2=dense(k[5], (f, d)))
+        layers.append(layer)
+    params = {
         "embed": dense(keys[0], (v, d), scale=0.02),
-        "pos": dense(keys[1], (cfg.max_seq, d), scale=0.02),
         "ln_f_scale": jnp.ones((d,), jnp.float32),
         "layers": layers,
     }
+    if cfg.positions == "learned":
+        params["pos"] = dense(keys[1], (cfg.max_seq, d), scale=0.02)
+    if not cfg.tie_embeddings:
+        params["head"] = dense(jax.random.fold_in(keys[1], 1), (d, v))
+    return params
 
 
 def param_specs(cfg: TransformerConfig, model_axis: Optional[str]):
@@ -89,50 +185,102 @@ def param_specs(cfg: TransformerConfig, model_axis: Optional[str]):
     layer = {
         "ln1_scale": P(), "ln2_scale": P(),
         "wq": col, "wk": col, "wv": col, "wo": row,
-        "w1": col, "w2": row,
     }
-    return {
+    if cfg.qk_norm:
+        layer.update(q_norm_scale=P(), k_norm_scale=P())
+    if cfg.n_experts:
+        # Every expert on every chip (experts over an axis: ROADMAP R2).
+        layer.update(router=P(), w_gate=P(), w_up=P(), w_down=P())
+    elif cfg.mlp == "swiglu":
+        layer.update(w_gate=col, w_up=col, w_down=row)
+    else:
+        layer.update(w1=col, w2=row)
+    specs = {
         "embed": P(),
-        "pos": P(),
         "ln_f_scale": P(),
         "layers": [dict(layer) for _ in range(cfg.n_layers)],
     }
+    if cfg.positions == "learned":
+        specs["pos"] = P()
+    if not cfg.tie_embeddings:
+        specs["head"] = P()
+    return specs
 
 
-def _rmsnorm(x, scale):
+def _rmsnorm(x, scale, eps):
     # Stats in f32; output in the INPUT dtype.  The scale param is f32,
     # and without the cast it silently promoted every rmsnorm output —
     # and therefore every qkv/mlp matmul INPUT — to f32: measured 63.5%
     # -> 72.2% MFU on the d3584/L6 LM config from this one cast (r4).
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return ((x * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype) *
+    return ((x * jax.lax.rsqrt(var + eps)).astype(x.dtype) *
             scale.astype(x.dtype))
 
 
-def _mlp_block(x, layer, dt, model_axis):
-    """rmsnorm -> gelu MLP -> row-parallel psum -> residual (shared by the
-    training forward and the KV-cache decode so the two cannot drift)."""
-    h = _rmsnorm(x, layer["ln2_scale"])
+def _mlp_block(x, layer, cfg, model_axis):
+    """rmsnorm -> dense MLP (gelu, or SwiGLU) -> row-parallel psum ->
+    residual (shared by the training forward and the KV-cache decode so
+    the two cannot drift)."""
+    dt = cfg.dtype
+    h = _rmsnorm(x, layer["ln2_scale"], cfg.norm_eps)
     hi = tp.region_input(h, model_axis) if model_axis else h
-    u = jax.nn.gelu(hi @ layer["w1"].astype(dt))
-    dn = u @ layer["w2"].astype(dt)
+    if cfg.mlp == "swiglu":
+        u = (jax.nn.silu(hi @ layer["w_gate"].astype(dt))
+             * (hi @ layer["w_up"].astype(dt)))
+        dn = u @ layer["w_down"].astype(dt)
+    else:
+        u = jax.nn.gelu(hi @ layer["w1"].astype(dt))
+        dn = u @ layer["w2"].astype(dt)
     if model_axis:
         dn = lax.psum(dn, model_axis)
     return x + dn
 
 
-def _qkv_proj(x, layer, dt, model_axis, head_dim):
-    """rmsnorm -> q/k/v projections -> head split (shared by forward,
-    decode_step and forward_pipelined so the projection math cannot
-    drift).  Returns q, k, v with a trailing [heads, head_dim] split."""
-    h = _rmsnorm(x, layer["ln1_scale"])
+def _moe_block(x, layer, cfg):
+    """rmsnorm -> dropless mixture of experts -> residual; also the
+    router's sums for the auxiliary losses."""
+    y, stats = moe.moe_ffn(_rmsnorm(x, layer["ln2_scale"], cfg.norm_eps),
+                           layer, cfg)
+    return x + y, stats
+
+
+def _rotary(x, positions, theta: float):
+    """Rotary embedding of ``x`` [..., T, H, head_dim] at ``positions``
+    [T], rotate-half convention (HF ``apply_rotary_pos_emb``): the pair
+    (x_i, x_{i + head_dim/2}) turns by ``position * theta^(-2i/head_dim)``.
+    Angles and the rotation in float32."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.cos(angles)[:, None, :]
+    sin = jnp.sin(angles)[:, None, :]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _qkv_proj(x, layer, cfg, model_axis, positions=None):
+    """rmsnorm -> q/k/v projections -> (QK-norm) -> head split ->
+    (rotary at ``positions`` [T]) (shared by forward, decode_step and
+    forward_pipelined so the projection math cannot drift).  Returns q,
+    k, v with a trailing [heads, head_dim] split."""
+    dt = cfg.dtype
+    h = _rmsnorm(x, layer["ln1_scale"], cfg.norm_eps)
     hi = tp.region_input(h, model_axis) if model_axis else h
     q = hi @ layer["wq"].astype(dt)
     k = hi @ layer["wk"].astype(dt)
     v = hi @ layer["wv"].astype(dt)
+    if cfg.qk_norm:
+        q = _rmsnorm(q, layer["q_norm_scale"], cfg.norm_eps)
+        k = _rmsnorm(k, layer["k_norm_scale"], cfg.norm_eps)
     dh = q.shape[-1]
-    split = q.shape[:-1] + (dh // head_dim, head_dim)
-    return q.reshape(split), k.reshape(split), v.reshape(split), dh
+    split = q.shape[:-1] + (dh // cfg.head_dim, cfg.head_dim)
+    q, k, v = q.reshape(split), k.reshape(split), v.reshape(split)
+    if cfg.positions == "rope":
+        q = _rotary(q, positions, cfg.rope_theta)
+        k = _rotary(k, positions, cfg.rope_theta)
+    return q, k, v, dh
 
 
 def _attn_out(o_flat, x, layer, dt, model_axis):
@@ -172,10 +320,20 @@ def _flash_profitable(t: int) -> bool:
 
 
 @jax.named_scope(scopes.HEAD)
-def _logits_head(x, params, dt):
-    """Final rmsnorm + tied-embedding projection (shared fwd/decode)."""
-    x = _rmsnorm(x, params["ln_f_scale"])
-    return (x @ params["embed"].T.astype(dt)).astype(jnp.float32)
+def _logits_head(x, params, cfg):
+    """Final rmsnorm + projection onto the vocabulary, by the transposed
+    embedding or the untied ``head`` (shared fwd/decode)."""
+    dt = cfg.dtype
+    x = _rmsnorm(x, params["ln_f_scale"], cfg.norm_eps)
+    w = params["embed"].T if cfg.tie_embeddings else params["head"]
+    return (x @ w.astype(dt)).astype(jnp.float32)
+
+
+def _refuse_under_model_axis(cfg, model_axis) -> None:
+    # QK-norm's statistics span the whole projection, which the model
+    # axis splits; the experts live whole on every chip (ROADMAP R2).
+    if model_axis:
+        _refuse(cfg, f"model_axis={model_axis!r}", ("qk_norm", "n_experts"))
 
 
 def _remat_wrap(body, remat: str):
@@ -204,7 +362,20 @@ def forward(params, tokens, cfg: TransformerConfig,
             seq_axis: Optional[str] = None,
             attention: str = "ring",
             segment_ids=None, remat: str = "none"):
-    """tokens: [B, T_local] int32 -> logits [B, T_local, vocab] fp32.
+    """tokens: [B, T_local] int32 -> logits [B, T_local, vocab] fp32
+    (:func:`forward_with_router_stats` without the router's sums)."""
+    return forward_with_router_stats(params, tokens, cfg, model_axis,
+                                     seq_axis, attention, segment_ids,
+                                     remat)[0]
+
+
+def forward_with_router_stats(params, tokens, cfg: TransformerConfig,
+                              model_axis: Optional[str] = None,
+                              seq_axis: Optional[str] = None,
+                              attention: str = "ring",
+                              segment_ids=None, remat: str = "none"):
+    """tokens: [B, T_local] int32 -> (logits [B, T_local, vocab] fp32,
+    one :class:`moe.RouterStats` per layer — empty for a dense MLP).
 
     Inside shard_map, weight leaves arrive as LOCAL shards (per
     :func:`param_specs`); outside (single device) they are global and the
@@ -216,18 +387,24 @@ def forward(params, tokens, cfg: TransformerConfig,
     K-side ids with the K/V blocks, Ulysses all-gathers them (int32 per
     token) after its head scatter.
     """
+    _refuse_under_model_axis(cfg, model_axis)
     dt = cfg.dtype
     t_local = tokens.shape[1]
     with jax.named_scope(scopes.EMBED):
         pos_offset = (lax.axis_index(seq_axis) * t_local) if seq_axis else 0
-        x = (params["embed"][tokens] +
-             lax.dynamic_slice_in_dim(params["pos"], pos_offset, t_local,
-                                      axis=0)[None]).astype(dt)
+        if cfg.positions == "learned":
+            positions = None
+            x = (params["embed"][tokens] +
+                 lax.dynamic_slice_in_dim(params["pos"], pos_offset,
+                                          t_local, axis=0)[None]).astype(dt)
+        else:
+            positions = pos_offset + jnp.arange(t_local)
+            x = params["embed"][tokens].astype(dt)
 
     def layer_block(x, layer, segment_ids):
         # --- attention block (each route opens its own attn/<route>) ---
         with jax.named_scope(scopes.ATTN_QKV):
-            q, k, v, dh = _qkv_proj(x, layer, dt, model_axis, cfg.head_dim)
+            q, k, v, dh = _qkv_proj(x, layer, cfg, model_axis, positions)
         b, t = q.shape[:2]
         if seq_axis is not None:
             if attention == "ring_flash" or (attention == "auto" and
@@ -266,14 +443,21 @@ def forward(params, tokens, cfg: TransformerConfig,
         with jax.named_scope(scopes.ATTN_OUT):
             x = _attn_out(o.reshape(b, t, dh), x, layer, dt, model_axis)
         with jax.named_scope(scopes.MLP):
-            return _mlp_block(x, layer, dt, model_axis)
+            if cfg.n_experts:
+                return _moe_block(x, layer, cfg)
+            return _mlp_block(x, layer, cfg, model_axis), None
 
     layer_block = _remat_wrap(layer_block, remat)
+    router_stats = []
     for i, layer in enumerate(params["layers"]):
         with jax.named_scope(scopes.LAYER % i):
-            x = layer_block(x, layer, segment_ids)
+            x, stats = layer_block(x, layer, segment_ids)
+        if cfg.n_experts:
+            router_stats.append(stats)
+            moe.record_assignments(
+                i, tokens.size * cfg.experts_per_token)
 
-    return _logits_head(x, params, dt)
+    return _logits_head(x, params, cfg), router_stats
 
 
 @jax.named_scope(scopes.LOSS)
@@ -287,11 +471,24 @@ def xent(logits, labels):
 
 def loss_fn(params, tokens, labels, cfg: TransformerConfig,
             model_axis=None, seq_axis=None, attention="ring",
-            segment_ids=None, remat="none"):
+            segment_ids=None, remat="none", batch_axes=()):
     """Mean next-token cross-entropy over the LOCAL shard (callers pmean
-    over data/seq axes)."""
-    return xent(forward(params, tokens, cfg, model_axis, seq_axis,
-                        attention, segment_ids, remat), labels)
+    over data/seq axes), plus, with experts, ``router_aux_coef`` x the
+    load-balancing loss and ``router_z_coef`` x the router z-loss over
+    all layers' tokens together.  ``batch_axes``: the mesh axes the batch
+    is split over, so that the mean of the shards' losses is the global
+    batch's loss (:func:`moe.router_losses`)."""
+    logits, router_stats = forward_with_router_stats(
+        params, tokens, cfg, model_axis, seq_axis, attention, segment_ids,
+        remat)
+    loss = xent(logits, labels)
+    if router_stats:
+        with jax.named_scope(scopes.LOSS):
+            balance, z = moe.router_losses(
+                router_stats, tokens.size * len(router_stats), batch_axes)
+            loss = (loss + cfg.router_aux_coef * balance
+                    + cfg.router_z_coef * z)
+    return loss
 
 
 def make_train_step(cfg: TransformerConfig, optimizer, mesh,
@@ -337,6 +534,7 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
     """
     from horovod_tpu.ops.fusion import fused_pytree_mean
 
+    _refuse_under_model_axis(cfg, model_axis)
     specs = param_specs(cfg, model_axis)
     grad_axes = tuple(a for a in (data_axis, seq_axis) if a)
 
@@ -376,7 +574,7 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
                                               params)
         loss, grads = jax.value_and_grad(loss_fn)(
             local_params, tokens, labels, cfg, model_axis, seq_axis,
-            attention, segment_ids, remat)
+            attention, segment_ids, remat, grad_axes)
 
         def do_update():
             if zopt is not None:
@@ -442,8 +640,11 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
         in_specs=in_specs,
         out_specs=(specs, opt_specs, P()),
         # The ZeRO path's axis_index-dependent slicing + psum_scatter do
-        # not type under the vma checker; the plain path keeps it on.
-        check_vma=zopt is None)
+        # not type under the vma checker, nor do the expert layer's
+        # grouped-matmul kernels in the Pallas interpreter (their index
+        # maps read arrays that vary over the batch axes,
+        # ops/grouped_matmul.py); the plain dense path keeps it on.
+        check_vma=zopt is None and not cfg.n_experts)
     jitted = jax.jit(scopes.named(step, scopes.LM_TRAIN_STEP),
                      donate_argnums=(0, 1) if donate else ())
     if zopt is not None:
@@ -491,6 +692,9 @@ def decode_step(params, token, cache, pos, cfg: TransformerConfig,
     the full static cache length with a position mask (TPU-friendly: no
     dynamic shapes), so cost is O(max_len) per step.
     """
+    # A rotated key cache and an expert layer per token are not written
+    # (serving: ROADMAP R8/R13).
+    _refuse(cfg, "decode_step", ("positions", "n_experts"))
     dt = cfg.dtype
     hd = cfg.head_dim
     x = (params["embed"][token] +
@@ -498,7 +702,7 @@ def decode_step(params, token, cache, pos, cfg: TransformerConfig,
          ).astype(dt)                                    # [B, D]
     new_cache = []
     for layer, c in zip(params["layers"], cache):
-        q, k, v, dh = _qkv_proj(x, layer, dt, model_axis, hd)
+        q, k, v, dh = _qkv_proj(x, layer, cfg, model_axis)
         b = q.shape[0]
         # Defensive cast: the cache is cfg.dtype forever; any future
         # dtype drift upstream (the r4 rmsnorm f32-scale promotion was
@@ -520,8 +724,8 @@ def decode_step(params, token, cache, pos, cfg: TransformerConfig,
         o = jnp.einsum("bht,bthd->bhd", p,
                        cv.astype(jnp.float32)).astype(dt)
         x = _attn_out(o.reshape(b, dh), x, layer, dt, model_axis)
-        x = _mlp_block(x, layer, dt, model_axis)
-    return _logits_head(x, params, dt), new_cache
+        x = _mlp_block(x, layer, cfg, model_axis)
+    return _logits_head(x, params, cfg), new_cache
 
 
 def generate(params, prompt, total_len: int, cfg: TransformerConfig,
@@ -637,7 +841,7 @@ def forward_pipelined(params, stacked_layers, tokens,
         y = pipeline_apply(_pipe_stage_fn(cfg), stacked_layers, mb,
                            axis_name=pipe_axis)
     x = y.reshape(b, t, cfg.d_model)
-    return _logits_head(x, params, cfg.dtype)
+    return _logits_head(x, params, cfg)
 
 
 def _embed_microbatches(base, tokens, cfg: TransformerConfig,
@@ -657,17 +861,17 @@ def _embed_microbatches(base, tokens, cfg: TransformerConfig,
 def _pipe_stage_fn(cfg: TransformerConfig):
     """stage_fn for the pipeline schedules: scan this device's layer
     slice (leaves [1, lps, ...]) over the activation."""
-    dt, hd = cfg.dtype, cfg.head_dim
+    dt = cfg.dtype
 
     def one_layer(x, lp):
         with jax.named_scope(scopes.ATTN_QKV):
-            q, k, v, dh = _qkv_proj(x, lp, dt, None, hd)
+            q, k, v, dh = _qkv_proj(x, lp, cfg, None)
         bb, tt = q.shape[:2]
         o = seq_mod.local_attention(q, k, v, causal=True)
         with jax.named_scope(scopes.ATTN_OUT):
             x = _attn_out(o.reshape(bb, tt, dh), x, lp, dt, None)
         with jax.named_scope(scopes.MLP):
-            x = _mlp_block(x, lp, dt, None)
+            x = _mlp_block(x, lp, cfg, None)
         # attention computes in f32; pin the carried activation to the
         # model dtype so the layer scan (and the pipeline's microbatch
         # buffers) keep a stable, bf16-safe type
@@ -752,6 +956,10 @@ def make_train_step_pipelined(cfg: TransformerConfig, optimizer, mesh,
     """
     from jax.sharding import NamedSharding
 
+    # The pipelined forward embeds with the position table, scans stacked
+    # dense layers and returns no router sums.
+    _refuse(cfg, "make_train_step_pipelined",
+            ("positions", "qk_norm", "tie_embeddings", "mlp", "n_experts"))
     n_stages = mesh.shape[pipe_axis]
     v_eff = (virtual if schedule in ("interleaved", "interleaved_1f1b")
              else 1)
@@ -775,7 +983,7 @@ def make_train_step_pipelined(cfg: TransformerConfig, optimizer, mesh,
         from horovod_tpu.parallel.pipeline import make_pipeline_1f1b_loss
 
         def head_loss(y, tgt, base):
-            return xent(_logits_head(y, base, cfg.dtype), tgt)
+            return xent(_logits_head(y, base, cfg), tgt)
 
         # microbatches/targets: [M, mb, T, ...] with the microbatch dim
         # sharded over data (GSPMD reshards the embedded activations once

@@ -8,6 +8,15 @@ same way — the standard Switch-Transformer dispatch expressed in pure SPMD.
 
 Static shapes throughout (XLA requirement): routing uses fixed expert
 capacity with drop-on-overflow, the standard TPU MoE trick.
+
+This is the one-expert-per-chip, capacity-dropping demo of the
+``all_to_all`` dispatch, reachable from no step builder.  It is **not**
+what ``horovod_tpu.models`` trains with: the model's expert layer is
+:mod:`horovod_tpu.models.moe` — many experts per chip, softmax-then-top-k
+routing that drops nothing, a grouped matmul, the load-balancing and
+router z-losses (``moe.router_losses``; :func:`load_balancing_loss` here
+is the top-1 Switch form over the expert axis).  Experts over a mesh axis
+for that layer are ROADMAP R2.
 """
 
 from __future__ import annotations

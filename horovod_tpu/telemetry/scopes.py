@@ -25,6 +25,14 @@ MLP = "mlp"
 HEAD = "head"
 LOSS = "loss"
 
+# The parts of a mixture-of-experts layer (models/moe.py).  They open
+# under MLP as bare path components (".../layer_1/mlp/moe_experts/..."),
+# so a reader that knows only the model scopes still answers "mlp".
+MOE_ROUTER = "moe_router"
+MOE_DISPATCH = "moe_dispatch"
+MOE_EXPERTS = "moe_experts"
+MOE_COMBINE = "moe_combine"
+
 # Step scopes: what the step does with the gradients.
 GRAD_MEAN = "grad_mean"
 OPTIMIZER = "optimizer"
@@ -37,6 +45,11 @@ STEP_GUARD = "step_guard"
 FLASH_FWD = "flash_fwd"
 FLASH_BWD_DQ = "flash_bwd_dq"
 FLASH_BWD_DKV = "flash_bwd_dkv"
+
+# The three grouped-matmul Pallas kernels (ops/grouped_matmul.py).
+MOE_GMM = "moe_gmm"
+MOE_GMM_NT = "moe_gmm_nt"
+MOE_TGMM = "moe_tgmm"
 
 # The functions handed to jax.jit: the XLA module is jit_<name>.
 LM_TRAIN_STEP = "hvd_lm_train_step"

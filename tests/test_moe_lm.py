@@ -1,0 +1,525 @@
+"""The config-driven transformer block (rotary, QK-norm, SwiGLU, untied
+head) and the dropless mixture-of-experts layer (``models/moe.py``,
+``ops/grouped_matmul.py``), at tiny sizes on the virtual CPU mesh.
+
+Oracles: a per-expert Python loop for the layer, the benchmark's plain
+float32 reference (``perfbench/reference/moe_lm.py``, which shares no code
+with the program) for the block and the train step, hand values for the
+auxiliary losses.  Tolerances, float32 everywhere unless a test says
+otherwise: 2e-5 relative, which is float32 rounding through two layers (a
+bfloat16 anywhere reads 1e-3 and up, and one test proves that for the
+router's softmax).
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from horovod_tpu.models import moe
+from horovod_tpu.models import transformer as tfm
+from horovod_tpu.ops import grouped_matmul as gm
+from perfbench.reference import moe_lm as reference
+
+F32_RTOL = 2e-5
+
+OLMOE_TINY = tfm.TransformerConfig(
+    vocab_size=128, d_model=64, n_heads=2, n_layers=2, d_ff=0, max_seq=64,
+    dtype=jnp.float32, positions="rope", qk_norm=True, norm_eps=1e-5,
+    tie_embeddings=False, mlp="swiglu", n_experts=8, experts_per_token=2,
+    d_expert=32, router_aux_coef=0.01, router_z_coef=0.001)
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def _batch(cfg, batch=4, seq=32, seed=1):
+    toks = jax.random.randint(jax.random.PRNGKey(seed), (batch, seq + 1), 0,
+                              cfg.vocab_size)
+    return toks[:, :-1], toks[:, 1:]
+
+
+def _reference(cfg, params, tokens, labels, **kw):
+    return reference.loss_and_tail_grads(
+        params, tokens, labels, n_heads=cfg.n_heads,
+        top_k=cfg.experts_per_token, eps=cfg.norm_eps, theta=cfg.rope_theta,
+        aux_coef=cfg.router_aux_coef, z_coef=cfg.router_z_coef, **kw)
+
+
+# --- the grouped matmul kernels --------------------------------------------
+
+@pytest.fixture()
+def small_tiles(monkeypatch):
+    """Tiles that make tiny shapes span several tiles and visits."""
+    monkeypatch.setattr(gm, "TILE_M", 16)
+    monkeypatch.setattr(gm, "TILE_K", 32)
+    monkeypatch.setattr(gm, "TILE_N", 32)
+
+
+GROUPS = {"ragged": [30, 0, 50, 1, 47, 0], "one_group": [0, 0, 128, 0, 0, 0],
+          "tile_aligned": [16, 16, 32, 16, 32, 16],
+          "first_row_alone": [1, 127, 0, 0, 0, 0]}
+
+
+@pytest.mark.parametrize("sizes", GROUPS.values(), ids=GROUPS.keys())
+def test_grouped_matmul_matches_ragged_dot(small_tiles, sizes):
+    """Forward and both gradients against XLA's own ``ragged_dot``; empty
+    groups get no rows and a zero weight gradient."""
+    x = jax.random.normal(jax.random.key(0), (128, 64), jnp.float32)
+    w = jax.random.normal(jax.random.key(1), (6, 64, 96), jnp.float32)
+    gs = jnp.asarray(sizes, jnp.int32)
+
+    def loss(mm):
+        return lambda x, w: jnp.sum(jnp.sin(mm(x, w, gs)))
+
+    got, got_g = jax.value_and_grad(loss(gm.grouped_matmul), (0, 1))(x, w)
+    want, want_g = jax.value_and_grad(
+        loss(lambda *a: jax.lax.ragged_dot(*a, precision="highest")),
+        (0, 1))(x, w)
+    # The interpreter's float32 matmul on the CPU sums in another order.
+    assert abs(got - want) <= 1e-4 * abs(want) + 1e-4
+    for g, wnt in zip(got_g, want_g):
+        np.testing.assert_allclose(g, wnt, atol=2e-4)
+    empty = np.asarray(sizes) == 0
+    assert not np.asarray(got_g[1])[empty].any()
+
+
+def test_grouped_matmul_visits_cover_each_row_once():
+    sizes = jnp.asarray([30, 0, 50, 1, 47, 0], jnp.int32)
+    offsets, group, tile, n = gm._visits(sizes, 128, 16, visit_empty=False)
+    offsets, group, tile = map(np.asarray, (offsets, group, tile))
+    rows = np.zeros(128, int)
+    for v in range(int(n)):
+        lo = max(offsets[group[v]], tile[v] * 16)
+        hi = min(offsets[group[v] + 1], tile[v] * 16 + 16)
+        assert hi > lo            # no visit without rows of its own
+        rows[lo:hi] += 1
+    assert (rows == 1).all()
+    # A shared tile is visited twice in a row: output tiles are revisited
+    # only consecutively.
+    assert (np.diff(tile[:int(n)]) >= 0).all()
+    *_, n_all = gm._visits(sizes, 128, 16, visit_empty=True)
+    assert int(n_all) == int(n) + 2
+
+
+def test_grouped_matmul_refuses_a_dimension_its_tile_does_not_divide():
+    with pytest.raises(ValueError, match="rows=600"):
+        gm.grouped_matmul(jnp.zeros((600, 64)), jnp.zeros((2, 64, 64)),
+                          jnp.asarray([300, 300], jnp.int32))
+
+
+# --- the expert layer -------------------------------------------------------
+
+def _loop_oracle(h, top_p, top_i, layer):
+    """Every expert in a Python loop over the tokens that chose it."""
+    out = jnp.zeros_like(h)
+    for e in range(layer["w_gate"].shape[0]):
+        weight = jnp.sum(jnp.where(top_i == e, top_p, 0.0), axis=-1)
+        gate = h @ layer["w_gate"][e]
+        y = (jax.nn.silu(gate) * (h @ layer["w_up"][e])) @ layer["w_down"][e]
+        out = out + weight[:, None] * y
+    return out
+
+
+def _layer(experts=8, d=32, f=16, seed=0):
+    k = jax.random.split(jax.random.key(seed), 4)
+    return {"router": jax.random.normal(k[0], (d, experts)) * d ** -0.5,
+            "w_gate": jax.random.normal(k[1], (experts, d, f)) * d ** -0.5,
+            "w_up": jax.random.normal(k[2], (experts, d, f)) * d ** -0.5,
+            "w_down": jax.random.normal(k[3], (experts, f, d)) * f ** -0.5}
+
+
+def _choices(kind, n=64, experts=8, k=2):
+    """[n, k] distinct experts per token."""
+    rng = np.random.default_rng(0)
+    if kind == "uniform":
+        pick = [rng.choice(experts, k, replace=False) for _ in range(n)]
+    elif kind == "skewed":       # 90% of the tokens take experts 0 and 1
+        pick = [[0, 1] if rng.random() < 0.9
+                else rng.choice(experts, k, replace=False) for _ in range(n)]
+    else:                        # nobody takes expert 3 or expert 7
+        allowed = [e for e in range(experts) if e not in (3, 7)]
+        pick = [rng.choice(allowed, k, replace=False) for _ in range(n)]
+    return jnp.asarray(np.asarray(pick), jnp.int32)
+
+
+@pytest.mark.parametrize("kind", ("uniform", "skewed", "empty_experts"))
+def test_expert_layer_matches_a_per_expert_loop(small_tiles, kind):
+    """Forward and every gradient (tokens, routing weights, the three
+    expert matrices), whatever the load: nothing dropped, nothing assumed
+    balanced, an expert with no token legal."""
+    layer = _layer()
+    h = jax.random.normal(jax.random.key(5), (64, 32))
+    top_i = _choices(kind)
+    top_p = jax.random.uniform(jax.random.key(6), top_i.shape, minval=0.05)
+
+    def loss(fn):
+        return lambda h, p, l: jnp.sum(jnp.sin(fn(h, p, top_i, l)))
+
+    counts = jnp.bincount(top_i.reshape(-1), length=8).astype(jnp.int32)
+    run = lambda h, p, i, l: moe.experts_ffn(h, p, i, counts, l,
+                                             jnp.float32)
+    got, got_g = jax.value_and_grad(loss(run), (0, 1, 2))(h, top_p, layer)
+    want, want_g = jax.value_and_grad(loss(_loop_oracle), (0, 1, 2))(
+        h, top_p, layer)
+    assert abs(got - want) <= F32_RTOL * abs(want) + 1e-5
+    flat_got, _ = jax.tree_util.tree_flatten(got_g)
+    flat_want, _ = jax.tree_util.tree_flatten(want_g)
+    for g, w in zip(flat_got, flat_want):
+        np.testing.assert_allclose(g, w, atol=5e-5)
+    if kind == "empty_experts":
+        assert not np.asarray(got_g[2]["w_down"])[[3, 7]].any()
+
+
+def test_route_is_float32_softmax_then_topk_and_ties_take_the_lower_index():
+    layer = _layer()
+    # Experts 2 and 5 share a router column: their scores tie everywhere.
+    router = layer["router"].at[:, 5].set(layer["router"][:, 2]) * 1.5
+    h = jax.random.normal(jax.random.key(7), (64, 32)).astype(jnp.bfloat16)
+    top_p, top_i, stats = moe.route(h, router, 3, False)
+    logits = np.asarray(h, np.float64) @ np.asarray(router, np.float64)
+    probs = np.exp(logits - logits.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    order = np.argsort(-probs, axis=-1, kind="stable")[:, :3]
+    np.testing.assert_array_equal(top_i, order)
+    both = (order == 2).any(-1) & (order == 5).any(-1)
+    assert both.any()            # the tie was among the chosen somewhere
+    want = np.take_along_axis(probs, order, axis=-1)
+    assert top_p.dtype == jnp.float32
+    np.testing.assert_allclose(top_p, want, rtol=1e-5)
+    # Not renormalised: the kept probabilities sum to less than one.
+    assert np.asarray(top_p).sum(-1).max() < 0.999
+    # The same softmax in bfloat16 fails that tolerance by far.
+    low = jax.nn.softmax(jnp.asarray(logits, jnp.bfloat16), axis=-1)
+    low = np.take_along_axis(np.asarray(low, np.float64), order, axis=-1)
+    assert np.abs(low / want - 1.0).max() > 1e-3
+    np.testing.assert_allclose(stats.counts.sum(), 64 * 3)
+    np.testing.assert_allclose(stats.prob_sum, probs.sum(0), rtol=1e-5)
+    renorm, _, _ = moe.route(h, router, 3, True)
+    np.testing.assert_allclose(renorm.sum(-1), 1.0, rtol=1e-6)
+
+
+def test_whole_layer_gradient_reaches_the_router(small_tiles):
+    """Through the combine's weights: router, softmax, top-k, experts,
+    against the same mathematics written densely."""
+    layer = _layer()
+    cfg = dataclasses.replace(OLMOE_TINY, d_model=32, d_expert=16)
+    h = jax.random.normal(jax.random.key(8), (64, 32))
+
+    def dense(h, layer):
+        probs = jax.nn.softmax(h @ layer["router"], axis=-1)
+        kth = jnp.sort(probs, axis=-1)[:, -cfg.experts_per_token]
+        weights = jnp.where(probs >= kth[:, None], probs, 0.0)
+        ys = jnp.einsum(
+            "nef,efd->ned",
+            jax.nn.silu(jnp.einsum("nd,edf->nef", h, layer["w_gate"]))
+            * jnp.einsum("nd,edf->nef", h, layer["w_up"]), layer["w_down"])
+        return jnp.einsum("ne,ned->nd", weights, ys)
+
+    f = lambda h, l: jnp.sum(jnp.sin(moe.moe_ffn(h, l, cfg)[0]))
+    g = lambda h, l: jnp.sum(jnp.sin(dense(h, l)))
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(f, (0, 1))(h, layer)
+        want = jax.grad(g, (0, 1))(h, layer)
+    assert np.abs(np.asarray(want[1]["router"])).max() > 1e-3
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+def test_router_losses_against_hand_values():
+    """E = 4, k = 2, three tokens.  Token logits chosen so that softmax
+    is exactly (1/2, 1/4, 1/8, 1/8), its permutation, and uniform."""
+    ln2 = np.log(2.0)
+    logits = jnp.asarray([[3 * ln2, 2 * ln2, ln2, ln2],
+                          [ln2, ln2, 2 * ln2, 3 * ln2],
+                          [0.0, 0.0, 0.0, 0.0]], jnp.float32)
+    _, top_i, stats = moe.route(jnp.eye(3, dtype=jnp.float32), logits, 2,
+                                False)
+    np.testing.assert_array_equal(top_i, [[0, 1], [3, 2], [0, 1]])
+    balance, z = moe.router_losses([stats], 3)
+    # f = assignments per token = (2, 2, 1, 1) / 3;
+    # P = mean probability = (7/8, 3/4, 5/8, 3/4) / 3.
+    f = np.array([2, 2, 1, 1]) / 3
+    p = np.array([0.5 + 0.125 + 0.25, 0.25 + 0.125 + 0.25,
+                  0.125 + 0.25 + 0.25, 0.125 + 0.5 + 0.25]) / 3
+    np.testing.assert_allclose(balance, 4 * np.sum(f * p), rtol=1e-6)
+    # logsumexp: ln 16, ln 16, ln 4.
+    np.testing.assert_allclose(
+        z, (2 * np.log(16.0) ** 2 + np.log(4.0) ** 2) / 3, rtol=1e-6)
+    # Two layers together: sums add, tokens add.
+    both, z2 = moe.router_losses([stats, stats], 6)
+    np.testing.assert_allclose([both, z2], [balance, z], rtol=1e-6)
+    # A uniform router over E experts reads k.
+    _, _, flat = moe.route(jnp.ones((8, 1)), jnp.zeros((1, 4)), 2, False)
+    np.testing.assert_allclose(moe.router_losses([flat], 8)[0], 2.0,
+                               rtol=1e-6)
+
+
+# --- the block, against the plain reference --------------------------------
+
+def test_rotary_matches_the_reference_formula():
+    x = jax.random.normal(jax.random.key(3), (2, 16, 2, 32))
+    got = tfm._rotary(x, jnp.arange(5, 21), 10000.0)
+    # The reference rotates one sequence from position 0: take rows 5..20
+    # of a longer one.
+    longer = jnp.concatenate([jnp.zeros((5, 2, 32)), x[0]], axis=0)
+    np.testing.assert_allclose(got[0], reference._rope(longer, 10000.0)[5:],
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype,loss_rtol,grad_rel", [
+    # float32 against float32: rounding alone.
+    (jnp.float32, F32_RTOL, 5e-5),
+    # bfloat16 compute: three digits, and top-2-of-8 choices flip on a
+    # few of 128 tokens, which the router's gradient feels most.
+    (jnp.bfloat16, 3e-3, 0.5),
+], ids=("float32", "bfloat16"))
+def test_loss_and_tail_gradients_match_the_reference(dtype, loss_rtol,
+                                                     grad_rel):
+    """Rotary, QK-norm, SwiGLU experts, untied head, both router losses:
+    the program's total loss and the gradients of the final norm, the
+    last ``w_down`` and the last router against the plain reference."""
+    cfg = dataclasses.replace(OLMOE_TINY, dtype=dtype)
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    tokens, labels = _batch(cfg)
+    loss, grads = jax.value_and_grad(tfm.loss_fn)(
+        params, tokens, labels, cfg, attention="local")
+    want, want_g, counts = jax.jit(
+        lambda *a: _reference(cfg, *a))(params, tokens, labels)
+    assert abs(loss - want) <= loss_rtol * abs(want)
+    last = grads["layers"][-1]
+    assert _rel(grads["ln_f_scale"], want_g["ln_f_scale"]) <= grad_rel
+    assert _rel(last["w_down"], want_g["w_down_last"]) <= grad_rel
+    assert _rel(last["router"], want_g["router_last"]) <= grad_rel
+    np.testing.assert_array_equal(counts.sum(1), tokens.size * 2)
+    if dtype == jnp.float32:
+        # The tolerance is tight enough for the precision the block
+        # states: the reference with bfloat16 operands and a bfloat16
+        # router softmax misses it.
+        low, _, _ = jax.jit(lambda *a: _reference(
+            cfg, *a, low_precision=jnp.bfloat16))(params, tokens, labels)
+        assert abs(low - want) > loss_rtol * abs(want)
+
+
+@pytest.mark.parametrize("remat", ("dots", "full"))
+def test_remat_leaves_loss_and_gradients_alone(remat):
+    params = tfm.init_params(jax.random.PRNGKey(0), OLMOE_TINY)
+    tokens, labels = _batch(OLMOE_TINY)
+    run = lambda r: jax.value_and_grad(tfm.loss_fn)(
+        params, tokens, labels, OLMOE_TINY, attention="local", remat=r)
+    (loss, grads), (want, want_g) = run(remat), run("none")
+    np.testing.assert_allclose(loss, want, rtol=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(want_g)):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+
+
+# --- the train step on one and on four devices ------------------------------
+
+@pytest.mark.parametrize("devices,shard_optimizer",
+                         [(1, False), (4, False), (4, True)])
+def test_train_step_takes_the_gradient_of_the_global_batch(
+        hvd, devices, shard_optimizer):
+    """Loss = the reference's on the whole batch; update = -lr x the
+    reference's gradient of the **global** batch mean (a step N times too
+    large, PR 21's bug, reads N - 1; a load-balancing loss taken per
+    shard instead of over the batch reads ~1e-2 on the router)."""
+    from horovod_tpu.topology import build_mesh
+
+    cfg, lr = OLMOE_TINY, 0.1
+    mesh = build_mesh(axes=("data",), devices=jax.devices()[:devices])
+    optimizer = optax.sgd(lr)
+    step, _, _ = tfm.make_train_step(
+        cfg, optimizer, mesh, attention="local", donate=False,
+        shard_optimizer=shard_optimizer)
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    tokens, labels = _batch(cfg, batch=8)
+    opt_state = (step.init if shard_optimizer else optimizer.init)(params)
+    new, _, loss = step(params, opt_state, tokens, labels)
+    want, want_g, _ = jax.jit(lambda *a: _reference(cfg, *a))(
+        params, tokens, labels)
+    assert abs(loss - want) <= F32_RTOL * abs(want)
+    pairs = {"ln_f_scale": (new["ln_f_scale"], params["ln_f_scale"]),
+             "w_down_last": (new["layers"][-1]["w_down"],
+                             params["layers"][-1]["w_down"]),
+             "router_last": (new["layers"][-1]["router"],
+                             params["layers"][-1]["router"])}
+    for name, (after, before) in pairs.items():
+        # (after - before) / -lr loses three digits to the subtraction.
+        assert _rel((after - before) / -lr, want_g[name]) <= 2e-3, name
+
+
+def test_sequence_axis_offsets_the_rotary_positions(hvd):
+    """Two sequence shards (ring attention) see positions 0..T/2-1 and
+    T/2..T-1; the loss is the single-device loss."""
+    from horovod_tpu.topology import build_mesh
+
+    cfg = OLMOE_TINY
+    mesh = build_mesh(axes=("data", "seq"), shape=(2, 2),
+                      devices=jax.devices()[:4])
+    optimizer = optax.sgd(0.1)
+    step, _, _ = tfm.make_train_step(cfg, optimizer, mesh, seq_axis="seq",
+                                     attention="ring", donate=False)
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    tokens, labels = _batch(cfg)
+    _, _, loss = step(params, optimizer.init(params), tokens, labels)
+    want = tfm.loss_fn(params, tokens, labels, cfg, attention="local")
+    np.testing.assert_allclose(loss, want, rtol=F32_RTOL)
+
+
+DENSE_NEW = dataclasses.replace(
+    OLMOE_TINY, n_experts=0, experts_per_token=0, d_expert=0,
+    router_aux_coef=0.0, router_z_coef=0.0, d_ff=96, qk_norm=False)
+
+
+def test_model_axis_runs_rotary_swiglu_and_the_untied_head(hvd):
+    from jax.sharding import PartitionSpec as P
+    from horovod_tpu.topology import build_mesh
+
+    cfg = DENSE_NEW
+    mesh = build_mesh(axes=("data", "model"), shape=(2, 2),
+                      devices=jax.devices()[:4])
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    tokens, _ = _batch(cfg)
+    sharded = jax.jit(jax.shard_map(
+        lambda p, t: tfm.forward(p, t, cfg, model_axis="model",
+                                 attention="local"),
+        mesh=mesh, in_specs=(tfm.param_specs(cfg, "model"), P("data")),
+        out_specs=P("data"), check_vma=False))
+    want = tfm.forward(params, tokens, cfg, attention="local")
+    np.testing.assert_allclose(sharded(params, tokens), want, atol=2e-4)
+
+
+def test_decode_runs_qk_norm_swiglu_and_the_untied_head(hvd):
+    cfg = dataclasses.replace(DENSE_NEW, positions="learned", qk_norm=True)
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    tokens, _ = _batch(cfg, batch=2, seq=8)
+    want = tfm.forward(params, tokens, cfg, attention="local")
+    cache = tfm.init_kv_cache(cfg, 2, 8)
+    for pos in range(8):
+        logits, cache = tfm.decode_step(params, tokens[:, pos], cache, pos,
+                                        cfg)
+        np.testing.assert_allclose(logits, want[:, pos], atol=2e-4)
+
+
+# --- refusals: never a silent fall back to the dense block -------------------
+
+def test_model_axis_refuses_qk_norm_and_experts(hvd):
+    from horovod_tpu.topology import build_mesh
+
+    mesh = build_mesh(axes=("data", "model"), shape=(2, 2),
+                      devices=jax.devices()[:4])
+    for cfg, field in ((OLMOE_TINY, "qk_norm"),
+                       (dataclasses.replace(OLMOE_TINY, qk_norm=False),
+                        "n_experts")):
+        with pytest.raises(NotImplementedError, match=field):
+            tfm.make_train_step(cfg, optax.sgd(0.1), mesh,
+                                model_axis="model")
+
+
+@pytest.mark.parametrize("field,value", [
+    ("positions", "rope"), ("qk_norm", True), ("tie_embeddings", False),
+    ("mlp", "swiglu")])
+def test_pipelined_step_refuses_every_new_field(hvd, field, value):
+    from horovod_tpu.topology import build_mesh
+
+    cfg = dataclasses.replace(
+        tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
+                              n_layers=2, d_ff=64, max_seq=16),
+        **{field: value})
+    mesh = build_mesh(axes=("data", "pipe"), shape=(2, 2),
+                      devices=jax.devices()[:4])
+    with pytest.raises(NotImplementedError, match=field):
+        tfm.make_train_step_pipelined(cfg, optax.sgd(0.1), mesh)
+
+
+def test_pipelined_step_and_decode_refuse_experts_and_rotary(hvd):
+    from horovod_tpu.topology import build_mesh
+
+    mesh = build_mesh(axes=("data", "pipe"), shape=(2, 2),
+                      devices=jax.devices()[:4])
+    with pytest.raises(NotImplementedError, match="positions|n_experts"):
+        tfm.make_train_step_pipelined(OLMOE_TINY, optax.sgd(0.1), mesh)
+    params = tfm.init_params(jax.random.PRNGKey(0), OLMOE_TINY)
+    cache = tfm.init_kv_cache(OLMOE_TINY, 2, 8)
+    token = jnp.zeros((2,), jnp.int32)
+    with pytest.raises(NotImplementedError, match="positions"):
+        tfm.decode_step(params, token, cache, 0, OLMOE_TINY)
+    learned = dataclasses.replace(OLMOE_TINY, positions="learned")
+    with pytest.raises(NotImplementedError, match="n_experts"):
+        tfm.decode_step(tfm.init_params(jax.random.PRNGKey(0), learned),
+                        token, cache, 0, learned)
+
+
+@pytest.mark.parametrize("fields,message", [
+    (dict(positions="alibi"), "positions"),
+    (dict(mlp="relu"), "mlp"),
+    (dict(n_experts=8, experts_per_token=2, d_expert=16), "SwiGLU"),
+    (dict(mlp="swiglu", n_experts=4, experts_per_token=5, d_expert=16),
+     "experts_per_token"),
+    (dict(mlp="swiglu", n_experts=4, experts_per_token=2), "d_expert"),
+    (dict(router_aux_coef=0.01), "n_experts"),
+])
+def test_config_refuses_what_it_cannot_mean(fields, message):
+    with pytest.raises(ValueError, match=message):
+        tfm.TransformerConfig(**fields)
+
+
+# --- the default is today's GPT-2 block --------------------------------------
+
+def test_default_config_is_todays_gpt2_block(hvd):
+    from horovod_tpu.topology import build_mesh
+
+    cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
+                                n_layers=2, d_ff=64, max_seq=16)
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    assert sorted(params) == ["embed", "layers", "ln_f_scale", "pos"]
+    assert sorted(params["layers"][0]) == [
+        "ln1_scale", "ln2_scale", "w1", "w2", "wk", "wo", "wq", "wv"]
+    specs = tfm.param_specs(cfg, None)
+    assert (jax.tree_util.tree_structure(specs, is_leaf=lambda x: x is None)
+            .num_leaves == len(jax.tree_util.tree_leaves(params)))
+    mesh = build_mesh(axes=("data",), devices=jax.devices()[:4])
+    optimizer = optax.sgd(0.1)
+    step, _, _ = tfm.make_train_step(cfg, optimizer, mesh, attention="local")
+    tokens = jax.ShapeDtypeStruct((8, 16), jnp.int32)
+    text = step.lower(tfm.init_abstract(cfg),
+                      jax.eval_shape(optimizer.init, tfm.init_abstract(cfg)),
+                      tokens, tokens).as_text()
+    # No rotary, no QK-norm, no routing in the lowered step.
+    for absent in ("sine", "cosine", "stablehlo.sort", "top_k"):
+        assert absent not in text, absent
+    moe_text = jax.jit(lambda p, t: tfm.loss_fn(
+        p, t, t, OLMOE_TINY, attention="local")).lower(
+        tfm.init_abstract(OLMOE_TINY), tokens).as_text()
+    for present in ("sine", "cosine", "stablehlo.sort", "top_k"):
+        assert present in moe_text, present
+
+
+def test_assignments_counter_counts_tokens_times_k(hvd):
+    from horovod_tpu import telemetry
+
+    telemetry.configure(True)
+    try:
+        telemetry.reset_for_tests()
+        telemetry.configure(True)
+        params = tfm.init_abstract(OLMOE_TINY)
+        tokens = jax.ShapeDtypeStruct((4, 32), jnp.int32)
+        jax.eval_shape(lambda p, t: tfm.loss_fn(
+            p, t, t, OLMOE_TINY, attention="local"), params, tokens)
+        snapshot = telemetry.metrics_snapshot()
+        series = {k: v for k, v in snapshot.items()
+                  if "hvd_moe_assignments_total" in k}
+        assert series, sorted(snapshot)
+        text = telemetry.render_prometheus()
+        for layer in (0, 1):
+            assert (f'hvd_moe_assignments_total{{layer="{layer}"}} 256'
+                    in text), text
+    finally:
+        telemetry.reset_for_tests()

@@ -65,13 +65,19 @@ def _unplaced(text):
     return out
 
 
-def _lm_step_text(attention, remat, shard_optimizer, seq_axis=None):
+MOE = dict(positions="rope", qk_norm=True, tie_embeddings=False,
+           mlp="swiglu", n_experts=4, experts_per_token=2, d_expert=64,
+           router_aux_coef=0.01, router_z_coef=0.001)
+
+
+def _lm_step_text(attention, remat, shard_optimizer, seq_axis=None,
+                  **fields):
     from horovod_tpu.models import transformer as tfm
     from horovod_tpu.topology import build_mesh
 
     cfg = tfm.TransformerConfig(vocab_size=256, d_model=64, n_heads=2,
                                 n_layers=2, d_ff=128, max_seq=128,
-                                dtype=jnp.bfloat16)
+                                dtype=jnp.bfloat16, **fields)
     axes = ("data", seq_axis) if seq_axis else ("data",)
     mesh = build_mesh(axes=axes, shape=(2, 2) if seq_axis else None,
                       devices=jax.devices()[:4])
@@ -129,6 +135,37 @@ def test_lm_step_carries_the_vocabulary(hvd, attention, remat,
                                         shard_optimizer):
     _check_lm(_lm_step_text(attention, remat, shard_optimizer), attention,
               remat, shard_optimizer)
+
+
+MOE_PARTS = (scopes.MOE_ROUTER, scopes.MOE_DISPATCH, scopes.MOE_EXPERTS,
+             scopes.MOE_COMBINE)
+
+
+@pytest.mark.parametrize(
+    "attention,remat,shard_optimizer",
+    [("local", "none", False), ("flash", "none", False),
+     ("local", "full", False), ("local", "none", True)])
+def test_moe_step_carries_the_vocabulary_and_its_parts_under_mlp(
+        hvd, attention, remat, shard_optimizer):
+    """The expert layer's four parts open *under* ``mlp``, forward and
+    backward, so the benchmark's fixed vocabulary still places every
+    executed op (and answers ``mlp``), while ``perfbench/moe_reduce.py``
+    reads the part one level down."""
+    from perfbench import moe_reduce
+
+    text = _lm_step_text(attention, remat, shard_optimizer, **MOE)
+    _check_lm(text, attention, remat, shard_optimizer)
+    names = _op_names(text)
+    for part in MOE_PARTS:
+        inside = f"{scopes.MLP}/{part}"
+        assert _under(names, part, inside, "jvp("), part
+        assert _under(names, part, inside, "transpose("), part
+        assert not any(part in n and inside not in n for n in names), part
+    hlo = scope_reduce.parse_hlo(text)
+    parts = {moe_reduce.part_of(moe_reduce.op_name_of(name, hlo))
+             for name, i in hlo.instructions.items()
+             if i.opcode in HELD}
+    assert set(MOE_PARTS) <= parts
 
 
 @pytest.mark.parametrize("attention", ("ring", "ulysses"))
@@ -218,10 +255,28 @@ def test_the_benchmark_reads_the_same_vocabulary():
     kernels = {scopes.FLASH_FWD, scopes.FLASH_BWD_DQ, scopes.FLASH_BWD_DKV}
     modules = {scopes.LM_TRAIN_STEP, scopes.LM_PIPELINED_TRAIN_STEP,
                scopes.TRAIN_STEP}
+    # A group of their own: the expert layer's parts are sub-scopes of
+    # ``mlp`` and its grouped-matmul kernels are named for
+    # ``perfbench/moe_reduce.py`` and the ``moe_lm`` adapter; the fixed
+    # vocabulary does not hold them and does not need to.
+    moe_parts = set(MOE_PARTS)
+    moe_kernels = {scopes.MOE_GMM, scopes.MOE_GMM_NT, scopes.MOE_TGMM}
     assert set(scope_reduce.KERNEL_NAMES) == kernels
     assert (set(scope_reduce.MODEL_SCOPES + scope_reduce.GRAD_MEAN_SCOPES
                 + scope_reduce.OPTIMIZER_SCOPES)
-            == program - kernels - modules - {scopes.LAYER})
+            == program - kernels - modules - {scopes.LAYER} - moe_parts
+            - moe_kernels)
     assert scope_reduce.scope_of(
         f"jit(x)/jvp({scopes.LAYER % 3})/{scopes.MLP}/dot_general"
     ) == scopes.MLP
+    from perfbench import moe_reduce
+    assert set(moe_reduce.SUB_SCOPES) == moe_parts
+    inside = (f"jit(x)/transpose(jvp({scopes.LAYER % 3}))/{scopes.MLP}/"
+              f"{scopes.MOE_EXPERTS}/dot_general")
+    assert scope_reduce.scope_of(inside) == scopes.MLP
+    assert scope_reduce.phase_of(inside) == "bwd"
+    assert moe_reduce.part_of(inside) == scopes.MOE_EXPERTS
+    assert moe_reduce.part_of(
+        f"jit(x)/jvp({scopes.LAYER % 0})/{scopes.MLP}/add"
+    ) == moe_reduce.OTHER
+    assert moe_reduce.part_of(f"jit(x)/{scopes.HEAD}/dot_general") is None
