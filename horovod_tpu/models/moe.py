@@ -34,7 +34,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu import telemetry
-from horovod_tpu.ops.grouped_matmul import grouped_matmul
+from horovod_tpu.ops.grouped_matmul import (grouped_matmul,
+                                            worst_matmul_rows)
 from horovod_tpu.telemetry import scopes
 
 
@@ -165,14 +166,25 @@ def moe_ffn(h, layer, cfg):
     return y.reshape(h.shape), stats
 
 
-def record_assignments(layer: int, assignments: int) -> None:
-    """Trace-time counter (like ``hvd_flash_blocks_total``: what was
+def record_assignments(layer: int, assignments: int, experts: int) -> None:
+    """Trace-time series (like ``hvd_flash_blocks_total``: what was
     compiled into the step, not per-step traffic): the (token, expert)
-    assignments layer ``layer`` computes per step on one device.  Static:
-    ``tokens * experts_per_token``, since nothing is dropped."""
-    if telemetry.enabled():
-        telemetry.counter(
-            "hvd_moe_assignments_total",
-            "(token, expert) assignments the traced MoE layer computes per "
-            "step on one device; dropless, so tokens x experts_per_token",
-            layer=str(layer)).inc(assignments)
+    assignments layer ``layer`` computes per step on one device — static,
+    ``tokens * experts_per_token``, since nothing is dropped — and the
+    most rows the grouped matmuls can multiply for one they need, which
+    is all that is static of it: how the assignments fall on the
+    ``experts`` is data (``grouped_matmul.matmul_rows``)."""
+    if not telemetry.enabled():
+        return
+    telemetry.counter(
+        "hvd_moe_assignments_total",
+        "(token, expert) assignments the traced MoE layer computes per "
+        "step on one device; dropless, so tokens x experts_per_token",
+        layer=str(layer)).inc(assignments)
+    telemetry.gauge(
+        "hvd_moe_gmm_rows_computed_over_needed",
+        "Rows the most recently traced MoE layer's grouped matmuls "
+        "multiply over the rows they need, at worst: every expert but "
+        "the first starts inside a sub-tile (1.0 = no masked work)",
+        bound="worst").set(
+            worst_matmul_rows(experts, assignments) / assignments)

@@ -455,7 +455,7 @@ def forward_with_router_stats(params, tokens, cfg: TransformerConfig,
         if cfg.n_experts:
             router_stats.append(stats)
             moe.record_assignments(
-                i, tokens.size * cfg.experts_per_token)
+                i, tokens.size * cfg.experts_per_token, cfg.n_experts)
 
     return _logits_head(x, params, cfg), router_stats
 

@@ -11,11 +11,22 @@ a tile that two groups share is visited twice and each visit writes only
 its own rows.  Nothing is padded to a capacity: a step costs
 ``M / tm + (groups that start inside a tile)`` visits whatever the sizes.
 
+That is true of visits, not of work.  A visit whose group owns the whole
+tile multiplies it in one piece.  A visit to a tile its group shares works
+on *sub-tiles* of ``SUB_M`` rows and multiplies only those in which the
+group has rows: one that is wholly the group's is multiplied and stored (or
+accumulated) as it is, one that holds a group boundary is multiplied and
+then masked, one that is another visit's costs nothing.  A tile with ``b``
+boundaries inside it costs ``tm / SUB_M + b`` sub-tile matmuls over its
+``b + 1`` visits at most, where whole-tile visits would cost
+``(tm / SUB_M) (b + 1)``; :func:`matmul_rows` is the exact count.
+
 Why not the library call: its ``pallas_call`` carries no ``vma`` on its
 outputs, so it cannot be traced inside the training step's
 ``shard_map(check_vma=True)``; it takes no ``name=``; and its default
 tiling of 128 is ten times slower at the OLMoE shapes than the one chosen
-here (PERF.md, PR 26, where :func:`jax.lax.ragged_dot` is measured too).
+here (PERF.md, PR 26, where :func:`jax.lax.ragged_dot` is measured too;
+the sweeps are in ``docs/kernels.md``).
 This file keeps what the layer needs — no group offset, no sharded
 groups, no existing output — and adds those three things.
 
@@ -40,8 +51,19 @@ from horovod_tpu.telemetry import scopes
 
 # Row, contraction and output tile of all three kernels, chosen on the
 # chip at 65,536 rows in 64 groups, K/N = 2048/1024 and 1024/2048, bf16
-# (PERF.md, PR 26).  A smaller dimension is one tile.
-TILE_M, TILE_K, TILE_N = 512, 1024, 1024
+# (PERF.md, PR 26 and PR 28).  A smaller dimension is one tile.  K and N
+# as wide as the OLMoE matrices: a group's weights then stay in VMEM over
+# its visits, where a K tile of 1024 fetched them anew on every grid step
+# and made a visit that multiplies little wait for 3 MB all the same.
+TILE_M, TILE_K, TILE_N = 512, 2048, 2048
+# Rows of a sub-tile, the unit in which a visit to a shared tile skips
+# rows that are not its group's: the MXU's height, and whole (16, 128)
+# tiles of bf16.  A smaller row tile is one sub-tile.
+SUB_M = 128
+# The blocks of those tiles, double-buffered, and the accumulator take up
+# to 40 MB (the weight gradient at K = N = 2048); the compiler's default
+# allowance is 16 MiB of the core's 128.
+VMEM_LIMIT_BYTES = 96 * 2 ** 20
 
 
 def _tile(dim: int, tile: int, what: str) -> int:
@@ -79,37 +101,107 @@ def _visits(group_sizes, m: int, tm: int, visit_empty: bool):
             visit_tile.astype(jnp.int32), jnp.sum(count))
 
 
-def _own_rows(offsets, group, tile, tm: int, shape):
-    """Mask of ``shape`` ([tm, 1] or [tm, n]): the rows of row tile
-    ``tile`` that belong to ``group``."""
-    rows = tile * tm + lax.broadcasted_iota(jnp.int32, shape, 0)
-    return (rows >= offsets[group]) & (rows < offsets[group + 1])
+def _row_tiles(m: int):
+    """``(tm, sub)``: the row tile of ``m`` rows and its sub-tile."""
+    tm = _tile(m, TILE_M, "rows")
+    return tm, _tile(tm, SUB_M, "the row tile")
+
+
+def matmul_rows(group_sizes, m: int):
+    """Rows the matmuls of one kernel run over, per K and N tile, for
+    these ``group_sizes`` [G] (they sum to ``m``): a group is multiplied
+    in every sub-tile in which it has rows, so ``m`` when every group
+    starts on a sub-tile's edge and at most ``m + (G - 1) * sub``
+    (:func:`worst_matmul_rows`).  The exact model of the kernels'
+    skipping rule, the same for all three; jit-able."""
+    _, sub = _row_tiles(m)
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    spans = jnp.where(group_sizes > 0,
+                      (ends - 1) // sub - starts // sub + 1, 0)
+    return sub * jnp.sum(spans)
+
+
+def worst_matmul_rows(groups: int, m: int) -> int:
+    """The most :func:`matmul_rows` can return: every group but the
+    first starts inside a sub-tile."""
+    return m + (groups - 1) * _row_tiles(m)[1]
+
+
+def _for_each_piece(offsets, group, tile, tm: int, sub: int, work):
+    """Row tile ``tile`` as ``group``'s visit works on it, in pieces:
+    calls ``work(rows, whole, split, mask)`` for each.  ``rows`` slices
+    the piece out of a block.  ``whole``: the visit multiplies the piece
+    and every row of it is the group's.  ``split``: it multiplies the
+    piece and only the rows that ``mask(n)`` ([rows, n]) marks are the
+    group's.  Neither: the piece is another visit's.  First the tile
+    itself, ``whole`` for the group that owns all of it (one matmul, as
+    high as feeds the MXU best) and never ``split`` (no ``mask``); then,
+    for a group that shares the tile, its sub-tiles."""
+    lo, hi = offsets[group], offsets[group + 1]
+    owns_tile = (lo <= tile * tm) & (hi >= tile * tm + tm)
+    work(pl.ds(0, tm), owns_tile, jnp.bool_(False), None)
+
+    # A loop, not tm / sub copies: the body is as long as the matmul is
+    # wide.
+    def sub_tile(s, carry):
+        start = pl.multiple_of(s * sub, sub)
+        first = tile * tm + start
+        whole = (lo <= first) & (hi >= first + sub)
+        owned = jnp.maximum(lo, first) < jnp.minimum(hi, first + sub)
+
+        def mask(n):
+            rows = first + lax.broadcasted_iota(jnp.int32, (sub, n), 0)
+            return (rows >= lo) & (rows < hi)
+
+        work(pl.ds(start, sub), whole & ~owns_tile, owned & ~whole, mask)
+        return carry
+
+    lax.fori_loop(0, tm // sub, sub_tile, None)
+
+
+def _dot(rows, other, contract):
+    """MXU matmul of a piece's ``rows`` with float32 accumulation."""
+    return lax.dot_general(rows, other, (contract, ((), ())),
+                           preferred_element_type=jnp.float32)
 
 
 def _gmm_kernel(offsets, visit_group, visit_tile, lhs, rhs, out, acc, *,
-                tm, tiles_k, transpose_rhs):
+                tm, sub, tiles_k, transpose_rhs):
     visit, k_i = pl.program_id(1), pl.program_id(2)
+    contract = ((1,), (1,)) if transpose_rhs else ((1,), (0,))
+    last_k = k_i == tiles_k - 1
 
     @pl.when(k_i == 0)
     def _zero():
         acc[...] = jnp.zeros_like(acc)
 
-    contract = ((1,), (1,)) if transpose_rhs else ((1,), (0,))
-    acc[...] += lax.dot_general(lhs[...], rhs[...], (contract, ((), ())),
-                                preferred_element_type=jnp.float32)
+    def work(rows, whole, split, mask):
+        @pl.when(whole | split)
+        def _multiply():
+            acc[rows, :] += _dot(lhs[rows, :], rhs[...], contract)
 
-    @pl.when(k_i == tiles_k - 1)
-    def _store():
-        # Only this group's rows: the tile's other rows are another
-        # visit's, before or after this one.
-        mask = _own_rows(offsets, visit_group[visit], visit_tile[visit],
-                         tm, acc.shape)
-        out[...] = jnp.where(mask, acc[...],
-                             out[...].astype(jnp.float32)).astype(out.dtype)
+        @pl.when(last_k & whole)
+        def _store():
+            out[rows, :] = acc[rows, :].astype(out.dtype)
+
+        if mask is None:
+            return
+
+        @pl.when(last_k & split)
+        def _store_own_rows():
+            # The piece's other rows are another visit's, before or
+            # after this one.
+            kept = out[rows, :].astype(jnp.float32)
+            out[rows, :] = jnp.where(mask(kept.shape[1]), acc[rows, :],
+                                     kept).astype(out.dtype)
+
+    _for_each_piece(offsets, visit_group[visit], visit_tile[visit], tm, sub,
+                    work)
 
 
 def _tgmm_kernel(offsets, visit_group, visit_tile, lhs, rhs, out, acc, *,
-                 tm):
+                 tm, sub):
     visit, last = pl.program_id(2), pl.num_programs(2) - 1
     group = visit_group[visit]
     before = visit_group[jnp.maximum(visit - 1, 0)]
@@ -119,13 +211,22 @@ def _tgmm_kernel(offsets, visit_group, visit_tile, lhs, rhs, out, acc, *,
     def _zero():
         acc[...] = jnp.zeros_like(acc)
 
-    @pl.when(offsets[group + 1] > offsets[group])
-    def _accumulate():
-        mask = _own_rows(offsets, group, visit_tile[visit], tm,
-                         (lhs.shape[0], 1))
-        own = jnp.where(mask, lhs[...].astype(jnp.float32), 0.0)
-        acc[...] += lax.dot(own.T.astype(rhs.dtype), rhs[...],
-                            preferred_element_type=jnp.float32)
+    # The rows are the contraction: a piece is so many of its terms.
+    def work(rows, whole, split, mask):
+        @pl.when(whole)
+        def _accumulate():
+            acc[...] += _dot(lhs[rows, :], rhs[rows, :], ((0,), (0,)))
+
+        if mask is None:
+            return
+
+        @pl.when(split)
+        def _accumulate_own_rows():
+            own = jnp.where(mask(1), lhs[rows, :].astype(jnp.float32), 0.0)
+            acc[...] += _dot(own.astype(lhs.dtype), rhs[rows, :],
+                             ((0,), (0,)))
+
+    _for_each_piece(offsets, group, visit_tile[visit], tm, sub, work)
 
     @pl.when((visit == last) | (after != group))
     def _store():
@@ -149,11 +250,26 @@ def _interpret(x) -> bool:
     return not exec_on_tpu(x)
 
 
+def _tiles(m: int, k: int, n: int):
+    """``(tm, sub, tk, tn)`` of an [m, k] x [k, n] product."""
+    return (*_row_tiles(m), _tile(k, TILE_K, "K"), _tile(n, TILE_N, "N"))
+
+
+# The calls are jitted with the tiles among their static arguments, and
+# inlined: every product of one shape in a step (gate and up, every
+# layer) is then one traced kernel and one lowering, not one each.
+
 def _gmm(lhs, rhs, group_sizes, transpose_rhs: bool):
-    m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    tm, tk, tn = (_tile(m, TILE_M, "rows"), _tile(k, TILE_K, "K"),
-                  _tile(n, TILE_N, "N"))
+    return _gmm_call(lhs, rhs, group_sizes, transpose_rhs=transpose_rhs,
+                     tiles=_tiles(*lhs.shape, n))
+
+
+@functools.partial(jax.jit, static_argnames=("transpose_rhs", "tiles"),
+                   inline=True)
+def _gmm_call(lhs, rhs, group_sizes, *, transpose_rhs, tiles):
+    (m, k), (tm, sub, tk, tn) = lhs.shape, tiles
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     *metadata, n_visits = _visits(group_sizes, m, tm, visit_empty=False)
     interpret, vma = _interpret(lhs), _vma(lhs, rhs, group_sizes)
     rhs_spec = (
@@ -163,7 +279,7 @@ def _gmm(lhs, rhs, group_sizes, transpose_rhs: bool):
         pl.BlockSpec((None, tk, tn),
                      lambda n_i, v, k_i, off, vg, vt: (vg[v], k_i, n_i)))
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, tm=tm, tiles_k=k // tk,
+        functools.partial(_gmm_kernel, tm=tm, sub=sub, tiles_k=k // tk,
                           transpose_rhs=transpose_rhs),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype, vma=vma),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -179,22 +295,26 @@ def _gmm(lhs, rhs, group_sizes, transpose_rhs: bool):
             grid=(n // tn, n_visits, k // tk),
             scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name=scopes.MOE_GMM_NT if transpose_rhs else scopes.MOE_GMM,
     )(*metadata, lhs, rhs)
 
 
 def _tgmm(lhs, rhs, group_sizes):
-    m, k = lhs.shape
-    n = rhs.shape[1]
+    return _tgmm_call(lhs, rhs, group_sizes,
+                      tiles=_tiles(*lhs.shape, rhs.shape[1]))
+
+
+@functools.partial(jax.jit, static_argnames="tiles", inline=True)
+def _tgmm_call(lhs, rhs, group_sizes, *, tiles):
+    (m, k), n, (tm, sub, tk, tn) = lhs.shape, rhs.shape[1], tiles
     groups = group_sizes.shape[0]
-    tm, tk, tn = (_tile(m, TILE_M, "rows"), _tile(k, TILE_K, "K"),
-                  _tile(n, TILE_N, "N"))
     *metadata, n_visits = _visits(group_sizes, m, tm, visit_empty=True)
     interpret, vma = _interpret(lhs), _vma(lhs, rhs, group_sizes)
     return pl.pallas_call(
-        functools.partial(_tgmm_kernel, tm=tm),
+        functools.partial(_tgmm_kernel, tm=tm, sub=sub),
         out_shape=jax.ShapeDtypeStruct((groups, k, n), lhs.dtype, vma=vma),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -211,7 +331,8 @@ def _tgmm(lhs, rhs, group_sizes):
             grid=(n // tn, k // tk, n_visits),
             scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name=scopes.MOE_TGMM,
     )(*metadata, lhs, rhs)

@@ -12,6 +12,7 @@ router's softmax).
 """
 
 import dataclasses
+import os
 
 import jax
 import jax.numpy as jnp
@@ -55,15 +56,28 @@ def _reference(cfg, params, tokens, labels, **kw):
 
 @pytest.fixture()
 def small_tiles(monkeypatch):
-    """Tiles that make tiny shapes span several tiles and visits."""
+    """Tiles that make tiny shapes span several tiles and visits, each
+    tile in four sub-tiles."""
     monkeypatch.setattr(gm, "TILE_M", 16)
+    monkeypatch.setattr(gm, "SUB_M", 4)
     monkeypatch.setattr(gm, "TILE_K", 32)
     monkeypatch.setattr(gm, "TILE_N", 32)
 
 
 GROUPS = {"ragged": [30, 0, 50, 1, 47, 0], "one_group": [0, 0, 128, 0, 0, 0],
           "tile_aligned": [16, 16, 32, 16, 32, 16],
-          "first_row_alone": [1, 127, 0, 0, 0, 0]}
+          "first_row_alone": [1, 127, 0, 0, 0, 0],
+          # Tiles of 16 rows in sub-tiles of 4: boundaries at 20 and 44,
+          # on a sub-tile's edge inside a tile;
+          "boundary_on_a_sub_tile_edge": [20, 24, 36, 48, 0, 0],
+          # at 19 and 45, one row before and one after such an edge;
+          "boundary_one_row_off_an_edge": [19, 26, 35, 48, 0, 0],
+          # rows 16-17, 18 and 19 of one sub-tile are three groups';
+          "three_groups_in_a_sub_tile": [18, 1, 1, 108, 0, 0],
+          # rows 20-23 are a group: one sub-tile, whole;
+          "group_is_one_sub_tile": [20, 4, 40, 64, 0, 0],
+          # boundaries at 18, 22, 26 and 30: every sub-tile of tile 1.
+          "boundary_in_every_sub_tile": [18, 4, 4, 4, 98, 0]}
 
 
 @pytest.mark.parametrize("sizes", GROUPS.values(), ids=GROUPS.keys())
@@ -105,6 +119,70 @@ def test_grouped_matmul_visits_cover_each_row_once():
     assert (np.diff(tile[:int(n)]) >= 0).all()
     *_, n_all = gm._visits(sizes, 128, 16, visit_empty=True)
     assert int(n_all) == int(n) + 2
+
+
+def test_matmul_rows_is_what_the_kernels_multiply(small_tiles, monkeypatch):
+    """``matmul_rows`` against a count taken inside the interpreter: every
+    matmul a kernel runs reports the rows of its piece, per K and N tile
+    (here 2 and 3 of them)."""
+    seen = []
+    dot = gm._dot
+
+    def counted(rows, other, contract):
+        jax.debug.callback(lambda: seen.append(rows.shape[0]))
+        return dot(rows, other, contract)
+
+    monkeypatch.setattr(gm, "_dot", counted)
+    x = jax.random.normal(jax.random.key(0), (128, 64), jnp.float32)
+    w = jax.random.normal(jax.random.key(1), (6, 64, 96), jnp.float32)
+    jax.clear_caches()       # the calls are jitted: trace them counted,
+    try:
+        for sizes in GROUPS.values():
+            gs = jnp.asarray(sizes, jnp.int32)
+            want = int(gm.matmul_rows(gs, 128))
+            for kernel in (lambda: gm._gmm(x, w, gs, transpose_rhs=False),
+                           lambda: gm._tgmm(x, x @ w[0], gs)):
+                seen.clear()
+                jax.block_until_ready(kernel())
+                jax.effects_barrier()
+                assert sum(seen) == want * 2 * 3, sizes
+            assert 128 <= want <= gm.worst_matmul_rows(6, 128) == 128 + 5 * 4
+    finally:
+        jax.clear_caches()   # and leave no counted trace behind.
+    assert int(gm.matmul_rows(jnp.asarray(GROUPS["tile_aligned"]), 128)) == 128
+    assert int(gm.matmul_rows(
+        jnp.asarray(GROUPS["boundary_in_every_sub_tile"]), 128)) == 128 + 16
+
+
+def test_matmul_rows_at_the_olmoe_cells_shape(monkeypatch):
+    """65,536 rows in 64 groups: 512 sub-tiles of 128 and one more for
+    each of 63 boundaries off an edge, where whole-tile visits (a
+    sub-tile as high as the tile) cost 128 + 63 tiles of 512."""
+    aligned = jnp.full(64, 1024, jnp.int32)
+    ragged = aligned.at[0].add(1).at[-1].add(-1)  # boundaries at 1024 g + 1
+    assert int(gm.matmul_rows(ragged, 65536)) == 575 * 128
+    assert int(jax.jit(gm.matmul_rows, static_argnums=1)(
+        aligned, 65536)) == 65536
+    assert gm.worst_matmul_rows(64, 65536) == 575 * 128
+    monkeypatch.setattr(gm, "SUB_M", gm.TILE_M)
+    assert int(gm.matmul_rows(ragged, 65536)) == 764 * 128
+    assert int(gm.matmul_rows(aligned, 65536)) == 65536
+
+
+@pytest.mark.parametrize("transpose_rhs", (False, True),
+                         ids=("moe_gmm", "moe_gmm_nt"))
+def test_sub_tiles_leave_every_output_bit_alone(small_tiles, monkeypatch,
+                                                transpose_rhs):
+    """Rows are independent and the contraction is cut the same way, so
+    four sub-tiles a tile give the bits of one sub-tile as high as the
+    tile (every visit multiplies its whole tile: the rule before)."""
+    x = jax.random.normal(jax.random.key(0), (128, 64), jnp.float32)
+    w = jax.random.normal(jax.random.key(1), (6, 96, 64) if transpose_rhs
+                          else (6, 64, 96), jnp.float32)
+    gs = jnp.asarray(GROUPS["ragged"], jnp.int32)
+    got = gm._gmm(x, w, gs, transpose_rhs)
+    monkeypatch.setattr(gm, "SUB_M", gm.TILE_M)
+    np.testing.assert_array_equal(got, gm._gmm(x, w, gs, transpose_rhs))
 
 
 def test_grouped_matmul_refuses_a_dimension_its_tile_does_not_divide():
@@ -521,5 +599,31 @@ def test_assignments_counter_counts_tokens_times_k(hvd):
         for layer in (0, 1):
             assert (f'hvd_moe_assignments_total{{layer="{layer}"}} 256'
                     in text), text
+    finally:
+        telemetry.reset_for_tests()
+
+
+def test_rows_computed_over_needed_gauge_is_the_static_worst_case(hvd):
+    from horovod_tpu import telemetry
+
+    telemetry.reset_for_tests()
+    telemetry.configure(True)
+    try:
+        tokens = jax.ShapeDtypeStruct((4, 32), jnp.int32)
+        jax.eval_shape(lambda p, t: tfm.loss_fn(
+            p, t, t, OLMOE_TINY, attention="local"),
+            tfm.init_abstract(OLMOE_TINY), tokens)
+        # 256 assignments, two sub-tiles of 128, over 8 experts: each but
+        # the first may start inside one.  (256 + 7 x 128) / 256.
+        assert ('hvd_moe_gmm_rows_computed_over_needed{bound="worst"} 4.5'
+                in telemetry.render_prometheus())
+        # The olmoe cell: (65536 + 63 x 128) / 65536.
+        moe.record_assignments(0, 65536, 64)
+        assert ('hvd_moe_gmm_rows_computed_over_needed{bound="worst"} '
+                '1.123046875' in telemetry.render_prometheus())
+        doc = open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "docs", "metrics.md")).read()
+        assert "`hvd_moe_gmm_rows_computed_over_needed`" in doc
+        assert "matmul_rows" in doc
     finally:
         telemetry.reset_for_tests()
