@@ -6,7 +6,7 @@
 
 One process drives every chip JAX reports, through the entry points a user
 calls: ``hvd.init()`` -> ``hvd.mesh()`` -> a jitted ``shard_map`` training
-step from the two step builders the benchmarks use.  Phases:
+step from the two step builders the benchmark's cells use.  Phases:
 
 ``flash_kernel``  ``ops.flash_attention`` forward and backward at the LM's
                   attention shape, with and without ``segment_ids``, against
@@ -15,8 +15,8 @@ step from the two step builders the benchmarks use.  Phases:
                   batch 4 per chip, bf16 compute, f32 master weights, SGD
                   with a bf16 momentum slot, ``attention="flash"``), built
                   by ``models.transformer.make_train_step`` through the
-                  state recipe ``benchmark.run_lm_benchmark`` uses
-                  (``make_lm_bench_state``), one step per call.
+                  state recipe ``benchmark.make_lm_bench_state``, one step
+                  per call.
 ``resnet50_dp``   ResNet-50 at 224x224, batch 256 per chip, bf16 input,
                   ``stem="s2d"``: ``benchmark.make_bench_state`` +
                   ``benchmark.make_train_step``.
@@ -73,14 +73,32 @@ def _count_compile(event, duration, **kwargs):
         _backend_compiles += 1
 
 
+def _device_info():
+    """The devices this check runs on, as JAX reports them."""
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices)}
+
+
 def _peak_bytes():
     """Largest ``peak_bytes_in_use`` over the local devices (None where the
     backend reports no memory stats, as the CPU does).  The backend's peak
     is that of the process so far and cannot be reset, so a phase's figure
-    includes the phases before it."""
-    peaks = [row["peak_bytes_in_use"]
-             for row in benchmark._device_memory_report(verbose=False)]
+    includes the phases before it.  It leaves out the temporaries of every
+    loaded program: a footprint is ``perfbench``'s ``peak_hbm_gib``, this
+    only proves the stats are readable."""
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in jax.local_devices()]
     return None if None in peaks else max(peaks)
+
+
+def _tree_bytes_per_device(tree):
+    """Bytes one device holds for ``tree``: per leaf, the first addressable
+    shard's size (a ``P()`` leaf contributes its full size, a ``P(ax)``
+    leaf 1/N)."""
+    return sum(leaf.addressable_shards[0].data.nbytes
+               for leaf in jax.tree_util.tree_leaves(tree))
 
 
 def _held_by(array):
@@ -210,7 +228,7 @@ def phase_lm(name, cfg, mesh, on_tpu, shard_optimizer):
         attention="flash", remat="none", steps_per_call=1, mesh=mesh,
         shard_optimizer=shard_optimizer, **cfg)
     opt_state = state[1]
-    per_device = benchmark._tree_bytes_per_device(opt_state)
+    per_device = _tree_bytes_per_device(opt_state)
     whole = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(opt_state))
     print(f"  LM d{cfg['d_model']}/L{cfg['n_layers']}/H{cfg['n_heads']}/"
           f"T{cfg['seq_len']} batch {cfg['batch_size']} per chip x {n}, "
@@ -248,7 +266,7 @@ def main(argv=None) -> int:
              "(the CPU); prints that it is a rehearsal, never a result")
     args = parser.parse_args(argv)
 
-    device = benchmark.device_info()
+    device = _device_info()
     print(f"device: platform={device['platform']} "
           f"device_kind={device['kind']} count={device['count']}",
           flush=True)

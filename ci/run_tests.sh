@@ -617,39 +617,18 @@ print("fleet-serving summary OK")
 PYEOF
 rm -rf "$SFLEET_DIR"
 
-echo "--- serving benchmark (BENCH json; offered load vs p50/p99 and
---- tokens/s at max_batch=1 vs 8 on a virtual clock — continuous
---- batching must dominate at high offered load)"
-JAX_PLATFORMS=cpu python -m horovod_tpu.benchmark --serving
-
-echo "--- step-guard overhead (BENCH json; target < 2% on real chips —
---- on the CPU smoke this only proves the lane runs end to end)"
-JAX_PLATFORMS=cpu python -m horovod_tpu.benchmark --step-guard
-
-echo "--- compression wire ratio (BENCH json; int8 target >= 3x logical
---- bytes with < 1% loss delta — trace-time counters, so the CPU smoke
---- proves the real ratio, not just that the lane runs)"
-JAX_PLATFORMS=cpu python -m horovod_tpu.benchmark --compression int8
-
-echo "--- hierarchical allreduce A/B (BENCH json; two hvdrun -np 4
---- loopback runs, flat ring vs 2-level; every worker asserts the
---- hier_allreduce knob live in runtime.tuned_config() — on this rig
---- the row bounds software overhead, the transport win is the np=4
---- telemetry gate's exact 1/local_size byte ratio)"
-JAX_PLATFORMS=cpu PYTHONPATH="$PWD" \
-  python -m horovod_tpu.benchmark --hierarchical --out BENCH_hier.json
-
-echo "--- transport backend A/B (BENCH json; six hvdrun -np 2 loopback
---- runs: single socket (CRC-framed + unframed) vs shm ring vs striped
---- x1/x2/x4 — every worker asserts the forced backend carried the
---- bytes, headline ratios come from the thread-CPU link counters so a
---- single-core runner measures the transport, not the scheduler; the
---- checksum A/B bounds the wire-integrity overhead at 64 MB)"
-JAX_PLATFORMS=cpu PYTHONPATH="$PWD" \
-  python -m horovod_tpu.benchmark --transport --out BENCH_transport.json
-python - <<'EOF'
-import json
-doc = json.load(open("BENCH_transport.json"))
+echo "--- transport backend A/B (six hvdrun -np 2 loopback runs of
+--- tools/bench_eager.py: single socket (CRC-framed + unframed) vs shm
+--- ring vs striped x1/x2/x4 — every worker asserts the forced backend
+--- carried the bytes, headline ratios come from the thread-CPU link
+--- counters so a single-core runner measures the transport, not the
+--- scheduler; the checksum A/B bounds the wire-integrity overhead at
+--- 64 MB)"
+TRANSPORT_JSON="$PWD/ci/artifacts/eager/transport.json"
+python tools/bench_eager.py --transport --out "$TRANSPORT_JSON"
+python - "$TRANSPORT_JSON" <<'EOF'
+import json, sys
+doc = json.load(open(sys.argv[1]))
 assert doc["backend_engagement_asserted"]
 assert doc["shm_vs_socket_64mb"] > 1.0, doc["shm_vs_socket_64mb"]
 assert doc["striped4_vs_striped1_64mb"] > 1.0, \
@@ -657,16 +636,10 @@ assert doc["striped4_vs_striped1_64mb"] > 1.0, \
 assert doc["checksum_overhead_64mb"] < 0.05, \
     f"CRC32C framing cost {doc['checksum_overhead_64mb']:.1%} of link " \
     f"bandwidth at 64 MB (target < 5%)"
-print("TRANSPORT_BENCH_OK shm=%.2fx striped4=%.2fx crc_overhead=%.1f%%" %
+print("TRANSPORT_GATE_OK shm=%.2fx striped4=%.2fx crc_overhead=%.1f%%" %
       (doc["shm_vs_socket_64mb"], doc["striped4_vs_striped1_64mb"],
        doc["checksum_overhead_64mb"] * 100))
 EOF
-
-echo "--- coordination message complexity (BENCH json; tree vs flat
---- per-tick fan-in at N in {8,64,256,1024} on the protocol simulator —
---- tree must stay bounded while flat grows linearly)"
-JAX_PLATFORMS=cpu PYTHONPATH="$PWD" \
-  python -m horovod_tpu.benchmark --coordsim --out BENCH_coord.json
 
 echo "--- sanitizer lane (TSAN build + np=2 distributed suite; races
 --- attributed to libhorovod_tpu.so fail CI, jaxlib/XLA noise is

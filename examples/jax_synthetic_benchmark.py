@@ -1,24 +1,27 @@
-"""JAX/SPMD synthetic benchmark — the TPU-native flagship (BASELINE
-config #2 analog; reference ``examples/tensorflow2_synthetic_benchmark.py``).
+"""JAX/SPMD synthetic benchmark: the port of the reference's
+``examples/tensorflow2_synthetic_benchmark.py`` (``:86-132``) to one
+compiled data-parallel step over the device mesh.
 
-Trains a flax ResNet on fixed synthetic data over the full device mesh
-(DP via fused-psum gradient averaging), printing img/sec, achieved
-TFLOP/s and MFU.  Run::
+A flax model trains on one fixed synthetic batch; after the warm-up
+batches, ``--num-iters`` rounds of ``--num-batches-per-iter`` steps are
+timed and img/sec is printed as mean +- 1.96 sigma over the rounds.  Run::
 
     python examples/jax_synthetic_benchmark.py --model resnet50 --batch-size 64
-    # scaling efficiency (1 chip/host baseline vs all chips):
-    python examples/jax_synthetic_benchmark.py --efficiency
 
 On a chip-less host, force a virtual mesh first:
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu``.
+This is a user's script, as the reference's is.  The repo's numbers of
+record (utilization, memory, per-layer times on the chip) come from
+``perfbench/run.py`` (``BENCHMARK.json``).
 """
 
 import argparse
-import json
+import timeit
 
-import horovod_tpu as hvd
-from horovod_tpu.benchmark import (run_scaling_efficiency,
-                                   run_synthetic_benchmark)
+import jax
+import numpy as np
+
+from horovod_tpu.benchmark import make_bench_state, make_train_step
 from horovod_tpu.utils.compile_cache import enable_compile_cache
 
 
@@ -33,25 +36,34 @@ def main():
     p.add_argument("--num-warmup-batches", type=int, default=5)
     p.add_argument("--num-batches-per-iter", type=int, default=10)
     p.add_argument("--num-iters", type=int, default=10)
-    p.add_argument("--efficiency", action="store_true",
-                   help="measure weak-scaling efficiency instead")
-    p.add_argument("--json", action="store_true",
-                   help="emit one JSON line instead of prose")
     args = p.parse_args()
 
     enable_compile_cache()
-    hvd.init()
-    kw = dict(model_name=args.model, batch_size=args.batch_size,
-              image_size=args.image_size,
-              num_warmup_batches=args.num_warmup_batches,
-              num_batches_per_iter=args.num_batches_per_iter,
-              num_iters=args.num_iters, verbose=not args.json)
-    if args.efficiency:
-        res = run_scaling_efficiency(**kw)
-    else:
-        res = run_synthetic_benchmark(**kw)
-    if args.json:
-        print(json.dumps(res))
+    mesh, ax, model, optimizer, _, state, batch = make_bench_state(
+        args.model, args.batch_size, args.image_size)
+    step = make_train_step(model, optimizer, mesh, ax)
+    n_chips = mesh.devices.size
+    print(f"Model: {args.model}\nBatch size: {args.batch_size} per chip\n"
+          f"Number of chips: {n_chips} ({jax.devices()[0].device_kind})")
+
+    def run(n):
+        nonlocal state
+        for _ in range(n):
+            *state, loss = step(*state, *batch)
+        jax.block_until_ready(loss)
+
+    print("Running warmup...")
+    run(args.num_warmup_batches)
+    print("Running benchmark...")
+    img_secs = []
+    for i in range(args.num_iters):
+        t = timeit.timeit(lambda: run(args.num_batches_per_iter), number=1)
+        img_secs.append(args.batch_size * args.num_batches_per_iter / t)
+        print(f"Iter #{i}: {img_secs[-1]:.1f} img/sec per chip")
+    mean, conf = np.mean(img_secs), 1.96 * np.std(img_secs)
+    print(f"Img/sec per chip: {mean:.1f} +-{conf:.1f}")
+    print(f"Total img/sec on {n_chips} chip(s): "
+          f"{n_chips * mean:.1f} +-{n_chips * conf:.1f}")
 
 
 if __name__ == "__main__":

@@ -123,6 +123,24 @@ def _valid_steps(ckpt_dir: str) -> list:
     return sorted(steps)
 
 
+def _write_step(ckpt_dir: str, state: Any, step: int,
+                max_to_keep: Optional[int]) -> None:
+    """The orbax write of ``state`` to ``ckpt_dir/<step>``, finished or
+    raised when this returns.  Both callers block on the write (``save``
+    by contract, ``save_async`` on a thread of its own), so orbax's own
+    asynchronous layer is switched off: when its directory creation
+    fails, the non-daemon threads it has already started wait out the
+    coordination timeout for a signal that never comes, and the process
+    cannot exit until they do."""
+    import orbax.checkpoint as ocp
+    with ocp.CheckpointManager(
+            ckpt_dir,
+            options=ocp.CheckpointManagerOptions(
+                max_to_keep=max_to_keep,
+                enable_async_checkpointing=False)) as mgr:
+        mgr.save(step, args=ocp.args.StandardSave(state))
+
+
 def save(ckpt_dir: str, state: Any, step: int = 0,
          max_to_keep: Optional[int] = None) -> Optional[str]:
     """Write ``state`` (a pytree) to ``ckpt_dir/<step>``; rank 0 writes,
@@ -146,15 +164,10 @@ def save(ckpt_dir: str, state: Any, step: int = 0,
     ok = np.zeros(1, np.int32)
     if basics.rank() == 0:
         try:
-            import orbax.checkpoint as ocp
             state = _gather_zero(state)
             ckpt_dir = os.path.abspath(ckpt_dir)
             t0 = telemetry.clock()
-            with ocp.CheckpointManager(
-                    ckpt_dir,
-                    options=ocp.CheckpointManagerOptions(
-                        max_to_keep=max_to_keep)) as mgr:
-                mgr.save(step, args=ocp.args.StandardSave(state))
+            _write_step(ckpt_dir, state, step, max_to_keep)
             if telemetry.enabled():
                 telemetry.counter("hvd_checkpoint_saves_total",
                                   "Checkpoints written by rank 0").inc()
@@ -234,12 +247,7 @@ def save_async(ckpt_dir: str, state: Any, step: int = 0,
     def _write():
         t1 = telemetry.clock()
         try:
-            import orbax.checkpoint as ocp
-            with ocp.CheckpointManager(
-                    ckpt_dir,
-                    options=ocp.CheckpointManagerOptions(
-                        max_to_keep=max_to_keep)) as mgr:
-                mgr.save(step, args=ocp.args.StandardSave(snapshot))
+            _write_step(ckpt_dir, snapshot, step, max_to_keep)
             record.path = os.path.join(ckpt_dir, str(step))
             if telemetry.enabled():
                 telemetry.counter(

@@ -479,8 +479,7 @@ class Runtime:
             "cache_hit_ratio": (hits / lookups) if lookups else 0.0,
             # Hierarchical routing as the data plane currently runs it —
             # env defaults until the autotuner flips the knobs through
-            # the response stream (the "observed live" knob of
-            # BENCH_hier.json).
+            # the response stream.
             "hier_allreduce": self.hierarchical_enabled(),
             "hier_allgather": self.hierarchical_allgather_enabled(),
             "hier_available": bool(self._hier_avail_fn

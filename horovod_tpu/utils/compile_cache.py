@@ -3,8 +3,8 @@
 The cache directory is part of every entry's key, so a directory that
 changes between runs (a ``tempfile``, a pid, a timestamp) never hits.
 The entry scripts that compile for the chip — ``chip_smoke.py``,
-``bench.py``, ``python -m horovod_tpu.benchmark`` and the JAX examples —
-call :func:`enable_compile_cache` once before their first compile.  It is
+``perfbench/run.py`` and the JAX examples — call
+:func:`enable_compile_cache` once before their first compile.  It is
 not called at import, and the library and the tests never call it.
 """
 

@@ -19,7 +19,7 @@ if BACKEND not in ("tensorflow", "jax"):
                 allow_module_level=True)
 
 
-@pytest.fixture(scope="session")
+@pytest.fixture()
 def khvd(hvd):
     import horovod_tpu.keras as khvd
     return khvd

@@ -8,7 +8,7 @@ import pytest
 tf = pytest.importorskip("tensorflow")
 
 
-@pytest.fixture(scope="session")
+@pytest.fixture()
 def tfhvd(hvd):
     import horovod_tpu.tensorflow as tfhvd
     return tfhvd

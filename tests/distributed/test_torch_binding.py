@@ -7,7 +7,7 @@ import pytest
 torch = pytest.importorskip("torch")
 
 
-@pytest.fixture(scope="session")
+@pytest.fixture()
 def thvd(hvd):
     import horovod_tpu.torch as thvd
     return thvd

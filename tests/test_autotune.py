@@ -164,7 +164,11 @@ def test_autotune_explores_hierarchical_and_ranks_agree(tmp_path):
     # routing states appear across trials.
     hier_vals = {row["hier_allreduce"] for row in rows}
     assert hier_vals == {"0", "1"}, rows
-    assert rows[-1]["pinned"] == "1", rows[-1]
+    # The search converged and pinned.  Not "the last row is the pinned
+    # one": after pinning the coordinator keeps monitoring and re-opens
+    # exploration when throughput drifts (docs/autotune.md, step 5),
+    # which the other workers of a parallel test run cause at will.
+    assert any(row["pinned"] == "1" for row in rows), rows
 
 
 def test_bayes_vs_grid_oracle():

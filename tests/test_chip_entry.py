@@ -1,15 +1,11 @@
 """The rules of the entry scripts that run on the chip, checked without one:
-``chip_smoke.py`` and ``bench.py`` refuse the CPU by name, a failing bench
-lane is a failing run, the compile cache can be placed from outside,
-``hvd.init()`` under the launcher claims no device, and the launcher
-refuses ranks that would contend for a chip."""
+``chip_smoke.py`` refuses the CPU by name, the compile cache can be placed
+from outside, ``hvd.init()`` under the launcher claims no device, and the
+launcher refuses ranks that would contend for a chip."""
 
-import json
 import os
 import subprocess
 import sys
-
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -67,55 +63,6 @@ def test_init_under_launcher_env_initialises_no_backend():
     assert res.stdout.split() == ["0", "1", "False"]
 
 
-@pytest.fixture()
-def bench(monkeypatch):
-    monkeypatch.syspath_prepend(REPO)
-    import bench
-    import horovod_tpu as hvd
-    yield bench
-    hvd.shutdown()           # main() initialises and never shuts down
-
-
-def test_bench_refuses_cpu_by_name(bench, capsys):
-    assert bench.main() == 2
-    out, err = capsys.readouterr()
-    assert out == ""
-    assert "platform 'cpu'" in err and "not 'tpu'" in err
-
-
-def test_bench_lane_failure_is_a_failing_run(bench, monkeypatch, capsys):
-    from horovod_tpu import benchmark
-    from horovod_tpu.utils import compile_cache
-
-    tpu = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
-    monkeypatch.setattr(benchmark, "device_info", lambda: tpu)
-    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: None)
-
-    def boom():
-        raise RuntimeError("lane exploded")
-
-    monkeypatch.setattr(bench, "LANES", (
-        ("resnet50", lambda: {"value": 1.0, "vs_baseline": 2.0}),
-        ("lm", boom),
-        ("resnet101", lambda: None),             # switched off
-        ("eager_allreduce", lambda: {"busbw_gbs": 3.0})))
-    assert bench.main() == 1
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert line["errors"] == {"lm": "RuntimeError: lane exploded"}
-    assert "lm" not in line and "resnet101" not in line
-    assert line["value"] == 1.0 and line["device"] == tpu
-    assert line["eager_allreduce"] == {"busbw_gbs": 3.0}
-
-    # A failed headline lane quotes no figure.
-    monkeypatch.setattr(bench, "LANES", (("resnet50", boom),))
-    assert bench.main() == 1
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert line["value"] is None and line["vs_baseline"] is None
-
-    monkeypatch.setattr(bench, "LANES", (("resnet50", lambda: {"value": 1}),))
-    assert bench.main() == 0
-
-
 def test_launcher_refuses_ranks_that_would_share_a_chip(monkeypatch):
     from jax._src import hardware_utils
 
@@ -134,18 +81,3 @@ def test_launcher_refuses_ranks_that_would_share_a_chip(monkeypatch):
     assert run.chip_contention(one, {}) is None
     chips[0] = 0
     assert run.chip_contention(two, {}) is None
-
-
-def test_unlisted_accelerator_kind_is_an_error():
-    """MFU is never quietly left out or computed against a guess."""
-    from horovod_tpu.benchmark import device_peak_tflops
-
-    class Dev:
-        platform, device_kind = "tpu", "TPU v5 lite"
-
-    assert device_peak_tflops(Dev) == 197.0
-    Dev.device_kind = "TPU v99"
-    with pytest.raises(ValueError, match="TPU v99"):
-        device_peak_tflops(Dev)
-    Dev.platform, Dev.device_kind = "cpu", "cpu"
-    assert device_peak_tflops(Dev) is None

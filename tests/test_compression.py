@@ -187,14 +187,14 @@ def test_chunked_fused_allreduce_matches_unchunked(hvd, mesh8):
 _SHAPES = [(16, 8), (37,), (5,)]
 
 
-def _ef_harness(mesh, codec_spec, steps):
+def _ef_harness(mesh, codec_spec, steps, shapes=_SHAPES):
     """Cumulative-mean relative error per step for a codec, reducing the
     SAME per-rank gradients each step (the EF convergence property)."""
     codec = C.resolve_codec(codec_spec)
     rng = np.random.RandomState(0)
-    g_all = [jnp.asarray(rng.randn(8, *s), jnp.float32) for s in _SHAPES]
+    g_all = [jnp.asarray(rng.randn(8, *s), jnp.float32) for s in shapes]
     true_mean = [g.mean(0) for g in g_all]
-    proto = [jax.ShapeDtypeStruct(s, jnp.float32) for s in _SHAPES]
+    proto = [jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes]
     plan = fusion.make_reduce_scatter_plan(proto, 8, codec=codec)
     state = codec.init_state(plan)
     specs = codec.state_specs(plan, "data")
@@ -206,13 +206,13 @@ def _ef_harness(mesh, codec_spec, steps):
 
     f = jax.jit(jax.shard_map(
         step, mesh=mesh,
-        in_specs=(tuple(P("data") for _ in _SHAPES), specs),
-        out_specs=(tuple(P() for _ in _SHAPES), specs),
+        in_specs=(tuple(P("data") for _ in shapes), specs),
+        out_specs=(tuple(P() for _ in shapes), specs),
         check_vma=False))
     gs_flat = tuple(g.reshape((-1,) + tuple(s[1:]))
                     for g, s in zip(g_all, [(8,) + tuple(sh)
-                                            for sh in _SHAPES]))
-    acc = [jnp.zeros(s, jnp.float32) for s in _SHAPES]
+                                            for sh in shapes]))
+    acc = [jnp.zeros(s, jnp.float32) for s in shapes]
     errs = []
     for t in range(steps):
         out, state = f(gs_flat, state)
@@ -309,6 +309,37 @@ def test_compression_telemetry_series(hvd, mesh8):
     finally:
         telemetry.configure(enabled_flag=False)
         telemetry.registry().clear()
+
+
+@pytest.mark.parametrize("codec,floor", [("int8", 3.0), ("bf16", 1.9),
+                                         ("fp16", 1.9)])
+def test_codec_wire_byte_ratio(hvd, mesh8, codec, floor):
+    """The transport saving docs/performance.md promises, on counts: the
+    logical wire bytes ``hvd_collective_bytes_total`` books at trace time
+    for one compressed allreduce of a layer-sized gradient, against the
+    uncompressed wire.  int8 packs four f32 bytes into about one (less
+    the per-bucket scales), the casts halve them."""
+    from horovod_tpu import telemetry
+    from horovod_tpu.telemetry import aggregate
+
+    def wire_bytes(spec):
+        telemetry.registry().clear()
+        _ef_harness(mesh8, spec, steps=1,
+                    shapes=[(256, 512), (512,), (512, 64)])
+        snap = telemetry.metrics_snapshot()
+        return sum(aggregate.counter_total(
+            snap, "hvd_collective_bytes_total",
+            {"plane": "spmd", "kind": kind,
+             "codec": C.resolve_codec(spec).name})
+            for kind in ("reduce_scatter", "all_gather"))
+
+    telemetry.configure(enabled_flag=True)
+    try:
+        plain, packed = wire_bytes("none"), wire_bytes(codec)
+    finally:
+        telemetry.configure(enabled_flag=False)
+        telemetry.registry().clear()
+    assert packed > 0 and plain / packed >= floor, (plain, packed)
 
 
 # ---------------------------------------------------------------------------

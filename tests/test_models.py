@@ -41,59 +41,67 @@ def test_headline_model_forward(hvd, name, size):
 
 @pytest.mark.slow
 def test_headline_models_train_step(hvd, mesh8):
-    """The synthetic benchmark harness must drive the new families end-to-end
-    (registry -> make_train_step -> finite loss)."""
-    from horovod_tpu.benchmark import run_synthetic_benchmark
+    """The state recipe and the flax step must drive the other families
+    end-to-end (registry -> make_bench_state -> make_train_step -> finite
+    loss)."""
+    from horovod_tpu.benchmark import make_bench_state, make_train_step
 
     for name, size in (("vgg11", 32), ("inception3", 96)):
-        res = run_synthetic_benchmark(
-            name, batch_size=1, image_size=size, num_classes=4,
-            num_warmup_batches=0, num_batches_per_iter=1, num_iters=1,
-            verbose=False)
-        assert np.isfinite(res["loss"])
-        assert res["img_sec_per_chip"] > 0
+        mesh, ax, model, optimizer, _, state, batch = make_bench_state(
+            name, batch_size=1, image_size=size, num_classes=4, mesh=mesh8)
+        step = make_train_step(model, optimizer, mesh, ax)
+        *state, loss = step(*state, *batch)
+        assert np.isfinite(float(loss))
+        assert batch[0].shape[0] == 8            # batch_size is per chip
 
 
-def test_lm_benchmark_plumbing(hvd):
-    """run_lm_benchmark (the bench.py 'lm' key) end-to-end on a tiny
-    config: finite loss, throughput, and the analytic FLOP accounting
-    present (MFU itself is None on CPU — no known peak)."""
-    from horovod_tpu.benchmark import lm_train_flops, run_lm_benchmark
+@pytest.mark.parametrize("shard_optimizer", [False, True])
+def test_lm_bench_state_is_placed_for_its_step(hvd, shard_optimizer):
+    """make_lm_bench_state — what chip_smoke.py's lm_dp and lm_zero phases
+    stand on: the state goes where the step's specs say (parameters whole
+    on every device; the optimizer state whole too, or 1/N of it per
+    device under ZeRO), the batch is batch_size per chip sharded over the
+    mesh, and the step it returns takes that state and batch as they
+    are."""
+    from jax.sharding import PartitionSpec as P
 
-    res = run_lm_benchmark(
-        d_model=32, n_layers=2, n_heads=2, vocab_size=64, seq_len=64,
-        batch_size=2, attention="local", remat="dots",
-        num_warmup_batches=1, num_batches_per_iter=2, num_iters=2,
-        verbose=False)
-    assert np.isfinite(res["loss"])
-    assert res["tok_sec_per_chip"] > 0
-    assert res["flops_per_step_analytic"] > 0
-    # every result names the devices it ran on: by default, all of them
-    assert res["device"] == {"platform": "cpu", "kind": "cpu", "count": 8}
-    assert res["mfu"] is None
-    # the analytic count matches the hand formula
-    from horovod_tpu.models.transformer import TransformerConfig
-    cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                            n_layers=2, d_ff=128, max_seq=64)
-    n_matmul = 2 * (4 * 32 * 32 + 2 * 32 * 128) + 32 * 64
-    want = 6.0 * n_matmul * 2 * 64 + 6.0 * 2 * 64 * 64 * 32 * 2
-    assert lm_train_flops(cfg, 2) == want
+    from horovod_tpu.benchmark import make_lm_bench_state
+
+    mesh, cfg, step, state, batch = make_lm_bench_state(
+        d_model=32, n_layers=2, n_heads=2, d_ff=128, vocab_size=64,
+        seq_len=64, batch_size=2, attention="local", remat="dots",
+        shard_optimizer=shard_optimizer)
+    n = mesh.devices.size
+    assert n == 8 and mesh.axis_names == ("data",)
+    assert cfg.max_seq == 64 and cfg.dtype == jnp.bfloat16
+    params, opt_state = state
+    for leaf in jax.tree_util.tree_leaves(params):
+        assert leaf.dtype == jnp.float32          # f32 master weights
+        assert leaf.sharding.is_fully_replicated
+    for arr in batch:
+        assert arr.shape == (2 * n, 64) and arr.sharding.spec == P("data")
+    slots = jax.tree_util.tree_leaves(opt_state)
+    assert {leaf.dtype for leaf in slots} == {jnp.dtype(jnp.bfloat16)}
+    per_device = sum(leaf.addressable_shards[0].data.nbytes
+                     for leaf in slots)
+    whole = sum(leaf.nbytes for leaf in slots)
+    if shard_optimizer:
+        # 1/N of what a replica holds whole; bucket padding to a multiple
+        # of N is all that may be added.
+        assert 1 / n <= per_device / whole < 1.1 / n
+    else:
+        assert per_device == whole
+    *state, loss = step(*state, *batch)
+    assert np.isfinite(float(loss))
+    for new, old in zip(jax.tree_util.tree_leaves(state),
+                        jax.tree_util.tree_leaves((params, opt_state))):
+        assert new.sharding.is_equivalent_to(old.sharding, old.ndim)
 
 
-def test_decode_benchmark_plumbing_and_bf16(hvd):
-    """run_decode_benchmark end-to-end on a tiny config, plus the bf16
-    regression: decode_step must accept a bf16 cfg (the rmsnorm f32
-    scale used to promote k/v past the cache dtype — r4 fix)."""
-    import jax.numpy as jnp
-
-    from horovod_tpu.benchmark import run_decode_benchmark
+def test_generate_accepts_bf16_config(hvd):
+    """decode_step must accept a bf16 cfg (the rmsnorm f32 scale used to
+    promote k/v past the cache dtype — r4 fix)."""
     from horovod_tpu.models import transformer as tfm
-
-    res = run_decode_benchmark(d_model=32, n_layers=2, n_heads=2,
-                               vocab_size=64, batch_size=2,
-                               prompt_len=4, total_len=16,
-                               num_iters=1, verbose=False)
-    assert res["decode_tok_sec"] > 0
 
     cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
                                 n_layers=2, d_ff=64, max_seq=16,
@@ -145,30 +153,6 @@ def test_train_step_runs_and_learns(hvd, mesh8):
         losses.append(float(np.asarray(loss)))
     assert np.isfinite(losses).all()
     assert losses[-1] < losses[0]
-
-
-@pytest.mark.slow
-def test_benchmark_reports_flops_and_efficiency(hvd):
-    """run_synthetic_benchmark must report FLOPs (XLA cost analysis) and
-    run_scaling_efficiency must compute the 1-vs-N ratio — the metric
-    BASELINE.md anchors on (reference README.rst:75)."""
-    from horovod_tpu.benchmark import (run_scaling_efficiency,
-                                       run_synthetic_benchmark)
-
-    res = run_synthetic_benchmark(
-        "resnet18", batch_size=2, image_size=32, num_warmup_batches=1,
-        num_batches_per_iter=2, num_iters=2, verbose=False)
-    assert res["img_sec_per_chip"] > 0
-    assert res["flops_per_step"] and res["flops_per_step"] > 1e8
-    assert res["tflops_per_chip"] and res["tflops_per_chip"] > 0
-    assert res["mfu"] is None  # CPU mesh: no peak -> no MFU claim
-
-    eff = run_scaling_efficiency(
-        "resnet18", batch_size=2, image_size=32, n_devices=8,
-        num_warmup_batches=1, num_batches_per_iter=2, num_iters=2,
-        verbose=False)
-    assert eff["n_devices"] == 8
-    assert 0 < eff["scaling_efficiency"] <= 1.5  # plumbing, not perf, on CPU
 
 
 def test_graft_entry_single_chip(hvd):

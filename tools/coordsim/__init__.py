@@ -15,8 +15,7 @@ by exhaustive assertion *before* it ever coordinates a real job:
 
 ``python -m tools.coordsim --ranks 64 --chaos drop:0.1`` runs one
 episode and prints the stats JSON; ``tests/test_coordsim.py`` is the CI
-lane; ``horovod_tpu/benchmark.py --coordsim`` sweeps N for
-``BENCH_coord.json``.
+lane and holds the fan-in bound at N=256.
 """
 
 from tools.coordsim.net import VirtualClock, VirtualNetwork

@@ -24,8 +24,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Set, Tuple
 
 # Directories scanned by default, relative to the repo root (ISSUE 10:
 # the correctness surface is the library, its tests and the examples).
-DEFAULT_SCAN_DIRS = ("horovod_tpu", "tests", "examples", "tools", "ci",
-                    "benchmark.py", "bench.py")
+DEFAULT_SCAN_DIRS = ("horovod_tpu", "tests", "examples", "tools", "ci")
 
 _SKIP_PARTS = {"__pycache__", ".git", ".pytest_cache", "build", "node_modules"}
 
