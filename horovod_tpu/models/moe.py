@@ -125,11 +125,24 @@ def _gather_rows_bwd(uses, residuals, g):
 _gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
 
 
+# The three matrices of every expert, [E, d, f], [E, d, f] and [E, f, d].
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def _expert_operands(layer):
+    """The :data:`EXPERT_LEAVES` as the grouped matmuls are handed them:
+    as they are stored.  The kernels round a group's block to the rows'
+    dtype in VMEM; an ``.astype`` here is a second copy of every expert in
+    HBM, written each step, kept for the backward pass and read twice
+    (PERF.md, PR 30), and :func:`record_weight_copies` counts it."""
+    return tuple(layer[name] for name in EXPERT_LEAVES)
+
+
 def experts_ffn(h, top_p, top_i, group_sizes, layer, dtype):
     """``sum_k top_p[:, k] * expert_{top_i[:, k]}(h)``: ``h`` [N, d] ->
-    [N, d].  ``group_sizes`` [E] int32: assignments per expert
-    (``RouterStats.counts``).  ``layer`` holds ``w_gate``, ``w_up``
-    [E, d, f] and ``w_down`` [E, f, d]."""
+    [N, d] in ``dtype``.  ``group_sizes`` [E] int32: assignments per
+    expert (``RouterStats.counts``).  ``layer`` holds ``w_gate``, ``w_up``
+    [E, d, f] and ``w_down`` [E, f, d] in the dtype they are stored in."""
     n, k = top_i.shape
     with jax.named_scope(scopes.MOE_DISPATCH):
         flat = top_i.reshape(-1)
@@ -141,13 +154,12 @@ def experts_ffn(h, top_p, top_i, group_sizes, layer, dtype):
             jnp.arange(n * k, dtype=jnp.int32), unique_indices=True)
         rows = _gather_rows(h, order // k, place, k)
     with jax.named_scope(scopes.MOE_EXPERTS):
-        gate = grouped_matmul(rows, layer["w_gate"].astype(dtype),
-                               group_sizes)
-        up = grouped_matmul(rows, layer["w_up"].astype(dtype), group_sizes)
+        w_gate, w_up, w_down = _expert_operands(layer)
+        gate = grouped_matmul(rows, w_gate, group_sizes)
+        up = grouped_matmul(rows, w_up, group_sizes)
         act = (jax.nn.silu(gate.astype(jnp.float32))
                * up.astype(jnp.float32)).astype(dtype)
-        out = grouped_matmul(act, layer["w_down"].astype(dtype),
-                              group_sizes)
+        out = grouped_matmul(act, w_down, group_sizes)
     with jax.named_scope(scopes.MOE_COMBINE):
         per_token = _gather_rows(out, place, order, 1).reshape(n, k, -1)
         return jnp.sum(per_token.astype(jnp.float32) * top_p[..., None],
@@ -188,3 +200,23 @@ def record_assignments(layer: int, assignments: int, experts: int) -> None:
         "the first starts inside a sub-tile (1.0 = no masked work)",
         bound="worst").set(
             worst_matmul_rows(experts, assignments) / assignments)
+
+
+def record_weight_copies(layer: int, weights) -> None:
+    """Trace-time gauge: the bytes of expert weights layer ``layer``
+    (``weights``: its parameters) holds a second time because
+    :func:`experts_ffn` hands the grouped matmuls a cast and not the
+    stored leaf.  0 since the kernels round in VMEM; 2 bytes a parameter
+    before."""
+    if not telemetry.enabled():
+        return
+    handed = jax.eval_shape(_expert_operands, weights)
+    telemetry.gauge(
+        "hvd_moe_expert_weight_copy_bytes",
+        "Bytes of expert weights the traced MoE layer materialises in the "
+        "compute dtype outside the grouped-matmul kernels (0 = the "
+        "kernels read the stored parameters)",
+        layer=str(layer)).set(sum(
+            h.size * h.dtype.itemsize
+            for h, name in zip(handed, EXPERT_LEAVES)
+            if h.dtype != weights[name].dtype))

@@ -456,6 +456,7 @@ def forward_with_router_stats(params, tokens, cfg: TransformerConfig,
             router_stats.append(stats)
             moe.record_assignments(
                 i, tokens.size * cfg.experts_per_token, cfg.n_experts)
+            moe.record_weight_copies(i, layer)
 
     return _logits_head(x, params, cfg), router_stats
 

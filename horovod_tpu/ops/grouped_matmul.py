@@ -35,6 +35,17 @@ Three kernels, named for the trace (``horovod_tpu/telemetry/scopes.py``):
 (the same against the transposed weights, the backward's gradient of the
 rows) and ``moe_tgmm`` ([M, K]^T x [M, N] per group -> [G, K, N], the
 gradient of the weights; a group without rows gets zeros).
+
+The weights come in the dtype they are stored in.  Where that is the
+rows' dtype the two kernels that read them are as they were.  Where it is
+wider (float32 masters under bf16 rows) they round a group's block to the
+rows' dtype in VMEM, for each piece they multiply, to nearest even: the
+bits a cast before the call gives, without a second copy of every group's
+weights in HBM that is written each step, kept for the backward pass and
+read twice.  A float32 block is twice as long on the way, longer than
+the one visit the grid's pipeline gives it, so with K as one tile the
+kernel fetches the blocks itself, a group ahead
+(:func:`_weights_a_group_ahead`; PERF.md, PR 30).
 """
 
 from __future__ import annotations
@@ -61,8 +72,10 @@ TILE_M, TILE_K, TILE_N = 512, 2048, 2048
 # tiles of bf16.  A smaller row tile is one sub-tile.
 SUB_M = 128
 # The blocks of those tiles, double-buffered, and the accumulator take up
-# to 40 MB (the weight gradient at K = N = 2048); the compiler's default
-# allowance is 16 MiB of the core's 128.
+# to 40 MB (the weight gradient at K = N = 2048), 52 with float32 weight
+# blocks of that size and their rounded copy; the OLMoE products, 2048 x
+# 1024, take 32.  The compiler's default allowance is 16 MiB of the
+# core's 128.
 VMEM_LIMIT_BYTES = 96 * 2 ** 20
 
 
@@ -166,9 +179,10 @@ def _dot(rows, other, contract):
                            preferred_element_type=jnp.float32)
 
 
-def _gmm_kernel(offsets, visit_group, visit_tile, lhs, rhs, out, acc, *,
-                tm, sub, tiles_k, transpose_rhs):
+def _gmm_kernel(offsets, visit_group, visit_tile, lhs, rhs, out, acc,
+                *ahead, tm, sub, tiles_k, transpose_rhs):
     visit, k_i = pl.program_id(1), pl.program_id(2)
+    group = visit_group[visit]
     contract = ((1,), (1,)) if transpose_rhs else ((1,), (0,))
     last_k = k_i == tiles_k - 1
 
@@ -176,10 +190,20 @@ def _gmm_kernel(offsets, visit_group, visit_tile, lhs, rhs, out, acc, *,
     def _zero():
         acc[...] = jnp.zeros_like(acc)
 
+    if ahead:
+        weights = _weights_a_group_ahead(offsets, visit_group, group, rhs,
+                                         *ahead, tm, transpose_rhs)
+    else:
+        weights = rhs
+
     def work(rows, whole, split, mask):
         @pl.when(whole | split)
         def _multiply():
-            acc[rows, :] += _dot(lhs[rows, :], rhs[...], contract)
+            # Weights stored wider than the rows are rounded here, per
+            # piece: on the way to the MXU it hides, where once a group
+            # into a scratch it stood before the group's first matmul.
+            acc[rows, :] += _dot(lhs[rows, :],
+                                 weights[...].astype(lhs.dtype), contract)
 
         @pl.when(last_k & whole)
         def _store():
@@ -196,8 +220,58 @@ def _gmm_kernel(offsets, visit_group, visit_tile, lhs, rhs, out, acc, *,
             out[rows, :] = jnp.where(mask(kept.shape[1]), acc[rows, :],
                                      kept).astype(out.dtype)
 
-    _for_each_piece(offsets, visit_group[visit], visit_tile[visit], tm, sub,
-                    work)
+    _for_each_piece(offsets, group, visit_tile[visit], tm, sub, work)
+
+
+def _weights_a_group_ahead(offsets, visit_group, group, rhs, buffers,
+                           arrived, slot, tm, transpose_rhs):
+    """``group``'s block of ``rhs`` (in HBM; K is one tile) in VMEM,
+    fetched by this kernel and not by the grid's pipeline, which looks one
+    grid step ahead: the block of the group after this one is asked for
+    when this group's first visit starts and has all of the group's
+    visits to arrive, where the pipeline gives it the last of them.  A
+    float32 block of the OLMoE experts is 8 MiB, longer on the way than a
+    visit lasts: through the pipeline the kernels lost to the wider fetch
+    most of what the step gained from not casting (PERF.md, PR 30).
+    ``buffers`` [2, ...] and the DMA semaphores ``arrived`` [2] take the
+    groups in turn; ``slot`` (SMEM) is the current group's.  Every fetch
+    that is started is waited for: the first group's at the first visit
+    of each N tile, another's only where a visit to that group follows."""
+    visit, visits = pl.program_id(1), pl.num_programs(1)
+    tn = buffers.shape[1] if transpose_rhs else buffers.shape[2]
+    columns = pl.ds(pl.multiple_of(pl.program_id(0) * tn, tn), tn)
+
+    def fetch(g, into):
+        block = (rhs.at[g, columns, :] if transpose_rhs
+                 else rhs.at[g, :, columns])
+        return pltpu.make_async_copy(block, buffers.at[into],
+                                     arrived.at[into])
+
+    first = visit == 0
+    fresh = first | (visit_group[jnp.maximum(visit - 1, 0)] != group)
+
+    @pl.when(first)
+    def _cold_start():
+        slot[0] = 0
+        fetch(group, 0).start()
+
+    @pl.when(fresh & ~first)
+    def _take_turns():
+        slot[0] = 1 - slot[0]
+
+    now = slot[0]
+
+    @pl.when(fresh)
+    def _arrive_and_ask_ahead():
+        fetch(group, now).wait()
+        lo, hi = offsets[group], offsets[group + 1]
+        after = visit + (hi - 1) // tm - lo // tm + 1  # _visits' count
+
+        @pl.when(after < visits)
+        def _ask():
+            fetch(visit_group[after], 1 - now).start()
+
+    return buffers.at[now]
 
 
 def _tgmm_kernel(offsets, visit_group, visit_tile, lhs, rhs, out, acc, *,
@@ -272,12 +346,20 @@ def _gmm_call(lhs, rhs, group_sizes, *, transpose_rhs, tiles):
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     *metadata, n_visits = _visits(group_sizes, m, tm, visit_empty=False)
     interpret, vma = _interpret(lhs), _vma(lhs, rhs, group_sizes)
-    rhs_spec = (
-        pl.BlockSpec((None, tn, tk),
-                     lambda n_i, v, k_i, off, vg, vt: (vg[v], n_i, k_i))
-        if transpose_rhs else
-        pl.BlockSpec((None, tk, tn),
-                     lambda n_i, v, k_i, off, vg, vt: (vg[v], k_i, n_i)))
+    rhs_block = (tn, tk) if transpose_rhs else (tk, tn)
+    scratch = [pltpu.VMEM((tm, tn), jnp.float32)]
+    if rhs.dtype != lhs.dtype and tk == k:
+        # Wider weights, K one tile: the kernel fetches a group's block
+        # itself, a group ahead (_weights_a_group_ahead).
+        rhs_spec = pl.BlockSpec(memory_space=pl.ANY)
+        scratch += [pltpu.VMEM((2, *rhs_block), rhs.dtype),
+                    pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32)]
+    else:
+        rhs_spec = pl.BlockSpec(
+            (None, *rhs_block),
+            (lambda n_i, v, k_i, off, vg, vt: (vg[v], n_i, k_i))
+            if transpose_rhs else
+            (lambda n_i, v, k_i, off, vg, vt: (vg[v], k_i, n_i)))
     return pl.pallas_call(
         functools.partial(_gmm_kernel, tm=tm, sub=sub, tiles_k=k // tk,
                           transpose_rhs=transpose_rhs),
@@ -293,7 +375,7 @@ def _gmm_call(lhs, rhs, group_sizes, *, transpose_rhs, tiles):
                 (tm, tn),
                 lambda n_i, v, k_i, off, vg, vt: (vt[v], n_i)),
             grid=(n // tn, n_visits, k // tk),
-            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
+            scratch_shapes=scratch),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
@@ -342,9 +424,11 @@ def _tgmm_call(lhs, rhs, group_sizes, *, tiles):
 def grouped_matmul(rows, weights, group_sizes):
     """``rows[start_g:end_g] @ weights[g]`` for each group ``g`` of
     consecutive rows: [M, K] x [G, K, N] -> [M, N] in ``rows.dtype``,
-    float32 accumulation.  ``group_sizes`` [G] int32 sums to M; a group
-    may be empty.  M, K and N are multiples of their tiles (or smaller
-    than one).  Differentiable in ``rows`` and ``weights``."""
+    float32 accumulation.  ``weights`` as they are stored: a dtype other
+    than the rows' is rounded to it inside the kernels, and the weights'
+    gradient comes back in it.  ``group_sizes`` [G] int32 sums to M; a
+    group may be empty.  M, K and N are multiples of their tiles (or
+    smaller than one).  Differentiable in ``rows`` and ``weights``."""
     return _gmm(rows, weights, group_sizes, transpose_rhs=False)
 
 

@@ -191,6 +191,111 @@ def test_grouped_matmul_refuses_a_dimension_its_tile_does_not_divide():
                           jnp.asarray([300, 300], jnp.int32))
 
 
+# --- weights in the dtype they are stored in -------------------------------
+
+def _bf16_rows_f32_weights(transpose_rhs=False):
+    x = jax.random.normal(jax.random.key(0), (128, 64)).astype(jnp.bfloat16)
+    w = jax.random.normal(jax.random.key(1), (6, 96, 64) if transpose_rhs
+                          else (6, 64, 96), jnp.float32)
+    return x, w
+
+
+@pytest.mark.parametrize("tile_k", (32, 2048),
+                         ids=("k_tiled", "k_one_tile"))
+@pytest.mark.parametrize("transpose_rhs", (False, True),
+                         ids=("moe_gmm", "moe_gmm_nt"))
+@pytest.mark.parametrize("sizes", GROUPS.values(), ids=GROUPS.keys())
+def test_float32_weights_are_rounded_in_the_kernel_bit_for_bit(
+        small_tiles, monkeypatch, sizes, transpose_rhs, tile_k):
+    """bf16 rows against float32 weights: the kernel rounds each block to
+    bf16 as a cast before the call would, so every output bit is that
+    call's.  With K in two tiles the grid's pipeline fetches the blocks;
+    with K as one tile the kernel does, a group ahead, three N tiles in
+    turn."""
+    monkeypatch.setattr(gm, "TILE_K", tile_k)
+    x, w = _bf16_rows_f32_weights(transpose_rhs)
+    gs = jnp.asarray(sizes, jnp.int32)
+    got = gm._gmm(x, w, gs, transpose_rhs)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        got, gm._gmm(x, w.astype(jnp.bfloat16), gs, transpose_rhs))
+
+
+@pytest.mark.parametrize("sizes", GROUPS.values(), ids=GROUPS.keys())
+def test_float32_weights_keep_every_gradient_bit(small_tiles, monkeypatch,
+                                                 sizes):
+    """Output and the rows' gradient bit for bit, the weights' gradient
+    to the last bit of bf16 and in the weights' own dtype.  K and N as
+    one tile, as at the OLMoE shapes: a group's block is fetched a group
+    ahead and stays over its visits."""
+    monkeypatch.setattr(gm, "TILE_K", 2048)
+    monkeypatch.setattr(gm, "TILE_N", 2048)
+    x, w = _bf16_rows_f32_weights()
+    gs = jnp.asarray(sizes, jnp.int32)
+
+    def run(cast):
+        def loss(x, w):
+            out = gm.grouped_matmul(x, cast(w), gs)
+            return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
+        (_, out), grads = jax.value_and_grad(loss, (0, 1), has_aux=True)(
+            x, w)
+        return (out, *grads)
+
+    got = run(lambda w: w)
+    want = run(lambda w: w.astype(jnp.bfloat16))
+    assert [a.dtype for a in got] == [jnp.bfloat16, jnp.bfloat16,
+                                      jnp.float32]
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    assert np.asarray(got[2]).any()
+    np.testing.assert_array_equal(got[2], got[2].astype(jnp.bfloat16))
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it (calls,
+    branches of a ``cond``, loop bodies, kernels)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else (
+                    value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("weights_dtype,tile_k,scratch,rounded", [
+    (jnp.bfloat16, 64, ["float32[16,32]"], 0),
+    # K in two tiles: the grid's pipeline fetches the float32 blocks.
+    (jnp.float32, 32, ["float32[16,32]"], 2),
+    # K one tile: the kernel fetches them itself, a group ahead.
+    (jnp.float32, 64, ["float32[16,32]", "float32[2,64,32]",
+                       "dma_sem[2]", "int32[1]"], 2)],
+    ids=("equal_dtypes_are_todays_kernel", "wider_weights_k_tiled",
+         "wider_weights_k_one_tile"))
+def test_gmm_rounds_only_weights_stored_wider(small_tiles, monkeypatch,
+                                              weights_dtype, tile_k,
+                                              scratch, rounded):
+    """Equal dtypes trace the kernel as it was: one scratch (the
+    accumulator), the weights through the grid's pipeline, no convert of
+    a weight block.  Wider weights are rounded where a piece is
+    multiplied (the whole tile, and the sub-tile loop's body)."""
+    monkeypatch.setattr(gm, "TILE_K", tile_k)
+    x, w = _bf16_rows_f32_weights()
+    gs = jnp.asarray(GROUPS["ragged"], jnp.int32)
+    (call,) = [e for e in _eqns(jax.make_jaxpr(
+        lambda x, w: gm._gmm(x, w, gs, False))(x, w.astype(weights_dtype))
+        .jaxpr) if e.primitive.name == "pallas_call"]
+    kernel = call.params["jaxpr"]
+    n = call.params["grid_mapping"].num_scratch_operands
+    assert [v.aval.inner_aval.str_short() for v in kernel.invars[-n:]] == \
+        scratch
+    converts = [e for e in _eqns(kernel)
+                if e.primitive.name == "convert_element_type"
+                and e.outvars[0].aval.shape == (tile_k, 32)]
+    assert len(converts) == rounded
+
+
 # --- the expert layer -------------------------------------------------------
 
 def _loop_oracle(h, top_p, top_i, layer):
@@ -433,6 +538,31 @@ def test_train_step_takes_the_gradient_of_the_global_batch(
         assert _rel((after - before) / -lr, want_g[name]) <= 2e-3, name
 
 
+def test_bf16_train_step_reads_the_float32_masters_on_four_devices(hvd):
+    """bf16 compute over float32 masters through the real step (the
+    kernels fetch and round the masters themselves, inside
+    ``shard_map``): its loss is the whole batch's bf16 loss, and the
+    expert leaves move."""
+    from horovod_tpu.topology import build_mesh
+
+    cfg = dataclasses.replace(OLMOE_TINY, dtype=jnp.bfloat16)
+    mesh = build_mesh(axes=("data",), devices=jax.devices()[:4])
+    optimizer = optax.sgd(0.1)
+    step, _, _ = tfm.make_train_step(cfg, optimizer, mesh,
+                                     attention="local", donate=False)
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    tokens, labels = _batch(cfg, batch=8)
+    new, _, loss = step(params, optimizer.init(params), tokens, labels)
+    # Per-shard router statistics make the shards' mean another function
+    # than the whole batch's loss only in the third digit of the 1% term.
+    want = tfm.loss_fn(params, tokens, labels, cfg, attention="local")
+    np.testing.assert_allclose(loss, want, rtol=3e-3)
+    for name in moe.EXPERT_LEAVES:
+        after, before = new["layers"][-1][name], params["layers"][-1][name]
+        assert after.dtype == jnp.float32
+        assert np.abs(np.asarray(after - before)).max() > 1e-6, name
+
+
 def test_sequence_axis_offsets_the_rotary_positions(hvd):
     """Two sequence shards (ring attention) see positions 0..T/2-1 and
     T/2..T-1; the loss is the single-device loss."""
@@ -621,9 +751,89 @@ def test_rows_computed_over_needed_gauge_is_the_static_worst_case(hvd):
         moe.record_assignments(0, 65536, 64)
         assert ('hvd_moe_gmm_rows_computed_over_needed{bound="worst"} '
                 '1.123046875' in telemetry.render_prometheus())
-        doc = open(os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "docs", "metrics.md")).read()
+        doc = _metrics_doc()
         assert "`hvd_moe_gmm_rows_computed_over_needed`" in doc
         assert "matmul_rows" in doc
+    finally:
+        telemetry.reset_for_tests()
+
+
+def _bf16_copies(layer):
+    """What ``experts_ffn`` handed the kernels before: a cast of every
+    expert leaf."""
+    return tuple(layer[name].astype(jnp.bfloat16)
+                 for name in moe.EXPERT_LEAVES)
+
+
+def _metrics_doc():
+    return open(os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "docs", "metrics.md")).read()
+
+
+def _expert_leaf_converts(jaxpr, shapes, dtype, scope):
+    """Converts to ``dtype`` of an array of one of ``shapes`` traced
+    under ``scope``, anywhere in ``jaxpr``."""
+    for eqn in _eqns(jaxpr):
+        if (eqn.primitive.name == "convert_element_type"
+                and eqn.outvars[0].aval.shape in shapes
+                and eqn.outvars[0].aval.dtype == dtype
+                and scope in str(eqn.source_info.name_stack)):
+            yield eqn
+
+
+def test_step_holds_no_compute_dtype_copy_of_an_expert_leaf(hvd,
+                                                            monkeypatch):
+    """float32 masters, bf16 compute: nothing under ``mlp/moe_experts``
+    of the train step converts an [experts, K, N] array to bf16 (the
+    kernels round in VMEM), forward or backward."""
+    from horovod_tpu.topology import build_mesh
+
+    cfg = dataclasses.replace(OLMOE_TINY, dtype=jnp.bfloat16)
+    mesh = build_mesh(axes=("data",), devices=jax.devices()[:1])
+    optimizer = optax.sgd(0.1)
+    tokens = jax.ShapeDtypeStruct((4, 32), jnp.int32)
+    params = tfm.init_abstract(cfg)
+    shapes = {params["layers"][0][name].shape
+              for name in moe.EXPERT_LEAVES}
+    assert shapes == {(8, 64, 32), (8, 32, 64)}
+
+    def copies():
+        step, _, _ = tfm.make_train_step(cfg, optimizer, mesh,
+                                         attention="local", donate=False)
+        jaxpr = jax.make_jaxpr(step)(
+            params, jax.eval_shape(optimizer.init, params), tokens, tokens)
+        return list(_expert_leaf_converts(jaxpr.jaxpr, shapes, jnp.bfloat16,
+                                          "moe_experts"))
+
+    assert not copies()
+    # The search finds the copies of a layer that does cast its leaves.
+    monkeypatch.setattr(moe, "_expert_operands", _bf16_copies)
+    assert len(copies()) >= 3 * cfg.n_layers
+
+
+@pytest.mark.parametrize("dtype,cast,expected", [
+    (jnp.bfloat16, False, 0), (jnp.float32, False, 0),
+    # What the layer did before: 3 x 8 x 64 x 32 parameters at 2 bytes.
+    (jnp.bfloat16, True, 98304)],
+    ids=("f32_masters_bf16_rows", "f32_masters_f32_rows",
+         "a_cast_at_the_call_site_is_counted"))
+def test_expert_weight_copy_bytes_gauge(hvd, monkeypatch, dtype, cast,
+                                        expected):
+    from horovod_tpu import telemetry
+
+    if cast:
+        monkeypatch.setattr(moe, "_expert_operands", _bf16_copies)
+    cfg = dataclasses.replace(OLMOE_TINY, dtype=dtype)
+    telemetry.reset_for_tests()
+    telemetry.configure(True)
+    try:
+        tokens = jax.ShapeDtypeStruct((4, 32), jnp.int32)
+        jax.eval_shape(lambda p, t: tfm.loss_fn(
+            p, t, t, cfg, attention="local"), tfm.init_abstract(cfg), tokens)
+        text = telemetry.render_prometheus()
+        for layer in (0, 1):
+            assert (f'hvd_moe_expert_weight_copy_bytes{{layer="{layer}"}} '
+                    f'{expected}' in text), text
+        assert "`hvd_moe_expert_weight_copy_bytes`" in _metrics_doc()
     finally:
         telemetry.reset_for_tests()
