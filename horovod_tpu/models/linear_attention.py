@@ -1,0 +1,312 @@
+"""Gated-delta-rule linear attention, the sequence mixer of a
+``"linear_attention"`` layer: what :mod:`horovod_tpu.models.transformer`
+runs in place of softmax attention where ``TransformerConfig.layer_types``
+says so (Olmo-Hybrid, Qwen3-Next and the other ``linear_*`` configs).
+
+The layer, as Gated DeltaNet (arXiv:2412.06464) and the public
+``linear_attention`` layer of HF transformers state it; per head,
+``x`` the normed input::
+
+    [q; k; v] = silu(causal_depthwise_conv1d([Wq x; Wk x; Wv x]))
+    q = l2norm(q) * d_k ** -0.5,  k = l2norm(k)
+    beta = sigmoid(Wb x) * (2 if allow_neg_eigval else 1)
+    g = -exp(A_log) * softplus(Wa x + dt_bias)            # alpha = exp(g)
+    S_t = alpha_t S_{t-1} + beta_t k_t (v_t - alpha_t S_{t-1}^T k_t)^T
+    o_t = S_t^T q_t
+    y = Wo (RMSNorm(o_t) * silu(Wz x))
+
+**The recurrence runs in chunked form** (:func:`gated_delta_rule`), never
+token by token: in a block of :data:`BLOCK` tokens with ``b_i`` the
+running sum of ``g`` from the block's start, ``u_i = beta_i (v_i -
+alpha_i S_{i-1}^T k_i)`` solves the unit-lower-triangular system
+
+    (I + N) U = diag(beta) (V - diag(exp b) K S_0),
+    N_ij = beta_i exp(b_i - b_j) (k_i . k_j)  for j < i,
+
+so with ``T = (I + N)^-1 diag(beta)``: ``U = T V - (T diag(exp b) K)
+S_0`` (the WY/UT form), ``O = diag(exp b) Q S_0 + (Q K^T * exp(b_i -
+b_j), j <= i) U`` and ``S_C = exp(b_C) S_0 + (diag(exp(b_C - b)) K)^T U``.
+Everything that does not need ``S_0`` (``N``, the inverse, ``T V``, ``T
+diag(exp b) K``, the masked ``Q K^T``) is computed for all blocks at
+once; a :func:`jax.lax.scan` over the blocks carries ``S`` and does three
+small matmuls a block.  Every decay is ``exp`` of a difference that is
+``<= 0``: nothing is divided, so an ``alpha`` near 0 underflows to an
+exact zero and never to ``inf``.
+
+Precision: ``g``, its running sums, the decays, ``N``, the inverse (its
+matmuls at precision ``highest``) and the carried state are float32;
+every other matmul takes operands in the model dtype (``T``, the state
+and ``U`` rounded to it where they are operands) and accumulates in
+float32; ``T V``, ``U`` and the part of the output that comes from
+``S_0`` are stored between the three phases in the model dtype.
+
+Backward: autodiff through the chunked form.  The inverse has its own
+rule (``d(A^-1) = -A^-1 dA A^-1``: two matmuls, where the chain of
+squarings would keep a dozen ``[BLOCK, BLOCK]`` matrices a block), and the
+scan's body is recomputed, so what the backward keeps of the scan is the
+state at each block's start (:func:`saved_state_bytes`).
+
+Not here: ``segment_ids`` (the state's reset at a document boundary and
+the convolution's mask: ROADMAP R11), a model or sequence axis, decode.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import PartitionSpec as P
+
+from horovod_tpu import telemetry
+from horovod_tpu.parallel._vma import pin_to, vma_of
+from horovod_tpu.telemetry import scopes
+
+# Tokens a block of the chunked recurrence holds: one [BLOCK, BLOCK]
+# triangular system a head and block, T / BLOCK steps of the scan.  Chosen
+# on the chip and on the CPU (PERF.md, PR 31): one layer's recurrence at
+# Olmo-Hybrid's sizes and 16384 tokens, forward + backward, takes 68.8 /
+# 65.0 / 49.1 / 84.4 ms at 32 / 64 / 128 / 256, but a [128, 128] system
+# with beta near 2 and alpha near 1 is conditioned a hundred times worse
+# than a [64, 64] one (float32 against the token-by-token recurrence:
+# 9e-4 where 64 reads 3e-6), and the operands are bf16.  64 is also the
+# family's.
+BLOCK = 64
+
+# The published initialisation of the gates (HF ``linear_attention``
+# layer, Mamba-2's): A ~ U(0, 16) and the step dt log-uniform in
+# [0.001, 0.1], stored as log A and softplus^-1(dt).
+A_INIT_RANGE = (1e-3, 16.0)
+DT_INIT_RANGE = (1e-3, 0.1)
+L2NORM_EPS = 1e-6
+
+
+def qkv_widths(cfg):
+    """Channels of q, k and v: what the convolution runs over."""
+    qk = cfg.linear_key_heads * cfg.linear_key_head_dim
+    return qk, qk, cfg.linear_value_heads * cfg.linear_value_head_dim
+
+
+def init_layer(key, cfg, dense):
+    """The mixer's leaves of one linear layer; ``dense(key, shape)`` is
+    the caller's matrix initialiser."""
+    d, h, dv = cfg.d_model, cfg.linear_value_heads, cfg.linear_value_head_dim
+    wq, wk, wv = qkv_widths(cfg)
+    k = jax.random.split(key, 10)
+    a = jax.random.uniform(k[7], (h,), jnp.float32, *A_INIT_RANGE)
+    dt = jnp.exp(jax.random.uniform(
+        k[8], (h,), jnp.float32, *(math.log(x) for x in DT_INIT_RANGE)))
+    bound = cfg.linear_conv_kernel ** -0.5      # torch's Conv1d, fan-in K
+    return {
+        "lin_wq": dense(k[0], (d, wq)), "lin_wk": dense(k[1], (d, wk)),
+        "lin_wv": dense(k[2], (d, wv)), "lin_wz": dense(k[3], (d, wv)),
+        "lin_wa": dense(k[4], (d, h)), "lin_wb": dense(k[5], (d, h)),
+        "lin_conv": jax.random.uniform(
+            k[6], (cfg.linear_conv_kernel, wq + wk + wv), jnp.float32,
+            -bound, bound),
+        "lin_a_log": jnp.log(a),
+        # softplus^-1(dt) = dt + log(1 - exp(-dt))
+        "lin_dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "lin_norm_scale": jnp.ones((dv,), jnp.float32),
+        "lin_wo": dense(k[9], (wv, d)),
+    }
+
+
+LEAVES = ("lin_wq", "lin_wk", "lin_wv", "lin_wz", "lin_wa", "lin_wb",
+          "lin_conv", "lin_a_log", "lin_dt_bias", "lin_norm_scale", "lin_wo")
+
+
+def layer_specs():
+    """Every leaf whole on every chip (no model axis: it is refused)."""
+    return {name: P() for name in LEAVES}
+
+
+def causal_conv(x, w):
+    """Depthwise causal convolution along T: ``x`` [B, T, C], ``w``
+    [K, C]; ``y_t = sum_j w_j x_{t-K+1+j}`` with zeros before the
+    sequence's start.  ``K`` shifted multiply-adds in float32, returned
+    in float32: what follows (``silu``, the per-head normalisation) reads
+    it unrounded, and the model dtype comes back once, at their end."""
+    taps, t = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    return sum(w[j] * lax.dynamic_slice_in_dim(padded, j, t, axis=1)
+               .astype(jnp.float32) for j in range(taps))
+
+
+def _l2norm(x):
+    return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2NORM_EPS)
+
+
+@jax.custom_vjp
+def _unit_lower_inverse(n):
+    """``(I + n)^-1`` for strictly lower-triangular ``n`` [..., C, C],
+    float32.  ``n`` is nilpotent (``n^C = 0``), so the Neumann series
+    ends and factors into ``log2 C`` products: ``(I - n)(I + n^2)(I +
+    n^4)...``: a dozen [C, C] matmuls and no loop over rows."""
+    size = n.shape[-1]
+    eye = jnp.eye(size, dtype=n.dtype)
+
+    def mm(a, b):
+        return jnp.matmul(a, b, precision=lax.Precision.HIGHEST)
+
+    inverse, power, reach = eye - n, n, 2
+    while reach < size:
+        power = mm(power, power)
+        inverse = inverse + mm(inverse, power)
+        reach *= 2
+    return inverse
+
+
+def _unit_lower_inverse_fwd(n):
+    inverse = _unit_lower_inverse(n)
+    return inverse, inverse
+
+
+def _unit_lower_inverse_bwd(inverse, g):
+    t = jnp.swapaxes(inverse, -1, -2)
+    hi = lax.Precision.HIGHEST
+    d = -jnp.matmul(jnp.matmul(t, g, precision=hi), t, precision=hi)
+    return (jnp.tril(d, -1),)
+
+
+_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def _mm(spec, a, b, dtype):
+    """einsum of operands in ``dtype``, accumulated in float32."""
+    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def gated_delta_rule(q, k, v, g, beta, dtype):
+    """The recurrence of the module's docstring from ``S_0 = 0``: ``q``,
+    ``k`` [B, T, H, d_k] (normalised, ``q`` scaled), ``v`` [B, T, H,
+    d_v], ``g`` (``log alpha <= 0``) and ``beta`` [B, T, H] float32 ->
+    ``o`` [B, T, H, d_v] in ``dtype``.  ``T`` a multiple of
+    :data:`BLOCK`."""
+    bsz, t, h, dk = q.shape
+    dv = v.shape[-1]
+    if t % BLOCK:
+        raise ValueError(f"linear attention: sequence length {t} is not a "
+                         f"multiple of the recurrence's block of {BLOCK}")
+    n = t // BLOCK
+
+    def blocks(x):       # [B, T, H, ...] -> [B, H, n, BLOCK, ...]
+        x = x.reshape((bsz, n, BLOCK, h) + x.shape[3:])
+        return jnp.moveaxis(x, 3, 1)
+
+    q, k, v = blocks(q), blocks(k), blocks(v)
+    beta = blocks(beta.astype(jnp.float32))
+    b = jnp.cumsum(blocks(g.astype(jnp.float32)), axis=-1)
+    rows = jnp.arange(BLOCK)
+    lower = rows[:, None] >= rows[None, :]
+    diff = b[..., :, None] - b[..., None, :]
+    # exp(b_i - b_j) for j <= i, else 0 (masked before the exp: above the
+    # diagonal the difference is positive and may overflow).
+    decay = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)
+    strict = jnp.where(rows[:, None] > rows[None, :], decay, 0.0)
+    kk = _mm("bhnid,bhnjd->bhnij", k, k, dtype)
+    solve = _unit_lower_inverse(beta[..., None] * strict * kk)
+    solve = solve * beta[..., None, :]                     # T
+    from_start = jnp.exp(b)[..., None]
+    u0 = _mm("bhnij,bhnjv->bhniv", solve, v, dtype)
+    w = _mm("bhnij,bhnjd->bhnid", solve, from_start * k, dtype)
+    to_end = jnp.exp(b[..., -1:] - b)[..., None]
+    xs = (w.astype(dtype), u0.astype(dtype), (to_end * k).astype(dtype),
+          (from_start * q).astype(dtype), jnp.exp(b[..., -1]))
+
+    @jax.checkpoint
+    def block(state, x):
+        w_c, u0_c, k_end, q_start, carry = x
+        u = u0_c - _mm("bhid,bhdv->bhiv", w_c, state, dtype)
+        across = _mm("bhid,bhdv->bhiv", q_start, state, dtype)
+        state = (carry[..., None, None] * state
+                 + _mm("bhid,bhiv->bhdv", k_end, u, dtype))
+        return state, (u.astype(dtype), across.astype(dtype))
+
+    # Blocks lead: the scan walks axis 0.
+    xs = jax.tree_util.tree_map(lambda x: jnp.moveaxis(x, 2, 0), xs)
+    # Inside shard_map the state varies over the axes its inputs do.
+    state = pin_to(vma_of(u0) | vma_of(w))(
+        jnp.zeros((bsz, h, dk, dv), jnp.float32))
+    _, (u, across) = lax.scan(block, state, xs)
+    u, across = jnp.moveaxis(u, 0, 2), jnp.moveaxis(across, 0, 2)
+    within = _mm("bhnid,bhnjd->bhnij", q, k, dtype) * decay
+    o = (across + _mm("bhnij,bhnjv->bhniv", within, u, dtype)).astype(dtype)
+    return jnp.moveaxis(o, 1, 3).reshape(bsz, t, h, dv)
+
+
+def saved_state_bytes(batch: int, t: int, cfg) -> int:
+    """Bytes of block states the backward of one layer's scan keeps: the
+    float32 state at the start of each block (the body is recomputed)."""
+    return (batch * (t // BLOCK) * cfg.linear_value_heads
+            * cfg.linear_key_head_dim * cfg.linear_value_head_dim * 4)
+
+
+def mixer(x, layer, cfg):
+    """The whole mixer on the normed ``x`` [B, T, d] -> [B, T, d] (the
+    caller adds the residual).  Opens its parts as bare components under
+    ``attn/qkv`` and ``attn/out`` and the recurrence as a route of its
+    own (``telemetry/scopes.py``)."""
+    dt = cfg.dtype
+    bsz, t, _ = x.shape
+    h, dk, dv = (cfg.linear_value_heads, cfg.linear_key_head_dim,
+                 cfg.linear_value_head_dim)
+    wq, wk, _ = qkv_widths(cfg)
+    with jax.named_scope(scopes.ATTN_QKV):
+        with jax.named_scope(scopes.GDN_PROJ):
+            q = x @ layer["lin_wq"].astype(dt)
+            k = x @ layer["lin_wk"].astype(dt)
+            v = x @ layer["lin_wv"].astype(dt)
+            z = x @ layer["lin_wz"].astype(dt)
+            # The gates leave their matmuls in float32: [B, T, H] each.
+            a = jnp.matmul(x, layer["lin_wa"].astype(dt),
+                           preferred_element_type=jnp.float32)
+            beta = jax.nn.sigmoid(jnp.matmul(
+                x, layer["lin_wb"].astype(dt),
+                preferred_element_type=jnp.float32))
+            if cfg.linear_allow_neg_eigval:
+                beta = 2.0 * beta
+            g = -jnp.exp(layer["lin_a_log"]) * jax.nn.softplus(
+                a + layer["lin_dt_bias"])
+        with jax.named_scope(scopes.GDN_CONV):
+            conv = layer["lin_conv"]
+            q = jax.nn.silu(causal_conv(q, conv[:, :wq]))
+            k = jax.nn.silu(causal_conv(k, conv[:, wq:wq + wk]))
+            v = jax.nn.silu(causal_conv(v, conv[:, wq + wk:]))
+            q = (_l2norm(q.reshape(bsz, t, h, dk)) * dk ** -0.5).astype(dt)
+            k = _l2norm(k.reshape(bsz, t, h, dk)).astype(dt)
+            v = v.reshape(bsz, t, h, dv).astype(dt)
+    with jax.named_scope(scopes.ATTN_GDN_SCAN):
+        o = gated_delta_rule(q, k, v, g, beta, dt)
+    with jax.named_scope(scopes.ATTN_OUT):
+        with jax.named_scope(scopes.GDN_GATE_NORM):
+            o = o.astype(jnp.float32)
+            o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                              + cfg.norm_eps) * layer["lin_norm_scale"]
+            o = (o.reshape(bsz, t, h * dv)
+                 * jax.nn.silu(z.astype(jnp.float32))).astype(dt)
+        with jax.named_scope(scopes.GDN_OUT):
+            return o @ layer["lin_wo"].astype(dt)
+
+
+def record_blocks(layer: int, batch: int, t: int, cfg) -> None:
+    """Trace-time series (what was compiled into the step, like
+    ``hvd_moe_assignments_total``): the blocks of the recurrence layer
+    ``layer`` walks per step on one device, over batch and heads, and the
+    bytes of block states its backward keeps."""
+    if not telemetry.enabled():
+        return
+    telemetry.counter(
+        "hvd_gdn_blocks_total",
+        "Blocks of the chunked gated-delta-rule recurrence the traced "
+        "linear-attention layer computes per step on one device (batch x "
+        "heads x T / block)",
+        layer=str(layer)).inc(batch * cfg.linear_value_heads * (t // BLOCK))
+    telemetry.gauge(
+        "hvd_gdn_saved_state_bytes",
+        "Bytes of block states the backward pass of the traced "
+        "linear-attention layer's recurrence keeps (0 = it recomputes "
+        "them)",
+        layer=str(layer)).set(saved_state_bytes(batch, t, cfg))

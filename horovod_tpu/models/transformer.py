@@ -20,7 +20,12 @@ positions, QK-norm, SwiGLU, an untied head and a dropless
 mixture-of-experts MLP (:mod:`horovod_tpu.models.moe`) are fields of the
 same config through the same ``forward`` and ``make_train_step`` — OLMoE's
 block is ``positions="rope", qk_norm=True, mlp="swiglu",
-tie_embeddings=False, n_experts=64, experts_per_token=8``.
+tie_embeddings=False, n_experts=64, experts_per_token=8``.  ``layer_types``
+names each layer's sequence mixer: ``"full_attention"`` (the block above)
+or ``"linear_attention"`` (the gated delta rule of
+:mod:`horovod_tpu.models.linear_attention`, sized by the ``linear_*``
+fields); Olmo-Hybrid is three linear layers to one full one, with
+``positions="none"``.
 """
 
 from __future__ import annotations
@@ -35,11 +40,16 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.models import moe
+from horovod_tpu.models import linear_attention, moe
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel import sequence as seq_mod
 from horovod_tpu.parallel import tensor as tp
 from horovod_tpu.telemetry import scopes
+
+
+FULL_ATTENTION = "full_attention"
+LINEAR_ATTENTION = "linear_attention"
+LAYER_TYPES = (FULL_ATTENTION, LINEAR_ATTENTION)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,7 +64,8 @@ class TransformerConfig:
     # --- the block; the defaults are the GPT-2-style one -----------------
     # "learned": a [max_seq, d_model] table added to the embedding;
     # "rope": rotary embedding of q and k (rotate-half convention,
-    # positions from 0), no table.
+    # positions from 0), no table; "none": neither (a hybrid's recurrent
+    # layers carry position, its full layers see the causal mask alone).
     positions: str = "learned"
     rope_theta: float = 10000.0
     # RMSNorm, each with its own scale, over the whole q and the whole k
@@ -76,11 +87,48 @@ class TransformerConfig:
     # and of the router z-loss (moe.router_losses).
     router_aux_coef: float = 0.0
     router_z_coef: float = 0.0
+    # One of LAYER_TYPES per layer; empty: "full_attention" everywhere.
+    # A "linear_attention" layer's mixer is the gated delta rule
+    # (models/linear_attention.py) over ``linear_key_heads`` heads of
+    # ``linear_key_head_dim`` (q, k) and ``linear_value_head_dim`` (v, the
+    # state's other side), after a causal depthwise convolution of
+    # ``linear_conv_kernel`` taps; beta in (0, 2) with
+    # ``linear_allow_neg_eigval``, else (0, 1).
+    layer_types: Tuple[str, ...] = ()
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 0
+    linear_allow_neg_eigval: bool = False
 
     def __post_init__(self):
-        if self.positions not in ("learned", "rope"):
+        if self.positions not in ("learned", "rope", "none"):
             raise ValueError(f"positions={self.positions!r}: expected "
-                             f"'learned' or 'rope'")
+                             f"'learned', 'rope' or 'none'")
+        linear = (self.linear_key_heads, self.linear_value_heads,
+                  self.linear_key_head_dim, self.linear_value_head_dim,
+                  self.linear_conv_kernel)
+        if self.layer_types:
+            unknown = set(self.layer_types) - set(LAYER_TYPES)
+            if unknown or len(self.layer_types) != self.n_layers:
+                raise ValueError(
+                    f"layer_types={self.layer_types!r}: expected n_layers="
+                    f"{self.n_layers} entries of {LAYER_TYPES}")
+        if self.has_linear_layers:
+            if min(linear) <= 0:
+                raise ValueError(
+                    "a 'linear_attention' layer needs linear_key_heads, "
+                    "linear_value_heads, linear_key_head_dim, "
+                    "linear_value_head_dim and linear_conv_kernel")
+            if self.linear_key_heads != self.linear_value_heads:
+                raise NotImplementedError(
+                    f"linear_value_heads={self.linear_value_heads} != "
+                    f"linear_key_heads={self.linear_key_heads}: value heads "
+                    f"that share a key head are not implemented")
+        elif any(linear) or self.linear_allow_neg_eigval:
+            raise ValueError("the linear_* fields mean nothing without a "
+                             "'linear_attention' entry in layer_types")
         if self.mlp not in ("gelu", "swiglu"):
             raise ValueError(f"mlp={self.mlp!r}: expected 'gelu' or "
                              f"'swiglu'")
@@ -108,6 +156,13 @@ class TransformerConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    @property
+    def has_linear_layers(self) -> bool:
+        return LINEAR_ATTENTION in self.layer_types
+
+    def layer_type(self, i: int) -> str:
+        return self.layer_types[i] if self.layer_types else FULL_ATTENTION
+
 
 def _refuse(cfg: TransformerConfig, where: str, fields) -> None:
     """Raise for config fields ``where`` does not implement — never a
@@ -119,6 +174,21 @@ def _refuse(cfg: TransformerConfig, where: str, fields) -> None:
             raise NotImplementedError(
                 f"{where} does not implement TransformerConfig.{name}="
                 f"{getattr(cfg, name)!r}")
+
+
+def _refuse_with_linear_layers(cfg: TransformerConfig, **arguments) -> None:
+    """Raise, by the argument's name, for what the linear-attention
+    layers do not implement: a sequence axis (the state crosses chunk
+    boundaries in order) and ``segment_ids`` / ``packed`` (the state's
+    reset and the convolution's mask at a document boundary: ROADMAP
+    R11)."""
+    if not cfg.has_linear_layers:
+        return
+    for name, value in arguments.items():
+        if value is not None and value is not False:
+            raise NotImplementedError(
+                f"{name}={value!r}: the 'linear_attention' layers of "
+                f"TransformerConfig.layer_types do not implement it")
 
 
 def init_params(rng, cfg: TransformerConfig):
@@ -142,14 +212,15 @@ def init_params(rng, cfg: TransformerConfig):
         layer = {
             "ln1_scale": jnp.ones((d,), jnp.float32),
             "ln2_scale": jnp.ones((d,), jnp.float32),
-            "wq": dense(k[0], (d, d)),
-            "wk": dense(k[1], (d, d)),
-            "wv": dense(k[2], (d, d)),
-            "wo": dense(k[3], (d, d)),
         }
-        if cfg.qk_norm:
-            layer["q_norm_scale"] = jnp.ones((d,), jnp.float32)
-            layer["k_norm_scale"] = jnp.ones((d,), jnp.float32)
+        if cfg.layer_type(i) == LINEAR_ATTENTION:
+            layer.update(linear_attention.init_layer(k[0], cfg, dense))
+        else:
+            layer.update(wq=dense(k[0], (d, d)), wk=dense(k[1], (d, d)),
+                         wv=dense(k[2], (d, d)), wo=dense(k[3], (d, d)))
+            if cfg.qk_norm:
+                layer["q_norm_scale"] = jnp.ones((d,), jnp.float32)
+                layer["k_norm_scale"] = jnp.ones((d,), jnp.float32)
         if cfg.n_experts:
             e = cfg.d_expert
             layer.update(router=dense(k_router, (d, cfg.n_experts)),
@@ -182,12 +253,12 @@ def param_specs(cfg: TransformerConfig, model_axis: Optional[str]):
     m = model_axis
     col = P(None, m)     # split output dim
     row = P(m, None)     # split input dim
-    layer = {
-        "ln1_scale": P(), "ln2_scale": P(),
-        "wq": col, "wk": col, "wv": col, "wo": row,
-    }
+    attention = {"wq": col, "wk": col, "wv": col, "wo": row}
     if cfg.qk_norm:
-        layer.update(q_norm_scale=P(), k_norm_scale=P())
+        attention.update(q_norm_scale=P(), k_norm_scale=P())
+    mixers = {FULL_ATTENTION: attention,
+              LINEAR_ATTENTION: linear_attention.layer_specs()}
+    layer = {"ln1_scale": P(), "ln2_scale": P()}
     if cfg.n_experts:
         # Every expert on every chip (experts over an axis: ROADMAP R2).
         layer.update(router=P(), w_gate=P(), w_up=P(), w_down=P())
@@ -198,7 +269,8 @@ def param_specs(cfg: TransformerConfig, model_axis: Optional[str]):
     specs = {
         "embed": P(),
         "ln_f_scale": P(),
-        "layers": [dict(layer) for _ in range(cfg.n_layers)],
+        "layers": [dict(layer, **mixers[cfg.layer_type(i)])
+                   for i in range(cfg.n_layers)],
     }
     if cfg.positions == "learned":
         specs["pos"] = P()
@@ -331,9 +403,11 @@ def _logits_head(x, params, cfg):
 
 def _refuse_under_model_axis(cfg, model_axis) -> None:
     # QK-norm's statistics span the whole projection, which the model
-    # axis splits; the experts live whole on every chip (ROADMAP R2).
+    # axis splits; the experts live whole on every chip (ROADMAP R2), and
+    # so do the linear-attention layers' heads.
     if model_axis:
-        _refuse(cfg, f"model_axis={model_axis!r}", ("qk_norm", "n_experts"))
+        _refuse(cfg, f"model_axis={model_axis!r}",
+                ("qk_norm", "n_experts", "layer_types"))
 
 
 def _remat_wrap(body, remat: str):
@@ -344,8 +418,14 @@ def _remat_wrap(body, remat: str):
     * ``"none"``  — save every intermediate (XLA default).
     * ``"dots"``  — save matmul outputs only, recompute elementwise
       (``checkpoint_dots``): the usual sweet spot, cheap recompute.
-    * ``"full"``  — save only layer inputs, recompute the whole block in
-      the backward: O(L) fewer activation bytes, ~1.3x fwd FLOPs.
+    * ``"full"``  — save only the block's inputs, recompute it in the
+      backward: O(L) fewer activation bytes, ~1.3x fwd FLOPs.
+
+    A layer is two blocks, its sequence mixer and its MLP, each wrapped
+    by itself: the backward holds one half's recomputed intermediates at
+    a time (a whole layer's do not fit a v5e beside an Olmo-Hybrid
+    period at 16384 tokens, PERF.md PR 31), for one more saved [B, T, d]
+    a layer.
     """
     if remat == "none":
         return body
@@ -388,6 +468,8 @@ def forward_with_router_stats(params, tokens, cfg: TransformerConfig,
     token) after its head scatter.
     """
     _refuse_under_model_axis(cfg, model_axis)
+    _refuse_with_linear_layers(cfg, seq_axis=seq_axis,
+                               segment_ids=segment_ids)
     dt = cfg.dtype
     t_local = tokens.shape[1]
     with jax.named_scope(scopes.EMBED):
@@ -401,7 +483,7 @@ def forward_with_router_stats(params, tokens, cfg: TransformerConfig,
             positions = pos_offset + jnp.arange(t_local)
             x = params["embed"][tokens].astype(dt)
 
-    def layer_block(x, layer, segment_ids):
+    def attention_part(x, layer, segment_ids):
         # --- attention block (each route opens its own attn/<route>) ---
         with jax.named_scope(scopes.ATTN_QKV):
             q, k, v, dh = _qkv_proj(x, layer, cfg, model_axis, positions)
@@ -441,17 +523,36 @@ def forward_with_router_stats(params, tokens, cfg: TransformerConfig,
             o = seq_mod.local_attention(q, k, v, causal=True,
                                         segment_ids=segment_ids)
         with jax.named_scope(scopes.ATTN_OUT):
-            x = _attn_out(o.reshape(b, t, dh), x, layer, dt, model_axis)
+            return _attn_out(o.reshape(b, t, dh), x, layer, dt, model_axis)
+
+    def mlp_part(x, layer):
         with jax.named_scope(scopes.MLP):
             if cfg.n_experts:
                 return _moe_block(x, layer, cfg)
             return _mlp_block(x, layer, cfg, model_axis), None
 
-    layer_block = _remat_wrap(layer_block, remat)
+    def linear_attention_part(x, layer, segment_ids):
+        # The mixer opens its own scopes (attn/qkv/gdn_*, attn/gdn_scan,
+        # attn/out/gdn_*); the norm is booked with its projections and
+        # the residual add with the out projection.
+        with jax.named_scope(scopes.ATTN_QKV), \
+                jax.named_scope(scopes.GDN_PROJ):
+            h = _rmsnorm(x, layer["ln1_scale"], cfg.norm_eps)
+        y = linear_attention.mixer(h, layer, cfg)
+        with jax.named_scope(scopes.ATTN_OUT), \
+                jax.named_scope(scopes.GDN_OUT):
+            return x + y
+
+    mixers = {FULL_ATTENTION: _remat_wrap(attention_part, remat),
+              LINEAR_ATTENTION: _remat_wrap(linear_attention_part, remat)}
+    mlp_part = _remat_wrap(mlp_part, remat)
     router_stats = []
     for i, layer in enumerate(params["layers"]):
         with jax.named_scope(scopes.LAYER % i):
-            x, stats = layer_block(x, layer, segment_ids)
+            x = mixers[cfg.layer_type(i)](x, layer, segment_ids)
+            x, stats = mlp_part(x, layer)
+        if cfg.layer_type(i) == LINEAR_ATTENTION:
+            linear_attention.record_blocks(i, tokens.shape[0], t_local, cfg)
         if cfg.n_experts:
             router_stats.append(stats)
             moe.record_assignments(
@@ -536,6 +637,7 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
     from horovod_tpu.ops.fusion import fused_pytree_mean
 
     _refuse_under_model_axis(cfg, model_axis)
+    _refuse_with_linear_layers(cfg, seq_axis=seq_axis, packed=packed)
     specs = param_specs(cfg, model_axis)
     grad_axes = tuple(a for a in (data_axis, seq_axis) if a)
 
@@ -693,9 +795,10 @@ def decode_step(params, token, cache, pos, cfg: TransformerConfig,
     the full static cache length with a position mask (TPU-friendly: no
     dynamic shapes), so cost is O(max_len) per step.
     """
-    # A rotated key cache and an expert layer per token are not written
-    # (serving: ROADMAP R8/R13).
-    _refuse(cfg, "decode_step", ("positions", "n_experts"))
+    # A rotated key cache, an expert layer per token and a recurrent
+    # layer's state and convolution window beside the key cache are not
+    # written (serving: ROADMAP R8/R13).
+    _refuse(cfg, "decode_step", ("positions", "n_experts", "layer_types"))
     dt = cfg.dtype
     hd = cfg.head_dim
     x = (params["embed"][token] +
@@ -958,9 +1061,10 @@ def make_train_step_pipelined(cfg: TransformerConfig, optimizer, mesh,
     from jax.sharding import NamedSharding
 
     # The pipelined forward embeds with the position table, scans stacked
-    # dense layers and returns no router sums.
+    # dense layers of one type and returns no router sums.
     _refuse(cfg, "make_train_step_pipelined",
-            ("positions", "qk_norm", "tie_embeddings", "mlp", "n_experts"))
+            ("positions", "qk_norm", "tie_embeddings", "mlp", "n_experts",
+             "layer_types"))
     n_stages = mesh.shape[pipe_axis]
     v_eff = (virtual if schedule in ("interleaved", "interleaved_1f1b")
              else 1)

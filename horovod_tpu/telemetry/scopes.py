@@ -33,6 +33,18 @@ MOE_DISPATCH = "moe_dispatch"
 MOE_EXPERTS = "moe_experts"
 MOE_COMBINE = "moe_combine"
 
+# The parts of a gated-delta-rule linear-attention layer
+# (models/linear_attention.py).  The recurrence is the layer's route, as
+# flash_attention is a full layer's; projections and convolution open
+# under ATTN_QKV, the gated norm and the out projection under ATTN_OUT, as
+# bare path components (".../layer_0/attn/qkv/gdn_conv/..."), so a reader
+# that knows only the model scopes still answers "attn/qkv", "attn/out".
+ATTN_GDN_SCAN = "attn/gdn_scan"
+GDN_PROJ = "gdn_proj"
+GDN_CONV = "gdn_conv"
+GDN_GATE_NORM = "gdn_gate_norm"
+GDN_OUT = "gdn_out"
+
 # Step scopes: what the step does with the gradients.
 GRAD_MEAN = "grad_mean"
 OPTIMIZER = "optimizer"

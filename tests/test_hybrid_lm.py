@@ -1,0 +1,421 @@
+"""Per-layer types and the gated-delta-rule linear-attention layer
+(``models/linear_attention.py``), at tiny sizes on the virtual CPU mesh.
+
+Oracle: the benchmark's plain float32 reference
+(``perfbench/reference/hybrid_lm.py``), which shares no code with the
+program and walks the recurrence one token at a time.  Tolerances, float32
+everywhere unless a test says otherwise: 5e-5 relative L2, which is
+float32 rounding through four layers and a 256-token recurrence (the
+chunked form sums in another order and inverts a [64, 64] triangular
+system where the reference substitutes; bfloat16 operands anywhere read
+3e-3 and up, and one test proves that).
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from horovod_tpu.models import linear_attention as la
+from horovod_tpu.models import transformer as tfm
+from perfbench.reference import hybrid_lm as reference
+
+F32_REL = 5e-5
+
+PATTERN = ("linear_attention",) * 3 + ("full_attention",)
+HYBRID_TINY = tfm.TransformerConfig(
+    vocab_size=128, d_model=64, n_heads=2, n_layers=4, d_ff=96, max_seq=256,
+    dtype=jnp.float32, positions="none", qk_norm=True, norm_eps=1e-6,
+    tie_embeddings=False, mlp="swiglu", layer_types=PATTERN,
+    linear_key_heads=2, linear_value_heads=2, linear_key_head_dim=24,
+    linear_value_head_dim=48, linear_conv_kernel=4,
+    linear_allow_neg_eigval=True)
+OLMOE_TINY = tfm.TransformerConfig(
+    vocab_size=128, d_model=64, n_heads=2, n_layers=2, d_ff=0, max_seq=64,
+    dtype=jnp.float32, positions="rope", qk_norm=True, norm_eps=1e-5,
+    tie_embeddings=False, mlp="swiglu", n_experts=8, experts_per_token=2,
+    d_expert=32, router_aux_coef=0.01, router_z_coef=0.001)
+GPT2_TINY = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
+                                  n_layers=2, d_ff=64, max_seq=128)
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def _batch(cfg, batch=2, seq=256, seed=1):
+    toks = jax.random.randint(jax.random.PRNGKey(seed), (batch, seq + 1), 0,
+                              cfg.vocab_size)
+    return toks[:, :-1], toks[:, 1:]
+
+
+def _reference(cfg, params, tokens, labels, **kw):
+    return reference.loss_and_tail_grads(
+        params, tokens, labels, n_heads=cfg.n_heads,
+        layer_types=cfg.layer_types, linear_heads=cfg.linear_value_heads,
+        key_dim=cfg.linear_key_head_dim, eps=cfg.norm_eps,
+        neg_eigval=cfg.linear_allow_neg_eigval, **kw)
+
+
+# --- the chunked recurrence and the layer -----------------------------------
+
+# g = log alpha per token and head is drawn uniformly from the range.
+GATES = {"alpha_mid": (-0.2, 0.0), "alpha_near_0": (-30.0, -5.0),
+         "alpha_near_1": (-1e-4, 0.0)}
+
+
+@pytest.mark.parametrize("blocks", (1, 4))
+@pytest.mark.parametrize("gates", GATES.values(), ids=GATES.keys())
+def test_chunked_recurrence_matches_token_by_token(gates, blocks):
+    """Forward and all five gradients, with beta up to 2 (the negative
+    eigenvalues), against the reference's scan over single tokens."""
+    t, h, dk, dv = blocks * la.BLOCK, 3, 24, 40
+    ks = jax.random.split(jax.random.key(0), 5)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (t, h, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], (t, h, dk)))
+    v = jax.random.normal(ks[2], (t, h, dv))
+    g = jax.random.uniform(ks[3], (t, h), minval=gates[0], maxval=gates[1])
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[4], (t, h)) + 1.0)
+    assert float(beta.max()) > 1.5
+
+    def chunked(q, k, v, g, beta):
+        return la.gated_delta_rule(q[None], k[None], v[None], g[None],
+                                   beta[None], jnp.float32)[0]
+
+    def by_token(q, k, v, g, beta):
+        return reference._delta_rule(q, k, v, jnp.exp(g), beta, None)
+
+    loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)))
+    with jax.default_matmul_precision("highest"):
+        got, got_g = jax.value_and_grad(loss(chunked), range(5))(
+            q, k, v, g, beta)
+        want, want_g = jax.value_and_grad(loss(by_token), range(5))(
+            q, k, v, g, beta)
+        assert _rel(chunked(q, k, v, g, beta),
+                    by_token(q, k, v, g, beta)) <= F32_REL
+    assert abs(got - want) <= F32_REL * abs(want)
+    for name, a, b in zip("q k v g beta".split(), got_g, want_g):
+        # Near alpha = 0 the gradient with respect to g is itself 1e-3
+        # of the others, and what is left of it is the rounding of
+        # exp(b_i - b_j) sums that cancel: an absolute floor at 1e-6 of
+        # the gradient of v.
+        bound = (2e-5 * np.linalg.norm(b)
+                 + 1e-6 * np.linalg.norm(want_g[2]))
+        assert np.linalg.norm(np.asarray(a - b)) <= bound, name
+
+
+def test_unit_lower_inverse_and_its_gradient():
+    n = jnp.tril(jax.random.normal(jax.random.key(1), (3, 64, 64)), -1) * 0.3
+    eye = jnp.eye(64)
+    with jax.default_matmul_precision("highest"):
+        got = la._unit_lower_inverse(n)
+        want = jnp.linalg.inv(eye + n)
+        g = jax.grad(lambda n: jnp.sum(jnp.cos(la._unit_lower_inverse(n))))(n)
+        w = jax.grad(lambda n: jnp.sum(jnp.cos(jnp.linalg.inv(eye + n))))(n)
+    assert _rel(got, want) <= 1e-5
+    assert _rel(g, jnp.tril(w, -1)) <= 1e-5
+
+
+def test_causal_conv_sees_no_future_token_and_pads_with_zeros():
+    x = jax.random.normal(jax.random.key(2), (1, 16, 6))
+    w = jax.random.normal(jax.random.key(3), (4, 6))
+    got = la.causal_conv(x, w)
+    np.testing.assert_allclose(got[0], reference._conv(x[0], w), atol=1e-6)
+    # The newest tap weighs the token itself, and the first output is
+    # that tap alone.
+    np.testing.assert_allclose(got[0, 0], w[3] * x[0, 0], atol=1e-6)
+    moved = la.causal_conv(x.at[0, 9].add(1.0), w) - got
+    assert not np.asarray(moved[0, :9]).any()
+    assert np.asarray(moved[0, 9:13]).any(axis=-1).all()
+    assert not np.asarray(moved[0, 13:]).any()
+
+
+def _linear_layer(gates):
+    cfg = HYBRID_TINY
+    layer = tfm.init_params(jax.random.PRNGKey(4), cfg)["layers"][0]
+    lo, hi = gates
+    # alpha = exp(-exp(a_log) softplus(. + dt_bias)): a softplus near
+    # 0.7 (dt_bias 0) times a rate drawn from the range.
+    rate = jnp.linspace(max(-hi, 1e-4), -lo, cfg.linear_value_heads)
+    return dict(layer, lin_a_log=jnp.log(rate / 0.7),
+                lin_dt_bias=jnp.zeros_like(layer["lin_dt_bias"]))
+
+
+@pytest.mark.parametrize("gates", GATES.values(), ids=GATES.keys())
+def test_linear_mixer_matches_the_reference_in_every_leaf(gates):
+    """Projections, convolution, gates, recurrence, gated norm and out
+    projection: forward, and the gradient of each of the eleven leaves
+    and of the input."""
+    cfg, layer = HYBRID_TINY, _linear_layer(gates)
+    x = jax.random.normal(jax.random.key(5), (2, 256, cfg.d_model))
+
+    def program(x, layer):
+        return la.mixer(x, layer, cfg)
+
+    def plain(x, layer):
+        return jax.vmap(lambda s: reference._linear_mixer(
+            s, layer, cfg.linear_value_heads, cfg.linear_key_head_dim,
+            cfg.norm_eps, True, None))(x)
+
+    loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)))
+    with jax.default_matmul_precision("highest"):
+        assert _rel(program(x, layer), plain(x, layer)) <= F32_REL
+        got = jax.grad(loss(program), (0, 1))(x, layer)
+        want = jax.grad(loss(plain), (0, 1))(x, layer)
+    assert sorted(got[1]) == sorted(la.LEAVES + ("ln1_scale", "ln2_scale",
+                                                 "w_gate", "w_up", "w_down"))
+    assert _rel(got[0], want[0]) <= 2 * F32_REL
+    for name in la.LEAVES:
+        assert np.abs(np.asarray(want[1][name])).max() > 0, name
+        assert _rel(got[1][name], want[1][name]) <= 4 * F32_REL, name
+
+
+def test_sequence_length_must_be_whole_blocks():
+    cfg, layer = HYBRID_TINY, _linear_layer(GATES["alpha_mid"])
+    with pytest.raises(ValueError, match=f"block of {la.BLOCK}"):
+        la.mixer(jnp.zeros((1, la.BLOCK + 8, cfg.d_model)), layer, cfg)
+
+
+# --- the four-layer 3:1 model, against the plain reference ------------------
+
+@pytest.mark.parametrize("dtype,loss_rtol,grad_rel", [
+    # float32 against float32: rounding alone.
+    (jnp.float32, F32_REL, 2e-4),
+    # bfloat16 operands: three digits in the loss; over 256 tokens the
+    # gradient through the decay (lin_wa) is a small difference of large
+    # terms and reads 0.19 where the others read 0.01-0.05.
+    (jnp.bfloat16, 3e-3, 0.3),
+], ids=("float32", "bfloat16"))
+def test_loss_and_tail_gradients_match_the_reference(dtype, loss_rtol,
+                                                     grad_rel):
+    cfg = dataclasses.replace(HYBRID_TINY, dtype=dtype)
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    tokens, labels = _batch(cfg)
+    loss, grads = jax.value_and_grad(tfm.loss_fn)(
+        params, tokens, labels, cfg, attention="local")
+    want, want_g, gates = jax.jit(
+        lambda *a: _reference(cfg, *a))(params, tokens, labels)
+    assert abs(loss - want) <= loss_rtol * abs(want)
+    got_g = _checked(grads)
+    for name, g in want_g.items():
+        assert _rel(got_g[name], g) <= grad_rel, name
+    assert gates.shape == (3, 6)
+    assert (np.asarray(gates[:, 0]) > 0).all()
+    assert (np.asarray(gates[:, 4]) <= 1).all()
+    assert 1.0 < float(gates[:, 5].max()) <= 2.0
+    if dtype == jnp.float32:
+        # Tight enough for the precision stated: the reference with
+        # bfloat16 operands misses it.
+        low, low_g, _ = jax.jit(lambda *a: _reference(
+            cfg, *a, low_precision=jnp.bfloat16))(params, tokens, labels)
+        assert abs(low - want) > loss_rtol * abs(want)
+        assert _rel(low_g["lin_wa_last"], want_g["lin_wa_last"]) > grad_rel
+
+
+def _checked(tree):
+    return {"ln_f_scale": tree["ln_f_scale"],
+            "w_down_last": tree["layers"][3]["w_down"],
+            "lin_wo_last": tree["layers"][2]["lin_wo"],
+            "lin_wa_last": tree["layers"][2]["lin_wa"]}
+
+
+@pytest.mark.parametrize("remat", ("dots", "full"))
+def test_remat_leaves_loss_and_gradients_alone(remat):
+    params = tfm.init_params(jax.random.PRNGKey(0), HYBRID_TINY)
+    tokens, labels = _batch(HYBRID_TINY)
+    run = lambda r: jax.value_and_grad(tfm.loss_fn)(
+        params, tokens, labels, HYBRID_TINY, attention="local", remat=r)
+    (loss, grads), (want, want_g) = run(remat), run("none")
+    np.testing.assert_allclose(loss, want, rtol=1e-6)
+    # The same arithmetic fused otherwise: float32 rounding, leaf by leaf
+    # (7e-5 on the gates' leaves, whose gradient is a small difference of
+    # large terms; 1e-5 and under elsewhere).
+    for a, b in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(want_g)):
+        assert _rel(a, b) <= 2e-4
+
+
+@pytest.mark.parametrize("devices", (1, 4))
+def test_train_step_takes_the_gradient_of_the_global_batch(hvd, devices):
+    """Through ``make_train_step``, on one device and on a four-device
+    data mesh: loss = the reference's on the whole batch; update = -lr x
+    the reference's gradient of the **global** batch mean."""
+    from horovod_tpu.topology import build_mesh
+
+    cfg, lr = HYBRID_TINY, 0.1
+    mesh = build_mesh(axes=("data",), devices=jax.devices()[:devices])
+    optimizer = optax.sgd(lr)
+    step, _, _ = tfm.make_train_step(cfg, optimizer, mesh, attention="local",
+                                     donate=False, remat="full")
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    tokens, labels = _batch(cfg, batch=4)
+    new, _, loss = step(params, optimizer.init(params), tokens, labels)
+    want, want_g, _ = jax.jit(lambda *a: _reference(cfg, *a))(
+        params, tokens, labels)
+    assert abs(loss - want) <= F32_REL * abs(want)
+    after, before = _checked(new), _checked(params)
+    for name, g in want_g.items():
+        # (after - before) / -lr loses three digits to the subtraction.
+        assert _rel((after[name] - before[name]) / -lr, g) <= 2e-3, name
+
+
+# --- refusals: never a silent fall back -------------------------------------
+
+def test_segment_ids_and_packed_are_refused_by_name(hvd):
+    from horovod_tpu.topology import build_mesh
+
+    params = tfm.init_abstract(HYBRID_TINY)
+    tokens = jnp.zeros((2, 256), jnp.int32)
+    with pytest.raises(NotImplementedError, match="segment_ids"):
+        jax.eval_shape(lambda p: tfm.forward(
+            p, tokens, HYBRID_TINY, attention="local",
+            segment_ids=jnp.zeros_like(tokens)), params)
+    mesh = build_mesh(axes=("data",), devices=jax.devices()[:2])
+    with pytest.raises(NotImplementedError, match="packed"):
+        tfm.make_train_step(HYBRID_TINY, optax.sgd(0.1), mesh, packed=True)
+
+
+@pytest.mark.parametrize("axis", ("model", "seq"))
+def test_model_and_sequence_axes_are_refused_by_name(hvd, axis):
+    from horovod_tpu.topology import build_mesh
+
+    mesh = build_mesh(axes=("data", axis), shape=(2, 2),
+                      devices=jax.devices()[:4])
+    match = "layer_types" if axis == "model" else "seq_axis"
+    cfg = dataclasses.replace(HYBRID_TINY, qk_norm=False)
+    with pytest.raises(NotImplementedError, match=match):
+        tfm.make_train_step(cfg, optax.sgd(0.1), mesh,
+                            **{f"{axis}_axis": axis})
+
+
+def test_decode_and_the_pipelined_builder_refuse_layer_types(hvd):
+    from horovod_tpu.topology import build_mesh
+
+    cfg = dataclasses.replace(
+        HYBRID_TINY, positions="learned", qk_norm=False, mlp="gelu",
+        tie_embeddings=True)
+    params = tfm.init_abstract(cfg)
+    with pytest.raises(NotImplementedError, match="layer_types"):
+        tfm.decode_step(params, jnp.zeros((2,), jnp.int32),
+                        tfm.init_kv_cache(cfg, 2, 8), 0, cfg)
+    mesh = build_mesh(axes=("data", "pipe"), shape=(2, 2),
+                      devices=jax.devices()[:4])
+    with pytest.raises(NotImplementedError, match="layer_types"):
+        tfm.make_train_step_pipelined(cfg, optax.sgd(0.1), mesh)
+    with pytest.raises(NotImplementedError, match="positions"):
+        tfm.decode_step(tfm.init_abstract(HYBRID_TINY),
+                        jnp.zeros((2,), jnp.int32),
+                        tfm.init_kv_cache(HYBRID_TINY, 2, 8), 0, HYBRID_TINY)
+
+
+@pytest.mark.parametrize("fields,error,message", [
+    (dict(layer_types=("linear_attention",)), ValueError, "n_layers"),
+    (dict(layer_types=("full_attention", "sliding") * 2), ValueError,
+     "layer_types"),
+    (dict(linear_key_head_dim=0), ValueError, "linear_key_head_dim"),
+    (dict(linear_value_heads=4), NotImplementedError, "linear_value_heads"),
+    (dict(layer_types=(), linear_allow_neg_eigval=True), ValueError,
+     "linear_"),
+    (dict(positions="alibi"), ValueError, "positions"),
+])
+def test_config_refuses_what_it_cannot_mean(fields, error, message):
+    with pytest.raises(error, match=message):
+        dataclasses.replace(HYBRID_TINY, **fields)
+
+
+# --- the trees, the counters, and what the other configurations lower to ----
+
+@pytest.mark.parametrize("cfg", (GPT2_TINY, OLMOE_TINY, HYBRID_TINY),
+                         ids=("gpt2", "olmoe", "olmo_hybrid"))
+def test_specs_and_abstract_params_cover_every_leaf(cfg):
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    abstract = tfm.init_abstract(cfg)
+    specs = tfm.param_specs(cfg, None)
+    paths = lambda tree, **kw: sorted(
+        jax.tree_util.keystr(p)
+        for p, _ in jax.tree_util.tree_flatten_with_path(tree, **kw)[0])
+    from jax.sharding import PartitionSpec
+    is_spec = lambda x: isinstance(x, PartitionSpec)
+    assert paths(params) == paths(abstract) == paths(specs, is_leaf=is_spec)
+    for a, b in zip(jax.tree_util.tree_leaves(params),
+                    jax.tree_util.tree_leaves(abstract)):
+        assert (a.shape, a.dtype) == (b.shape, b.dtype)
+    for i in range(cfg.n_layers):
+        linear = cfg.layer_type(i) == tfm.LINEAR_ATTENTION
+        assert ("lin_wq" in params["layers"][i]) == linear
+        assert ("wq" in params["layers"][i]) != linear
+
+
+def test_published_gate_initialisation_ranges():
+    layer = tfm.init_params(jax.random.PRNGKey(7), dataclasses.replace(
+        HYBRID_TINY, linear_key_heads=64, linear_value_heads=64))["layers"][0]
+    a = np.exp(np.asarray(layer["lin_a_log"]))
+    dt = np.log1p(np.exp(np.asarray(layer["lin_dt_bias"])))
+    assert a.min() >= la.A_INIT_RANGE[0] and a.max() <= la.A_INIT_RANGE[1]
+    assert (dt.min() >= la.DT_INIT_RANGE[0] * 0.999
+            and dt.max() <= la.DT_INIT_RANGE[1] * 1.001)
+    assert np.abs(np.asarray(layer["lin_conv"])).max() <= 0.5
+
+
+def test_block_counters_count_what_was_traced(hvd):
+    from horovod_tpu import telemetry
+
+    telemetry.reset_for_tests()
+    telemetry.configure(True)
+    try:
+        tokens = jax.ShapeDtypeStruct((2, 256), jnp.int32)
+        jax.eval_shape(lambda p, t: tfm.loss_fn(
+            p, t, t, HYBRID_TINY, attention="local"),
+            tfm.init_abstract(HYBRID_TINY), tokens)
+        text = telemetry.render_prometheus()
+        # batch 2 x 2 heads x 256 / BLOCK blocks; a float32 [24, 48]
+        # state kept at the start of each.
+        blocks = 2 * 2 * 256 // la.BLOCK
+        for layer in (0, 1, 2):
+            assert (f'hvd_gdn_blocks_total{{layer="{layer}"}} {blocks}'
+                    in text), text
+            assert (f'hvd_gdn_saved_state_bytes{{layer="{layer}"}} '
+                    f'{blocks * 24 * 48 * 4}') in text, text
+        assert 'hvd_gdn_blocks_total{layer="3"}' not in text
+    finally:
+        telemetry.reset_for_tests()
+
+
+@pytest.mark.parametrize("cfg", (GPT2_TINY, OLMOE_TINY),
+                         ids=("gpt2", "olmoe"))
+def test_configurations_without_linear_layers_lower_without_them(hvd, cfg):
+    """Neither the GPT-2 block nor OLMoE's holds any of the linear layer
+    in its lowered step: no ``gdn`` scope, no ``lin_`` leaf; and all-``full_attention`` layer types are the empty default, to
+    the byte.  (Byte-equal programs against the parent commit at the
+    cells' real sizes were compiled once, by hand: PERF.md, PR 31.)"""
+    from horovod_tpu.topology import build_mesh
+
+    mesh = build_mesh(axes=("data",), devices=jax.devices()[:2])
+    optimizer = optax.sgd(0.1)
+    tokens = jax.ShapeDtypeStruct((4, 64), jnp.int32)
+
+    def lowered(cfg, **kw):
+        step, _, _ = tfm.make_train_step(cfg, optimizer, mesh,
+                                         attention="local")
+        params = tfm.init_abstract(cfg)
+        return step.lower(params, jax.eval_shape(optimizer.init, params),
+                          tokens, tokens).as_text(**kw)
+
+    named = lowered(cfg, debug_info=True)
+    for absent in ("gdn_", "lin_"):
+        assert absent not in named, absent
+    spelled = dataclasses.replace(
+        cfg, layer_types=("full_attention",) * cfg.n_layers)
+    assert lowered(spelled) == lowered(cfg)
+    hybrid = jax.jit(lambda p, t: tfm.loss_fn(
+        p, t, t, HYBRID_TINY, attention="local")).lower(
+        tfm.init_abstract(HYBRID_TINY),
+        jax.ShapeDtypeStruct((4, 256), jnp.int32)).as_text(debug_info=True)
+    for present in ("stablehlo.while", "cumsum", "gdn_scan", "gdn_conv",
+                    "lin_"):
+        assert present in hybrid, present

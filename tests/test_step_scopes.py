@@ -96,7 +96,8 @@ ROUTE = {"local": scopes.ATTN_LOCAL, "flash": scopes.ATTN_FLASH,
          "ring": scopes.ATTN_RING, "ulysses": scopes.ATTN_ULYSSES}
 
 
-def _check_lm(text, attention, remat, shard_optimizer):
+def _check_lm(text, attention, remat, shard_optimizer,
+              recurrence_recomputes=False):
     assert text.startswith(f"HloModule jit_{scopes.LM_TRAIN_STEP},")
     names = _op_names(text)
     assert all(n.startswith(f"jit({scopes.LM_TRAIN_STEP})")
@@ -109,10 +110,14 @@ def _check_lm(text, attention, remat, shard_optimizer):
     for i in range(2):
         assert _under(names, scopes.LAYER % i, "jvp("), i
     if remat == "full":
-        for scope in (scopes.ATTN_QKV, scopes.ATTN_OUT, ROUTE[attention],
-                      scopes.MLP):
+        # Mixer and MLP are recomputed apart (transformer._remat_wrap):
+        # the out projection's result is the MLP half's saved input, so
+        # nothing recomputes attn/out.
+        for scope in (scopes.ATTN_QKV, ROUTE[attention], scopes.MLP):
             assert _under(names, scope, "rematted_computation"), scope
-    else:
+    elif not recurrence_recomputes:
+        # (A linear layer's recurrence recomputes its blocks under every
+        # policy: models/linear_attention.py.)
         assert not any("rematted_computation" in n for n in names)
     step = ([scopes.GRAD_REDUCE_SCATTER, scopes.OPTIMIZER,
              scopes.PARAM_ALL_GATHER] if shard_optimizer
@@ -166,6 +171,55 @@ def test_moe_step_carries_the_vocabulary_and_its_parts_under_mlp(
              for name, i in hlo.instructions.items()
              if i.opcode in HELD}
     assert set(MOE_PARTS) <= parts
+
+
+GDN_PARTS = {scopes.GDN_PROJ: scopes.ATTN_QKV, scopes.GDN_CONV: scopes.ATTN_QKV,
+             scopes.GDN_GATE_NORM: scopes.ATTN_OUT,
+             scopes.GDN_OUT: scopes.ATTN_OUT}
+HYBRID = dict(positions="none", qk_norm=True, tie_embeddings=False,
+              mlp="swiglu", linear_key_heads=2, linear_value_heads=2,
+              linear_key_head_dim=16, linear_value_head_dim=32,
+              linear_conv_kernel=4, linear_allow_neg_eigval=True,
+              layer_types=("linear_attention", "full_attention"))
+
+
+@pytest.mark.parametrize("attention,remat",
+                         [("local", "none"), ("flash", "full")])
+def test_hybrid_step_carries_the_vocabulary_and_the_mixers_parts(
+        hvd, attention, remat):
+    """The linear layer's projections and convolution open *under*
+    ``attn/qkv``, its gated norm and out projection under ``attn/out``,
+    forward, backward and recomputed, so the benchmark's fixed vocabulary
+    still places every executed op; the recurrence is a route of its own
+    that the vocabulary answers with ``layer``; ``perfbench/gdn_reduce.py``
+    reads all five by name."""
+    from perfbench import gdn_reduce, moe_reduce
+
+    text = _lm_step_text(attention, remat, False, **HYBRID)
+    _check_lm(text, attention, remat, False, recurrence_recomputes=True)
+    names = _op_names(text)
+    marks = ("jvp(", "transpose(") + (
+        ("rematted_computation",) if remat == "full" else ())
+    for part, parent in GDN_PARTS.items():
+        inside = f"{parent}/{part}"
+        for mark in marks:
+            if (part, mark) != (scopes.GDN_OUT, "rematted_computation"):
+                assert _under(names, part, inside, mark), (part, mark)
+        assert not any(part in n and inside not in n for n in names), part
+    for mark in marks:
+        assert _under(names, scopes.ATTN_GDN_SCAN, mark), mark
+    # Only layer 0 is linear: no part of the mixer under layer_1, no
+    # attention route under layer_0.
+    assert not any("gdn_" in n and "layer_1" in n for n in names)
+    assert not any(ROUTE[attention] in n and "layer_0" in n for n in names)
+    scan = (f"jit(x)/transpose(jvp({scopes.LAYER % 0}))/"
+            f"{scopes.ATTN_GDN_SCAN}/while/body/dot_general")
+    assert scope_reduce.scope_of(scan) == "layer"
+    assert scope_reduce.phase_of(scan) == "bwd"
+    hlo = scope_reduce.parse_hlo(text)
+    parts = {gdn_reduce.part_of(moe_reduce.op_name_of(name, hlo))
+             for name, i in hlo.instructions.items() if i.opcode in HELD}
+    assert set(gdn_reduce.PARTS) <= parts
 
 
 @pytest.mark.parametrize("attention", ("ring", "ulysses"))
@@ -261,11 +315,19 @@ def test_the_benchmark_reads_the_same_vocabulary():
     # vocabulary does not hold them and does not need to.
     moe_parts = set(MOE_PARTS)
     moe_kernels = {scopes.MOE_GMM, scopes.MOE_GMM_NT, scopes.MOE_TGMM}
+    # Likewise the linear-attention layer's parts, read by
+    # ``perfbench/gdn_reduce.py``: four sub-scopes of ``attn/qkv`` and
+    # ``attn/out``, and the recurrence's route, which the fixed
+    # vocabulary books as ``layer``.
+    gdn_parts = set(GDN_PARTS) | {scopes.ATTN_GDN_SCAN}
     assert set(scope_reduce.KERNEL_NAMES) == kernels
     assert (set(scope_reduce.MODEL_SCOPES + scope_reduce.GRAD_MEAN_SCOPES
                 + scope_reduce.OPTIMIZER_SCOPES)
             == program - kernels - modules - {scopes.LAYER} - moe_parts
-            - moe_kernels)
+            - moe_kernels - gdn_parts)
+    from perfbench import gdn_reduce
+    assert ({p.rsplit("/", 1)[-1] for p in gdn_parts}
+            == set(gdn_reduce.PARTS))
     assert scope_reduce.scope_of(
         f"jit(x)/jvp({scopes.LAYER % 3})/{scopes.MLP}/dot_general"
     ) == scopes.MLP
