@@ -15,10 +15,10 @@ The layer, as Gated DeltaNet (arXiv:2412.06464) and the public
     o_t = S_t^T q_t
     y = Wo (RMSNorm(o_t) * silu(Wz x))
 
-**The recurrence runs in chunked form** (:func:`gated_delta_rule`), never
-token by token: in a block of :data:`BLOCK` tokens with ``b_i`` the
-running sum of ``g`` from the block's start, ``u_i = beta_i (v_i -
-alpha_i S_{i-1}^T k_i)`` solves the unit-lower-triangular system
+**The recurrence runs in chunked form**, never token by token: in a
+block of :data:`BLOCK` tokens with ``b_i`` the running sum of ``g`` from
+the block's start, ``u_i = beta_i (v_i - alpha_i S_{i-1}^T k_i)`` solves
+the unit-lower-triangular system
 
     (I + N) U = diag(beta) (V - diag(exp b) K S_0),
     N_ij = beta_i exp(b_i - b_j) (k_i . k_j)  for j < i,
@@ -26,25 +26,43 @@ alpha_i S_{i-1}^T k_i)`` solves the unit-lower-triangular system
 so with ``T = (I + N)^-1 diag(beta)``: ``U = T V - (T diag(exp b) K)
 S_0`` (the WY/UT form), ``O = diag(exp b) Q S_0 + (Q K^T * exp(b_i -
 b_j), j <= i) U`` and ``S_C = exp(b_C) S_0 + (diag(exp(b_C - b)) K)^T U``.
-Everything that does not need ``S_0`` (``N``, the inverse, ``T V``, ``T
-diag(exp b) K``, the masked ``Q K^T``) is computed for all blocks at
-once; a :func:`jax.lax.scan` over the blocks carries ``S`` and does three
-small matmuls a block.  Every decay is ``exp`` of a difference that is
-``<= 0``: nothing is divided, so an ``alpha`` near 0 underflows to an
-exact zero and never to ``inf``.
+Every decay is ``exp`` of a difference that is ``<= 0``: nothing is
+divided, so an ``alpha`` near 0 underflows to an exact zero and never to
+``inf``.
 
-Precision: ``g``, its running sums, the decays, ``N``, the inverse (its
-matmuls at precision ``highest``) and the carried state are float32;
-every other matmul takes operands in the model dtype (``T``, the state
-and ``U`` rounded to it where they are operands) and accumulates in
-float32; ``T V``, ``U`` and the part of the output that comes from
-``S_0`` are stored between the three phases in the model dtype.
+**What runs it** is read from the operand (:func:`recurrence_path`), not
+set by anyone: the Pallas kernels of
+:mod:`horovod_tpu.ops.gated_delta_rule` wherever they can run (compiled
+where the mesh that executes the step is TPU, in the Pallas interpreter
+elsewhere), with a head's state in VMEM over all of its blocks and
+nothing of a block but its inputs and its output in HBM; else
+:func:`gated_delta_rule` below, the same algorithm as ``jax.numpy`` that
+XLA compiles: everything that does not need ``S_0`` (``N``, the inverse,
+``T V``, ``T diag(exp b) K``, the masked ``Q K^T``) for all blocks at
+once through HBM, then a :func:`jax.lax.scan` over the blocks that
+carries ``S`` and does three small matmuls a block.  It is the kernels'
+oracle in the tests, and what runs where they cannot: a sequence length
+that does not cut into their tiles, and, on the CPU, inside
+``shard_map(check_vma=True)`` (the training step's), where the
+interpreter's loops do not type.  ``hvd_gdn_blocks_total{path}`` says
+which was traced.
 
-Backward: autodiff through the chunked form.  The inverse has its own
-rule (``d(A^-1) = -A^-1 dA A^-1``: two matmuls, where the chain of
-squarings would keep a dozen ``[BLOCK, BLOCK]`` matrices a block), and the
-scan's body is recomputed, so what the backward keeps of the scan is the
-state at each block's start (:func:`saved_state_bytes`).
+Precision, of both: ``g``, its running sums, the decays, ``N``, the
+inverse (its matmuls at precision ``highest``) and the carried state are
+float32; every other matmul takes operands in the model dtype (``T``,
+the state and ``U`` rounded to it where they are operands) and
+accumulates in float32.  Between its three phases the ``jax.numpy`` form
+stores ``T V``, ``U`` and the part of the output that comes from ``S_0``
+in the model dtype; the kernels keep them in float32 on the chip.
+
+Backward: the inverse has its own rule in both (``d(A^-1) = -A^-1 dA
+A^-1``: two matmuls, where the chain of squarings would keep a dozen
+``[BLOCK, BLOCK]`` matrices a block).  What the backward keeps of the
+recurrence is the float32 state at each block's start
+(:func:`saved_state_bytes`) in both: the kernels' forward writes them
+when it runs under differentiation and their backward kernel recomputes
+a block from its inputs and its state; the ``jax.numpy`` form is
+differentiated through, with the scan's body recomputed.
 
 Not here: ``segment_ids`` (the state's reset at a document boundary and
 the convolution's mask: ROADMAP R11), a model or sequence axis, decode.
@@ -60,19 +78,10 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu import telemetry
+from horovod_tpu.ops import gated_delta_rule as kernels
+from horovod_tpu.ops.gated_delta_rule import BLOCK
 from horovod_tpu.parallel._vma import pin_to, vma_of
 from horovod_tpu.telemetry import scopes
-
-# Tokens a block of the chunked recurrence holds: one [BLOCK, BLOCK]
-# triangular system a head and block, T / BLOCK steps of the scan.  Chosen
-# on the chip and on the CPU (PERF.md, PR 31): one layer's recurrence at
-# Olmo-Hybrid's sizes and 16384 tokens, forward + backward, takes 68.8 /
-# 65.0 / 49.1 / 84.4 ms at 32 / 64 / 128 / 256, but a [128, 128] system
-# with beta near 2 and alpha near 1 is conditioned a hundred times worse
-# than a [64, 64] one (float32 against the token-by-token recurrence:
-# 9e-4 where 64 reads 3e-6), and the operands are bf16.  64 is also the
-# family's.
-BLOCK = 64
 
 # The published initialisation of the gates (HF ``linear_attention``
 # layer, Mamba-2's): A ~ U(0, 16) and the step dt log-uniform in
@@ -180,7 +189,9 @@ def _mm(spec, a, b, dtype):
 
 
 def gated_delta_rule(q, k, v, g, beta, dtype):
-    """The recurrence of the module's docstring from ``S_0 = 0``: ``q``,
+    """The recurrence of the module's docstring from ``S_0 = 0`` as
+    ``jax.numpy`` (the kernels' oracle, and what runs where they cannot:
+    :func:`recurrence_path`): ``q``,
     ``k`` [B, T, H, d_k] (normalised, ``q`` scaled), ``v`` [B, T, H,
     d_v], ``g`` (``log alpha <= 0``) and ``beta`` [B, T, H] float32 ->
     ``o`` [B, T, H, d_v] in ``dtype``.  ``T`` a multiple of
@@ -237,9 +248,21 @@ def gated_delta_rule(q, k, v, g, beta, dtype):
     return jnp.moveaxis(o, 1, 3).reshape(bsz, t, h, dv)
 
 
+def recurrence_path(x) -> str:
+    """What runs the recurrence over ``x`` [B, T, ...], read from ``x``
+    alone: ``"kernel"``, the Pallas kernels of
+    :mod:`horovod_tpu.ops.gated_delta_rule`, compiled where the mesh that
+    executes ``x`` is TPU and interpreted elsewhere; ``"xla"``,
+    :func:`gated_delta_rule`, where the kernels cannot run (a length that
+    does not cut into their tiles; the interpreter inside
+    ``shard_map(check_vma=True)``: ``gated_delta_rule.takes``)."""
+    return "kernel" if kernels.takes(x) else "xla"
+
+
 def saved_state_bytes(batch: int, t: int, cfg) -> int:
-    """Bytes of block states the backward of one layer's scan keeps: the
-    float32 state at the start of each block (the body is recomputed)."""
+    """Bytes of block states the backward of one layer's recurrence
+    keeps: the float32 state at the start of each block (the block is
+    recomputed from it), whichever path runs."""
     return (batch * (t // BLOCK) * cfg.linear_value_heads
             * cfg.linear_key_head_dim * cfg.linear_value_head_dim * 4)
 
@@ -279,7 +302,10 @@ def mixer(x, layer, cfg):
             k = _l2norm(k.reshape(bsz, t, h, dk)).astype(dt)
             v = v.reshape(bsz, t, h, dv).astype(dt)
     with jax.named_scope(scopes.ATTN_GDN_SCAN):
-        o = gated_delta_rule(q, k, v, g, beta, dt)
+        if recurrence_path(q) == "kernel":
+            o = kernels.gated_delta_rule(q, k, v, g, beta)
+        else:
+            o = gated_delta_rule(q, k, v, g, beta, dt)
     with jax.named_scope(scopes.ATTN_OUT):
         with jax.named_scope(scopes.GDN_GATE_NORM):
             o = o.astype(jnp.float32)
@@ -291,19 +317,23 @@ def mixer(x, layer, cfg):
             return o @ layer["lin_wo"].astype(dt)
 
 
-def record_blocks(layer: int, batch: int, t: int, cfg) -> None:
+def record_blocks(layer: int, x, cfg) -> None:
     """Trace-time series (what was compiled into the step, like
     ``hvd_moe_assignments_total``): the blocks of the recurrence layer
-    ``layer`` walks per step on one device, over batch and heads, and the
-    bytes of block states its backward keeps."""
+    ``layer`` walks per step on one device over the batch and heads of
+    its input ``x`` [B, T, d], by what runs them
+    (:func:`recurrence_path`), and the bytes of block states its backward
+    keeps."""
     if not telemetry.enabled():
         return
+    batch, t = x.shape[:2]
     telemetry.counter(
         "hvd_gdn_blocks_total",
         "Blocks of the chunked gated-delta-rule recurrence the traced "
         "linear-attention layer computes per step on one device (batch x "
-        "heads x T / block)",
-        layer=str(layer)).inc(batch * cfg.linear_value_heads * (t // BLOCK))
+        "heads x T / block), by what runs them (path: kernel | xla)",
+        layer=str(layer), path=recurrence_path(x)).inc(
+            batch * cfg.linear_value_heads * (t // BLOCK))
     telemetry.gauge(
         "hvd_gdn_saved_state_bytes",
         "Bytes of block states the backward pass of the traced "

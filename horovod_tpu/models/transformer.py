@@ -552,7 +552,7 @@ def forward_with_router_stats(params, tokens, cfg: TransformerConfig,
             x = mixers[cfg.layer_type(i)](x, layer, segment_ids)
             x, stats = mlp_part(x, layer)
         if cfg.layer_type(i) == LINEAR_ATTENTION:
-            linear_attention.record_blocks(i, tokens.shape[0], t_local, cfg)
+            linear_attention.record_blocks(i, x, cfg)
         if cfg.n_experts:
             router_stats.append(stats)
             moe.record_assignments(

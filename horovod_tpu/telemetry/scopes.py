@@ -63,6 +63,11 @@ MOE_GMM = "moe_gmm"
 MOE_GMM_NT = "moe_gmm_nt"
 MOE_TGMM = "moe_tgmm"
 
+# The two gated-delta-rule Pallas kernels (ops/gated_delta_rule.py); they
+# run under ATTN_GDN_SCAN.
+GDN_SCAN_FWD = "gdn_scan_fwd"
+GDN_SCAN_BWD = "gdn_scan_bwd"
+
 # The functions handed to jax.jit: the XLA module is jit_<name>.
 LM_TRAIN_STEP = "hvd_lm_train_step"
 LM_PIPELINED_TRAIN_STEP = "hvd_lm_pipelined_train_step"

@@ -1,5 +1,5 @@
-"""The three flash kernels compiled at the benchmark's attention shapes for
-a v5e that is described and not attached (rehearsal 3 of the
+"""The three flash kernels and the two gated-delta-rule kernels compiled at
+the benchmark's shapes for a v5e that is described and not attached (rehearsal 3 of the
 on-chip-measurement guide; the recipe of
 ``perfbench/tests/test_chip_compile.py``).
 
@@ -12,6 +12,7 @@ this process (a child could not load libtpu beside it).
 """
 
 import os
+import re
 
 import pytest
 
@@ -64,3 +65,39 @@ def test_kernels_compile_for_the_v5e(one_chip, shape, segments):
     for name in (scopes.FLASH_FWD, scopes.FLASH_BWD_DQ,
                  scopes.FLASH_BWD_DKV):
         assert f"%{name}." in text or f"%{name} " in text, name
+
+
+# One layer's recurrence of olmohybrid_t16k: B*H = 30 heads of key width
+# 96 and value width 192 over 16384 tokens, bf16 with float32 gates.
+def test_gated_delta_rule_kernels_compile_for_the_v5e(one_chip):
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import gated_delta_rule as op
+    from horovod_tpu.telemetry import scopes
+
+    bh, t, dk, dv = 30, 16384, 96, 192
+    packs = op.tiles(t)
+    assert packs == op.TILE_PACKS
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    qk, vo = shape(bh, t, dk), shape(bh, t, dv)
+    gates = shape(bh, t // op.ROWS, op.ROWS, dtype=jnp.float32)
+
+    # The calls themselves, told to compile: the public function reads
+    # the executing mesh, and this process's is the CPU.
+    def fwd_and_grads(q, k, v, g, beta, do):
+        plain = op._fwd_call(q, k, v, g, beta, packs=packs,
+                             save_states=False, interpret=False)
+        o, states = op._fwd_call(q, k, v, g, beta, packs=packs,
+                                 save_states=True, interpret=False)
+        return plain, o, op._bwd_call(q, k, v, g, beta, do, states,
+                                      packs=packs, interpret=False)
+
+    text = jax.jit(fwd_and_grads).lower(
+        qk, qk, vo, gates, gates, vo).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name, calls in ((scopes.GDN_SCAN_FWD, 2), (scopes.GDN_SCAN_BWD, 1)):
+        assert len(re.findall(rf"%{name}[.\d]* = ", text)) == calls, name

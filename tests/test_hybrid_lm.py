@@ -374,14 +374,47 @@ def test_block_counters_count_what_was_traced(hvd):
             tfm.init_abstract(HYBRID_TINY), tokens)
         text = telemetry.render_prometheus()
         # batch 2 x 2 heads x 256 / BLOCK blocks; a float32 [24, 48]
-        # state kept at the start of each.
+        # state kept at the start of each.  Traced outside shard_map, the
+        # kernels run them (here in the interpreter).
         blocks = 2 * 2 * 256 // la.BLOCK
         for layer in (0, 1, 2):
-            assert (f'hvd_gdn_blocks_total{{layer="{layer}"}} {blocks}'
-                    in text), text
+            assert (f'hvd_gdn_blocks_total{{layer="{layer}",path="kernel"}} '
+                    f'{blocks}' in text), text
             assert (f'hvd_gdn_saved_state_bytes{{layer="{layer}"}} '
                     f'{blocks * 24 * 48 * 4}') in text, text
-        assert 'hvd_gdn_blocks_total{layer="3"}' not in text
+        assert 'hvd_gdn_blocks_total{layer="3"' not in text
+        assert 'path="xla"' not in text
+    finally:
+        telemetry.reset_for_tests()
+
+
+def test_block_counters_say_which_path_the_training_step_took(hvd):
+    """The label is read where the path is chosen: the training step on a
+    CPU mesh traces the ``jax.numpy`` form (the interpreter cannot run
+    inside ``shard_map(check_vma=True)``); on a TPU mesh it traces the
+    kernels (``perfbench/tests/test_chip_compile_hybrid_lm.py`` compiles
+    that step)."""
+    from horovod_tpu import telemetry
+    from horovod_tpu.topology import build_mesh
+
+    telemetry.reset_for_tests()
+    telemetry.configure(True)
+    try:
+        mesh = build_mesh(axes=("data",), devices=jax.devices()[:2])
+        optimizer = optax.sgd(0.1)
+        step, _, _ = tfm.make_train_step(HYBRID_TINY, optimizer, mesh,
+                                         attention="local")
+        params = tfm.init_abstract(HYBRID_TINY)
+        tokens = jax.ShapeDtypeStruct((4, 256), jnp.int32)
+        lowered = step.lower(params, jax.eval_shape(optimizer.init, params),
+                             tokens, tokens).as_text(debug_info=True)
+        text = telemetry.render_prometheus()
+        blocks = 2 * 2 * 256 // la.BLOCK
+        for layer in (0, 1, 2):
+            assert (f'hvd_gdn_blocks_total{{layer="{layer}",path="xla"}} '
+                    f'{blocks}' in text), text
+        assert 'path="kernel"' not in text
+        assert "cumsum" in lowered and "gdn_scan_fwd" not in lowered
     finally:
         telemetry.reset_for_tests()
 
@@ -416,6 +449,6 @@ def test_configurations_without_linear_layers_lower_without_them(hvd, cfg):
         p, t, t, HYBRID_TINY, attention="local")).lower(
         tfm.init_abstract(HYBRID_TINY),
         jax.ShapeDtypeStruct((4, 256), jnp.int32)).as_text(debug_info=True)
-    for present in ("stablehlo.while", "cumsum", "gdn_scan", "gdn_conv",
-                    "lin_"):
+    for present in ("stablehlo.while", "gdn_scan_fwd", "gdn_scan",
+                    "gdn_conv", "lin_"):
         assert present in hybrid, present

@@ -320,14 +320,24 @@ def test_the_benchmark_reads_the_same_vocabulary():
     # ``attn/out``, and the recurrence's route, which the fixed
     # vocabulary books as ``layer``.
     gdn_parts = set(GDN_PARTS) | {scopes.ATTN_GDN_SCAN}
+    # The recurrence's two kernels run under that route and are booked by
+    # it, not by their names.
+    gdn_kernels = {scopes.GDN_SCAN_FWD, scopes.GDN_SCAN_BWD}
     assert set(scope_reduce.KERNEL_NAMES) == kernels
     assert (set(scope_reduce.MODEL_SCOPES + scope_reduce.GRAD_MEAN_SCOPES
                 + scope_reduce.OPTIMIZER_SCOPES)
             == program - kernels - modules - {scopes.LAYER} - moe_parts
-            - moe_kernels - gdn_parts)
+            - moe_kernels - gdn_parts - gdn_kernels)
     from perfbench import gdn_reduce
     assert ({p.rsplit("/", 1)[-1] for p in gdn_parts}
             == set(gdn_reduce.PARTS))
+    for phase, name, kernel in (
+            ("fwd", "jvp(%s)", scopes.GDN_SCAN_FWD),
+            ("bwd", "transpose(jvp(%s))", scopes.GDN_SCAN_BWD)):
+        call = (f"jit(x)/{name % (scopes.LAYER % 1)}/{scopes.ATTN_GDN_SCAN}"
+                f"/{kernel}/pallas_call")
+        assert gdn_reduce.part_of(call) == "gdn_scan"
+        assert scope_reduce.phase_of(call) == phase
     assert scope_reduce.scope_of(
         f"jit(x)/jvp({scopes.LAYER % 3})/{scopes.MLP}/dot_general"
     ) == scopes.MLP
