@@ -1,0 +1,231 @@
+"""Mamba-2 selective state-space mixer, the whole of a ``"mamba2"`` layer:
+what :mod:`horovod_tpu.models.transformer` runs where
+``TransformerConfig.layer_types`` says so (Nemotron-H and the other
+``nemotron_h`` / ``mamba2`` configs).
+
+The layer, as Mamba-2 (arXiv:2405.21060) and the public ``nemotron_h``
+mixer state it; ``u`` the normed input, ``H`` heads of ``P`` channels, a
+state of ``N`` a head, ``G`` groups of heads that share ``B`` and ``C``::
+
+    [z | xBC | dt] = u W_in                     widths H P, H P + 2 G N, H
+    xBC = silu(causal_depthwise_conv1d(xBC) + b)
+    x [T, H, P], B [T, G, N], C [T, G, N] = split(xBC)
+    delta = softplus(dt + dt_bias)
+    a_t = exp(-delta_t exp(A_log))              a scalar a head and token
+    S_t = a_t S_{t-1} + delta_t x_t B_t^T       S in R^{P x N}, from 0
+    y_t = S_t C_t + D x_t
+    out = GroupRMSNorm(y * silu(z)) W_out       G groups of H P / G channels
+
+**The recurrence runs in chunked form** (the paper's SSD algorithm),
+never token by token: in a chunk of ``ssm_chunk`` tokens with ``b_i`` the
+running sum of ``log a`` from the chunk's start and ``S_0`` the state it
+starts from,
+
+    y_i = sum_{j <= i} exp(b_i - b_j) (C_i . B_j) delta_j x_j
+          + exp(b_i) S_0 C_i,
+    S_end = exp(b_L) S_0 + sum_j exp(b_L - b_j) delta_j x_j B_j^T.
+
+Every decay is ``exp`` of a difference that is ``<= 0``: nothing is
+divided, so an ``a`` near 0 underflows to an exact zero and never to
+``inf``.  :func:`ssd` is that as ``jax.numpy`` that XLA compiles: the
+masked ``C B^T`` products and every chunk's own contribution to its end
+state for all chunks at once, a :func:`jax.lax.scan` over the chunks that
+carries ``S`` (one multiply-add a chunk), then the carried states' part of
+the output for all chunks at once.
+
+Precision: ``delta``, ``log a``, its running sums, every decay and the
+carried state are float32; every matmul takes operands in the model dtype
+(the masked and decayed ``C B^T``, ``delta x`` and the state rounded to it
+where they are operands) and accumulates in float32.
+
+What the backward keeps of the recurrence is the float32 state at each
+chunk's start (:func:`saved_state_bytes`); everything else is
+differentiated through.
+
+Not here: ``segment_ids`` (the state's reset at a document boundary and
+the convolution's mask: ROADMAP R11), a model or sequence axis, decode.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import PartitionSpec as P
+
+from horovod_tpu import telemetry
+from horovod_tpu.models.linear_attention import causal_conv
+from horovod_tpu.parallel._vma import pin_to, vma_of
+from horovod_tpu.telemetry import scopes
+
+# Mamba-2's initialisation of the decay: A ~ U(1, 16) and the step delta
+# log-uniform in [0.001, 0.1] floored at 1e-4, stored as log A and
+# softplus^-1(delta).
+A_INIT_RANGE = (1.0, 16.0)
+DT_INIT_RANGE = (1e-3, 0.1)
+DT_INIT_FLOOR = 1e-4
+
+LEAVES = ("ssm_w_in", "ssm_conv", "ssm_conv_bias", "ssm_a_log",
+          "ssm_dt_bias", "ssm_d", "ssm_norm_scale", "ssm_w_out")
+
+
+def widths(cfg):
+    """``(z, xBC, dt)``: the widths of the in-projection's three parts."""
+    inner = cfg.ssm_heads * cfg.ssm_head_dim
+    return inner, inner + 2 * cfg.ssm_groups * cfg.ssm_state, cfg.ssm_heads
+
+
+def init_layer(key, cfg, dense):
+    """The mixer's leaves of one Mamba-2 layer; ``dense(key, shape)`` is
+    the caller's matrix initialiser."""
+    inner, conv, heads = widths(cfg)
+    k = jax.random.split(key, 6)
+    a = jax.random.uniform(k[2], (heads,), jnp.float32, *A_INIT_RANGE)
+    dt = jnp.maximum(jnp.exp(jax.random.uniform(
+        k[3], (heads,), jnp.float32,
+        *(math.log(x) for x in DT_INIT_RANGE))), DT_INIT_FLOOR)
+    bound = cfg.ssm_conv_kernel ** -0.5         # torch's Conv1d, fan-in K
+    return {
+        "ssm_w_in": dense(k[0], (cfg.d_model, inner + conv + heads)),
+        "ssm_conv": jax.random.uniform(
+            k[1], (cfg.ssm_conv_kernel, conv), jnp.float32, -bound, bound),
+        "ssm_conv_bias": jax.random.uniform(
+            k[4], (conv,), jnp.float32, -bound, bound),
+        "ssm_a_log": jnp.log(a),
+        # softplus^-1(dt) = dt + log(1 - exp(-dt))
+        "ssm_dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "ssm_d": jnp.ones((heads,), jnp.float32),
+        "ssm_norm_scale": jnp.ones((inner,), jnp.float32),
+        "ssm_w_out": dense(k[5], (inner, cfg.d_model)),
+    }
+
+
+def layer_specs():
+    """Every leaf whole on every chip (no model axis: it is refused)."""
+    return {name: P() for name in LEAVES}
+
+
+def _mm(spec, a, b, dtype):
+    """einsum of operands in ``dtype``, accumulated in float32."""
+    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def ssd(x, b_in, c_in, delta, log_a, chunk: int, dtype):
+    """The recurrence of the module's docstring from ``S_0 = 0``, without
+    the ``D x`` skip: ``x`` [B, T, H, P], ``b_in``, ``c_in`` [B, T, G, N]
+    (head ``h`` reads group ``h // (H / G)``), ``delta`` and ``log_a``
+    (``<= 0``) [B, T, H] float32 -> ``y`` [B, T, H, P] float32.  ``T`` a
+    multiple of ``chunk``."""
+    bsz, t, h, p = x.shape
+    g, n = b_in.shape[2:]
+    if t % chunk:
+        raise ValueError(f"mamba2: sequence length {t} is not a multiple "
+                         f"of the recurrence's chunk of {chunk}")
+    nc, r = t // chunk, h // g
+
+    def chunks(v, *tail):      # [B, T, ...] -> [B, nc, chunk, *tail]
+        return v.reshape((bsz, nc, chunk) + tail)
+
+    # Heads as (group, head in group): a group's B and C meet its heads.
+    xd = chunks(x.astype(jnp.float32) * delta[..., None], g, r, p)
+    b_in, c_in = chunks(b_in, g, n), chunks(c_in, g, n)
+    b = jnp.cumsum(chunks(log_a, g, r), axis=2)      # [B, nc, L, G, R]
+    by_head = jnp.moveaxis(b, 2, -1)                 # [B, nc, G, R, L]
+    rows = jnp.arange(chunk)
+    lower = rows[:, None] >= rows[None, :]
+    diff = by_head[..., :, None] - by_head[..., None, :]
+    # exp(b_i - b_j) for j <= i, else 0 (masked before the exp: above the
+    # diagonal the difference is positive and may overflow).
+    decay = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)
+    scores = _mm("bcign,bcjgn->bcgij", c_in, b_in, dtype)
+    within = _mm("bcgrij,bcjgrp->bcigrp", scores[:, :, :, None] * decay,
+                 xd, dtype)
+    to_end = jnp.exp(b[:, :, -1:] - b)[..., None]    # [B, nc, L, G, R, 1]
+    own = _mm("bcjgn,bcjgrp->bcgrpn", b_in, to_end * xd, dtype)
+    carry = jnp.exp(b[:, :, -1])                     # [B, nc, G, R]
+
+    def step(state, inputs):
+        own_c, carry_c = inputs
+        return carry_c[..., None, None] * state + own_c, state
+
+    # Inside shard_map the state varies over the axes its inputs do.
+    state = pin_to(vma_of(own))(jnp.zeros((bsz, g, r, p, n), jnp.float32))
+    _, starts = lax.scan(step, state, (jnp.moveaxis(own, 1, 0),
+                                       jnp.moveaxis(carry, 1, 0)))
+    across = _mm("bcign,cbgrpn->bcigrp", c_in, starts, dtype)
+    y = within + jnp.exp(b)[..., None] * across
+    return y.reshape(bsz, t, h, p)
+
+
+def saved_state_bytes(batch: int, t: int, cfg) -> int:
+    """Bytes of chunk states the backward of one layer's recurrence
+    keeps: the float32 state at the start of each chunk."""
+    return (batch * (t // cfg.ssm_chunk) * cfg.ssm_heads * cfg.ssm_head_dim
+            * cfg.ssm_state * 4)
+
+
+def mixer(u, layer, cfg):
+    """The whole mixer on the normed ``u`` [B, T, d] -> [B, T, d] (the
+    caller adds the residual).  Opens its parts as bare components under
+    ``attn/qkv`` and ``attn/out`` and the recurrence as a route of its
+    own (``telemetry/scopes.py``)."""
+    dt = cfg.dtype
+    bsz, t, _ = u.shape
+    h, p, g, n = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                  cfg.ssm_state)
+    inner, conv, _ = widths(cfg)
+    with jax.named_scope(scopes.ATTN_QKV):
+        with jax.named_scope(scopes.SSM_PROJ):
+            w_in = layer["ssm_w_in"]
+            z = u @ w_in[:, :inner].astype(dt)
+            xbc = u @ w_in[:, inner:inner + conv].astype(dt)
+            # The step leaves its matmul in float32: [B, T, H].
+            delta = jax.nn.softplus(jnp.matmul(
+                u, w_in[:, inner + conv:].astype(dt),
+                preferred_element_type=jnp.float32) + layer["ssm_dt_bias"])
+            log_a = -delta * jnp.exp(layer["ssm_a_log"])
+        with jax.named_scope(scopes.SSM_CONV):
+            xbc = jax.nn.silu(causal_conv(xbc, layer["ssm_conv"])
+                              + layer["ssm_conv_bias"]).astype(dt)
+            x = xbc[..., :inner].reshape(bsz, t, h, p)
+            b_in = xbc[..., inner:inner + g * n].reshape(bsz, t, g, n)
+            c_in = xbc[..., inner + g * n:].reshape(bsz, t, g, n)
+    with jax.named_scope(scopes.ATTN_SSM_SCAN):
+        y = ssd(x, b_in, c_in, delta, log_a, cfg.ssm_chunk, dt)
+        y = y + layer["ssm_d"][:, None] * x.astype(jnp.float32)
+    with jax.named_scope(scopes.ATTN_OUT):
+        with jax.named_scope(scopes.SSM_GATE_NORM):
+            y = (y.reshape(bsz, t, g, inner // g)
+                 * jax.nn.silu(z.astype(jnp.float32)).reshape(
+                     bsz, t, g, inner // g))
+            y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                              + cfg.norm_eps)
+            y = (y.reshape(bsz, t, inner)
+                 * layer["ssm_norm_scale"]).astype(dt)
+        with jax.named_scope(scopes.SSM_OUT):
+            return y @ layer["ssm_w_out"].astype(dt)
+
+
+def record_chunks(layer: int, x, cfg) -> None:
+    """Trace-time series (what was compiled into the step, like
+    ``hvd_gdn_blocks_total``): the chunks of the recurrence layer
+    ``layer`` walks per step on one device over the batch and heads of
+    its input ``x`` [B, T, d], and the bytes of chunk states its backward
+    keeps."""
+    if not telemetry.enabled():
+        return
+    batch, t = x.shape[:2]
+    telemetry.counter(
+        "hvd_ssm_chunks_total",
+        "Chunks of the chunked Mamba-2 recurrence the traced state-space "
+        "layer computes per step on one device (batch x heads x T / "
+        "chunk)",
+        layer=str(layer)).inc(batch * cfg.ssm_heads * (t // cfg.ssm_chunk))
+    telemetry.gauge(
+        "hvd_ssm_saved_state_bytes",
+        "Bytes of chunk states the backward pass of the traced "
+        "state-space layer's recurrence keeps",
+        layer=str(layer)).set(saved_state_bytes(batch, t, cfg))

@@ -20,8 +20,26 @@ nothing assumes balance.  Both row moves are gathers in both directions
 (:func:`_gather_rows`): the backward pass of "gather the sorted rows" is
 "gather them back and add", never a scatter.
 
-Not here: experts over a mesh axis (ROADMAP R2).  The one-expert-per-chip,
-capacity-dropping ``all_to_all`` demo is :mod:`horovod_tpu.parallel.expert`.
+**One chip's share of a wider layer** (``TransformerConfig.experts_held``
+fewer than ``n_experts``): the router still scores all ``n_experts`` and
+keeps its ``k``; this chip computes the rows of the contiguous range it
+holds and leaves the rest out (:func:`this_chips_share`, for either
+router and either expert form): no exchange, and
+nothing stands in for the absent chips.  Shapes stay static: a token can
+land here at most ``min(k, held)`` times, so a row buffer of ``N *
+min(k, held)`` bounds every batch, the grouped matmuls visit only the
+tiles that held rows reach, and **nothing held is ever dropped**.
+
+**The latent layer** (:func:`latent_moe_ffn`; DeepSeek-V3's scoring with
+Nemotron-3's experts): sigmoid scores with a selection bias that chooses
+but does not weigh, the chosen scores renormalised and scaled
+(:func:`route_sigmoid`); non-gated ``relu^2`` experts that live in a
+latent width between two dense projections; a shared expert on the hidden
+state, added for every token.
+
+Not here: the exchange of rows between chips that hold different experts
+(ROADMAP R2).  The one-expert-per-chip, capacity-dropping ``all_to_all``
+demo is :mod:`horovod_tpu.parallel.expert`.
 """
 
 from __future__ import annotations
@@ -125,57 +143,204 @@ def _gather_rows_bwd(uses, residuals, g):
 _gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
 
 
-# The three matrices of every expert, [E, d, f], [E, d, f] and [E, f, d].
+# The matrices of every expert, [E, d, f], [E, d, f] and [E, f, d]; a
+# non-gated expert (``relu2``) has no ``w_gate``.
 EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
 
 def _expert_operands(layer):
-    """The :data:`EXPERT_LEAVES` as the grouped matmuls are handed them:
-    as they are stored.  The kernels round a group's block to the rows'
-    dtype in VMEM; an ``.astype`` here is a second copy of every expert in
-    HBM, written each step, kept for the backward pass and read twice
-    (PERF.md, PR 30), and :func:`record_weight_copies` counts it."""
-    return tuple(layer[name] for name in EXPERT_LEAVES)
+    """The :data:`EXPERT_LEAVES` the layer has, as the grouped matmuls
+    are handed them: as they are stored.  The kernels round a group's
+    block to the rows' dtype in VMEM; an ``.astype`` here is a second copy
+    of every expert in HBM, written each step, kept for the backward pass
+    and read twice (PERF.md, PR 30), and :func:`record_weight_copies`
+    counts it."""
+    return tuple(layer[name] for name in EXPERT_LEAVES if name in layer)
 
 
-def experts_ffn(h, top_p, top_i, group_sizes, layer, dtype):
-    """``sum_k top_p[:, k] * expert_{top_i[:, k]}(h)``: ``h`` [N, d] ->
-    [N, d] in ``dtype``.  ``group_sizes`` [E] int32: assignments per
-    expert (``RouterStats.counts``).  ``layer`` holds ``w_gate``, ``w_up``
-    [E, d, f] and ``w_down`` [E, f, d] in the dtype they are stored in."""
-    n, k = top_i.shape
+def experts_ffn(h, slot_w, slot_e, group_sizes, layer, dtype,
+                act: str = "swiglu", live=None):
+    """``sum_s slot_w[:, s] * expert_{slot_e[:, s]}(h)``: ``h`` [N, d] ->
+    [N, d] in ``dtype``.  ``slot_e`` [N, s] int32: a token's experts, as
+    indices into the experts ``layer`` holds (or their count, for a slot
+    that is no held expert's: :func:`held_slots`); ``group_sizes`` [E]
+    int32: rows per held expert.  ``layer`` holds the expert matrices in
+    the dtype they are stored in: ``w_gate``, ``w_up`` [E, d, f] and
+    ``w_down`` [E, f, d] for ``act="swiglu"`` (``W_down (silu(W_gate h) *
+    W_up h)``), ``w_up`` and ``w_down`` for ``act="relu2"`` (``W_down
+    relu(W_up h)^2``).
+
+    The ``N * s`` slots are sorted by expert, "not here" last: the held
+    rows are the buffer's head, in expert order, and the grouped matmuls
+    take the group sizes as they come and visit no tile past the last
+    group.  ``live`` [N * s, 1] bool (None where every row is some
+    expert's: the chip holds them all) marks that head.  What a grouped
+    matmul leaves in the tail of its result, and of its operand's
+    gradient, is not defined (any bits, ``nan`` among them): each is
+    zeroed on both sides (:func:`matmul`), so nothing undefined meets a
+    product, forward or backward."""
+    n, s = slot_e.shape
     with jax.named_scope(scopes.MOE_DISPATCH):
-        flat = top_i.reshape(-1)
-        # order[j]: the assignment (token * k + slot) at sorted place j;
+        flat = slot_e.reshape(-1)
+        # order[j]: the assignment (token * s + slot) at sorted place j;
         # place[a]: where assignment a went.  Stable, so an expert's rows
-        # keep token order.  The counts are the router's.
+        # keep token order.  The group sizes are the caller's count of
+        # the same keys.
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)
         place = jnp.zeros_like(order).at[order].set(
-            jnp.arange(n * k, dtype=jnp.int32), unique_indices=True)
-        rows = _gather_rows(h, order // k, place, k)
+            jnp.arange(n * s, dtype=jnp.int32), unique_indices=True)
+        rows = _gather_rows(h, order // s, place, s)
+
+    def matmul(x, weights):
+        if live is None:
+            return grouped_matmul(x, weights, group_sizes)
+        # A select, not a product: its gradient is a select too, so the
+        # operand's gradient (a grouped matmul's result as well) is
+        # zeroed before anything multiplies it.
+        x = jnp.where(live, x, 0)
+        return jnp.where(live, grouped_matmul(x, weights, group_sizes), 0)
+
     with jax.named_scope(scopes.MOE_EXPERTS):
-        w_gate, w_up, w_down = _expert_operands(layer)
-        gate = grouped_matmul(rows, w_gate, group_sizes)
-        up = grouped_matmul(rows, w_up, group_sizes)
-        act = (jax.nn.silu(gate.astype(jnp.float32))
-               * up.astype(jnp.float32)).astype(dtype)
-        out = grouped_matmul(act, w_down, group_sizes)
+        if act == "swiglu":
+            w_gate, w_up, w_down = _expert_operands(layer)
+            gate = matmul(rows, w_gate)
+            up = matmul(rows, w_up)
+            hidden = (jax.nn.silu(gate.astype(jnp.float32))
+                      * up.astype(jnp.float32)).astype(dtype)
+        elif act == "relu2":
+            w_up, w_down = _expert_operands(layer)
+            up = matmul(rows, w_up)
+            hidden = jnp.square(
+                jax.nn.relu(up.astype(jnp.float32))).astype(dtype)
+        else:
+            raise ValueError(f"act={act!r}: expected 'swiglu' or 'relu2'")
+        out = matmul(hidden, w_down)
     with jax.named_scope(scopes.MOE_COMBINE):
-        per_token = _gather_rows(out, place, order, 1).reshape(n, k, -1)
-        return jnp.sum(per_token.astype(jnp.float32) * top_p[..., None],
+        per_token = _gather_rows(out, place, order, 1).reshape(n, s, -1)
+        return jnp.sum(per_token.astype(jnp.float32) * slot_w[..., None],
                        axis=1).astype(dtype)
 
 
+def rows_bound(tokens: int, k: int, held: int) -> int:
+    """Rows of :func:`experts_ffn`'s buffer: a token's ``k`` experts are
+    distinct, so at most ``min(k, held)`` of them are held here."""
+    return tokens * min(k, held)
+
+
+def held_slots(top_w, top_i, first: int, held: int):
+    """A token's assignments that land on the experts ``first .. first +
+    held`` this chip holds: ``(slot_w [N, s] f32, slot_e [N, s] int32)``
+    with ``s = min(k, held)``; ``slot_e`` is the held expert's local index
+    or ``held`` for "not here" (weight 0).  A token's held experts are
+    among its ``s`` least keys, so none is lost."""
+    k = top_i.shape[1]
+    local = top_i - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    slots = min(k, held)
+    if slots < k:
+        least, at = lax.top_k(-key, slots)
+        key, top_w = -least, jnp.take_along_axis(top_w, at, axis=-1)
+    return jnp.where(key < held, top_w, 0.0), key.astype(jnp.int32)
+
+
+def this_chips_share(top_w, top_i, cfg, counts=None):
+    """A router's choice as :func:`experts_ffn` takes it on this chip:
+    ``(slot_w, slot_e, group_sizes, live)``.  Where the chip holds every
+    expert that is the choice itself and ``live`` is None (``counts``: the
+    router's assignments per expert, where it has them already).  Where
+    it holds a share (``cfg.experts_held``): the held slots
+    (:func:`held_slots`), the rows each held expert receives and the mask
+    of the buffer's rows that are some held expert's."""
+    held = cfg.held_experts
+    share = held < cfg.n_experts
+    if share:
+        top_w, top_i = held_slots(top_w, top_i, cfg.experts_held_from, held)
+    if share or counts is None:
+        counts = jnp.sum(
+            top_i.reshape(-1, 1) == jnp.arange(held)[None, :], axis=0,
+            dtype=jnp.int32)
+    live = ((jnp.arange(top_i.size) < jnp.sum(counts))[:, None] if share
+            else None)
+    return top_w, top_i, counts, live
+
+
 def moe_ffn(h, layer, cfg):
-    """The whole layer on ``h`` [..., d]: ``(y [..., d], RouterStats)``.
-    ``cfg`` is the model's ``TransformerConfig``."""
+    """The whole softmax-routed layer on ``h`` [..., d]: ``(y [..., d],
+    RouterStats)``.  ``cfg`` is the model's ``TransformerConfig``."""
     flat = h.reshape(-1, h.shape[-1])
     with jax.named_scope(scopes.MOE_ROUTER):
         top_p, top_i, stats = route(flat, layer["router"],
                                     cfg.experts_per_token,
                                     cfg.norm_topk_prob)
-    y = experts_ffn(flat, top_p, top_i, stats.counts, layer, cfg.dtype)
+        slot_w, slot_e, rows, live = this_chips_share(
+            top_p, top_i, cfg, stats.counts)
+    y = experts_ffn(flat, slot_w, slot_e, rows, layer, cfg.dtype,
+                    act="swiglu", live=live)
     return y.reshape(h.shape), stats
+
+
+def route_sigmoid(h, router_w, bias, k: int, scale: float):
+    """``h`` [N, d], ``router_w`` [d, E], ``bias`` [E] -> ``(top_w [N, k]
+    f32, top_i [N, k] int32)``: scores ``s = sigmoid(h W_r)`` in float32
+    over all ``E`` (the matmul at precision ``highest``, as
+    :func:`route`'s), the ``k`` experts with the largest ``s + bias``
+    (``bias`` chooses and carries no gradient), and weights ``scale *
+    s[chosen] / (sum of s[chosen] + 1e-20)``: the sum runs over all ``k``
+    whether this chip holds them or not."""
+    logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, top_i = lax.top_k(scores + lax.stop_gradient(bias), k)
+    chosen = jnp.take_along_axis(scores, top_i, axis=-1)
+    return (scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True)
+                              + 1e-20), top_i)
+
+
+def latent_moe_ffn(u, layer, cfg):
+    """The latent mixture of experts with a shared expert on ``u`` [...,
+    d]: ``(routed + shared [..., d], rows per held expert)``.  ``routed =
+    (sum over the chosen experts held here of w_k expert_k(u W_in^lat))
+    W_out^lat``, ``shared = W_sd relu(W_su u)^2``.  ``cfg`` is the
+    model's ``TransformerConfig``."""
+    dt = cfg.dtype
+    flat = u.reshape(-1, u.shape[-1])
+    with jax.named_scope(scopes.MOE_ROUTER):
+        top_w, top_i = route_sigmoid(
+            flat, layer["router"], layer["router_bias"],
+            cfg.experts_per_token, cfg.routed_scale)
+        slot_w, slot_e, rows, live = this_chips_share(top_w, top_i, cfg)
+    with jax.named_scope(scopes.MOE_LATENT):
+        latent = flat @ layer["w_latent_in"].astype(dt)
+    routed = experts_ffn(latent, slot_w, slot_e, rows, layer, dt,
+                         act="relu2", live=live)
+    with jax.named_scope(scopes.MOE_LATENT):
+        routed = routed @ layer["w_latent_out"].astype(dt)
+    with jax.named_scope(scopes.MOE_SHARED):
+        hidden = jnp.square(jax.nn.relu(
+            (flat @ layer["w_shared_up"].astype(dt)).astype(jnp.float32)))
+        shared = hidden.astype(dt) @ layer["w_shared_down"].astype(dt)
+    return (routed + shared).reshape(u.shape), rows
+
+
+def record_held(layer: int, tokens: int, cfg) -> None:
+    """Trace-time series beside ``hvd_moe_assignments_total``: the routed
+    experts layer ``layer`` holds on this chip, and the static bound on
+    the rows they can receive from ``tokens`` tokens (what they do
+    receive is data)."""
+    if not telemetry.enabled():
+        return
+    telemetry.gauge(
+        "hvd_moe_experts_held",
+        "Routed experts of the traced MoE layer that this chip holds (of "
+        "n_experts the router scores)",
+        layer=str(layer)).set(cfg.held_experts)
+    telemetry.gauge(
+        "hvd_moe_rows_bound",
+        "Rows of the traced MoE layer's buffer: tokens x min("
+        "experts_per_token, experts held); no batch can need more, so "
+        "nothing held is dropped",
+        layer=str(layer)).set(rows_bound(tokens, cfg.experts_per_token,
+                                         cfg.held_experts))
 
 
 def record_assignments(layer: int, assignments: int, experts: int) -> None:
@@ -218,5 +383,6 @@ def record_weight_copies(layer: int, weights) -> None:
         "kernels read the stored parameters)",
         layer=str(layer)).set(sum(
             h.size * h.dtype.itemsize
-            for h, name in zip(handed, EXPERT_LEAVES)
+            for h, name in zip(handed, (n for n in EXPERT_LEAVES
+                                        if n in weights))
             if h.dtype != weights[name].dtype))

@@ -25,7 +25,16 @@ names each layer's sequence mixer: ``"full_attention"`` (the block above)
 or ``"linear_attention"`` (the gated delta rule of
 :mod:`horovod_tpu.models.linear_attention`, sized by the ``linear_*``
 fields); Olmo-Hybrid is three linear layers to one full one, with
-``positions="none"``.
+``positions="none"``.  Three more kinds are **one part alone**, with one
+norm and one residual add: ``"mamba2"`` (the state-space mixer of
+:mod:`horovod_tpu.models.mamba2`, sized by the ``ssm_*`` fields),
+``"attention"`` (softmax attention, no MLP) and ``"mlp"`` (the config's
+feed-forward part, no mixer).  ``n_kv_heads`` fewer than ``n_heads`` is
+grouped-query attention; ``mlp="relu2"`` with experts is the latent
+mixture of experts with a shared expert (:func:`moe.latent_moe_ffn`), of
+which this chip may hold a share (``experts_held``); ``mtp_layer_types``
+adds a multi-token-prediction module and its loss.  Nemotron-3 is
+``MEMEMEM*EME`` of those three, repeated.
 """
 
 from __future__ import annotations
@@ -40,7 +49,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.models import linear_attention, moe
+from horovod_tpu.models import linear_attention, mamba2, moe
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel import sequence as seq_mod
 from horovod_tpu.parallel import tensor as tp
@@ -49,7 +58,17 @@ from horovod_tpu.telemetry import scopes
 
 FULL_ATTENTION = "full_attention"
 LINEAR_ATTENTION = "linear_attention"
-LAYER_TYPES = (FULL_ATTENTION, LINEAR_ATTENTION)
+MAMBA2 = "mamba2"
+ATTENTION_ONLY = "attention"
+MLP_ONLY = "mlp"
+LAYER_TYPES = (FULL_ATTENTION, LINEAR_ATTENTION, MAMBA2, ATTENTION_ONLY,
+               MLP_ONLY)
+# What a layer of each type holds: its sequence mixer and whether the
+# config's feed-forward part follows it.
+_MIXER = {FULL_ATTENTION: FULL_ATTENTION, LINEAR_ATTENTION: LINEAR_ATTENTION,
+          MAMBA2: MAMBA2, ATTENTION_ONLY: FULL_ATTENTION, MLP_ONLY: None}
+_HAS_MLP = {FULL_ATTENTION: True, LINEAR_ATTENTION: True, MAMBA2: False,
+            ATTENTION_ONLY: False, MLP_ONLY: True}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,15 +93,33 @@ class TransformerConfig:
     norm_eps: float = 1e-6
     # False: an untied ``head`` leaf [d_model, vocab] instead of embed.T.
     tie_embeddings: bool = True
-    # "gelu": w2 gelu(w1 h); "swiglu": w_down (silu(w_gate h) * (w_up h)).
+    # Key-value heads that ``n_heads`` query heads share, ``n_heads /
+    # n_kv_heads`` each (grouped-query attention); 0: one each.
+    n_kv_heads: int = 0
+    # "gelu": w2 gelu(w1 h); "swiglu": w_down (silu(w_gate h) * (w_up h));
+    # "relu2" (with experts only): the latent mixture below.
     mlp: str = "gelu"
     # n_experts > 0: the MLP is ``n_experts`` SwiGLU experts of width
     # ``d_expert`` with softmax-then-top-``experts_per_token`` routing
     # that drops nothing (models/moe.py); d_ff is then unused.
+    # ``experts_held`` (0: all) of them, from ``experts_held_from`` on,
+    # are held by this chip and computed here; the rest are another
+    # chip's, and left out (the router still scores and ranks them all).
     n_experts: int = 0
     experts_per_token: int = 0
     d_expert: int = 0
     norm_topk_prob: bool = False
+    experts_held: int = 0
+    experts_held_from: int = 0
+    # mlp="relu2": the router scores all ``n_experts`` by sigmoid (+ a
+    # selection bias), its top-k weights are renormalised and scaled by
+    # ``routed_scale``; an expert is w_down relu(w_up l)^2 on ``l``, the
+    # token in a latent width ``d_latent`` between two dense projections;
+    # a shared expert of width ``d_shared`` on the hidden state is added
+    # for every token.
+    d_latent: int = 0
+    d_shared: int = 0
+    routed_scale: float = 1.0
     # Added to the cross-entropy: coefficient of the load-balancing loss
     # and of the router z-loss (moe.router_losses).
     router_aux_coef: float = 0.0
@@ -101,6 +138,22 @@ class TransformerConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel: int = 0
     linear_allow_neg_eigval: bool = False
+    # A "mamba2" layer (models/mamba2.py): ``ssm_heads`` heads of
+    # ``ssm_head_dim`` channels with a state of ``ssm_state`` each, B and
+    # C shared by the heads of each of ``ssm_groups`` groups, a causal
+    # depthwise convolution of ``ssm_conv_kernel`` taps, the recurrence in
+    # chunks of ``ssm_chunk`` tokens.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 0
+    ssm_conv_kernel: int = 0
+    ssm_chunk: int = 0
+    # Multi-token prediction: layers of these types on [norm(embed(x_{t+1}));
+    # norm(h_t)] W_eh predict x_{t+2} through the model's own embedding
+    # and head; ``mtp_loss_coef`` x their cross-entropy is added.
+    mtp_layer_types: Tuple[str, ...] = ()
+    mtp_loss_coef: float = 0.0
 
     def __post_init__(self):
         if self.positions not in ("learned", "rope", "none"):
@@ -129,16 +182,50 @@ class TransformerConfig:
         elif any(linear) or self.linear_allow_neg_eigval:
             raise ValueError("the linear_* fields mean nothing without a "
                              "'linear_attention' entry in layer_types")
-        if self.mlp not in ("gelu", "swiglu"):
-            raise ValueError(f"mlp={self.mlp!r}: expected 'gelu' or "
-                             f"'swiglu'")
+        if set(self.mtp_layer_types) - set(LAYER_TYPES):
+            raise ValueError(f"mtp_layer_types={self.mtp_layer_types!r}: "
+                             f"expected entries of {LAYER_TYPES}")
+        if bool(self.mtp_layer_types) != bool(self.mtp_loss_coef):
+            raise ValueError("mtp_layer_types and mtp_loss_coef come "
+                             "together")
+        ssm = (self.ssm_heads, self.ssm_head_dim, self.ssm_state,
+               self.ssm_groups, self.ssm_conv_kernel, self.ssm_chunk)
+        if MAMBA2 in self.layer_types + self.mtp_layer_types:
+            if min(ssm) <= 0 or self.ssm_heads % self.ssm_groups:
+                raise ValueError(
+                    "a 'mamba2' layer needs ssm_heads, ssm_head_dim, "
+                    "ssm_state, ssm_groups (a divisor of ssm_heads), "
+                    "ssm_conv_kernel and ssm_chunk")
+        elif any(ssm):
+            raise ValueError("the ssm_* fields mean nothing without a "
+                             "'mamba2' entry in layer_types")
+        if self.n_kv_heads and self.n_heads % self.n_kv_heads:
+            raise ValueError(f"n_kv_heads={self.n_kv_heads} does not "
+                             f"divide n_heads={self.n_heads}")
+        if self.mlp not in ("gelu", "swiglu", "relu2"):
+            raise ValueError(f"mlp={self.mlp!r}: expected 'gelu', "
+                             f"'swiglu' or 'relu2'")
         if self.positions == "rope" and self.head_dim % 2:
             raise ValueError(f"positions='rope' needs an even head_dim, "
                              f"got {self.head_dim}")
+        if self.mlp == "relu2":
+            if (not self.n_experts or self.d_latent <= 0
+                    or self.d_shared <= 0):
+                raise ValueError("mlp='relu2' is the latent mixture of "
+                                 "experts: it needs n_experts, d_latent "
+                                 "and d_shared")
+            if self.router_aux_coef or self.router_z_coef:
+                raise NotImplementedError(
+                    "mlp='relu2': the sigmoid router has no auxiliary "
+                    "loss (its balance is the selection bias's)")
+        elif self.d_latent or self.d_shared or self.routed_scale != 1.0:
+            raise ValueError("d_latent, d_shared and routed_scale mean "
+                             "nothing without mlp='relu2'")
         if self.n_experts:
-            if self.mlp != "swiglu":
-                raise ValueError("n_experts > 0: the experts are SwiGLU; "
-                                 "set mlp='swiglu'")
+            if self.mlp == "gelu":
+                raise ValueError("n_experts > 0: the experts are SwiGLU "
+                                 "(mlp='swiglu') or latent relu^2 "
+                                 "(mlp='relu2')")
             if not 0 < self.experts_per_token <= self.n_experts:
                 raise ValueError(
                     f"experts_per_token={self.experts_per_token} must lie "
@@ -146,19 +233,41 @@ class TransformerConfig:
             if self.d_expert <= 0:
                 raise ValueError("n_experts > 0 needs d_expert, one "
                                  "expert's width")
+            if not (0 <= self.experts_held_from and
+                    self.experts_held_from + self.held_experts
+                    <= self.n_experts):
+                raise ValueError(
+                    f"experts_held={self.experts_held} from "
+                    f"{self.experts_held_from} is not a range of the "
+                    f"n_experts={self.n_experts}")
         elif (self.experts_per_token or self.d_expert or self.norm_topk_prob
-              or self.router_aux_coef or self.router_z_coef):
-            raise ValueError("experts_per_token, d_expert, norm_topk_prob "
-                             "and the router loss coefficients mean "
-                             "nothing without n_experts")
+              or self.router_aux_coef or self.router_z_coef
+              or self.experts_held or self.experts_held_from):
+            raise ValueError("experts_per_token, d_expert, norm_topk_prob, "
+                             "experts_held* and the router loss "
+                             "coefficients mean nothing without n_experts")
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
     @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
     def has_linear_layers(self) -> bool:
         return LINEAR_ATTENTION in self.layer_types
+
+    @property
+    def recurrent_layer_types(self) -> Tuple[str, ...]:
+        """The types in use whose state crosses the sequence in order."""
+        used = self.layer_types + self.mtp_layer_types
+        return tuple(t for t in (LINEAR_ATTENTION, MAMBA2) if t in used)
 
     def layer_type(self, i: int) -> str:
         return self.layer_types[i] if self.layer_types else FULL_ATTENTION
@@ -176,19 +285,24 @@ def _refuse(cfg: TransformerConfig, where: str, fields) -> None:
                 f"{getattr(cfg, name)!r}")
 
 
-def _refuse_with_linear_layers(cfg: TransformerConfig, **arguments) -> None:
-    """Raise, by the argument's name, for what the linear-attention
-    layers do not implement: a sequence axis (the state crosses chunk
-    boundaries in order) and ``segment_ids`` / ``packed`` (the state's
-    reset and the convolution's mask at a document boundary: ROADMAP
-    R11)."""
-    if not cfg.has_linear_layers:
-        return
+def _refuse_with_recurrent_layers(cfg: TransformerConfig,
+                                  **arguments) -> None:
+    """Raise, by the argument's name, for what the recurrent layers
+    (linear attention, Mamba-2) do not implement: a sequence axis (the
+    state crosses chunk boundaries in order) and ``segment_ids`` /
+    ``packed`` (the state's reset and the convolution's mask at a
+    document boundary: ROADMAP R11).  Nor does the multi-token-prediction
+    module, whose second target is the next shard's or the next
+    document's at such a boundary."""
+    kinds = [f"the {kind!r} layers of TransformerConfig.layer_types"
+             for kind in cfg.recurrent_layer_types]
+    if cfg.mtp_layer_types:
+        kinds.append("the multi-token-prediction module "
+                     "(TransformerConfig.mtp_layer_types)")
     for name, value in arguments.items():
-        if value is not None and value is not False:
+        if kinds and value is not None and value is not False:
             raise NotImplementedError(
-                f"{name}={value!r}: the 'linear_attention' layers of "
-                f"TransformerConfig.layer_types do not implement it")
+                f"{name}={value!r}: {kinds[0]} do not implement it")
 
 
 def init_params(rng, cfg: TransformerConfig):
@@ -196,6 +310,7 @@ def init_params(rng, cfg: TransformerConfig):
     ``jax.device_put`` before use."""
     keys = jax.random.split(rng, 2 + cfg.n_layers)
     d, f, v = cfg.d_model, cfg.d_ff, cfg.vocab_size
+    d_kv = cfg.kv_heads * cfg.head_dim
 
     def dense(key, shape, scale=None):
         scale = scale if scale is not None else (shape[0] ** -0.5)
@@ -203,25 +318,46 @@ def init_params(rng, cfg: TransformerConfig):
 
     def experts(key, shape):
         # [E, in, out]: each expert a dense matrix of its own fan-in.
-        return dense(key, (cfg.n_experts,) + shape, scale=shape[0] ** -0.5)
+        return dense(key, (cfg.held_experts,) + shape,
+                     scale=shape[0] ** -0.5)
 
-    layers = []
-    for i in range(cfg.n_layers):
-        k = jax.random.split(keys[2 + i], 6)
+    def one_layer(key, kind):
+        """A layer's leaves: a norm's scale and the weights of each part
+        it holds."""
+        k = jax.random.split(key, 6)
         k_up, k_router = jax.random.split(jax.random.fold_in(k[4], 1))
-        layer = {
-            "ln1_scale": jnp.ones((d,), jnp.float32),
-            "ln2_scale": jnp.ones((d,), jnp.float32),
-        }
-        if cfg.layer_type(i) == LINEAR_ATTENTION:
+        layer = {}
+        if _MIXER[kind]:
+            layer["ln1_scale"] = jnp.ones((d,), jnp.float32)
+        if _HAS_MLP[kind]:
+            layer["ln2_scale"] = jnp.ones((d,), jnp.float32)
+        if _MIXER[kind] == LINEAR_ATTENTION:
             layer.update(linear_attention.init_layer(k[0], cfg, dense))
-        else:
-            layer.update(wq=dense(k[0], (d, d)), wk=dense(k[1], (d, d)),
-                         wv=dense(k[2], (d, d)), wo=dense(k[3], (d, d)))
+        elif _MIXER[kind] == MAMBA2:
+            layer.update(mamba2.init_layer(k[0], cfg, dense))
+        elif _MIXER[kind] == FULL_ATTENTION:
+            layer.update(wq=dense(k[0], (d, d)), wk=dense(k[1], (d, d_kv)),
+                         wv=dense(k[2], (d, d_kv)), wo=dense(k[3], (d, d)))
             if cfg.qk_norm:
                 layer["q_norm_scale"] = jnp.ones((d,), jnp.float32)
-                layer["k_norm_scale"] = jnp.ones((d,), jnp.float32)
-        if cfg.n_experts:
+                layer["k_norm_scale"] = jnp.ones((d_kv,), jnp.float32)
+        if not _HAS_MLP[kind]:
+            return layer
+        if cfg.mlp == "relu2":
+            e, lat = cfg.d_expert, cfg.d_latent
+            k_lat, k_shared = jax.random.split(jax.random.fold_in(k[5], 1))
+            layer.update(
+                router=dense(k_router, (d, cfg.n_experts)),
+                # Chooses and is not trained: its gradient is zero.
+                router_bias=jnp.zeros((cfg.n_experts,), jnp.float32),
+                w_latent_in=dense(k_lat, (d, lat)),
+                w_latent_out=dense(jax.random.fold_in(k_lat, 1), (lat, d)),
+                w_up=experts(k_up, (lat, e)),
+                w_down=experts(k[5], (e, lat)),
+                w_shared_up=dense(k_shared, (d, cfg.d_shared)),
+                w_shared_down=dense(jax.random.fold_in(k_shared, 1),
+                                    (cfg.d_shared, d)))
+        elif cfg.n_experts:
             e = cfg.d_expert
             layer.update(router=dense(k_router, (d, cfg.n_experts)),
                          w_gate=experts(k[4], (d, e)),
@@ -233,12 +369,25 @@ def init_params(rng, cfg: TransformerConfig):
                          w_down=dense(k[5], (f, d)))
         else:
             layer.update(w1=dense(k[4], (d, f)), w2=dense(k[5], (f, d)))
-        layers.append(layer)
+        return layer
+
     params = {
         "embed": dense(keys[0], (v, d), scale=0.02),
         "ln_f_scale": jnp.ones((d,), jnp.float32),
-        "layers": layers,
+        "layers": [one_layer(keys[2 + i], cfg.layer_type(i))
+                   for i in range(cfg.n_layers)],
     }
+    if cfg.mtp_layer_types:
+        k_mtp = jax.random.split(jax.random.fold_in(keys[1], 2),
+                                 1 + len(cfg.mtp_layer_types))
+        params["mtp"] = {
+            "embed_norm_scale": jnp.ones((d,), jnp.float32),
+            "hidden_norm_scale": jnp.ones((d,), jnp.float32),
+            "w_eh": dense(k_mtp[0], (2 * d, d)),
+            "layers": [one_layer(key, kind) for key, kind in
+                       zip(k_mtp[1:], cfg.mtp_layer_types)],
+            "ln_f_scale": jnp.ones((d,), jnp.float32),
+        }
     if cfg.positions == "learned":
         params["pos"] = dense(keys[1], (cfg.max_seq, d), scale=0.02)
     if not cfg.tie_embeddings:
@@ -256,22 +405,41 @@ def param_specs(cfg: TransformerConfig, model_axis: Optional[str]):
     attention = {"wq": col, "wk": col, "wv": col, "wo": row}
     if cfg.qk_norm:
         attention.update(q_norm_scale=P(), k_norm_scale=P())
-    mixers = {FULL_ATTENTION: attention,
-              LINEAR_ATTENTION: linear_attention.layer_specs()}
-    layer = {"ln1_scale": P(), "ln2_scale": P()}
-    if cfg.n_experts:
-        # Every expert on every chip (experts over an axis: ROADMAP R2).
-        layer.update(router=P(), w_gate=P(), w_up=P(), w_down=P())
+    mixers = {FULL_ATTENTION: dict(attention, ln1_scale=P()),
+              LINEAR_ATTENTION: dict(linear_attention.layer_specs(),
+                                     ln1_scale=P()),
+              MAMBA2: dict(mamba2.layer_specs(), ln1_scale=P()),
+              None: {}}
+    mlp = {"ln2_scale": P()}
+    if cfg.mlp == "relu2":
+        # The experts this chip holds, whole (the exchange with the chips
+        # that hold the others: ROADMAP R2).
+        mlp.update({name: P() for name in (
+            "router", "router_bias", "w_latent_in", "w_latent_out", "w_up",
+            "w_down", "w_shared_up", "w_shared_down")})
+    elif cfg.n_experts:
+        # Every held expert on every chip of the mesh (experts over an
+        # axis: ROADMAP R2).
+        mlp.update(router=P(), w_gate=P(), w_up=P(), w_down=P())
     elif cfg.mlp == "swiglu":
-        layer.update(w_gate=col, w_up=col, w_down=row)
+        mlp.update(w_gate=col, w_up=col, w_down=row)
     else:
-        layer.update(w1=col, w2=row)
+        mlp.update(w1=col, w2=row)
+
+    def one_layer(kind):
+        return dict(mlp if _HAS_MLP[kind] else {}, **mixers[_MIXER[kind]])
+
     specs = {
         "embed": P(),
         "ln_f_scale": P(),
-        "layers": [dict(layer, **mixers[cfg.layer_type(i)])
+        "layers": [one_layer(cfg.layer_type(i))
                    for i in range(cfg.n_layers)],
     }
+    if cfg.mtp_layer_types:
+        specs["mtp"] = {
+            "embed_norm_scale": P(), "hidden_norm_scale": P(), "w_eh": P(),
+            "layers": [one_layer(kind) for kind in cfg.mtp_layer_types],
+            "ln_f_scale": P()}
     if cfg.positions == "learned":
         specs["pos"] = P()
     if not cfg.tie_embeddings:
@@ -310,9 +478,12 @@ def _mlp_block(x, layer, cfg, model_axis):
 
 def _moe_block(x, layer, cfg):
     """rmsnorm -> dropless mixture of experts -> residual; also the
-    router's sums for the auxiliary losses."""
-    y, stats = moe.moe_ffn(_rmsnorm(x, layer["ln2_scale"], cfg.norm_eps),
-                           layer, cfg)
+    router's sums for the auxiliary losses (None from the latent layer,
+    which has none)."""
+    h = _rmsnorm(x, layer["ln2_scale"], cfg.norm_eps)
+    if cfg.mlp == "relu2":
+        return x + moe.latent_moe_ffn(h, layer, cfg)[0], None
+    y, stats = moe.moe_ffn(h, layer, cfg)
     return x + y, stats
 
 
@@ -347,12 +518,28 @@ def _qkv_proj(x, layer, cfg, model_axis, positions=None):
         q = _rmsnorm(q, layer["q_norm_scale"], cfg.norm_eps)
         k = _rmsnorm(k, layer["k_norm_scale"], cfg.norm_eps)
     dh = q.shape[-1]
-    split = q.shape[:-1] + (dh // cfg.head_dim, cfg.head_dim)
-    q, k, v = q.reshape(split), k.reshape(split), v.reshape(split)
+
+    def heads(a):
+        return a.reshape(a.shape[:-1] + (a.shape[-1] // cfg.head_dim,
+                                         cfg.head_dim))
+
+    q, k, v = heads(q), heads(k), heads(v)
     if cfg.positions == "rope":
         q = _rotary(q, positions, cfg.rope_theta)
         k = _rotary(k, positions, cfg.rope_theta)
     return q, k, v, dh
+
+
+def _share_kv_heads(k, v, n_heads: int):
+    """Grouped-query attention's K and V as the attention routes take
+    them, one head a query head: each key-value head repeated for the
+    ``n_heads / kv_heads`` query heads that read it (so dK and dV sum over
+    the group); as they are where the counts are equal.  A copy in HBM:
+    a kernel that reads head ``h // group`` instead is ROADMAP R3."""
+    group = n_heads // k.shape[-2]
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=-2), jnp.repeat(v, group, axis=-2)
 
 
 def _attn_out(o_flat, x, layer, dt, model_axis):
@@ -407,7 +594,8 @@ def _refuse_under_model_axis(cfg, model_axis) -> None:
     # so do the linear-attention layers' heads.
     if model_axis:
         _refuse(cfg, f"model_axis={model_axis!r}",
-                ("qk_norm", "n_experts", "layer_types"))
+                ("qk_norm", "n_experts", "layer_types", "n_kv_heads",
+                 "mtp_layer_types"))
 
 
 def _remat_wrap(body, remat: str):
@@ -421,8 +609,8 @@ def _remat_wrap(body, remat: str):
     * ``"full"``  — save only the block's inputs, recompute it in the
       backward: O(L) fewer activation bytes, ~1.3x fwd FLOPs.
 
-    A layer is two blocks, its sequence mixer and its MLP, each wrapped
-    by itself: the backward holds one half's recomputed intermediates at
+    A layer is up to two blocks, its sequence mixer and its MLP, each
+    wrapped by itself: the backward holds one half's recomputed intermediates at
     a time (a whole layer's do not fit a v5e beside an Olmo-Hybrid
     period at 16384 tokens, PERF.md PR 31), for one more saved [B, T, d]
     a layer.
@@ -467,9 +655,22 @@ def forward_with_router_stats(params, tokens, cfg: TransformerConfig,
     K-side ids with the K/V blocks, Ulysses all-gathers them (int32 per
     token) after its head scatter.
     """
+    x, router_stats = _hidden_states(params, tokens, cfg, model_axis,
+                                     seq_axis, attention, segment_ids,
+                                     remat)[:2]
+    return _logits_head(x, params, cfg), router_stats
+
+
+def _hidden_states(params, tokens, cfg: TransformerConfig, model_axis,
+                   seq_axis, attention, segment_ids, remat):
+    """``(x, router stats, run_layers)``: the last layer's output before
+    the final norm, one :class:`moe.RouterStats` per softmax-routed MoE
+    layer, and the function that ran the stack (``run_layers(x, layers,
+    types, label)``), for the multi-token-prediction module to run its
+    own layers by."""
     _refuse_under_model_axis(cfg, model_axis)
-    _refuse_with_linear_layers(cfg, seq_axis=seq_axis,
-                               segment_ids=segment_ids)
+    _refuse_with_recurrent_layers(cfg, seq_axis=seq_axis,
+                                  segment_ids=segment_ids)
     dt = cfg.dtype
     t_local = tokens.shape[1]
     with jax.named_scope(scopes.EMBED):
@@ -488,6 +689,12 @@ def forward_with_router_stats(params, tokens, cfg: TransformerConfig,
         with jax.named_scope(scopes.ATTN_QKV):
             q, k, v, dh = _qkv_proj(x, layer, cfg, model_axis, positions)
         b, t = q.shape[:2]
+        flash = seq_axis is None and (
+            attention in ("flash", "ring_flash")
+            or (attention == "auto" and _flash_profitable(t)))
+        with jax.named_scope(scopes.ATTN_FLASH if flash
+                             else scopes.ATTN_QKV):
+            k, v = _share_kv_heads(k, v, q.shape[-2])
         if seq_axis is not None:
             if attention == "ring_flash" or (attention == "auto" and
                                              _flash_profitable(t)):
@@ -511,8 +718,7 @@ def forward_with_router_stats(params, tokens, cfg: TransformerConfig,
                     f"attention={attention!r} is not available with a "
                     f"sequence axis; choose 'ring', 'ring_flash' or "
                     f"'ulysses'")
-        elif attention in ("flash", "ring_flash") or (
-                attention == "auto" and _flash_profitable(t)):
+        elif flash:
             # Pallas flash kernel (ops/flash_attention.py): same exact
             # math blockwise in VMEM; requires T divisible by its blocks.
             # 'ring_flash' without a seq axis degenerates to exactly
@@ -543,32 +749,92 @@ def forward_with_router_stats(params, tokens, cfg: TransformerConfig,
                 jax.named_scope(scopes.GDN_OUT):
             return x + y
 
+    def mamba2_part(x, layer, segment_ids):
+        # As the linear mixer: attn/qkv/ssm_*, attn/ssm_scan,
+        # attn/out/ssm_*.
+        with jax.named_scope(scopes.ATTN_QKV), \
+                jax.named_scope(scopes.SSM_PROJ):
+            h = _rmsnorm(x, layer["ln1_scale"], cfg.norm_eps)
+        y = mamba2.mixer(h, layer, cfg)
+        with jax.named_scope(scopes.ATTN_OUT), \
+                jax.named_scope(scopes.SSM_OUT):
+            return x + y
+
     mixers = {FULL_ATTENTION: _remat_wrap(attention_part, remat),
-              LINEAR_ATTENTION: _remat_wrap(linear_attention_part, remat)}
+              LINEAR_ATTENTION: _remat_wrap(linear_attention_part, remat),
+              MAMBA2: _remat_wrap(mamba2_part, remat)}
     mlp_part = _remat_wrap(mlp_part, remat)
     router_stats = []
-    for i, layer in enumerate(params["layers"]):
-        with jax.named_scope(scopes.LAYER % i):
-            x = mixers[cfg.layer_type(i)](x, layer, segment_ids)
-            x, stats = mlp_part(x, layer)
-        if cfg.layer_type(i) == LINEAR_ATTENTION:
-            linear_attention.record_blocks(i, x, cfg)
-        if cfg.n_experts:
-            router_stats.append(stats)
-            moe.record_assignments(
-                i, tokens.size * cfg.experts_per_token, cfg.n_experts)
-            moe.record_weight_copies(i, layer)
 
-    return _logits_head(x, params, cfg), router_stats
+    def run_layers(x, layers, types, label="%d"):
+        """``x`` through ``layers`` of ``types``; ``label % i`` names
+        layer ``i`` in the trace-time series."""
+        for i, (layer, kind) in enumerate(zip(layers, types)):
+            mixer, name = _MIXER[kind], label % i
+            with jax.named_scope(scopes.LAYER % i):
+                if mixer:
+                    x = mixers[mixer](x, layer, segment_ids)
+                if _HAS_MLP[kind]:
+                    x, stats = mlp_part(x, layer)
+            if mixer == LINEAR_ATTENTION:
+                linear_attention.record_blocks(name, x, cfg)
+            if mixer == MAMBA2:
+                mamba2.record_chunks(name, x, cfg)
+            if cfg.n_experts and _HAS_MLP[kind]:
+                moe.record_held(name, tokens.size, cfg)
+                moe.record_weight_copies(name, layer)
+                if stats is not None:
+                    router_stats.append(stats)
+                if cfg.held_experts == cfg.n_experts:
+                    # What lands on a share is data.
+                    moe.record_assignments(
+                        name, tokens.size * cfg.experts_per_token,
+                        cfg.n_experts)
+        return x
+
+    x = run_layers(x, params["layers"],
+                   [cfg.layer_type(i) for i in range(cfg.n_layers)])
+    return x, router_stats, run_layers
 
 
 @jax.named_scope(scopes.LOSS)
-def xent(logits, labels):
+def xent(logits, labels, counted=None):
     """Mean next-token cross-entropy (the one loss formula — shared by
-    the plain and pipelined training steps and the oracle tests)."""
+    the plain and pipelined training steps and the oracle tests), over
+    the positions where ``counted`` (bool, broadcast against ``labels``)
+    holds; over all of them without it."""
     logp = jax.nn.log_softmax(logits, axis=-1)
     ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll)
+    if counted is None:
+        return -jnp.mean(ll)
+    counted = jnp.broadcast_to(counted, ll.shape)
+    return -jnp.sum(jnp.where(counted, ll, 0.0)) / jnp.sum(counted)
+
+
+def _mtp_loss(params, x, labels, cfg: TransformerConfig, run_layers):
+    """Cross-entropy of the multi-token-prediction module: with ``x`` [B,
+    T, d] the stack's output before the final norm (``h_t``) and
+    ``labels`` the next tokens (``x_{t+1}``), ``[RMSNorm(embed(x_{t+1}));
+    RMSNorm(h_t)] W_eh`` through the module's layers and its own final
+    norm, then **the model's embedding and head**, predicts ``x_{t+2}``:
+    the mean over the ``T - 1`` positions that have one.  Every position
+    runs (the layers are causal, and ``T`` keeps the length the kernels
+    tile); the last is left out of the mean."""
+    mtp, dt = params["mtp"], cfg.dtype
+    with jax.named_scope(scopes.MTP):
+        with jax.named_scope(scopes.EMBED):
+            ahead = _rmsnorm(params["embed"][labels].astype(dt),
+                             mtp["embed_norm_scale"], cfg.norm_eps)
+            here = _rmsnorm(x, mtp["hidden_norm_scale"], cfg.norm_eps)
+            h = (jnp.concatenate([ahead, here], axis=-1)
+                 @ mtp["w_eh"].astype(dt))
+        h = run_layers(h, mtp["layers"], cfg.mtp_layer_types, "mtp_%d")
+        logits = _logits_head(h, dict(params, ln_f_scale=mtp["ln_f_scale"]),
+                              cfg)
+        with jax.named_scope(scopes.LOSS):
+            second = jnp.roll(labels, -1, axis=1)
+            has_second = jnp.arange(labels.shape[1]) < labels.shape[1] - 1
+        return xent(logits, second, has_second)
 
 
 def loss_fn(params, tokens, labels, cfg: TransformerConfig,
@@ -580,10 +846,14 @@ def loss_fn(params, tokens, labels, cfg: TransformerConfig,
     all layers' tokens together.  ``batch_axes``: the mesh axes the batch
     is split over, so that the mean of the shards' losses is the global
     batch's loss (:func:`moe.router_losses`)."""
-    logits, router_stats = forward_with_router_stats(
+    x, router_stats, run_layers = _hidden_states(
         params, tokens, cfg, model_axis, seq_axis, attention, segment_ids,
         remat)
-    loss = xent(logits, labels)
+    loss = xent(_logits_head(x, params, cfg), labels)
+    if cfg.mtp_layer_types:
+        ahead = _mtp_loss(params, x, labels, cfg, run_layers)
+        with jax.named_scope(scopes.LOSS):
+            loss = loss + cfg.mtp_loss_coef * ahead
     if router_stats:
         with jax.named_scope(scopes.LOSS):
             balance, z = moe.router_losses(
@@ -637,7 +907,7 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
     from horovod_tpu.ops.fusion import fused_pytree_mean
 
     _refuse_under_model_axis(cfg, model_axis)
-    _refuse_with_linear_layers(cfg, seq_axis=seq_axis, packed=packed)
+    _refuse_with_recurrent_layers(cfg, seq_axis=seq_axis, packed=packed)
     specs = param_specs(cfg, model_axis)
     grad_axes = tuple(a for a in (data_axis, seq_axis) if a)
 
@@ -798,7 +1068,8 @@ def decode_step(params, token, cache, pos, cfg: TransformerConfig,
     # A rotated key cache, an expert layer per token and a recurrent
     # layer's state and convolution window beside the key cache are not
     # written (serving: ROADMAP R8/R13).
-    _refuse(cfg, "decode_step", ("positions", "n_experts", "layer_types"))
+    _refuse(cfg, "decode_step", ("positions", "n_experts", "layer_types",
+                                 "n_kv_heads", "mtp_layer_types"))
     dt = cfg.dtype
     hd = cfg.head_dim
     x = (params["embed"][token] +
@@ -1064,7 +1335,7 @@ def make_train_step_pipelined(cfg: TransformerConfig, optimizer, mesh,
     # dense layers of one type and returns no router sums.
     _refuse(cfg, "make_train_step_pipelined",
             ("positions", "qk_norm", "tie_embeddings", "mlp", "n_experts",
-             "layer_types"))
+             "layer_types", "n_kv_heads", "mtp_layer_types"))
     n_stages = mesh.shape[pipe_axis]
     v_eff = (virtual if schedule in ("interleaved", "interleaved_1f1b")
              else 1)

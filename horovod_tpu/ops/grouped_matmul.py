@@ -62,7 +62,8 @@ from horovod_tpu.telemetry import scopes
 
 # Row, contraction and output tile of all three kernels, chosen on the
 # chip at 65,536 rows in 64 groups, K/N = 2048/1024 and 1024/2048, bf16
-# (PERF.md, PR 26 and PR 28).  A smaller dimension is one tile.  K and N
+# (PERF.md, PR 26 and PR 28).  A smaller dimension is one tile; a K or N
+# that its tile does not divide takes a narrower one (:func:`_tile`).  K and N
 # as wide as the OLMoE matrices: a group's weights then stay in VMEM over
 # its visits, where a K tile of 1024 fetched them anew on every grid step
 # and made a visit that multiplies little wait for 3 MB all the same.
@@ -80,11 +81,19 @@ VMEM_LIMIT_BYTES = 96 * 2 ** 20
 
 
 def _tile(dim: int, tile: int, what: str) -> int:
-    tile = min(tile, dim)
-    if dim % tile:
-        raise ValueError(f"grouped matmul: {what}={dim} is not a multiple "
-                         f"of its tile {tile}")
-    return tile
+    """The tile of a dimension: ``tile`` where it divides ``dim`` (a
+    smaller ``dim`` is one tile); else, for K and N, the largest multiple
+    of a lane row of 128 under ``tile`` that does (2688 = 21 x 128 takes
+    896)."""
+    largest = min(tile, dim)
+    candidates = [largest]
+    if what in ("K", "N"):
+        candidates += range(largest // 128 * 128, 0, -128)
+    for candidate in candidates:
+        if dim % candidate == 0:
+            return candidate
+    raise ValueError(f"grouped matmul: {what}={dim} is not a multiple "
+                     f"of its tile {largest}")
 
 
 def _visits(group_sizes, m: int, tm: int, visit_empty: bool):
@@ -426,9 +435,13 @@ def grouped_matmul(rows, weights, group_sizes):
     consecutive rows: [M, K] x [G, K, N] -> [M, N] in ``rows.dtype``,
     float32 accumulation.  ``weights`` as they are stored: a dtype other
     than the rows' is rounded to it inside the kernels, and the weights'
-    gradient comes back in it.  ``group_sizes`` [G] int32 sums to M; a
-    group may be empty.  M, K and N are multiples of their tiles (or
-    smaller than one).  Differentiable in ``rows`` and ``weights``."""
+    gradient comes back in it.  ``group_sizes`` [G] int32 sums to M **or
+    to less**; a group may be empty.  Rows past the last group belong to
+    no one: no visit reads them, their tiles are not visited, and what
+    the result holds there is not defined (the caller masks it,
+    ``models/moe.experts_ffn``).  M is a multiple of its tile (or
+    smaller than one), K and N of 128 where their tile does not divide
+    them.  Differentiable in ``rows`` and ``weights``."""
     return _gmm(rows, weights, group_sizes, transpose_rhs=False)
 
 

@@ -33,6 +33,13 @@ MOE_DISPATCH = "moe_dispatch"
 MOE_EXPERTS = "moe_experts"
 MOE_COMBINE = "moe_combine"
 
+# The two dense parts of a latent mixture of experts with a shared expert
+# (models/moe.latent_moe_ffn), beside the four above and opened the same
+# way: the projections into and out of the experts' latent width, and the
+# shared expert every token passes through.
+MOE_LATENT = "moe_latent"
+MOE_SHARED = "moe_shared"
+
 # The parts of a gated-delta-rule linear-attention layer
 # (models/linear_attention.py).  The recurrence is the layer's route, as
 # flash_attention is a full layer's; projections and convolution open
@@ -44,6 +51,22 @@ GDN_PROJ = "gdn_proj"
 GDN_CONV = "gdn_conv"
 GDN_GATE_NORM = "gdn_gate_norm"
 GDN_OUT = "gdn_out"
+
+# The parts of a Mamba-2 state-space mixer (models/mamba2.py), opened as
+# the gated delta rule's are: the recurrence is the layer's route, the
+# in-projection and the convolution sit under ATTN_QKV, the gated group
+# norm and the out projection under ATTN_OUT.
+ATTN_SSM_SCAN = "attn/ssm_scan"
+SSM_PROJ = "ssm_proj"
+SSM_CONV = "ssm_conv"
+SSM_GATE_NORM = "ssm_gate_norm"
+SSM_OUT = "ssm_out"
+
+# The multi-token-prediction module (transformer._mtp_loss): a bare
+# component that holds model scopes of its own (".../mtp/embed/...",
+# ".../mtp/layer_0/attn/qkv/...", ".../mtp/head/..."), so a reader that
+# knows only the model scopes books its parts with the main stack's.
+MTP = "mtp"
 
 # Step scopes: what the step does with the gradients.
 GRAD_MEAN = "grad_mean"

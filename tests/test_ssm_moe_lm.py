@@ -1,0 +1,714 @@
+"""Layers of one part each (Mamba-2, attention alone, the latent mixture of
+experts alone), grouped-query attention, one chip's share of the experts
+and the multi-token-prediction loss, at tiny sizes on the virtual CPU
+mesh.
+
+Oracle: the benchmark's plain float32 reference
+(``perfbench/reference/ssm_moe_lm.py``), which shares no code with the
+program, walks the recurrence one token at a time and loops over the held
+experts.  Tolerances, float32 everywhere unless a test says otherwise:
+5e-5 relative L2, which is float32 rounding through eleven layers and a
+128-token recurrence summed in another order (bfloat16 operands anywhere
+read 3e-3 and up, and one test proves that).
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from horovod_tpu.models import mamba2, moe
+from horovod_tpu.models import transformer as tfm
+from horovod_tpu.ops import grouped_matmul as gm
+from perfbench.reference import ssm_moe_lm as reference
+
+F32_REL = 5e-5
+
+KINDS = {"M": "mamba2", "*": "attention", "E": "mlp"}
+PATTERN = tuple(KINDS[c] for c in "MEMEMEM*EME")
+NEMOTRON_TINY = tfm.TransformerConfig(
+    vocab_size=128, d_model=64, n_heads=4, n_kv_heads=2, n_layers=11,
+    d_ff=0, max_seq=128, dtype=jnp.float32, positions="none", norm_eps=1e-5,
+    tie_embeddings=False, mlp="relu2", n_experts=16, experts_per_token=6,
+    d_expert=64, d_latent=32, d_shared=96, routed_scale=5.0, experts_held=4,
+    experts_held_from=4, layer_types=PATTERN, ssm_heads=4, ssm_head_dim=16,
+    ssm_state=32, ssm_groups=2, ssm_conv_kernel=4, ssm_chunk=32,
+    mtp_layer_types=(KINDS["*"], KINDS["E"]), mtp_loss_coef=0.1)
+OLMOE_TINY = tfm.TransformerConfig(
+    vocab_size=128, d_model=64, n_heads=2, n_layers=2, d_ff=0, max_seq=64,
+    dtype=jnp.float32, positions="rope", qk_norm=True, norm_eps=1e-5,
+    tie_embeddings=False, mlp="swiglu", n_experts=8, experts_per_token=2,
+    d_expert=32, router_aux_coef=0.01, router_z_coef=0.001)
+
+
+NO_SSM = dict(ssm_heads=0, ssm_head_dim=0, ssm_state=0, ssm_groups=0,
+              ssm_conv_kernel=0, ssm_chunk=0)
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def _params(cfg, seed=0):
+    params = tfm.init_params(jax.random.PRNGKey(seed), cfg)
+    # As the benchmark's adapter: at the program's 0.02 every token is the
+    # same token to the router.
+    params["embed"] = params["embed"] * 50.0
+    return params
+
+
+def _batch(cfg, batch=2, seq=128, seed=1):
+    toks = jax.random.randint(jax.random.PRNGKey(seed), (batch, seq + 1), 0,
+                              cfg.vocab_size)
+    return toks[:, :-1], toks[:, 1:]
+
+
+def _dims(cfg):
+    return {"n_heads": cfg.n_heads, "kv_heads": cfg.kv_heads,
+            "ssm_heads": cfg.ssm_heads, "ssm_head_dim": cfg.ssm_head_dim,
+            "ssm_state": cfg.ssm_state, "ssm_groups": cfg.ssm_groups,
+            "eps": cfg.norm_eps, "top_k": cfg.experts_per_token,
+            "routed_scale": cfg.routed_scale,
+            "held_from": cfg.experts_held_from}
+
+
+def _reference(cfg, params, tokens, labels, **kw):
+    """Every leaf the reference can differentiate, not only the cell's."""
+    return reference.loss_and_tail_grads(
+        params, tokens, labels, dims=_dims(cfg), layer_types=cfg.layer_types,
+        mtp_layer_types=cfg.mtp_layer_types, mtp_coef=cfg.mtp_loss_coef,
+        names=tuple(reference.LEAVES), **kw)
+
+
+def _checked(tree):
+    return {name: reference.leaf(tree, path)
+            for name, path in reference.leaf_paths(PATTERN).items()}
+
+
+# --- the chunked recurrence -------------------------------------------------
+
+# log a per token and head is drawn uniformly from the range.
+DECAYS = {"a_mid": (-0.2, 0.0), "a_near_0": (-30.0, -5.0),
+          "a_near_1": (-1e-4, 0.0)}
+
+
+@pytest.mark.parametrize("chunks", (1, 3))
+@pytest.mark.parametrize("decay", DECAYS.values(), ids=DECAYS.keys())
+def test_chunked_recurrence_matches_token_by_token(decay, chunks):
+    """Forward and all five gradients against the reference's scan over
+    single tokens, at a length that is and is not one chunk."""
+    chunk, h, p, n, g = 32, 4, 16, 24, 2
+    t = chunks * chunk
+    ks = jax.random.split(jax.random.key(0), 5)
+    x = jax.random.normal(ks[0], (t, h, p))
+    b_in = jax.random.normal(ks[1], (t, g, n)) * n ** -0.5
+    c_in = jax.random.normal(ks[2], (t, g, n))
+    log_a = jax.random.uniform(ks[3], (t, h), minval=decay[0],
+                               maxval=decay[1])
+    delta = jax.nn.softplus(jax.random.normal(ks[4], (t, h)))
+
+    def chunked(x, b_in, c_in, delta, log_a):
+        return mamba2.ssd(x[None], b_in[None], c_in[None], delta[None],
+                          log_a[None], chunk, jnp.float32)[0]
+
+    def by_token(x, b_in, c_in, delta, log_a):
+        return reference._state_space(
+            x, jnp.repeat(b_in, h // g, axis=1),
+            jnp.repeat(c_in, h // g, axis=1), delta, jnp.exp(log_a), None,
+            None)
+
+    args = (x, b_in, c_in, delta, log_a)
+    loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)))
+    with jax.default_matmul_precision("highest"):
+        got, got_g = jax.value_and_grad(loss(chunked), range(5))(*args)
+        want, want_g = jax.value_and_grad(loss(by_token), range(5))(*args)
+        assert _rel(chunked(*args), by_token(*args)) <= F32_REL
+    assert abs(got - want) <= F32_REL * abs(want)
+    for name, a, b in zip("x B C delta log_a".split(), got_g, want_g):
+        # Near a = 0 the gradient with respect to log a is itself tiny,
+        # and what is left of it is rounding: an absolute floor at 1e-6
+        # of the gradient of x.
+        bound = (2e-5 * np.linalg.norm(b)
+                 + 1e-6 * np.linalg.norm(want_g[0]))
+        assert np.linalg.norm(np.asarray(a - b)) <= bound, name
+
+
+def test_carried_state_matters_to_the_oracle():
+    """The reference with its state zeroed every chunk is another
+    function: what the comparison on the chip must be able to see."""
+    cfg = NEMOTRON_TINY
+    params, (tokens, labels) = _params(cfg), _batch(cfg)
+    whole = _reference(cfg, params, tokens, labels)[1]
+    reset = _reference(cfg, params, tokens, labels,
+                       reset_every=cfg.ssm_chunk)[1]
+    assert _rel(reset["ssm_w_out_last"], whole["ssm_w_out_last"]) > 0.05
+
+
+def test_sequence_length_must_be_whole_chunks():
+    cfg = NEMOTRON_TINY
+    tokens = jnp.zeros((1, 48), jnp.int32)
+    with pytest.raises(ValueError, match="chunk of 32"):
+        jax.eval_shape(lambda p: tfm.forward(p, tokens, cfg,
+                                             attention="local"),
+                       tfm.init_abstract(cfg))
+
+
+# --- grouped-query attention ------------------------------------------------
+
+def test_grouped_query_attention_is_attention_over_repeated_heads():
+    """Two key-value heads under four query heads against full
+    multi-head attention whose wk, wv hold each head twice: the same
+    output, and the gradient of a shared head is the sum over its
+    group."""
+    gqa = tfm.TransformerConfig(
+        vocab_size=64, d_model=64, n_heads=4, n_kv_heads=2, n_layers=1,
+        d_ff=32, max_seq=32, dtype=jnp.float32, layer_types=("attention",),
+        positions="none", tie_embeddings=False)
+    mha = dataclasses.replace(gqa, n_kv_heads=0)
+    params = tfm.init_params(jax.random.PRNGKey(0), gqa)
+    assert params["layers"][0]["wk"].shape == (64, 32)
+    assert set(params["layers"][0]) == {"ln1_scale", "wq", "wk", "wv", "wo"}
+    tokens, labels = _batch(gqa, seq=32)
+
+    def repeated(w):           # [d, 2 * 16] -> [d, 4 * 16], head h // 2
+        return jnp.repeat(w.reshape(64, 2, 16), 2, axis=1).reshape(64, 64)
+
+    def mha_loss(layer):
+        full = dict(layer, wk=repeated(layer["wk"]),
+                    wv=repeated(layer["wv"]))
+        return tfm.loss_fn(dict(params, layers=[full]), tokens, labels, mha,
+                           attention="local")
+
+    def gqa_loss(layer):
+        return tfm.loss_fn(dict(params, layers=[layer]), tokens, labels,
+                           gqa, attention="local")
+
+    with jax.default_matmul_precision("highest"):
+        got, got_g = jax.value_and_grad(gqa_loss)(params["layers"][0])
+        want, want_g = jax.value_and_grad(mha_loss)(params["layers"][0])
+    assert abs(got - want) <= 1e-6 * abs(want)
+    for name in ("wq", "wk", "wv", "wo"):
+        assert _rel(got_g[name], want_g[name]) <= 1e-5, name
+
+
+@pytest.mark.parametrize("attention", ("local", "flash"))
+def test_grouped_heads_reach_every_attention_route(attention):
+    cfg = dataclasses.replace(NEMOTRON_TINY, max_seq=128)
+    params, (tokens, labels) = _params(cfg), _batch(cfg, batch=1)
+    with jax.default_matmul_precision("highest"):
+        got = tfm.loss_fn(params, tokens, labels, cfg, attention=attention)
+        want = _reference(cfg, params, tokens, labels)[0]
+    assert abs(got - want) <= F32_REL * abs(want)
+
+
+# --- the grouped matmuls at widths the tile does not divide -----------------
+
+@pytest.mark.parametrize("k,n", [(128, 384), (384, 128), (384, 640)],
+                         ids=["n_21x128_like", "k_21x128_like", "both"])
+def test_grouped_matmul_at_widths_its_tile_does_not_divide(monkeypatch, k,
+                                                           n):
+    """Tiles of 256: 384 and 640 are multiples of 128 that 256 does not
+    divide, as 2688 = 21 x 128 is to 2048; they take 128."""
+    monkeypatch.setattr(gm, "TILE_M", 16)
+    monkeypatch.setattr(gm, "SUB_M", 4)
+    monkeypatch.setattr(gm, "TILE_K", 256)
+    monkeypatch.setattr(gm, "TILE_N", 256)
+    sizes = jnp.asarray([30, 0, 50, 1, 47], jnp.int32)
+    rows = jax.random.normal(jax.random.key(0), (128, k))
+    weights = jax.random.normal(jax.random.key(1), (5, k, n)) * k ** -0.5
+    group = jnp.repeat(jnp.arange(5), sizes, total_repeat_length=128)
+
+    def plain(rows, weights):
+        return jnp.einsum("mk,mkn->mn", rows, weights[group])
+
+    loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)))
+    with jax.default_matmul_precision("highest"):
+        got, got_g = jax.value_and_grad(
+            loss(lambda r, w: gm.grouped_matmul(r, w, sizes)), (0, 1))(
+                rows, weights)
+        want, want_g = jax.value_and_grad(loss(plain), (0, 1))(rows, weights)
+    assert abs(got - want) <= 1e-5 * abs(want)
+    assert _rel(got_g[0], want_g[0]) <= 1e-5
+    assert _rel(got_g[1], want_g[1]) <= 1e-5
+
+
+@pytest.mark.parametrize("dim,tile,expected", [
+    (2688, 2048, 896), (1024, 2048, 1024), (2048, 2048, 2048),
+    (4096, 2048, 2048), (5376, 2048, 1792), (64, 2048, 64)])
+def test_tile_rule(dim, tile, expected):
+    assert gm._tile(dim, tile, "N") == expected
+    assert gm._tile(dim, tile, "K") == expected
+
+
+def test_tile_rule_still_refuses_what_no_tile_divides():
+    with pytest.raises(ValueError, match="N=2100"):
+        gm._tile(2100, 2048, "N")
+    with pytest.raises(ValueError, match="rows=600"):
+        gm._tile(600, 512, "rows")
+
+
+def test_rows_past_the_last_group_are_nobodys(monkeypatch):
+    """Group sizes that sum to less than the buffer: the rows they cover
+    are right, and the gradients count no row past them."""
+    monkeypatch.setattr(gm, "TILE_M", 16)
+    monkeypatch.setattr(gm, "SUB_M", 4)
+    sizes = jnp.asarray([10, 0, 27], jnp.int32)
+    rows = jax.random.normal(jax.random.key(0), (64, 32))
+    weights = jax.random.normal(jax.random.key(1), (3, 32, 48))
+    live = (jnp.arange(64) < 37)[:, None]
+    group = jnp.repeat(jnp.arange(3), sizes, total_repeat_length=64)
+
+    def kernel(rows, weights):
+        return jnp.where(live, gm.grouped_matmul(rows, weights, sizes), 0.0)
+
+    def plain(rows, weights):
+        return jnp.where(live, jnp.einsum("mk,mkn->mn", rows,
+                                          weights[group]), 0.0)
+
+    loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)))
+    with jax.default_matmul_precision("highest"):
+        got_g = jax.grad(loss(kernel), (0, 1))(rows, weights)
+        want_g = jax.grad(loss(plain), (0, 1))(rows, weights)
+        assert _rel(kernel(rows, weights), plain(rows, weights)) <= 1e-6
+    assert _rel(got_g[0][:37], want_g[0][:37]) <= 1e-5
+    assert _rel(got_g[1], want_g[1]) <= 1e-5
+
+
+# --- one chip's share of the expert layer -----------------------------------
+
+def _expert_layer(cfg, seed=0):
+    """One expert layer's leaves and normed inputs ``u`` [N, d]."""
+    only = dataclasses.replace(cfg, n_layers=1, layer_types=("mlp",),
+                               mtp_layer_types=(), mtp_loss_coef=0.0,
+                               **NO_SSM)
+    layer = tfm.init_params(jax.random.PRNGKey(seed), only)["layers"][0]
+    u = jax.random.normal(jax.random.PRNGKey(seed + 1), (64, cfg.d_model))
+    return only, layer, u
+
+
+def test_the_shares_add_up():
+    """The routed parts that the four shares of sixteen experts compute,
+    plus the shared expert counted once, are the uncut layer: the
+    reference's with every expert in its tree."""
+    cfg, layer, u = _expert_layer(NEMOTRON_TINY)
+    whole = dataclasses.replace(cfg, experts_held=0, experts_held_from=0)
+    k_up, k_down = jax.random.split(jax.random.PRNGKey(7))
+    w_up = jax.random.normal(k_up, (16, 32, 64)) * 32 ** -0.5
+    w_down = jax.random.normal(k_down, (16, 64, 32)) * 64 ** -0.5
+    dims = dict(_dims(cfg), held_from=0)
+    with jax.default_matmul_precision("highest"):
+        want = reference._moe_part(u, dict(layer, w_up=w_up, w_down=w_down),
+                                   dims, None, True)
+        shared = reference._moe_part(
+            u, dict(layer, w_up=w_up[:0], w_down=w_down[:0]), dims, None,
+            True)
+        total, rows = shared, []
+        for first in range(0, 16, 4):
+            share = dataclasses.replace(cfg, experts_held_from=first)
+            mine = dict(layer, w_up=w_up[first:first + 4],
+                        w_down=w_down[first:first + 4])
+            y, held_rows = moe.latent_moe_ffn(u, mine, share)
+            total = total + (y - shared)
+            rows.append(held_rows)
+        uncut, uncut_rows = moe.latent_moe_ffn(
+            u, dict(layer, w_up=w_up, w_down=w_down), whole)
+    assert _rel(total, want) <= F32_REL
+    assert _rel(uncut, want) <= F32_REL
+    # Every assignment is some share's, once.
+    assert int(jnp.sum(jnp.concatenate(rows))) == 64 * cfg.experts_per_token
+    np.testing.assert_array_equal(jnp.concatenate(rows), uncut_rows)
+
+
+def test_nothing_held_is_dropped_under_an_adversarial_router():
+    """Every token picks all four held experts (their router columns
+    dominate): the buffer of tokens x 4 is full to the last row and every
+    row is computed."""
+    cfg, layer, u = _expert_layer(NEMOTRON_TINY)
+    bias = jnp.zeros((16,)).at[4:8].set(10.0)
+    layer = dict(layer, router_bias=bias)
+    with jax.default_matmul_precision("highest"):
+        got, rows = moe.latent_moe_ffn(u, layer, cfg)
+        want = reference._moe_part(u, layer, _dims(cfg), None, True)
+    np.testing.assert_array_equal(rows, [64, 64, 64, 64])
+    assert int(rows.sum()) == moe.rows_bound(64, cfg.experts_per_token, 4)
+    assert _rel(got, want) <= F32_REL
+
+
+def test_an_empty_share_is_the_shared_expert_alone():
+    """No token picks a held expert: the buffer holds no row, no tile is
+    visited, and nothing undefined reaches the output or a gradient."""
+    cfg, layer, u = _expert_layer(NEMOTRON_TINY)
+    layer = dict(layer,
+                 router_bias=jnp.zeros((16,)).at[4:8].set(-10.0))
+
+    def loss(layer):
+        y, rows = moe.latent_moe_ffn(u, layer, cfg)
+        return jnp.sum(jnp.sin(y)), rows
+
+    with jax.default_matmul_precision("highest"):
+        (_, rows), grads = jax.value_and_grad(loss, has_aux=True)(layer)
+        want = reference._moe_part(
+            u, dict(layer, w_up=layer["w_up"][:0],
+                    w_down=layer["w_down"][:0]), _dims(cfg), None, True)
+        got = moe.latent_moe_ffn(u, layer, cfg)[0]
+    assert int(rows.sum()) == 0
+    assert _rel(got, want) <= F32_REL
+    assert all(bool(jnp.all(jnp.isfinite(g)))
+               for g in jax.tree_util.tree_leaves(grads))
+    assert float(jnp.abs(grads["w_up"]).max()) == 0.0
+
+
+def test_undefined_tails_never_meet_a_product(monkeypatch):
+    """What a grouped matmul leaves past its last group is any bits, in
+    its result and in the gradient of its rows (on the chip: whatever the
+    buffer held, ``nan`` among it).  Poisoned with ``nan`` here: the
+    layer's output and every gradient stay what they are, because every
+    tail is selected away before it meets a product (``0 * nan``)."""
+    cfg, layer, u = _expert_layer(NEMOTRON_TINY)
+
+    def poison(x, sizes):
+        tail = (jnp.arange(x.shape[0]) >= jnp.sum(sizes))[:, None]
+        return jnp.where(tail, jnp.nan, x)
+
+    @jax.custom_vjp
+    def poisoned(rows, weights, sizes):
+        return poison(gm.grouped_matmul(rows, weights, sizes), sizes)
+
+    def fwd(rows, weights, sizes):
+        return poisoned(rows, weights, sizes), (rows, weights, sizes)
+
+    def bwd(residuals, g):
+        rows, weights, sizes = residuals
+        d_rows, d_weights = jax.vjp(
+            lambda r, w: gm.grouped_matmul(r, w, sizes), rows, weights)[1](g)
+        return poison(d_rows, sizes), d_weights, None
+
+    poisoned.defvjp(fwd, bwd)
+
+    def loss(layer, u):
+        return jnp.sum(jnp.sin(moe.latent_moe_ffn(u, layer, cfg)[0]))
+
+    with jax.default_matmul_precision("highest"):
+        want, want_g = jax.value_and_grad(loss, (0, 1))(layer, u)
+        monkeypatch.setattr(moe, "grouped_matmul", poisoned)
+        got, got_g = jax.value_and_grad(loss, (0, 1))(layer, u)
+    assert bool(jnp.isfinite(got)) and abs(got - want) <= 1e-6 * abs(want)
+    for a, b in zip(jax.tree_util.tree_leaves(got_g),
+                    jax.tree_util.tree_leaves(want_g)):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+
+
+def test_sigmoid_router_weighs_over_all_chosen_and_bias_only_chooses():
+    h = jax.random.normal(jax.random.key(0), (32, 16))
+    w = jax.random.normal(jax.random.key(1), (16, 8))
+    bias = jnp.zeros((8,)).at[3].set(100.0)
+    top_w, top_i = moe.route_sigmoid(h, w, bias, 3, 5.0)
+    scores = jax.nn.sigmoid(h @ w)
+    assert bool(jnp.all(jnp.any(top_i == 3, axis=1)))
+    np.testing.assert_allclose(jnp.sum(top_w, axis=1), 5.0, rtol=1e-5)
+    chosen = jnp.take_along_axis(scores, top_i, axis=1)
+    np.testing.assert_allclose(
+        top_w, 5.0 * chosen / chosen.sum(1, keepdims=True), rtol=1e-5)
+    g = jax.grad(lambda b: jnp.sum(moe.route_sigmoid(h, w, b, 3, 5.0)[0]
+                                   ** 2))(bias)
+    assert float(jnp.abs(g).max()) == 0.0
+
+
+def test_held_slots_keep_every_held_assignment():
+    """More experts a token than held here: the slots are the held ones,
+    whatever their place among the token's choices."""
+    top_i = jnp.asarray([[9, 4, 0, 7, 12, 5], [0, 1, 2, 3, 8, 9],
+                         [15, 14, 6, 13, 12, 11]], jnp.int32)
+    top_w = jnp.arange(18, dtype=jnp.float32).reshape(3, 6) + 1.0
+    slot_w, slot_e = moe.held_slots(top_w, top_i, 4, 4)
+    assert slot_e.shape == (3, 4)
+    for row, want in enumerate(({0: 2.0, 3: 4.0, 1: 6.0}, {}, {2: 15.0})):
+        got = {int(e): float(w) for e, w in zip(slot_e[row], slot_w[row])
+               if e < 4}
+        assert got == want
+        assert float(slot_w[row][slot_e[row] == 4].sum()) == 0.0
+
+
+# --- the whole model --------------------------------------------------------
+
+@pytest.mark.parametrize("dtype,loss_rtol,grad_rel", [
+    (jnp.float32, F32_REL, 2e-4), (jnp.bfloat16, 5e-3, 0.4)],
+    ids=["float32", "bfloat16"])
+def test_loss_and_tail_gradients_match_the_reference(dtype, loss_rtol,
+                                                     grad_rel):
+    """The 11-layer pattern with the prediction module: both terms of the
+    loss and the nine gradients the reference can return."""
+    cfg = dataclasses.replace(NEMOTRON_TINY, dtype=dtype)
+    params, (tokens, labels) = _params(cfg), _batch(cfg)
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.value_and_grad(lambda p: tfm.loss_fn(
+            p, tokens, labels, cfg, attention="local"))(params)
+        want, want_g, stats = _reference(cfg, params, tokens, labels)
+    assert abs(loss - want) <= loss_rtol * abs(want)
+    got = _checked(grads)
+    assert set(reference.CHECKED) < set(got) == set(want_g)
+    for name in want_g:
+        assert _rel(got[name], want_g[name]) <= grad_rel, name
+    if dtype == jnp.bfloat16:
+        assert max(_rel(got[n], want_g[n]) for n in want_g) > 3e-3
+    # The selection bias chooses and is not trained.
+    assert float(jnp.abs(grads["layers"][1]["router_bias"]).max()) == 0.0
+    assert stats["rows"].shape == (6, 4)
+
+
+def test_the_second_loss_is_the_prediction_modules():
+    """Without the module the loss is the first term alone, and the
+    module's term is mtp_loss_coef times the rest."""
+    cfg = NEMOTRON_TINY
+    params, (tokens, labels) = _params(cfg), _batch(cfg)
+    plain = dataclasses.replace(cfg, mtp_layer_types=(), mtp_loss_coef=0.0)
+    bare = {k: v for k, v in params.items() if k != "mtp"}
+    doubled = dataclasses.replace(cfg, mtp_loss_coef=0.2)
+    first = tfm.loss_fn(bare, tokens, labels, plain, attention="local")
+    both = tfm.loss_fn(params, tokens, labels, cfg, attention="local")
+    more = tfm.loss_fn(params, tokens, labels, doubled, attention="local")
+    assert float(both) > float(first)
+    np.testing.assert_allclose(more - first, 2 * (both - first), rtol=1e-4)
+    assert "mtp" not in tfm.init_params(jax.random.PRNGKey(0), plain)
+
+
+@pytest.mark.parametrize("remat", ("dots", "full"))
+def test_remat_leaves_loss_and_gradients_alone(remat):
+    cfg = NEMOTRON_TINY
+    params, (tokens, labels) = _params(cfg), _batch(cfg, batch=1)
+    f = lambda r: jax.value_and_grad(lambda p: tfm.loss_fn(
+        p, tokens, labels, cfg, attention="local", remat=r))(params)
+    (loss, grads), (want, want_g) = f(remat), f("none")
+    assert abs(loss - want) <= 1e-6 * abs(want)
+    for name, g in _checked(want_g).items():
+        assert _rel(_checked(grads)[name], g) <= 1e-5, name
+
+
+@pytest.mark.parametrize("devices", (1, 4))
+def test_train_step_takes_the_gradient_of_the_global_batch(hvd, devices):
+    """Through ``make_train_step``, on one device and on a four-device
+    data mesh: loss = the reference's on the whole batch; update = -lr x
+    the reference's gradient of the **global** batch mean."""
+    from horovod_tpu.topology import build_mesh
+
+    cfg, lr = NEMOTRON_TINY, 0.1
+    mesh = build_mesh(axes=("data",), devices=jax.devices()[:devices])
+    optimizer = optax.sgd(lr, momentum=0.9)
+    step, _, _ = tfm.make_train_step(cfg, optimizer, mesh, attention="local",
+                                     donate=False, remat="full")
+    params = _params(cfg)
+    tokens, labels = _batch(cfg, batch=4)
+    new, opt_state, loss = step(params, optimizer.init(params), tokens,
+                                labels)
+    want, want_g, _ = jax.jit(lambda *a: _reference(cfg, *a))(
+        params, tokens, labels)
+    assert abs(loss - want) <= F32_REL * abs(want)
+    after, before = _checked(new), _checked(params)
+    # From zero momentum the slot holds the gradient itself: the one way
+    # to read dt_bias's (values of -7 to -4 beside an update of 1e-6).
+    momentum = _checked(opt_state[0].trace)
+    for name, g in want_g.items():
+        assert _rel(momentum[name], g) <= F32_REL, name
+        if name != "ssm_dt_bias_last":
+            # (after - before) / -lr loses three digits to the
+            # subtraction.
+            assert _rel((after[name] - before[name]) / -lr, g) <= 3e-3, name
+
+
+# --- refusals: never a silent fall back -------------------------------------
+
+def test_segment_ids_and_packed_are_refused_by_name(hvd):
+    from horovod_tpu.topology import build_mesh
+
+    cfg = NEMOTRON_TINY
+    tokens = jnp.zeros((2, 128), jnp.int32)
+    with pytest.raises(NotImplementedError, match="segment_ids.*mamba2"):
+        jax.eval_shape(lambda p: tfm.forward(
+            p, tokens, cfg, attention="local",
+            segment_ids=jnp.zeros_like(tokens)), tfm.init_abstract(cfg))
+    mesh = build_mesh(axes=("data",), devices=jax.devices()[:2])
+    with pytest.raises(NotImplementedError, match="packed"):
+        tfm.make_train_step(cfg, optax.sgd(0.1), mesh, packed=True)
+    # The prediction module alone refuses them too.
+    no_ssm = dataclasses.replace(
+        cfg, n_layers=2, layer_types=("attention", "mlp"), **NO_SSM)
+    with pytest.raises(NotImplementedError, match="packed.*prediction"):
+        tfm.make_train_step(no_ssm, optax.sgd(0.1), mesh, packed=True)
+
+
+@pytest.mark.parametrize("axis", ("model", "seq"))
+def test_model_and_sequence_axes_are_refused_by_name(hvd, axis):
+    from horovod_tpu.topology import build_mesh
+
+    mesh = build_mesh(axes=("data", axis), shape=(2, 2),
+                      devices=jax.devices()[:4])
+    match = "n_experts" if axis == "model" else "seq_axis"
+    with pytest.raises(NotImplementedError, match=match):
+        tfm.make_train_step(NEMOTRON_TINY, optax.sgd(0.1), mesh,
+                            **{f"{axis}_axis": axis})
+
+
+@pytest.mark.parametrize("field,value", [
+    ("n_kv_heads", 1), ("layer_types", ("attention", "mlp")),
+    ("mtp_layer_types", ("attention",))])
+def test_model_axis_decode_and_the_pipelined_builder_refuse_by_name(
+        hvd, field, value):
+    from horovod_tpu.topology import build_mesh
+
+    fields = {field: value}
+    if field == "mtp_layer_types":
+        fields["mtp_loss_coef"] = 0.1
+    cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
+                                n_layers=2, d_ff=64, max_seq=32, **fields)
+    params = tfm.init_abstract(cfg)
+    with pytest.raises(NotImplementedError, match=field):
+        tfm.decode_step(params, jnp.zeros((2,), jnp.int32),
+                        tfm.init_kv_cache(cfg, 2, 8), 0, cfg)
+    mesh = build_mesh(axes=("data", "pipe"), shape=(2, 2),
+                      devices=jax.devices()[:4])
+    with pytest.raises(NotImplementedError, match=field):
+        tfm.make_train_step_pipelined(cfg, optax.sgd(0.1), mesh)
+    mesh = build_mesh(axes=("data", "model"), shape=(2, 2),
+                      devices=jax.devices()[:4])
+    with pytest.raises(NotImplementedError, match=field):
+        tfm.make_train_step(cfg, optax.sgd(0.1), mesh, model_axis="model")
+
+
+@pytest.mark.parametrize("fields,error,message", [
+    (dict(ssm_groups=3), ValueError, "ssm_groups"),
+    (dict(ssm_chunk=0), ValueError, "ssm_chunk"),
+    (dict(n_kv_heads=3), ValueError, "n_kv_heads"),
+    (dict(d_latent=0), ValueError, "d_latent"),
+    (dict(experts_held_from=14), ValueError, "experts_held"),
+    (dict(router_aux_coef=0.01), NotImplementedError, "auxiliary"),
+    (dict(mtp_loss_coef=0.0), ValueError, "mtp_loss_coef"),
+    (dict(mtp_layer_types=("sliding",)), ValueError, "mtp_layer_types"),
+    (dict(mlp="swiglu"), ValueError, "relu2"),
+    (dict(layer_types=("attention", "mlp") * 5 + ("mlp",)), ValueError,
+     "ssm_"),
+])
+def test_config_refuses_what_it_cannot_mean(fields, error, message):
+    with pytest.raises(error, match=message):
+        dataclasses.replace(NEMOTRON_TINY, **fields)
+
+
+def test_specs_and_abstract_params_cover_every_leaf():
+    cfg = NEMOTRON_TINY
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    specs = tfm.param_specs(cfg, None)
+    assert (jax.tree_util.tree_structure(params)
+            == jax.tree_util.tree_structure(
+                specs, is_leaf=lambda x: isinstance(
+                    x, jax.sharding.PartitionSpec)))
+    # One norm a layer: the mixer's or the feed-forward part's.
+    for layer, kind in zip(params["layers"], cfg.layer_types):
+        norms = {k for k in layer if k.startswith("ln")}
+        assert norms == ({"ln2_scale"} if kind == "mlp" else {"ln1_scale"})
+    assert params["layers"][0]["ssm_w_in"].shape == (64, 64 + 192 + 4)
+    assert params["layers"][1]["w_up"].shape == (4, 32, 64)
+    assert params["layers"][1]["router"].shape == (64, 16)
+    assert params["mtp"]["w_eh"].shape == (128, 64)
+
+
+def test_published_decay_initialisation_ranges():
+    layer = mamba2.init_layer(jax.random.PRNGKey(0), dataclasses.replace(
+        NEMOTRON_TINY, ssm_heads=512, ssm_groups=2),
+        lambda key, shape: jnp.zeros(shape))
+    a, dt = jnp.exp(layer["ssm_a_log"]), jax.nn.softplus(layer["ssm_dt_bias"])
+    assert 1.0 <= float(a.min()) and float(a.max()) <= 16.0
+    assert 1e-3 * 0.999 <= float(dt.min()) and float(dt.max()) <= 0.1 * 1.001
+
+
+def test_trace_time_series_count_what_was_traced(hvd):
+    from horovod_tpu import telemetry
+
+    cfg = NEMOTRON_TINY
+    telemetry.reset_for_tests()
+    telemetry.configure(True)
+    try:
+        tokens = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+        jax.eval_shape(lambda p, t: tfm.loss_fn(
+            p, t, t, cfg, attention="local"), tfm.init_abstract(cfg), tokens)
+        text = telemetry.render_prometheus()
+        chunks = 2 * 4 * 128 // 32
+        for layer in (0, 2, 4, 6, 9):
+            assert f'hvd_ssm_chunks_total{{layer="{layer}"}} {chunks}' \
+                in text, text
+            assert (f'hvd_ssm_saved_state_bytes{{layer="{layer}"}} '
+                    f'{chunks * 16 * 32 * 4}') in text, text
+        for layer in ("1", "3", "5", "8", "10", "mtp_1"):
+            assert f'hvd_moe_experts_held{{layer="{layer}"}} 4' in text, text
+            assert (f'hvd_moe_rows_bound{{layer="{layer}"}} '
+                    f'{256 * 4}') in text, text
+        assert 'hvd_ssm_chunks_total{layer="1"' not in text
+        # Data, not static, on a share: not counted.
+        assert "hvd_moe_assignments_total" not in text
+    finally:
+        telemetry.reset_for_tests()
+
+
+def test_all_experts_held_is_the_layer_of_before(hvd):
+    """With every expert held the SwiGLU layer's step lowers without the
+    share's masks, and says how many it holds."""
+    from horovod_tpu import telemetry
+    from horovod_tpu.topology import build_mesh
+
+    mesh = build_mesh(axes=("data",), devices=jax.devices()[:2])
+    telemetry.reset_for_tests()
+    telemetry.configure(True)
+    try:
+        step, _, _ = tfm.make_train_step(OLMOE_TINY, optax.sgd(0.1), mesh,
+                                         attention="local")
+        params = tfm.init_abstract(OLMOE_TINY)
+        tokens = jax.ShapeDtypeStruct((4, 32), jnp.int32)
+        text = step.lower(params, jax.eval_shape(optax.sgd(0.1).init,
+                                                 params),
+                          tokens, tokens).as_text()
+        assert "moe_latent" not in text and "ssm_" not in text
+        assert "mtp" not in text
+        series = telemetry.render_prometheus()
+        assert 'hvd_moe_experts_held{layer="0"} 8' in series
+        assert 'hvd_moe_assignments_total{layer="0"}' in series
+    finally:
+        telemetry.reset_for_tests()
+
+
+def test_the_shares_of_a_gated_softmax_layer_add_up():
+    """Which experts are held and which form they have are two things:
+    two shares of the SwiGLU, softmax-routed layer's eight experts sum to
+    the layer with all of them held, value and gradient, and the router's
+    statistics (over all eight) are every share's."""
+    whole = dataclasses.replace(OLMOE_TINY, dtype=jnp.float32)
+    layer = tfm.init_params(jax.random.PRNGKey(0), whole)["layers"][0]
+    h = jax.random.normal(jax.random.PRNGKey(1), (64, whole.d_model))
+
+    def share_of(h, first):
+        cfg = dataclasses.replace(whole, experts_held=4,
+                                  experts_held_from=first)
+        mine = dict(layer, **{name: layer[name][first:first + 4]
+                              for name in moe.EXPERT_LEAVES})
+        assert jax.tree_util.tree_map(jnp.shape, mine) == \
+            jax.tree_util.tree_map(
+                jnp.shape, tfm.init_abstract(cfg)["layers"][0])
+        return moe.moe_ffn(h, mine, cfg)
+
+    def loss(f):
+        return lambda h: jnp.sum(jnp.sin(f(h)))
+
+    with jax.default_matmul_precision("highest"):
+        want, stats = moe.moe_ffn(h, layer, whole)
+        for first in (0, 4):
+            np.testing.assert_array_equal(share_of(h, first)[1].counts,
+                                          stats.counts)
+        both = lambda h: share_of(h, 0)[0] + share_of(h, 4)[0]
+        want_g = jax.grad(loss(lambda h: moe.moe_ffn(h, layer, whole)[0]))(h)
+        got_g = jax.grad(loss(both))(h)
+    assert _rel(both(h), want) <= F32_REL
+    assert _rel(got_g, want_g) <= F32_REL
+    with pytest.raises(ValueError, match="experts_held"):
+        dataclasses.replace(OLMOE_TINY, experts_held=4, experts_held_from=6)

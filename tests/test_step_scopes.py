@@ -222,6 +222,78 @@ def test_hybrid_step_carries_the_vocabulary_and_the_mixers_parts(
     assert set(gdn_reduce.PARTS) <= parts
 
 
+SSM_PARTS = {scopes.SSM_PROJ: scopes.ATTN_QKV, scopes.SSM_CONV: scopes.ATTN_QKV,
+             scopes.SSM_GATE_NORM: scopes.ATTN_OUT,
+             scopes.SSM_OUT: scopes.ATTN_OUT}
+LATENT_PARTS = MOE_PARTS + (scopes.MOE_LATENT, scopes.MOE_SHARED)
+NEMOTRON = dict(positions="none", tie_embeddings=False, n_kv_heads=1,
+                mlp="relu2", n_experts=8, experts_per_token=3, d_expert=64,
+                d_latent=32, d_shared=64, routed_scale=5.0, experts_held=2,
+                experts_held_from=2, ssm_heads=2, ssm_head_dim=32,
+                ssm_state=16, ssm_groups=1, ssm_conv_kernel=4, ssm_chunk=32,
+                layer_types=("mamba2", "mlp"),
+                mtp_layer_types=("attention", "mlp"), mtp_loss_coef=0.1)
+
+
+@pytest.mark.parametrize("attention,remat",
+                         [("local", "none"), ("flash", "full")])
+def test_nemotron_step_carries_the_vocabulary_and_every_part(
+        hvd, attention, remat):
+    """Layers of one part each and the prediction module: the Mamba-2
+    mixer's parts open under ``attn/qkv`` and ``attn/out`` and its
+    recurrence as a route the vocabulary answers with ``layer``; the
+    latent expert layer's six parts under ``mlp``; the module under
+    ``mtp``, holding model scopes of its own; every executed op has a
+    phase and a scope, and ``perfbench/ssm_reduce.py`` reads each part by
+    name."""
+    from perfbench import moe_reduce, ssm_reduce
+
+    text = _lm_step_text(attention, remat, False, **NEMOTRON)
+    _check_lm(text, attention, remat, False)
+    names = _op_names(text)
+    marks = ("jvp(", "transpose(") + (
+        ("rematted_computation",) if remat == "full" else ())
+    for part, parent in SSM_PARTS.items():
+        inside = f"{parent}/{part}"
+        for mark in marks:
+            if (part, mark) != (scopes.SSM_OUT, "rematted_computation"):
+                assert _under(names, part, inside, mark), (part, mark)
+        assert not _under(names, part, without=(inside,)), part
+    for mark in marks:
+        assert _under(names, scopes.ATTN_SSM_SCAN, mark), mark
+    for part in LATENT_PARTS:
+        inside = f"{scopes.MLP}/{part}"
+        for mark in marks:
+            assert _under(names, part, inside, mark), (part, mark)
+        assert not _under(names, part, without=(inside,)), part
+    # Layer 0 is a mixer alone and layer 1 a feed-forward part alone; the
+    # only attention is the prediction module's.
+    assert not any("ssm_" in n and "layer_1" in n for n in names)
+    assert not any(f"layer_0)/{scopes.MLP}" in n or
+                   f"layer_0/{scopes.MLP}" in n for n in names
+                   if scopes.MTP not in n)
+    assert all(scopes.MTP in n for n in names if ROUTE[attention] in n)
+    for scope in (scopes.EMBED, scopes.ATTN_QKV, scopes.ATTN_OUT,
+                  scopes.MLP, scopes.HEAD, scopes.LOSS):
+        assert _under(names, scope, scopes.MTP, "jvp("), scope
+        assert _under(names, scope, scopes.MTP, "transpose("), scope
+    inside_mtp = (f"jit(x)/transpose(jvp({scopes.MTP}))/{scopes.LAYER % 1}/"
+                  f"{scopes.MLP}/{scopes.MOE_SHARED}/dot_general")
+    assert scope_reduce.scope_of(inside_mtp) == scopes.MLP
+    assert ssm_reduce.parts_of(inside_mtp) == [scopes.MOE_SHARED, scopes.MTP]
+    scan = (f"jit(x)/transpose(jvp({scopes.LAYER % 0}))/"
+            f"{scopes.ATTN_SSM_SCAN}/while/body/mul")
+    assert scope_reduce.scope_of(scan) == "layer"
+    assert scope_reduce.phase_of(scan) == "bwd"
+    hlo = scope_reduce.parse_hlo(text)
+    parts = set()
+    for name, i in hlo.instructions.items():
+        if i.opcode in HELD:
+            parts.update(ssm_reduce.parts_of(moe_reduce.op_name_of(name,
+                                                                   hlo)))
+    assert set(ssm_reduce.PARTS) | {ssm_reduce.MTP} <= parts
+
+
 @pytest.mark.parametrize("attention", ("ring", "ulysses"))
 def test_sequence_routes_open_their_own_scope(hvd, attention):
     text = _lm_step_text(attention, "none", False, seq_axis="seq")
@@ -323,11 +395,16 @@ def test_the_benchmark_reads_the_same_vocabulary():
     # The recurrence's two kernels run under that route and are booked by
     # it, not by their names.
     gdn_kernels = {scopes.GDN_SCAN_FWD, scopes.GDN_SCAN_BWD}
+    # And the Mamba-2 mixer's parts with its route, the latent expert
+    # layer's two dense parts and the prediction module's component, read
+    # by ``perfbench/ssm_reduce.py``.
+    ssm_parts = (set(SSM_PARTS) | {scopes.ATTN_SSM_SCAN, scopes.MOE_LATENT,
+                                   scopes.MOE_SHARED, scopes.MTP})
     assert set(scope_reduce.KERNEL_NAMES) == kernels
     assert (set(scope_reduce.MODEL_SCOPES + scope_reduce.GRAD_MEAN_SCOPES
                 + scope_reduce.OPTIMIZER_SCOPES)
             == program - kernels - modules - {scopes.LAYER} - moe_parts
-            - moe_kernels - gdn_parts - gdn_kernels)
+            - moe_kernels - gdn_parts - gdn_kernels - ssm_parts)
     from perfbench import gdn_reduce
     assert ({p.rsplit("/", 1)[-1] for p in gdn_parts}
             == set(gdn_reduce.PARTS))
