@@ -27,20 +27,35 @@ starts from,
 
 Every decay is ``exp`` of a difference that is ``<= 0``: nothing is
 divided, so an ``a`` near 0 underflows to an exact zero and never to
-``inf``.  :func:`ssd` is that as ``jax.numpy`` that XLA compiles: the
-masked ``C B^T`` products and every chunk's own contribution to its end
-state for all chunks at once, a :func:`jax.lax.scan` over the chunks that
-carries ``S`` (one multiply-add a chunk), then the carried states' part of
-the output for all chunks at once.
+``inf``.
 
-Precision: ``delta``, ``log a``, its running sums, every decay and the
-carried state are float32; every matmul takes operands in the model dtype
-(the masked and decayed ``C B^T``, ``delta x`` and the state rounded to it
-where they are operands) and accumulates in float32.
+**What runs it** is read from the operand (:func:`recurrence_path`), not
+from a switch: the Pallas kernels of :mod:`horovod_tpu.ops.mamba2_scan`
+wherever they can run (compiled on a TPU mesh, interpreted elsewhere): a
+group's head states stay in VMEM over all of its chunks, the decay masks
+and the masked ``C B^T`` never go through HBM, and the chunk states only
+as what the backward keeps.
+:func:`ssd` is the same algorithm as ``jax.numpy`` that XLA compiles, for
+the operands the kernels do not take (a chunk, a group's channels or a
+state that is not whole lanes, a group wider than VMEM holds, as the
+published Mamba-2 models' one group of 80 heads; on the CPU, inside
+``shard_map(check_vma=True)``, so a training step without experts traces
+it there) and as the tests' second oracle: the masked ``C B^T`` products
+and every chunk's own contribution to its end state for all chunks at
+once, a :func:`jax.lax.scan` over the chunks that carries ``S`` (one
+multiply-add a chunk), then the carried states' part of the output for
+all chunks at once.  ``hvd_ssm_chunks_total{path}`` says which was
+traced.
+
+Precision, either way: ``delta``, ``log a``, its running sums, every
+decay and the carried state are float32; every matmul takes operands in
+the model dtype (the masked and decayed ``C B^T``, ``delta x`` and the
+state rounded to it where they are operands) and accumulates in float32.
 
 What the backward keeps of the recurrence is the float32 state at each
-chunk's start (:func:`saved_state_bytes`); everything else is
-differentiated through.
+chunk's start (:func:`saved_state_bytes`), either way; the ``jax.numpy``
+form differentiates through everything else, the backward kernel
+recomputes a chunk from its inputs and that state.
 
 Not here: ``segment_ids`` (the state's reset at a document boundary and
 the convolution's mask: ROADMAP R11), a model or sequence axis, decode.
@@ -57,6 +72,7 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu import telemetry
 from horovod_tpu.models.linear_attention import causal_conv
+from horovod_tpu.ops import mamba2_scan as kernels
 from horovod_tpu.parallel._vma import pin_to, vma_of
 from horovod_tpu.telemetry import scopes
 
@@ -160,6 +176,34 @@ def ssd(x, b_in, c_in, delta, log_a, chunk: int, dtype):
     return y.reshape(bsz, t, h, p)
 
 
+def ssd_scan(x, b_in, c_in, delta, log_a, d, chunk: int, groups: int):
+    """:func:`ssd` with the ``D x`` skip on the operands of
+    :func:`horovod_tpu.ops.mamba2_scan.mamba2_scan`, whose signature this
+    is: ``x`` [B, T, H P], ``b_in``, ``c_in`` [B, T, G N] in the model
+    dtype, ``delta``, ``log_a`` [B, T, H] float32, ``d`` [H] -> ``y``
+    [B, T, H P] float32."""
+    (bsz, t, width), h = x.shape, delta.shape[2]
+    by_head = x.reshape(bsz, t, h, width // h)
+    y = ssd(by_head, b_in.reshape(bsz, t, groups, -1),
+            c_in.reshape(bsz, t, groups, -1), delta, log_a, chunk, x.dtype)
+    return (y + d[:, None] * by_head.astype(jnp.float32)).reshape(
+        bsz, t, width)
+
+
+def recurrence_path(x, cfg) -> str:
+    """What runs the recurrence of a layer of ``cfg`` over an operand
+    ``x`` [B, T, ...]: ``"kernel"``, the Pallas kernels of
+    :mod:`horovod_tpu.ops.mamba2_scan`, compiled where the mesh that
+    executes ``x`` is TPU and interpreted elsewhere; or ``"xla"``,
+    :func:`ssd_scan`, where the kernels cannot run (a chunk, a group's
+    width or a state that is not whole lanes, a group wider than VMEM
+    holds, and the interpreter inside ``shard_map(check_vma=True)``:
+    ``mamba2_scan.takes``)."""
+    return "kernel" if kernels.takes(
+        x, cfg.ssm_chunk, cfg.ssm_heads // cfg.ssm_groups, cfg.ssm_head_dim,
+        cfg.ssm_state) else "xla"
+
+
 def saved_state_bytes(batch: int, t: int, cfg) -> int:
     """Bytes of chunk states the backward of one layer's recurrence
     keeps: the float32 state at the start of each chunk."""
@@ -174,8 +218,7 @@ def mixer(u, layer, cfg):
     own (``telemetry/scopes.py``)."""
     dt = cfg.dtype
     bsz, t, _ = u.shape
-    h, p, g, n = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
-                  cfg.ssm_state)
+    g, n = cfg.ssm_groups, cfg.ssm_state
     inner, conv, _ = widths(cfg)
     with jax.named_scope(scopes.ATTN_QKV):
         with jax.named_scope(scopes.SSM_PROJ):
@@ -190,12 +233,16 @@ def mixer(u, layer, cfg):
         with jax.named_scope(scopes.SSM_CONV):
             xbc = jax.nn.silu(causal_conv(xbc, layer["ssm_conv"])
                               + layer["ssm_conv_bias"]).astype(dt)
-            x = xbc[..., :inner].reshape(bsz, t, h, p)
-            b_in = xbc[..., inner:inner + g * n].reshape(bsz, t, g, n)
-            c_in = xbc[..., inner + g * n:].reshape(bsz, t, g, n)
+            x = xbc[..., :inner]
+            b_in = xbc[..., inner:inner + g * n]
+            c_in = xbc[..., inner + g * n:]
     with jax.named_scope(scopes.ATTN_SSM_SCAN):
-        y = ssd(x, b_in, c_in, delta, log_a, cfg.ssm_chunk, dt)
-        y = y + layer["ssm_d"][:, None] * x.astype(jnp.float32)
+        # Token-major as the convolution wrote them, either way: a
+        # group's heads are a column slab.
+        scan = (kernels.mamba2_scan if recurrence_path(u, cfg) == "kernel"
+                else ssd_scan)
+        y = scan(x, b_in, c_in, delta, log_a, layer["ssm_d"], cfg.ssm_chunk,
+                 g)
     with jax.named_scope(scopes.ATTN_OUT):
         with jax.named_scope(scopes.SSM_GATE_NORM):
             y = (y.reshape(bsz, t, g, inner // g)
@@ -213,7 +260,8 @@ def record_chunks(layer: int, x, cfg) -> None:
     """Trace-time series (what was compiled into the step, like
     ``hvd_gdn_blocks_total``): the chunks of the recurrence layer
     ``layer`` walks per step on one device over the batch and heads of
-    its input ``x`` [B, T, d], and the bytes of chunk states its backward
+    its input ``x`` [B, T, d], by what runs them
+    (:func:`recurrence_path`), and the bytes of chunk states its backward
     keeps."""
     if not telemetry.enabled():
         return
@@ -222,8 +270,9 @@ def record_chunks(layer: int, x, cfg) -> None:
         "hvd_ssm_chunks_total",
         "Chunks of the chunked Mamba-2 recurrence the traced state-space "
         "layer computes per step on one device (batch x heads x T / "
-        "chunk)",
-        layer=str(layer)).inc(batch * cfg.ssm_heads * (t // cfg.ssm_chunk))
+        "chunk), by what runs them (path: kernel | xla)",
+        layer=str(layer), path=recurrence_path(x, cfg)).inc(
+            batch * cfg.ssm_heads * (t // cfg.ssm_chunk))
     telemetry.gauge(
         "hvd_ssm_saved_state_bytes",
         "Bytes of chunk states the backward pass of the traced "

@@ -91,6 +91,11 @@ MOE_TGMM = "moe_tgmm"
 GDN_SCAN_FWD = "gdn_scan_fwd"
 GDN_SCAN_BWD = "gdn_scan_bwd"
 
+# The two Mamba-2 scan Pallas kernels (ops/mamba2_scan.py); they run under
+# ATTN_SSM_SCAN.
+SSM_SCAN_FWD = "ssm_scan_fwd"
+SSM_SCAN_BWD = "ssm_scan_bwd"
+
 # The functions handed to jax.jit: the XLA module is jit_<name>.
 LM_TRAIN_STEP = "hvd_lm_train_step"
 LM_PIPELINED_TRAIN_STEP = "hvd_lm_pipelined_train_step"

@@ -1,4 +1,5 @@
-"""The three flash kernels and the two gated-delta-rule kernels compiled at
+"""The three flash kernels, the two gated-delta-rule kernels and the two
+Mamba-2 scan kernels compiled at
 the benchmark's shapes for a v5e that is described and not attached (rehearsal 3 of the
 on-chip-measurement guide; the recipe of
 ``perfbench/tests/test_chip_compile.py``).
@@ -100,4 +101,48 @@ def test_gated_delta_rule_kernels_compile_for_the_v5e(one_chip):
         qk, qk, vo, gates, gates, vo).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     for name, calls in ((scopes.GDN_SCAN_FWD, 2), (scopes.GDN_SCAN_BWD, 1)):
+        assert len(re.findall(rf"%{name}[.\d]* = ", text)) == calls, name
+
+
+# One layer's recurrence: of nemotron3s_t8192 (8 groups of 16 heads of 64
+# channels, a state of 128, 8192 tokens), and of a group four times as
+# wide, which VMEM holds a chunk a grid step; bf16 with float32 gates.
+@pytest.mark.parametrize("t,g,r,p,chunks", [
+    (8192, 8, 16, 64, 2), (1024, 1, 32, 128, 1)],
+    ids=["nemotron3s_t8192", "wide_group"])
+def test_mamba2_scan_kernels_compile_for_the_v5e(one_chip, t, g, r, p,
+                                                 chunks):
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import mamba2_scan as op
+    from horovod_tpu.telemetry import scopes
+
+    bsz, n, chunk = 1, 128, 128
+    assert op.tiles(t, chunk, r, p, n) == chunks
+    assert op.takes(jnp.zeros((bsz, t, 8)), chunk, r, p, n)
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    x, bc = shape(bsz, t, g * r * p), shape(bsz, t, g * n)
+    gates = shape(bsz, g, r, t, dtype=jnp.float32)
+    skip = shape(g, 1, r * p, dtype=jnp.float32)
+    dy = shape(bsz, t, g * r * p, dtype=jnp.float32)
+
+    # The calls themselves, told to compile: the public function reads
+    # the executing mesh, and this process's is the CPU.
+    def fwd_and_grads(x, b_in, c_in, delta, log_a, d, dy):
+        operands = (x, b_in, c_in, delta, log_a, d)
+        plain = op._fwd_call(*operands, chunk=chunk, save_states=False,
+                             interpret=False)
+        y, states = op._fwd_call(*operands, chunk=chunk, save_states=True,
+                                 interpret=False)
+        return plain, y, op._bwd_call(*operands, y, dy, states, chunk=chunk,
+                                      interpret=False)
+
+    text = jax.jit(fwd_and_grads).lower(
+        x, bc, bc, gates, gates, skip, dy).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name, calls in ((scopes.SSM_SCAN_FWD, 2), (scopes.SSM_SCAN_BWD, 1)):
         assert len(re.findall(rf"%{name}[.\d]* = ", text)) == calls, name

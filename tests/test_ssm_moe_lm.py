@@ -624,10 +624,21 @@ def test_published_decay_initialisation_ranges():
     assert 1e-3 * 0.999 <= float(dt.min()) and float(dt.max()) <= 0.1 * 1.001
 
 
-def test_trace_time_series_count_what_was_traced(hvd):
+# Widths the Mamba-2 scan kernels take (``ops/mamba2_scan.takes``): a
+# chunk, a group's channels and a state of whole lanes.
+KERNEL_WIDTHS = dict(ssm_heads=16, ssm_head_dim=16, ssm_state=128,
+                     ssm_chunk=128)
+
+
+@pytest.mark.parametrize("widths,path", [({}, "xla"),
+                                         (KERNEL_WIDTHS, "kernel")],
+                         ids=["narrow", "whole_lanes"])
+def test_trace_time_series_count_what_was_traced(hvd, widths, path):
+    """Traced outside ``shard_map``, the kernels run the recurrence (here
+    in the interpreter) wherever they take the layer's widths."""
     from horovod_tpu import telemetry
 
-    cfg = NEMOTRON_TINY
+    cfg = dataclasses.replace(NEMOTRON_TINY, **widths)
     telemetry.reset_for_tests()
     telemetry.configure(True)
     try:
@@ -635,19 +646,55 @@ def test_trace_time_series_count_what_was_traced(hvd):
         jax.eval_shape(lambda p, t: tfm.loss_fn(
             p, t, t, cfg, attention="local"), tfm.init_abstract(cfg), tokens)
         text = telemetry.render_prometheus()
-        chunks = 2 * 4 * 128 // 32
+        chunks = 2 * cfg.ssm_heads * 128 // cfg.ssm_chunk
         for layer in (0, 2, 4, 6, 9):
-            assert f'hvd_ssm_chunks_total{{layer="{layer}"}} {chunks}' \
-                in text, text
+            assert (f'hvd_ssm_chunks_total{{layer="{layer}",path="{path}"}} '
+                    f'{chunks}') in text, text
             assert (f'hvd_ssm_saved_state_bytes{{layer="{layer}"}} '
-                    f'{chunks * 16 * 32 * 4}') in text, text
+                    f'{chunks * cfg.ssm_head_dim * cfg.ssm_state * 4}'
+                    ) in text, text
         for layer in ("1", "3", "5", "8", "10", "mtp_1"):
             assert f'hvd_moe_experts_held{{layer="{layer}"}} 4' in text, text
             assert (f'hvd_moe_rows_bound{{layer="{layer}"}} '
                     f'{256 * 4}') in text, text
         assert 'hvd_ssm_chunks_total{layer="1"' not in text
+        other = {"xla": "kernel", "kernel": "xla"}[path]
+        assert f'path="{other}"' not in text
         # Data, not static, on a share: not counted.
         assert "hvd_moe_assignments_total" not in text
+    finally:
+        telemetry.reset_for_tests()
+
+
+def test_chunk_counters_say_which_path_the_training_step_took(hvd):
+    """The label is read where the path is chosen: a training step whose
+    ``shard_map`` checks varying axes (no experts) traces the
+    ``jax.numpy`` form on a CPU mesh, where the interpreter cannot run;
+    on a TPU mesh it traces the kernels
+    (``tests/test_flash_compile.py`` compiles them)."""
+    from horovod_tpu import telemetry
+    from horovod_tpu.topology import build_mesh
+
+    cfg = tfm.TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=64,
+        max_seq=128, dtype=jnp.float32, positions="none",
+        layer_types=("mamba2", "mlp"), ssm_groups=2, ssm_conv_kernel=4,
+        **KERNEL_WIDTHS)
+    telemetry.reset_for_tests()
+    telemetry.configure(True)
+    try:
+        mesh = build_mesh(axes=("data",), devices=jax.devices()[:2])
+        optimizer = optax.sgd(0.1)
+        step, _, _ = tfm.make_train_step(cfg, optimizer, mesh,
+                                         attention="local")
+        params = tfm.init_abstract(cfg)
+        tokens = jax.ShapeDtypeStruct((4, 128), jnp.int32)
+        lowered = step.lower(params, jax.eval_shape(optimizer.init, params),
+                             tokens, tokens).as_text(debug_info=True)
+        text = telemetry.render_prometheus()
+        assert 'hvd_ssm_chunks_total{layer="0",path="xla"} 32' in text, text
+        assert 'path="kernel"' not in text
+        assert "cumsum" in lowered and "ssm_scan_fwd" not in lowered
     finally:
         telemetry.reset_for_tests()
 

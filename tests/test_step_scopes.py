@@ -400,11 +400,14 @@ def test_the_benchmark_reads_the_same_vocabulary():
     # by ``perfbench/ssm_reduce.py``.
     ssm_parts = (set(SSM_PARTS) | {scopes.ATTN_SSM_SCAN, scopes.MOE_LATENT,
                                    scopes.MOE_SHARED, scopes.MTP})
+    # Its recurrence's two kernels, likewise booked by the route.
+    ssm_kernels = {scopes.SSM_SCAN_FWD, scopes.SSM_SCAN_BWD}
     assert set(scope_reduce.KERNEL_NAMES) == kernels
     assert (set(scope_reduce.MODEL_SCOPES + scope_reduce.GRAD_MEAN_SCOPES
                 + scope_reduce.OPTIMIZER_SCOPES)
             == program - kernels - modules - {scopes.LAYER} - moe_parts
-            - moe_kernels - gdn_parts - gdn_kernels - ssm_parts)
+            - moe_kernels - gdn_parts - gdn_kernels - ssm_parts
+            - ssm_kernels)
     from perfbench import gdn_reduce
     assert ({p.rsplit("/", 1)[-1] for p in gdn_parts}
             == set(gdn_reduce.PARTS))
@@ -414,6 +417,14 @@ def test_the_benchmark_reads_the_same_vocabulary():
         call = (f"jit(x)/{name % (scopes.LAYER % 1)}/{scopes.ATTN_GDN_SCAN}"
                 f"/{kernel}/pallas_call")
         assert gdn_reduce.part_of(call) == "gdn_scan"
+        assert scope_reduce.phase_of(call) == phase
+    from perfbench import ssm_reduce
+    for phase, name, kernel in (
+            ("fwd", "jvp(%s)", scopes.SSM_SCAN_FWD),
+            ("bwd", "transpose(jvp(%s))", scopes.SSM_SCAN_BWD)):
+        call = (f"jit(x)/{name % (scopes.LAYER % 2)}/{scopes.ATTN_SSM_SCAN}"
+                f"/{kernel}/pallas_call")
+        assert ssm_reduce.parts_of(call) == ["ssm_scan"]
         assert scope_reduce.phase_of(call) == phase
     assert scope_reduce.scope_of(
         f"jit(x)/jvp({scopes.LAYER % 3})/{scopes.MLP}/dot_general"
