@@ -16,9 +16,10 @@ expert matmuls run as *grouped* matmuls over the ragged groups
 (:mod:`horovod_tpu.ops.grouped_matmul`; the candidates that lost are in
 PERF.md, PR 26).
 Group sizes are taken as they come: nothing is padded to a capacity and
-nothing assumes balance.  Both row moves are gathers in both directions
-(:func:`_gather_rows`): the backward pass of "gather the sorted rows" is
-"gather them back and add", never a scatter.
+nothing assumes balance.  Where every slot has a row of the buffer, both
+row moves are gathers in both directions (:func:`_gather_rows`): the
+backward pass of "gather the sorted rows" is "gather them back and add",
+never a scatter (decided at 65,536 rows of 2,048: PERF.md, PR 26).
 
 **One chip's share of a wider layer** (``TransformerConfig.experts_held``
 fewer than ``n_experts``): the router still scores all ``n_experts`` and
@@ -27,8 +28,27 @@ holds and leaves the rest out (:func:`this_chips_share`, for either
 router and either expert form): no exchange, and
 nothing stands in for the absent chips.  Shapes stay static: a token can
 land here at most ``min(k, held)`` times, so a row buffer of ``N *
-min(k, held)`` bounds every batch, the grouped matmuls visit only the
-tiles that held rows reach, and **nothing held is ever dropped**.
+min(k, held)`` bounds every batch (:func:`rows_bound`), and **nothing
+held is ever dropped**.  A batch fills a few percent of that bound, at
+its head, so the layer works on a *prefix* of the sorted buffer, derived
+from the shapes (:func:`rows_prefix`: four times the rows a uniform
+router sends, in whole row tiles), and a batch whose held rows overflow
+it is computed over the whole buffer: the same function of the same
+inputs, chosen on the device by the batch's own row count
+(:func:`_prefix_ffn`; under ``remat`` either form runs forward twice a
+step, as the layer of before did, and a batch past the prefix costs what
+it cost: ``nemotron3s_t8192`` with every expert layer forced past it,
+835.8 ms a step against the parent's 839.3; PERF.md, PR 36).  On the
+prefix a token's rows cannot be listed in a static shape, so the rows go
+out by one gather of the prefix and come back by **one sum into the
+tokens, a scatter-add** (:func:`_head_ffn`).
+Decided again on the chip at the prefix of ``nemotron3s_t8192``, 11,264
+rows of 1,024 into 8,192 tokens (PERF.md, PR 36): the sum takes 0.54 ms
+(``moe_combine`` of a traced step 9.4), a gather of all 65,536 slots that
+reads a zero row for a dead one 0.50, and the gather of before, from
+65,536 rows, 1.52.  Level on the chip, so the sum it is: it costs what
+the rows that exist cost, and leaves nothing in the program as long as
+the bound but indices.
 
 **The latent layer** (:func:`latent_moe_ffn`; DeepSeek-V3's scoring with
 Nemotron-3's experts): sigmoid scores with a selection bias that chooses
@@ -52,6 +72,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu import telemetry
+from horovod_tpu.ops import grouped_matmul as gmm
 from horovod_tpu.ops.grouped_matmul import (grouped_matmul,
                                             worst_matmul_rows)
 from horovod_tpu.telemetry import scopes
@@ -158,8 +179,195 @@ def _expert_operands(layer):
     return tuple(layer[name] for name in EXPERT_LEAVES if name in layer)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _rows_of(tokens: int, h, token):
+    """``h[token]`` for ``h`` [tokens, d]: the tokens' rows at the head of
+    the sorted buffer (``token`` [m] int32, a token as often as it has
+    places there).  Its gradient is the sum of the rows' into their
+    tokens, a scatter-add of ``m`` rows, in float32 like the sum over a
+    token's uses in :func:`_gather_rows`."""
+    del tokens
+    return _take(h, token)
+
+
+def _rows_of_fwd(tokens, h, token):
+    return _take(h, token), token
+
+
+def _rows_of_bwd(tokens, token, g):
+    return jax.ops.segment_sum(g.astype(jnp.float32), token,
+                               num_segments=tokens).astype(g.dtype), None
+
+
+_rows_of.defvjp(_rows_of_fwd, _rows_of_bwd)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _lowered_once(matmul, x, w, group_sizes):
+    """``matmul(x, w, group_sizes)`` as a call of one function a shape:
+    a share's step holds 96 grouped-matmul kernels (two forms of each
+    layer; the forward's two, then the backward's two again and their
+    four gradients), and traced in line each is lowered on its own, ~15
+    ms apiece before every run (ROADMAP S4)."""
+    return matmul(x, w, group_sizes)
+
+
+def _experts(x, live, group_sizes, layer, act: str, dtype):
+    """The held experts on the sorted rows ``x`` [m, d] -> [m, d];
+    ``live`` [m, 1] bool marks the rows that are some held expert's (None:
+    all of them)."""
+
+    def matmul(x, w):
+        if live is None:
+            return grouped_matmul(x, w, group_sizes)
+        # A select, not a product: its gradient is a select too, so the
+        # operand's gradient (a grouped matmul's result as well) is
+        # zeroed before anything multiplies it.
+        x = jnp.where(live, x, 0)
+        return jnp.where(
+            live, _lowered_once(grouped_matmul, x, w, group_sizes), 0)
+
+    if act == "swiglu":
+        w_gate, w_up, w_down = _expert_operands(layer)
+        gate = matmul(x, w_gate)
+        up = matmul(x, w_up)
+        hidden = (jax.nn.silu(gate.astype(jnp.float32))
+                  * up.astype(jnp.float32)).astype(dtype)
+    elif act == "relu2":
+        w_up, w_down = _expert_operands(layer)
+        up = matmul(x, w_up)
+        hidden = jnp.square(
+            jax.nn.relu(up.astype(jnp.float32))).astype(dtype)
+    else:
+        raise ValueError(f"act={act!r}: expected 'swiglu' or 'relu2'")
+    return matmul(hidden, w_down)
+
+
+def _every_slot_ffn(act: str, dtype, masked: bool, h, slot_w, order,
+                    group_sizes, layer):
+    """:func:`experts_ffn` with a row of the buffer for every slot, all
+    ``N * s``: both row moves are gathers in both directions
+    (:func:`_gather_rows`).  ``masked``: rows past ``sum(group_sizes)``
+    are no held expert's."""
+    n, s = slot_w.shape
+    live = ((jnp.arange(n * s) < jnp.sum(group_sizes))[:, None] if masked
+            else None)
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        # place[a]: where assignment a went.
+        place = jnp.zeros_like(order).at[order].set(
+            jnp.arange(n * s, dtype=jnp.int32), unique_indices=True)
+        x = _gather_rows(h, order // s, place, s)
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        out = _experts(x, live, group_sizes, layer, act, dtype)
+    with jax.named_scope(scopes.MOE_COMBINE):
+        per_token = _gather_rows(out, place, order, 1).reshape(n, s, -1)
+        return jnp.sum(per_token.astype(jnp.float32) * slot_w[..., None],
+                       axis=1).astype(dtype)
+
+
+def _head_ffn(rows: int, act: str, dtype, h, slot_w, order, group_sizes,
+              layer):
+    """:func:`experts_ffn` over the first ``rows`` sorted places, which
+    hold every held row (the caller's promise; ``rows`` static, fewer
+    than ``N * s``).  A token's places among them cannot be listed in a
+    static shape, so the rows go out by one gather of ``rows``
+    (:func:`_rows_of`) and come back by one sum into the tokens, a
+    scatter-add of ``rows``, each the other's gradient: nothing here is
+    ``N * s`` long but ``order``."""
+    n, s = slot_w.shape
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        head = order[:rows]
+        token = head // s
+        live = (jnp.arange(rows) < jnp.sum(group_sizes))[:, None]
+        x = _rows_of(n, h, token)
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        out = _experts(x, live, group_sizes, layer, act, dtype)
+    with jax.named_scope(scopes.MOE_COMBINE):
+        weighted = (out.astype(jnp.float32)
+                    * _take(slot_w.reshape(-1), head)[:, None])
+        return jax.ops.segment_sum(weighted, token,
+                                   num_segments=n).astype(dtype)
+
+
+def _projected(y, project, dtype):
+    """``y @ project`` (None: ``y``), under the latent projections'
+    scope."""
+    if project is None:
+        return y
+    with jax.named_scope(scopes.MOE_LATENT):
+        return y @ project.astype(dtype)
+
+
+def _head_or_every_slot(prefix: int, act: str, dtype):
+    """The two forms of a share's layer, as functions of ``(h, slot_w,
+    order, group_sizes, experts, project)``: over the ``prefix`` rows of
+    the buffer's head, for a batch whose held rows fit them, and over
+    every slot, for one that overflows them.  One function of the same
+    inputs either way, so such a batch is computed whole."""
+
+    def form(ffn):
+        return lambda h, slot_w, order, group_sizes, experts, project: (
+            _projected(ffn(h, slot_w, order, group_sizes, experts), project,
+                       dtype))
+
+    return (form(functools.partial(_head_ffn, prefix, act, dtype)),
+            form(functools.partial(_every_slot_ffn, act, dtype, True)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _prefix_ffn(prefix: int, act: str, dtype, h, slot_w, order, group_sizes,
+                experts, project):
+    """:func:`experts_ffn` on a share: the form the batch's own row count
+    chooses on the device (:func:`_head_or_every_slot`).
+
+    Why a gradient of its own: autodiff of the conditional keeps both
+    branches' intermediates for the backward pass and has the branch
+    taken write zeros for the other's, a pass over the bound's shapes (~1
+    GB an expert layer of ``nemotron3s_t8192``) on the path that is there
+    to avoid them.  So the backward pass branches for itself and
+    differentiates the form the batch needs, from the layer's inputs:
+    they are all that is kept between the passes, and the backward
+    computes the chosen form once more.
+
+    Why ``project`` is inside: the product that follows the layer keeps
+    its operand for its own gradient.  With it inside, nothing outside
+    needs this function's result a second time, so where the model
+    recomputes the layer for the backward pass (``remat``) the recomputed
+    forward is dead code and is dropped: either form then runs forward
+    twice a step, as the layer of before did, and not three times."""
+    return lax.cond(jnp.sum(group_sizes) <= prefix,
+                    *_head_or_every_slot(prefix, act, dtype), h, slot_w,
+                    order, group_sizes, experts, project)
+
+
+def _prefix_ffn_fwd(prefix, act, dtype, h, slot_w, order, group_sizes,
+                    experts, project):
+    return (_prefix_ffn(prefix, act, dtype, h, slot_w, order, group_sizes,
+                        experts, project),
+            (h, slot_w, order, group_sizes, experts, project))
+
+
+def _prefix_ffn_bwd(prefix, act, dtype, residuals, g):
+    h, slot_w, order, group_sizes, experts, project = residuals
+
+    def gradients(ffn):
+        return lambda h, slot_w, experts, project, g: jax.vjp(
+            lambda h, slot_w, experts, project: ffn(
+                h, slot_w, order, group_sizes, experts, project),
+            h, slot_w, experts, project)[1](g)
+
+    d_h, d_slot_w, d_experts, d_project = lax.cond(
+        jnp.sum(group_sizes) <= prefix,
+        *map(gradients, _head_or_every_slot(prefix, act, dtype)), h, slot_w,
+        experts, project, g)
+    return d_h, d_slot_w, None, None, d_experts, d_project
+
+
+_prefix_ffn.defvjp(_prefix_ffn_fwd, _prefix_ffn_bwd)
+
+
 def experts_ffn(h, slot_w, slot_e, group_sizes, layer, dtype,
-                act: str = "swiglu", live=None):
+                act: str = "swiglu", prefix=None, project=None):
     """``sum_s slot_w[:, s] * expert_{slot_e[:, s]}(h)``: ``h`` [N, d] ->
     [N, d] in ``dtype``.  ``slot_e`` [N, s] int32: a token's experts, as
     indices into the experts ``layer`` holds (or their count, for a slot
@@ -173,58 +381,63 @@ def experts_ffn(h, slot_w, slot_e, group_sizes, layer, dtype,
     The ``N * s`` slots are sorted by expert, "not here" last: the held
     rows are the buffer's head, in expert order, and the grouped matmuls
     take the group sizes as they come and visit no tile past the last
-    group.  ``live`` [N * s, 1] bool (None where every row is some
-    expert's: the chip holds them all) marks that head.  What a grouped
-    matmul leaves in the tail of its result, and of its operand's
-    gradient, is not defined (any bits, ``nan`` among them): each is
-    zeroed on both sides (:func:`matmul`), so nothing undefined meets a
-    product, forward or backward."""
+    group.  ``prefix`` (static; :func:`this_chips_share`): None where
+    every slot is some held expert's, and the buffer is the ``N * s``
+    places; on a share, the rows of the buffer's head that the layer
+    works on while a batch's held rows fit them (:func:`rows_prefix`), a
+    batch with more taking all ``N * s`` (:func:`_prefix_ffn`).  What a
+    grouped matmul leaves in the tail of its result, and of its operand's
+    gradient, is not defined (any bits, ``nan`` among them): on a share
+    each is zeroed on both sides (:func:`_experts`), so nothing undefined
+    meets a product, forward or backward.  ``project`` [d, d_out], where
+    given: the result times it, as part of the layer (why:
+    :func:`_prefix_ffn`)."""
     n, s = slot_e.shape
     with jax.named_scope(scopes.MOE_DISPATCH):
-        flat = slot_e.reshape(-1)
-        # order[j]: the assignment (token * s + slot) at sorted place j;
-        # place[a]: where assignment a went.  Stable, so an expert's rows
-        # keep token order.  The group sizes are the caller's count of
-        # the same keys.
-        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-        place = jnp.zeros_like(order).at[order].set(
-            jnp.arange(n * s, dtype=jnp.int32), unique_indices=True)
-        rows = _gather_rows(h, order // s, place, s)
-
-    def matmul(x, weights):
-        if live is None:
-            return grouped_matmul(x, weights, group_sizes)
-        # A select, not a product: its gradient is a select too, so the
-        # operand's gradient (a grouped matmul's result as well) is
-        # zeroed before anything multiplies it.
-        x = jnp.where(live, x, 0)
-        return jnp.where(live, grouped_matmul(x, weights, group_sizes), 0)
-
-    with jax.named_scope(scopes.MOE_EXPERTS):
-        if act == "swiglu":
-            w_gate, w_up, w_down = _expert_operands(layer)
-            gate = matmul(rows, w_gate)
-            up = matmul(rows, w_up)
-            hidden = (jax.nn.silu(gate.astype(jnp.float32))
-                      * up.astype(jnp.float32)).astype(dtype)
-        elif act == "relu2":
-            w_up, w_down = _expert_operands(layer)
-            up = matmul(rows, w_up)
-            hidden = jnp.square(
-                jax.nn.relu(up.astype(jnp.float32))).astype(dtype)
-        else:
-            raise ValueError(f"act={act!r}: expected 'swiglu' or 'relu2'")
-        out = matmul(hidden, w_down)
-    with jax.named_scope(scopes.MOE_COMBINE):
-        per_token = _gather_rows(out, place, order, 1).reshape(n, s, -1)
-        return jnp.sum(per_token.astype(jnp.float32) * slot_w[..., None],
-                       axis=1).astype(dtype)
+        # order[j]: the assignment (token * s + slot) at sorted place j.
+        # Stable, so an expert's rows keep token order.  The group sizes
+        # are the caller's count of the same keys.
+        order = jnp.argsort(slot_e.reshape(-1), stable=True).astype(
+            jnp.int32)
+    if prefix is None or prefix >= n * s:
+        return _projected(
+            _every_slot_ffn(act, dtype, prefix is not None, h, slot_w, order,
+                            group_sizes, layer), project, dtype)
+    experts = {name: layer[name] for name in EXPERT_LEAVES if name in layer}
+    return _prefix_ffn(prefix, act, dtype, h, slot_w, order, group_sizes,
+                       experts, project)
 
 
 def rows_bound(tokens: int, k: int, held: int) -> int:
     """Rows of :func:`experts_ffn`'s buffer: a token's ``k`` experts are
     distinct, so at most ``min(k, held)`` of them are held here."""
     return tokens * min(k, held)
+
+
+# Rows of the prefix over the rows a uniform router sends to the held
+# experts, ``tokens * k * held / n_experts``.  At initialisation the six
+# expert layers of ``nemotron3s_t8192`` draw up to 1.45 times that (4,094
+# rows against 2,816; PERF.md, PR 35-36).  What a trained router sends is
+# not measured here: 4 keeps one whose held experts are four times as
+# popular as the mean on the prefix (11,264 rows there) and still leaves
+# 83% of the bound's rows alone; 2 (5,632) would save ~8 ms a step more
+# and send a skew of 2 over the bound.  Derived from the shapes, never
+# set: a batch past it is computed whole all the same
+# (:func:`_prefix_ffn`), and a chip that is sent a deployment's rows
+# (a third of the bound in that cell) is past it every step: the rungs
+# are to be sized again from such traffic (ROADMAP R15).
+PREFIX_SLACK = 4
+
+
+def rows_prefix(tokens: int, k: int, held: int, n_experts: int) -> int:
+    """Rows of the sorted buffer's head that a share of the experts works
+    on (:func:`experts_ffn`): :data:`PREFIX_SLACK` times the expected
+    rows, rounded up to the grouped matmuls' row tile, and never more
+    than :func:`rows_bound`, which it is where every expert is held."""
+    bound = rows_bound(tokens, k, held)
+    expected = -(-tokens * k * held // n_experts)
+    tile = gmm.TILE_M
+    return min(bound, -(-PREFIX_SLACK * expected // tile) * tile)
 
 
 def held_slots(top_w, top_i, first: int, held: int):
@@ -245,12 +458,13 @@ def held_slots(top_w, top_i, first: int, held: int):
 
 def this_chips_share(top_w, top_i, cfg, counts=None):
     """A router's choice as :func:`experts_ffn` takes it on this chip:
-    ``(slot_w, slot_e, group_sizes, live)``.  Where the chip holds every
-    expert that is the choice itself and ``live`` is None (``counts``: the
-    router's assignments per expert, where it has them already).  Where
-    it holds a share (``cfg.experts_held``): the held slots
-    (:func:`held_slots`), the rows each held expert receives and the mask
-    of the buffer's rows that are some held expert's."""
+    ``(slot_w, slot_e, group_sizes, prefix)``.  Where the chip holds every
+    expert that is the choice itself and ``prefix`` is None (``counts``:
+    the router's assignments per expert, where it has them already).
+    Where it holds a share (``cfg.experts_held``): the held slots
+    (:func:`held_slots`), the rows each held expert receives and the rows
+    of the buffer's head that hold an ordinary batch's
+    (:func:`rows_prefix`, static)."""
     held = cfg.held_experts
     share = held < cfg.n_experts
     if share:
@@ -259,9 +473,9 @@ def this_chips_share(top_w, top_i, cfg, counts=None):
         counts = jnp.sum(
             top_i.reshape(-1, 1) == jnp.arange(held)[None, :], axis=0,
             dtype=jnp.int32)
-    live = ((jnp.arange(top_i.size) < jnp.sum(counts))[:, None] if share
-            else None)
-    return top_w, top_i, counts, live
+    prefix = (rows_prefix(top_i.shape[0], cfg.experts_per_token, held,
+                          cfg.n_experts) if share else None)
+    return top_w, top_i, counts, prefix
 
 
 def moe_ffn(h, layer, cfg):
@@ -272,10 +486,10 @@ def moe_ffn(h, layer, cfg):
         top_p, top_i, stats = route(flat, layer["router"],
                                     cfg.experts_per_token,
                                     cfg.norm_topk_prob)
-        slot_w, slot_e, rows, live = this_chips_share(
+        slot_w, slot_e, rows, prefix = this_chips_share(
             top_p, top_i, cfg, stats.counts)
     y = experts_ffn(flat, slot_w, slot_e, rows, layer, cfg.dtype,
-                    act="swiglu", live=live)
+                    act="swiglu", prefix=prefix)
     return y.reshape(h.shape), stats
 
 
@@ -308,13 +522,12 @@ def latent_moe_ffn(u, layer, cfg):
         top_w, top_i = route_sigmoid(
             flat, layer["router"], layer["router_bias"],
             cfg.experts_per_token, cfg.routed_scale)
-        slot_w, slot_e, rows, live = this_chips_share(top_w, top_i, cfg)
+        slot_w, slot_e, rows, prefix = this_chips_share(top_w, top_i, cfg)
     with jax.named_scope(scopes.MOE_LATENT):
         latent = flat @ layer["w_latent_in"].astype(dt)
     routed = experts_ffn(latent, slot_w, slot_e, rows, layer, dt,
-                         act="relu2", live=live)
-    with jax.named_scope(scopes.MOE_LATENT):
-        routed = routed @ layer["w_latent_out"].astype(dt)
+                         act="relu2", prefix=prefix,
+                         project=layer["w_latent_out"])
     with jax.named_scope(scopes.MOE_SHARED):
         hidden = jnp.square(jax.nn.relu(
             (flat @ layer["w_shared_up"].astype(dt)).astype(jnp.float32)))
@@ -324,23 +537,30 @@ def latent_moe_ffn(u, layer, cfg):
 
 def record_held(layer: int, tokens: int, cfg) -> None:
     """Trace-time series beside ``hvd_moe_assignments_total``: the routed
-    experts layer ``layer`` holds on this chip, and the static bound on
-    the rows they can receive from ``tokens`` tokens (what they do
-    receive is data)."""
+    experts layer ``layer`` holds on this chip, the static bound on the
+    rows they can receive from ``tokens`` tokens, and the rows of the
+    buffer's head that the layer works on while a batch's fit them (what
+    they do receive is data)."""
     if not telemetry.enabled():
         return
+    k, held = cfg.experts_per_token, cfg.held_experts
     telemetry.gauge(
         "hvd_moe_experts_held",
         "Routed experts of the traced MoE layer that this chip holds (of "
         "n_experts the router scores)",
-        layer=str(layer)).set(cfg.held_experts)
+        layer=str(layer)).set(held)
     telemetry.gauge(
         "hvd_moe_rows_bound",
         "Rows of the traced MoE layer's buffer: tokens x min("
         "experts_per_token, experts held); no batch can need more, so "
         "nothing held is dropped",
-        layer=str(layer)).set(rows_bound(tokens, cfg.experts_per_token,
-                                         cfg.held_experts))
+        layer=str(layer)).set(rows_bound(tokens, k, held))
+    telemetry.gauge(
+        "hvd_moe_rows_prefix",
+        "Rows of the buffer's head that the traced MoE layer's row work "
+        "runs over while a batch's held rows fit them (a batch with more "
+        "takes the bound's): the bound where every expert is held",
+        layer=str(layer)).set(rows_prefix(tokens, k, held, cfg.n_experts))
 
 
 def record_assignments(layer: int, assignments: int, experts: int) -> None:

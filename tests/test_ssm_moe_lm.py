@@ -403,6 +403,240 @@ def test_undefined_tails_never_meet_a_product(monkeypatch):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
 
 
+# --- the rows that are live: a prefix of the sorted buffer --------------------
+
+@pytest.fixture()
+def small_row_tiles(monkeypatch):
+    """A row tile that lets a prefix of tiny shapes be shorter than their
+    bound."""
+    monkeypatch.setattr(gm, "TILE_M", 16)
+    monkeypatch.setattr(gm, "SUB_M", 4)
+
+
+def _share(held, n_experts, k, act, tokens=256, seed=0):
+    """A share's config, expert leaves, inputs and a uniform router's
+    choice of ``k`` distinct experts a token."""
+    cfg = dataclasses.replace(
+        NEMOTRON_TINY, n_experts=n_experts, experts_per_token=k,
+        experts_held=held, experts_held_from=n_experts // 2)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    d, f = 32, 64
+    layer = {"w_up": jax.random.normal(keys[0], (held, d, f)) * d ** -0.5,
+             "w_down": jax.random.normal(keys[1], (held, f, d)) * f ** -0.5}
+    if act == "swiglu":
+        layer["w_gate"] = jax.random.normal(keys[2], (held, d, f)) * d ** -0.5
+    h = jax.random.normal(keys[3], (tokens, d))
+    top_i = jnp.argsort(jax.random.uniform(keys[4], (tokens, n_experts)),
+                        axis=-1)[:, :k].astype(jnp.int32)
+    top_w = jax.random.uniform(keys[5], (tokens, k), minval=0.05)
+    return cfg, layer, h, top_w, top_i
+
+
+def _layer_grads(run, h, slot_w, layer):
+    return jax.value_and_grad(
+        lambda h, w, l: jnp.sum(jnp.sin(run(h, w, l))), (0, 1, 2))(
+            h, slot_w, layer)
+
+
+@pytest.mark.parametrize("held,n_experts,k", [(1, 64, 6), (4, 64, 6),
+                                              (8, 512, 22)],
+                         ids=["1_of_64", "4_of_64", "8_of_512"])
+@pytest.mark.parametrize("act", ("relu2", "swiglu"))
+def test_the_prefix_computes_what_the_bound_computes(small_row_tiles, act,
+                                                     held, n_experts, k):
+    """The same inputs over the prefix and over all of the buffer: value
+    and the gradients of the tokens, the routing weights and every expert
+    leaf."""
+    cfg, layer, h, top_w, top_i = _share(held, n_experts, k, act)
+    slot_w, slot_e, sizes, prefix = moe.this_chips_share(top_w, top_i, cfg)
+    bound = moe.rows_bound(256, k, held)
+    assert prefix % gm.TILE_M == 0 and int(sizes.sum()) <= prefix < bound
+
+    def over(rows):
+        return lambda h, w, l: moe.experts_ffn(h, w, slot_e, sizes, l,
+                                               jnp.float32, act=act,
+                                               prefix=rows)
+
+    with jax.default_matmul_precision("highest"):
+        got, got_g = _layer_grads(over(prefix), h, slot_w, layer)
+        want, want_g = _layer_grads(over(bound), h, slot_w, layer)
+    assert abs(got - want) <= 1e-6 * abs(want)
+    assert set(got_g[2]) == set(layer)
+    for a, b in zip(jax.tree_util.tree_leaves(got_g),
+                    jax.tree_util.tree_leaves(want_g)):
+        assert float(jnp.abs(b).max()) > 0.0
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+def _per_slot_oracle(h, slot_w, slot_e, layer):
+    """float32, a slot at a time, every token through every held expert."""
+    y = jnp.zeros_like(h)
+    for e in range(layer["w_up"].shape[0]):
+        out = jnp.square(jax.nn.relu(h @ layer["w_up"][e])) @ \
+            layer["w_down"][e]
+        y = y + jnp.sum(jnp.where(slot_e == e, slot_w, 0.0), axis=1,
+                        keepdims=True) * out
+    return y
+
+
+@pytest.mark.parametrize("live", (64, 65, 256),
+                         ids=["fills_the_prefix", "one_row_past_it",
+                              "fills_the_bound"])
+def test_a_batch_past_the_prefix_is_computed_whole(small_row_tiles, live):
+    """64 tokens, four held experts, a prefix of 64 rows of the 256: with
+    exactly 64 held rows the prefix holds them; with 65, and with all
+    256, the layer works on every slot and drops nothing: value and
+    gradients are the per-slot float32 oracle's, which the buffer's head
+    alone is not, and not by a matter of tolerance."""
+    _, layer, h, _, _ = _share(4, 64, 6, "relu2", tokens=64)
+    key = jax.random.PRNGKey(live)
+    held_here = jnp.zeros((256,), bool).at[
+        jax.random.permutation(key, 256)[:live]].set(True).reshape(64, 4)
+    slot_e = jnp.where(held_here, jnp.arange(4)[None, :], 4).astype(jnp.int32)
+    slot_w = jnp.where(held_here, jax.random.uniform(key, (64, 4),
+                                                     minval=0.5), 0.0)
+    sizes = jnp.sum(held_here, axis=0, dtype=jnp.int32)
+    run = lambda h, w, l: moe.experts_ffn(h, w, slot_e, sizes, l,
+                                          jnp.float32, act="relu2",
+                                          prefix=64)
+    oracle = lambda h, w, l: _per_slot_oracle(h, w, slot_e, l)
+    with jax.default_matmul_precision("highest"):
+        got, got_g = jax.jit(lambda *a: _layer_grads(run, *a))(
+            h, slot_w, layer)
+        want, want_g = _layer_grads(oracle, h, slot_w, layer)
+        head = moe._head_ffn(
+            64, "relu2", jnp.float32, h, slot_w, jnp.argsort(
+                slot_e.reshape(-1), stable=True).astype(jnp.int32), sizes,
+            layer)
+    assert abs(got - want) <= F32_REL * abs(want)
+    for a, b in zip(jax.tree_util.tree_leaves(got_g),
+                    jax.tree_util.tree_leaves(want_g)):
+        assert _rel(a, b) <= F32_REL
+    lost = _rel(head, oracle(h, slot_w, layer))
+    assert lost <= F32_REL if live == 64 else lost > 1e-3
+
+
+def test_prefix_arithmetic(hvd):
+    """Derived from the shapes: whole row tiles or the bound, never past
+    the bound, the bound where every expert is held, and no shorter for
+    holding more."""
+    from horovod_tpu import telemetry
+
+    tile = gm.TILE_M
+    assert moe.rows_prefix(8192, 22, 8, 512) == 11264 == 22 * tile
+    assert moe.rows_prefix(8192, 22, 512, 512) == 8192 * 22
+    assert moe.rows_prefix(8192, 8, 64, 64) == moe.rows_bound(8192, 8, 64)
+    for tokens, k, n_experts in ((8192, 22, 512), (8192, 8, 64),
+                                 (256, 6, 16), (4096, 1, 128), (96, 2, 8)):
+        before = 0
+        for held in range(1, n_experts + 1):
+            bound = moe.rows_bound(tokens, k, held)
+            prefix = moe.rows_prefix(tokens, k, held, n_experts)
+            assert before <= prefix <= bound
+            assert prefix == bound or prefix % tile == 0
+            assert prefix >= min(bound, moe.PREFIX_SLACK * tokens * k * held
+                                 / n_experts)
+            before = prefix
+        assert prefix == bound
+    cfg = dataclasses.replace(NEMOTRON_TINY, n_experts=512,
+                              experts_per_token=22, experts_held=8)
+    telemetry.reset_for_tests()
+    telemetry.configure(True)
+    try:
+        moe.record_held("x", 8192, cfg)
+        text = telemetry.render_prometheus()
+        assert 'hvd_moe_rows_bound{layer="x"} 65536' in text, text
+        assert 'hvd_moe_rows_prefix{layer="x"} 11264' in text, text
+    finally:
+        telemetry.reset_for_tests()
+
+
+def _eqns(jaxpr, cond_branch=None):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it (calls,
+    loop bodies, branches) but the kernels' own; ``cond_branch``: of a
+    conditional, that branch alone."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        values = ([eqn.params["branches"][cond_branch]]
+                  if eqn.primitive.name == "cond" and cond_branch is not None
+                  else eqn.params.values())
+        for value in values:
+            for inner in value if isinstance(value, (tuple, list)) else (
+                    value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner, cond_branch)
+
+
+def _as_long_as_the_bound(eqns, bound):
+    """Results with a row of numbers for every place of the buffer
+    (indices, counts and masks are not such rows)."""
+    return [v.aval for eqn in eqns for v in eqn.outvars
+            if len(v.aval.shape) == 2 and v.aval.shape[0] == bound
+            and v.aval.shape[1] > 1
+            and jnp.issubdtype(v.aval.dtype, jnp.floating)]
+
+
+@pytest.mark.parametrize("recompute", (False, True),
+                         ids=["kept", "recomputed"])
+def test_an_ordinary_batch_makes_no_pass_over_the_bound(recompute):
+    """The jaxpr of a share's layer and its gradient, on the branch an
+    ordinary batch takes: nothing is as long as the bound but indices,
+    counts and masks; the other branch is the layer of before, and the
+    search sees its rows.  Either branch runs forward twice, also where
+    the model recomputes the layer for the backward pass: nothing there
+    needs the recomputed result, so it is dropped."""
+    cfg, layer, u = _expert_layer(dataclasses.replace(
+        NEMOTRON_TINY, n_experts=512, experts_per_token=22, experts_held=8,
+        experts_held_from=8))
+    u = jnp.tile(u, (32, 1))
+    tokens, bound = u.shape[0], moe.rows_bound(u.shape[0], 22, 8)
+    assert moe.rows_prefix(tokens, 22, 8, 512) == 6 * gm.TILE_M < bound
+    block = lambda layer, u: u + moe.latent_moe_ffn(u, layer, cfg)[0]
+    if recompute:
+        block = jax.checkpoint(block)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda layer, u: jnp.sum(jnp.sin(
+        block(layer, u))), (0, 1)))(layer, u).jaxpr
+    # lax.cond's branches: (false, true) = (every slot, the head).
+    ordinary, overflow = list(_eqns(jaxpr, 1)), list(_eqns(jaxpr, 0))
+    # Forward and backward, each choosing for itself.
+    assert [e.primitive.name for e in ordinary].count("cond") == 2
+    assert not _as_long_as_the_bound(ordinary, bound)
+    assert _as_long_as_the_bound(overflow, bound)
+    for taken in (ordinary, overflow):
+        # 2 products forward; the backward computes them again and their
+        # 2 + 2 gradients.
+        assert [e.primitive.name for e in taken].count(
+            "pallas_call") == 2 + 2 + 2 * 2
+
+
+@pytest.mark.parametrize("layer_of", ("softmax_swiglu", "latent_relu2"))
+def test_every_expert_held_is_one_pass(layer_of):
+    """Where the chip holds every expert the prefix is the bound: one
+    pass over it, no loop and no conditional, forward or backward."""
+    if layer_of == "softmax_swiglu":
+        cfg = OLMOE_TINY
+        layer = tfm.init_params(jax.random.PRNGKey(0), cfg)["layers"][0]
+        run = lambda layer, h: moe.moe_ffn(h, layer, cfg)[0]
+    else:
+        cfg, layer, _ = _expert_layer(dataclasses.replace(
+            NEMOTRON_TINY, experts_held=0, experts_held_from=0))
+        run = lambda layer, h: moe.latent_moe_ffn(h, layer, cfg)[0]
+    h = jax.random.normal(jax.random.PRNGKey(1), (64, cfg.d_model))
+    assert cfg.held_experts == cfg.n_experts
+    jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(run(*a)), (0, 1)))(
+        layer, h).jaxpr
+    eqns = list(_eqns(jaxpr))
+    names = {e.primitive.name for e in eqns}
+    assert "pallas_call" in names and not names & {"cond", "while"}
+    # Rows move by gathers, both ways.
+    assert not [e for e in eqns if e.primitive.name == "scatter-add"
+                and ("moe_dispatch" in str(e.source_info.name_stack)
+                     or "moe_combine" in str(e.source_info.name_stack))]
+
+
 def test_sigmoid_router_weighs_over_all_chosen_and_bias_only_chooses():
     h = jax.random.normal(jax.random.key(0), (32, 16))
     w = jax.random.normal(jax.random.key(1), (16, 8))
@@ -518,6 +752,33 @@ def test_train_step_takes_the_gradient_of_the_global_batch(hvd, devices):
             # (after - before) / -lr loses three digits to the
             # subtraction.
             assert _rel((after[name] - before[name]) / -lr, g) <= 3e-3, name
+
+
+def test_train_step_over_the_prefix_takes_the_same_gradient(hvd):
+    """A share small enough for the prefix to be shorter than the bound
+    (4 of 64 experts, 512 tokens: 1,024 rows of 2,048), through
+    ``make_train_step`` under ``remat="full"``: the loss and every
+    gradient the reference returns, and every expert layer's batch on the
+    prefix (the reference's routing)."""
+    from horovod_tpu.topology import build_mesh
+
+    cfg = dataclasses.replace(NEMOTRON_TINY, n_experts=64)
+    prefix = moe.rows_prefix(4 * 128, cfg.experts_per_token, 4, 64)
+    assert prefix == 1024 < moe.rows_bound(4 * 128, cfg.experts_per_token, 4)
+    mesh = build_mesh(axes=("data",), devices=jax.devices()[:1])
+    optimizer = optax.sgd(0.1, momentum=0.9)
+    step, _, _ = tfm.make_train_step(cfg, optimizer, mesh, attention="local",
+                                     donate=False, remat="full")
+    params = _params(cfg)
+    tokens, labels = _batch(cfg, batch=4)
+    _, opt_state, loss = step(params, optimizer.init(params), tokens, labels)
+    want, want_g, stats = jax.jit(lambda *a: _reference(cfg, *a))(
+        params, tokens, labels)
+    assert 0 < int(stats["rows"].sum(axis=1).max()) <= prefix
+    assert abs(loss - want) <= F32_REL * abs(want)
+    momentum = _checked(opt_state[0].trace)
+    for name, g in want_g.items():
+        assert _rel(momentum[name], g) <= F32_REL, name
 
 
 # --- refusals: never a silent fall back -------------------------------------
@@ -656,6 +917,9 @@ def test_trace_time_series_count_what_was_traced(hvd, widths, path):
         for layer in ("1", "3", "5", "8", "10", "mtp_1"):
             assert f'hvd_moe_experts_held{{layer="{layer}"}} 4' in text, text
             assert (f'hvd_moe_rows_bound{{layer="{layer}"}} '
+                    f'{256 * 4}') in text, text
+            # 4 x the 384 rows expected, in whole tiles, is past the bound.
+            assert (f'hvd_moe_rows_prefix{{layer="{layer}"}} '
                     f'{256 * 4}') in text, text
         assert 'hvd_ssm_chunks_total{layer="1"' not in text
         other = {"xla": "kernel", "kernel": "xla"}[path]
