@@ -57,6 +57,11 @@ but does not weigh, the chosen scores renormalised and scaled
 latent width between two dense projections; a shared expert on the hidden
 state, added for every token.
 
+**SwiGLU experts under the sigmoid router** (:func:`sigmoid_moe_ffn`;
+DeepSeek-V3's layer as GLM-4.7-Flash runs it): the same router and the
+same shared expert beside SwiGLU experts on the hidden state itself, no
+latent width between.
+
 Not here: the exchange of rows between chips that hold different experts
 (ROADMAP R2).  The one-expert-per-chip, capacity-dropping ``all_to_all``
 demo is :mod:`horovod_tpu.parallel.expert`.
@@ -532,6 +537,29 @@ def latent_moe_ffn(u, layer, cfg):
         hidden = jnp.square(jax.nn.relu(
             (flat @ layer["w_shared_up"].astype(dt)).astype(jnp.float32)))
         shared = hidden.astype(dt) @ layer["w_shared_down"].astype(dt)
+    return (routed + shared).reshape(u.shape), rows
+
+
+def sigmoid_moe_ffn(u, layer, cfg):
+    """SwiGLU experts under the sigmoid router, with a shared expert, on
+    ``u`` [..., d]: ``(routed + shared [..., d], rows per held expert)``.
+    ``routed = sum over the chosen experts held here of w_k expert_k(u)``
+    with :func:`route_sigmoid`'s weights, ``shared = W_sd (silu(W_sg u) *
+    W_su u)``, added for every token.  ``cfg`` is the model's
+    ``TransformerConfig``."""
+    dt = cfg.dtype
+    flat = u.reshape(-1, u.shape[-1])
+    with jax.named_scope(scopes.MOE_ROUTER):
+        top_w, top_i = route_sigmoid(
+            flat, layer["router"], layer["router_bias"],
+            cfg.experts_per_token, cfg.routed_scale)
+        slot_w, slot_e, rows, prefix = this_chips_share(top_w, top_i, cfg)
+    routed = experts_ffn(flat, slot_w, slot_e, rows, layer, dt,
+                         act="swiglu", prefix=prefix)
+    with jax.named_scope(scopes.MOE_SHARED):
+        hidden = (jax.nn.silu(flat @ layer["w_shared_gate"].astype(dt))
+                  * (flat @ layer["w_shared_up"].astype(dt)))
+        shared = hidden @ layer["w_shared_down"].astype(dt)
     return (routed + shared).reshape(u.shape), rows
 
 

@@ -34,7 +34,15 @@ grouped-query attention; ``mlp="relu2"`` with experts is the latent
 mixture of experts with a shared expert (:func:`moe.latent_moe_ffn`), of
 which this chip may hold a share (``experts_held``); ``mtp_layer_types``
 adds a multi-token-prediction module and its loss.  Nemotron-3 is
-``MEMEMEM*EME`` of those three, repeated.
+``MEMEMEM*EME`` of those three, repeated.  ``kv_latent_rank`` makes the
+softmax attention **latent** (DeepSeek-V2's MLA, in the up-projected form
+training runs: :func:`_latent_qkv`), with heads of ``head_width`` that
+need not be ``d_model / n_heads`` and a rotary part of ``rope_dim``;
+``dense_layers`` leading layers keep a dense MLP of ``d_ff`` before the
+expert layers; ``mlp="swiglu"`` with ``d_shared`` is SwiGLU experts under
+the sigmoid router beside a shared SwiGLU expert
+(:func:`moe.sigmoid_moe_ffn`).  GLM-4.7-Flash is those three together,
+with a prediction module of one such layer.
 """
 
 from __future__ import annotations
@@ -96,12 +104,25 @@ class TransformerConfig:
     # Key-value heads that ``n_heads`` query heads share, ``n_heads /
     # n_kv_heads`` each (grouped-query attention); 0: one each.
     n_kv_heads: int = 0
+    # Latent attention (MLA), all four together: heads of ``head_width``
+    # (0: d_model / n_heads, the plain block's) whose last ``rope_dim``
+    # query dims are rotary; the queries come up from a latent of
+    # ``q_latent_rank`` and each head's rotary-free key dims and its
+    # values (``head_width`` wide too) from one of ``kv_latent_rank``,
+    # each latent RMS-normed; the rotary key of ``rope_dim`` is one head,
+    # shared by all.  The out projection takes n_heads * head_width.
+    head_width: int = 0
+    q_latent_rank: int = 0
+    kv_latent_rank: int = 0
+    rope_dim: int = 0
     # "gelu": w2 gelu(w1 h); "swiglu": w_down (silu(w_gate h) * (w_up h));
     # "relu2" (with experts only): the latent mixture below.
     mlp: str = "gelu"
     # n_experts > 0: the MLP is ``n_experts`` SwiGLU experts of width
     # ``d_expert`` with softmax-then-top-``experts_per_token`` routing
-    # that drops nothing (models/moe.py); d_ff is then unused.
+    # that drops nothing (models/moe.py); d_ff is then the width of the
+    # dense MLP that the first ``dense_layers`` layers keep instead (the
+    # config's ``mlp`` form: SwiGLU; 0: every layer's is the experts').
     # ``experts_held`` (0: all) of them, from ``experts_held_from`` on,
     # are held by this chip and computed here; the rest are another
     # chip's, and left out (the router still scores and ranks them all).
@@ -111,12 +132,15 @@ class TransformerConfig:
     norm_topk_prob: bool = False
     experts_held: int = 0
     experts_held_from: int = 0
+    dense_layers: int = 0
     # mlp="relu2": the router scores all ``n_experts`` by sigmoid (+ a
     # selection bias), its top-k weights are renormalised and scaled by
     # ``routed_scale``; an expert is w_down relu(w_up l)^2 on ``l``, the
     # token in a latent width ``d_latent`` between two dense projections;
     # a shared expert of width ``d_shared`` on the hidden state is added
-    # for every token.
+    # for every token.  mlp="swiglu" with ``d_shared``: the same router
+    # and ``routed_scale`` over SwiGLU experts on the hidden state itself
+    # (no ``d_latent``), the shared expert SwiGLU too.
     d_latent: int = 0
     d_shared: int = 0
     routed_scale: float = 1.0
@@ -205,6 +229,37 @@ class TransformerConfig:
         if self.mlp not in ("gelu", "swiglu", "relu2"):
             raise ValueError(f"mlp={self.mlp!r}: expected 'gelu', "
                              f"'swiglu' or 'relu2'")
+        latent = (self.head_width, self.q_latent_rank,
+                  self.kv_latent_rank, self.rope_dim)
+        if any(latent):
+            if (self.q_latent_rank or self.kv_latent_rank) \
+                    and not self.head_width:
+                raise ValueError(
+                    f"q_latent_rank={self.q_latent_rank}, kv_latent_rank="
+                    f"{self.kv_latent_rank}: latent attention needs "
+                    f"head_width, the width its up-projections give a "
+                    f"head")
+            if min(latent) <= 0:
+                raise ValueError(
+                    "head_width, q_latent_rank, kv_latent_rank and "
+                    "rope_dim come together: they are latent attention "
+                    "(a head width alone is not implemented)")
+            if self.rope_dim > self.head_width:
+                raise ValueError(
+                    f"rope_dim={self.rope_dim} is wider than head_width="
+                    f"{self.head_width}: the rotary part is the tail of a "
+                    f"head")
+            if self.positions != "rope" or self.rope_dim % 2:
+                raise ValueError(
+                    f"latent attention carries position in its rotary "
+                    f"part: it needs positions='rope' and an even "
+                    f"rope_dim, got {self.positions!r} and "
+                    f"{self.rope_dim}")
+            if self.qk_norm or self.n_kv_heads:
+                raise NotImplementedError(
+                    "latent attention norms its latents and gives every "
+                    "head its own key: qk_norm and n_kv_heads are not "
+                    "implemented with it")
         if self.positions == "rope" and self.head_dim % 2:
             raise ValueError(f"positions='rope' needs an even head_dim, "
                              f"got {self.head_dim}")
@@ -218,9 +273,24 @@ class TransformerConfig:
                 raise NotImplementedError(
                     "mlp='relu2': the sigmoid router has no auxiliary "
                     "loss (its balance is the selection bias's)")
-        elif self.d_latent or self.d_shared or self.routed_scale != 1.0:
-            raise ValueError("d_latent, d_shared and routed_scale mean "
-                             "nothing without mlp='relu2'")
+        elif self.d_latent:
+            raise ValueError("d_latent means nothing without mlp='relu2'")
+        elif self.d_shared:
+            if self.mlp != "swiglu" or not self.n_experts:
+                raise ValueError(
+                    "d_shared is the shared expert beside routed experts: "
+                    "it needs n_experts and mlp='swiglu' (or 'relu2', the "
+                    "latent mixture)")
+            if (self.router_aux_coef or self.router_z_coef
+                    or self.norm_topk_prob):
+                raise NotImplementedError(
+                    "d_shared with mlp='swiglu' is the sigmoid router: it "
+                    "has no auxiliary loss (its balance is the selection "
+                    "bias's) and always renormalises its top-k "
+                    "(norm_topk_prob is the softmax router's)")
+        elif self.routed_scale != 1.0:
+            raise ValueError("routed_scale scales the sigmoid router's "
+                             "weights: it means nothing without d_shared")
         if self.n_experts:
             if self.mlp == "gelu":
                 raise ValueError("n_experts > 0: the experts are SwiGLU "
@@ -240,16 +310,36 @@ class TransformerConfig:
                     f"experts_held={self.experts_held} from "
                     f"{self.experts_held_from} is not a range of the "
                     f"n_experts={self.n_experts}")
+            if not 0 <= self.dense_layers <= self.n_layers:
+                raise ValueError(
+                    f"dense_layers={self.dense_layers} must lie in "
+                    f"0..n_layers={self.n_layers}")
+            if self.dense_layers and self.mlp != "swiglu":
+                raise NotImplementedError(
+                    f"dense_layers={self.dense_layers} with mlp="
+                    f"{self.mlp!r}: the leading dense MLP is SwiGLU")
         elif (self.experts_per_token or self.d_expert or self.norm_topk_prob
               or self.router_aux_coef or self.router_z_coef
-              or self.experts_held or self.experts_held_from):
+              or self.experts_held or self.experts_held_from
+              or self.dense_layers):
             raise ValueError("experts_per_token, d_expert, norm_topk_prob, "
-                             "experts_held* and the router loss "
-                             "coefficients mean nothing without n_experts")
+                             "experts_held*, dense_layers and the router "
+                             "loss coefficients mean nothing without "
+                             "n_experts")
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_width or self.d_model // self.n_heads
+
+    @property
+    def latent_attention(self) -> bool:
+        return self.kv_latent_rank > 0
+
+    @property
+    def sigmoid_router(self) -> bool:
+        """SwiGLU experts under the sigmoid router, with a shared expert
+        (the latent mixture, ``mlp="relu2"``, has the same router)."""
+        return self.mlp == "swiglu" and self.d_shared > 0
 
     @property
     def kv_heads(self) -> int:
@@ -321,9 +411,10 @@ def init_params(rng, cfg: TransformerConfig):
         return dense(key, (cfg.held_experts,) + shape,
                      scale=shape[0] ** -0.5)
 
-    def one_layer(key, kind):
+    def one_layer(key, kind, dense_mlp=False):
         """A layer's leaves: a norm's scale and the weights of each part
-        it holds."""
+        it holds (``dense_mlp``: a dense MLP where the config has
+        experts)."""
         k = jax.random.split(key, 6)
         k_up, k_router = jax.random.split(jax.random.fold_in(k[4], 1))
         layer = {}
@@ -335,6 +426,20 @@ def init_params(rng, cfg: TransformerConfig):
             layer.update(linear_attention.init_layer(k[0], cfg, dense))
         elif _MIXER[kind] == MAMBA2:
             layer.update(mamba2.init_layer(k[0], cfg, dense))
+        elif _MIXER[kind] == FULL_ATTENTION and cfg.latent_attention:
+            wide = cfg.n_heads * cfg.head_dim
+            r_q, r_kv = cfg.q_latent_rank, cfg.kv_latent_rank
+            layer.update(
+                w_qa=dense(k[0], (d, r_q)),
+                q_latent_norm_scale=jnp.ones((r_q,), jnp.float32),
+                w_qb=dense(k[1], (r_q, wide)),
+                # To [c_kv | k_r], the latent and the one rotary key.
+                w_kva=dense(k[2], (d, r_kv + cfg.rope_dim)),
+                kv_latent_norm_scale=jnp.ones((r_kv,), jnp.float32),
+                # To [k_n | v] of each head in turn.
+                w_kvb=dense(jax.random.fold_in(k[2], 1),
+                            (r_kv, 2 * wide - cfg.n_heads * cfg.rope_dim)),
+                wo=dense(k[3], (wide, d)))
         elif _MIXER[kind] == FULL_ATTENTION:
             layer.update(wq=dense(k[0], (d, d)), wk=dense(k[1], (d, d_kv)),
                          wv=dense(k[2], (d, d_kv)), wo=dense(k[3], (d, d)))
@@ -343,7 +448,11 @@ def init_params(rng, cfg: TransformerConfig):
                 layer["k_norm_scale"] = jnp.ones((d_kv,), jnp.float32)
         if not _HAS_MLP[kind]:
             return layer
-        if cfg.mlp == "relu2":
+        if dense_mlp:
+            layer.update(w_gate=dense(k[4], (d, f)),
+                         w_up=dense(k_up, (d, f)),
+                         w_down=dense(k[5], (f, d)))
+        elif cfg.mlp == "relu2":
             e, lat = cfg.d_expert, cfg.d_latent
             k_lat, k_shared = jax.random.split(jax.random.fold_in(k[5], 1))
             layer.update(
@@ -363,6 +472,14 @@ def init_params(rng, cfg: TransformerConfig):
                          w_gate=experts(k[4], (d, e)),
                          w_up=experts(k_up, (d, e)),
                          w_down=experts(k[5], (e, d)))
+            if cfg.sigmoid_router:
+                k_shared = jax.random.split(jax.random.fold_in(k[5], 1), 3)
+                layer.update(
+                    # Chooses and is not trained: its gradient is zero.
+                    router_bias=jnp.zeros((cfg.n_experts,), jnp.float32),
+                    w_shared_gate=dense(k_shared[0], (d, cfg.d_shared)),
+                    w_shared_up=dense(k_shared[1], (d, cfg.d_shared)),
+                    w_shared_down=dense(k_shared[2], (cfg.d_shared, d)))
         elif cfg.mlp == "swiglu":
             layer.update(w_gate=dense(k[4], (d, f)),
                          w_up=dense(k_up, (d, f)),
@@ -374,7 +491,8 @@ def init_params(rng, cfg: TransformerConfig):
     params = {
         "embed": dense(keys[0], (v, d), scale=0.02),
         "ln_f_scale": jnp.ones((d,), jnp.float32),
-        "layers": [one_layer(keys[2 + i], cfg.layer_type(i))
+        "layers": [one_layer(keys[2 + i], cfg.layer_type(i),
+                             i < cfg.dense_layers)
                    for i in range(cfg.n_layers)],
     }
     if cfg.mtp_layer_types:
@@ -405,6 +523,11 @@ def param_specs(cfg: TransformerConfig, model_axis: Optional[str]):
     attention = {"wq": col, "wk": col, "wv": col, "wo": row}
     if cfg.qk_norm:
         attention.update(q_norm_scale=P(), k_norm_scale=P())
+    if cfg.latent_attention:
+        # Whole on every chip (heads over the model axis: ROADMAP R16).
+        attention = {name: P() for name in (
+            "w_qa", "q_latent_norm_scale", "w_qb", "w_kva",
+            "kv_latent_norm_scale", "w_kvb", "wo")}
     mixers = {FULL_ATTENTION: dict(attention, ln1_scale=P()),
               LINEAR_ATTENTION: dict(linear_attention.layer_specs(),
                                      ln1_scale=P()),
@@ -421,18 +544,26 @@ def param_specs(cfg: TransformerConfig, model_axis: Optional[str]):
         # Every held expert on every chip of the mesh (experts over an
         # axis: ROADMAP R2).
         mlp.update(router=P(), w_gate=P(), w_up=P(), w_down=P())
+        if cfg.sigmoid_router:
+            mlp.update({name: P() for name in (
+                "router_bias", "w_shared_gate", "w_shared_up",
+                "w_shared_down")})
     elif cfg.mlp == "swiglu":
         mlp.update(w_gate=col, w_up=col, w_down=row)
     else:
         mlp.update(w1=col, w2=row)
 
-    def one_layer(kind):
-        return dict(mlp if _HAS_MLP[kind] else {}, **mixers[_MIXER[kind]])
+    # A leading dense MLP in a model with experts: whole, like them.
+    dense_mlp = {"ln2_scale": P(), "w_gate": P(), "w_up": P(), "w_down": P()}
+
+    def one_layer(kind, dense=False):
+        return dict((dense_mlp if dense else mlp) if _HAS_MLP[kind] else {},
+                    **mixers[_MIXER[kind]])
 
     specs = {
         "embed": P(),
         "ln_f_scale": P(),
-        "layers": [one_layer(cfg.layer_type(i))
+        "layers": [one_layer(cfg.layer_type(i), i < cfg.dense_layers)
                    for i in range(cfg.n_layers)],
     }
     if cfg.mtp_layer_types:
@@ -483,8 +614,17 @@ def _moe_block(x, layer, cfg):
     h = _rmsnorm(x, layer["ln2_scale"], cfg.norm_eps)
     if cfg.mlp == "relu2":
         return x + moe.latent_moe_ffn(h, layer, cfg)[0], None
+    if cfg.sigmoid_router:
+        return x + moe.sigmoid_moe_ffn(h, layer, cfg)[0], None
     y, stats = moe.moe_ffn(h, layer, cfg)
     return x + y, stats
+
+
+def _holds_experts(layer) -> bool:
+    """Whether ``layer``'s feed-forward part is the config's experts: in
+    a model with ``dense_layers`` the leading layers hold a dense MLP
+    instead, and the tree says which (:func:`init_params`)."""
+    return "router" in layer
 
 
 def _rotary(x, positions, theta: float):
@@ -510,6 +650,8 @@ def _qkv_proj(x, layer, cfg, model_axis, positions=None):
     k, v with a trailing [heads, head_dim] split."""
     dt = cfg.dtype
     h = _rmsnorm(x, layer["ln1_scale"], cfg.norm_eps)
+    if cfg.latent_attention:
+        return _latent_qkv(h, layer, cfg, positions)
     hi = tp.region_input(h, model_axis) if model_axis else h
     q = hi @ layer["wq"].astype(dt)
     k = hi @ layer["wk"].astype(dt)
@@ -528,6 +670,40 @@ def _qkv_proj(x, layer, cfg, model_axis, positions=None):
         q = _rotary(q, positions, cfg.rope_theta)
         k = _rotary(k, positions, cfg.rope_theta)
     return q, k, v, dh
+
+
+def _latent_qkv(h, layer, cfg, positions):
+    """Latent attention's q, k, v from the normed input ``h`` [..., T, d],
+    in the up-projected form (K and V materialised per head; the absorbed
+    form and a cache of latents are decode's: ROADMAP R13): ``c_q =
+    RMSNorm(h W_qa)``, ``q = c_q W_qb`` as heads of ``[q_n | q_r]``;
+    ``[c_kv | k_r] = h W_kva``, ``c_kv <- RMSNorm(c_kv)``, ``[k_n | v] =
+    c_kv W_kvb`` per head; ``q_r`` and ``k_r`` rotary at ``positions``,
+    ``k_r`` **one head that every head's key ends in** (so its gradient
+    sums over the heads); ``k = [k_n | k_r]``.  Returns q, k, v [..., T,
+    heads, head_width] and ``heads * head_width``."""
+    dt, heads, hd, rope = cfg.dtype, cfg.n_heads, cfg.head_dim, cfg.rope_dim
+    nope, rank = hd - rope, cfg.kv_latent_rank
+    with jax.named_scope(scopes.MLA_Q):
+        c_q = _rmsnorm(h @ layer["w_qa"].astype(dt),
+                       layer["q_latent_norm_scale"], cfg.norm_eps)
+        q = (c_q @ layer["w_qb"].astype(dt)).reshape(
+            h.shape[:-1] + (heads, hd))
+    with jax.named_scope(scopes.MLA_KV):
+        down = h @ layer["w_kva"].astype(dt)
+        c_kv = _rmsnorm(down[..., :rank], layer["kv_latent_norm_scale"],
+                        cfg.norm_eps)
+        up = (c_kv @ layer["w_kvb"].astype(dt)).reshape(
+            h.shape[:-1] + (heads, nope + hd))
+        k_n, v = up[..., :nope], up[..., nope:]
+    with jax.named_scope(scopes.MLA_ROPE):
+        q = jnp.concatenate(
+            [q[..., :nope], _rotary(q[..., nope:], positions,
+                                    cfg.rope_theta)], axis=-1)
+        k_r = _rotary(down[..., None, rank:], positions, cfg.rope_theta)
+        k = jnp.concatenate(
+            [k_n, jnp.broadcast_to(k_r, k_n.shape[:-1] + (rope,))], axis=-1)
+    return q, k, v, heads * hd
 
 
 def _share_kv_heads(k, v, n_heads: int):
@@ -588,6 +764,15 @@ def _logits_head(x, params, cfg):
     return (x @ w.astype(dt)).astype(jnp.float32)
 
 
+# Latent attention's fields, for the paths that refuse it by name: the
+# tensor axis (heads of a latent's up-projection over chips), the
+# sequence axis (the shared rotary key under the ring and Ulysses
+# routes), decode_step (a cache of latents, the absorbed form) and the
+# pipelined builder.
+_LATENT_FIELDS = ("head_width", "q_latent_rank", "kv_latent_rank",
+                  "rope_dim")
+
+
 def _refuse_under_model_axis(cfg, model_axis) -> None:
     # QK-norm's statistics span the whole projection, which the model
     # axis splits; the experts live whole on every chip (ROADMAP R2), and
@@ -595,7 +780,12 @@ def _refuse_under_model_axis(cfg, model_axis) -> None:
     if model_axis:
         _refuse(cfg, f"model_axis={model_axis!r}",
                 ("qk_norm", "n_experts", "layer_types", "n_kv_heads",
-                 "mtp_layer_types"))
+                 "mtp_layer_types") + _LATENT_FIELDS)
+
+
+def _refuse_under_seq_axis(cfg, seq_axis) -> None:
+    if seq_axis:
+        _refuse(cfg, f"seq_axis={seq_axis!r}", _LATENT_FIELDS)
 
 
 def _remat_wrap(body, remat: str):
@@ -669,6 +859,7 @@ def _hidden_states(params, tokens, cfg: TransformerConfig, model_axis,
     types, label)``), for the multi-token-prediction module to run its
     own layers by."""
     _refuse_under_model_axis(cfg, model_axis)
+    _refuse_under_seq_axis(cfg, seq_axis)
     _refuse_with_recurrent_layers(cfg, seq_axis=seq_axis,
                                   segment_ids=segment_ids)
     dt = cfg.dtype
@@ -733,8 +924,12 @@ def _hidden_states(params, tokens, cfg: TransformerConfig, model_axis,
 
     def mlp_part(x, layer):
         with jax.named_scope(scopes.MLP):
-            if cfg.n_experts:
+            if _holds_experts(layer):
                 return _moe_block(x, layer, cfg)
+            if cfg.n_experts:
+                # A leading dense layer of a model with experts.
+                with jax.named_scope(scopes.MLP_DENSE):
+                    return _mlp_block(x, layer, cfg, model_axis), None
             return _mlp_block(x, layer, cfg, model_axis), None
 
     def linear_attention_part(x, layer, segment_ids):
@@ -780,7 +975,7 @@ def _hidden_states(params, tokens, cfg: TransformerConfig, model_axis,
                 linear_attention.record_blocks(name, x, cfg)
             if mixer == MAMBA2:
                 mamba2.record_chunks(name, x, cfg)
-            if cfg.n_experts and _HAS_MLP[kind]:
+            if _HAS_MLP[kind] and _holds_experts(layer):
                 moe.record_held(name, tokens.size, cfg)
                 moe.record_weight_copies(name, layer)
                 if stats is not None:
@@ -907,6 +1102,7 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
     from horovod_tpu.ops.fusion import fused_pytree_mean
 
     _refuse_under_model_axis(cfg, model_axis)
+    _refuse_under_seq_axis(cfg, seq_axis)
     _refuse_with_recurrent_layers(cfg, seq_axis=seq_axis, packed=packed)
     specs = param_specs(cfg, model_axis)
     grad_axes = tuple(a for a in (data_axis, seq_axis) if a)
@@ -1068,8 +1264,9 @@ def decode_step(params, token, cache, pos, cfg: TransformerConfig,
     # A rotated key cache, an expert layer per token and a recurrent
     # layer's state and convolution window beside the key cache are not
     # written (serving: ROADMAP R8/R13).
-    _refuse(cfg, "decode_step", ("positions", "n_experts", "layer_types",
-                                 "n_kv_heads", "mtp_layer_types"))
+    _refuse(cfg, "decode_step", _LATENT_FIELDS + (
+        "positions", "n_experts", "layer_types", "n_kv_heads",
+        "mtp_layer_types"))
     dt = cfg.dtype
     hd = cfg.head_dim
     x = (params["embed"][token] +
@@ -1333,9 +1530,9 @@ def make_train_step_pipelined(cfg: TransformerConfig, optimizer, mesh,
 
     # The pipelined forward embeds with the position table, scans stacked
     # dense layers of one type and returns no router sums.
-    _refuse(cfg, "make_train_step_pipelined",
-            ("positions", "qk_norm", "tie_embeddings", "mlp", "n_experts",
-             "layer_types", "n_kv_heads", "mtp_layer_types"))
+    _refuse(cfg, "make_train_step_pipelined", _LATENT_FIELDS + (
+        "positions", "qk_norm", "tie_embeddings", "mlp", "n_experts",
+        "layer_types", "n_kv_heads", "mtp_layer_types"))
     n_stages = mesh.shape[pipe_axis]
     v_eff = (virtual if schedule in ("interleaved", "interleaved_1f1b")
              else 1)

@@ -662,14 +662,16 @@ def flash_attention(q, k, v, causal: bool = True,
     accumulation tolerance, forward and backward.
 
     ``block_q``/``block_k`` default to AUTO: the largest power of two
-    ≤ 1024 dividing ``T`` (≤ 512 when ``D > 128`` — the sweeps only
-    covered head dims ≤ 128, and bigger heads roughly double the bwd
-    kernel's VMEM pressure).  Swept on a v5e (docs/kernels.md, PR 25):
+    ≤ 1024 dividing ``T`` (≤ 512 when ``D > 256``, which no sweep has
+    covered, and when ``D > 128`` with ``segment_ids``, where the dK+dV
+    kernel at 1024² is refused for VMEM).  Swept on a v5e (docs/kernels.md): at ``D = 128`` (PR 25)
     1024 blocks run the three kernels 1.40× faster than 512 blocks at
     T=8192 and 1.32× at T=2048, and faster than every mixed shape tried
     (bigger tiles amortize the grid/DMA overhead and the per-row work;
     1024×1024 f32 scores ≈ 4 MB of the ~16 MB VMEM; 2048 on either side
-    is refused for VMEM).
+    is refused for VMEM); at ``D = 256`` (PR 37, B·H=20, T=8192) all nine
+    shapes of {256, 512, 1024}² compile inside the default scoped VMEM
+    and 1024² is again the fastest, 1.13× over 512².
 
     ``segment_ids`` ([B, T] int32) enables sequence packing: tokens
     attend only within their own segment (composes with ``causal``) —
@@ -681,7 +683,8 @@ def flash_attention(q, k, v, causal: bool = True,
     return out
 
 
-def _auto_block(t: int, head_dim: Optional[int] = None) -> int:
+def _auto_block(t: int, head_dim: Optional[int] = None,
+                segments: bool = False) -> int:
     if t < 128:
         # Short sequences (interpret mode / tests): old clamp behavior.
         for b in (64, 32, 16, 8):
@@ -695,13 +698,20 @@ def _auto_block(t: int, head_dim: Optional[int] = None) -> int:
     # annoying — same contract as the old fixed-128 default.
     # 1024 preferred over 512: measured 1.32x at T=2048 (B4 H32 D128)
     # and 1.40x at T=8192 (B1 H32) over the three kernels
-    # (docs/kernels.md, PR 25); 1024x1024 f32 scores = 4 MB of VMEM.  The
-    # 1024 preference was swept at head_dim<=128 only; larger head dims
-    # roughly double the dkv kernel's operand + f32 score/p VMEM
-    # pressure, so cap the auto choice at 512 there (explicit
+    # (docs/kernels.md, PR 25); 1024x1024 f32 scores = 4 MB of VMEM.  At
+    # head_dim 256 the operand tiles and the accumulators double (the
+    # dK+dV kernel's: 5 MB of tiles double-buffered and 2 MB of
+    # scratch, beside the scores' 4 MB) and every shape of {256, 512,
+    # 1024}^2 still compiles in the default scoped VMEM; swept on the chip
+    # at B*H=20, T=8192, 1024^2 is the fastest there too: 19.90 ms for
+    # the three kernels against 22.55 at 512^2 (docs/kernels.md, PR 37).
+    # With segment ids the dK+dV kernel at head_dim 256 and 1024^2 is
+    # refused for VMEM (tests/test_flash_compile.py), and wider heads than
+    # 256 have not been swept: 512 at most in both cases (explicit
     # block_q/block_k still override).
-    prefs = (512, 256, 128) if (head_dim or 0) > 128 else (1024, 512,
-                                                           256, 128)
+    wide = (head_dim or 0) > 128
+    capped = (head_dim or 0) > 256 or (wide and segments)
+    prefs = (512, 256, 128) if capped else (1024, 512, 256, 128)
     for b in prefs:
         if t % b == 0:
             return b
@@ -710,14 +720,16 @@ def _auto_block(t: int, head_dim: Optional[int] = None) -> int:
         f"sizing (pad the sequence, or pass explicit block_q/block_k)")
 
 
-def _eff_blocks(t, block_q, block_k, head_dim=None):
+def _eff_blocks(t, block_q, block_k, head_dim=None, segments=False):
     # None = auto (largest power of two <= 1024 dividing T — capped at
-    # 512 when head_dim > 128, see _auto_block — measured fastest);
-    # explicit blocks are clamped to T so e.g. T=64 works with block
-    # 128 (divisibility still enforced after clamping).
-    bq = _auto_block(t, head_dim) if block_q is None else min(block_q, t)
-    bk = _auto_block(t, head_dim) if block_k is None else min(block_k, t)
-    return bq, bk
+    # 512 when head_dim > 256, or > 128 with segment ids, see _auto_block
+    # — measured fastest); explicit blocks are clamped to T so e.g. T=64
+    # works with block 128 (divisibility still enforced after clamping).
+    def pick(block):
+        return (_auto_block(t, head_dim, segments) if block is None
+                else min(block, t))
+
+    return pick(block_q), pick(block_k)
 
 
 @jax.named_scope(scopes.ATTN_FLASH)
@@ -726,7 +738,8 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
     d = q.shape[-1]
     scale_ = (d ** -0.5) if scale is None else scale
     interp = _interpret_default(q) if interpret is None else interpret
-    bq, bk = _eff_blocks(q.shape[1], block_q, block_k, d)
+    bq, bk = _eff_blocks(q.shape[1], block_q, block_k, d,
+                         segment_ids is not None)
     return _fwd(q, k, v, segment_ids, causal, scale_, bq, bk, interp)
 
 
@@ -735,7 +748,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, do):
     t, d = res[0].shape[1], res[0].shape[-1]
     scale_ = (d ** -0.5) if scale is None else scale
     interp = _interpret_default(res[0]) if interpret is None else interpret
-    bq, bk = _eff_blocks(t, block_q, block_k, d)
+    bq, bk = _eff_blocks(t, block_q, block_k, d, res[6] is not None)
     return _bwd(causal, scale_, bq, bk, interp, res, do)
 
 

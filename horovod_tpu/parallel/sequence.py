@@ -264,7 +264,8 @@ def _ring_flash_fwd(q, k, v, axis_name, causal, scale, interpret,
 
     size = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
-    bq, bk = fa._eff_blocks(q.shape[1], None, None, q.shape[-1])
+    bq, bk = fa._eff_blocks(q.shape[1], None, None, q.shape[-1],
+                            segment_ids is not None)
     b, t, h, d = fa._check_shapes(q, k, v, bq, bk)
     scale_ = (d ** -0.5) if scale is None else scale
     interp = _interp_default_for(q) if interpret is None else interpret
@@ -349,7 +350,7 @@ def _ring_flash_bwd(axis_name, causal, scale, interpret, res, do):
     bh, t, d = qf.shape
     scale_ = (d ** -0.5) if scale is None else scale
     interp = _interp_default_for(qf) if interpret is None else interpret
-    bq, bk = fa._eff_blocks(t, None, None, d)
+    bq, bk = fa._eff_blocks(t, None, None, d, segf is not None)
     dof = fa._fold(do)
 
     from horovod_tpu.parallel._vma import pin_to, vma_of

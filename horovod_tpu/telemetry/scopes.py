@@ -62,6 +62,19 @@ SSM_CONV = "ssm_conv"
 SSM_GATE_NORM = "ssm_gate_norm"
 SSM_OUT = "ssm_out"
 
+# The parts of latent attention's projections (transformer._latent_qkv),
+# bare components under ATTN_QKV: the query's way through its latent, the
+# keys' and values' through theirs, and the rotary part with the
+# concatenations that put a head together.
+MLA_Q = "mla_q"
+MLA_KV = "mla_kv"
+MLA_ROPE = "mla_rope"
+
+# A leading dense MLP in a model whose other layers hold experts: a bare
+# component under MLP, so that a reader can tell it from an expert
+# layer's norm and residual add, which sit under MLP too.
+MLP_DENSE = "mlp_dense"
+
 # The multi-token-prediction module (transformer._mtp_loss): a bare
 # component that holds model scopes of its own (".../mtp/embed/...",
 # ".../mtp/layer_0/attn/qkv/...", ".../mtp/head/..."), so a reader that

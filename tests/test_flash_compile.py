@@ -38,11 +38,14 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-# [B, T, H, D] of gpt67_t8192 (B*H = 32) and gpt67_t2048 (B*H = 128).
+# [B, T, H, D] of gpt67_t8192 (B*H = 32), gpt67_t2048 (B*H = 128) and
+# glm47flash_t8192 (B*H = 20 at head dim 256, 1024^2 auto blocks: twice
+# the tiles and accumulators in VMEM).
 @pytest.mark.parametrize("segments", [False, True],
                          ids=["causal", "segment_ids"])
-@pytest.mark.parametrize("shape", [(1, 8192, 32, 128), (4, 2048, 32, 128)],
-                         ids=["t8192", "t2048"])
+@pytest.mark.parametrize("shape", [(1, 8192, 32, 128), (4, 2048, 32, 128),
+                                   (1, 8192, 20, 256)],
+                         ids=["t8192", "t2048", "t8192_d256"])
 def test_kernels_compile_for_the_v5e(one_chip, shape, segments):
     import jax
     import jax.numpy as jnp

@@ -294,6 +294,59 @@ def test_nemotron_step_carries_the_vocabulary_and_every_part(
     assert set(ssm_reduce.PARTS) | {ssm_reduce.MTP} <= parts
 
 
+MLA_PARTS = (scopes.MLA_Q, scopes.MLA_KV, scopes.MLA_ROPE)
+SIGMOID_PARTS = MOE_PARTS + (scopes.MOE_SHARED,)
+GLM = dict(positions="rope", tie_embeddings=False, head_width=64,
+           q_latent_rank=32, kv_latent_rank=32, rope_dim=16, mlp="swiglu",
+           n_experts=8, experts_per_token=2, d_expert=64, d_shared=64,
+           routed_scale=1.8, experts_held=2, experts_held_from=2,
+           dense_layers=1, mtp_layer_types=("full_attention",),
+           mtp_loss_coef=0.1)
+
+
+@pytest.mark.parametrize("attention,remat",
+                         [("local", "none"), ("flash", "full")])
+def test_glm_step_carries_the_vocabulary_and_every_part(hvd, attention,
+                                                        remat):
+    """Latent attention's three parts open under ``attn/qkv``, a leading
+    dense layer's MLP under ``mlp/mlp_dense``, the sigmoid-routed layer's
+    five parts under ``mlp``, the module under ``mtp``; every executed op
+    has a phase and a scope, and ``perfbench/mla_reduce.py`` reads each
+    part by name."""
+    from perfbench import mla_reduce, moe_reduce
+
+    text = _lm_step_text(attention, remat, False, **GLM)
+    _check_lm(text, attention, remat, False)
+    names = _op_names(text)
+    for part in MLA_PARTS:
+        inside = f"{scopes.ATTN_QKV}/{part}"
+        for mark in ("jvp(", "transpose("):
+            assert _under(names, part, inside, mark), (part, mark)
+        assert not _under(names, part, without=(inside,)), part
+    for part in SIGMOID_PARTS + (scopes.MLP_DENSE,):
+        inside = f"{scopes.MLP}/{part}"
+        for mark in ("jvp(", "transpose("):
+            assert _under(names, part, inside, mark), (part, mark)
+        assert not _under(names, part, without=(inside,)), part
+    # Layer 0 alone is dense, and nothing of it is an expert layer's.
+    main = [n for n in names if scopes.MTP not in n]
+    assert all("layer_0" in n for n in main if scopes.MLP_DENSE in n)
+    assert not any("layer_0" in n and "/moe_" in n for n in main)
+    assert not any(scopes.MOE_LATENT in n for n in names)
+    for scope in (scopes.EMBED, scopes.ATTN_QKV, scopes.ATTN_OUT,
+                  ROUTE[attention], scopes.MLP, scopes.HEAD, scopes.LOSS):
+        assert _under(names, scope, scopes.MTP, "jvp("), scope
+        assert _under(names, scope, scopes.MTP, "transpose("), scope
+    hlo = scope_reduce.parse_hlo(text)
+    parts = set()
+    for name, i in hlo.instructions.items():
+        if i.opcode in HELD:
+            parts.update(mla_reduce.parts_of(moe_reduce.op_name_of(name,
+                                                                   hlo)))
+    assert set(mla_reduce.PARTS) | {mla_reduce.MTP} <= parts
+    assert _unplaced(text) == []
+
+
 @pytest.mark.parametrize("attention", ("ring", "ulysses"))
 def test_sequence_routes_open_their_own_scope(hvd, attention):
     text = _lm_step_text(attention, "none", False, seq_axis="seq")
@@ -402,12 +455,19 @@ def test_the_benchmark_reads_the_same_vocabulary():
                                    scopes.MOE_SHARED, scopes.MTP})
     # Its recurrence's two kernels, likewise booked by the route.
     ssm_kernels = {scopes.SSM_SCAN_FWD, scopes.SSM_SCAN_BWD}
+    # And latent attention's three parts under ``attn/qkv`` with the
+    # leading dense layer's component under ``mlp``, read by
+    # ``perfbench/mla_reduce.py``.
+    mla_parts = set(MLA_PARTS) | {scopes.MLP_DENSE}
     assert set(scope_reduce.KERNEL_NAMES) == kernels
     assert (set(scope_reduce.MODEL_SCOPES + scope_reduce.GRAD_MEAN_SCOPES
                 + scope_reduce.OPTIMIZER_SCOPES)
             == program - kernels - modules - {scopes.LAYER} - moe_parts
             - moe_kernels - gdn_parts - gdn_kernels - ssm_parts
-            - ssm_kernels)
+            - ssm_kernels - mla_parts)
+    from perfbench import mla_reduce
+    assert (mla_parts | moe_parts | {scopes.MOE_SHARED}
+            == set(mla_reduce.PARTS))
     from perfbench import gdn_reduce
     assert ({p.rsplit("/", 1)[-1] for p in gdn_parts}
             == set(gdn_reduce.PARTS))
