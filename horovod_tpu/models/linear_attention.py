@@ -47,6 +47,15 @@ that does not cut into their tiles, and, on the CPU, inside
 interpreter's loops do not type.  ``hvd_gdn_blocks_total{path}`` says
 which was traced.
 
+**The short convolution**, ``silu`` and the normalisation of q and k are
+one pass over each projection's output: the Pallas kernels of
+:mod:`horovod_tpu.ops.short_conv` wherever they can run
+(:func:`conv_path`), which read the model dtype once, keep float32 from
+the taps to the norm and write q, k and v once, head-major, as the
+recurrence's kernels read them; else :func:`causal_conv` and what follows
+it as ``jax.numpy``, the kernels' oracle in the tests.
+``hvd_short_conv_rows_total{path}`` says which was traced.
+
 Precision, of both: ``g``, its running sums, the decays, ``N``, the
 inverse (its matmuls at precision ``highest``) and the carried state are
 float32; every other matmul takes operands in the model dtype (``T``,
@@ -79,6 +88,7 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu import telemetry
 from horovod_tpu.ops import gated_delta_rule as kernels
+from horovod_tpu.ops import short_conv
 from horovod_tpu.ops.gated_delta_rule import BLOCK
 from horovod_tpu.parallel._vma import pin_to, vma_of
 from horovod_tpu.telemetry import scopes
@@ -136,7 +146,13 @@ def causal_conv(x, w):
     [K, C]; ``y_t = sum_j w_j x_{t-K+1+j}`` with zeros before the
     sequence's start.  ``K`` shifted multiply-adds in float32, returned
     in float32: what follows (``silu``, the per-head normalisation) reads
-    it unrounded, and the model dtype comes back once, at their end."""
+    it unrounded, and the model dtype comes back once, at their end.
+
+    The oracle of :mod:`horovod_tpu.ops.short_conv` (same operations, same
+    order, same one rounding) and what both recurrent mixers run where its
+    kernels cannot (``short_conv.takes``): as ``jax.numpy`` it pads
+    ``x`` to ``T + K - 1`` rows and keeps float32 ``[T, C]`` arrays
+    between its steps and for the backward."""
     taps, t = w.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
     return sum(w[j] * lax.dynamic_slice_in_dim(padded, j, t, axis=1)
@@ -259,6 +275,21 @@ def recurrence_path(x) -> str:
     return "kernel" if kernels.takes(x) else "xla"
 
 
+def conv_path(x, cfg) -> str:
+    """What runs the three short convolutions of a layer of ``cfg`` over
+    projections of ``x`` [B, T, d], read as :func:`recurrence_path` reads
+    its: ``"kernel"``, the Pallas kernels of
+    :mod:`horovod_tpu.ops.short_conv` (convolution, ``silu`` and a head's
+    normalisation in one pass, written head-major); ``"xla"``,
+    :func:`causal_conv` and what follows it as ``jax.numpy``, where they
+    cannot run (``short_conv.takes``)."""
+    h, dk, dv = (cfg.linear_value_heads, cfg.linear_key_head_dim,
+                 cfg.linear_value_head_dim)
+    return "kernel" if all(
+        short_conv.takes(x, cfg.linear_conv_kernel, head_dim=d,
+                         channels=h * d) for d in (dk, dv)) else "xla"
+
+
 def saved_state_bytes(batch: int, t: int, cfg) -> int:
     """Bytes of block states the backward of one layer's recurrence
     keeps: the float32 state at the start of each block (the block is
@@ -295,17 +326,33 @@ def mixer(x, layer, cfg):
                 a + layer["lin_dt_bias"])
         with jax.named_scope(scopes.GDN_CONV):
             conv = layer["lin_conv"]
-            q = jax.nn.silu(causal_conv(q, conv[:, :wq]))
-            k = jax.nn.silu(causal_conv(k, conv[:, wq:wq + wk]))
-            v = jax.nn.silu(causal_conv(v, conv[:, wq + wk:]))
-            q = (_l2norm(q.reshape(bsz, t, h, dk)) * dk ** -0.5).astype(dt)
-            k = _l2norm(k.reshape(bsz, t, h, dk)).astype(dt)
-            v = v.reshape(bsz, t, h, dv).astype(dt)
+            conv = conv[:, :wq], conv[:, wq:wq + wk], conv[:, wq + wk:]
+            head_major = conv_path(x, cfg) == "kernel"
+            if head_major:
+                # [B * H, T, d]: what the recurrence's kernels read.
+                q = short_conv.short_conv(q, conv[0], head_dim=dk,
+                                          norm_scale=dk ** -0.5,
+                                          eps=L2NORM_EPS)
+                k = short_conv.short_conv(k, conv[1], head_dim=dk,
+                                          norm_scale=1.0, eps=L2NORM_EPS)
+                v = short_conv.short_conv(v, conv[2], head_dim=dv)
+            else:
+                q, k, v = (jax.nn.silu(causal_conv(a, w))
+                           for a, w in zip((q, k, v), conv))
+                q = (_l2norm(q.reshape(bsz, t, h, dk))
+                     * dk ** -0.5).astype(dt)
+                k = _l2norm(k.reshape(bsz, t, h, dk)).astype(dt)
+                v = v.reshape(bsz, t, h, dv).astype(dt)
     with jax.named_scope(scopes.ATTN_GDN_SCAN):
-        if recurrence_path(q) == "kernel":
-            o = kernels.gated_delta_rule(q, k, v, g, beta)
-        else:
+        if recurrence_path(x) != "kernel":
+            if head_major:
+                q, k, v = (kernels.token_major(a, bsz) for a in (q, k, v))
             o = gated_delta_rule(q, k, v, g, beta, dt)
+        elif head_major:
+            o = kernels.token_major(
+                kernels.gated_delta_rule_head_major(q, k, v, g, beta), bsz)
+        else:
+            o = kernels.gated_delta_rule(q, k, v, g, beta)
     with jax.named_scope(scopes.ATTN_OUT):
         with jax.named_scope(scopes.GDN_GATE_NORM):
             o = o.astype(jnp.float32)
@@ -340,3 +387,4 @@ def record_blocks(layer: int, x, cfg) -> None:
         "linear-attention layer's recurrence keeps (0 = it recomputes "
         "them)",
         layer=str(layer)).set(saved_state_bytes(batch, t, cfg))
+    short_conv.record_rows(layer, 3 * batch * t, conv_path(x, cfg))

@@ -47,6 +47,14 @@ multiply-add a chunk), then the carried states' part of the output for
 all chunks at once.  ``hvd_ssm_chunks_total{path}`` says which was
 traced.
 
+**The short convolution** with its bias, ``silu`` and the cut into
+``x``, ``B`` and ``C`` are one pass over ``xBC``: the Pallas kernels of
+:mod:`horovod_tpu.ops.short_conv` wherever they can run
+(:func:`conv_path`: the three widths whole lanes), float32 from the taps
+to the one rounding, three token-major arrays written through three
+output specs; else ``causal_conv`` and what follows it as ``jax.numpy``.
+``hvd_short_conv_rows_total{path}`` says which was traced.
+
 Precision, either way: ``delta``, ``log a``, its running sums, every
 decay and the carried state are float32; every matmul takes operands in
 the model dtype (the masked and decayed ``C B^T``, ``delta x`` and the
@@ -73,6 +81,7 @@ from jax.sharding import PartitionSpec as P
 from horovod_tpu import telemetry
 from horovod_tpu.models.linear_attention import causal_conv
 from horovod_tpu.ops import mamba2_scan as kernels
+from horovod_tpu.ops import short_conv
 from horovod_tpu.parallel._vma import pin_to, vma_of
 from horovod_tpu.telemetry import scopes
 
@@ -204,6 +213,25 @@ def recurrence_path(x, cfg) -> str:
         cfg.ssm_state) else "xla"
 
 
+def _conv_widths(cfg):
+    """The convolution's output cut into ``x``, ``B`` and ``C``."""
+    inner, conv, _ = widths(cfg)
+    return inner, (conv - inner) // 2, (conv - inner) // 2
+
+
+def conv_path(u, cfg) -> str:
+    """What runs the short convolution of a layer of ``cfg`` over the
+    projection of ``u`` [B, T, d], read as :func:`recurrence_path` reads
+    its: ``"kernel"``, the Pallas kernels of
+    :mod:`horovod_tpu.ops.short_conv` (convolution, bias and ``silu`` in
+    one pass, ``x``, ``B`` and ``C`` written as three arrays); ``"xla"``,
+    ``causal_conv`` and what follows it as ``jax.numpy``, where they
+    cannot run (``short_conv.takes``)."""
+    return "kernel" if short_conv.takes(
+        u, cfg.ssm_conv_kernel, widths=_conv_widths(cfg),
+        channels=widths(cfg)[1]) else "xla"
+
+
 def saved_state_bytes(batch: int, t: int, cfg) -> int:
     """Bytes of chunk states the backward of one layer's recurrence
     keeps: the float32 state at the start of each chunk."""
@@ -231,13 +259,18 @@ def mixer(u, layer, cfg):
                 preferred_element_type=jnp.float32) + layer["ssm_dt_bias"])
             log_a = -delta * jnp.exp(layer["ssm_a_log"])
         with jax.named_scope(scopes.SSM_CONV):
-            xbc = jax.nn.silu(causal_conv(xbc, layer["ssm_conv"])
-                              + layer["ssm_conv_bias"]).astype(dt)
-            x = xbc[..., :inner]
-            b_in = xbc[..., inner:inner + g * n]
-            c_in = xbc[..., inner + g * n:]
+            if conv_path(u, cfg) == "kernel":
+                x, b_in, c_in = short_conv.short_conv(
+                    xbc, layer["ssm_conv"], layer["ssm_conv_bias"],
+                    widths=_conv_widths(cfg))
+            else:
+                xbc = jax.nn.silu(causal_conv(xbc, layer["ssm_conv"])
+                                  + layer["ssm_conv_bias"]).astype(dt)
+                x = xbc[..., :inner]
+                b_in = xbc[..., inner:inner + g * n]
+                c_in = xbc[..., inner + g * n:]
     with jax.named_scope(scopes.ATTN_SSM_SCAN):
-        # Token-major as the convolution wrote them, either way: a
+        # Token-major, three arrays, whichever way the convolution ran: a
         # group's heads are a column slab.
         scan = (kernels.mamba2_scan if recurrence_path(u, cfg) == "kernel"
                 else ssd_scan)
@@ -278,3 +311,4 @@ def record_chunks(layer: int, x, cfg) -> None:
         "Bytes of chunk states the backward pass of the traced "
         "state-space layer's recurrence keeps",
         layer=str(layer)).set(saved_state_bytes(batch, t, cfg))
+    short_conv.record_rows(layer, batch * t, conv_path(x, cfg))

@@ -403,7 +403,7 @@ def _bwd_call(q, k, v, g, beta, do, states, *, packs, interpret):
     )(q, k, v, g, beta, do, states)
 
 
-def _head_major(x):
+def head_major(x):
     """[B, T, H, ...] -> [B * H, T, ...]."""
     x = jnp.moveaxis(x, 2, 1)
     return x.reshape((-1,) + x.shape[2:])
@@ -412,25 +412,25 @@ def _head_major(x):
 def _gates(x):
     """[B, T, H] -> [B * H, T / ROWS, ROWS] float32: a pack's gates are
     one row along the lanes."""
-    x = _head_major(x.astype(jnp.float32))
+    x = head_major(x.astype(jnp.float32))
     return x.reshape(x.shape[0], -1, ROWS)
 
 
-def _token_major(x, batch: int):
+def token_major(x, batch: int):
     """[B * H, T, ...] -> [B, T, H, ...]."""
     return jnp.moveaxis(x.reshape((batch, -1) + x.shape[1:]), 1, 2)
 
 
 def _forward(q, k, v, g, beta, save_states: bool):
-    operands = (_head_major(q), _head_major(k), _head_major(v), _gates(g),
-                _gates(beta))
-    o, *states = _fwd_call(*operands, packs=tiles(q.shape[1]),
+    o, *states = _fwd_call(q, k, v, g, beta, packs=tiles(q.shape[1]),
                            save_states=save_states, interpret=_interpret(q))
-    return _token_major(o, q.shape[0]), operands + tuple(states)
+    return o, (q, k, v, g, beta) + tuple(states)
 
 
 @jax.custom_vjp
 def _recurrence(q, k, v, g, beta):
+    """The kernels on their own operands: ``q``, ``k``, ``v`` head-major
+    and the gates a pack a row (:func:`_gates`)."""
     return _forward(q, k, v, g, beta, save_states=False)[0]
 
 
@@ -439,25 +439,22 @@ def _recurrence_fwd(q, k, v, g, beta):
 
 
 def _recurrence_bwd(residuals, do):
-    batch, t = do.shape[:2]
-    dq, dk, dv, dg, dbeta = _bwd_call(
-        *residuals[:5], _head_major(do), residuals[5],
-        packs=tiles(t), interpret=_interpret(do))
-    return (_token_major(dq, batch), _token_major(dk, batch),
-            _token_major(dv, batch),
-            _token_major(dg.reshape(dg.shape[0], t), batch),
-            _token_major(dbeta.reshape(dbeta.shape[0], t), batch))
+    return tuple(_bwd_call(*residuals[:5], do, residuals[5],
+                           packs=tiles(do.shape[1]),
+                           interpret=_interpret(do)))
 
 
 _recurrence.defvjp(_recurrence_fwd, _recurrence_bwd)
 
 
-def gated_delta_rule(q, k, v, g, beta):
-    """The recurrence from ``S_0 = 0``: ``q``, ``k`` [B, T, H, d_k]
-    (normalised, ``q`` scaled), ``v`` [B, T, H, d_v] in the model dtype,
-    ``g`` (``log alpha <= 0``) and ``beta`` [B, T, H] float32 -> ``o``
-    [B, T, H, d_v] in the model dtype.  ``T`` is one that :func:`takes`
-    accepts.  Differentiable in all five."""
+def gated_delta_rule_head_major(q, k, v, g, beta):
+    """The recurrence from ``S_0 = 0`` on head-major operands, the
+    kernels' own layout and what :mod:`horovod_tpu.ops.short_conv`
+    writes: ``q``, ``k`` [B * H, T, d_k] (normalised, ``q`` scaled),
+    ``v`` [B * H, T, d_v] in the model dtype, ``g`` (``log alpha <= 0``)
+    and ``beta`` [B, T, H] float32 -> ``o`` [B * H, T, d_v] in the model
+    dtype.  ``T`` is one that :func:`takes` accepts.  Differentiable in
+    all five."""
     t = q.shape[1]
     if _packs(t) is None:
         raise ValueError(
@@ -469,4 +466,13 @@ def gated_delta_rule(q, k, v, g, beta):
         q, k, v, g, beta = (
             jnp.pad(x, ((0, 0), (0, -t % ROWS)) + ((0, 0),) * (x.ndim - 2))
             for x in (q, k, v, g, beta))
-    return _recurrence(q, k, v, g, beta)[:, :t]
+    return _recurrence(q, k, v, _gates(g), _gates(beta))[:, :t]
+
+
+def gated_delta_rule(q, k, v, g, beta):
+    """:func:`gated_delta_rule_head_major` on token-major operands:
+    ``q``, ``k`` [B, T, H, d_k], ``v`` [B, T, H, d_v] -> ``o`` [B, T, H,
+    d_v]."""
+    o = gated_delta_rule_head_major(head_major(q), head_major(k),
+                                    head_major(v), g, beta)
+    return token_major(o, q.shape[0])

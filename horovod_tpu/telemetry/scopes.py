@@ -109,6 +109,11 @@ GDN_SCAN_BWD = "gdn_scan_bwd"
 SSM_SCAN_FWD = "ssm_scan_fwd"
 SSM_SCAN_BWD = "ssm_scan_bwd"
 
+# The two short-convolution Pallas kernels (ops/short_conv.py); they run
+# under GDN_CONV and SSM_CONV.
+SHORT_CONV_FWD = "short_conv_fwd"
+SHORT_CONV_BWD = "short_conv_bwd"
+
 # The functions handed to jax.jit: the XLA module is jit_<name>.
 LM_TRAIN_STEP = "hvd_lm_train_step"
 LM_PIPELINED_TRAIN_STEP = "hvd_lm_pipelined_train_step"

@@ -1,5 +1,5 @@
-"""The three flash kernels, the two gated-delta-rule kernels and the two
-Mamba-2 scan kernels compiled at
+"""The three flash kernels, the two gated-delta-rule kernels, the two
+Mamba-2 scan kernels and the two short-convolution kernels compiled at
 the benchmark's shapes for a v5e that is described and not attached (rehearsal 3 of the
 on-chip-measurement guide; the recipe of
 ``perfbench/tests/test_chip_compile.py``).
@@ -149,3 +149,44 @@ def test_mamba2_scan_kernels_compile_for_the_v5e(one_chip, t, g, r, p,
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     for name, calls in ((scopes.SSM_SCAN_FWD, 2), (scopes.SSM_SCAN_BWD, 1)):
         assert len(re.findall(rf"%{name}[.\d]* = ", text)) == calls, name
+
+
+# The short convolutions of one mixer layer: olmohybrid_t16k's q (or k: 30
+# heads of 96 with the L2 norm, written head-major) and v (30 heads of 192),
+# nemotron3s_t8192's xBC with its bias (three token-major outputs).
+@pytest.mark.parametrize("t,channels,kw,tile", [
+    (16384, 2880, dict(head_dim=96, norm_scale=96 ** -0.5), 512),
+    (16384, 5760, dict(head_dim=192), 512),
+    (8192, 10240, dict(widths=(8192, 1024, 1024)), 256)],
+    ids=["olmohybrid_q", "olmohybrid_v", "nemotron3s_xBC"])
+def test_short_conv_kernels_compile_for_the_v5e(one_chip, t, channels, kw,
+                                                tile):
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import short_conv as op
+    from horovod_tpu.telemetry import scopes
+
+    head_dim, widths = kw.get("head_dim"), kw.get("widths")
+    assert op.tiles(t, channels, 4, head_dim, widths) == tile
+    plan = op._Plan(widths or (channels,), head_dim, kw.get("norm_scale"),
+                    1e-6, widths is not None)
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    x, w = shape(1, t, channels), shape(4, channels, dtype=jnp.float32)
+    bias = shape(1, channels, dtype=jnp.float32) if plan.bias else None
+    dys = ((shape(channels // head_dim, t, head_dim),) if head_dim
+           else tuple(shape(1, t, width) for width in widths))
+
+    # The calls themselves, told to compile: the public function reads
+    # the executing mesh, and this process's is the CPU.
+    def fwd_and_grads(x, w, bias, dys):
+        return (op._fwd_call(x, w, bias, plan=plan, interpret=False),
+                op._bwd_call(x, w, bias, dys, plan=plan, interpret=False))
+
+    text = jax.jit(fwd_and_grads).lower(x, w, bias, dys).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for name in (scopes.SHORT_CONV_FWD, scopes.SHORT_CONV_BWD):
+        assert len(re.findall(rf"%{name}[.\d]* = ", text)) == 1, name

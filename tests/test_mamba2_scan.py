@@ -18,6 +18,7 @@ import pytest
 
 from horovod_tpu.models import mamba2
 from horovod_tpu.ops import mamba2_scan as op
+from horovod_tpu.telemetry import scopes
 from perfbench.reference import ssm_moe_lm as reference
 from tests.test_ssm_moe_lm import DECAYS, F32_REL, NEMOTRON_TINY, _rel
 
@@ -280,7 +281,7 @@ def test_the_mixer_calls_the_kernels_where_they_run():
     def loss(layer, u, path):
         traced = jax.make_jaxpr(lambda l, u: mamba2.mixer(u, l, cfg))(
             layer, u)
-        assert ("pallas_call" in str(traced)) is (path == "kernel")
+        assert (scopes.SSM_SCAN_FWD in str(traced)) is (path == "kernel")
         return jnp.sum(mamba2.mixer(u, layer, cfg) * dy)
 
     with jax.default_matmul_precision("highest"):
@@ -306,7 +307,9 @@ def test_a_group_too_wide_for_vmem_runs_the_jax_numpy_form():
         jax.random.key(0), cfg, lambda k, shape: jnp.zeros(shape)))
     assert mamba2.recurrence_path(u, cfg) == "xla"
     traced = jax.make_jaxpr(lambda l, u: mamba2.mixer(u, l, cfg))(layer, u)
-    assert "pallas_call" not in str(traced)
+    # The short convolution's kernels take these widths; the scan's do not.
+    assert "pallas_call" in str(traced)
+    assert scopes.SSM_SCAN_FWD not in str(traced)
     assert traced.out_avals[0].shape == u.shape
 
 

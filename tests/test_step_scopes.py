@@ -459,12 +459,15 @@ def test_the_benchmark_reads_the_same_vocabulary():
     # leading dense layer's component under ``mlp``, read by
     # ``perfbench/mla_reduce.py``.
     mla_parts = set(MLA_PARTS) | {scopes.MLP_DENSE}
+    # The short convolution's two kernels run under ``gdn_conv`` and
+    # ``ssm_conv`` and are booked by those parts, not by their names.
+    conv_kernels = {scopes.SHORT_CONV_FWD, scopes.SHORT_CONV_BWD}
     assert set(scope_reduce.KERNEL_NAMES) == kernels
     assert (set(scope_reduce.MODEL_SCOPES + scope_reduce.GRAD_MEAN_SCOPES
                 + scope_reduce.OPTIMIZER_SCOPES)
             == program - kernels - modules - {scopes.LAYER} - moe_parts
             - moe_kernels - gdn_parts - gdn_kernels - ssm_parts
-            - ssm_kernels - mla_parts)
+            - ssm_kernels - mla_parts - conv_kernels)
     from perfbench import mla_reduce
     assert (mla_parts | moe_parts | {scopes.MOE_SHARED}
             == set(mla_reduce.PARTS))
