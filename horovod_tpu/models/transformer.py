@@ -42,11 +42,18 @@ need not be ``d_model / n_heads`` and a rotary part of ``rope_dim``;
 expert layers; ``mlp="swiglu"`` with ``d_shared`` is SwiGLU experts under
 the sigmoid router beside a shared SwiGLU expert
 (:func:`moe.sigmoid_moe_ffn`).  GLM-4.7-Flash is those three together,
-with a prediction module of one such layer.
+with a prediction module of one such layer.  ``head_width`` alone is
+plain attention whose heads are not ``d_model / n_heads`` wide,
+``qk_norm_per_head`` norms q and k a head at a time, and the ``index_*``
+fields put an indexer beside every attention layer that chooses the
+``index_topk`` keys each query reads and learns from its own loss
+(:mod:`horovod_tpu.ops.sparse_attention`); Keye-VL-2.0's language model is
+those three over grouped heads and softmax-routed experts.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Optional, Tuple
@@ -58,6 +65,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.models import linear_attention, mamba2, moe
+from horovod_tpu.ops import sparse_attention
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel import sequence as seq_mod
 from horovod_tpu.parallel import tensor as tp
@@ -178,6 +186,24 @@ class TransformerConfig:
     # and head; ``mtp_loss_coef`` x their cross-entropy is added.
     mtp_layer_types: Tuple[str, ...] = ()
     mtp_loss_coef: float = 0.0
+    # RMSNorm of q and of k over each head's ``head_dim``, one learned
+    # scale [head_dim] each, after the head split and before the rotation
+    # (Qwen3); ``qk_norm`` above norms the whole projection instead.
+    qk_norm_per_head: bool = False
+    # Learned sparse attention, all four together: beside every softmax
+    # attention layer an indexer of ``index_heads`` heads of
+    # ``index_head_dim`` over ONE key head scores every earlier key of a
+    # query from the layer's normed input (gradient cut), the
+    # ``index_topk`` best are the keys all of the query's heads read
+    # (every earlier key while there are no more), and
+    # ``indexer_loss_coef`` x the mean over tokens of KL(head-mean
+    # attention probabilities || softmax of the indexer's scores on those
+    # keys), summed over layers, is added to the loss and reaches the
+    # indexer alone (ops/sparse_attention.py).
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    indexer_loss_coef: float = 0.0
 
     def __post_init__(self):
         if self.positions not in ("learned", "rope", "none"):
@@ -229,9 +255,10 @@ class TransformerConfig:
         if self.mlp not in ("gelu", "swiglu", "relu2"):
             raise ValueError(f"mlp={self.mlp!r}: expected 'gelu', "
                              f"'swiglu' or 'relu2'")
-        latent = (self.head_width, self.q_latent_rank,
-                  self.kv_latent_rank, self.rope_dim)
+        # A head width alone is plain attention with heads of that width.
+        latent = (self.q_latent_rank, self.kv_latent_rank, self.rope_dim)
         if any(latent):
+            latent += (self.head_width,)
             if (self.q_latent_rank or self.kv_latent_rank) \
                     and not self.head_width:
                 raise ValueError(
@@ -241,9 +268,8 @@ class TransformerConfig:
                     f"head")
             if min(latent) <= 0:
                 raise ValueError(
-                    "head_width, q_latent_rank, kv_latent_rank and "
-                    "rope_dim come together: they are latent attention "
-                    "(a head width alone is not implemented)")
+                    "q_latent_rank, kv_latent_rank and rope_dim come "
+                    "together, with head_width: they are latent attention")
             if self.rope_dim > self.head_width:
                 raise ValueError(
                     f"rope_dim={self.rope_dim} is wider than head_width="
@@ -255,11 +281,31 @@ class TransformerConfig:
                     f"part: it needs positions='rope' and an even "
                     f"rope_dim, got {self.positions!r} and "
                     f"{self.rope_dim}")
-            if self.qk_norm or self.n_kv_heads:
+            if self.qk_norm or self.n_kv_heads or self.qk_norm_per_head:
                 raise NotImplementedError(
                     "latent attention norms its latents and gives every "
-                    "head its own key: qk_norm and n_kv_heads are not "
-                    "implemented with it")
+                    "head its own key: qk_norm, qk_norm_per_head and "
+                    "n_kv_heads are not implemented with it")
+        if self.qk_norm and self.qk_norm_per_head:
+            raise ValueError("qk_norm norms the whole projection, "
+                             "qk_norm_per_head each head: one of them")
+        sparse = (self.index_heads, self.index_head_dim, self.index_topk,
+                  self.indexer_loss_coef)
+        if any(sparse):
+            if min(sparse) <= 0:
+                raise ValueError(
+                    "index_heads, index_head_dim, index_topk and "
+                    "indexer_loss_coef come together: they are learned "
+                    "sparse attention")
+            if self.positions != "rope" or self.index_head_dim % 2:
+                raise ValueError(
+                    f"the indexer's queries and key are rotary: it needs "
+                    f"positions='rope' and an even index_head_dim, got "
+                    f"{self.positions!r} and {self.index_head_dim}")
+            if self.latent_attention:
+                raise NotImplementedError(
+                    "an indexer beside latent attention (index_heads with "
+                    "kv_latent_rank) is not implemented")
         if self.positions == "rope" and self.head_dim % 2:
             raise ValueError(f"positions='rope' needs an even head_dim, "
                              f"got {self.head_dim}")
@@ -334,6 +380,16 @@ class TransformerConfig:
     @property
     def latent_attention(self) -> bool:
         return self.kv_latent_rank > 0
+
+    @property
+    def sparse_attention(self) -> bool:
+        return self.index_topk > 0
+
+    @property
+    def attn_width(self) -> int:
+        """What the query projection gives and the out projection takes."""
+        return (self.n_heads * self.head_width if self.head_width
+                else self.d_model)
 
     @property
     def sigmoid_router(self) -> bool:
@@ -441,11 +497,26 @@ def init_params(rng, cfg: TransformerConfig):
                             (r_kv, 2 * wide - cfg.n_heads * cfg.rope_dim)),
                 wo=dense(k[3], (wide, d)))
         elif _MIXER[kind] == FULL_ATTENTION:
-            layer.update(wq=dense(k[0], (d, d)), wk=dense(k[1], (d, d_kv)),
-                         wv=dense(k[2], (d, d_kv)), wo=dense(k[3], (d, d)))
+            wide = cfg.attn_width
+            layer.update(wq=dense(k[0], (d, wide)),
+                         wk=dense(k[1], (d, d_kv)),
+                         wv=dense(k[2], (d, d_kv)),
+                         wo=dense(k[3], (wide, d)))
             if cfg.qk_norm:
                 layer["q_norm_scale"] = jnp.ones((d,), jnp.float32)
                 layer["k_norm_scale"] = jnp.ones((d_kv,), jnp.float32)
+            if cfg.qk_norm_per_head:
+                layer["q_norm_scale"] = jnp.ones((cfg.head_dim,),
+                                                 jnp.float32)
+                layer["k_norm_scale"] = jnp.ones((cfg.head_dim,),
+                                                 jnp.float32)
+            if cfg.sparse_attention:
+                k_index = jax.random.split(jax.random.fold_in(k[0], 1), 3)
+                layer.update(
+                    index_wq=dense(k_index[0], (
+                        d, cfg.index_heads * cfg.index_head_dim)),
+                    index_wk=dense(k_index[1], (d, cfg.index_head_dim)),
+                    index_ww=dense(k_index[2], (d, cfg.index_heads)))
         if not _HAS_MLP[kind]:
             return layer
         if dense_mlp:
@@ -521,8 +592,10 @@ def param_specs(cfg: TransformerConfig, model_axis: Optional[str]):
     col = P(None, m)     # split output dim
     row = P(m, None)     # split input dim
     attention = {"wq": col, "wk": col, "wv": col, "wo": row}
-    if cfg.qk_norm:
+    if cfg.qk_norm or cfg.qk_norm_per_head:
         attention.update(q_norm_scale=P(), k_norm_scale=P())
+    if cfg.sparse_attention:
+        attention.update(index_wq=P(), index_wk=P(), index_ww=P())
     if cfg.latent_attention:
         # Whole on every chip (heads over the model axis: ROADMAP R16).
         attention = {name: P() for name in (
@@ -643,13 +716,15 @@ def _rotary(x, positions, theta: float):
                            axis=-1).astype(x.dtype)
 
 
-def _qkv_proj(x, layer, cfg, model_axis, positions=None):
+def _qkv_proj(x, layer, cfg, model_axis, positions=None, normed=None):
     """rmsnorm -> q/k/v projections -> (QK-norm) -> head split ->
     (rotary at ``positions`` [T]) (shared by forward, decode_step and
     forward_pipelined so the projection math cannot drift).  Returns q,
-    k, v with a trailing [heads, head_dim] split."""
+    k, v with a trailing [heads, head_dim] split.  ``normed``: the normed
+    ``x`` where the caller has it already (it hands it to an indexer too)."""
     dt = cfg.dtype
-    h = _rmsnorm(x, layer["ln1_scale"], cfg.norm_eps)
+    h = (_rmsnorm(x, layer["ln1_scale"], cfg.norm_eps) if normed is None
+         else normed)
     if cfg.latent_attention:
         return _latent_qkv(h, layer, cfg, positions)
     hi = tp.region_input(h, model_axis) if model_axis else h
@@ -666,10 +741,35 @@ def _qkv_proj(x, layer, cfg, model_axis, positions=None):
                                          cfg.head_dim))
 
     q, k, v = heads(q), heads(k), heads(v)
-    if cfg.positions == "rope":
-        q = _rotary(q, positions, cfg.rope_theta)
-        k = _rotary(k, positions, cfg.rope_theta)
+    # The per-head norm and the rotation after it are a part of their own
+    # in a trace; without the norm the rotation is booked as it always was.
+    with (jax.named_scope(scopes.QK_HEAD_NORM_ROPE) if cfg.qk_norm_per_head
+          else contextlib.nullcontext()):
+        if cfg.qk_norm_per_head:
+            q = _rmsnorm(q, layer["q_norm_scale"], cfg.norm_eps)
+            k = _rmsnorm(k, layer["k_norm_scale"], cfg.norm_eps)
+        if cfg.positions == "rope":
+            q = _rotary(q, positions, cfg.rope_theta)
+            k = _rotary(k, positions, cfg.rope_theta)
     return q, k, v, dh
+
+
+@jax.named_scope(scopes.DSA_INDEX_PROJ)
+def _indexer_proj(u, layer, cfg, positions):
+    """The indexer's operands from the layer's normed input ``u`` [B, T, d],
+    whose gradient stops here (the indexer learns from its own loss and
+    moves nothing else): queries ``[B, T, index_heads, index_head_dim]`` and
+    ONE key head ``[B, T, index_head_dim]``, both rotary at ``positions``
+    over all their dims, and a weight a head ``[B, T, index_heads]``."""
+    dt = cfg.dtype
+    u = lax.stop_gradient(u)
+    qi = (u @ layer["index_wq"].astype(dt)).reshape(
+        u.shape[:-1] + (cfg.index_heads, cfg.index_head_dim))
+    ki = (u @ layer["index_wk"].astype(dt))[..., None, :]
+    w = u @ layer["index_ww"].astype(dt)
+    qi = _rotary(qi, positions, cfg.rope_theta)
+    ki = _rotary(ki, positions, cfg.rope_theta)[..., 0, :]
+    return qi, ki, w
 
 
 def _latent_qkv(h, layer, cfg, positions):
@@ -771,6 +871,12 @@ def _logits_head(x, params, cfg):
 # pipelined builder.
 _LATENT_FIELDS = ("head_width", "q_latent_rank", "kv_latent_rank",
                   "rope_dim")
+# Learned sparse attention's, for the same four paths: a query's selected
+# keys lie on other chips under a sequence axis, the indexer's key cache
+# and a selection per decoded token are not written (ROADMAP R3), and the
+# per-head norm's scale is not split with the heads.
+_SPARSE_FIELDS = ("index_heads", "index_head_dim", "index_topk",
+                  "indexer_loss_coef", "qk_norm_per_head")
 
 
 def _refuse_under_model_axis(cfg, model_axis) -> None:
@@ -780,12 +886,13 @@ def _refuse_under_model_axis(cfg, model_axis) -> None:
     if model_axis:
         _refuse(cfg, f"model_axis={model_axis!r}",
                 ("qk_norm", "n_experts", "layer_types", "n_kv_heads",
-                 "mtp_layer_types") + _LATENT_FIELDS)
+                 "mtp_layer_types") + _LATENT_FIELDS + _SPARSE_FIELDS)
 
 
 def _refuse_under_seq_axis(cfg, seq_axis) -> None:
     if seq_axis:
-        _refuse(cfg, f"seq_axis={seq_axis!r}", _LATENT_FIELDS)
+        _refuse(cfg, f"seq_axis={seq_axis!r}",
+                _LATENT_FIELDS + _SPARSE_FIELDS)
 
 
 def _remat_wrap(body, remat: str):
@@ -853,15 +960,22 @@ def forward_with_router_stats(params, tokens, cfg: TransformerConfig,
 
 def _hidden_states(params, tokens, cfg: TransformerConfig, model_axis,
                    seq_axis, attention, segment_ids, remat):
-    """``(x, router stats, run_layers)``: the last layer's output before
-    the final norm, one :class:`moe.RouterStats` per softmax-routed MoE
-    layer, and the function that ran the stack (``run_layers(x, layers,
-    types, label)``), for the multi-token-prediction module to run its
-    own layers by."""
+    """``(x, router stats, run_layers, index_kl)``: the last layer's
+    output before the final norm, one :class:`moe.RouterStats` per
+    softmax-routed MoE layer, the function that ran the stack
+    (``run_layers(x, layers, types, label)``), for the
+    multi-token-prediction module to run its own layers by, and the list
+    that every sparse attention layer run so far has put its indexer's
+    summed KL in."""
     _refuse_under_model_axis(cfg, model_axis)
     _refuse_under_seq_axis(cfg, seq_axis)
     _refuse_with_recurrent_layers(cfg, seq_axis=seq_axis,
                                   segment_ids=segment_ids)
+    if cfg.sparse_attention and segment_ids is not None:
+        raise NotImplementedError(
+            "segment_ids: learned sparse attention "
+            "(TransformerConfig.index_topk) does not implement it: the "
+            "selection would have to stay inside a document")
     dt = cfg.dtype
     t_local = tokens.shape[1]
     with jax.named_scope(scopes.EMBED):
@@ -877,6 +991,22 @@ def _hidden_states(params, tokens, cfg: TransformerConfig, model_axis,
 
     def attention_part(x, layer, segment_ids):
         # --- attention block (each route opens its own attn/<route>) ---
+        if cfg.sparse_attention:
+            # The route of its own: the indexer chooses each query's keys
+            # (ops/sparse_attention.py), whatever ``attention`` says.
+            with jax.named_scope(scopes.ATTN_QKV):
+                u = _rmsnorm(x, layer["ln1_scale"], cfg.norm_eps)
+                q, k, v, dh = _qkv_proj(x, layer, cfg, model_axis, positions,
+                                        normed=u)
+                qi, ki, w = _indexer_proj(u, layer, cfg, positions)
+            with jax.named_scope(scopes.ATTN_FLASH):
+                o, kl = sparse_attention.dsa_attention(
+                    q, k, v, qi, ki, w, topk=cfg.index_topk,
+                    index_scale=(cfg.index_heads
+                                 * cfg.index_head_dim) ** -0.5)
+            with jax.named_scope(scopes.ATTN_OUT):
+                return (_attn_out(o.reshape(o.shape[:2] + (dh,)), x, layer,
+                                  dt, model_axis), jnp.sum(kl))
         with jax.named_scope(scopes.ATTN_QKV):
             q, k, v, dh = _qkv_proj(x, layer, cfg, model_axis, positions)
         b, t = q.shape[:2]
@@ -959,7 +1089,7 @@ def _hidden_states(params, tokens, cfg: TransformerConfig, model_axis,
               LINEAR_ATTENTION: _remat_wrap(linear_attention_part, remat),
               MAMBA2: _remat_wrap(mamba2_part, remat)}
     mlp_part = _remat_wrap(mlp_part, remat)
-    router_stats = []
+    router_stats, index_kl = [], []
 
     def run_layers(x, layers, types, label="%d"):
         """``x`` through ``layers`` of ``types``; ``label % i`` names
@@ -969,12 +1099,17 @@ def _hidden_states(params, tokens, cfg: TransformerConfig, model_axis,
             with jax.named_scope(scopes.LAYER % i):
                 if mixer:
                     x = mixers[mixer](x, layer, segment_ids)
+                if mixer == FULL_ATTENTION and cfg.sparse_attention:
+                    x, kl = x
+                    index_kl.append(kl)
                 if _HAS_MLP[kind]:
                     x, stats = mlp_part(x, layer)
             if mixer == LINEAR_ATTENTION:
                 linear_attention.record_blocks(name, x, cfg)
             if mixer == MAMBA2:
                 mamba2.record_chunks(name, x, cfg)
+            if mixer == FULL_ATTENTION and cfg.sparse_attention:
+                sparse_attention.record_path(sparse_attention.path(x))
             if _HAS_MLP[kind] and _holds_experts(layer):
                 moe.record_held(name, tokens.size, cfg)
                 moe.record_weight_copies(name, layer)
@@ -989,7 +1124,7 @@ def _hidden_states(params, tokens, cfg: TransformerConfig, model_axis,
 
     x = run_layers(x, params["layers"],
                    [cfg.layer_type(i) for i in range(cfg.n_layers)])
-    return x, router_stats, run_layers
+    return x, router_stats, run_layers, index_kl
 
 
 @jax.named_scope(scopes.LOSS)
@@ -1041,7 +1176,7 @@ def loss_fn(params, tokens, labels, cfg: TransformerConfig,
     all layers' tokens together.  ``batch_axes``: the mesh axes the batch
     is split over, so that the mean of the shards' losses is the global
     batch's loss (:func:`moe.router_losses`)."""
-    x, router_stats, run_layers = _hidden_states(
+    x, router_stats, run_layers, index_kl = _hidden_states(
         params, tokens, cfg, model_axis, seq_axis, attention, segment_ids,
         remat)
     loss = xent(_logits_head(x, params, cfg), labels)
@@ -1049,6 +1184,12 @@ def loss_fn(params, tokens, labels, cfg: TransformerConfig,
         ahead = _mtp_loss(params, x, labels, cfg, run_layers)
         with jax.named_scope(scopes.LOSS):
             loss = loss + cfg.mtp_loss_coef * ahead
+    if index_kl:
+        # Every sparse layer run, the prediction module's included: the
+        # mean over this shard's tokens of each, summed over layers.
+        with jax.named_scope(scopes.LOSS):
+            loss = loss + cfg.indexer_loss_coef * (
+                sum(index_kl) / tokens.size)
     if router_stats:
         with jax.named_scope(scopes.LOSS):
             balance, z = moe.router_losses(
@@ -1264,7 +1405,7 @@ def decode_step(params, token, cache, pos, cfg: TransformerConfig,
     # A rotated key cache, an expert layer per token and a recurrent
     # layer's state and convolution window beside the key cache are not
     # written (serving: ROADMAP R8/R13).
-    _refuse(cfg, "decode_step", _LATENT_FIELDS + (
+    _refuse(cfg, "decode_step", _LATENT_FIELDS + _SPARSE_FIELDS + (
         "positions", "n_experts", "layer_types", "n_kv_heads",
         "mtp_layer_types"))
     dt = cfg.dtype
@@ -1530,7 +1671,8 @@ def make_train_step_pipelined(cfg: TransformerConfig, optimizer, mesh,
 
     # The pipelined forward embeds with the position table, scans stacked
     # dense layers of one type and returns no router sums.
-    _refuse(cfg, "make_train_step_pipelined", _LATENT_FIELDS + (
+    _refuse(cfg, "make_train_step_pipelined",
+            _LATENT_FIELDS + _SPARSE_FIELDS + (
         "positions", "qk_norm", "tie_embeddings", "mlp", "n_experts",
         "layer_types", "n_kv_heads", "mtp_layer_types"))
     n_stages = mesh.shape[pipe_axis]

@@ -70,6 +70,21 @@ MLA_Q = "mla_q"
 MLA_KV = "mla_kv"
 MLA_ROPE = "mla_rope"
 
+# The parts of learned sparse attention (ops/sparse_attention.py,
+# transformer._indexer_proj), bare components as latent attention's are.
+# Under ATTN_QKV: the indexer's three projections and its rotation, and
+# the per-head QK-norm with the rotation of q and k.  The route itself
+# opens under ATTN_FLASH (it is the flash kernels' work under a mask, and
+# a reader that knows only the model scopes books it there): the indexer's
+# scores, the selection (mask and its transpose), the attention kernels
+# under the mask, and the indexer's loss (head-mean probabilities, KL).
+DSA_INDEX_PROJ = "dsa_index_proj"
+QK_HEAD_NORM_ROPE = "qk_head_norm_rope"
+DSA_INDEX_SCORES = "dsa_index_scores"
+DSA_SELECT = "dsa_select"
+DSA_FLASH = "dsa_flash"
+DSA_INDEX_LOSS = "dsa_index_loss"
+
 # A leading dense MLP in a model whose other layers hold experts: a bare
 # component under MLP, so that a reader can tell it from an expert
 # layer's norm and residual add, which sit under MLP too.
@@ -113,6 +128,17 @@ SSM_SCAN_BWD = "ssm_scan_bwd"
 # under GDN_CONV and SSM_CONV.
 SHORT_CONV_FWD = "short_conv_fwd"
 SHORT_CONV_BWD = "short_conv_bwd"
+
+# The seven sparse-attention Pallas kernels (ops/sparse_attention.py): the
+# indexer's scores and their gradient, the selection, attention under the
+# mask (forward, dQ, dK+dV) and the head-mean probabilities.
+DSA_INDEX_FWD = "dsa_index_fwd"
+DSA_INDEX_BWD = "dsa_index_bwd"
+DSA_SELECT_KERNEL = "dsa_select_rows"
+DSA_FWD = "dsa_fwd"
+DSA_BWD_DQ = "dsa_bwd_dq"
+DSA_BWD_DKV = "dsa_bwd_dkv"
+DSA_PROBS = "dsa_probs"
 
 # The functions handed to jax.jit: the XLA module is jit_<name>.
 LM_TRAIN_STEP = "hvd_lm_train_step"

@@ -1,0 +1,553 @@
+"""Keye-VL-2.0's language block through the normal LM step, at tiny widths
+that keep the shape of the thing: grouped heads whose total width is not
+the hidden size, QK-norm a head at a time, an indexer beside every
+attention layer that chooses each query's keys and learns from its own
+loss, and softmax-routed SwiGLU experts of which this chip holds a share;
+against the plain reference of ``perfbench/reference/dsa_moe_lm.py``, which
+shares no code with the program, and the Pallas kernels of
+``horovod_tpu/ops/sparse_attention.py`` in the interpreter against their
+``jax.numpy`` forms.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from horovod_tpu.models import moe
+from horovod_tpu.models import transformer as tfm
+from horovod_tpu.ops import sparse_attention as sa
+from perfbench.reference import dsa_moe_lm as reference
+
+F32_REL = 5e-5
+
+# 4 query heads over 2 key-value heads of 32 on a hidden size of 64 (4 x
+# 32 = 128, twice the hidden size, as 32 x 128 is of 2048); 4 indexer
+# heads of 16, 32 keys a query of 128; 8 experts top-2, 4 held from 2.
+KEYE_TINY = tfm.TransformerConfig(
+    vocab_size=128, d_model=64, n_heads=4, n_kv_heads=2, head_width=32,
+    n_layers=2, d_ff=0, max_seq=128, dtype=jnp.float32, positions="rope",
+    rope_theta=1e7, norm_eps=1e-6, tie_embeddings=False,
+    qk_norm_per_head=True, index_heads=4, index_head_dim=16, index_topk=32,
+    indexer_loss_coef=1.0, mlp="swiglu", n_experts=8, experts_per_token=2,
+    d_expert=48, norm_topk_prob=True, experts_held=4, experts_held_from=2)
+INDEX_LEAVES = ("index_wq", "index_wk", "index_ww")
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def _params(cfg, seed=0):
+    params = tfm.init_params(jax.random.PRNGKey(seed), cfg)
+    # As the benchmark's adapter: at the program's 0.02 every token is the
+    # same token to the router.
+    params["embed"] = params["embed"] * 50.0
+    return params
+
+
+def _batch(cfg, batch=2, seq=128, seed=1):
+    toks = jax.random.randint(jax.random.PRNGKey(seed), (batch, seq + 1), 0,
+                              cfg.vocab_size)
+    return toks[:, :-1], toks[:, 1:]
+
+
+def _dims(cfg):
+    return {"n_heads": cfg.n_heads, "n_kv_heads": cfg.kv_heads,
+            "head_dim": cfg.head_dim, "index_heads": cfg.index_heads,
+            "index_head_dim": cfg.index_head_dim, "topk": cfg.index_topk,
+            "eps": cfg.norm_eps, "theta": cfg.rope_theta,
+            "top_k": cfg.experts_per_token,
+            "held_from": cfg.experts_held_from}
+
+
+def _reference(cfg, params, tokens, labels, **kw):
+    """Every leaf the reference can differentiate, not only the cell's."""
+    kw.setdefault("names", tuple(reference.LEAVES))
+    kw.setdefault("index_coef", cfg.indexer_loss_coef)
+    return reference.loss_and_tail_grads(params, tokens, labels,
+                                         dims=_dims(cfg), **kw)
+
+
+def _checked(tree, cfg=KEYE_TINY):
+    return {name: reference.leaf(tree, path)
+            for name, path in reference.leaf_paths(cfg.n_layers).items()}
+
+
+# --- the model against the reference ------------------------------------------
+
+def test_loss_and_every_leaf_match_the_reference():
+    """Float32 program against the float32 reference: the loss, both
+    terms, and the gradient of every leaf of a layer: the three attention
+    projections behind the masked softmax, both per-head norms, ``W_o``,
+    the indexer's three matrices, the router, the three expert matrices,
+    both layer norms and the final norm."""
+    cfg = KEYE_TINY
+    params, (tokens, labels) = _params(cfg), _batch(cfg)
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.value_and_grad(tfm.loss_fn)(
+            params, tokens, labels, cfg)
+        want, want_g, stats = _reference(cfg, params, tokens, labels)
+        ce = tfm.loss_fn(params, tokens, labels, dataclasses.replace(
+            cfg, indexer_loss_coef=1e-30))
+    assert abs(loss - want) <= F32_REL * abs(want)
+    assert abs(ce - stats["ce"]) <= F32_REL * abs(ce)
+    assert float(stats["index_kl"]) > 1e-3
+    assert abs((loss - ce) - stats["index_kl"]) <= 1e-4 * stats["index_kl"]
+    assert set(want_g) == set(reference.LEAVES)
+    got_g = _checked(grads)
+    for name, g in want_g.items():
+        assert float(jnp.linalg.norm(g)) > 0, name
+        assert _rel(got_g[name], g) <= F32_REL, name
+    assert stats["rows"].shape == (2, 4)
+
+
+@pytest.mark.parametrize("control,moved", [
+    (dict(low_precision=jnp.float8_e4m3fn), "wo_last"),
+    (dict(topk=16), "wk_last"),
+    (dict(select=False), "wk_last"),
+    (dict(index_coef=0.0), "index_wq_last")],
+    ids=["float8", "half_the_keys", "no_selection", "no_indexer_loss"])
+def test_the_oracle_sees_what_the_cells_controls_change(control, moved):
+    """The four references that the cell's check must refuse are other
+    functions at this size too."""
+    cfg = KEYE_TINY
+    params, (tokens, labels) = _params(cfg), _batch(cfg)
+    want, want_g, _ = _reference(cfg, params, tokens, labels,
+                                 names=reference.CHECKED)
+    off, off_g, _ = _reference(cfg, params, tokens, labels,
+                               names=reference.CHECKED, **control)
+    assert abs(off - want) > 1e-4 * abs(want)
+    assert _rel(off_g[moved], want_g[moved]) > 0.02
+
+
+def test_each_loss_reaches_its_own_leaves_alone():
+    """The cross-entropy's gradient is zero on the indexer's three
+    matrices, and the indexer's loss's on everything else."""
+    cfg = KEYE_TINY
+    params, (tokens, labels) = _params(cfg), _batch(cfg)
+
+    def total(params, coef):
+        return tfm.loss_fn(params, tokens, labels, dataclasses.replace(
+            cfg, indexer_loss_coef=coef))
+
+    both = jax.grad(total)(params, 1.0)
+    doubled = jax.grad(total)(params, 2.0)
+    flat, _ = jax.tree_util.tree_flatten_with_path(both)
+    seen = set()
+    for (path, g1), g2 in zip(flat, jax.tree_util.tree_leaves(doubled)):
+        name = path[-1].key
+        if name in INDEX_LEAVES:
+            # All of it is the indexer's loss's: it doubles with it.
+            assert float(jnp.linalg.norm(g1)) > 0, path
+            assert _rel(g2, 2.0 * g1) <= 1e-6, path
+            seen.add(name)
+        else:
+            # None of it: the coefficient changes nothing.
+            np.testing.assert_array_equal(np.asarray(g1), np.asarray(g2))
+    assert seen == set(INDEX_LEAVES)
+
+
+@pytest.mark.parametrize("remat", ("dots", "full"))
+def test_remat_leaves_loss_and_gradients_alone(remat):
+    cfg = KEYE_TINY
+    params, (tokens, labels) = _params(cfg), _batch(cfg)
+    with jax.default_matmul_precision("highest"):
+        want, want_g = jax.value_and_grad(tfm.loss_fn)(
+            params, tokens, labels, cfg)
+        loss, grads = jax.value_and_grad(tfm.loss_fn)(
+            params, tokens, labels, cfg, remat=remat)
+    assert abs(loss - want) <= 1e-6 * abs(want)
+    for name, g in _checked(want_g).items():
+        assert _rel(_checked(grads)[name], g) <= 1e-5, name
+
+
+def test_the_heads_are_normed_one_at_a_time_and_grouped():
+    """``qk_norm_per_head`` scales a head's own 32 dims and no other
+    head's; query heads 0, 1 read key-value head 0 and 2, 3 head 1."""
+    cfg = dataclasses.replace(
+        KEYE_TINY, index_heads=0, index_head_dim=0, index_topk=0,
+        indexer_loss_coef=0.0)
+    layer = tfm.init_params(jax.random.PRNGKey(0), cfg)["layers"][0]
+    assert layer["wq"].shape == (64, 128) and layer["wo"].shape == (128, 64)
+    assert layer["wk"].shape == (64, 64)
+    assert layer["q_norm_scale"].shape == (32,)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 16, 64))
+    q, k, v, wide = tfm._qkv_proj(x, layer, cfg, None, jnp.arange(16))
+    assert q.shape == (1, 16, 4, 32) and k.shape == (1, 16, 2, 32)
+    assert wide == 128
+    # Rotation keeps a head's norm, and the norm made it sqrt(32).
+    np.testing.assert_allclose(jnp.linalg.norm(q, axis=-1), 32 ** 0.5,
+                               rtol=1e-4)
+    np.testing.assert_allclose(jnp.linalg.norm(k, axis=-1), 32 ** 0.5,
+                               rtol=1e-4)
+    # Grouping: a change to key-value head 1 moves query heads 2 and 3.
+    mask = jnp.tril(jnp.ones((1, 16, 16), jnp.int8))
+    o, _, _ = sa.attention_jnp(q, k, v, mask, 32 ** -0.5)
+    o2, _, _ = sa.attention_jnp(q, k, v.at[:, :, 1].add(1.0), mask,
+                                32 ** -0.5)
+    np.testing.assert_array_equal(o[:, :, :2], o2[:, :, :2])
+    assert float(jnp.abs(o[:, :, 2:] - o2[:, :, 2:]).min()) > 0.5
+
+
+# --- the selection -------------------------------------------------------------
+
+def _scores(seed, b=2, t=128, ties=False):
+    s = jax.random.normal(jax.random.PRNGKey(seed), (b, t, t))
+    if ties:
+        # A handful of values: every row's threshold is shared by many.
+        s = jnp.round(s * 2.0) / 2.0
+    return s
+
+
+@pytest.mark.parametrize("ties", (False, True), ids=["distinct", "tied"])
+@pytest.mark.parametrize("topk", (32, 48, 128, 200))
+def test_the_selection_is_lax_top_ks(topk, ties):
+    """The bisection kernel against ``lax.top_k`` on the same scores: rows
+    with fewer keys than ``topk`` (all of them), exactly ``topk``, and
+    more, with and without equal scores at the threshold (the lower index
+    first); the count is the closed form."""
+    scores = _scores(topk, ties=ties)
+    want = sa.select_jnp(scores, topk)
+    got = sa.select(scores, topk, True) != 0
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    rows = np.asarray(want).sum(-1)
+    np.testing.assert_array_equal(
+        rows, np.broadcast_to(np.minimum(np.arange(128) + 1, topk),
+                              rows.shape))
+    assert int(rows.sum()) == 2 * sa.keys_selected(128, topk)
+    if topk < 128:
+        # Row topk - 1 selects all its topk keys, row topk its best topk
+        # of topk + 1: by lax.top_k on that row alone.
+        row = np.asarray(scores[0, topk, :topk + 1])
+        _, best = jax.lax.top_k(jnp.asarray(row), topk)
+        assert set(np.flatnonzero(np.asarray(got)[0, topk])) == set(
+            np.asarray(best).tolist())
+
+
+def test_the_selection_orders_negative_zero_and_signs():
+    """Scores of both signs, zeros and repeated values: the bit-pattern
+    order is the numbers' order."""
+    base = jnp.asarray([0.0, -1.5, 2.0, 0.0, -0.25, 2.0, 1e-30, -1e-30])
+    scores = jnp.tile(base, (1, 128, 16))
+    want = sa.select_jnp(scores, 32)
+    got = sa.select(scores, 32, True) != 0
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# --- the kernels in the interpreter ----------------------------------------------
+
+def _operands(seed=0, b=2, t=128, h=4, hkv=2, d=32, hi=4, di=16):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    return dict(
+        q=jax.random.normal(ks[0], (b, t, h, d)),
+        k=jax.random.normal(ks[1], (b, t, hkv, d)),
+        v=jax.random.normal(ks[2], (b, t, hkv, d)),
+        qi=jax.random.normal(ks[3], (b, t, hi, di)),
+        ki=jax.random.normal(ks[4], (b, t, di)),
+        w=jax.random.normal(ks[5], (b, t, hi)),
+        ct=jax.random.normal(ks[6], (b, t, h, d)))
+
+
+@pytest.mark.parametrize("block", (32, 64, 128))
+def test_masked_attention_kernels_match_the_masked_softmax(block,
+                                                           monkeypatch):
+    """Forward, dQ, dK, dV and the head-mean probabilities of the Pallas
+    kernels against a ``jax.numpy`` masked softmax, under a selection with
+    ties; block 32 puts whole query blocks under ``topk`` (interior and
+    diagonal tiles) and others over it (masked tiles)."""
+    x = _operands()
+    scale = 32 ** -0.5
+    monkeypatch.setattr(sa, "attention_block", lambda t: block)
+    with jax.default_matmul_precision("highest"):
+        scores = jnp.round(sa.index_scores_jnp(
+            x["qi"], x["ki"], x["w"], 0.125) * 4.0) / 4.0
+        mask = sa.select(scores, 32, True)
+        mask_t = jnp.swapaxes(mask, 1, 2)
+
+        def plain(q, k, v):
+            o, _, p = sa.attention_jnp(q, k, v, mask, scale)
+            return jnp.sum(o * x["ct"]), p
+
+        def kernels(q, k, v):
+            o, lse = sa.masked_attention(q, k, v, mask, mask_t, 32, scale,
+                                         True)
+            p = sa.head_mean_probs(q, k, lse, mask, scale, True)
+            return jnp.sum(o * x["ct"]), p
+
+        args = (x["q"], x["k"], x["v"])
+        (want, want_p), want_g = jax.value_and_grad(
+            plain, argnums=(0, 1, 2), has_aux=True)(*args)
+        (got, got_p), got_g = jax.value_and_grad(
+            kernels, argnums=(0, 1, 2), has_aux=True)(*args)
+    assert abs(got - want) <= 1e-5 * abs(want)
+    np.testing.assert_allclose(got_p, want_p, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(want_p).sum(-1), 1.0, rtol=1e-5)
+    for name, g, w in zip("qkv", got_g, want_g):
+        assert _rel(g, w) <= 1e-5, name
+    classes = sa.tile_classes(128, block, block, 32)
+    assert sum(classes.values()) == (128 // block) ** 2
+    assert classes["masked"] > 0
+    assert (classes["interior"] + classes["diagonal"] > 0) == (block == 32)
+
+
+def test_indexer_kernels_match_their_jnp_form():
+    x = _operands(1)
+    causal = jnp.tril(jnp.ones((128, 128)))
+    g = jax.random.normal(jax.random.PRNGKey(9), (2, 128, 128)) * causal
+    args = (x["qi"], x["ki"], x["w"])
+    with jax.default_matmul_precision("highest"):
+        want, pull = jax.vjp(
+            lambda *a: sa.index_scores_jnp(*a, 0.125) * causal, *args)
+        got, pull_k = jax.vjp(lambda *a: sa.index_scores(*a, 0.125, True),
+                              *args)
+        # Above the diagonal the kernel's scores mean nothing.
+        np.testing.assert_allclose(got * causal, want, atol=1e-5)
+        for name, a, b in zip(INDEX_LEAVES, pull_k(g), pull(g)):
+            assert _rel(a, b) <= 1e-5, name
+
+
+def test_the_route_by_kernels_is_the_route_by_jnp(monkeypatch):
+    """``dsa_attention`` whole, kernels in the interpreter against the
+    ``jax.numpy`` forms: output, the rows' KL, and all six gradients."""
+    monkeypatch.setattr(sa, "attention_block", lambda t: 64)
+    x = _operands(2)
+    args = tuple(x[n] for n in ("q", "k", "v", "qi", "ki", "w"))
+
+    def total(kernels, *a):
+        o, kl = sa.dsa_attention(*a, topk=32, index_scale=0.125,
+                                 kernels=kernels, interpret=True)
+        return jnp.sum(o * x["ct"]) + jnp.sum(kl), kl
+
+    with jax.default_matmul_precision("highest"):
+        (want, want_kl), want_g = jax.value_and_grad(
+            lambda *a: total(False, *a), argnums=range(6),
+            has_aux=True)(*args)
+        (got, got_kl), got_g = jax.value_and_grad(
+            lambda *a: total(True, *a), argnums=range(6),
+            has_aux=True)(*args)
+    assert abs(got - want) <= 1e-5 * abs(want)
+    assert float(want_kl.min()) >= -1e-6 and float(want_kl.max()) > 0.01
+    np.testing.assert_allclose(got_kl, want_kl, atol=1e-5)
+    for name, g, w in zip(("q", "k", "v") + INDEX_LEAVES, got_g, want_g):
+        assert _rel(g, w) <= 2e-5, name
+
+
+# --- the expert share -----------------------------------------------------------
+
+def test_the_shares_add_up():
+    """What the eight chips of a deployment compute, 16 of 128 experts
+    each (here: the four shares of 2 of 8), adds up to the uncut layer:
+    the reference's with every expert in its tree."""
+    cfg = KEYE_TINY
+    k = jax.random.split(jax.random.PRNGKey(7), 5)
+    u = jax.random.normal(k[0], (2, 64, 64))
+    layer = {"router": jax.random.normal(k[1], (64, 8)) * 0.3}
+    experts = {"w_gate": jax.random.normal(k[2], (8, 64, 48)) * 0.1,
+               "w_up": jax.random.normal(k[3], (8, 64, 48)) * 0.1,
+               "w_down": jax.random.normal(k[4], (8, 48, 64)) * 0.1}
+    whole = dataclasses.replace(cfg, experts_held=0, experts_held_from=0)
+    with jax.default_matmul_precision("highest"):
+        want, rows = reference._moe_part(
+            u.reshape(-1, 64), dict(layer, **experts),
+            dict(_dims(cfg), held_from=0), None)
+        total = jnp.zeros_like(u)
+        for first in range(0, 8, 2):
+            share = dataclasses.replace(cfg, experts_held=2,
+                                        experts_held_from=first)
+            held = {n: w[first:first + 2] for n, w in experts.items()}
+            total = total + moe.moe_ffn(u, dict(layer, **held), share)[0]
+        uncut = moe.moe_ffn(u, dict(layer, **experts), whole)[0]
+    assert _rel(total.reshape(-1, 64), want) <= F32_REL
+    assert _rel(uncut.reshape(-1, 64), want) <= F32_REL
+    assert int(rows.sum()) == 128 * cfg.experts_per_token
+
+
+# --- the step ---------------------------------------------------------------------
+
+@pytest.mark.parametrize("devices", (1, 4))
+def test_train_step_takes_the_gradient_of_the_global_batch(hvd, devices):
+    """Through ``make_train_step``, on one device and on a four-device
+    data mesh, recomputed (``remat="full"``): loss = the reference's on
+    the whole batch; the momentum slot after one step from zero = the
+    reference's gradient of the **global** batch mean."""
+    from horovod_tpu.topology import build_mesh
+
+    cfg, lr = KEYE_TINY, 0.1
+    mesh = build_mesh(axes=("data",), devices=jax.devices()[:devices])
+    optimizer = optax.sgd(lr, momentum=0.9)
+    step, _, _ = tfm.make_train_step(cfg, optimizer, mesh, attention="flash",
+                                     donate=False, remat="full")
+    params = _params(cfg)
+    tokens, labels = _batch(cfg, batch=4)
+    new, opt_state, loss = step(params, optimizer.init(params), tokens,
+                                labels)
+    want, want_g, _ = jax.jit(lambda *a: _reference(cfg, *a))(
+        params, tokens, labels)
+    assert abs(loss - want) <= F32_REL * abs(want)
+    momentum = _checked(opt_state[0].trace)
+    after, before = _checked(new), _checked(params)
+    for name, g in want_g.items():
+        assert _rel(momentum[name], g) <= F32_REL, name
+        assert _rel((after[name] - before[name]) / -lr, g) <= 3e-3, name
+
+
+def test_specs_and_abstract_params_cover_every_leaf():
+    cfg = KEYE_TINY
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    specs = tfm.param_specs(cfg, None)
+    assert (jax.tree_util.tree_structure(params)
+            == jax.tree_util.tree_structure(
+                specs, is_leaf=lambda x: isinstance(
+                    x, jax.sharding.PartitionSpec)))
+    layer = params["layers"][1]
+    assert layer["index_wq"].shape == (64, 4 * 16)
+    assert layer["index_wk"].shape == (64, 16)
+    assert layer["index_ww"].shape == (64, 4)
+    assert layer["w_down"].shape == (4, 48, 64)
+    assert layer["router"].shape == (64, 8)
+    abstract = tfm.init_abstract(cfg)
+    assert (jax.tree_util.tree_map(lambda a: a.shape, abstract)
+            == jax.tree_util.tree_map(lambda a: a.shape, params))
+
+
+def test_trace_time_series_count_path_tiles_and_share(hvd, monkeypatch):
+    from horovod_tpu import telemetry
+
+    cfg = KEYE_TINY
+    telemetry.reset_for_tests()
+    telemetry.configure(True)
+    try:
+        tokens = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+        jax.eval_shape(lambda p, t: tfm.loss_fn(p, t, t, cfg),
+                       tfm.init_abstract(cfg), tokens)
+        text = telemetry.render_prometheus()
+        assert sa.keys_selected(128, 32) == 32 * 33 // 2 + 96 * 32
+        for layer in ("0", "1"):
+            assert f'hvd_moe_experts_held{{layer="{layer}"}} 4' in text, text
+        assert 'hvd_dsa_layers_total{path="jnp"} 2' in text, text
+        # Data, not static, on a share: not counted.
+        assert "hvd_moe_assignments_total" not in text
+        # The kernels' tiles, counted where a kernel is traced.
+        x = _operands()
+        mask = jnp.tril(jnp.ones((2, 128, 128), jnp.int8))
+        monkeypatch.setattr(sa, "attention_block", lambda t: 32)
+        jax.eval_shape(lambda q, k, v: sa.masked_attention(
+            q, k, v, mask, mask, 32, 1.0, True), x["q"], x["k"], x["v"])
+        text = telemetry.render_prometheus()
+        classes = sa.tile_classes(128, 32, 32, 32)
+        assert classes == {"skipped": 6, "interior": 0, "diagonal": 1,
+                           "masked": 9}
+        for name, n in classes.items():
+            assert (f'hvd_dsa_tiles_total{{class="{name}",kernel="dsa_fwd"}}'
+                    f' {4 * n}') in text, text
+    finally:
+        telemetry.reset_for_tests()
+
+
+def test_scopes_name_the_new_parts(hvd):
+    """The lowered step carries the sub-scopes the per-layer metrics read
+    (``perfbench/dsa_reduce.py``)."""
+    cfg = KEYE_TINY
+    tokens = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    text = jax.jit(lambda p, t: tfm.loss_fn(p, t, t, cfg)).lower(
+        tfm.init_abstract(cfg), tokens).as_text(debug_info=True)
+    for scope in ("layer_0/attn/qkv/qk_head_norm_rope",
+                  "layer_0/attn/qkv/dsa_index_proj",
+                  "layer_1/attn/flash_attention/dsa_index_scores",
+                  "layer_1/attn/flash_attention/dsa_select",
+                  "layer_1/attn/flash_attention/dsa_flash",
+                  "layer_1/attn/flash_attention/dsa_index_loss",
+                  "layer_1/mlp/moe_router", "layer_1/mlp/moe_experts"):
+        assert scope in text, scope
+
+
+# --- refusals: never a silent fall back ---------------------------------------------
+
+PLAIN = dict(n_experts=0, experts_per_token=0, d_expert=0,
+             norm_topk_prob=False, experts_held=0, experts_held_from=0,
+             n_kv_heads=0, head_width=0, n_heads=2, d_ff=128)
+
+
+@pytest.mark.parametrize("axis", ("model", "seq"))
+def test_model_and_sequence_axes_are_refused_by_name(hvd, axis):
+    from horovod_tpu.topology import build_mesh
+
+    mesh = build_mesh(axes=("data", axis), shape=(2, 2),
+                      devices=jax.devices()[:4])
+    # The indexer alone, without experts or grouped heads to refuse first.
+    cfg = dataclasses.replace(KEYE_TINY, **PLAIN)
+    with pytest.raises(NotImplementedError,
+                       match=f"{axis}_axis.*index_heads"):
+        tfm.make_train_step(cfg, optax.sgd(0.1), mesh,
+                            **{f"{axis}_axis": axis})
+    with pytest.raises(NotImplementedError, match=f"{axis}_axis"):
+        tfm.make_train_step(KEYE_TINY, optax.sgd(0.1), mesh,
+                            **{f"{axis}_axis": axis})
+
+
+def test_segment_ids_are_refused_by_name(hvd):
+    cfg = KEYE_TINY
+    tokens = jnp.zeros((2, 128), jnp.int32)
+    with pytest.raises(NotImplementedError,
+                       match="segment_ids.*index_topk"):
+        jax.eval_shape(lambda p: tfm.loss_fn(
+            p, tokens, tokens, cfg, segment_ids=tokens),
+            tfm.init_abstract(cfg))
+
+
+def test_decode_and_the_pipelined_builder_refuse_the_indexer_by_name(hvd):
+    from horovod_tpu.topology import build_mesh
+
+    cfg = dataclasses.replace(KEYE_TINY, **PLAIN)
+    with pytest.raises(NotImplementedError, match="decode_step.*index_heads"):
+        tfm.decode_step(tfm.init_abstract(cfg), jnp.zeros((2,), jnp.int32),
+                        tfm.init_kv_cache(cfg, 2, 8), 0, cfg)
+    mesh = build_mesh(axes=("data", "pipe"), shape=(2, 2),
+                      devices=jax.devices()[:4])
+    with pytest.raises(NotImplementedError, match="pipelined.*index_heads"):
+        tfm.make_train_step_pipelined(cfg, optax.sgd(0.1), mesh)
+    wide = dataclasses.replace(
+        cfg, index_heads=0, index_head_dim=0, index_topk=0,
+        indexer_loss_coef=0.0, qk_norm_per_head=False, head_width=48)
+    with pytest.raises(NotImplementedError, match="pipelined.*head_width"):
+        tfm.make_train_step_pipelined(wide, optax.sgd(0.1), mesh)
+
+
+@pytest.mark.parametrize("fields,error,message", [
+    (dict(index_topk=0), ValueError, "come together.*sparse attention"),
+    (dict(indexer_loss_coef=0.0), ValueError, "come together"),
+    (dict(index_head_dim=15), ValueError, "even index_head_dim"),
+    (dict(positions="none"), ValueError, "positions='rope'"),
+    (dict(qk_norm=True), ValueError, "one of them"),
+    (dict(head_width=31), ValueError, "even head_dim"),
+    (dict(q_latent_rank=8, kv_latent_rank=8, rope_dim=8),
+     NotImplementedError, "qk_norm_per_head"),
+    (dict(q_latent_rank=8, kv_latent_rank=8, rope_dim=8, n_kv_heads=0,
+          qk_norm_per_head=False), NotImplementedError,
+     "indexer beside latent attention"),
+], ids=["no_topk", "no_coef", "odd_index_dim", "no_rope", "both_norms",
+        "odd_head", "per_head_norm_on_latents", "indexer_on_latents"])
+def test_config_says_what_the_new_fields_cannot_mean(fields, error, message):
+    with pytest.raises(error, match=message):
+        dataclasses.replace(KEYE_TINY, **fields)
+
+
+def test_a_head_width_alone_is_plain_attention_with_wide_heads():
+    cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
+                                n_layers=1, d_ff=64, max_seq=16,
+                                dtype=jnp.float32, head_width=24)
+    assert cfg.head_dim == 24 and cfg.attn_width == 48
+    assert not cfg.latent_attention and not cfg.sparse_attention
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    assert params["layers"][0]["wq"].shape == (32, 48)
+    assert params["layers"][0]["wo"].shape == (48, 32)
+    tokens = jnp.arange(16)[None] % 64
+    logits = tfm.forward(params, tokens, cfg, attention="local")
+    assert logits.shape == (1, 16, 64) and bool(jnp.isfinite(logits).all())

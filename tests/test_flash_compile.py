@@ -190,3 +190,39 @@ def test_short_conv_kernels_compile_for_the_v5e(one_chip, t, channels, kw,
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     for name in (scopes.SHORT_CONV_FWD, scopes.SHORT_CONV_BWD):
         assert len(re.findall(rf"%{name}[.\d]* = ", text)) == 1, name
+
+
+# One layer's learned sparse attention of keyevl2_t16k: 32 query heads
+# over 4 key-value heads of 128, 16 indexer heads of 64 over one key head,
+# the 2048 best of up to 16384 keys a query.
+def test_sparse_attention_kernels_compile_for_the_v5e(one_chip):
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import sparse_attention as op
+    from horovod_tpu.telemetry import scopes
+
+    t = 16384
+    assert op.attention_block(t) == 512
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    operands = (shape(1, t, 32, 128), shape(1, t, 4, 128),
+                shape(1, t, 4, 128), shape(1, t, 16, 64), shape(1, t, 64),
+                shape(1, t, 16))
+
+    # The route told to take the kernels and to compile them: left to
+    # itself it reads the executing mesh, and this process's is the CPU.
+    def total(*operands):
+        o, kl = op.dsa_attention(*operands, topk=2048, index_scale=2.0 ** -5,
+                                 kernels=True, interpret=False)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(kl)
+
+    text = jax.jit(jax.grad(total, argnums=tuple(range(6)))).lower(
+        *operands).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 7
+    for name in (scopes.DSA_INDEX_FWD, scopes.DSA_INDEX_BWD,
+                 scopes.DSA_SELECT_KERNEL, scopes.DSA_FWD, scopes.DSA_BWD_DQ,
+                 scopes.DSA_BWD_DKV, scopes.DSA_PROBS):
+        assert len(re.findall(rf"%{name}[.\d]* = ", text)) == 1, name

@@ -505,8 +505,7 @@ def test_decode_and_the_pipelined_builder_refuse_latent_attention_by_name(
 @pytest.mark.parametrize("fields,error,message", [
     (dict(head_width=0), ValueError, "latent attention needs head_width"),
     (dict(rope_dim=40), ValueError, "rope_dim=40 is wider than head_width"),
-    (dict(q_latent_rank=0, kv_latent_rank=0, rope_dim=0), ValueError,
-     "a head width alone"),
+    (dict(qk_norm_per_head=True), NotImplementedError, "qk_norm_per_head"),
     (dict(rope_dim=0), ValueError, "come together"),
     (dict(positions="none"), ValueError, "positions='rope'"),
     (dict(rope_dim=7), ValueError, "even rope_dim"),
@@ -533,9 +532,10 @@ def test_config_says_what_the_new_fields_cannot_mean(fields, error, message):
 
 @pytest.mark.parametrize("fields", [
     dict(d_shared=0, routed_scale=1.0), dict(dense_layers=3),
-    dict(dense_layers=0)],
+    dict(dense_layers=0),
+    dict(q_latent_rank=0, kv_latent_rank=0, rope_dim=0)],
     ids=["softmax_router_after_a_dense_layer", "every_layer_dense",
-         "no_dense_layer"])
+         "no_dense_layer", "a_head_width_alone"])
 def test_config_takes_what_the_new_fields_can_mean(fields):
     cfg = dataclasses.replace(GLM_TINY, **fields)
     layers = tfm.init_abstract(cfg)["layers"]

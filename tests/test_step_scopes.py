@@ -462,12 +462,25 @@ def test_the_benchmark_reads_the_same_vocabulary():
     # The short convolution's two kernels run under ``gdn_conv`` and
     # ``ssm_conv`` and are booked by those parts, not by their names.
     conv_kernels = {scopes.SHORT_CONV_FWD, scopes.SHORT_CONV_BWD}
+    # Learned sparse attention's six parts (two under ``attn/qkv``, four
+    # under ``attn/flash_attention``, its route), read by
+    # ``perfbench/dsa_reduce.py``, and its seven kernels, booked by the
+    # parts they run under and named in the ``dsa_moe_lm`` adapter.
+    dsa_parts = {scopes.DSA_INDEX_PROJ, scopes.QK_HEAD_NORM_ROPE,
+                 scopes.DSA_INDEX_SCORES, scopes.DSA_SELECT,
+                 scopes.DSA_FLASH, scopes.DSA_INDEX_LOSS}
+    dsa_kernels = {scopes.DSA_INDEX_FWD, scopes.DSA_INDEX_BWD,
+                   scopes.DSA_SELECT_KERNEL, scopes.DSA_FWD,
+                   scopes.DSA_BWD_DQ, scopes.DSA_BWD_DKV, scopes.DSA_PROBS}
+    from perfbench import dsa_reduce
+    assert dsa_parts == set(dsa_reduce.DSA_PARTS)
     assert set(scope_reduce.KERNEL_NAMES) == kernels
     assert (set(scope_reduce.MODEL_SCOPES + scope_reduce.GRAD_MEAN_SCOPES
                 + scope_reduce.OPTIMIZER_SCOPES)
             == program - kernels - modules - {scopes.LAYER} - moe_parts
             - moe_kernels - gdn_parts - gdn_kernels - ssm_parts
-            - ssm_kernels - mla_parts - conv_kernels)
+            - ssm_kernels - mla_parts - conv_kernels - dsa_parts
+            - dsa_kernels)
     from perfbench import mla_reduce
     assert (mla_parts | moe_parts | {scopes.MOE_SHARED}
             == set(mla_reduce.PARTS))
