@@ -22,7 +22,9 @@ the tests hold it to and that a CPU traces in its place (:func:`path`):
     a query block's scores in VMEM, the ``topk``-th largest of each row
     found **exactly** by bisection on the float32 bit pattern (32 counting
     passes, no sort), ties admitted from the lowest index up by a second
-    bisection on the index; writes the mask;
+    bisection on the index; writes the mask and, by one more pass, the
+    log-sum-exp of each row's scores over its set (the normaliser of the
+    indexer's softmax);
 ``dsa_fwd`` / ``dsa_bwd_dq`` / ``dsa_bwd_dkv``
     flash attention under the mask.  A grid step holds one key-value head's
     K/V tile and the ``group`` query heads that read it (grouped-query
@@ -33,12 +35,19 @@ the tests hold it to and that a CPU traces in its place (:func:`path`):
     diagonal run without the mask (*interior*), those on it with it
     (*diagonal*); every other live tile is *masked*;
 ``dsa_probs``
-    the head-mean of the attention's probabilities per (query, key), from
-    the forward's saved log-sum-exp: what the indexer's loss compares with.
+    the indexer's loss a query: the head-mean of the attention's
+    probabilities per (query, key), from the forward's saved log-sum-exp,
+    a tile at a time in VMEM, and its cross-entropy against the scores
+    summed along the row.
 
-Memory: the scores, the head-mean probabilities and the scores' gradient
-are [B, T, T] float32 in HBM, the mask and its transpose [B, T, T] int8;
-nothing is [heads, T, T].
+The head-mean probabilities exist a [block_q, block_k] tile at a time, in
+the forward pass for the loss (``dsa_probs``) and in the backward pass for
+its gradient (``dsa_bwd_dq``, which has every head's probabilities of a
+tile in hand and writes the scores' cotangent beside dQ).
+
+Memory: the scores and their gradient are [B, T, T] float32 in HBM, the
+mask and its transpose [B, T, T] int8; nothing is [heads, T, T] and the
+probabilities are nowhere.
 """
 
 from __future__ import annotations
@@ -172,6 +181,17 @@ def _record_tiles(kernel: str, bh: int, t: int, block_q: int, block_k: int,
             "rows that select every earlier key), masked (the selection "
             "crosses the tile)",
             kernel=kernel, **{"class": name}).inc(bh * n)
+
+
+def _record_pass(kernel: str) -> None:
+    if telemetry.enabled():
+        telemetry.counter(
+            "hvd_dsa_probability_passes_total",
+            "Traced kernel calls that compute the head-mean of the "
+            "attention's probabilities, a tile at a time in VMEM (a call "
+            "that dead-code elimination drops afterwards, as "
+            "jax.checkpoint's recomputation drops dsa_probs, was traced "
+            "and is counted)", kernel=kernel).inc()
 
 
 def record_path(traced_path: str) -> None:
@@ -368,8 +388,10 @@ _index_scores.defvjp(_index_fwd, _index_bwd)
 # The selection
 # ---------------------------------------------------------------------------
 
-def _select_kernel(s_ref, mask_ref, key_ref, *, topk, block_q, t, chunk):
-    """Rows ``i * block_q ..`` of the mask from their scores.
+def _select_kernel(s_ref, mask_ref, lse_ref, key_ref, *, topk, block_q, t,
+                   chunk):
+    """Rows ``i * block_q ..`` of the mask from their scores, and the
+    log-sum-exp of each row's scores over the keys it selects.
 
     A float32's bit pattern, its low 31 bits flipped where the sign is
     set, orders as the number does under signed integer comparison.  The
@@ -384,6 +406,8 @@ def _select_kernel(s_ref, mask_ref, key_ref, *, topk, block_q, t, chunk):
     live_chunks = jnp.minimum((row0 + block_q + chunk - 1) // chunk, chunks)
 
     def at(c):
+        if isinstance(c, int):
+            return slice(c * chunk, (c + 1) * chunk)
         return pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
 
     def position(c):
@@ -392,23 +416,49 @@ def _select_kernel(s_ref, mask_ref, key_ref, *, topk, block_q, t, chunk):
                                                (block_q, chunk), 1)
         return row, col
 
+    def causal(c):
+        row, col = position(c)
+        return jnp.where(col <= row, s_ref[0, :, at(c)], NEG_INF)
+
+    def highest(c, high):
+        return jnp.maximum(high, jnp.max(causal(c), axis=1, keepdims=True))
+
+    lowest = jnp.full((block_q, 1), NEG_INF, jnp.float32)
+
+    def finish(selected, high):
+        """The mask, and the rows' log-sum-exp over it in one more pass,
+        from ``selected(c)``, chunk ``c``'s [block_q, chunk] selection, and
+        ``high``, each row's largest causal score: a row's best key is
+        selected whatever ``topk`` is."""
+        for c in range(chunks):
+            mask_ref[0, :, at(c)] = selected(c).astype(jnp.int32).astype(
+                jnp.int8)
+        mass = lax.fori_loop(
+            0, live_chunks,
+            lambda c, l: l + jnp.sum(jnp.where(
+                selected(c), jnp.exp(causal(c) - high), 0.0), axis=1,
+                keepdims=True),
+            jnp.zeros((block_q, 1), jnp.float32))
+        lse_ref[0, 0, :] = (high + jnp.log(mass))[:, 0]
+
     @pl.when(row0 + block_q <= topk)
     def _every_key():
-        for c in range(chunks):
+        def selected(c):
             row, col = position(c)
-            mask_ref[0, :, c * chunk:(c + 1) * chunk] = (
-                col <= row).astype(jnp.int32).astype(jnp.int8)
+            return col <= row
+
+        finish(selected, lax.fori_loop(0, live_chunks, highest, lowest))
 
     @pl.when(row0 + block_q > topk)
     def _choose():
-        def to_key(c, carry):
+        def to_key(c, high):
             row, col = position(c)
             bits = lax.bitcast_convert_type(s_ref[0, :, at(c)], jnp.int32)
             key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
             key_ref[:, at(c)] = jnp.where(col <= row, key, INT_MIN)
-            return carry
+            return highest(c, high)
 
-        lax.fori_loop(0, live_chunks, to_key, 0)
+        high = lax.fori_loop(0, live_chunks, to_key, lowest)
 
         def count(pred):
             def one(c, total):
@@ -447,13 +497,15 @@ def _select_kernel(s_ref, mask_ref, key_ref, *, topk, block_q, t, chunk):
         cut = lax.fori_loop(0, bits, index_bit,
                             jnp.zeros((block_q, 1), jnp.int32))
 
-        for c in range(chunks):
+        def selected(c):
+            # Past the live chunks ``key_ref`` holds nothing: no column
+            # there is at or before a row.
             row, col = position(c)
-            key = key_ref[:, c * chunk:(c + 1) * chunk]
+            key = key_ref[:, at(c)]
             chosen = (key > tau) | ((key == tau) & (col <= cut))
-            sel = (col <= row) & ((row < topk) | chosen)
-            mask_ref[0, :, c * chunk:(c + 1) * chunk] = sel.astype(
-                jnp.int32).astype(jnp.int8)
+            return (col <= row) & ((row < topk) | chosen)
+
+        finish(selected, high)
 
 
 @functools.cache
@@ -463,24 +515,29 @@ def _select_call(b, t, topk, block_q, chunk, interpret, vma):
     return pl.pallas_call(
         kernel, grid=(b, t // block_q),
         in_specs=[pl.BlockSpec((1, block_q, t), lambda b_, i: (b_, i, 0))],
-        out_specs=pl.BlockSpec((1, block_q, t), lambda b_, i: (b_, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, t, t), jnp.int8, vma=vma),
+        out_specs=[pl.BlockSpec((1, block_q, t), lambda b_, i: (b_, i, 0)),
+                   pl.BlockSpec((1, 1, block_q), lambda b_, i: (b_, 0, i))],
+        out_shape=[jax.ShapeDtypeStruct((b, t, t), jnp.int8, vma=vma),
+                   jax.ShapeDtypeStruct((b, 1, t), jnp.float32, vma=vma)],
         scratch_shapes=[pltpu.VMEM((block_q, t), jnp.int32)],
         compiler_params=_params("parallel", "arbitrary"),
         interpret=interpret, name=scopes.DSA_SELECT_KERNEL)
 
 
 def select(scores, topk: int, interpret: bool):
-    """The mask [B, T, T] int8 of ``scores`` [B, T, T] float32 as a Pallas
-    kernel; :func:`select_jnp` is its oracle."""
+    """``(mask [B, T, T] int8, lse [B, T] float32)`` of ``scores`` [B, T,
+    T] float32 as a Pallas kernel: the keys each query selects
+    (:func:`select_jnp` is the oracle) and the log-sum-exp of its scores
+    over them."""
     b, t, _ = scores.shape
     block_q = min(SELECT_BLOCK_Q, t)
     chunk = min(SELECT_CHUNK, t)
     if t % block_q or t % chunk:
         raise ValueError(f"sequence length {t} must be divisible by "
                          f"{block_q} and {chunk} (pad the sequence)")
-    return _select_call(b, t, topk, block_q, chunk, interpret,
-                        _out_vma(scores))(scores)
+    mask, lse = _select_call(b, t, topk, block_q, chunk, interpret,
+                             _out_vma(scores))(scores)
+    return mask, lse[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -548,31 +605,56 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, acc_ref,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   mask_ref, dq_ref, acc_ref, *, group, block_q, block_k,
-                   num_k, topk, scale):
-    qi, kj = pl.program_id(1), pl.program_id(2)
+                   mask_ref, s_ref, lse_i_ref, dkl_ref, dq_ref, g_ref,
+                   acc_ref, p_ref, *, group, hkv, block_q, block_k, num_k,
+                   topk, scale):
+    """dQ of every head of a query block, and the indexer's scores'
+    cotangent of the tile: a grid step is one key-value head's ``group``
+    query heads on one tile, the key-value head innermost, so the tile's
+    probabilities of all the heads pass through VMEM between two writes of
+    ``g_ref``: ``g = dkl x (softmax over the selection of the scores -
+    their mean)`` on the selected pairs and zero on the others."""
+    qi, kj, kv = pl.program_id(1), pl.program_id(2), pl.program_id(3)
 
-    @pl.when(kj == 0)
+    @pl.when(jnp.logical_and(kj == 0, kv == 0))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(kv == 0)
+    def _tile():
+        p_ref[...] = jnp.zeros_like(p_ref)
+        # Tiles above the diagonal stay so.
+        g_ref[0] = jnp.zeros((block_q, block_k), jnp.float32)
 
     def tile_body(use_mask):
         k, v = k_ref[0], v_ref[0]
         sel = _selected(mask_ref) if use_mask else None
+        total = jnp.zeros((block_q, block_k), jnp.float32)
         for g in range(group):
-            s = _dot(q_ref[0, g], k, _NT) * scale
+            s = _dot(q_ref[kv, g], k, _NT) * scale
             if use_mask:
                 s = jnp.where(sel, s, NEG_INF)
-            p = jnp.exp(s - lse_ref[0, g, :][:, None])
-            dp = _dot(do_ref[0, g], v, _NT)
-            ds = p * (dp - delta_ref[0, g, :][:, None])
-            acc_ref[g] += _dot(ds, k, _NN)
+            p = jnp.exp(s - lse_ref[kv, g, :][:, None])
+            total = total + p
+            dp = _dot(do_ref[kv, g], v, _NT)
+            ds = p * (dp - delta_ref[kv, g, :][:, None])
+            acc_ref[kv, g] += _dot(ds, k, _NN)
+        p_ref[...] += total
+
+        @pl.when(kv == hkv - 1)
+        def _scores_cotangent():
+            scores = s_ref[0]
+            if use_mask:
+                scores = jnp.where(sel, scores, NEG_INF)
+            soft = jnp.exp(scores - lse_i_ref[0, 0, :][:, None])
+            g_ref[0] = dkl_ref[0, 0, :][:, None] * (
+                soft - p_ref[...] * (1.0 / (hkv * group)))
 
     _by_tile_class(qi, kj, block_q, block_k, topk, tile_body)
 
     @pl.when(kj == num_k - 1)
     def _finalize():
-        dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+        dq_ref[kv] = (acc_ref[kv] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -609,13 +691,23 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _probs_kernel(q_ref, k_ref, lse_ref, mask_ref, p_ref, *, group, heads,
-                  block_q, block_k, scale):
+def _probs_kernel(q_ref, k_ref, lse_ref, mask_ref, s_ref, kl_ref, p_ref,
+                  kl_acc, *, group, hkv, block_q, block_k, num_k, scale):
+    """A query block's ``sum p log p - sum p I`` over its selection, ``p``
+    the mean over the heads of the attention's probabilities and ``I`` the
+    indexer's scores: the rows' KL less the log-sum-exp of ``I``, which is
+    not this kernel's to know (``p`` sums to one over the selection).  The
+    key-value head is innermost, so a tile's ``p`` is whole on its last
+    step and never leaves VMEM."""
     qi, kj, kv = pl.program_id(1), pl.program_id(2), pl.program_id(3)
 
-    @pl.when(kv == 0)
+    @pl.when(jnp.logical_and(kj == 0, kv == 0))
     def _init():
-        p_ref[0] = jnp.zeros((block_q, block_k), jnp.float32)
+        kl_acc[...] = jnp.zeros_like(kl_acc)
+
+    @pl.when(kv == 0)
+    def _tile():
+        p_ref[...] = jnp.zeros_like(p_ref)
 
     @pl.when(_live(qi, kj, block_q, block_k))
     def _compute():
@@ -626,7 +718,19 @@ def _probs_kernel(q_ref, k_ref, lse_ref, mask_ref, p_ref, *, group, heads,
             s = _dot(q_ref[0, g], k, _NT) * scale
             total = total + jnp.exp(jnp.where(sel, s, NEG_INF)
                                     - lse_ref[0, g, :][:, None])
-        p_ref[0] += total * (1.0 / heads)
+        p_ref[...] += total
+
+        @pl.when(kv == hkv - 1)
+        def _cross_entropy():
+            p = p_ref[...] * (1.0 / (hkv * group))
+            held = p > 0.0          # an unselected pair's p is 0
+            kl_acc[...] += jnp.sum(jnp.where(
+                held, p * (jnp.log(jnp.where(held, p, 1.0)) - s_ref[0]),
+                0.0), axis=-1, keepdims=True)
+
+    @pl.when(jnp.logical_and(kj == num_k - 1, kv == hkv - 1))
+    def _finalize():
+        kl_ref[0, 0, :] = kl_acc[...][:, 0]
 
 
 def _kv_map(block_q, block_k):
@@ -634,6 +738,25 @@ def _kv_map(block_q, block_k):
     # above the diagonal repeat its index and Mosaic elides the fetch.
     return lambda bh, i, j: (
         bh, jnp.minimum(j, ((i + 1) * block_q - 1) // block_k), 0)
+
+
+def _head_innermost_maps(hkv, block_q, block_k):
+    """Index maps of a grid (batch, query block, key block, key-value
+    head): ``rows`` [B * Hkv, G, T, D], ``stat`` [B * Hkv, G, T] and
+    ``keys`` [B * Hkv, T, D] a key-value head a step, ``tile`` [B, T, T].
+    The steps above the diagonal repeat the indices of the last one under
+    it, head included, so that nothing is fetched for them."""
+    def clamped(index):
+        def of_step(b_, i, j, h):
+            last = ((i + 1) * block_q - 1) // block_k
+            head = b_ * hkv + jnp.where(j > last, hkv - 1, h)
+            return index(head, b_, i, jnp.minimum(j, last))
+        return of_step
+
+    return {"rows": clamped(lambda head, b_, i, j: (head, 0, i, 0)),
+            "stat": clamped(lambda head, b_, i, j: (head, 0, i)),
+            "keys": clamped(lambda head, b_, i, j: (head, j, 0)),
+            "tile": clamped(lambda head, b_, i, j: (b_, i, j))}
 
 
 def _q_first(block_q, block_k):
@@ -678,26 +801,33 @@ def _fwd_call(bh, hkv, group, t, d, dtype, scale, block_q, block_k, topk,
 @functools.cache
 def _bwd_dq_call(bh, hkv, group, t, d, dtype, scale, block_q, block_k, topk,
                  interpret, vma):
-    num_k = t // block_k
-    kernel = functools.partial(_bwd_dq_kernel, group=group, block_q=block_q,
-                               block_k=block_k, num_k=num_k, topk=topk,
-                               scale=scale)
-    kv = _kv_map(block_q, block_k)
-    rows = pl.BlockSpec((1, group, block_q, d),
-                        lambda bh_, i, j: (bh_, 0, i, 0))
-    stat = pl.BlockSpec((1, group, block_q), lambda bh_, i, j: (bh_, 0, i))
+    # In VMEM at the cell's shapes (32 heads of 128, 512 x 512 tiles): q,
+    # dO and dQ of every head of a query block, 4 MiB each and twice (the
+    # pipeline's two buffers), dQ's float32 accumulators 8 MiB, the
+    # scores' tile and its cotangent's 1 MiB each and twice, the
+    # probabilities' sum 1 MiB: 39 MiB and a head's temporaries.
+    b, num_k = bh // hkv, t // block_k
+    kernel = functools.partial(_bwd_dq_kernel, group=group, hkv=hkv,
+                               block_q=block_q, block_k=block_k,
+                               num_k=num_k, topk=topk, scale=scale)
+    maps = _head_innermost_maps(hkv, block_q, block_k)
+    rows = pl.BlockSpec((hkv, group, block_q, d),
+                        lambda b_, i, j, h: (b_, 0, i, 0))
+    stat = pl.BlockSpec((hkv, group, block_q), lambda b_, i, j, h: (b_, 0, i))
+    keys = pl.BlockSpec((1, block_k, d), maps["keys"])
+    tile = pl.BlockSpec((1, block_q, block_k), maps["tile"])
+    row = pl.BlockSpec((1, 1, block_q), lambda b_, i, j, h: (b_, 0, i))
     return pl.pallas_call(
-        kernel, grid=(bh, t // block_q, num_k),
-        in_specs=[
-            rows, pl.BlockSpec((1, block_k, d), kv),
-            pl.BlockSpec((1, block_k, d), kv), rows, stat, stat,
-            pl.BlockSpec((1, block_q, block_k),
-                         lambda bh_, i, j: (bh_ // hkv, i, kv(bh_, i, j)[1])),
-        ],
-        out_specs=rows,
-        out_shape=jax.ShapeDtypeStruct((bh, group, t, d), dtype, vma=vma),
-        scratch_shapes=[pltpu.VMEM((group, block_q, d), jnp.float32)],
-        compiler_params=_params("parallel", "parallel", "arbitrary"),
+        kernel, grid=(b, t // block_q, num_k, hkv),
+        in_specs=[rows, keys, keys, rows, stat, stat, tile, tile, row, row],
+        out_specs=[rows, pl.BlockSpec((1, block_q, block_k),
+                                      lambda b_, i, j, h: (b_, i, j))],
+        out_shape=[jax.ShapeDtypeStruct((bh, group, t, d), dtype, vma=vma),
+                   jax.ShapeDtypeStruct((b, t, t), jnp.float32, vma=vma)],
+        scratch_shapes=[pltpu.VMEM((hkv, group, block_q, d), jnp.float32),
+                        pltpu.VMEM((block_q, block_k), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "arbitrary",
+                                "arbitrary"),
         interpret=interpret, name=scopes.DSA_BWD_DQ)
 
 
@@ -733,25 +863,25 @@ def _bwd_dkv_call(bh, hkv, group, t, d, dtype, scale, block_q, block_k,
 @functools.cache
 def _probs_call(b, hkv, group, t, d, scale, block_q, block_k, interpret,
                 vma):
-    kernel = functools.partial(_probs_kernel, group=group,
-                               heads=hkv * group, block_q=block_q,
-                               block_k=block_k, scale=scale)
+    num_k = t // block_k
+    kernel = functools.partial(_probs_kernel, group=group, hkv=hkv,
+                               block_q=block_q, block_k=block_k,
+                               num_k=num_k, scale=scale)
+    maps = _head_innermost_maps(hkv, block_q, block_k)
+    tile = pl.BlockSpec((1, block_q, block_k), maps["tile"])
     return pl.pallas_call(
-        kernel, grid=(b, t // block_q, t // block_k, hkv),
+        kernel, grid=(b, t // block_q, num_k, hkv),
         in_specs=[
-            pl.BlockSpec((1, group, block_q, d),
-                         lambda b_, i, j, h: (b_ * hkv + h, 0, i, 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda b_, i, j, h: (b_ * hkv + h, j, 0)),
-            pl.BlockSpec((1, group, block_q),
-                         lambda b_, i, j, h: (b_ * hkv + h, 0, i)),
-            pl.BlockSpec((1, block_q, block_k),
-                         lambda b_, i, j, h: (b_, i, j)),
+            pl.BlockSpec((1, group, block_q, d), maps["rows"]),
+            pl.BlockSpec((1, block_k, d), maps["keys"]),
+            pl.BlockSpec((1, group, block_q), maps["stat"]), tile, tile,
         ],
-        out_specs=pl.BlockSpec((1, block_q, block_k),
-                               lambda b_, i, j, h: (b_, i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, t, t), jnp.float32, vma=vma),
-        compiler_params=_params("parallel", "parallel", "parallel",
+        out_specs=pl.BlockSpec((1, 1, block_q),
+                               lambda b_, i, j, h: (b_, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, t), jnp.float32, vma=vma),
+        scratch_shapes=[pltpu.VMEM((block_q, block_k), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "arbitrary",
                                 "arbitrary"),
         interpret=interpret, name=scopes.DSA_PROBS)
 
@@ -780,19 +910,30 @@ def _unfold_kv(x, b):
     return x.reshape(b, bh // b, t, d).transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def masked_attention(q, k, v, mask, mask_t, topk: int, scale: float,
-                     interpret: bool):
-    """Softmax attention over the keys ``mask`` [B, T, T] int8 selects, by
-    the Pallas kernels: q [B, T, H, D], k/v [B, T, Hkv, D], ``mask_t`` the
-    mask's transpose (the dK/dV kernel reads it keys by rows); rows under
-    ``topk`` select every earlier key.  Returns ``(o [B, T, H, D], lse [B
-    * Hkv, G, T])``; ``lse`` carries no gradient (:func:`head_mean_probs`
-    reads it under ``stop_gradient``)."""
-    return _masked_fwd(q, k, v, mask, mask_t, topk, scale, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def masked_attention(q, k, v, scores, lse_i, mask, mask_t, topk: int,
+                     scale: float, interpret: bool):
+    """Softmax attention over the keys ``mask`` [B, T, T] int8 selects and
+    the indexer's loss a query, by the Pallas kernels: q [B, T, H, D], k/v
+    [B, T, Hkv, D]; ``scores`` [B, T, T] float32 the indexer's, ``lse_i``
+    [B, T] their log-sum-exp over each row's selection (:func:`select`'s
+    second result; a constant here); ``mask_t`` the mask's transpose (the
+    dK/dV kernel reads it keys by rows); rows under ``topk`` select every
+    earlier key.  Returns ``(o [B, T, H, D], kl [B, T])``, ``kl[t] =
+    KL(mean over the heads of the attention's probabilities || softmax
+    over the selection of scores[t, .])``.
+
+    One gradient rule for the pair: ``o``'s cotangent reaches q, k and v
+    alone and ``kl``'s the scores alone, as ``dkl x (softmax - mean
+    probabilities)`` on the selected pairs and zero on every other (the
+    attention's probabilities are a constant of the loss, by this rule and
+    not by ``stop_gradient``)."""
+    return _masked_fwd(q, k, v, scores, lse_i, mask, mask_t, topk, scale,
+                       interpret)[0]
 
 
-def _masked_fwd(q, k, v, mask, mask_t, topk, scale, interpret):
+def _masked_fwd(q, k, v, scores, lse_i, mask, mask_t, topk, scale,
+                interpret):
     b, t, h, d = q.shape
     hkv = k.shape[2]
     group = h // hkv
@@ -802,14 +943,23 @@ def _masked_fwd(q, k, v, mask, mask_t, topk, scale, interpret):
     _record_tiles(scopes.DSA_FWD, b * hkv, t, block, block, topk)
     of, lse = _fwd_call(b * hkv, hkv, group, t, d, q.dtype, scale, block,
                         block, topk, interpret, _out_vma(*operands))(*operands)
-    return ((_unfold_q(of, b), lse),
-            (qf, kf, vf, of, lse, mask, mask_t, b, hkv))
+    with jax.named_scope(scopes.DSA_INDEX_LOSS):
+        # Nothing the backward pass needs comes out of this call: under
+        # jax.checkpoint the recomputed forward holds it as dead code.
+        operands = (qf, kf, lse, mask, scores)
+        _record_pass(scopes.DSA_PROBS)
+        kl = _probs_call(b, hkv, group, t, d, scale, block, block, interpret,
+                         _out_vma(*operands))(*operands)[:, 0] + lse_i
+    return ((_unfold_q(of, b), kl),
+            (qf, kf, vf, of, lse, scores, lse_i, mask, mask_t))
 
 
 def _masked_bwd(topk, scale, interpret, res, cotangents):
-    do, _ = cotangents
-    qf, kf, vf, of, lse, mask, mask_t, b, hkv = res
+    do, dkl = cotangents
+    qf, kf, vf, of, lse, scores, lse_i, mask, mask_t = res
     bh, group, t, d = qf.shape
+    b = mask.shape[0]
+    hkv = bh // b
     block = attention_block(t)
     dof = _fold_q(do, hkv)
     delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32),
@@ -817,35 +967,39 @@ def _masked_bwd(topk, scale, interpret, res, cotangents):
     config = (bh, hkv, group, t, d, qf.dtype, scale, block, block, topk,
               interpret)
     operands = (qf, kf, vf, dof, lse, delta)
+    loss = (mask, scores, lse_i[:, None], dkl.astype(jnp.float32)[:, None])
     _record_tiles(scopes.DSA_BWD_DQ, bh, t, block, block, topk)
-    dq = _bwd_dq_call(*config, _out_vma(*operands, mask))(*operands, mask)
+    _record_pass(scopes.DSA_BWD_DQ)
+    dq, g = _bwd_dq_call(*config, _out_vma(*operands, *loss))(*operands,
+                                                              *loss)
     _record_tiles(scopes.DSA_BWD_DKV, bh, t, block, block, topk)
     dk, dv = _bwd_dkv_call(*config, _out_vma(*operands, mask_t))(
         *operands, mask_t)
     nothing = np.zeros(mask.shape, jax.dtypes.float0)
-    return (_unfold_q(dq, b), _unfold_kv(dk, b), _unfold_kv(dv, b), nothing,
-            nothing)
+    return (_unfold_q(dq, b), _unfold_kv(dk, b), _unfold_kv(dv, b), g,
+            jnp.zeros_like(lse_i), nothing, nothing)
 
 
 masked_attention.defvjp(_masked_fwd, _masked_bwd)
 
 
-def head_mean_probs(q, k, lse, mask, scale: float, interpret: bool):
-    """[B, T, T] float32: the mean over the heads of the attention's
-    probabilities under ``mask``, from the forward's ``lse``.  It carries
-    no gradient (the indexer's loss holds its target fixed)."""
-    q, k, lse = lax.stop_gradient((q, k, lse))
-    b, t, h, d = q.shape
-    hkv = k.shape[2]
-    block = attention_block(t)
-    operands = (_fold_q(q, hkv), _fold_kv(k), lse, mask)
-    return _probs_call(b, hkv, h // hkv, t, d, scale, block, block,
-                       interpret, _out_vma(*operands))(*operands)
-
-
 # ---------------------------------------------------------------------------
 # The layer's route
 # ---------------------------------------------------------------------------
+
+def _selection(qi, ki, w, topk, index_scale, kernels, interpret):
+    """``(scores, mask, lse)``: :func:`indexer_selection`'s pair and, by
+    the kernels, the log-sum-exp of each row's scores over its selection
+    (None by the ``jax.numpy`` forms, whose loss finds it for itself)."""
+    with jax.named_scope(scopes.DSA_INDEX_SCORES):
+        scores = (index_scores(qi, ki, w, index_scale, interpret) if kernels
+                  else index_scores_jnp(qi, ki, w, index_scale))
+    with jax.named_scope(scopes.DSA_SELECT):
+        held = lax.stop_gradient(scores)
+        if kernels:
+            return (scores,) + select(held, topk, interpret)
+        return scores, select_jnp(held, topk), None
+
 
 def indexer_selection(qi, ki, w, *, topk: int, index_scale: float,
                       kernels: Optional[bool] = None,
@@ -858,13 +1012,7 @@ def indexer_selection(qi, ki, w, *, topk: int, index_scale: float,
         kernels = path(qi) == "kernel"
     if interpret is None:
         interpret = _interpret_default(qi)
-    with jax.named_scope(scopes.DSA_INDEX_SCORES):
-        scores = (index_scores(qi, ki, w, index_scale, interpret) if kernels
-                  else index_scores_jnp(qi, ki, w, index_scale))
-    with jax.named_scope(scopes.DSA_SELECT):
-        held = lax.stop_gradient(scores)
-        return scores, (select(held, topk, interpret) if kernels
-                        else select_jnp(held, topk))
+    return _selection(qi, ki, w, topk, index_scale, kernels, interpret)[:2]
 
 
 def dsa_attention(q, k, v, qi, ki, w, *, topk: int, index_scale: float,
@@ -884,9 +1032,8 @@ def dsa_attention(q, k, v, qi, ki, w, *, topk: int, index_scale: float,
     if kernels is None:
         kernels = path(q) == "kernel"
     interp = _interpret_default(q) if interpret is None else interpret
-    scores, mask = indexer_selection(qi, ki, w, topk=topk,
-                                     index_scale=index_scale,
-                                     kernels=kernels, interpret=interp)
+    scores, mask, lse_i = _selection(qi, ki, w, topk, index_scale, kernels,
+                                     interp)
     if not kernels:
         with jax.named_scope(scopes.DSA_FLASH):
             o, _, p = attention_jnp(q, k, v, mask, scale)
@@ -895,7 +1042,5 @@ def dsa_attention(q, k, v, qi, ki, w, *, topk: int, index_scale: float,
     with jax.named_scope(scopes.DSA_SELECT):
         mask_t = jnp.swapaxes(mask, 1, 2)
     with jax.named_scope(scopes.DSA_FLASH):
-        o, lse = masked_attention(q, k, v, mask, mask_t, topk, scale, interp)
-    with jax.named_scope(scopes.DSA_INDEX_LOSS):
-        p = head_mean_probs(q, k, lse, mask, scale, interp)
-        return o, indexer_kl(scores, mask, p)
+        return masked_attention(q, k, v, scores, lse_i, mask, mask_t, topk,
+                                scale, interp)

@@ -77,7 +77,9 @@ MLA_ROPE = "mla_rope"
 # opens under ATTN_FLASH (it is the flash kernels' work under a mask, and
 # a reader that knows only the model scopes books it there): the indexer's
 # scores, the selection (mask and its transpose), the attention kernels
-# under the mask, and the indexer's loss (head-mean probabilities, KL).
+# under the mask (with the loss's gradient, which the dQ kernel writes),
+# and the indexer's loss (the rows' KL against the head-mean
+# probabilities, forward).
 DSA_INDEX_PROJ = "dsa_index_proj"
 QK_HEAD_NORM_ROPE = "qk_head_norm_rope"
 DSA_INDEX_SCORES = "dsa_index_scores"
@@ -131,7 +133,8 @@ SHORT_CONV_BWD = "short_conv_bwd"
 
 # The seven sparse-attention Pallas kernels (ops/sparse_attention.py): the
 # indexer's scores and their gradient, the selection, attention under the
-# mask (forward, dQ, dK+dV) and the head-mean probabilities.
+# mask (forward, dQ with the scores' cotangent, dK+dV) and the rows' KL
+# against the head-mean probabilities.
 DSA_INDEX_FWD = "dsa_index_fwd"
 DSA_INDEX_BWD = "dsa_index_bwd"
 DSA_SELECT_KERNEL = "dsa_select_rows"

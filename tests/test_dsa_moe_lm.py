@@ -215,8 +215,13 @@ def test_the_selection_is_lax_top_ks(topk, ties):
     first); the count is the closed form."""
     scores = _scores(topk, ties=ties)
     want = sa.select_jnp(scores, topk)
-    got = sa.select(scores, topk, True) != 0
+    mask, lse = sa.select(scores, topk, True)
+    got = mask != 0
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # The rows' log-sum-exp over their own selection, by the same kernel.
+    np.testing.assert_allclose(
+        lse, jax.nn.logsumexp(jnp.where(want, scores, -jnp.inf), axis=-1),
+        rtol=1e-6)
     rows = np.asarray(want).sum(-1)
     np.testing.assert_array_equal(
         rows, np.broadcast_to(np.minimum(np.arange(128) + 1, topk),
@@ -237,7 +242,7 @@ def test_the_selection_orders_negative_zero_and_signs():
     base = jnp.asarray([0.0, -1.5, 2.0, 0.0, -0.25, 2.0, 1e-30, -1e-30])
     scores = jnp.tile(base, (1, 128, 16))
     want = sa.select_jnp(scores, 32)
-    got = sa.select(scores, 32, True) != 0
+    got = sa.select(scores, 32, True)[0] != 0
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -255,46 +260,90 @@ def _operands(seed=0, b=2, t=128, h=4, hkv=2, d=32, hi=4, di=16):
         ct=jax.random.normal(ks[6], (b, t, h, d)))
 
 
+def _tied_selection(x, topk=32):
+    """Scores with ties at every row's threshold, the kernel's selection
+    by them and the rows' log-sum-exp over it."""
+    scores = jnp.round(sa.index_scores_jnp(
+        x["qi"], x["ki"], x["w"], 0.125) * 4.0) / 4.0
+    mask, lse_i = sa.select(scores, topk, True)
+    return scores, mask, lse_i
+
+
 @pytest.mark.parametrize("block", (32, 64, 128))
 def test_masked_attention_kernels_match_the_masked_softmax(block,
                                                            monkeypatch):
-    """Forward, dQ, dK, dV and the head-mean probabilities of the Pallas
-    kernels against a ``jax.numpy`` masked softmax, under a selection with
-    ties; block 32 puts whole query blocks under ``topk`` (interior and
-    diagonal tiles) and others over it (masked tiles)."""
+    """Forward, dQ, dK, dV, the rows' KL against the head-mean
+    probabilities and the scores' cotangent of the Pallas kernels against
+    a ``jax.numpy`` masked softmax and :func:`indexer_kl`, under a
+    selection with ties; block 32 puts whole query blocks under ``topk``
+    (interior and diagonal tiles) and others over it (masked tiles)."""
     x = _operands()
     scale = 32 ** -0.5
+    # A weight a row: the loss's cotangent is not the same for every query.
+    rows = jax.random.uniform(jax.random.PRNGKey(3), (2, 128)) + 0.5
     monkeypatch.setattr(sa, "attention_block", lambda t: block)
     with jax.default_matmul_precision("highest"):
-        scores = jnp.round(sa.index_scores_jnp(
-            x["qi"], x["ki"], x["w"], 0.125) * 4.0) / 4.0
-        mask = sa.select(scores, 32, True)
+        scores, mask, lse_i = _tied_selection(x)
         mask_t = jnp.swapaxes(mask, 1, 2)
 
-        def plain(q, k, v):
+        def plain(q, k, v, scores):
             o, _, p = sa.attention_jnp(q, k, v, mask, scale)
-            return jnp.sum(o * x["ct"]), p
+            kl = sa.indexer_kl(scores, mask, jax.lax.stop_gradient(p))
+            return jnp.sum(o * x["ct"]) + jnp.sum(kl * rows), (p, kl)
 
-        def kernels(q, k, v):
-            o, lse = sa.masked_attention(q, k, v, mask, mask_t, 32, scale,
-                                         True)
-            p = sa.head_mean_probs(q, k, lse, mask, scale, True)
-            return jnp.sum(o * x["ct"]), p
+        def kernels(q, k, v, scores):
+            o, kl = sa.masked_attention(q, k, v, scores, lse_i, mask,
+                                        mask_t, 32, scale, True)
+            return jnp.sum(o * x["ct"]) + jnp.sum(kl * rows), kl
 
-        args = (x["q"], x["k"], x["v"])
-        (want, want_p), want_g = jax.value_and_grad(
-            plain, argnums=(0, 1, 2), has_aux=True)(*args)
-        (got, got_p), got_g = jax.value_and_grad(
-            kernels, argnums=(0, 1, 2), has_aux=True)(*args)
+        args = (x["q"], x["k"], x["v"], scores)
+        (want, (want_p, want_kl)), want_g = jax.value_and_grad(
+            plain, argnums=(0, 1, 2, 3), has_aux=True)(*args)
+        (got, got_kl), got_g = jax.value_and_grad(
+            kernels, argnums=(0, 1, 2, 3), has_aux=True)(*args)
     assert abs(got - want) <= 1e-5 * abs(want)
-    np.testing.assert_allclose(got_p, want_p, atol=1e-6)
     np.testing.assert_allclose(np.asarray(want_p).sum(-1), 1.0, rtol=1e-5)
-    for name, g, w in zip("qkv", got_g, want_g):
+    assert float(want_kl.max()) > 0.01
+    np.testing.assert_allclose(got_kl, want_kl, atol=1e-5)
+    for name, g, w in zip(("q", "k", "v", "scores"), got_g, want_g):
         assert _rel(g, w) <= 1e-5, name
+    # The scores' cotangent: nothing off the selection, so nothing above
+    # the diagonal, which index_scores demands of it.
+    off = np.asarray(mask) == 0
+    assert float(np.abs(np.asarray(got_g[3]))[off].max()) == 0.0
+    assert off[:, np.triu_indices(128, 1)[0], np.triu_indices(128, 1)[1]].all()
     classes = sa.tile_classes(128, block, block, 32)
     assert sum(classes.values()) == (128 // block) ** 2
     assert classes["masked"] > 0
     assert (classes["interior"] + classes["diagonal"] > 0) == (block == 32)
+
+
+@pytest.mark.parametrize("topk", (32, 48, 200))
+@pytest.mark.parametrize("block", (32, 128))
+def test_the_rows_kl_and_its_gradient_are_indexer_kls(block, topk,
+                                                      monkeypatch):
+    """The loss alone, query blocks under and over ``topk`` and a ``topk``
+    no row reaches: the rows' KL of ``dsa_probs`` (with the select
+    kernel's normaliser) and the cotangent ``dsa_bwd_dq`` writes, against
+    :func:`indexer_kl` and its ``jax.grad`` on the oracle's
+    probabilities."""
+    x = _operands(3)
+    scale = 32 ** -0.5
+    rows = jax.random.uniform(jax.random.PRNGKey(4), (2, 128)) + 0.5
+    monkeypatch.setattr(sa, "attention_block", lambda t: block)
+    with jax.default_matmul_precision("highest"):
+        scores, mask, lse_i = _tied_selection(x, topk)
+        _, _, p = sa.attention_jnp(x["q"], x["k"], x["v"], mask, scale)
+        want_kl, pull = jax.vjp(lambda s: sa.indexer_kl(s, mask, p), scores)
+        got_kl, pull_k = jax.vjp(
+            lambda s: sa.masked_attention(
+                x["q"], x["k"], x["v"], s, lse_i, mask,
+                jnp.swapaxes(mask, 1, 2), topk, scale, True)[1], scores)
+        (want_g,), (got_g,) = pull(rows), pull_k(rows)
+    np.testing.assert_allclose(got_kl, want_kl, atol=1e-5)
+    assert float(jnp.linalg.norm(want_g)) > 0.1
+    assert _rel(got_g, want_g) <= 1e-5
+    assert float(jnp.abs(jnp.where(mask == 0, got_g, 0.0)).max()) == 0.0
 
 
 def test_indexer_kernels_match_their_jnp_form():
@@ -337,6 +386,55 @@ def test_the_route_by_kernels_is_the_route_by_jnp(monkeypatch):
     np.testing.assert_allclose(got_kl, want_kl, atol=1e-5)
     for name, g, w in zip(("q", "k", "v") + INDEX_LEAVES, got_g, want_g):
         assert _rel(g, w) <= 2e-5, name
+
+
+def _kernel_calls(jaxpr, found):
+    """Every ``pallas_call`` of ``jaxpr`` and of the jaxprs inside it by
+    name, and under ``"float32 [B, T, T]"`` the primitives of the
+    equations that produce such an array."""
+    from jax._src import core
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            found[name] = found.get(name, 0) + 1
+        else:
+            for sub in core.jaxprs_in_params(eqn.params):
+                _kernel_calls(sub, found)
+        for var in eqn.outvars:
+            shape = getattr(var.aval, "shape", ())
+            if (len(shape) == 3 and shape[1] == shape[2] == 256
+                    and var.aval.dtype == jnp.float32):
+                found.setdefault("float32 [B, T, T]", set()).add(
+                    eqn.params.get("name", eqn.primitive.name))
+    return found
+
+
+def test_the_recomputed_layer_reads_the_probabilities_once_a_direction(
+        monkeypatch):
+    """The gradient of the model under ``remat`` ``full``, the kernels
+    traced in place of their ``jax.numpy`` forms: a layer's recomputation
+    runs the scores, the selection and the masked forward again and **not**
+    the indexer's loss (nothing the backward pass reads comes out of
+    ``dsa_probs``; the gradient's probabilities are the dQ kernel's), and
+    the only float32 [B, T, T] arrays of the program are the scores and
+    their gradient: no probabilities, and no pass of XLA's over either."""
+    # 256 tokens: no other array of the model is [B, 256, 256].
+    cfg = dataclasses.replace(KEYE_TINY, max_seq=256)
+    monkeypatch.setattr(sa, "path", lambda x: "kernel")
+    tokens = jax.ShapeDtypeStruct((2, 256), jnp.int32)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        lambda p, t: tfm.loss_fn(p, t, t, cfg, remat="full")))(
+            tfm.init_abstract(cfg), tokens)
+    found = _kernel_calls(jaxpr.jaxpr, {})
+    layers = cfg.n_layers
+    assert found.pop("float32 [B, T, T]") == {
+        "dsa_index_fwd", "dsa_bwd_dq", "stop_gradient"}
+    found = {k: n for k, n in found.items() if k.startswith("dsa_")}
+    assert found == {"dsa_index_fwd": 2 * layers,
+                     "dsa_select_rows": 2 * layers, "dsa_fwd": 2 * layers,
+                     "dsa_probs": layers, "dsa_bwd_dq": layers,
+                     "dsa_bwd_dkv": layers, "dsa_index_bwd": layers}
 
 
 # --- the expert share -----------------------------------------------------------
@@ -438,8 +536,11 @@ def test_trace_time_series_count_path_tiles_and_share(hvd, monkeypatch):
         x = _operands()
         mask = jnp.tril(jnp.ones((2, 128, 128), jnp.int8))
         monkeypatch.setattr(sa, "attention_block", lambda t: 32)
-        jax.eval_shape(lambda q, k, v: sa.masked_attention(
-            q, k, v, mask, mask, 32, 1.0, True), x["q"], x["k"], x["v"])
+        scores = jnp.zeros((2, 128, 128))
+        jax.eval_shape(lambda q, k, v: jax.vjp(
+            lambda *a: sa.masked_attention(
+                *a, scores, scores[:, 0], mask, mask, 32, 1.0, True),
+            q, k, v)[1]((q, scores[:, 0])), x["q"], x["k"], x["v"])
         text = telemetry.render_prometheus()
         classes = sa.tile_classes(128, 32, 32, 32)
         assert classes == {"skipped": 6, "interior": 0, "diagonal": 1,
@@ -447,6 +548,10 @@ def test_trace_time_series_count_path_tiles_and_share(hvd, monkeypatch):
         for name, n in classes.items():
             assert (f'hvd_dsa_tiles_total{{class="{name}",kernel="dsa_fwd"}}'
                     f' {4 * n}') in text, text
+        # One pass over the head-mean probabilities a direction.
+        for kernel in ("dsa_probs", "dsa_bwd_dq"):
+            assert (f'hvd_dsa_probability_passes_total{{kernel="{kernel}"}}'
+                    f' 1') in text, text
     finally:
         telemetry.reset_for_tests()
 

@@ -194,8 +194,13 @@ def test_short_conv_kernels_compile_for_the_v5e(one_chip, t, channels, kw,
 
 # One layer's learned sparse attention of keyevl2_t16k: 32 query heads
 # over 4 key-value heads of 128, 16 indexer heads of 64 over one key head,
-# the 2048 best of up to 16384 keys a query.
-def test_sparse_attention_kernels_compile_for_the_v5e(one_chip):
+# the 2048 best of up to 16384 keys a query; as it stands and under
+# ``jax.checkpoint`` (the cell's ``remat`` ``full``), which runs every
+# forward kernel again but the indexer's loss's: nothing the backward pass
+# reads comes out of ``dsa_probs``.
+@pytest.mark.parametrize("recomputed", [False, True],
+                         ids=["plain", "checkpoint"])
+def test_sparse_attention_kernels_compile_for_the_v5e(one_chip, recomputed):
     import jax
     import jax.numpy as jnp
 
@@ -219,10 +224,27 @@ def test_sparse_attention_kernels_compile_for_the_v5e(one_chip):
                                  kernels=True, interpret=False)
         return jnp.sum(o.astype(jnp.float32)) + jnp.sum(kl)
 
-    text = jax.jit(jax.grad(total, argnums=tuple(range(6)))).lower(
+    if recomputed:
+        total = jax.checkpoint(total)
+    text = jax.jit(jax.value_and_grad(total, argnums=tuple(range(6)))).lower(
         *operands).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 7
-    for name in (scopes.DSA_INDEX_FWD, scopes.DSA_INDEX_BWD,
-                 scopes.DSA_SELECT_KERNEL, scopes.DSA_FWD, scopes.DSA_BWD_DQ,
-                 scopes.DSA_BWD_DKV, scopes.DSA_PROBS):
-        assert len(re.findall(rf"%{name}[.\d]* = ", text)) == 1, name
+    again = 2 if recomputed else 1
+    calls = {scopes.DSA_INDEX_FWD: again, scopes.DSA_SELECT_KERNEL: again,
+             scopes.DSA_FWD: again, scopes.DSA_PROBS: 1,
+             scopes.DSA_INDEX_BWD: 1, scopes.DSA_BWD_DQ: 1,
+             scopes.DSA_BWD_DKV: 1}
+    assert (text.count('custom_call_target="tpu_custom_call"')
+            == sum(calls.values()))
+    for name, times in calls.items():
+        assert len(re.findall(rf"%{name}[.\d]* = ", text)) == times, name
+    # The only [T, T] float32 arrays are the scores and their gradient:
+    # no instruction reads or writes one but the kernels (and the pick of
+    # dQ's second result).
+    touching = re.findall(rf"%([\w.\-]+) = [^\n]*f32\[1,{t},{t}\]", text)
+    assert {name.rsplit(".", 1)[0] for name in touching} == {
+        scopes.DSA_INDEX_FWD, scopes.DSA_SELECT_KERNEL, scopes.DSA_PROBS,
+        scopes.DSA_BWD_DQ, scopes.DSA_INDEX_BWD, "pallas_call"}, touching
+    picks = [line for line in text.splitlines()
+             if re.match(r"\s*%pallas_call[.\d]* = ", line)
+             and f"f32[1,{t},{t}]" in line]
+    assert all(" get-tuple-element(" in line for line in picks), picks
