@@ -56,6 +56,15 @@ recurrence's kernels read them; else :func:`causal_conv` and what follows
 it as ``jax.numpy``, the kernels' oracle in the tests.
 ``hvd_short_conv_rows_total{path}`` says which was traced.
 
+**The gated norm** between the recurrence and the out projection is one
+pass likewise: the Pallas kernels of :mod:`horovod_tpu.ops.gated_norm`
+wherever they can run (:func:`norm_path`), which read ``o`` as the
+recurrence left it (head-major from its kernels behind the convolution's,
+and then no transpose of ``o`` is made, forward or backward) and ``z``
+once and write the out projection's operand token-major once; else
+:func:`gated_norm` below, the same lines as ``jax.numpy``.
+``hvd_gated_norm_rows_total{path}`` says which was traced.
+
 Precision, of both: ``g``, its running sums, the decays, ``N``, the
 inverse (its matmuls at precision ``highest``) and the carried state are
 float32; every other matmul takes operands in the model dtype (``T``,
@@ -88,6 +97,7 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu import telemetry
 from horovod_tpu.ops import gated_delta_rule as kernels
+from horovod_tpu.ops import gated_norm as norm_kernels
 from horovod_tpu.ops import short_conv
 from horovod_tpu.ops.gated_delta_rule import BLOCK
 from horovod_tpu.parallel._vma import pin_to, vma_of
@@ -290,6 +300,37 @@ def conv_path(x, cfg) -> str:
                          channels=h * d) for d in (dk, dv)) else "xla"
 
 
+def _o_head_major(x, cfg) -> bool:
+    """Whether the recurrence leaves ``o`` head-major [B * H, T, d_v]:
+    from its kernels on the operands the convolution's kernels wrote."""
+    return conv_path(x, cfg) == "kernel" and recurrence_path(x) == "kernel"
+
+
+def gated_norm(o, z, scale, eps: float):
+    """``RMSNorm(o) * silu(z)`` of the module's docstring as
+    ``jax.numpy``: ``o`` [B, T, H, d_v] and ``z`` [B, T, H d_v] in the
+    model dtype, ``scale`` [d_v] -> [B, T, H d_v] in that dtype, float32
+    to the one rounding.  The oracle of
+    :mod:`horovod_tpu.ops.gated_norm`'s norm-first form and what runs
+    where its kernels cannot (:func:`norm_path`)."""
+    o = o.astype(jnp.float32)
+    o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps) * scale
+    return (o.reshape(z.shape)
+            * jax.nn.silu(z.astype(jnp.float32))).astype(z.dtype)
+
+
+def norm_path(x, cfg) -> str:
+    """What runs the gated norm of a layer of ``cfg`` over the
+    recurrence's output for ``x`` [B, T, d], read as
+    :func:`recurrence_path` reads its: ``"kernel"``, the Pallas kernels of
+    :mod:`horovod_tpu.ops.gated_norm` (on ``o`` head-major or token-major,
+    as the recurrence leaves it); ``"xla"``, :func:`gated_norm`, where
+    they cannot run (``gated_norm.takes``)."""
+    h, dv = cfg.linear_value_heads, cfg.linear_value_head_dim
+    return "kernel" if norm_kernels.takes(
+        x, dv, width=h * dv, head_major=_o_head_major(x, cfg)) else "xla"
+
+
 def saved_state_bytes(batch: int, t: int, cfg) -> int:
     """Bytes of block states the backward of one layer's recurrence
     keeps: the float32 state at the start of each block (the block is
@@ -343,23 +384,30 @@ def mixer(x, layer, cfg):
                      * dk ** -0.5).astype(dt)
                 k = _l2norm(k.reshape(bsz, t, h, dk)).astype(dt)
                 v = v.reshape(bsz, t, h, dv).astype(dt)
+    # ``o`` stays as the recurrence's kernels leave it, [B * H, T, d_v],
+    # where the norm's kernels read it.
+    o_head_major = _o_head_major(x, cfg)
+    norm_kernel = norm_path(x, cfg) == "kernel"
     with jax.named_scope(scopes.ATTN_GDN_SCAN):
         if recurrence_path(x) != "kernel":
             if head_major:
                 q, k, v = (kernels.token_major(a, bsz) for a in (q, k, v))
             o = gated_delta_rule(q, k, v, g, beta, dt)
         elif head_major:
-            o = kernels.token_major(
-                kernels.gated_delta_rule_head_major(q, k, v, g, beta), bsz)
+            o = kernels.gated_delta_rule_head_major(q, k, v, g, beta)
+            if not norm_kernel:
+                o = kernels.token_major(o, bsz)
         else:
             o = kernels.gated_delta_rule(q, k, v, g, beta)
     with jax.named_scope(scopes.ATTN_OUT):
         with jax.named_scope(scopes.GDN_GATE_NORM):
-            o = o.astype(jnp.float32)
-            o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
-                              + cfg.norm_eps) * layer["lin_norm_scale"]
-            o = (o.reshape(bsz, t, h * dv)
-                 * jax.nn.silu(z.astype(jnp.float32))).astype(dt)
+            if norm_kernel:
+                o = norm_kernels.gated_norm(
+                    o if o_head_major else o.reshape(bsz, t, h * dv), z,
+                    layer["lin_norm_scale"], group=dv, gate_first=False,
+                    head_major=o_head_major, eps=cfg.norm_eps)
+            else:
+                o = gated_norm(o, z, layer["lin_norm_scale"], cfg.norm_eps)
         with jax.named_scope(scopes.GDN_OUT):
             return o @ layer["lin_wo"].astype(dt)
 
@@ -388,3 +436,4 @@ def record_blocks(layer: int, x, cfg) -> None:
         "them)",
         layer=str(layer)).set(saved_state_bytes(batch, t, cfg))
     short_conv.record_rows(layer, 3 * batch * t, conv_path(x, cfg))
+    norm_kernels.record_rows(layer, batch * t, norm_path(x, cfg))

@@ -55,6 +55,13 @@ to the one rounding, three token-major arrays written through three
 output specs; else ``causal_conv`` and what follows it as ``jax.numpy``.
 ``hvd_short_conv_rows_total{path}`` says which was traced.
 
+**The gated norm** between the recurrence and the out projection is one
+pass likewise: the Pallas kernels of :mod:`horovod_tpu.ops.gated_norm`
+wherever they can run (:func:`norm_path`), which read ``y`` and ``z``
+once, keep float32 to the one rounding and write the normed operand of
+the out projection once; else :func:`gated_norm` below, the same lines as
+``jax.numpy``.  ``hvd_gated_norm_rows_total{path}`` says which was traced.
+
 Precision, either way: ``delta``, ``log a``, its running sums, every
 decay and the carried state are float32; every matmul takes operands in
 the model dtype (the masked and decayed ``C B^T``, ``delta x`` and the
@@ -80,6 +87,7 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu import telemetry
 from horovod_tpu.models.linear_attention import causal_conv
+from horovod_tpu.ops import gated_norm as norm_kernels
 from horovod_tpu.ops import mamba2_scan as kernels
 from horovod_tpu.ops import short_conv
 from horovod_tpu.parallel._vma import pin_to, vma_of
@@ -232,6 +240,33 @@ def conv_path(u, cfg) -> str:
         channels=widths(cfg)[1]) else "xla"
 
 
+def gated_norm(y, z, scale, groups: int, eps: float):
+    """``GroupRMSNorm(y * silu(z))`` of the module's docstring as
+    ``jax.numpy``: ``y`` [B, T, H P] float32, ``z`` [B, T, H P] in the
+    model dtype, ``scale`` [H P] -> [B, T, H P] in ``z``'s dtype, float32
+    to the one rounding.  The oracle of
+    :mod:`horovod_tpu.ops.gated_norm`'s gate-first form and what runs
+    where its kernels cannot (:func:`norm_path`)."""
+    bsz, t, inner = y.shape
+    y = (y.reshape(bsz, t, groups, inner // groups)
+         * jax.nn.silu(z.astype(jnp.float32)).reshape(
+             bsz, t, groups, inner // groups))
+    y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps)
+    return (y.reshape(bsz, t, inner) * scale).astype(z.dtype)
+
+
+def norm_path(u, cfg) -> str:
+    """What runs the gated norm of a layer of ``cfg`` over the
+    recurrence's output for ``u`` [B, T, d], read as
+    :func:`recurrence_path` reads its: ``"kernel"``, the Pallas kernels of
+    :mod:`horovod_tpu.ops.gated_norm`; ``"xla"``, :func:`gated_norm`,
+    where they cannot run (``gated_norm.takes``)."""
+    inner = widths(cfg)[0]
+    return "kernel" if norm_kernels.takes(
+        u, inner // cfg.ssm_groups, width=inner,
+        x_dtype=jnp.float32) else "xla"
+
+
 def saved_state_bytes(batch: int, t: int, cfg) -> int:
     """Bytes of chunk states the backward of one layer's recurrence
     keeps: the float32 state at the start of each chunk."""
@@ -245,7 +280,6 @@ def mixer(u, layer, cfg):
     ``attn/qkv`` and ``attn/out`` and the recurrence as a route of its
     own (``telemetry/scopes.py``)."""
     dt = cfg.dtype
-    bsz, t, _ = u.shape
     g, n = cfg.ssm_groups, cfg.ssm_state
     inner, conv, _ = widths(cfg)
     with jax.named_scope(scopes.ATTN_QKV):
@@ -278,13 +312,13 @@ def mixer(u, layer, cfg):
                  g)
     with jax.named_scope(scopes.ATTN_OUT):
         with jax.named_scope(scopes.SSM_GATE_NORM):
-            y = (y.reshape(bsz, t, g, inner // g)
-                 * jax.nn.silu(z.astype(jnp.float32)).reshape(
-                     bsz, t, g, inner // g))
-            y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
-                              + cfg.norm_eps)
-            y = (y.reshape(bsz, t, inner)
-                 * layer["ssm_norm_scale"]).astype(dt)
+            if norm_path(u, cfg) == "kernel":
+                y = norm_kernels.gated_norm(
+                    y, z, layer["ssm_norm_scale"], group=inner // g,
+                    gate_first=True, eps=cfg.norm_eps)
+            else:
+                y = gated_norm(y, z, layer["ssm_norm_scale"], g,
+                               cfg.norm_eps)
         with jax.named_scope(scopes.SSM_OUT):
             return y @ layer["ssm_w_out"].astype(dt)
 
@@ -312,3 +346,4 @@ def record_chunks(layer: int, x, cfg) -> None:
         "state-space layer's recurrence keeps",
         layer=str(layer)).set(saved_state_bytes(batch, t, cfg))
     short_conv.record_rows(layer, batch * t, conv_path(x, cfg))
+    norm_kernels.record_rows(layer, batch * t, norm_path(x, cfg))

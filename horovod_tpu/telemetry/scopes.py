@@ -131,6 +131,11 @@ SSM_SCAN_BWD = "ssm_scan_bwd"
 SHORT_CONV_FWD = "short_conv_fwd"
 SHORT_CONV_BWD = "short_conv_bwd"
 
+# The two gated-norm Pallas kernels (ops/gated_norm.py); they run under
+# GDN_GATE_NORM and SSM_GATE_NORM.
+GATED_NORM_FWD = "gated_norm_fwd"
+GATED_NORM_BWD = "gated_norm_bwd"
+
 # The seven sparse-attention Pallas kernels (ops/sparse_attention.py): the
 # indexer's scores and their gradient, the selection, attention under the
 # mask (forward, dQ with the scores' cotangent, dK+dV) and the rows' KL

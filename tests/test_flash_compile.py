@@ -192,6 +192,44 @@ def test_short_conv_kernels_compile_for_the_v5e(one_chip, t, channels, kw,
         assert len(re.findall(rf"%{name}[.\d]* = ", text)) == 1, name
 
 
+# The gated norm of one mixer layer: nemotron3s_t8192's (``y`` float32 in
+# 8 groups of 1024, gate first) and olmohybrid_t16k's (``o`` head-major in
+# 30 heads of 192, norm first).
+@pytest.mark.parametrize("t,width,group,gate_first,head_major,tile", [
+    (8192, 8192, 1024, True, False, 128),
+    (16384, 5760, 192, False, True, 256)],
+    ids=["nemotron3s", "olmohybrid"])
+def test_gated_norm_kernels_compile_for_the_v5e(one_chip, t, width, group,
+                                                gate_first, head_major,
+                                                tile):
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import gated_norm as op
+    from horovod_tpu.telemetry import scopes
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    x = (shape(width // group, t, group) if head_major
+         else shape(1, t, width, dtype=jnp.float32))
+    z, scale = shape(1, t, width), shape(1, width, dtype=jnp.float32)
+    assert op.tiles(t, width, group, head_major, x.dtype.itemsize,
+                    2) == tile
+    plan = op._Plan(width, group, gate_first, head_major, 1e-5)
+
+    # The calls themselves, told to compile: the public function reads
+    # the executing mesh, and this process's is the CPU.
+    def fwd_and_grads(x, z, scale, dout):
+        return (op._fwd_call(x, z, scale, plan=plan, interpret=False),
+                op._bwd_call(x, z, scale, dout, plan=plan, interpret=False))
+
+    text = jax.jit(fwd_and_grads).lower(x, z, scale, z).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for name in (scopes.GATED_NORM_FWD, scopes.GATED_NORM_BWD):
+        assert len(re.findall(rf"%{name}[.\d]* = ", text)) == 1, name
+
+
 # One layer's learned sparse attention of keyevl2_t16k: 32 query heads
 # over 4 key-value heads of 128, 16 indexer heads of 64 over one key head,
 # the 2048 best of up to 16384 keys a query; as it stands and under

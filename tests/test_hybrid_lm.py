@@ -362,6 +362,70 @@ def test_published_gate_initialisation_ranges():
     assert np.abs(np.asarray(layer["lin_conv"])).max() <= 0.5
 
 
+def _mixer_grads(cfg, t, norm, monkeypatch, conv="kernel"):
+    """One linear mixer's output and the gradients of all of its leaves
+    and of its input, the gated norm by ``norm``."""
+    from horovod_tpu.telemetry import scopes
+
+    ks = jax.random.split(jax.random.key(0), 3)
+    layer = la.init_layer(
+        ks[0], cfg, lambda k, shape: jax.random.normal(k, shape)
+        * shape[0] ** -0.5)
+    layer["lin_norm_scale"] = 1.0 + 0.1 * jax.random.normal(
+        ks[0], layer["lin_norm_scale"].shape)
+    x = jax.random.normal(ks[1], (2, t, cfg.d_model))
+    dy = jax.random.normal(ks[2], x.shape)
+    if norm == "xla":
+        monkeypatch.setattr(la, "norm_path", lambda x, cfg: "xla")
+    if conv == "xla":
+        monkeypatch.setattr(la, "conv_path", lambda x, cfg: "xla")
+    assert la.norm_path(x, cfg) == norm
+    traced = str(jax.make_jaxpr(lambda l, x: la.mixer(x, l, cfg))(layer, x))
+    assert (scopes.GATED_NORM_FWD in traced) is (norm == "kernel")
+    with jax.default_matmul_precision("highest"):
+        return (la.mixer(x, layer, cfg), jax.grad(
+            lambda l, x: jnp.sum(la.mixer(x, l, cfg) * dy),
+            (0, 1))(layer, x))
+
+
+@pytest.mark.parametrize("t,conv", [(128, "kernel"), (64, "kernel"),
+                                    (128, "xla")],
+                         ids=["head_major", "one_block", "token_major"])
+def test_the_mixer_through_the_gated_norm_kernels(t, conv):
+    """The whole mixer with the gated norm's kernels (interpreted) against
+    the ``jax.numpy`` lines: its output and the gradient of every leaf and
+    of its input.  ``o`` comes head-major from the recurrence's kernels
+    behind the convolution's, token-major where either is the
+    ``jax.numpy`` form (64 tokens are one block, which the recurrence's
+    kernels do not take)."""
+    with pytest.MonkeyPatch.context() as patch:
+        got = _mixer_grads(HYBRID_TINY, t, "kernel", patch, conv)
+    with pytest.MonkeyPatch.context() as patch:
+        want = _mixer_grads(HYBRID_TINY, t, "xla", patch, conv)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(
+            np.linalg.norm(np.asarray(a, np.float64) - b)
+            / np.linalg.norm(b), 0.0, atol=5e-5)
+
+
+def test_the_gated_norm_kernels_take_the_cells_shapes():
+    """``olmohybrid_t16k``: one sequence of 16384 tokens, 30 value heads
+    of 192 channels, bfloat16, ``o`` head-major from the recurrence's
+    kernels.  Shapes only; nothing runs."""
+    from horovod_tpu.ops import gated_norm
+
+    cfg = dataclasses.replace(
+        HYBRID_TINY, d_model=3840, linear_key_heads=30,
+        linear_value_heads=30, linear_key_head_dim=96,
+        linear_value_head_dim=192, dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((1, 16384, 3840), jnp.bfloat16)
+    assert la._o_head_major(x, cfg) and la.norm_path(x, cfg) == "kernel"
+    assert gated_norm.tiles(16384, 5760, 192, True, 2, 2) == 256
+    assert la.norm_path(
+        jax.ShapeDtypeStruct((1, 16392, 3840), jnp.bfloat16), cfg) == "xla"
+
+
 def test_block_counters_count_what_was_traced(hvd):
     from horovod_tpu import telemetry
 
@@ -383,6 +447,12 @@ def test_block_counters_count_what_was_traced(hvd):
             assert (f'hvd_gdn_saved_state_bytes{{layer="{layer}"}} '
                     f'{blocks * 24 * 48 * 4}') in text, text
         assert 'hvd_gdn_blocks_total{layer="3"' not in text
+        # The gated norm once a linear layer, batch x T rows, by its
+        # kernels too (heads of 48 channels).
+        for layer in (0, 1, 2):
+            assert ('hvd_gated_norm_rows_total{layer="%d",path="kernel"} 512'
+                    % layer) in text, text
+        assert text.count("hvd_gated_norm_rows_total{") == 3
         assert 'path="xla"' not in text
     finally:
         telemetry.reset_for_tests()
@@ -413,6 +483,8 @@ def test_block_counters_say_which_path_the_training_step_took(hvd):
         for layer in (0, 1, 2):
             assert (f'hvd_gdn_blocks_total{{layer="{layer}",path="xla"}} '
                     f'{blocks}' in text), text
+            assert ('hvd_gated_norm_rows_total{layer="%d",path="xla"} 512'
+                    % layer) in text, text
         assert 'path="kernel"' not in text
         assert "cumsum" in lowered and "gdn_scan_fwd" not in lowered
     finally:

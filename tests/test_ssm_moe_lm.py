@@ -13,6 +13,7 @@ read 3e-3 and up, and one test proves that).
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -923,11 +924,82 @@ def test_trace_time_series_count_what_was_traced(hvd, widths, path):
                     f'{256 * 4}') in text, text
         assert 'hvd_ssm_chunks_total{layer="1"' not in text
         other = {"xla": "kernel", "kernel": "xla"}[path]
-        assert f'path="{other}"' not in text
+        for series in ("hvd_ssm_chunks_total", "hvd_short_conv_rows_total"):
+            assert not re.search(rf'{series}{{[^}}]*path="{other}"', text)
+        # The gated norm's kernels take both: groups of 32 and of 128
+        # channels are slabs of whole groups and whole lanes.  Once a
+        # mixer layer, batch x T rows.
+        for layer in (0, 2, 4, 6, 9):
+            assert ('hvd_gated_norm_rows_total{layer="%d",path="kernel"} 256'
+                    % layer) in text, text
+        assert text.count("hvd_gated_norm_rows_total{") == 5
         # Data, not static, on a share: not counted.
         assert "hvd_moe_assignments_total" not in text
     finally:
         telemetry.reset_for_tests()
+
+
+def _mixer_grads(cfg, t, norm, monkeypatch):
+    """One Mamba-2 mixer's output and the gradients of all of its leaves
+    and of its input, the gated norm by ``norm``."""
+    from horovod_tpu.models import mamba2
+    from horovod_tpu.telemetry import scopes
+
+    ks = jax.random.split(jax.random.key(0), 3)
+    layer = mamba2.init_layer(
+        ks[0], cfg, lambda k, shape: jax.random.normal(k, shape)
+        * shape[0] ** -0.5)
+    layer["ssm_norm_scale"] = 1.0 + 0.1 * jax.random.normal(
+        ks[0], layer["ssm_norm_scale"].shape)
+    u = jax.random.normal(ks[1], (2, t, cfg.d_model))
+    dy = jax.random.normal(ks[2], u.shape)
+    if norm == "xla":
+        monkeypatch.setattr(mamba2, "norm_path", lambda u, cfg: "xla")
+    assert mamba2.norm_path(u, cfg) == norm
+    traced = str(jax.make_jaxpr(lambda l, u: mamba2.mixer(u, l, cfg))(
+        layer, u))
+    assert (scopes.GATED_NORM_FWD in traced) is (norm == "kernel")
+    with jax.default_matmul_precision("highest"):
+        return (mamba2.mixer(u, layer, cfg), jax.grad(
+            lambda l, u: jnp.sum(mamba2.mixer(u, l, cfg) * dy),
+            (0, 1))(layer, u))
+
+
+@pytest.mark.parametrize("widths", [{}, KERNEL_WIDTHS],
+                         ids=["narrow", "whole_lanes"])
+def test_the_mixer_through_the_gated_norm_kernels(widths):
+    """The whole mixer with the gated norm's kernels (interpreted) against
+    the ``jax.numpy`` lines: its output and the gradient of every leaf and
+    of its input; groups of 32 channels (two of them a register) and of
+    128."""
+    cfg = dataclasses.replace(NEMOTRON_TINY, **widths)
+    with pytest.MonkeyPatch.context() as patch:
+        got = _mixer_grads(cfg, 128, "kernel", patch)
+    with pytest.MonkeyPatch.context() as patch:
+        want = _mixer_grads(cfg, 128, "xla", patch)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(
+            np.linalg.norm(np.asarray(a, np.float64) - b)
+            / np.linalg.norm(b), 0.0, atol=5e-5)
+
+
+def test_the_gated_norm_kernels_take_the_cells_shapes():
+    """``nemotron3s_t8192``: one sequence of 8192 tokens, 128 heads of 64
+    channels in 8 norm groups of 1024, bfloat16 with the scan's ``y`` in
+    float32.  Shapes only; nothing runs."""
+    from horovod_tpu.models import mamba2
+    from horovod_tpu.ops import gated_norm
+
+    cfg = dataclasses.replace(
+        NEMOTRON_TINY, d_model=4096, ssm_heads=128, ssm_head_dim=64,
+        ssm_state=128, ssm_groups=8, ssm_chunk=128, dtype=jnp.bfloat16)
+    u = jax.ShapeDtypeStruct((1, 8192, 4096), jnp.bfloat16)
+    assert mamba2.norm_path(u, cfg) == "kernel"
+    assert gated_norm.tiles(8192, 8192, 1024, False, 4, 2) == 128
+    # A length that is not whole sublane tiles goes the other way.
+    assert mamba2.norm_path(
+        jax.ShapeDtypeStruct((1, 8200, 4096), jnp.bfloat16), cfg) == "xla"
 
 
 def test_chunk_counters_say_which_path_the_training_step_took(hvd):
@@ -957,6 +1029,8 @@ def test_chunk_counters_say_which_path_the_training_step_took(hvd):
                              tokens, tokens).as_text(debug_info=True)
         text = telemetry.render_prometheus()
         assert 'hvd_ssm_chunks_total{layer="0",path="xla"} 32' in text, text
+        assert ('hvd_gated_norm_rows_total{layer="0",path="xla"} 256'
+                in text), text
         assert 'path="kernel"' not in text
         assert "cumsum" in lowered and "ssm_scan_fwd" not in lowered
     finally:

@@ -462,6 +462,9 @@ def test_the_benchmark_reads_the_same_vocabulary():
     # The short convolution's two kernels run under ``gdn_conv`` and
     # ``ssm_conv`` and are booked by those parts, not by their names.
     conv_kernels = {scopes.SHORT_CONV_FWD, scopes.SHORT_CONV_BWD}
+    # Likewise the gated norm's two, under ``gdn_gate_norm`` and
+    # ``ssm_gate_norm``.
+    norm_kernels = {scopes.GATED_NORM_FWD, scopes.GATED_NORM_BWD}
     # Learned sparse attention's six parts (two under ``attn/qkv``, four
     # under ``attn/flash_attention``, its route), read by
     # ``perfbench/dsa_reduce.py``, and its seven kernels, booked by the
@@ -479,8 +482,8 @@ def test_the_benchmark_reads_the_same_vocabulary():
                 + scope_reduce.OPTIMIZER_SCOPES)
             == program - kernels - modules - {scopes.LAYER} - moe_parts
             - moe_kernels - gdn_parts - gdn_kernels - ssm_parts
-            - ssm_kernels - mla_parts - conv_kernels - dsa_parts
-            - dsa_kernels)
+            - ssm_kernels - mla_parts - conv_kernels - norm_kernels
+            - dsa_parts - dsa_kernels)
     from perfbench import mla_reduce
     assert (mla_parts | moe_parts | {scopes.MOE_SHARED}
             == set(mla_reduce.PARTS))
@@ -502,6 +505,19 @@ def test_the_benchmark_reads_the_same_vocabulary():
                 f"/{kernel}/pallas_call")
         assert ssm_reduce.parts_of(call) == ["ssm_scan"]
         assert scope_reduce.phase_of(call) == phase
+    # The gated norm's kernels are booked where the ``jax.numpy`` lines
+    # were: by the part they run under, in both mixers.
+    for phase, name, kernel in (
+            ("fwd", "jvp(%s)", scopes.GATED_NORM_FWD),
+            ("bwd", "transpose(jvp(%s))", scopes.GATED_NORM_BWD)):
+        call = (f"jit(x)/{name % (scopes.LAYER % 1)}/{scopes.ATTN_OUT}/%s/"
+                f"{kernel}/pallas_call")
+        assert gdn_reduce.part_of(
+            call % scopes.GDN_GATE_NORM) == scopes.GDN_GATE_NORM
+        assert ssm_reduce.parts_of(
+            call % scopes.SSM_GATE_NORM) == [scopes.SSM_GATE_NORM]
+        assert scope_reduce.phase_of(call) == phase
+        assert scope_reduce.scope_of(call) == scopes.ATTN_OUT
     assert scope_reduce.scope_of(
         f"jit(x)/jvp({scopes.LAYER % 3})/{scopes.MLP}/dot_general"
     ) == scopes.MLP
