@@ -22,11 +22,11 @@ echo "--- capability report"
 python -m horovod_tpu.runner --check-build
 
 echo "--- unit + SPMD suites, fast lane (8-device virtual CPU mesh)"
-python -m pytest tests/ -x -q
+python -m pytest tests/ -q
 
 echo "--- slow lane (multi-minute end-to-end oracles; pyproject addopts
 --- deselects these by default, CI runs them explicitly)"
-python -m pytest tests/ -x -q -m slow
+python -m pytest tests/ -q -m slow
 
 echo "--- chaos lane (fault-injection harness; single host, subprocess
 --- ranks, each test bounded <=30s.  These also run in the fast lane —

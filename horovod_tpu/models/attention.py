@@ -1,0 +1,434 @@
+"""Softmax attention, the sequence mixer of a ``"full_attention"`` or
+``"attention"`` layer, in its three forms (:mod:`horovod_tpu.models.parts`):
+
+* :data:`ATTENTION` — plain: q, k, v projections over grouped heads
+  (``n_kv_heads``) of ``head_width``, QK-norm over the whole projection
+  (``qk_norm``, OLMoE) or a head at a time (``qk_norm_per_head``, Qwen3),
+  rotary or learned positions, and the route ``forward`` was asked for:
+  ``local | flash | ring | ring_flash | ulysses | auto``;
+* :data:`LATENT_ATTENTION` — DeepSeek-V2's MLA in the up-projected form
+  training runs (:func:`latent_qkv`), through the same routes;
+* :data:`SPARSE_ATTENTION` — plain attention's projections beside an
+  indexer that chooses the ``index_topk`` keys each query reads and learns
+  from its own loss (:mod:`horovod_tpu.ops.sparse_attention`), a route of
+  its own.
+
+:func:`qkv_proj` and :func:`attn_out` are also what ``decode_step`` and
+the pipelined stage run, so the three cannot drift.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import logging
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import PartitionSpec as P
+
+from horovod_tpu.models import parts
+from horovod_tpu.models.parts import dense, ones, rmsnorm, whole
+from horovod_tpu.ops import sparse_attention
+from horovod_tpu.ops.flash_attention import flash_attention
+from horovod_tpu.parallel import sequence as seq_mod
+from horovod_tpu.parallel import tensor as tp
+from horovod_tpu.telemetry import scopes
+
+
+def rotary(x, positions, theta: float):
+    """Rotary embedding of ``x`` [..., T, H, head_dim] at ``positions``
+    [T], rotate-half convention (HF ``apply_rotary_pos_emb``): the pair
+    (x_i, x_{i + head_dim/2}) turns by ``position * theta^(-2i/head_dim)``.
+    Angles and the rotation in float32."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.cos(angles)[:, None, :]
+    sin = jnp.sin(angles)[:, None, :]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def qkv_proj(x, layer, cfg, model_axis, positions=None, normed=None):
+    """rmsnorm -> q/k/v projections -> (QK-norm) -> head split ->
+    (rotary at ``positions`` [T]) (shared by forward, decode_step and
+    forward_pipelined so the projection math cannot drift).  Returns q,
+    k, v with a trailing [heads, head_dim] split.  ``normed``: the normed
+    ``x`` where the caller has it already (it hands it to an indexer too)."""
+    dt = cfg.dtype
+    h = (rmsnorm(x, layer["ln1_scale"], cfg.norm_eps) if normed is None
+         else normed)
+    hi = tp.region_input(h, model_axis) if model_axis else h
+    q = hi @ layer["wq"].astype(dt)
+    k = hi @ layer["wk"].astype(dt)
+    v = hi @ layer["wv"].astype(dt)
+    if cfg.qk_norm:
+        q = rmsnorm(q, layer["q_norm_scale"], cfg.norm_eps)
+        k = rmsnorm(k, layer["k_norm_scale"], cfg.norm_eps)
+    dh = q.shape[-1]
+
+    def heads(a):
+        return a.reshape(a.shape[:-1] + (a.shape[-1] // cfg.head_dim,
+                                         cfg.head_dim))
+
+    q, k, v = heads(q), heads(k), heads(v)
+    # The per-head norm and the rotation after it are a part of their own
+    # in a trace; without the norm the rotation is booked as it always was.
+    with (jax.named_scope(scopes.QK_HEAD_NORM_ROPE) if cfg.qk_norm_per_head
+          else contextlib.nullcontext()):
+        if cfg.qk_norm_per_head:
+            q = rmsnorm(q, layer["q_norm_scale"], cfg.norm_eps)
+            k = rmsnorm(k, layer["k_norm_scale"], cfg.norm_eps)
+        if cfg.positions == "rope":
+            q = rotary(q, positions, cfg.rope_theta)
+            k = rotary(k, positions, cfg.rope_theta)
+    return q, k, v, dh
+
+
+@jax.named_scope(scopes.DSA_INDEX_PROJ)
+def indexer_proj(u, layer, cfg, positions):
+    """The indexer's operands from the layer's normed input ``u`` [B, T, d],
+    whose gradient stops here (the indexer learns from its own loss and
+    moves nothing else): queries ``[B, T, index_heads, index_head_dim]`` and
+    ONE key head ``[B, T, index_head_dim]``, both rotary at ``positions``
+    over all their dims, and a weight a head ``[B, T, index_heads]``."""
+    dt = cfg.dtype
+    u = lax.stop_gradient(u)
+    qi = (u @ layer["index_wq"].astype(dt)).reshape(
+        u.shape[:-1] + (cfg.index_heads, cfg.index_head_dim))
+    ki = (u @ layer["index_wk"].astype(dt))[..., None, :]
+    w = u @ layer["index_ww"].astype(dt)
+    qi = rotary(qi, positions, cfg.rope_theta)
+    ki = rotary(ki, positions, cfg.rope_theta)[..., 0, :]
+    return qi, ki, w
+
+
+def latent_qkv(h, layer, cfg, positions):
+    """Latent attention's q, k, v from the normed input ``h`` [..., T, d],
+    in the up-projected form (K and V materialised per head; the absorbed
+    form and a cache of latents are decode's: ROADMAP R13): ``c_q =
+    RMSNorm(h W_qa)``, ``q = c_q W_qb`` as heads of ``[q_n | q_r]``;
+    ``[c_kv | k_r] = h W_kva``, ``c_kv <- RMSNorm(c_kv)``, ``[k_n | v] =
+    c_kv W_kvb`` per head; ``q_r`` and ``k_r`` rotary at ``positions``,
+    ``k_r`` **one head that every head's key ends in** (so its gradient
+    sums over the heads); ``k = [k_n | k_r]``.  Returns q, k, v [..., T,
+    heads, head_width] and ``heads * head_width``."""
+    dt, heads, hd, rope = cfg.dtype, cfg.n_heads, cfg.head_dim, cfg.rope_dim
+    nope, rank = hd - rope, cfg.kv_latent_rank
+    with jax.named_scope(scopes.MLA_Q):
+        c_q = rmsnorm(h @ layer["w_qa"].astype(dt),
+                      layer["q_latent_norm_scale"], cfg.norm_eps)
+        q = (c_q @ layer["w_qb"].astype(dt)).reshape(
+            h.shape[:-1] + (heads, hd))
+    with jax.named_scope(scopes.MLA_KV):
+        down = h @ layer["w_kva"].astype(dt)
+        c_kv = rmsnorm(down[..., :rank], layer["kv_latent_norm_scale"],
+                       cfg.norm_eps)
+        up = (c_kv @ layer["w_kvb"].astype(dt)).reshape(
+            h.shape[:-1] + (heads, nope + hd))
+        k_n, v = up[..., :nope], up[..., nope:]
+    with jax.named_scope(scopes.MLA_ROPE):
+        q = jnp.concatenate(
+            [q[..., :nope], rotary(q[..., nope:], positions,
+                                   cfg.rope_theta)], axis=-1)
+        k_r = rotary(down[..., None, rank:], positions, cfg.rope_theta)
+        k = jnp.concatenate(
+            [k_n, jnp.broadcast_to(k_r, k_n.shape[:-1] + (rope,))], axis=-1)
+    return q, k, v, heads * hd
+
+
+def _share_kv_heads(k, v, n_heads: int):
+    """Grouped-query attention's K and V as the attention routes take
+    them, one head a query head: each key-value head repeated for the
+    ``n_heads / kv_heads`` query heads that read it (so dK and dV sum over
+    the group); as they are where the counts are equal.  A copy in HBM:
+    a kernel that reads head ``h // group`` instead is ROADMAP R3."""
+    group = n_heads // k.shape[-2]
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=-2), jnp.repeat(v, group, axis=-2)
+
+
+def attn_out(o_flat, x, layer, dt, model_axis):
+    """Output projection (row-parallel psum under TP) + residual."""
+    o = o_flat @ layer["wo"].astype(dt)
+    if model_axis:
+        o = lax.psum(o, model_axis)
+    return x + o
+
+
+_flash_declined_shapes: set = set()
+
+
+def _flash_profitable(t: int) -> bool:
+    """``attention="auto"``'s flash-vs-lax decision, made at TRACE time
+    from the (static) sequence length.  With the kernel's auto block
+    sizes (r3 sweep, docs/kernels.md table): measured fwd-only PARITY at
+    T=1024 and measured wins from T=2048 up (fwd-only and fwd+bwd), so
+    1024 is the safe default threshold — at worst a tie; override with
+    HOROVOD_FLASH_AUTO_MIN_T.  Auto also refuses lengths the compiled
+    kernel cannot tile (indivisible by the 128-lane block) and falls
+    back to the lax path — ``auto`` NEVER raises on shape; only an
+    explicit ``attention="flash"`` may (the user asked for the kernel).
+    """
+    import os
+    min_t = int(os.environ.get("HOROVOD_FLASH_AUTO_MIN_T", "1024"))
+    if t >= min_t and t % 128 != 0:
+        if t not in _flash_declined_shapes:   # one-time per length
+            _flash_declined_shapes.add(t)
+            logging.getLogger("horovod_tpu").debug(
+                "attention='auto': T=%d is not divisible by 128; using "
+                "the lax attention path (pad the sequence to enable the "
+                "flash kernel)", t)
+        return False
+    return t >= min_t
+
+
+def _routed(q, k, v, dh, x, layer, cfg, ctx):
+    """``q, k, v`` through the route ``ctx.attention`` names (each opens
+    its own ``attn/<route>``), the out projection and the residual."""
+    seq_axis, attention, segment_ids = (ctx.seq_axis, ctx.attention,
+                                        ctx.segment_ids)
+    b, t = q.shape[:2]
+    flash = seq_axis is None and (
+        attention in ("flash", "ring_flash")
+        or (attention == "auto" and _flash_profitable(t)))
+    with jax.named_scope(scopes.ATTN_FLASH if flash else scopes.ATTN_QKV):
+        k, v = _share_kv_heads(k, v, q.shape[-2])
+    if seq_axis is not None:
+        if attention == "ring_flash" or (attention == "auto" and
+                                         _flash_profitable(t)):
+            # Ring attention with the flash kernel as the per-step
+            # block math: auto upgrades when the LOCAL chunk length
+            # clears the kernel's measured crossover.
+            o = seq_mod.ring_flash_attention(
+                q, k, v, seq_axis, True, None, None, segment_ids)
+        elif attention in ("ring", "auto"):
+            o = seq_mod.ring_attention(q, k, v, seq_axis, causal=True,
+                                       segment_ids=segment_ids)
+        elif attention == "ulysses":
+            o = seq_mod.ulysses_attention(q, k, v, seq_axis, causal=True,
+                                          segment_ids=segment_ids)
+        else:
+            # The single-device flash kernel route makes no sense
+            # under a sequence axis; K/V blocks arrive over ICI and
+            # the blockwise math lives in ring[_flash]_attention.
+            # Never silently substitute a different algorithm.
+            raise ValueError(
+                f"attention={attention!r} is not available with a "
+                f"sequence axis; choose 'ring', 'ring_flash' or "
+                f"'ulysses'")
+    elif flash:
+        # Pallas flash kernel (ops/flash_attention.py): same exact
+        # math blockwise in VMEM; requires T divisible by its blocks.
+        # 'ring_flash' without a seq axis degenerates to exactly
+        # this kernel (a 1-ring's only step is the diagonal one) —
+        # the user still measures the algorithm they selected.
+        o = flash_attention(q, k, v, True, segment_ids=segment_ids)
+    else:
+        o = seq_mod.local_attention(q, k, v, causal=True,
+                                    segment_ids=segment_ids)
+    with jax.named_scope(scopes.ATTN_OUT):
+        return attn_out(o.reshape(b, t, dh), x, layer, cfg.dtype,
+                        ctx.model_axis)
+
+
+# --- plain attention --------------------------------------------------------
+
+def _validate(cfg, used):
+    if cfg.n_kv_heads and cfg.n_heads % cfg.n_kv_heads:
+        raise ValueError(f"n_kv_heads={cfg.n_kv_heads} does not "
+                         f"divide n_heads={cfg.n_heads}")
+    if cfg.qk_norm and cfg.qk_norm_per_head:
+        raise ValueError("qk_norm norms the whole projection, "
+                         "qk_norm_per_head each head: one of them")
+
+
+def _init(k, cfg):
+    d, wide, d_kv = cfg.d_model, cfg.attn_width, cfg.kv_heads * cfg.head_dim
+    layer = dict(ln1_scale=ones(d), wq=dense(k[0], (d, wide)),
+                 wk=dense(k[1], (d, d_kv)), wv=dense(k[2], (d, d_kv)),
+                 wo=dense(k[3], (wide, d)))
+    if cfg.qk_norm:
+        layer.update(q_norm_scale=ones(d), k_norm_scale=ones(d_kv))
+    if cfg.qk_norm_per_head:
+        layer.update(q_norm_scale=ones(cfg.head_dim),
+                     k_norm_scale=ones(cfg.head_dim))
+    return layer
+
+
+def _specs(cfg, model_axis):
+    col, row = P(None, model_axis), P(model_axis, None)
+    specs = dict(whole("ln1_scale"), wq=col, wk=col, wv=col, wo=row)
+    if cfg.qk_norm or cfg.qk_norm_per_head:
+        specs.update(whole("q_norm_scale", "k_norm_scale"))
+    return specs
+
+
+def _apply(x, layer, cfg, ctx):
+    with jax.named_scope(scopes.ATTN_QKV):
+        q, k, v, dh = qkv_proj(x, layer, cfg, ctx.model_axis, ctx.positions)
+    return _routed(q, k, v, dh, x, layer, cfg, ctx), {}
+
+
+# QK-norm's statistics span the whole projection, which the model axis
+# splits; the per-head norm's scale and a head's own width are not split
+# with the heads, nor are grouped key-value heads.
+_NOT_SPLIT = ("qk_norm", "n_kv_heads", "head_width", "qk_norm_per_head")
+
+ATTENTION = parts.Part(
+    name="attention",
+    fields=("n_kv_heads", "qk_norm", "qk_norm_per_head", "head_width"),
+    validate=_validate, init=_init, specs=_specs, apply=_apply,
+    unsupported={"model_axis": _NOT_SPLIT,
+                 "seq_axis": ("head_width", "qk_norm_per_head")})
+
+
+# --- latent attention -------------------------------------------------------
+
+_LATENT = ("q_latent_rank", "kv_latent_rank", "rope_dim")
+
+
+def _latent_validate(cfg, used):
+    # A head width alone is plain attention with heads of that width.
+    latent = tuple(getattr(cfg, name) for name in _LATENT)
+    if not any(latent):
+        return
+    if (cfg.q_latent_rank or cfg.kv_latent_rank) and not cfg.head_width:
+        raise ValueError(
+            f"q_latent_rank={cfg.q_latent_rank}, kv_latent_rank="
+            f"{cfg.kv_latent_rank}: latent attention needs head_width, the "
+            f"width its up-projections give a head")
+    if min(latent + (cfg.head_width,)) <= 0:
+        raise ValueError(
+            "q_latent_rank, kv_latent_rank and rope_dim come together, with "
+            "head_width: they are latent attention")
+    if cfg.rope_dim > cfg.head_width:
+        raise ValueError(
+            f"rope_dim={cfg.rope_dim} is wider than head_width="
+            f"{cfg.head_width}: the rotary part is the tail of a head")
+    if cfg.positions != "rope" or cfg.rope_dim % 2:
+        raise ValueError(
+            f"latent attention carries position in its rotary part: it "
+            f"needs positions='rope' and an even rope_dim, got "
+            f"{cfg.positions!r} and {cfg.rope_dim}")
+    if cfg.qk_norm or cfg.n_kv_heads or cfg.qk_norm_per_head:
+        raise NotImplementedError(
+            "latent attention norms its latents and gives every head its "
+            "own key: qk_norm, qk_norm_per_head and n_kv_heads are not "
+            "implemented with it")
+
+
+def _latent_init(k, cfg):
+    d, wide = cfg.d_model, cfg.n_heads * cfg.head_dim
+    r_q, r_kv = cfg.q_latent_rank, cfg.kv_latent_rank
+    return dict(
+        ln1_scale=ones(d),
+        w_qa=dense(k[0], (d, r_q)), q_latent_norm_scale=ones(r_q),
+        w_qb=dense(k[1], (r_q, wide)),
+        # To [c_kv | k_r], the latent and the one rotary key.
+        w_kva=dense(k[2], (d, r_kv + cfg.rope_dim)),
+        kv_latent_norm_scale=ones(r_kv),
+        # To [k_n | v] of each head in turn.
+        w_kvb=dense(jax.random.fold_in(k[2], 1),
+                    (r_kv, 2 * wide - cfg.n_heads * cfg.rope_dim)),
+        wo=dense(k[3], (wide, d)))
+
+
+def _latent_specs(cfg, model_axis):
+    # Whole on every chip (heads over the model axis: ROADMAP R16).
+    return whole("ln1_scale", "w_qa", "q_latent_norm_scale", "w_qb", "w_kva",
+                 "kv_latent_norm_scale", "w_kvb", "wo")
+
+
+def _latent_apply(x, layer, cfg, ctx):
+    with jax.named_scope(scopes.ATTN_QKV):
+        q, k, v, dh = latent_qkv(
+            rmsnorm(x, layer["ln1_scale"], cfg.norm_eps), layer, cfg,
+            ctx.positions)
+    return _routed(q, k, v, dh, x, layer, cfg, ctx), {}
+
+
+# Not written: heads of a latent's up-projection over chips, and the
+# shared rotary key under the ring and Ulysses routes.
+LATENT_ATTENTION = parts.Part(
+    name="latent_attention", fields=_LATENT, validate=_latent_validate,
+    init=_latent_init, specs=_latent_specs, apply=_latent_apply,
+    unsupported={"model_axis": ("head_width",) + _LATENT,
+                 "seq_axis": ("head_width",) + _LATENT})
+
+
+# --- learned sparse attention -----------------------------------------------
+
+_SPARSE = ("index_heads", "index_head_dim", "index_topk",
+           "indexer_loss_coef")
+
+
+def _sparse_validate(cfg, used):
+    sparse = tuple(getattr(cfg, name) for name in _SPARSE)
+    if not any(sparse):
+        return
+    if min(sparse) <= 0:
+        raise ValueError(
+            "index_heads, index_head_dim, index_topk and indexer_loss_coef "
+            "come together: they are learned sparse attention")
+    if cfg.positions != "rope" or cfg.index_head_dim % 2:
+        raise ValueError(
+            f"the indexer's queries and key are rotary: it needs "
+            f"positions='rope' and an even index_head_dim, got "
+            f"{cfg.positions!r} and {cfg.index_head_dim}")
+    if cfg.latent_attention:
+        raise NotImplementedError(
+            "an indexer beside latent attention (index_heads with "
+            "kv_latent_rank) is not implemented")
+
+
+def _sparse_init(k, cfg):
+    d = cfg.d_model
+    k_index = jax.random.split(jax.random.fold_in(k[0], 1), 3)
+    return dict(
+        _init(k, cfg),
+        index_wq=dense(k_index[0], (d, cfg.index_heads * cfg.index_head_dim)),
+        index_wk=dense(k_index[1], (d, cfg.index_head_dim)),
+        index_ww=dense(k_index[2], (d, cfg.index_heads)))
+
+
+def _sparse_specs(cfg, model_axis):
+    return dict(_specs(cfg, model_axis),
+                **whole("index_wq", "index_wk", "index_ww"))
+
+
+def _sparse_apply(x, layer, cfg, ctx):
+    # The route of its own: the indexer chooses each query's keys
+    # (ops/sparse_attention.py), whatever ``ctx.attention`` says.
+    with jax.named_scope(scopes.ATTN_QKV):
+        u = rmsnorm(x, layer["ln1_scale"], cfg.norm_eps)
+        q, k, v, dh = qkv_proj(x, layer, cfg, ctx.model_axis, ctx.positions,
+                               normed=u)
+        qi, ki, w = indexer_proj(u, layer, cfg, ctx.positions)
+    with jax.named_scope(scopes.ATTN_FLASH):
+        o, kl = sparse_attention.dsa_attention(
+            q, k, v, qi, ki, w, topk=cfg.index_topk,
+            index_scale=(cfg.index_heads * cfg.index_head_dim) ** -0.5)
+    with jax.named_scope(scopes.ATTN_OUT):
+        return (attn_out(o.reshape(o.shape[:2] + (dh,)), x, layer, cfg.dtype,
+                         ctx.model_axis), {"index_kl": jnp.sum(kl)})
+
+
+def _sparse_record(name, x, layer, cfg, ctx):
+    sparse_attention.record_path(sparse_attention.path(x))
+
+
+# Not written: a query's selected keys lie on other chips under a sequence
+# axis, a selection that stays inside a document, and the indexer's
+# weights over a model axis.
+SPARSE_ATTENTION = parts.Part(
+    name="sparse_attention", fields=_SPARSE, validate=_sparse_validate,
+    init=_sparse_init, specs=_sparse_specs, apply=_sparse_apply,
+    record=_sparse_record,
+    unsupported={"model_axis": _NOT_SPLIT + _SPARSE,
+                 "seq_axis": ("head_width", "qk_norm_per_head") + _SPARSE,
+                 "segment_ids": ("index_topk",)})

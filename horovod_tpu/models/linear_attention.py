@@ -93,9 +93,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
 
 from horovod_tpu import telemetry
+from horovod_tpu.models import parts
 from horovod_tpu.ops import gated_delta_rule as kernels
 from horovod_tpu.ops import gated_norm as norm_kernels
 from horovod_tpu.ops import short_conv
@@ -144,11 +144,6 @@ def init_layer(key, cfg, dense):
 
 LEAVES = ("lin_wq", "lin_wk", "lin_wv", "lin_wz", "lin_wa", "lin_wb",
           "lin_conv", "lin_a_log", "lin_dt_bias", "lin_norm_scale", "lin_wo")
-
-
-def layer_specs():
-    """Every leaf whole on every chip (no model axis: it is refused)."""
-    return {name: P() for name in LEAVES}
 
 
 def causal_conv(x, w):
@@ -437,3 +432,42 @@ def record_blocks(layer: int, x, cfg) -> None:
         layer=str(layer)).set(saved_state_bytes(batch, t, cfg))
     short_conv.record_rows(layer, 3 * batch * t, conv_path(x, cfg))
     norm_kernels.record_rows(layer, batch * t, norm_path(x, cfg))
+
+
+# --- the mixer as a part (models/parts.py) ----------------------------------
+
+_FIELDS = ("linear_key_heads", "linear_value_heads", "linear_key_head_dim",
+           "linear_value_head_dim", "linear_conv_kernel",
+           "linear_allow_neg_eigval")
+
+
+def _validate(cfg, used):
+    sizes = tuple(getattr(cfg, name) for name in _FIELDS[:5])
+    if not used:
+        if any(sizes) or cfg.linear_allow_neg_eigval:
+            raise ValueError("the linear_* fields mean nothing without a "
+                             "'linear_attention' entry in layer_types")
+        return
+    if min(sizes) <= 0:
+        raise ValueError(
+            "a 'linear_attention' layer needs linear_key_heads, "
+            "linear_value_heads, linear_key_head_dim, linear_value_head_dim "
+            "and linear_conv_kernel")
+    if cfg.linear_key_heads != cfg.linear_value_heads:
+        raise NotImplementedError(
+            f"linear_value_heads={cfg.linear_value_heads} != "
+            f"linear_key_heads={cfg.linear_key_heads}: value heads that "
+            f"share a key head are not implemented")
+
+
+# The state crosses the sequence in order, so no sequence axis; its reset
+# and the convolution's mask at a document boundary are ROADMAP R11; every
+# leaf is whole on every chip.
+PART = parts.Part(
+    name="linear_attention", fields=_FIELDS, validate=_validate,
+    init=lambda k, cfg: dict(init_layer(k[0], cfg, parts.dense),
+                             ln1_scale=parts.ones(cfg.d_model)),
+    specs=lambda cfg, model_axis: parts.whole("ln1_scale", *LEAVES),
+    apply=parts.normed_mixer(mixer, scopes.GDN_PROJ, scopes.GDN_OUT),
+    record=lambda name, x, layer, cfg, ctx: record_blocks(name, x, cfg),
+    unsupported=parts.everywhere("layer_types"))

@@ -83,9 +83,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
 
 from horovod_tpu import telemetry
+from horovod_tpu.models import parts
 from horovod_tpu.models.linear_attention import causal_conv
 from horovod_tpu.ops import gated_norm as norm_kernels
 from horovod_tpu.ops import mamba2_scan as kernels
@@ -133,11 +133,6 @@ def init_layer(key, cfg, dense):
         "ssm_norm_scale": jnp.ones((inner,), jnp.float32),
         "ssm_w_out": dense(k[5], (inner, cfg.d_model)),
     }
-
-
-def layer_specs():
-    """Every leaf whole on every chip (no model axis: it is refused)."""
-    return {name: P() for name in LEAVES}
 
 
 def _mm(spec, a, b, dtype):
@@ -347,3 +342,34 @@ def record_chunks(layer: int, x, cfg) -> None:
         layer=str(layer)).set(saved_state_bytes(batch, t, cfg))
     short_conv.record_rows(layer, batch * t, conv_path(x, cfg))
     norm_kernels.record_rows(layer, batch * t, norm_path(x, cfg))
+
+
+# --- the mixer as a part (models/parts.py) ----------------------------------
+
+_FIELDS = ("ssm_heads", "ssm_head_dim", "ssm_state", "ssm_groups",
+           "ssm_conv_kernel", "ssm_chunk")
+
+
+def _validate(cfg, used):
+    sizes = tuple(getattr(cfg, name) for name in _FIELDS)
+    if not used:
+        if any(sizes):
+            raise ValueError("the ssm_* fields mean nothing without a "
+                             "'mamba2' entry in layer_types")
+    elif min(sizes) <= 0 or cfg.ssm_heads % cfg.ssm_groups:
+        raise ValueError(
+            "a 'mamba2' layer needs ssm_heads, ssm_head_dim, ssm_state, "
+            "ssm_groups (a divisor of ssm_heads), ssm_conv_kernel and "
+            "ssm_chunk")
+
+
+# As the linear mixer: the state crosses the sequence in order, and every
+# leaf is whole on every chip.
+PART = parts.Part(
+    name="mamba2", fields=_FIELDS, validate=_validate,
+    init=lambda k, cfg: dict(init_layer(k[0], cfg, parts.dense),
+                             ln1_scale=parts.ones(cfg.d_model)),
+    specs=lambda cfg, model_axis: parts.whole("ln1_scale", *LEAVES),
+    apply=parts.normed_mixer(mixer, scopes.SSM_PROJ, scopes.SSM_OUT),
+    record=lambda name, x, layer, cfg, ctx: record_chunks(name, x, cfg),
+    unsupported=parts.everywhere("layer_types"))

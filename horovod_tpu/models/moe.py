@@ -77,6 +77,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu import telemetry
+from horovod_tpu.models import parts
+from horovod_tpu.models.parts import dense, ones, rmsnorm, whole
 from horovod_tpu.ops import grouped_matmul as gmm
 from horovod_tpu.ops.grouped_matmul import (grouped_matmul,
                                             worst_matmul_rows)
@@ -634,3 +636,163 @@ def record_weight_copies(layer: int, weights) -> None:
             for h, name in zip(handed, (n for n in EXPERT_LEAVES
                                         if n in weights))
             if h.dtype != weights[name].dtype))
+
+
+# --- the three expert forms as parts (models/parts.py) ----------------------
+
+def _validate(cfg, used):
+    if not cfg.n_experts:
+        if (cfg.experts_per_token or cfg.d_expert or cfg.norm_topk_prob
+                or cfg.router_aux_coef or cfg.router_z_coef
+                or cfg.experts_held or cfg.experts_held_from):
+            raise ValueError("experts_per_token, d_expert, norm_topk_prob, "
+                             "experts_held* and the router loss coefficients "
+                             "mean nothing without n_experts")
+        return
+    if cfg.mlp == "gelu":
+        raise ValueError("n_experts > 0: the experts are SwiGLU "
+                         "(mlp='swiglu') or latent relu^2 (mlp='relu2')")
+    if not 0 < cfg.experts_per_token <= cfg.n_experts:
+        raise ValueError(
+            f"experts_per_token={cfg.experts_per_token} must lie "
+            f"in 1..n_experts={cfg.n_experts}")
+    if cfg.d_expert <= 0:
+        raise ValueError("n_experts > 0 needs d_expert, one expert's width")
+    if not (0 <= cfg.experts_held_from and
+            cfg.experts_held_from + cfg.held_experts <= cfg.n_experts):
+        raise ValueError(
+            f"experts_held={cfg.experts_held} from {cfg.experts_held_from} "
+            f"is not a range of the n_experts={cfg.n_experts}")
+
+
+def _validate_sigmoid(cfg, used):
+    if cfg.mlp == "relu2":      # the latent mixture's d_shared
+        return
+    if cfg.d_shared:
+        if cfg.mlp != "swiglu" or not cfg.n_experts:
+            raise ValueError(
+                "d_shared is the shared expert beside routed experts: it "
+                "needs n_experts and mlp='swiglu' (or 'relu2', the latent "
+                "mixture)")
+        if cfg.router_aux_coef or cfg.router_z_coef or cfg.norm_topk_prob:
+            raise NotImplementedError(
+                "d_shared with mlp='swiglu' is the sigmoid router: it has "
+                "no auxiliary loss (its balance is the selection bias's) "
+                "and always renormalises its top-k (norm_topk_prob is the "
+                "softmax router's)")
+    elif cfg.routed_scale != 1.0:
+        raise ValueError("routed_scale scales the sigmoid router's "
+                         "weights: it means nothing without d_shared")
+
+
+def _validate_latent(cfg, used):
+    if cfg.mlp != "relu2":
+        if cfg.d_latent:
+            raise ValueError("d_latent means nothing without mlp='relu2'")
+        return
+    if not cfg.n_experts or cfg.d_latent <= 0 or cfg.d_shared <= 0:
+        raise ValueError("mlp='relu2' is the latent mixture of experts: it "
+                         "needs n_experts, d_latent and d_shared")
+    if cfg.router_aux_coef or cfg.router_z_coef:
+        raise NotImplementedError(
+            "mlp='relu2': the sigmoid router has no auxiliary loss (its "
+            "balance is the selection bias's)")
+
+
+def _stacked(key, shape, cfg):
+    """[E held, in, out]: each expert a dense matrix of its own fan-in."""
+    return dense(key, (cfg.held_experts,) + shape, scale=shape[0] ** -0.5)
+
+
+def _init(k, cfg):
+    d, e = cfg.d_model, cfg.d_expert
+    k_up, k_router = parts.ffn_keys(k)
+    return dict(ln2_scale=ones(d), router=dense(k_router, (d, cfg.n_experts)),
+                w_gate=_stacked(k[4], (d, e), cfg),
+                w_up=_stacked(k_up, (d, e), cfg),
+                w_down=_stacked(k[5], (e, d), cfg))
+
+
+def _init_sigmoid(k, cfg):
+    d, s = cfg.d_model, cfg.d_shared
+    k_shared = jax.random.split(jax.random.fold_in(k[5], 1), 3)
+    return dict(
+        _init(k, cfg),
+        # Chooses and is not trained: its gradient is zero.
+        router_bias=jnp.zeros((cfg.n_experts,), jnp.float32),
+        w_shared_gate=dense(k_shared[0], (d, s)),
+        w_shared_up=dense(k_shared[1], (d, s)),
+        w_shared_down=dense(k_shared[2], (s, d)))
+
+
+def _init_latent(k, cfg):
+    d, e, lat, s = cfg.d_model, cfg.d_expert, cfg.d_latent, cfg.d_shared
+    k_up, k_router = parts.ffn_keys(k)
+    k_lat, k_shared = jax.random.split(jax.random.fold_in(k[5], 1))
+    return dict(
+        ln2_scale=ones(d), router=dense(k_router, (d, cfg.n_experts)),
+        # Chooses and is not trained: its gradient is zero.
+        router_bias=jnp.zeros((cfg.n_experts,), jnp.float32),
+        w_latent_in=dense(k_lat, (d, lat)),
+        w_latent_out=dense(jax.random.fold_in(k_lat, 1), (lat, d)),
+        w_up=_stacked(k_up, (lat, e), cfg),
+        w_down=_stacked(k[5], (e, lat), cfg),
+        w_shared_up=dense(k_shared, (d, s)),
+        w_shared_down=dense(jax.random.fold_in(k_shared, 1), (s, d)))
+
+
+def _applies(ffn, extras):
+    """rmsnorm -> ``ffn`` -> residual, under ``mlp``; ``extras(stats)`` is
+    what the loss collects of the second thing ``ffn`` returns."""
+    def apply(x, layer, cfg, ctx):
+        with jax.named_scope(scopes.MLP):
+            y, stats = ffn(rmsnorm(x, layer["ln2_scale"], cfg.norm_eps),
+                           layer, cfg)
+            return x + y, extras(stats)
+    return apply
+
+
+def _record(name, x, layer, cfg, ctx):
+    record_held(name, ctx.tokens, cfg)
+    record_weight_copies(name, layer)
+    if cfg.held_experts == cfg.n_experts:
+        # What lands on a share is data.
+        record_assignments(name, ctx.tokens * cfg.experts_per_token,
+                           cfg.n_experts)
+
+
+# What the three share.  Every held expert is whole on every chip of the
+# mesh (experts over an axis, and the exchange with the chips that hold the
+# others: ROADMAP R2); the grouped-matmul kernels' index maps read arrays
+# that vary over the batch axes, which the Pallas interpreter does not type
+# under shard_map's checker (ops/grouped_matmul.py).
+_EXPERTS = dict(record=_record, unsupported={"model_axis": ("n_experts",)},
+                check_vma=False)
+_SOFTMAX_LEAVES = ("ln2_scale", "router", "w_gate", "w_up", "w_down")
+
+SOFTMAX_EXPERTS = parts.Part(
+    name="softmax_experts",
+    fields=("n_experts", "experts_per_token", "d_expert", "norm_topk_prob",
+            "experts_held", "experts_held_from", "router_aux_coef",
+            "router_z_coef"),
+    validate=_validate, init=_init,
+    specs=lambda cfg, model_axis: whole(*_SOFTMAX_LEAVES),
+    apply=_applies(moe_ffn, lambda stats: {"router_stats": stats}),
+    **_EXPERTS)
+
+# The sigmoid router has no auxiliary loss: nothing for the loss to collect.
+SIGMOID_EXPERTS = parts.Part(
+    name="sigmoid_experts", fields=("d_shared", "routed_scale"),
+    validate=_validate_sigmoid, init=_init_sigmoid,
+    specs=lambda cfg, model_axis: whole(
+        *_SOFTMAX_LEAVES, "router_bias", "w_shared_gate", "w_shared_up",
+        "w_shared_down"),
+    apply=_applies(sigmoid_moe_ffn, lambda rows: {}), **_EXPERTS)
+
+LATENT_EXPERTS = parts.Part(
+    name="latent_experts", fields=("d_latent",),
+    validate=_validate_latent, init=_init_latent,
+    specs=lambda cfg, model_axis: whole(
+        "ln2_scale", "router", "router_bias", "w_latent_in", "w_latent_out",
+        "w_up", "w_down", "w_shared_up", "w_shared_down"),
+    apply=_applies(latent_moe_ffn, lambda rows: {}), **_EXPERTS)

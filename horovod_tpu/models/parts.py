@@ -1,0 +1,123 @@
+"""What a layer of the LM is made of: the seam between the step
+(:mod:`horovod_tpu.models.transformer`) and the parts it runs.
+
+A layer holds up to two **parts**, a sequence mixer and a feed-forward
+form, each a block of its own from norm to residual add.  A :class:`Part`
+states in one place everything the step has to know of one: the
+``TransformerConfig`` fields it owns and their rules, its leaves and how
+they are split over a model axis, its body, its trace-time series, and
+what it cannot run under.  The step keeps one table of them
+(``transformer.PARTS``) and one function that chooses
+(``transformer.layer_parts``); it branches on nothing else, so a new
+mixer or feed-forward form is one module and one row.
+
+The parts: :mod:`~horovod_tpu.models.attention` (plain, latent and learned
+sparse attention), :mod:`~horovod_tpu.models.linear_attention`,
+:mod:`~horovod_tpu.models.mamba2`, :mod:`~horovod_tpu.models.mlp` (the
+dense MLP) and :mod:`~horovod_tpu.models.moe` (softmax-routed,
+sigmoid-routed and latent experts).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Callable, Mapping, NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from horovod_tpu.telemetry import scopes
+
+
+class Ctx(NamedTuple):
+    """What the step hands every part's body beside the layer's input."""
+
+    model_axis: Optional[str]
+    seq_axis: Optional[str]
+    attention: str           # the route forward() was asked for
+    positions: Any           # [T] int32 of this shard's tokens; None: learned
+    tokens: int              # tokens of this shard's batch, B * T
+    segment_ids: Any = None  # [B, T] int32 (packing), a mixer's alone
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class Part:
+    """One sequence mixer or feed-forward form.
+
+    ``validate(cfg, used)``: its share of ``TransformerConfig``'s rules,
+    what ``fields`` need and that they mean nothing without it (``used``:
+    some layer of ``cfg`` holds it).  ``init(keys, cfg)``: its leaves, the
+    norm's scale among them, from the layer's six keys.  ``specs(cfg,
+    model_axis)``: a ``PartitionSpec`` a leaf.  ``apply(x, layer, cfg,
+    ctx)``: norm, body and residual under its own scopes, ``(x, extras)``
+    where ``extras`` maps a name to what the loss collects from every
+    layer (``router_stats``, ``index_kl``).  ``record(name, x, layer, cfg,
+    ctx)``: its trace-time series for layer ``name``.  ``unsupported``:
+    for each of ``model_axis``, ``seq_axis`` and ``segment_ids``, its
+    fields that it cannot run under that argument with (one that selects
+    the part, where it implements none of it).  ``check_vma``: its body
+    types under ``shard_map``'s checker of what varies over which axis."""
+
+    name: str
+    fields: Tuple[str, ...]
+    validate: Callable[[Any, bool], None]
+    init: Callable
+    specs: Callable
+    apply: Callable
+    record: Callable = lambda name, x, layer, cfg, ctx: None
+    unsupported: Mapping[str, Tuple[str, ...]] = dataclasses.field(
+        default_factory=dict)
+    check_vma: bool = True
+
+
+def everywhere(*fields):
+    """``unsupported`` of a part that runs over the data axis alone."""
+    return {what: fields for what in ("model_axis", "seq_axis",
+                                      "segment_ids")}
+
+
+def dense(key, shape, scale=None):
+    """A float32 matrix ~ N(0, scale^2); ``shape[0] ** -0.5`` (its fan-in)
+    without ``scale``."""
+    scale = scale if scale is not None else (shape[0] ** -0.5)
+    return jax.random.normal(key, shape, jnp.float32) * scale
+
+
+def ffn_keys(keys):
+    """``(k_up, k_router)``, what a feed-forward form draws beside the
+    layer's ``keys[4]`` and ``keys[5]``."""
+    return jax.random.split(jax.random.fold_in(keys[4], 1))
+
+
+def ones(width: int):
+    return jnp.ones((width,), jnp.float32)
+
+
+def whole(*names):
+    """Each leaf whole on every chip."""
+    return {name: P() for name in names}
+
+
+def normed_mixer(mixer, proj_scope: str, out_scope: str):
+    """``apply`` of a mixer that opens its own scopes (``attn/qkv/*``, its
+    scan, ``attn/out/*``): the norm is booked with its projections
+    (``proj_scope``) and the residual add with the out projection
+    (``out_scope``)."""
+    def apply(x, layer, cfg, ctx):
+        with jax.named_scope(scopes.ATTN_QKV), jax.named_scope(proj_scope):
+            h = rmsnorm(x, layer["ln1_scale"], cfg.norm_eps)
+        y = mixer(h, layer, cfg)
+        with jax.named_scope(scopes.ATTN_OUT), jax.named_scope(out_scope):
+            return x + y, {}
+    return apply
+
+
+def rmsnorm(x, scale, eps):
+    # Stats in f32; output in the INPUT dtype.  The scale param is f32,
+    # and without the cast it silently promoted every rmsnorm output —
+    # and therefore every qkv/mlp matmul INPUT — to f32: measured 63.5%
+    # -> 72.2% MFU on the d3584/L6 LM config from this one cast (r4).
+    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
+    return ((x * jax.lax.rsqrt(var + eps)).astype(x.dtype) *
+            scale.astype(x.dtype))

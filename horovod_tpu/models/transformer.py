@@ -14,77 +14,107 @@ param pytree, manual-SPMD forward) so it drops straight into ``shard_map``:
 
 bf16 matmuls / fp32 params+softmax, MXU-friendly dims.
 
-The block is config-driven (:class:`TransformerConfig`): the defaults are
-the GPT-2-style block (learned positions, GELU MLP, tied head); rotary
-positions, QK-norm, SwiGLU, an untied head and a dropless
-mixture-of-experts MLP (:mod:`horovod_tpu.models.moe`) are fields of the
-same config through the same ``forward`` and ``make_train_step`` — OLMoE's
-block is ``positions="rope", qk_norm=True, mlp="swiglu",
-tie_embeddings=False, n_experts=64, experts_per_token=8``.  ``layer_types``
-names each layer's sequence mixer: ``"full_attention"`` (the block above)
-or ``"linear_attention"`` (the gated delta rule of
-:mod:`horovod_tpu.models.linear_attention`, sized by the ``linear_*``
-fields); Olmo-Hybrid is three linear layers to one full one, with
-``positions="none"``.  Three more kinds are **one part alone**, with one
-norm and one residual add: ``"mamba2"`` (the state-space mixer of
-:mod:`horovod_tpu.models.mamba2`, sized by the ``ssm_*`` fields),
-``"attention"`` (softmax attention, no MLP) and ``"mlp"`` (the config's
-feed-forward part, no mixer).  ``n_kv_heads`` fewer than ``n_heads`` is
-grouped-query attention; ``mlp="relu2"`` with experts is the latent
-mixture of experts with a shared expert (:func:`moe.latent_moe_ffn`), of
-which this chip may hold a share (``experts_held``); ``mtp_layer_types``
-adds a multi-token-prediction module and its loss.  Nemotron-3 is
-``MEMEMEM*EME`` of those three, repeated.  ``kv_latent_rank`` makes the
-softmax attention **latent** (DeepSeek-V2's MLA, in the up-projected form
-training runs: :func:`_latent_qkv`), with heads of ``head_width`` that
-need not be ``d_model / n_heads`` and a rotary part of ``rope_dim``;
-``dense_layers`` leading layers keep a dense MLP of ``d_ff`` before the
-expert layers; ``mlp="swiglu"`` with ``d_shared`` is SwiGLU experts under
-the sigmoid router beside a shared SwiGLU expert
-(:func:`moe.sigmoid_moe_ffn`).  GLM-4.7-Flash is those three together,
-with a prediction module of one such layer.  ``head_width`` alone is
-plain attention whose heads are not ``d_model / n_heads`` wide,
-``qk_norm_per_head`` norms q and k a head at a time, and the ``index_*``
-fields put an indexer beside every attention layer that chooses the
-``index_topk`` keys each query reads and learns from its own loss
-(:mod:`horovod_tpu.ops.sparse_attention`); Keye-VL-2.0's language model is
-those three over grouped heads and softmax-routed experts.
+The block is config-driven (:class:`TransformerConfig`, whose comments say
+what each field means): the defaults are the GPT-2-style block (learned
+positions, GELU MLP, tied head), and every other block goes through the
+same ``forward`` and ``make_train_step``.  A layer holds up to two
+**parts** (:mod:`horovod_tpu.models.parts`), a sequence mixer and a
+feed-forward form; :data:`PARTS` is the table of them, and
+:func:`layer_parts` the one function that says which of them layer ``i``
+holds, from ``layer_types`` (one of :data:`LAYER_KINDS` a layer) and the
+fields that select a form.  This file runs the table: parameters, specs,
+the stack, the losses the parts hand back, the step, and the refusals,
+which are asked of the parts.  OLMoE, Olmo-Hybrid, Nemotron-3,
+GLM-4.7-Flash and Keye-VL-2.0's language model are configs, not code
+here.  ``decode_step`` and the pipelined builder implement the GPT-2 block
+alone and say so by name.
 """
 
 from __future__ import annotations
 
-import contextlib
+import collections
 import dataclasses
 import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.models import linear_attention, mamba2, moe
-from horovod_tpu.ops import sparse_attention
-from horovod_tpu.ops.flash_attention import flash_attention
+from horovod_tpu.models import (attention as attention_mod, linear_attention,
+                                mamba2, mlp as mlp_mod, moe, parts)
 from horovod_tpu.parallel import sequence as seq_mod
-from horovod_tpu.parallel import tensor as tp
 from horovod_tpu.telemetry import scopes
 
+# What perfbench/ reads here, under the names they always had.
+_rmsnorm = parts.rmsnorm
+_indexer_proj = attention_mod.indexer_proj
 
 FULL_ATTENTION = "full_attention"
 LINEAR_ATTENTION = "linear_attention"
 MAMBA2 = "mamba2"
 ATTENTION_ONLY = "attention"
 MLP_ONLY = "mlp"
-LAYER_TYPES = (FULL_ATTENTION, LINEAR_ATTENTION, MAMBA2, ATTENTION_ONLY,
-               MLP_ONLY)
-# What a layer of each type holds: its sequence mixer and whether the
-# config's feed-forward part follows it.
-_MIXER = {FULL_ATTENTION: FULL_ATTENTION, LINEAR_ATTENTION: LINEAR_ATTENTION,
-          MAMBA2: MAMBA2, ATTENTION_ONLY: FULL_ATTENTION, MLP_ONLY: None}
-_HAS_MLP = {FULL_ATTENTION: True, LINEAR_ATTENTION: True, MAMBA2: False,
-            ATTENTION_ONLY: False, MLP_ONLY: True}
+# What a layer of each type holds: its sequence mixer (a row of PARTS, or
+# "attention" for the form of softmax attention the config selects) and
+# whether the config's feed-forward form follows it.
+LAYER_KINDS = {FULL_ATTENTION: ("attention", True),
+               LINEAR_ATTENTION: ("linear_attention", True),
+               MAMBA2: ("mamba2", False),
+               ATTENTION_ONLY: ("attention", False),
+               MLP_ONLY: (None, True)}
+# Every part a layer can hold (models/parts.py), in the order their rules
+# are checked.
+PARTS = {part.name: part for part in (
+    linear_attention.PART, mamba2.PART, attention_mod.ATTENTION, mlp_mod.MLP,
+    mlp_mod.MLP_BESIDE_EXPERTS, attention_mod.LATENT_ATTENTION,
+    attention_mod.SPARSE_ATTENTION,
+    moe.LATENT_EXPERTS, moe.SIGMOID_EXPERTS, moe.SOFTMAX_EXPERTS)}
+# The fields no part owns: the model's sizes, its positions and head, the
+# layers' kinds and the prediction module.
+BLOCK_FIELDS = ("vocab_size", "d_model", "n_heads", "n_layers", "max_seq",
+                "dtype", "positions", "rope_theta", "norm_eps",
+                "tie_embeddings", "layer_types", "mtp_layer_types",
+                "mtp_loss_coef")
+
+
+def layer_parts(cfg, i: int, mtp: bool = False):
+    """``(mixer, feed-forward)``: the parts layer ``i`` of ``cfg`` holds
+    (of the prediction module's layers with ``mtp``), ``None`` where it
+    holds none.  **The one place that chooses**: everything else asks."""
+    kind = cfg.mtp_layer_types[i] if mtp else cfg.layer_type(i)
+    mixer, has_ffn = LAYER_KINDS[kind]
+    if mixer == "attention":
+        mixer = ("latent_attention" if cfg.latent_attention
+                 else "sparse_attention" if cfg.sparse_attention
+                 else "attention")
+    if not has_ffn:
+        ffn = None
+    elif not cfg.n_experts:
+        ffn = "mlp"
+    elif not mtp and i < cfg.dense_layers:
+        ffn = "mlp_beside_experts"
+    elif cfg.mlp == "relu2":
+        ffn = "latent_experts"
+    else:
+        ffn = "sigmoid_experts" if cfg.sigmoid_router else "softmax_experts"
+    return PARTS.get(mixer), PARTS.get(ffn)
+
+
+def stack_parts(cfg, mtp: bool = False):
+    """:func:`layer_parts` of every layer of the stack (of the prediction
+    module with ``mtp``)."""
+    count = len(cfg.mtp_layer_types) if mtp else cfg.n_layers
+    return [layer_parts(cfg, i, mtp) for i in range(count)]
+
+
+def parts_in_use(cfg):
+    """The parts that some layer of ``cfg`` holds, the prediction
+    module's among them, each once."""
+    chosen = stack_parts(cfg) + stack_parts(cfg, mtp=True)
+    return list(dict.fromkeys(
+        part for pair in chosen for part in pair if part))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,7 +186,7 @@ class TransformerConfig:
     # and of the router z-loss (moe.router_losses).
     router_aux_coef: float = 0.0
     router_z_coef: float = 0.0
-    # One of LAYER_TYPES per layer; empty: "full_attention" everywhere.
+    # One of LAYER_KINDS per layer; empty: "full_attention" everywhere.
     # A "linear_attention" layer's mixer is the gated delta rule
     # (models/linear_attention.py) over ``linear_key_heads`` heads of
     # ``linear_key_head_dim`` (q, k) and ``linear_value_head_dim`` (v, the
@@ -209,169 +239,26 @@ class TransformerConfig:
         if self.positions not in ("learned", "rope", "none"):
             raise ValueError(f"positions={self.positions!r}: expected "
                              f"'learned', 'rope' or 'none'")
-        linear = (self.linear_key_heads, self.linear_value_heads,
-                  self.linear_key_head_dim, self.linear_value_head_dim,
-                  self.linear_conv_kernel)
-        if self.layer_types:
-            unknown = set(self.layer_types) - set(LAYER_TYPES)
-            if unknown or len(self.layer_types) != self.n_layers:
-                raise ValueError(
-                    f"layer_types={self.layer_types!r}: expected n_layers="
-                    f"{self.n_layers} entries of {LAYER_TYPES}")
-        if self.has_linear_layers:
-            if min(linear) <= 0:
-                raise ValueError(
-                    "a 'linear_attention' layer needs linear_key_heads, "
-                    "linear_value_heads, linear_key_head_dim, "
-                    "linear_value_head_dim and linear_conv_kernel")
-            if self.linear_key_heads != self.linear_value_heads:
-                raise NotImplementedError(
-                    f"linear_value_heads={self.linear_value_heads} != "
-                    f"linear_key_heads={self.linear_key_heads}: value heads "
-                    f"that share a key head are not implemented")
-        elif any(linear) or self.linear_allow_neg_eigval:
-            raise ValueError("the linear_* fields mean nothing without a "
-                             "'linear_attention' entry in layer_types")
-        if set(self.mtp_layer_types) - set(LAYER_TYPES):
+        kinds = tuple(LAYER_KINDS)
+        if self.layer_types and (set(self.layer_types) - set(kinds)
+                                 or len(self.layer_types) != self.n_layers):
+            raise ValueError(
+                f"layer_types={self.layer_types!r}: expected n_layers="
+                f"{self.n_layers} entries of {kinds}")
+        if set(self.mtp_layer_types) - set(kinds):
             raise ValueError(f"mtp_layer_types={self.mtp_layer_types!r}: "
-                             f"expected entries of {LAYER_TYPES}")
+                             f"expected entries of {kinds}")
         if bool(self.mtp_layer_types) != bool(self.mtp_loss_coef):
             raise ValueError("mtp_layer_types and mtp_loss_coef come "
                              "together")
-        ssm = (self.ssm_heads, self.ssm_head_dim, self.ssm_state,
-               self.ssm_groups, self.ssm_conv_kernel, self.ssm_chunk)
-        if MAMBA2 in self.layer_types + self.mtp_layer_types:
-            if min(ssm) <= 0 or self.ssm_heads % self.ssm_groups:
-                raise ValueError(
-                    "a 'mamba2' layer needs ssm_heads, ssm_head_dim, "
-                    "ssm_state, ssm_groups (a divisor of ssm_heads), "
-                    "ssm_conv_kernel and ssm_chunk")
-        elif any(ssm):
-            raise ValueError("the ssm_* fields mean nothing without a "
-                             "'mamba2' entry in layer_types")
-        if self.n_kv_heads and self.n_heads % self.n_kv_heads:
-            raise ValueError(f"n_kv_heads={self.n_kv_heads} does not "
-                             f"divide n_heads={self.n_heads}")
-        if self.mlp not in ("gelu", "swiglu", "relu2"):
-            raise ValueError(f"mlp={self.mlp!r}: expected 'gelu', "
-                             f"'swiglu' or 'relu2'")
-        # A head width alone is plain attention with heads of that width.
-        latent = (self.q_latent_rank, self.kv_latent_rank, self.rope_dim)
-        if any(latent):
-            latent += (self.head_width,)
-            if (self.q_latent_rank or self.kv_latent_rank) \
-                    and not self.head_width:
-                raise ValueError(
-                    f"q_latent_rank={self.q_latent_rank}, kv_latent_rank="
-                    f"{self.kv_latent_rank}: latent attention needs "
-                    f"head_width, the width its up-projections give a "
-                    f"head")
-            if min(latent) <= 0:
-                raise ValueError(
-                    "q_latent_rank, kv_latent_rank and rope_dim come "
-                    "together, with head_width: they are latent attention")
-            if self.rope_dim > self.head_width:
-                raise ValueError(
-                    f"rope_dim={self.rope_dim} is wider than head_width="
-                    f"{self.head_width}: the rotary part is the tail of a "
-                    f"head")
-            if self.positions != "rope" or self.rope_dim % 2:
-                raise ValueError(
-                    f"latent attention carries position in its rotary "
-                    f"part: it needs positions='rope' and an even "
-                    f"rope_dim, got {self.positions!r} and "
-                    f"{self.rope_dim}")
-            if self.qk_norm or self.n_kv_heads or self.qk_norm_per_head:
-                raise NotImplementedError(
-                    "latent attention norms its latents and gives every "
-                    "head its own key: qk_norm, qk_norm_per_head and "
-                    "n_kv_heads are not implemented with it")
-        if self.qk_norm and self.qk_norm_per_head:
-            raise ValueError("qk_norm norms the whole projection, "
-                             "qk_norm_per_head each head: one of them")
-        sparse = (self.index_heads, self.index_head_dim, self.index_topk,
-                  self.indexer_loss_coef)
-        if any(sparse):
-            if min(sparse) <= 0:
-                raise ValueError(
-                    "index_heads, index_head_dim, index_topk and "
-                    "indexer_loss_coef come together: they are learned "
-                    "sparse attention")
-            if self.positions != "rope" or self.index_head_dim % 2:
-                raise ValueError(
-                    f"the indexer's queries and key are rotary: it needs "
-                    f"positions='rope' and an even index_head_dim, got "
-                    f"{self.positions!r} and {self.index_head_dim}")
-            if self.latent_attention:
-                raise NotImplementedError(
-                    "an indexer beside latent attention (index_heads with "
-                    "kv_latent_rank) is not implemented")
+        # Each part's own rules: what its fields need, and that they mean
+        # nothing without it.
+        used = parts_in_use(self)
+        for part in PARTS.values():
+            part.validate(self, part in used)
         if self.positions == "rope" and self.head_dim % 2:
             raise ValueError(f"positions='rope' needs an even head_dim, "
                              f"got {self.head_dim}")
-        if self.mlp == "relu2":
-            if (not self.n_experts or self.d_latent <= 0
-                    or self.d_shared <= 0):
-                raise ValueError("mlp='relu2' is the latent mixture of "
-                                 "experts: it needs n_experts, d_latent "
-                                 "and d_shared")
-            if self.router_aux_coef or self.router_z_coef:
-                raise NotImplementedError(
-                    "mlp='relu2': the sigmoid router has no auxiliary "
-                    "loss (its balance is the selection bias's)")
-        elif self.d_latent:
-            raise ValueError("d_latent means nothing without mlp='relu2'")
-        elif self.d_shared:
-            if self.mlp != "swiglu" or not self.n_experts:
-                raise ValueError(
-                    "d_shared is the shared expert beside routed experts: "
-                    "it needs n_experts and mlp='swiglu' (or 'relu2', the "
-                    "latent mixture)")
-            if (self.router_aux_coef or self.router_z_coef
-                    or self.norm_topk_prob):
-                raise NotImplementedError(
-                    "d_shared with mlp='swiglu' is the sigmoid router: it "
-                    "has no auxiliary loss (its balance is the selection "
-                    "bias's) and always renormalises its top-k "
-                    "(norm_topk_prob is the softmax router's)")
-        elif self.routed_scale != 1.0:
-            raise ValueError("routed_scale scales the sigmoid router's "
-                             "weights: it means nothing without d_shared")
-        if self.n_experts:
-            if self.mlp == "gelu":
-                raise ValueError("n_experts > 0: the experts are SwiGLU "
-                                 "(mlp='swiglu') or latent relu^2 "
-                                 "(mlp='relu2')")
-            if not 0 < self.experts_per_token <= self.n_experts:
-                raise ValueError(
-                    f"experts_per_token={self.experts_per_token} must lie "
-                    f"in 1..n_experts={self.n_experts}")
-            if self.d_expert <= 0:
-                raise ValueError("n_experts > 0 needs d_expert, one "
-                                 "expert's width")
-            if not (0 <= self.experts_held_from and
-                    self.experts_held_from + self.held_experts
-                    <= self.n_experts):
-                raise ValueError(
-                    f"experts_held={self.experts_held} from "
-                    f"{self.experts_held_from} is not a range of the "
-                    f"n_experts={self.n_experts}")
-            if not 0 <= self.dense_layers <= self.n_layers:
-                raise ValueError(
-                    f"dense_layers={self.dense_layers} must lie in "
-                    f"0..n_layers={self.n_layers}")
-            if self.dense_layers and self.mlp != "swiglu":
-                raise NotImplementedError(
-                    f"dense_layers={self.dense_layers} with mlp="
-                    f"{self.mlp!r}: the leading dense MLP is SwiGLU")
-        elif (self.experts_per_token or self.d_expert or self.norm_topk_prob
-              or self.router_aux_coef or self.router_z_coef
-              or self.experts_held or self.experts_held_from
-              or self.dense_layers):
-            raise ValueError("experts_per_token, d_expert, norm_topk_prob, "
-                             "experts_held*, dense_layers and the router "
-                             "loss coefficients mean nothing without "
-                             "n_experts")
 
     @property
     def head_dim(self) -> int:
@@ -405,166 +292,78 @@ class TransformerConfig:
     def held_experts(self) -> int:
         return self.experts_held or self.n_experts
 
-    @property
-    def has_linear_layers(self) -> bool:
-        return LINEAR_ATTENTION in self.layer_types
-
-    @property
-    def recurrent_layer_types(self) -> Tuple[str, ...]:
-        """The types in use whose state crosses the sequence in order."""
-        used = self.layer_types + self.mtp_layer_types
-        return tuple(t for t in (LINEAR_ATTENTION, MAMBA2) if t in used)
-
     def layer_type(self, i: int) -> str:
         return self.layer_types[i] if self.layer_types else FULL_ATTENTION
 
 
-def _refuse(cfg: TransformerConfig, where: str, fields) -> None:
-    """Raise for config fields ``where`` does not implement — never a
-    silent fall back to the default block.  ``fields``: names to check
-    against the dataclass defaults."""
-    defaults = TransformerConfig()
-    for name in fields:
-        if getattr(cfg, name) != getattr(defaults, name):
-            raise NotImplementedError(
-                f"{where} does not implement TransformerConfig.{name}="
-                f"{getattr(cfg, name)!r}")
+def _off_default(cfg, fields):
+    """``name=value`` for each of ``fields`` that ``cfg`` sets."""
+    defaults = TransformerConfig.__dataclass_fields__
+    return [f"TransformerConfig.{name}={getattr(cfg, name)!r}"
+            for name in fields if getattr(cfg, name) != defaults[name].default]
 
 
-def _refuse_with_recurrent_layers(cfg: TransformerConfig,
-                                  **arguments) -> None:
-    """Raise, by the argument's name, for what the recurrent layers
-    (linear attention, Mamba-2) do not implement: a sequence axis (the
-    state crosses chunk boundaries in order) and ``segment_ids`` /
-    ``packed`` (the state's reset and the convolution's mask at a
-    document boundary: ROADMAP R11).  Nor does the multi-token-prediction
-    module, whose second target is the next shard's or the next
-    document's at such a boundary."""
-    kinds = [f"the {kind!r} layers of TransformerConfig.layer_types"
-             for kind in cfg.recurrent_layer_types]
-    if cfg.mtp_layer_types:
-        kinds.append("the multi-token-prediction module "
-                     "(TransformerConfig.mtp_layer_types)")
+def _refuse_beyond_the_data_axis(cfg: TransformerConfig, **arguments) -> None:
+    """Raise, by the argument's name and the config field's, for what a
+    part of ``cfg`` does not implement: a tensor axis, a sequence axis,
+    ``segment_ids`` / ``packed`` — never a silent fall back.  Each part in
+    use is asked (``Part.unsupported``); the multi-token-prediction
+    module implements none of the three (its second target is the next
+    shard's or the next document's at a boundary)."""
+    asked = [(part.name, part.unsupported) for part in parts_in_use(cfg)]
+    asked.append(("the multi-token-prediction module",
+                  parts.everywhere("mtp_layer_types")))
     for name, value in arguments.items():
-        if kinds and value is not None and value is not False:
+        if value is None or value is False:
+            continue
+        what = "segment_ids" if name == "packed" else name
+        refused = [f"{part} with {', '.join(fields)}"
+                   for part, unsupported in asked
+                   if (fields := _off_default(cfg, unsupported.get(what, ())))]
+        if refused:
+            shown = f"={value!r}" if isinstance(value, (str, bool)) else ""
             raise NotImplementedError(
-                f"{name}={value!r}: {kinds[0]} do not implement it")
+                f"{name}{shown} is not implemented by "
+                + "; ".join(refused))
+
+
+# The fields of the GPT-2 block that decode_step implements and, but for
+# the last three, the pipelined builder: any other field of the config
+# that is set is refused by name, whichever configuration added it.
+_PIPELINED_FIELDS = ("vocab_size", "d_model", "n_heads", "n_layers", "d_ff",
+                     "max_seq", "dtype", "norm_eps", "rope_theta")
+_DECODE_FIELDS = _PIPELINED_FIELDS + ("qk_norm", "tie_embeddings", "mlp")
+
+
+def _refuse_all_but(cfg: TransformerConfig, where: str, implemented) -> None:
+    others = _off_default(cfg, [f.name for f in dataclasses.fields(cfg)
+                                if f.name not in implemented])
+    if others:
+        raise NotImplementedError(
+            f"{where} implements the GPT-2 block and not "
+            + ", ".join(others))
 
 
 def init_params(rng, cfg: TransformerConfig):
     """GLOBAL-shape parameters; shard with :func:`param_specs` +
     ``jax.device_put`` before use."""
     keys = jax.random.split(rng, 2 + cfg.n_layers)
-    d, f, v = cfg.d_model, cfg.d_ff, cfg.vocab_size
-    d_kv = cfg.kv_heads * cfg.head_dim
+    d, v = cfg.d_model, cfg.vocab_size
+    dense = parts.dense
 
-    def dense(key, shape, scale=None):
-        scale = scale if scale is not None else (shape[0] ** -0.5)
-        return (jax.random.normal(key, shape, jnp.float32) * scale)
-
-    def experts(key, shape):
-        # [E, in, out]: each expert a dense matrix of its own fan-in.
-        return dense(key, (cfg.held_experts,) + shape,
-                     scale=shape[0] ** -0.5)
-
-    def one_layer(key, kind, dense_mlp=False):
-        """A layer's leaves: a norm's scale and the weights of each part
-        it holds (``dense_mlp``: a dense MLP where the config has
-        experts)."""
+    def one_layer(key, chosen):
+        """A layer's leaves: those of each part it holds."""
         k = jax.random.split(key, 6)
-        k_up, k_router = jax.random.split(jax.random.fold_in(k[4], 1))
         layer = {}
-        if _MIXER[kind]:
-            layer["ln1_scale"] = jnp.ones((d,), jnp.float32)
-        if _HAS_MLP[kind]:
-            layer["ln2_scale"] = jnp.ones((d,), jnp.float32)
-        if _MIXER[kind] == LINEAR_ATTENTION:
-            layer.update(linear_attention.init_layer(k[0], cfg, dense))
-        elif _MIXER[kind] == MAMBA2:
-            layer.update(mamba2.init_layer(k[0], cfg, dense))
-        elif _MIXER[kind] == FULL_ATTENTION and cfg.latent_attention:
-            wide = cfg.n_heads * cfg.head_dim
-            r_q, r_kv = cfg.q_latent_rank, cfg.kv_latent_rank
-            layer.update(
-                w_qa=dense(k[0], (d, r_q)),
-                q_latent_norm_scale=jnp.ones((r_q,), jnp.float32),
-                w_qb=dense(k[1], (r_q, wide)),
-                # To [c_kv | k_r], the latent and the one rotary key.
-                w_kva=dense(k[2], (d, r_kv + cfg.rope_dim)),
-                kv_latent_norm_scale=jnp.ones((r_kv,), jnp.float32),
-                # To [k_n | v] of each head in turn.
-                w_kvb=dense(jax.random.fold_in(k[2], 1),
-                            (r_kv, 2 * wide - cfg.n_heads * cfg.rope_dim)),
-                wo=dense(k[3], (wide, d)))
-        elif _MIXER[kind] == FULL_ATTENTION:
-            wide = cfg.attn_width
-            layer.update(wq=dense(k[0], (d, wide)),
-                         wk=dense(k[1], (d, d_kv)),
-                         wv=dense(k[2], (d, d_kv)),
-                         wo=dense(k[3], (wide, d)))
-            if cfg.qk_norm:
-                layer["q_norm_scale"] = jnp.ones((d,), jnp.float32)
-                layer["k_norm_scale"] = jnp.ones((d_kv,), jnp.float32)
-            if cfg.qk_norm_per_head:
-                layer["q_norm_scale"] = jnp.ones((cfg.head_dim,),
-                                                 jnp.float32)
-                layer["k_norm_scale"] = jnp.ones((cfg.head_dim,),
-                                                 jnp.float32)
-            if cfg.sparse_attention:
-                k_index = jax.random.split(jax.random.fold_in(k[0], 1), 3)
-                layer.update(
-                    index_wq=dense(k_index[0], (
-                        d, cfg.index_heads * cfg.index_head_dim)),
-                    index_wk=dense(k_index[1], (d, cfg.index_head_dim)),
-                    index_ww=dense(k_index[2], (d, cfg.index_heads)))
-        if not _HAS_MLP[kind]:
-            return layer
-        if dense_mlp:
-            layer.update(w_gate=dense(k[4], (d, f)),
-                         w_up=dense(k_up, (d, f)),
-                         w_down=dense(k[5], (f, d)))
-        elif cfg.mlp == "relu2":
-            e, lat = cfg.d_expert, cfg.d_latent
-            k_lat, k_shared = jax.random.split(jax.random.fold_in(k[5], 1))
-            layer.update(
-                router=dense(k_router, (d, cfg.n_experts)),
-                # Chooses and is not trained: its gradient is zero.
-                router_bias=jnp.zeros((cfg.n_experts,), jnp.float32),
-                w_latent_in=dense(k_lat, (d, lat)),
-                w_latent_out=dense(jax.random.fold_in(k_lat, 1), (lat, d)),
-                w_up=experts(k_up, (lat, e)),
-                w_down=experts(k[5], (e, lat)),
-                w_shared_up=dense(k_shared, (d, cfg.d_shared)),
-                w_shared_down=dense(jax.random.fold_in(k_shared, 1),
-                                    (cfg.d_shared, d)))
-        elif cfg.n_experts:
-            e = cfg.d_expert
-            layer.update(router=dense(k_router, (d, cfg.n_experts)),
-                         w_gate=experts(k[4], (d, e)),
-                         w_up=experts(k_up, (d, e)),
-                         w_down=experts(k[5], (e, d)))
-            if cfg.sigmoid_router:
-                k_shared = jax.random.split(jax.random.fold_in(k[5], 1), 3)
-                layer.update(
-                    # Chooses and is not trained: its gradient is zero.
-                    router_bias=jnp.zeros((cfg.n_experts,), jnp.float32),
-                    w_shared_gate=dense(k_shared[0], (d, cfg.d_shared)),
-                    w_shared_up=dense(k_shared[1], (d, cfg.d_shared)),
-                    w_shared_down=dense(k_shared[2], (cfg.d_shared, d)))
-        elif cfg.mlp == "swiglu":
-            layer.update(w_gate=dense(k[4], (d, f)),
-                         w_up=dense(k_up, (d, f)),
-                         w_down=dense(k[5], (f, d)))
-        else:
-            layer.update(w1=dense(k[4], (d, f)), w2=dense(k[5], (f, d)))
+        for part in filter(None, chosen):
+            layer.update(part.init(k, cfg))
         return layer
 
     params = {
         "embed": dense(keys[0], (v, d), scale=0.02),
         "ln_f_scale": jnp.ones((d,), jnp.float32),
-        "layers": [one_layer(keys[2 + i], cfg.layer_type(i),
-                             i < cfg.dense_layers)
-                   for i in range(cfg.n_layers)],
+        "layers": [one_layer(key, chosen)
+                   for key, chosen in zip(keys[2:], stack_parts(cfg))],
     }
     if cfg.mtp_layer_types:
         k_mtp = jax.random.split(jax.random.fold_in(keys[1], 2),
@@ -573,8 +372,8 @@ def init_params(rng, cfg: TransformerConfig):
             "embed_norm_scale": jnp.ones((d,), jnp.float32),
             "hidden_norm_scale": jnp.ones((d,), jnp.float32),
             "w_eh": dense(k_mtp[0], (2 * d, d)),
-            "layers": [one_layer(key, kind) for key, kind in
-                       zip(k_mtp[1:], cfg.mtp_layer_types)],
+            "layers": [one_layer(key, chosen) for key, chosen in
+                       zip(k_mtp[1:], stack_parts(cfg, mtp=True))],
             "ln_f_scale": jnp.ones((d,), jnp.float32),
         }
     if cfg.positions == "learned":
@@ -587,271 +386,29 @@ def init_params(rng, cfg: TransformerConfig):
 def param_specs(cfg: TransformerConfig, model_axis: Optional[str]):
     """PartitionSpec tree matching :func:`init_params` output: Megatron TP
     sharding over ``model_axis`` (column-parallel outputs, row-parallel
-    inputs), everything else replicated."""
-    m = model_axis
-    col = P(None, m)     # split output dim
-    row = P(m, None)     # split input dim
-    attention = {"wq": col, "wk": col, "wv": col, "wo": row}
-    if cfg.qk_norm or cfg.qk_norm_per_head:
-        attention.update(q_norm_scale=P(), k_norm_scale=P())
-    if cfg.sparse_attention:
-        attention.update(index_wq=P(), index_wk=P(), index_ww=P())
-    if cfg.latent_attention:
-        # Whole on every chip (heads over the model axis: ROADMAP R16).
-        attention = {name: P() for name in (
-            "w_qa", "q_latent_norm_scale", "w_qb", "w_kva",
-            "kv_latent_norm_scale", "w_kvb", "wo")}
-    mixers = {FULL_ATTENTION: dict(attention, ln1_scale=P()),
-              LINEAR_ATTENTION: dict(linear_attention.layer_specs(),
-                                     ln1_scale=P()),
-              MAMBA2: dict(mamba2.layer_specs(), ln1_scale=P()),
-              None: {}}
-    mlp = {"ln2_scale": P()}
-    if cfg.mlp == "relu2":
-        # The experts this chip holds, whole (the exchange with the chips
-        # that hold the others: ROADMAP R2).
-        mlp.update({name: P() for name in (
-            "router", "router_bias", "w_latent_in", "w_latent_out", "w_up",
-            "w_down", "w_shared_up", "w_shared_down")})
-    elif cfg.n_experts:
-        # Every held expert on every chip of the mesh (experts over an
-        # axis: ROADMAP R2).
-        mlp.update(router=P(), w_gate=P(), w_up=P(), w_down=P())
-        if cfg.sigmoid_router:
-            mlp.update({name: P() for name in (
-                "router_bias", "w_shared_gate", "w_shared_up",
-                "w_shared_down")})
-    elif cfg.mlp == "swiglu":
-        mlp.update(w_gate=col, w_up=col, w_down=row)
-    else:
-        mlp.update(w1=col, w2=row)
-
-    # A leading dense MLP in a model with experts: whole, like them.
-    dense_mlp = {"ln2_scale": P(), "w_gate": P(), "w_up": P(), "w_down": P()}
-
-    def one_layer(kind, dense=False):
-        return dict((dense_mlp if dense else mlp) if _HAS_MLP[kind] else {},
-                    **mixers[_MIXER[kind]])
+    inputs) where a part splits its leaves, everything else replicated."""
+    def one_layer(chosen):
+        layer = {}
+        for part in filter(None, chosen):
+            layer.update(part.specs(cfg, model_axis))
+        return layer
 
     specs = {
         "embed": P(),
         "ln_f_scale": P(),
-        "layers": [one_layer(cfg.layer_type(i), i < cfg.dense_layers)
-                   for i in range(cfg.n_layers)],
+        "layers": [one_layer(chosen) for chosen in stack_parts(cfg)],
     }
     if cfg.mtp_layer_types:
         specs["mtp"] = {
             "embed_norm_scale": P(), "hidden_norm_scale": P(), "w_eh": P(),
-            "layers": [one_layer(kind) for kind in cfg.mtp_layer_types],
+            "layers": [one_layer(chosen)
+                       for chosen in stack_parts(cfg, mtp=True)],
             "ln_f_scale": P()}
     if cfg.positions == "learned":
         specs["pos"] = P()
     if not cfg.tie_embeddings:
         specs["head"] = P()
     return specs
-
-
-def _rmsnorm(x, scale, eps):
-    # Stats in f32; output in the INPUT dtype.  The scale param is f32,
-    # and without the cast it silently promoted every rmsnorm output —
-    # and therefore every qkv/mlp matmul INPUT — to f32: measured 63.5%
-    # -> 72.2% MFU on the d3584/L6 LM config from this one cast (r4).
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return ((x * jax.lax.rsqrt(var + eps)).astype(x.dtype) *
-            scale.astype(x.dtype))
-
-
-def _mlp_block(x, layer, cfg, model_axis):
-    """rmsnorm -> dense MLP (gelu, or SwiGLU) -> row-parallel psum ->
-    residual (shared by the training forward and the KV-cache decode so
-    the two cannot drift)."""
-    dt = cfg.dtype
-    h = _rmsnorm(x, layer["ln2_scale"], cfg.norm_eps)
-    hi = tp.region_input(h, model_axis) if model_axis else h
-    if cfg.mlp == "swiglu":
-        u = (jax.nn.silu(hi @ layer["w_gate"].astype(dt))
-             * (hi @ layer["w_up"].astype(dt)))
-        dn = u @ layer["w_down"].astype(dt)
-    else:
-        u = jax.nn.gelu(hi @ layer["w1"].astype(dt))
-        dn = u @ layer["w2"].astype(dt)
-    if model_axis:
-        dn = lax.psum(dn, model_axis)
-    return x + dn
-
-
-def _moe_block(x, layer, cfg):
-    """rmsnorm -> dropless mixture of experts -> residual; also the
-    router's sums for the auxiliary losses (None from the latent layer,
-    which has none)."""
-    h = _rmsnorm(x, layer["ln2_scale"], cfg.norm_eps)
-    if cfg.mlp == "relu2":
-        return x + moe.latent_moe_ffn(h, layer, cfg)[0], None
-    if cfg.sigmoid_router:
-        return x + moe.sigmoid_moe_ffn(h, layer, cfg)[0], None
-    y, stats = moe.moe_ffn(h, layer, cfg)
-    return x + y, stats
-
-
-def _holds_experts(layer) -> bool:
-    """Whether ``layer``'s feed-forward part is the config's experts: in
-    a model with ``dense_layers`` the leading layers hold a dense MLP
-    instead, and the tree says which (:func:`init_params`)."""
-    return "router" in layer
-
-
-def _rotary(x, positions, theta: float):
-    """Rotary embedding of ``x`` [..., T, H, head_dim] at ``positions``
-    [T], rotate-half convention (HF ``apply_rotary_pos_emb``): the pair
-    (x_i, x_{i + head_dim/2}) turns by ``position * theta^(-2i/head_dim)``.
-    Angles and the rotation in float32."""
-    half = x.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
-    cos = jnp.cos(angles)[:, None, :]
-    sin = jnp.sin(angles)[:, None, :]
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
-
-
-def _qkv_proj(x, layer, cfg, model_axis, positions=None, normed=None):
-    """rmsnorm -> q/k/v projections -> (QK-norm) -> head split ->
-    (rotary at ``positions`` [T]) (shared by forward, decode_step and
-    forward_pipelined so the projection math cannot drift).  Returns q,
-    k, v with a trailing [heads, head_dim] split.  ``normed``: the normed
-    ``x`` where the caller has it already (it hands it to an indexer too)."""
-    dt = cfg.dtype
-    h = (_rmsnorm(x, layer["ln1_scale"], cfg.norm_eps) if normed is None
-         else normed)
-    if cfg.latent_attention:
-        return _latent_qkv(h, layer, cfg, positions)
-    hi = tp.region_input(h, model_axis) if model_axis else h
-    q = hi @ layer["wq"].astype(dt)
-    k = hi @ layer["wk"].astype(dt)
-    v = hi @ layer["wv"].astype(dt)
-    if cfg.qk_norm:
-        q = _rmsnorm(q, layer["q_norm_scale"], cfg.norm_eps)
-        k = _rmsnorm(k, layer["k_norm_scale"], cfg.norm_eps)
-    dh = q.shape[-1]
-
-    def heads(a):
-        return a.reshape(a.shape[:-1] + (a.shape[-1] // cfg.head_dim,
-                                         cfg.head_dim))
-
-    q, k, v = heads(q), heads(k), heads(v)
-    # The per-head norm and the rotation after it are a part of their own
-    # in a trace; without the norm the rotation is booked as it always was.
-    with (jax.named_scope(scopes.QK_HEAD_NORM_ROPE) if cfg.qk_norm_per_head
-          else contextlib.nullcontext()):
-        if cfg.qk_norm_per_head:
-            q = _rmsnorm(q, layer["q_norm_scale"], cfg.norm_eps)
-            k = _rmsnorm(k, layer["k_norm_scale"], cfg.norm_eps)
-        if cfg.positions == "rope":
-            q = _rotary(q, positions, cfg.rope_theta)
-            k = _rotary(k, positions, cfg.rope_theta)
-    return q, k, v, dh
-
-
-@jax.named_scope(scopes.DSA_INDEX_PROJ)
-def _indexer_proj(u, layer, cfg, positions):
-    """The indexer's operands from the layer's normed input ``u`` [B, T, d],
-    whose gradient stops here (the indexer learns from its own loss and
-    moves nothing else): queries ``[B, T, index_heads, index_head_dim]`` and
-    ONE key head ``[B, T, index_head_dim]``, both rotary at ``positions``
-    over all their dims, and a weight a head ``[B, T, index_heads]``."""
-    dt = cfg.dtype
-    u = lax.stop_gradient(u)
-    qi = (u @ layer["index_wq"].astype(dt)).reshape(
-        u.shape[:-1] + (cfg.index_heads, cfg.index_head_dim))
-    ki = (u @ layer["index_wk"].astype(dt))[..., None, :]
-    w = u @ layer["index_ww"].astype(dt)
-    qi = _rotary(qi, positions, cfg.rope_theta)
-    ki = _rotary(ki, positions, cfg.rope_theta)[..., 0, :]
-    return qi, ki, w
-
-
-def _latent_qkv(h, layer, cfg, positions):
-    """Latent attention's q, k, v from the normed input ``h`` [..., T, d],
-    in the up-projected form (K and V materialised per head; the absorbed
-    form and a cache of latents are decode's: ROADMAP R13): ``c_q =
-    RMSNorm(h W_qa)``, ``q = c_q W_qb`` as heads of ``[q_n | q_r]``;
-    ``[c_kv | k_r] = h W_kva``, ``c_kv <- RMSNorm(c_kv)``, ``[k_n | v] =
-    c_kv W_kvb`` per head; ``q_r`` and ``k_r`` rotary at ``positions``,
-    ``k_r`` **one head that every head's key ends in** (so its gradient
-    sums over the heads); ``k = [k_n | k_r]``.  Returns q, k, v [..., T,
-    heads, head_width] and ``heads * head_width``."""
-    dt, heads, hd, rope = cfg.dtype, cfg.n_heads, cfg.head_dim, cfg.rope_dim
-    nope, rank = hd - rope, cfg.kv_latent_rank
-    with jax.named_scope(scopes.MLA_Q):
-        c_q = _rmsnorm(h @ layer["w_qa"].astype(dt),
-                       layer["q_latent_norm_scale"], cfg.norm_eps)
-        q = (c_q @ layer["w_qb"].astype(dt)).reshape(
-            h.shape[:-1] + (heads, hd))
-    with jax.named_scope(scopes.MLA_KV):
-        down = h @ layer["w_kva"].astype(dt)
-        c_kv = _rmsnorm(down[..., :rank], layer["kv_latent_norm_scale"],
-                        cfg.norm_eps)
-        up = (c_kv @ layer["w_kvb"].astype(dt)).reshape(
-            h.shape[:-1] + (heads, nope + hd))
-        k_n, v = up[..., :nope], up[..., nope:]
-    with jax.named_scope(scopes.MLA_ROPE):
-        q = jnp.concatenate(
-            [q[..., :nope], _rotary(q[..., nope:], positions,
-                                    cfg.rope_theta)], axis=-1)
-        k_r = _rotary(down[..., None, rank:], positions, cfg.rope_theta)
-        k = jnp.concatenate(
-            [k_n, jnp.broadcast_to(k_r, k_n.shape[:-1] + (rope,))], axis=-1)
-    return q, k, v, heads * hd
-
-
-def _share_kv_heads(k, v, n_heads: int):
-    """Grouped-query attention's K and V as the attention routes take
-    them, one head a query head: each key-value head repeated for the
-    ``n_heads / kv_heads`` query heads that read it (so dK and dV sum over
-    the group); as they are where the counts are equal.  A copy in HBM:
-    a kernel that reads head ``h // group`` instead is ROADMAP R3."""
-    group = n_heads // k.shape[-2]
-    if group == 1:
-        return k, v
-    return jnp.repeat(k, group, axis=-2), jnp.repeat(v, group, axis=-2)
-
-
-def _attn_out(o_flat, x, layer, dt, model_axis):
-    """Output projection (row-parallel psum under TP) + residual."""
-    o = o_flat @ layer["wo"].astype(dt)
-    if model_axis:
-        o = lax.psum(o, model_axis)
-    return x + o
-
-
-_flash_declined_shapes: set = set()
-
-
-def _flash_profitable(t: int) -> bool:
-    """``attention="auto"``'s flash-vs-lax decision, made at TRACE time
-    from the (static) sequence length.  With the kernel's auto block
-    sizes (r3 sweep, docs/kernels.md table): measured fwd-only PARITY at
-    T=1024 and measured wins from T=2048 up (fwd-only and fwd+bwd), so
-    1024 is the safe default threshold — at worst a tie; override with
-    HOROVOD_FLASH_AUTO_MIN_T.  Auto also refuses lengths the compiled
-    kernel cannot tile (indivisible by the 128-lane block) and falls
-    back to the lax path — ``auto`` NEVER raises on shape; only an
-    explicit ``attention="flash"`` may (the user asked for the kernel).
-    """
-    import os
-    min_t = int(os.environ.get("HOROVOD_FLASH_AUTO_MIN_T", "1024"))
-    if t >= min_t and t % 128 != 0:
-        if t not in _flash_declined_shapes:   # one-time per length
-            _flash_declined_shapes.add(t)
-            import logging
-            logging.getLogger("horovod_tpu").debug(
-                "attention='auto': T=%d is not divisible by 128; using "
-                "the lax attention path (pad the sequence to enable the "
-                "flash kernel)", t)
-        return False
-    return t >= min_t
 
 
 @jax.named_scope(scopes.HEAD)
@@ -862,37 +419,6 @@ def _logits_head(x, params, cfg):
     x = _rmsnorm(x, params["ln_f_scale"], cfg.norm_eps)
     w = params["embed"].T if cfg.tie_embeddings else params["head"]
     return (x @ w.astype(dt)).astype(jnp.float32)
-
-
-# Latent attention's fields, for the paths that refuse it by name: the
-# tensor axis (heads of a latent's up-projection over chips), the
-# sequence axis (the shared rotary key under the ring and Ulysses
-# routes), decode_step (a cache of latents, the absorbed form) and the
-# pipelined builder.
-_LATENT_FIELDS = ("head_width", "q_latent_rank", "kv_latent_rank",
-                  "rope_dim")
-# Learned sparse attention's, for the same four paths: a query's selected
-# keys lie on other chips under a sequence axis, the indexer's key cache
-# and a selection per decoded token are not written (ROADMAP R3), and the
-# per-head norm's scale is not split with the heads.
-_SPARSE_FIELDS = ("index_heads", "index_head_dim", "index_topk",
-                  "indexer_loss_coef", "qk_norm_per_head")
-
-
-def _refuse_under_model_axis(cfg, model_axis) -> None:
-    # QK-norm's statistics span the whole projection, which the model
-    # axis splits; the experts live whole on every chip (ROADMAP R2), and
-    # so do the linear-attention layers' heads.
-    if model_axis:
-        _refuse(cfg, f"model_axis={model_axis!r}",
-                ("qk_norm", "n_experts", "layer_types", "n_kv_heads",
-                 "mtp_layer_types") + _LATENT_FIELDS + _SPARSE_FIELDS)
-
-
-def _refuse_under_seq_axis(cfg, seq_axis) -> None:
-    if seq_axis:
-        _refuse(cfg, f"seq_axis={seq_axis!r}",
-                _LATENT_FIELDS + _SPARSE_FIELDS)
 
 
 def _remat_wrap(body, remat: str):
@@ -952,30 +478,22 @@ def forward_with_router_stats(params, tokens, cfg: TransformerConfig,
     K-side ids with the K/V blocks, Ulysses all-gathers them (int32 per
     token) after its head scatter.
     """
-    x, router_stats = _hidden_states(params, tokens, cfg, model_axis,
-                                     seq_axis, attention, segment_ids,
-                                     remat)[:2]
-    return _logits_head(x, params, cfg), router_stats
+    x, extras, _ = _hidden_states(params, tokens, cfg, model_axis, seq_axis,
+                                  attention, segment_ids, remat)
+    return _logits_head(x, params, cfg), extras["router_stats"]
 
 
 def _hidden_states(params, tokens, cfg: TransformerConfig, model_axis,
                    seq_axis, attention, segment_ids, remat):
-    """``(x, router stats, run_layers, index_kl)``: the last layer's
-    output before the final norm, one :class:`moe.RouterStats` per
-    softmax-routed MoE layer, the function that ran the stack
-    (``run_layers(x, layers, types, label)``), for the
-    multi-token-prediction module to run its own layers by, and the list
-    that every sparse attention layer run so far has put its indexer's
-    summed KL in."""
-    _refuse_under_model_axis(cfg, model_axis)
-    _refuse_under_seq_axis(cfg, seq_axis)
-    _refuse_with_recurrent_layers(cfg, seq_axis=seq_axis,
-                                  segment_ids=segment_ids)
-    if cfg.sparse_attention and segment_ids is not None:
-        raise NotImplementedError(
-            "segment_ids: learned sparse attention "
-            "(TransformerConfig.index_topk) does not implement it: the "
-            "selection would have to stay inside a document")
+    """``(x, extras, run_layers)``: the last layer's output before the
+    final norm; what the loss collects from the layers run so far, a list
+    a name (``router_stats``: one :class:`moe.RouterStats` per
+    softmax-routed MoE layer; ``index_kl``: every sparse attention
+    layer's summed KL of its indexer); and the function that ran the
+    stack (``run_layers(x, layers, chosen, label)``), for the
+    multi-token-prediction module to run its own layers by."""
+    _refuse_beyond_the_data_axis(cfg, model_axis=model_axis,
+                                 seq_axis=seq_axis, segment_ids=segment_ids)
     dt = cfg.dtype
     t_local = tokens.shape[1]
     with jax.named_scope(scopes.EMBED):
@@ -988,143 +506,34 @@ def _hidden_states(params, tokens, cfg: TransformerConfig, model_axis,
         else:
             positions = pos_offset + jnp.arange(t_local)
             x = params["embed"][tokens].astype(dt)
+    ctx = parts.Ctx(model_axis, seq_axis, attention, positions, tokens.size)
+    extras = collections.defaultdict(list)
 
-    def attention_part(x, layer, segment_ids):
-        # --- attention block (each route opens its own attn/<route>) ---
-        if cfg.sparse_attention:
-            # The route of its own: the indexer chooses each query's keys
-            # (ops/sparse_attention.py), whatever ``attention`` says.
-            with jax.named_scope(scopes.ATTN_QKV):
-                u = _rmsnorm(x, layer["ln1_scale"], cfg.norm_eps)
-                q, k, v, dh = _qkv_proj(x, layer, cfg, model_axis, positions,
-                                        normed=u)
-                qi, ki, w = _indexer_proj(u, layer, cfg, positions)
-            with jax.named_scope(scopes.ATTN_FLASH):
-                o, kl = sparse_attention.dsa_attention(
-                    q, k, v, qi, ki, w, topk=cfg.index_topk,
-                    index_scale=(cfg.index_heads
-                                 * cfg.index_head_dim) ** -0.5)
-            with jax.named_scope(scopes.ATTN_OUT):
-                return (_attn_out(o.reshape(o.shape[:2] + (dh,)), x, layer,
-                                  dt, model_axis), jnp.sum(kl))
-        with jax.named_scope(scopes.ATTN_QKV):
-            q, k, v, dh = _qkv_proj(x, layer, cfg, model_axis, positions)
-        b, t = q.shape[:2]
-        flash = seq_axis is None and (
-            attention in ("flash", "ring_flash")
-            or (attention == "auto" and _flash_profitable(t)))
-        with jax.named_scope(scopes.ATTN_FLASH if flash
-                             else scopes.ATTN_QKV):
-            k, v = _share_kv_heads(k, v, q.shape[-2])
-        if seq_axis is not None:
-            if attention == "ring_flash" or (attention == "auto" and
-                                             _flash_profitable(t)):
-                # Ring attention with the flash kernel as the per-step
-                # block math: auto upgrades when the LOCAL chunk length
-                # clears the kernel's measured crossover.
-                o = seq_mod.ring_flash_attention(
-                    q, k, v, seq_axis, True, None, None, segment_ids)
-            elif attention in ("ring", "auto"):
-                o = seq_mod.ring_attention(q, k, v, seq_axis, causal=True,
-                                           segment_ids=segment_ids)
-            elif attention == "ulysses":
-                o = seq_mod.ulysses_attention(q, k, v, seq_axis, causal=True,
-                                              segment_ids=segment_ids)
-            else:
-                # The single-device flash kernel route makes no sense
-                # under a sequence axis; K/V blocks arrive over ICI and
-                # the blockwise math lives in ring[_flash]_attention.
-                # Never silently substitute a different algorithm.
-                raise ValueError(
-                    f"attention={attention!r} is not available with a "
-                    f"sequence axis; choose 'ring', 'ring_flash' or "
-                    f"'ulysses'")
-        elif flash:
-            # Pallas flash kernel (ops/flash_attention.py): same exact
-            # math blockwise in VMEM; requires T divisible by its blocks.
-            # 'ring_flash' without a seq axis degenerates to exactly
-            # this kernel (a 1-ring's only step is the diagonal one) —
-            # the user still measures the algorithm they selected.
-            o = flash_attention(q, k, v, True, segment_ids=segment_ids)
-        else:
-            o = seq_mod.local_attention(q, k, v, causal=True,
-                                        segment_ids=segment_ids)
-        with jax.named_scope(scopes.ATTN_OUT):
-            return _attn_out(o.reshape(b, t, dh), x, layer, dt, model_axis)
+    @functools.cache
+    def block(part):
+        """A part's block, norm to residual, recomputed by itself."""
+        return _remat_wrap(
+            lambda x, layer, segment_ids: part.apply(
+                x, layer, cfg, ctx._replace(segment_ids=segment_ids)), remat)
 
-    def mlp_part(x, layer):
-        with jax.named_scope(scopes.MLP):
-            if _holds_experts(layer):
-                return _moe_block(x, layer, cfg)
-            if cfg.n_experts:
-                # A leading dense layer of a model with experts.
-                with jax.named_scope(scopes.MLP_DENSE):
-                    return _mlp_block(x, layer, cfg, model_axis), None
-            return _mlp_block(x, layer, cfg, model_axis), None
-
-    def linear_attention_part(x, layer, segment_ids):
-        # The mixer opens its own scopes (attn/qkv/gdn_*, attn/gdn_scan,
-        # attn/out/gdn_*); the norm is booked with its projections and
-        # the residual add with the out projection.
-        with jax.named_scope(scopes.ATTN_QKV), \
-                jax.named_scope(scopes.GDN_PROJ):
-            h = _rmsnorm(x, layer["ln1_scale"], cfg.norm_eps)
-        y = linear_attention.mixer(h, layer, cfg)
-        with jax.named_scope(scopes.ATTN_OUT), \
-                jax.named_scope(scopes.GDN_OUT):
-            return x + y
-
-    def mamba2_part(x, layer, segment_ids):
-        # As the linear mixer: attn/qkv/ssm_*, attn/ssm_scan,
-        # attn/out/ssm_*.
-        with jax.named_scope(scopes.ATTN_QKV), \
-                jax.named_scope(scopes.SSM_PROJ):
-            h = _rmsnorm(x, layer["ln1_scale"], cfg.norm_eps)
-        y = mamba2.mixer(h, layer, cfg)
-        with jax.named_scope(scopes.ATTN_OUT), \
-                jax.named_scope(scopes.SSM_OUT):
-            return x + y
-
-    mixers = {FULL_ATTENTION: _remat_wrap(attention_part, remat),
-              LINEAR_ATTENTION: _remat_wrap(linear_attention_part, remat),
-              MAMBA2: _remat_wrap(mamba2_part, remat)}
-    mlp_part = _remat_wrap(mlp_part, remat)
-    router_stats, index_kl = [], []
-
-    def run_layers(x, layers, types, label="%d"):
-        """``x`` through ``layers`` of ``types``; ``label % i`` names
-        layer ``i`` in the trace-time series."""
-        for i, (layer, kind) in enumerate(zip(layers, types)):
-            mixer, name = _MIXER[kind], label % i
+    def run_layers(x, layers, chosen, label="%d"):
+        """``x`` through ``layers``, each holding the parts ``chosen``
+        names for it; ``label % i`` names layer ``i`` in the trace-time
+        series."""
+        for i, (layer, (mixer, ffn)) in enumerate(zip(layers, chosen)):
             with jax.named_scope(scopes.LAYER % i):
-                if mixer:
-                    x = mixers[mixer](x, layer, segment_ids)
-                if mixer == FULL_ATTENTION and cfg.sparse_attention:
-                    x, kl = x
-                    index_kl.append(kl)
-                if _HAS_MLP[kind]:
-                    x, stats = mlp_part(x, layer)
-            if mixer == LINEAR_ATTENTION:
-                linear_attention.record_blocks(name, x, cfg)
-            if mixer == MAMBA2:
-                mamba2.record_chunks(name, x, cfg)
-            if mixer == FULL_ATTENTION and cfg.sparse_attention:
-                sparse_attention.record_path(sparse_attention.path(x))
-            if _HAS_MLP[kind] and _holds_experts(layer):
-                moe.record_held(name, tokens.size, cfg)
-                moe.record_weight_copies(name, layer)
-                if stats is not None:
-                    router_stats.append(stats)
-                if cfg.held_experts == cfg.n_experts:
-                    # What lands on a share is data.
-                    moe.record_assignments(
-                        name, tokens.size * cfg.experts_per_token,
-                        cfg.n_experts)
+                # Packing is the mixer's business alone.
+                for part, ids in ((mixer, segment_ids), (ffn, None)):
+                    if part:
+                        x, extra = block(part)(x, layer, ids)
+                        for name, value in extra.items():
+                            extras[name].append(value)
+            for part in filter(None, (mixer, ffn)):
+                part.record(label % i, x, layer, cfg, ctx)
         return x
 
-    x = run_layers(x, params["layers"],
-                   [cfg.layer_type(i) for i in range(cfg.n_layers)])
-    return x, router_stats, run_layers, index_kl
+    x = run_layers(x, params["layers"], stack_parts(cfg))
+    return x, extras, run_layers
 
 
 @jax.named_scope(scopes.LOSS)
@@ -1158,7 +567,8 @@ def _mtp_loss(params, x, labels, cfg: TransformerConfig, run_layers):
             here = _rmsnorm(x, mtp["hidden_norm_scale"], cfg.norm_eps)
             h = (jnp.concatenate([ahead, here], axis=-1)
                  @ mtp["w_eh"].astype(dt))
-        h = run_layers(h, mtp["layers"], cfg.mtp_layer_types, "mtp_%d")
+        h = run_layers(h, mtp["layers"], stack_parts(cfg, mtp=True),
+                       "mtp_%d")
         logits = _logits_head(h, dict(params, ln_f_scale=mtp["ln_f_scale"]),
                               cfg)
         with jax.named_scope(scopes.LOSS):
@@ -1176,7 +586,7 @@ def loss_fn(params, tokens, labels, cfg: TransformerConfig,
     all layers' tokens together.  ``batch_axes``: the mesh axes the batch
     is split over, so that the mean of the shards' losses is the global
     batch's loss (:func:`moe.router_losses`)."""
-    x, router_stats, run_layers, index_kl = _hidden_states(
+    x, extras, run_layers = _hidden_states(
         params, tokens, cfg, model_axis, seq_axis, attention, segment_ids,
         remat)
     loss = xent(_logits_head(x, params, cfg), labels)
@@ -1184,6 +594,8 @@ def loss_fn(params, tokens, labels, cfg: TransformerConfig,
         ahead = _mtp_loss(params, x, labels, cfg, run_layers)
         with jax.named_scope(scopes.LOSS):
             loss = loss + cfg.mtp_loss_coef * ahead
+    # Read after the module has run: its layers' are among them.
+    index_kl, router_stats = extras["index_kl"], extras["router_stats"]
     if index_kl:
         # Every sparse layer run, the prediction module's included: the
         # mean over this shard's tokens of each, summed over layers.
@@ -1242,9 +654,8 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
     """
     from horovod_tpu.ops.fusion import fused_pytree_mean
 
-    _refuse_under_model_axis(cfg, model_axis)
-    _refuse_under_seq_axis(cfg, seq_axis)
-    _refuse_with_recurrent_layers(cfg, seq_axis=seq_axis, packed=packed)
+    _refuse_beyond_the_data_axis(cfg, model_axis=model_axis,
+                                 seq_axis=seq_axis, packed=packed)
     specs = param_specs(cfg, model_axis)
     grad_axes = tuple(a for a in (data_axis, seq_axis) if a)
 
@@ -1354,7 +765,8 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
         # grouped-matmul kernels in the Pallas interpreter (their index
         # maps read arrays that vary over the batch axes,
         # ops/grouped_matmul.py); the plain dense path keeps it on.
-        check_vma=zopt is None and not cfg.n_experts)
+        check_vma=zopt is None and all(part.check_vma
+                                       for part in parts_in_use(cfg)))
     jitted = jax.jit(scopes.named(step, scopes.LM_TRAIN_STEP),
                      donate_argnums=(0, 1) if donate else ())
     if zopt is not None:
@@ -1405,9 +817,7 @@ def decode_step(params, token, cache, pos, cfg: TransformerConfig,
     # A rotated key cache, an expert layer per token and a recurrent
     # layer's state and convolution window beside the key cache are not
     # written (serving: ROADMAP R8/R13).
-    _refuse(cfg, "decode_step", _LATENT_FIELDS + _SPARSE_FIELDS + (
-        "positions", "n_experts", "layer_types", "n_kv_heads",
-        "mtp_layer_types"))
+    _refuse_all_but(cfg, "decode_step", _DECODE_FIELDS)
     dt = cfg.dtype
     hd = cfg.head_dim
     x = (params["embed"][token] +
@@ -1415,7 +825,7 @@ def decode_step(params, token, cache, pos, cfg: TransformerConfig,
          ).astype(dt)                                    # [B, D]
     new_cache = []
     for layer, c in zip(params["layers"], cache):
-        q, k, v, dh = _qkv_proj(x, layer, cfg, model_axis)
+        q, k, v, dh = attention_mod.qkv_proj(x, layer, cfg, model_axis)
         b = q.shape[0]
         # Defensive cast: the cache is cfg.dtype forever; any future
         # dtype drift upstream (the r4 rmsnorm f32-scale promotion was
@@ -1436,8 +846,9 @@ def decode_step(params, token, cache, pos, cfg: TransformerConfig,
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bht,bthd->bhd", p,
                        cv.astype(jnp.float32)).astype(dt)
-        x = _attn_out(o.reshape(b, dh), x, layer, dt, model_axis)
-        x = _mlp_block(x, layer, cfg, model_axis)
+        x = attention_mod.attn_out(o.reshape(b, dh), x, layer, dt,
+                                   model_axis)
+        x = mlp_mod.mlp_block(x, layer, cfg, model_axis)
     return _logits_head(x, params, cfg), new_cache
 
 
@@ -1578,13 +989,14 @@ def _pipe_stage_fn(cfg: TransformerConfig):
 
     def one_layer(x, lp):
         with jax.named_scope(scopes.ATTN_QKV):
-            q, k, v, dh = _qkv_proj(x, lp, cfg, None)
+            q, k, v, dh = attention_mod.qkv_proj(x, lp, cfg, None)
         bb, tt = q.shape[:2]
         o = seq_mod.local_attention(q, k, v, causal=True)
         with jax.named_scope(scopes.ATTN_OUT):
-            x = _attn_out(o.reshape(bb, tt, dh), x, lp, dt, None)
+            x = attention_mod.attn_out(o.reshape(bb, tt, dh), x, lp, dt,
+                                           None)
         with jax.named_scope(scopes.MLP):
-            x = _mlp_block(x, lp, cfg, None)
+            x = mlp_mod.mlp_block(x, lp, cfg, None)
         # attention computes in f32; pin the carried activation to the
         # model dtype so the layer scan (and the pipeline's microbatch
         # buffers) keep a stable, bf16-safe type
@@ -1671,10 +1083,7 @@ def make_train_step_pipelined(cfg: TransformerConfig, optimizer, mesh,
 
     # The pipelined forward embeds with the position table, scans stacked
     # dense layers of one type and returns no router sums.
-    _refuse(cfg, "make_train_step_pipelined",
-            _LATENT_FIELDS + _SPARSE_FIELDS + (
-        "positions", "qk_norm", "tie_embeddings", "mlp", "n_experts",
-        "layer_types", "n_kv_heads", "mtp_layer_types"))
+    _refuse_all_but(cfg, "make_train_step_pipelined", _PIPELINED_FIELDS)
     n_stages = mesh.shape[pipe_axis]
     v_eff = (virtual if schedule in ("interleaved", "interleaved_1f1b")
              else 1)

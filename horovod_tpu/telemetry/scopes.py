@@ -62,7 +62,7 @@ SSM_CONV = "ssm_conv"
 SSM_GATE_NORM = "ssm_gate_norm"
 SSM_OUT = "ssm_out"
 
-# The parts of latent attention's projections (transformer._latent_qkv),
+# The parts of latent attention's projections (models/attention.latent_qkv),
 # bare components under ATTN_QKV: the query's way through its latent, the
 # keys' and values' through theirs, and the rotary part with the
 # concatenations that put a head together.
@@ -71,7 +71,7 @@ MLA_KV = "mla_kv"
 MLA_ROPE = "mla_rope"
 
 # The parts of learned sparse attention (ops/sparse_attention.py,
-# transformer._indexer_proj), bare components as latent attention's are.
+# models/attention.indexer_proj), bare components as latent attention's are.
 # Under ATTN_QKV: the indexer's three projections and its rotation, and
 # the per-head QK-norm with the rotation of q and k.  The route itself
 # opens under ATTN_FLASH (it is the flash kernels' work under a mask, and

@@ -16,26 +16,23 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
-from horovod_tpu.models import moe
+from horovod_tpu.models import attention, moe
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.ops import sparse_attention as sa
 from perfbench.reference import dsa_moe_lm as reference
 
 F32_REL = 5e-5
 
-# 4 query heads over 2 key-value heads of 32 on a hidden size of 64 (4 x
-# 32 = 128, twice the hidden size, as 32 x 128 is of 2048); 4 indexer
-# heads of 16, 32 keys a query of 128; 8 experts top-2, 4 held from 2.
-KEYE_TINY = tfm.TransformerConfig(
-    vocab_size=128, d_model=64, n_heads=4, n_kv_heads=2, head_width=32,
-    n_layers=2, d_ff=0, max_seq=128, dtype=jnp.float32, positions="rope",
-    rope_theta=1e7, norm_eps=1e-6, tie_embeddings=False,
-    qk_norm_per_head=True, index_heads=4, index_head_dim=16, index_topk=32,
-    indexer_loss_coef=1.0, mlp="swiglu", n_experts=8, experts_per_token=2,
-    d_expert=48, norm_topk_prob=True, experts_held=4, experts_held_from=2)
+# The families every configuration shares, and the table of configurations
+# (tests/test_lm_configs.py); those that compile this row's program run
+# here, in the row's own file: a file is one worker's chain.
+from test_lm_configs import *  # noqa: E402,F401,F403
+from test_lm_configs import KEYE_TINY, keye_dims as _dims  # noqa: E402
+
+COSTLY_ROWS = ("keye",)
+
 INDEX_LEAVES = ("index_wq", "index_wk", "index_ww")
 
 
@@ -58,73 +55,20 @@ def _batch(cfg, batch=2, seq=128, seed=1):
     return toks[:, :-1], toks[:, 1:]
 
 
-def _dims(cfg):
-    return {"n_heads": cfg.n_heads, "n_kv_heads": cfg.kv_heads,
-            "head_dim": cfg.head_dim, "index_heads": cfg.index_heads,
-            "index_head_dim": cfg.index_head_dim, "topk": cfg.index_topk,
-            "eps": cfg.norm_eps, "theta": cfg.rope_theta,
-            "top_k": cfg.experts_per_token,
-            "held_from": cfg.experts_held_from}
-
-
-def _reference(cfg, params, tokens, labels, **kw):
-    """Every leaf the reference can differentiate, not only the cell's."""
-    kw.setdefault("names", tuple(reference.LEAVES))
-    kw.setdefault("index_coef", cfg.indexer_loss_coef)
-    return reference.loss_and_tail_grads(params, tokens, labels,
-                                         dims=_dims(cfg), **kw)
-
-
-def _checked(tree, cfg=KEYE_TINY):
-    return {name: reference.leaf(tree, path)
-            for name, path in reference.leaf_paths(cfg.n_layers).items()}
-
-
 # --- the model against the reference ------------------------------------------
 
-def test_loss_and_every_leaf_match_the_reference():
-    """Float32 program against the float32 reference: the loss, both
-    terms, and the gradient of every leaf of a layer: the three attention
-    projections behind the masked softmax, both per-head norms, ``W_o``,
-    the indexer's three matrices, the router, the three expert matrices,
-    both layer norms and the final norm."""
-    cfg = KEYE_TINY
-    params, (tokens, labels) = _params(cfg), _batch(cfg)
+
+def test_the_loss_is_the_cross_entropy_plus_the_indexers_kl():
+    """Both terms are the reference's: the loss without the indexer's is
+    its cross-entropy, and the rest its KL."""
+    row = built("keye")
+    loss, _ = row.program()
+    _, _, stats = row.reference()
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(tfm.loss_fn)(
-            params, tokens, labels, cfg)
-        want, want_g, stats = _reference(cfg, params, tokens, labels)
-        ce = tfm.loss_fn(params, tokens, labels, dataclasses.replace(
-            cfg, indexer_loss_coef=1e-30))
-    assert abs(loss - want) <= F32_REL * abs(want)
+        ce = tfm.loss_fn(row.params, *row.batch(), dataclasses.replace(
+            row.cfg, indexer_loss_coef=1e-30))
     assert abs(ce - stats["ce"]) <= F32_REL * abs(ce)
-    assert float(stats["index_kl"]) > 1e-3
     assert abs((loss - ce) - stats["index_kl"]) <= 1e-4 * stats["index_kl"]
-    assert set(want_g) == set(reference.LEAVES)
-    got_g = _checked(grads)
-    for name, g in want_g.items():
-        assert float(jnp.linalg.norm(g)) > 0, name
-        assert _rel(got_g[name], g) <= F32_REL, name
-    assert stats["rows"].shape == (2, 4)
-
-
-@pytest.mark.parametrize("control,moved", [
-    (dict(low_precision=jnp.float8_e4m3fn), "wo_last"),
-    (dict(topk=16), "wk_last"),
-    (dict(select=False), "wk_last"),
-    (dict(index_coef=0.0), "index_wq_last")],
-    ids=["float8", "half_the_keys", "no_selection", "no_indexer_loss"])
-def test_the_oracle_sees_what_the_cells_controls_change(control, moved):
-    """The four references that the cell's check must refuse are other
-    functions at this size too."""
-    cfg = KEYE_TINY
-    params, (tokens, labels) = _params(cfg), _batch(cfg)
-    want, want_g, _ = _reference(cfg, params, tokens, labels,
-                                 names=reference.CHECKED)
-    off, off_g, _ = _reference(cfg, params, tokens, labels,
-                               names=reference.CHECKED, **control)
-    assert abs(off - want) > 1e-4 * abs(want)
-    assert _rel(off_g[moved], want_g[moved]) > 0.02
 
 
 def test_each_loss_reaches_its_own_leaves_alone():
@@ -154,20 +98,6 @@ def test_each_loss_reaches_its_own_leaves_alone():
     assert seen == set(INDEX_LEAVES)
 
 
-@pytest.mark.parametrize("remat", ("dots", "full"))
-def test_remat_leaves_loss_and_gradients_alone(remat):
-    cfg = KEYE_TINY
-    params, (tokens, labels) = _params(cfg), _batch(cfg)
-    with jax.default_matmul_precision("highest"):
-        want, want_g = jax.value_and_grad(tfm.loss_fn)(
-            params, tokens, labels, cfg)
-        loss, grads = jax.value_and_grad(tfm.loss_fn)(
-            params, tokens, labels, cfg, remat=remat)
-    assert abs(loss - want) <= 1e-6 * abs(want)
-    for name, g in _checked(want_g).items():
-        assert _rel(_checked(grads)[name], g) <= 1e-5, name
-
-
 def test_the_heads_are_normed_one_at_a_time_and_grouped():
     """``qk_norm_per_head`` scales a head's own 32 dims and no other
     head's; query heads 0, 1 read key-value head 0 and 2, 3 head 1."""
@@ -179,7 +109,7 @@ def test_the_heads_are_normed_one_at_a_time_and_grouped():
     assert layer["wk"].shape == (64, 64)
     assert layer["q_norm_scale"].shape == (32,)
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 16, 64))
-    q, k, v, wide = tfm._qkv_proj(x, layer, cfg, None, jnp.arange(16))
+    q, k, v, wide = attention.qkv_proj(x, layer, cfg, None, jnp.arange(16))
     assert q.shape == (1, 16, 4, 32) and k.shape == (1, 16, 2, 32)
     assert wide == 128
     # Rotation keeps a head's norm, and the norm made it sqrt(32).
@@ -469,51 +399,6 @@ def test_the_shares_add_up():
 
 # --- the step ---------------------------------------------------------------------
 
-@pytest.mark.parametrize("devices", (1, 4))
-def test_train_step_takes_the_gradient_of_the_global_batch(hvd, devices):
-    """Through ``make_train_step``, on one device and on a four-device
-    data mesh, recomputed (``remat="full"``): loss = the reference's on
-    the whole batch; the momentum slot after one step from zero = the
-    reference's gradient of the **global** batch mean."""
-    from horovod_tpu.topology import build_mesh
-
-    cfg, lr = KEYE_TINY, 0.1
-    mesh = build_mesh(axes=("data",), devices=jax.devices()[:devices])
-    optimizer = optax.sgd(lr, momentum=0.9)
-    step, _, _ = tfm.make_train_step(cfg, optimizer, mesh, attention="flash",
-                                     donate=False, remat="full")
-    params = _params(cfg)
-    tokens, labels = _batch(cfg, batch=4)
-    new, opt_state, loss = step(params, optimizer.init(params), tokens,
-                                labels)
-    want, want_g, _ = jax.jit(lambda *a: _reference(cfg, *a))(
-        params, tokens, labels)
-    assert abs(loss - want) <= F32_REL * abs(want)
-    momentum = _checked(opt_state[0].trace)
-    after, before = _checked(new), _checked(params)
-    for name, g in want_g.items():
-        assert _rel(momentum[name], g) <= F32_REL, name
-        assert _rel((after[name] - before[name]) / -lr, g) <= 3e-3, name
-
-
-def test_specs_and_abstract_params_cover_every_leaf():
-    cfg = KEYE_TINY
-    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
-    specs = tfm.param_specs(cfg, None)
-    assert (jax.tree_util.tree_structure(params)
-            == jax.tree_util.tree_structure(
-                specs, is_leaf=lambda x: isinstance(
-                    x, jax.sharding.PartitionSpec)))
-    layer = params["layers"][1]
-    assert layer["index_wq"].shape == (64, 4 * 16)
-    assert layer["index_wk"].shape == (64, 16)
-    assert layer["index_ww"].shape == (64, 4)
-    assert layer["w_down"].shape == (4, 48, 64)
-    assert layer["router"].shape == (64, 8)
-    abstract = tfm.init_abstract(cfg)
-    assert (jax.tree_util.tree_map(lambda a: a.shape, abstract)
-            == jax.tree_util.tree_map(lambda a: a.shape, params))
-
 
 def test_trace_time_series_count_path_tiles_and_share(hvd, monkeypatch):
     from horovod_tpu import telemetry
@@ -556,92 +441,11 @@ def test_trace_time_series_count_path_tiles_and_share(hvd, monkeypatch):
         telemetry.reset_for_tests()
 
 
-def test_scopes_name_the_new_parts(hvd):
-    """The lowered step carries the sub-scopes the per-layer metrics read
-    (``perfbench/dsa_reduce.py``)."""
-    cfg = KEYE_TINY
-    tokens = jax.ShapeDtypeStruct((2, 128), jnp.int32)
-    text = jax.jit(lambda p, t: tfm.loss_fn(p, t, t, cfg)).lower(
-        tfm.init_abstract(cfg), tokens).as_text(debug_info=True)
-    for scope in ("layer_0/attn/qkv/qk_head_norm_rope",
-                  "layer_0/attn/qkv/dsa_index_proj",
-                  "layer_1/attn/flash_attention/dsa_index_scores",
-                  "layer_1/attn/flash_attention/dsa_select",
-                  "layer_1/attn/flash_attention/dsa_flash",
-                  "layer_1/attn/flash_attention/dsa_index_loss",
-                  "layer_1/mlp/moe_router", "layer_1/mlp/moe_experts"):
-        assert scope in text, scope
-
-
 # --- refusals: never a silent fall back ---------------------------------------------
 
 PLAIN = dict(n_experts=0, experts_per_token=0, d_expert=0,
              norm_topk_prob=False, experts_held=0, experts_held_from=0,
              n_kv_heads=0, head_width=0, n_heads=2, d_ff=128)
-
-
-@pytest.mark.parametrize("axis", ("model", "seq"))
-def test_model_and_sequence_axes_are_refused_by_name(hvd, axis):
-    from horovod_tpu.topology import build_mesh
-
-    mesh = build_mesh(axes=("data", axis), shape=(2, 2),
-                      devices=jax.devices()[:4])
-    # The indexer alone, without experts or grouped heads to refuse first.
-    cfg = dataclasses.replace(KEYE_TINY, **PLAIN)
-    with pytest.raises(NotImplementedError,
-                       match=f"{axis}_axis.*index_heads"):
-        tfm.make_train_step(cfg, optax.sgd(0.1), mesh,
-                            **{f"{axis}_axis": axis})
-    with pytest.raises(NotImplementedError, match=f"{axis}_axis"):
-        tfm.make_train_step(KEYE_TINY, optax.sgd(0.1), mesh,
-                            **{f"{axis}_axis": axis})
-
-
-def test_segment_ids_are_refused_by_name(hvd):
-    cfg = KEYE_TINY
-    tokens = jnp.zeros((2, 128), jnp.int32)
-    with pytest.raises(NotImplementedError,
-                       match="segment_ids.*index_topk"):
-        jax.eval_shape(lambda p: tfm.loss_fn(
-            p, tokens, tokens, cfg, segment_ids=tokens),
-            tfm.init_abstract(cfg))
-
-
-def test_decode_and_the_pipelined_builder_refuse_the_indexer_by_name(hvd):
-    from horovod_tpu.topology import build_mesh
-
-    cfg = dataclasses.replace(KEYE_TINY, **PLAIN)
-    with pytest.raises(NotImplementedError, match="decode_step.*index_heads"):
-        tfm.decode_step(tfm.init_abstract(cfg), jnp.zeros((2,), jnp.int32),
-                        tfm.init_kv_cache(cfg, 2, 8), 0, cfg)
-    mesh = build_mesh(axes=("data", "pipe"), shape=(2, 2),
-                      devices=jax.devices()[:4])
-    with pytest.raises(NotImplementedError, match="pipelined.*index_heads"):
-        tfm.make_train_step_pipelined(cfg, optax.sgd(0.1), mesh)
-    wide = dataclasses.replace(
-        cfg, index_heads=0, index_head_dim=0, index_topk=0,
-        indexer_loss_coef=0.0, qk_norm_per_head=False, head_width=48)
-    with pytest.raises(NotImplementedError, match="pipelined.*head_width"):
-        tfm.make_train_step_pipelined(wide, optax.sgd(0.1), mesh)
-
-
-@pytest.mark.parametrize("fields,error,message", [
-    (dict(index_topk=0), ValueError, "come together.*sparse attention"),
-    (dict(indexer_loss_coef=0.0), ValueError, "come together"),
-    (dict(index_head_dim=15), ValueError, "even index_head_dim"),
-    (dict(positions="none"), ValueError, "positions='rope'"),
-    (dict(qk_norm=True), ValueError, "one of them"),
-    (dict(head_width=31), ValueError, "even head_dim"),
-    (dict(q_latent_rank=8, kv_latent_rank=8, rope_dim=8),
-     NotImplementedError, "qk_norm_per_head"),
-    (dict(q_latent_rank=8, kv_latent_rank=8, rope_dim=8, n_kv_heads=0,
-          qk_norm_per_head=False), NotImplementedError,
-     "indexer beside latent attention"),
-], ids=["no_topk", "no_coef", "odd_index_dim", "no_rope", "both_norms",
-        "odd_head", "per_head_norm_on_latents", "indexer_on_latents"])
-def test_config_says_what_the_new_fields_cannot_mean(fields, error, message):
-    with pytest.raises(error, match=message):
-        dataclasses.replace(KEYE_TINY, **fields)
 
 
 def test_a_head_width_alone_is_plain_attention_with_wide_heads():

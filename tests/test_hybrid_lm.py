@@ -25,40 +25,20 @@ from perfbench.reference import hybrid_lm as reference
 
 F32_REL = 5e-5
 
+# The families every configuration shares, and the table of configurations
+# (tests/test_lm_configs.py); those that compile this row's program run
+# here, in the row's own file: a file is one worker's chain.
+from test_lm_configs import *  # noqa: E402,F401,F403
+from test_lm_configs import HYBRID_TINY, OLMOE_TINY, GPT2_TINY  # noqa: E402
+
+COSTLY_ROWS = ("hybrid",)
+
 PATTERN = ("linear_attention",) * 3 + ("full_attention",)
-HYBRID_TINY = tfm.TransformerConfig(
-    vocab_size=128, d_model=64, n_heads=2, n_layers=4, d_ff=96, max_seq=256,
-    dtype=jnp.float32, positions="none", qk_norm=True, norm_eps=1e-6,
-    tie_embeddings=False, mlp="swiglu", layer_types=PATTERN,
-    linear_key_heads=2, linear_value_heads=2, linear_key_head_dim=24,
-    linear_value_head_dim=48, linear_conv_kernel=4,
-    linear_allow_neg_eigval=True)
-OLMOE_TINY = tfm.TransformerConfig(
-    vocab_size=128, d_model=64, n_heads=2, n_layers=2, d_ff=0, max_seq=64,
-    dtype=jnp.float32, positions="rope", qk_norm=True, norm_eps=1e-5,
-    tie_embeddings=False, mlp="swiglu", n_experts=8, experts_per_token=2,
-    d_expert=32, router_aux_coef=0.01, router_z_coef=0.001)
-GPT2_TINY = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2,
-                                  n_layers=2, d_ff=64, max_seq=128)
 
 
 def _rel(got, want):
     got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
     return float(np.linalg.norm(got - want) / np.linalg.norm(want))
-
-
-def _batch(cfg, batch=2, seq=256, seed=1):
-    toks = jax.random.randint(jax.random.PRNGKey(seed), (batch, seq + 1), 0,
-                              cfg.vocab_size)
-    return toks[:, :-1], toks[:, 1:]
-
-
-def _reference(cfg, params, tokens, labels, **kw):
-    return reference.loss_and_tail_grads(
-        params, tokens, labels, n_heads=cfg.n_heads,
-        layer_types=cfg.layer_types, linear_heads=cfg.linear_value_heads,
-        key_dim=cfg.linear_key_head_dim, eps=cfg.norm_eps,
-        neg_eigval=cfg.linear_allow_neg_eigval, **kw)
 
 
 # --- the chunked recurrence and the layer -----------------------------------
@@ -183,172 +163,11 @@ def test_sequence_length_must_be_whole_blocks():
 
 # --- the four-layer 3:1 model, against the plain reference ------------------
 
-@pytest.mark.parametrize("dtype,loss_rtol,grad_rel", [
-    # float32 against float32: rounding alone.
-    (jnp.float32, F32_REL, 2e-4),
-    # bfloat16 operands: three digits in the loss; over 256 tokens the
-    # gradient through the decay (lin_wa) is a small difference of large
-    # terms and reads 0.19 where the others read 0.01-0.05.
-    (jnp.bfloat16, 3e-3, 0.3),
-], ids=("float32", "bfloat16"))
-def test_loss_and_tail_gradients_match_the_reference(dtype, loss_rtol,
-                                                     grad_rel):
-    cfg = dataclasses.replace(HYBRID_TINY, dtype=dtype)
-    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
-    tokens, labels = _batch(cfg)
-    loss, grads = jax.value_and_grad(tfm.loss_fn)(
-        params, tokens, labels, cfg, attention="local")
-    want, want_g, gates = jax.jit(
-        lambda *a: _reference(cfg, *a))(params, tokens, labels)
-    assert abs(loss - want) <= loss_rtol * abs(want)
-    got_g = _checked(grads)
-    for name, g in want_g.items():
-        assert _rel(got_g[name], g) <= grad_rel, name
-    assert gates.shape == (3, 6)
-    assert (np.asarray(gates[:, 0]) > 0).all()
-    assert (np.asarray(gates[:, 4]) <= 1).all()
-    assert 1.0 < float(gates[:, 5].max()) <= 2.0
-    if dtype == jnp.float32:
-        # Tight enough for the precision stated: the reference with
-        # bfloat16 operands misses it.
-        low, low_g, _ = jax.jit(lambda *a: _reference(
-            cfg, *a, low_precision=jnp.bfloat16))(params, tokens, labels)
-        assert abs(low - want) > loss_rtol * abs(want)
-        assert _rel(low_g["lin_wa_last"], want_g["lin_wa_last"]) > grad_rel
-
-
-def _checked(tree):
-    return {"ln_f_scale": tree["ln_f_scale"],
-            "w_down_last": tree["layers"][3]["w_down"],
-            "lin_wo_last": tree["layers"][2]["lin_wo"],
-            "lin_wa_last": tree["layers"][2]["lin_wa"]}
-
-
-@pytest.mark.parametrize("remat", ("dots", "full"))
-def test_remat_leaves_loss_and_gradients_alone(remat):
-    params = tfm.init_params(jax.random.PRNGKey(0), HYBRID_TINY)
-    tokens, labels = _batch(HYBRID_TINY)
-    run = lambda r: jax.value_and_grad(tfm.loss_fn)(
-        params, tokens, labels, HYBRID_TINY, attention="local", remat=r)
-    (loss, grads), (want, want_g) = run(remat), run("none")
-    np.testing.assert_allclose(loss, want, rtol=1e-6)
-    # The same arithmetic fused otherwise: float32 rounding, leaf by leaf
-    # (7e-5 on the gates' leaves, whose gradient is a small difference of
-    # large terms; 1e-5 and under elsewhere).
-    for a, b in zip(jax.tree_util.tree_leaves(grads),
-                    jax.tree_util.tree_leaves(want_g)):
-        assert _rel(a, b) <= 2e-4
-
-
-@pytest.mark.parametrize("devices", (1, 4))
-def test_train_step_takes_the_gradient_of_the_global_batch(hvd, devices):
-    """Through ``make_train_step``, on one device and on a four-device
-    data mesh: loss = the reference's on the whole batch; update = -lr x
-    the reference's gradient of the **global** batch mean."""
-    from horovod_tpu.topology import build_mesh
-
-    cfg, lr = HYBRID_TINY, 0.1
-    mesh = build_mesh(axes=("data",), devices=jax.devices()[:devices])
-    optimizer = optax.sgd(lr)
-    step, _, _ = tfm.make_train_step(cfg, optimizer, mesh, attention="local",
-                                     donate=False, remat="full")
-    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
-    tokens, labels = _batch(cfg, batch=4)
-    new, _, loss = step(params, optimizer.init(params), tokens, labels)
-    want, want_g, _ = jax.jit(lambda *a: _reference(cfg, *a))(
-        params, tokens, labels)
-    assert abs(loss - want) <= F32_REL * abs(want)
-    after, before = _checked(new), _checked(params)
-    for name, g in want_g.items():
-        # (after - before) / -lr loses three digits to the subtraction.
-        assert _rel((after[name] - before[name]) / -lr, g) <= 2e-3, name
-
 
 # --- refusals: never a silent fall back -------------------------------------
 
-def test_segment_ids_and_packed_are_refused_by_name(hvd):
-    from horovod_tpu.topology import build_mesh
-
-    params = tfm.init_abstract(HYBRID_TINY)
-    tokens = jnp.zeros((2, 256), jnp.int32)
-    with pytest.raises(NotImplementedError, match="segment_ids"):
-        jax.eval_shape(lambda p: tfm.forward(
-            p, tokens, HYBRID_TINY, attention="local",
-            segment_ids=jnp.zeros_like(tokens)), params)
-    mesh = build_mesh(axes=("data",), devices=jax.devices()[:2])
-    with pytest.raises(NotImplementedError, match="packed"):
-        tfm.make_train_step(HYBRID_TINY, optax.sgd(0.1), mesh, packed=True)
-
-
-@pytest.mark.parametrize("axis", ("model", "seq"))
-def test_model_and_sequence_axes_are_refused_by_name(hvd, axis):
-    from horovod_tpu.topology import build_mesh
-
-    mesh = build_mesh(axes=("data", axis), shape=(2, 2),
-                      devices=jax.devices()[:4])
-    match = "layer_types" if axis == "model" else "seq_axis"
-    cfg = dataclasses.replace(HYBRID_TINY, qk_norm=False)
-    with pytest.raises(NotImplementedError, match=match):
-        tfm.make_train_step(cfg, optax.sgd(0.1), mesh,
-                            **{f"{axis}_axis": axis})
-
-
-def test_decode_and_the_pipelined_builder_refuse_layer_types(hvd):
-    from horovod_tpu.topology import build_mesh
-
-    cfg = dataclasses.replace(
-        HYBRID_TINY, positions="learned", qk_norm=False, mlp="gelu",
-        tie_embeddings=True)
-    params = tfm.init_abstract(cfg)
-    with pytest.raises(NotImplementedError, match="layer_types"):
-        tfm.decode_step(params, jnp.zeros((2,), jnp.int32),
-                        tfm.init_kv_cache(cfg, 2, 8), 0, cfg)
-    mesh = build_mesh(axes=("data", "pipe"), shape=(2, 2),
-                      devices=jax.devices()[:4])
-    with pytest.raises(NotImplementedError, match="layer_types"):
-        tfm.make_train_step_pipelined(cfg, optax.sgd(0.1), mesh)
-    with pytest.raises(NotImplementedError, match="positions"):
-        tfm.decode_step(tfm.init_abstract(HYBRID_TINY),
-                        jnp.zeros((2,), jnp.int32),
-                        tfm.init_kv_cache(HYBRID_TINY, 2, 8), 0, HYBRID_TINY)
-
-
-@pytest.mark.parametrize("fields,error,message", [
-    (dict(layer_types=("linear_attention",)), ValueError, "n_layers"),
-    (dict(layer_types=("full_attention", "sliding") * 2), ValueError,
-     "layer_types"),
-    (dict(linear_key_head_dim=0), ValueError, "linear_key_head_dim"),
-    (dict(linear_value_heads=4), NotImplementedError, "linear_value_heads"),
-    (dict(layer_types=(), linear_allow_neg_eigval=True), ValueError,
-     "linear_"),
-    (dict(positions="alibi"), ValueError, "positions"),
-])
-def test_config_refuses_what_it_cannot_mean(fields, error, message):
-    with pytest.raises(error, match=message):
-        dataclasses.replace(HYBRID_TINY, **fields)
-
 
 # --- the trees, the counters, and what the other configurations lower to ----
-
-@pytest.mark.parametrize("cfg", (GPT2_TINY, OLMOE_TINY, HYBRID_TINY),
-                         ids=("gpt2", "olmoe", "olmo_hybrid"))
-def test_specs_and_abstract_params_cover_every_leaf(cfg):
-    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
-    abstract = tfm.init_abstract(cfg)
-    specs = tfm.param_specs(cfg, None)
-    paths = lambda tree, **kw: sorted(
-        jax.tree_util.keystr(p)
-        for p, _ in jax.tree_util.tree_flatten_with_path(tree, **kw)[0])
-    from jax.sharding import PartitionSpec
-    is_spec = lambda x: isinstance(x, PartitionSpec)
-    assert paths(params) == paths(abstract) == paths(specs, is_leaf=is_spec)
-    for a, b in zip(jax.tree_util.tree_leaves(params),
-                    jax.tree_util.tree_leaves(abstract)):
-        assert (a.shape, a.dtype) == (b.shape, b.dtype)
-    for i in range(cfg.n_layers):
-        linear = cfg.layer_type(i) == tfm.LINEAR_ATTENTION
-        assert ("lin_wq" in params["layers"][i]) == linear
-        assert ("wq" in params["layers"][i]) != linear
 
 
 def test_published_gate_initialisation_ranges():

@@ -15,10 +15,9 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
-from horovod_tpu.models import moe
+from horovod_tpu.models import attention, moe
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.ops import grouped_matmul as gm
 from horovod_tpu.ops.flash_attention import flash_attention
@@ -27,15 +26,13 @@ from perfbench.reference import mla_moe_lm as reference
 
 F32_REL = 5e-5
 
-# 3 heads of 32 on a hidden size of 64 (3 x 32 != 64), 8 of them rotary;
-# 1 dense + 2 expert layers + the module; 8 experts top-2, 4 held from 2.
-GLM_TINY = tfm.TransformerConfig(
-    vocab_size=128, d_model=64, n_heads=3, n_layers=3, d_ff=160, max_seq=128,
-    dtype=jnp.float32, positions="rope", rope_theta=1e6, norm_eps=1e-5,
-    tie_embeddings=False, head_width=32, q_latent_rank=24, kv_latent_rank=16,
-    rope_dim=8, mlp="swiglu", n_experts=8, experts_per_token=2, d_expert=48,
-    d_shared=48, routed_scale=1.8, experts_held=4, experts_held_from=2,
-    dense_layers=1, mtp_layer_types=("full_attention",), mtp_loss_coef=0.1)
+# The families every configuration shares, and the table of configurations
+# (tests/test_lm_configs.py); those that compile this row's program run
+# here, in the row's own file: a file is one worker's chain.
+from test_lm_configs import *  # noqa: E402,F401,F403
+from test_lm_configs import GLM_TINY, glm_dims as _dims  # noqa: E402
+
+COSTLY_ROWS = ("glm",)
 
 
 def _rel(got, want):
@@ -57,68 +54,7 @@ def _batch(cfg, batch=2, seq=128, seed=1):
     return toks[:, :-1], toks[:, 1:]
 
 
-def _dims(cfg):
-    return {"n_heads": cfg.n_heads, "head_dim": cfg.head_dim,
-            "rope_dim": cfg.rope_dim, "kv_rank": cfg.kv_latent_rank,
-            "eps": cfg.norm_eps, "theta": cfg.rope_theta,
-            "top_k": cfg.experts_per_token,
-            "routed_scale": cfg.routed_scale,
-            "held_from": cfg.experts_held_from}
-
-
-def _reference(cfg, params, tokens, labels, **kw):
-    """Every leaf the reference can differentiate, not only the cell's."""
-    kw.setdefault("names", tuple(reference.LEAVES))
-    return reference.loss_and_tail_grads(
-        params, tokens, labels, dims=_dims(cfg),
-        dense_layers=cfg.dense_layers, mtp_coef=cfg.mtp_loss_coef, **kw)
-
-
-def _checked(tree, cfg=GLM_TINY):
-    return {name: reference.leaf(tree, path)
-            for name, path in reference.leaf_paths(cfg.n_layers).items()}
-
-
 # --- the model against the reference ------------------------------------------
-
-@pytest.mark.parametrize("attention", ("local", "flash"))
-def test_loss_and_every_kind_of_leaf_match_the_reference(attention):
-    """Float32 program against the float32 reference: the loss (both
-    terms) and the gradient of every kind of leaf: both latents' norms,
-    ``W_qb``, ``W_kva``, ``W_kvb``, ``W_o``, the router, a routed and the
-    shared ``w_down``, the dense layer's, ``W_eh`` and the final norm."""
-    cfg = GLM_TINY
-    params, (tokens, labels) = _params(cfg), _batch(cfg)
-    with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(tfm.loss_fn)(
-            params, tokens, labels, cfg, attention=attention)
-        want, want_g, stats = _reference(cfg, params, tokens, labels)
-    assert abs(loss - want) <= F32_REL * abs(want)
-    assert set(want_g) == set(reference.LEAVES)
-    got_g = _checked(grads)
-    for name, g in want_g.items():
-        assert float(jnp.linalg.norm(g)) > 0, name
-        assert _rel(got_g[name], g) <= F32_REL, name
-    # Two expert layers and the module's; the dense layer routes nothing.
-    assert stats["rows"].shape == (3, 4)
-
-
-@pytest.mark.parametrize("control,moved", [
-    (dict(shared_expert=False), "w_shared_down_last"),
-    (dict(rotate_shared_key=False), "w_kvb_last"),
-    (dict(low_precision=jnp.float8_e4m3fn), "wo_last")],
-    ids=["no_shared_expert", "k_r_unrotated", "float8"])
-def test_the_oracle_sees_what_the_cells_controls_change(control, moved):
-    """The three references that the cell's check must refuse are other
-    functions at this size too."""
-    cfg = GLM_TINY
-    params, (tokens, labels) = _params(cfg), _batch(cfg)
-    want, want_g, _ = _reference(cfg, params, tokens, labels,
-                                 names=reference.CHECKED)
-    off, off_g, _ = _reference(cfg, params, tokens, labels,
-                               names=reference.CHECKED, **control)
-    assert abs(off - want) > 1e-4 * abs(want)
-    assert _rel(off_g[moved], want_g[moved]) > 0.02
 
 
 def test_the_second_loss_is_the_prediction_modules():
@@ -133,20 +69,6 @@ def test_the_second_loss_is_the_prediction_modules():
             cfg, mtp_loss_coef=0.2), attention="local")
     assert float(both - first) > 0.1
     assert abs((twice - first) - 2 * (both - first)) <= 1e-5 * abs(both)
-
-
-@pytest.mark.parametrize("remat", ("dots", "full"))
-def test_remat_leaves_loss_and_gradients_alone(remat):
-    cfg = GLM_TINY
-    params, (tokens, labels) = _params(cfg), _batch(cfg, seq=64)
-    run = lambda r: jax.value_and_grad(tfm.loss_fn)(
-        params, tokens, labels, cfg, attention="local", remat=r)
-    (loss, grads), (want, want_g) = run(remat), run("none")
-    assert abs(loss - want) <= 1e-6 * abs(want)
-    for got, g in zip(jax.tree_util.tree_leaves(grads),
-                      jax.tree_util.tree_leaves(want_g)):
-        if float(jnp.linalg.norm(g)):      # the selection bias's is zero
-            assert _rel(got, g) <= 1e-5
 
 
 # --- latent attention -----------------------------------------------------------
@@ -164,10 +86,10 @@ def test_the_rotary_key_is_one_head_shared_by_all():
     cfg = GLM_TINY
     layer, h, positions = _attention_layer()
     rank, nope = cfg.kv_latent_rank, cfg.head_dim - cfg.rope_dim
-    q, k, v, wide = tfm._latent_qkv(h, layer, cfg, positions)
+    q, k, v, wide = attention.latent_qkv(h, layer, cfg, positions)
     assert wide == cfg.n_heads * cfg.head_dim == 96
     assert q.shape == k.shape == v.shape == (2, 32, 3, 32)
-    one = tfm._rotary((h @ layer["w_kva"])[..., None, rank:], positions,
+    one = attention.rotary((h @ layer["w_kva"])[..., None, rank:], positions,
                       cfg.rope_theta)
     for head in range(cfg.n_heads):
         np.testing.assert_allclose(k[..., head, nope:], one[..., 0, :],
@@ -177,11 +99,11 @@ def test_the_rotary_key_is_one_head_shared_by_all():
     weight = jax.random.normal(jax.random.PRNGKey(9), k.shape)
 
     def through_all_heads(w_kva):
-        return jnp.sum(weight * tfm._latent_qkv(
+        return jnp.sum(weight * attention.latent_qkv(
             h, dict(layer, w_kva=w_kva), cfg, positions)[1])
 
     def through_one_key(w_kva):
-        key = tfm._rotary((h @ w_kva)[..., None, rank:], positions,
+        key = attention.rotary((h @ w_kva)[..., None, rank:], positions,
                           cfg.rope_theta)[..., 0, :]
         return jnp.sum(jnp.sum(weight[..., nope:], axis=-2) * key)
 
@@ -196,8 +118,8 @@ def test_only_the_tail_of_a_query_head_is_rotary():
     cfg = GLM_TINY
     layer, h, positions = _attention_layer()
     nope = cfg.head_dim - cfg.rope_dim
-    here = tfm._latent_qkv(h, layer, cfg, positions)
-    later = tfm._latent_qkv(h, layer, cfg, positions + 7)
+    here = attention.latent_qkv(h, layer, cfg, positions)
+    later = attention.latent_qkv(h, layer, cfg, positions + 7)
     for a, b in zip(here[:2], later[:2]):
         np.testing.assert_array_equal(a[..., :nope], b[..., :nope])
         assert _rel(a[..., nope:], b[..., nope:]) > 0.1
@@ -358,56 +280,6 @@ def test_the_bias_chooses_and_the_weights_are_the_scores():
 
 # --- through make_train_step ------------------------------------------------------
 
-@pytest.mark.parametrize("devices", (1, 4))
-def test_train_step_takes_the_gradient_of_the_global_batch(hvd, devices):
-    """Through ``make_train_step``, on one device and on a four-device
-    data mesh, recomputed (``remat="full"``): loss = the reference's on
-    the whole batch; the momentum slot after one step from zero = the
-    reference's gradient of the **global** batch mean."""
-    from horovod_tpu.topology import build_mesh
-
-    cfg, lr = GLM_TINY, 0.1
-    mesh = build_mesh(axes=("data",), devices=jax.devices()[:devices])
-    optimizer = optax.sgd(lr, momentum=0.9)
-    step, _, _ = tfm.make_train_step(cfg, optimizer, mesh, attention="local",
-                                     donate=False, remat="full")
-    params = _params(cfg)
-    tokens, labels = _batch(cfg, batch=4)
-    new, opt_state, loss = step(params, optimizer.init(params), tokens,
-                                labels)
-    want, want_g, _ = jax.jit(lambda *a: _reference(cfg, *a))(
-        params, tokens, labels)
-    assert abs(loss - want) <= F32_REL * abs(want)
-    momentum = _checked(opt_state[0].trace)
-    after, before = _checked(new), _checked(params)
-    for name, g in want_g.items():
-        assert _rel(momentum[name], g) <= F32_REL, name
-        assert _rel((after[name] - before[name]) / -lr, g) <= 3e-3, name
-    # The selection bias is not trained.
-    np.testing.assert_array_equal(new["layers"][1]["router_bias"], 0.0)
-
-
-def test_specs_and_abstract_params_cover_every_leaf():
-    cfg = GLM_TINY
-    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
-    specs = tfm.param_specs(cfg, None)
-    assert (jax.tree_util.tree_structure(params)
-            == jax.tree_util.tree_structure(
-                specs, is_leaf=lambda x: isinstance(
-                    x, jax.sharding.PartitionSpec)))
-    dense, expert = params["layers"][0], params["layers"][1]
-    assert dense["w_down"].shape == (160, 64) and "router" not in dense
-    assert expert["w_down"].shape == (4, 48, 64)
-    assert expert["router"].shape == (64, 8)
-    assert set(params["mtp"]["layers"][0]) == set(expert)
-    assert expert["w_qb"].shape == (24, 96)
-    assert expert["w_kva"].shape == (64, 16 + 8)
-    assert expert["w_kvb"].shape == (16, 3 * (24 + 32))
-    assert expert["wo"].shape == (96, 64)
-    abstract = tfm.init_abstract(cfg)
-    assert (jax.tree_util.tree_map(lambda a: a.shape, abstract)
-            == jax.tree_util.tree_map(lambda a: a.shape, params))
-
 
 def test_trace_time_series_count_the_expert_layers_alone(hvd):
     from horovod_tpu import telemetry
@@ -440,94 +312,7 @@ def test_trace_time_series_count_the_expert_layers_alone(hvd):
         telemetry.reset_for_tests()
 
 
-def test_scopes_name_the_new_parts(hvd):
-    """The lowered step carries the sub-scopes the per-layer metrics read
-    (``perfbench/mla_reduce.py``)."""
-    cfg = GLM_TINY
-    tokens = jax.ShapeDtypeStruct((2, 128), jnp.int32)
-    text = jax.jit(lambda p, t: tfm.loss_fn(
-        p, t, t, cfg, attention="local")).lower(
-            tfm.init_abstract(cfg), tokens).as_text(debug_info=True)
-    for scope in ("layer_1/attn/qkv/mla_q", "layer_1/attn/qkv/mla_kv",
-                  "layer_1/attn/qkv/mla_rope", "layer_0/mlp/mlp_dense",
-                  "layer_1/mlp/moe_router", "layer_1/mlp/moe_shared",
-                  "mtp/layer_0/attn/qkv/mla_kv", "mtp/layer_0/mlp/moe_experts"):
-        assert scope in text, scope
-    assert "layer_1/mlp/mlp_dense" not in text
-    # (The module's own layer_0 is an expert layer.)
-    assert ")/layer_0/mlp/moe_router" not in text
-
-
 # --- refusals: never a silent fall back ---------------------------------------------
-
-@pytest.mark.parametrize("axis", ("model", "seq"))
-def test_model_and_sequence_axes_are_refused_by_name(hvd, axis):
-    from horovod_tpu.topology import build_mesh
-
-    mesh = build_mesh(axes=("data", axis), shape=(2, 2),
-                      devices=jax.devices()[:4])
-    # Latent attention alone, without experts or a module to refuse first.
-    cfg = dataclasses.replace(
-        GLM_TINY, mlp="swiglu", n_experts=0, experts_per_token=0, d_expert=0,
-        d_shared=0, routed_scale=1.0, experts_held=0, experts_held_from=0,
-        dense_layers=0, mtp_layer_types=(), mtp_loss_coef=0.0)
-    with pytest.raises(NotImplementedError,
-                       match=f"{axis}_axis.*head_width"):
-        tfm.make_train_step(cfg, optax.sgd(0.1), mesh,
-                            **{f"{axis}_axis": axis})
-    with pytest.raises(NotImplementedError, match="n_experts|seq_axis"):
-        tfm.make_train_step(GLM_TINY, optax.sgd(0.1), mesh,
-                            **{f"{axis}_axis": axis})
-
-
-def test_packed_is_refused_by_the_prediction_module(hvd):
-    from horovod_tpu.topology import build_mesh
-
-    mesh = build_mesh(axes=("data",), devices=jax.devices()[:2])
-    with pytest.raises(NotImplementedError, match="packed.*prediction"):
-        tfm.make_train_step(GLM_TINY, optax.sgd(0.1), mesh, packed=True)
-
-
-def test_decode_and_the_pipelined_builder_refuse_latent_attention_by_name(
-        hvd):
-    from horovod_tpu.topology import build_mesh
-
-    cfg = GLM_TINY
-    with pytest.raises(NotImplementedError, match="decode_step.*head_width"):
-        tfm.decode_step(tfm.init_abstract(cfg), jnp.zeros((2,), jnp.int32),
-                        tfm.init_kv_cache(cfg, 2, 8), 0, cfg)
-    mesh = build_mesh(axes=("data", "pipe"), shape=(2, 2),
-                      devices=jax.devices()[:4])
-    with pytest.raises(NotImplementedError, match="pipelined.*head_width"):
-        tfm.make_train_step_pipelined(cfg, optax.sgd(0.1), mesh)
-
-
-@pytest.mark.parametrize("fields,error,message", [
-    (dict(head_width=0), ValueError, "latent attention needs head_width"),
-    (dict(rope_dim=40), ValueError, "rope_dim=40 is wider than head_width"),
-    (dict(qk_norm_per_head=True), NotImplementedError, "qk_norm_per_head"),
-    (dict(rope_dim=0), ValueError, "come together"),
-    (dict(positions="none"), ValueError, "positions='rope'"),
-    (dict(rope_dim=7), ValueError, "even rope_dim"),
-    (dict(qk_norm=True), NotImplementedError, "qk_norm"),
-    (dict(n_kv_heads=1), NotImplementedError, "n_kv_heads"),
-    (dict(dense_layers=4), ValueError, "dense_layers=4 must lie in 0..n"),
-    (dict(dense_layers=1, mlp="relu2", d_latent=16), NotImplementedError,
-     "leading dense MLP is SwiGLU"),
-    (dict(n_experts=0, experts_per_token=0, d_expert=0, experts_held=0,
-          experts_held_from=0, d_shared=0, routed_scale=1.0), ValueError,
-     "dense_layers"),
-    (dict(n_experts=0, experts_per_token=0, d_expert=0, experts_held=0,
-          experts_held_from=0, dense_layers=0, routed_scale=1.0),
-     ValueError, "d_shared is the shared expert"),
-    (dict(d_shared=0), ValueError, "routed_scale"),
-    (dict(d_latent=8), ValueError, "d_latent means nothing"),
-    (dict(router_aux_coef=0.01), NotImplementedError, "no auxiliary loss"),
-    (dict(norm_topk_prob=True), NotImplementedError, "renormalises"),
-])
-def test_config_says_what_the_new_fields_cannot_mean(fields, error, message):
-    with pytest.raises(error, match=message):
-        dataclasses.replace(GLM_TINY, **fields)
 
 
 @pytest.mark.parametrize("fields", [

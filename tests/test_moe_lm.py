@@ -20,36 +20,26 @@ import numpy as np
 import optax
 import pytest
 
-from horovod_tpu.models import moe
+from horovod_tpu.models import attention, moe
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.ops import grouped_matmul as gm
 from perfbench.reference import moe_lm as reference
 
 F32_RTOL = 2e-5
 
-OLMOE_TINY = tfm.TransformerConfig(
-    vocab_size=128, d_model=64, n_heads=2, n_layers=2, d_ff=0, max_seq=64,
-    dtype=jnp.float32, positions="rope", qk_norm=True, norm_eps=1e-5,
-    tie_embeddings=False, mlp="swiglu", n_experts=8, experts_per_token=2,
-    d_expert=32, router_aux_coef=0.01, router_z_coef=0.001)
+# The families every configuration shares, and the table of configurations
+# (tests/test_lm_configs.py); those that compile this row's program run
+# here, in the row's own file: a file is one worker's chain.
+from test_lm_configs import *  # noqa: E402,F401,F403
+from test_lm_configs import OLMOE_TINY  # noqa: E402
 
-
-def _rel(got, want):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+COSTLY_ROWS = ("olmoe",)
 
 
 def _batch(cfg, batch=4, seq=32, seed=1):
     toks = jax.random.randint(jax.random.PRNGKey(seed), (batch, seq + 1), 0,
                               cfg.vocab_size)
     return toks[:, :-1], toks[:, 1:]
-
-
-def _reference(cfg, params, tokens, labels, **kw):
-    return reference.loss_and_tail_grads(
-        params, tokens, labels, n_heads=cfg.n_heads,
-        top_k=cfg.experts_per_token, eps=cfg.norm_eps, theta=cfg.rope_theta,
-        aux_coef=cfg.router_aux_coef, z_coef=cfg.router_z_coef, **kw)
 
 
 # --- the grouped matmul kernels --------------------------------------------
@@ -448,7 +438,7 @@ def test_router_losses_against_hand_values():
 
 def test_rotary_matches_the_reference_formula():
     x = jax.random.normal(jax.random.key(3), (2, 16, 2, 32))
-    got = tfm._rotary(x, jnp.arange(5, 21), 10000.0)
+    got = attention.rotary(x, jnp.arange(5, 21), 10000.0)
     # The reference rotates one sequence from position 0: take rows 5..20
     # of a longer one.
     longer = jnp.concatenate([jnp.zeros((5, 2, 32)), x[0]], axis=0)
@@ -456,86 +446,7 @@ def test_rotary_matches_the_reference_formula():
                                atol=1e-5)
 
 
-@pytest.mark.parametrize("dtype,loss_rtol,grad_rel", [
-    # float32 against float32: rounding alone.
-    (jnp.float32, F32_RTOL, 5e-5),
-    # bfloat16 compute: three digits, and top-2-of-8 choices flip on a
-    # few of 128 tokens, which the router's gradient feels most.
-    (jnp.bfloat16, 3e-3, 0.5),
-], ids=("float32", "bfloat16"))
-def test_loss_and_tail_gradients_match_the_reference(dtype, loss_rtol,
-                                                     grad_rel):
-    """Rotary, QK-norm, SwiGLU experts, untied head, both router losses:
-    the program's total loss and the gradients of the final norm, the
-    last ``w_down`` and the last router against the plain reference."""
-    cfg = dataclasses.replace(OLMOE_TINY, dtype=dtype)
-    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
-    tokens, labels = _batch(cfg)
-    loss, grads = jax.value_and_grad(tfm.loss_fn)(
-        params, tokens, labels, cfg, attention="local")
-    want, want_g, counts = jax.jit(
-        lambda *a: _reference(cfg, *a))(params, tokens, labels)
-    assert abs(loss - want) <= loss_rtol * abs(want)
-    last = grads["layers"][-1]
-    assert _rel(grads["ln_f_scale"], want_g["ln_f_scale"]) <= grad_rel
-    assert _rel(last["w_down"], want_g["w_down_last"]) <= grad_rel
-    assert _rel(last["router"], want_g["router_last"]) <= grad_rel
-    np.testing.assert_array_equal(counts.sum(1), tokens.size * 2)
-    if dtype == jnp.float32:
-        # The tolerance is tight enough for the precision the block
-        # states: the reference with bfloat16 operands and a bfloat16
-        # router softmax misses it.
-        low, _, _ = jax.jit(lambda *a: _reference(
-            cfg, *a, low_precision=jnp.bfloat16))(params, tokens, labels)
-        assert abs(low - want) > loss_rtol * abs(want)
-
-
-@pytest.mark.parametrize("remat", ("dots", "full"))
-def test_remat_leaves_loss_and_gradients_alone(remat):
-    params = tfm.init_params(jax.random.PRNGKey(0), OLMOE_TINY)
-    tokens, labels = _batch(OLMOE_TINY)
-    run = lambda r: jax.value_and_grad(tfm.loss_fn)(
-        params, tokens, labels, OLMOE_TINY, attention="local", remat=r)
-    (loss, grads), (want, want_g) = run(remat), run("none")
-    np.testing.assert_allclose(loss, want, rtol=1e-6)
-    for a, b in zip(jax.tree_util.tree_leaves(grads),
-                    jax.tree_util.tree_leaves(want_g)):
-        np.testing.assert_allclose(a, b, atol=1e-5)
-
-
 # --- the train step on one and on four devices ------------------------------
-
-@pytest.mark.parametrize("devices,shard_optimizer",
-                         [(1, False), (4, False), (4, True)])
-def test_train_step_takes_the_gradient_of_the_global_batch(
-        hvd, devices, shard_optimizer):
-    """Loss = the reference's on the whole batch; update = -lr x the
-    reference's gradient of the **global** batch mean (a step N times too
-    large, PR 21's bug, reads N - 1; a load-balancing loss taken per
-    shard instead of over the batch reads ~1e-2 on the router)."""
-    from horovod_tpu.topology import build_mesh
-
-    cfg, lr = OLMOE_TINY, 0.1
-    mesh = build_mesh(axes=("data",), devices=jax.devices()[:devices])
-    optimizer = optax.sgd(lr)
-    step, _, _ = tfm.make_train_step(
-        cfg, optimizer, mesh, attention="local", donate=False,
-        shard_optimizer=shard_optimizer)
-    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
-    tokens, labels = _batch(cfg, batch=8)
-    opt_state = (step.init if shard_optimizer else optimizer.init)(params)
-    new, _, loss = step(params, opt_state, tokens, labels)
-    want, want_g, _ = jax.jit(lambda *a: _reference(cfg, *a))(
-        params, tokens, labels)
-    assert abs(loss - want) <= F32_RTOL * abs(want)
-    pairs = {"ln_f_scale": (new["ln_f_scale"], params["ln_f_scale"]),
-             "w_down_last": (new["layers"][-1]["w_down"],
-                             params["layers"][-1]["w_down"]),
-             "router_last": (new["layers"][-1]["router"],
-                             params["layers"][-1]["router"])}
-    for name, (after, before) in pairs.items():
-        # (after - before) / -lr loses three digits to the subtraction.
-        assert _rel((after - before) / -lr, want_g[name]) <= 2e-3, name
 
 
 def test_bf16_train_step_reads_the_float32_masters_on_four_devices(hvd):
@@ -618,18 +529,6 @@ def test_decode_runs_qk_norm_swiglu_and_the_untied_head(hvd):
 
 # --- refusals: never a silent fall back to the dense block -------------------
 
-def test_model_axis_refuses_qk_norm_and_experts(hvd):
-    from horovod_tpu.topology import build_mesh
-
-    mesh = build_mesh(axes=("data", "model"), shape=(2, 2),
-                      devices=jax.devices()[:4])
-    for cfg, field in ((OLMOE_TINY, "qk_norm"),
-                       (dataclasses.replace(OLMOE_TINY, qk_norm=False),
-                        "n_experts")):
-        with pytest.raises(NotImplementedError, match=field):
-            tfm.make_train_step(cfg, optax.sgd(0.1), mesh,
-                                model_axis="model")
-
 
 @pytest.mark.parametrize("field,value", [
     ("positions", "rope"), ("qk_norm", True), ("tie_embeddings", False),
@@ -645,38 +544,6 @@ def test_pipelined_step_refuses_every_new_field(hvd, field, value):
                       devices=jax.devices()[:4])
     with pytest.raises(NotImplementedError, match=field):
         tfm.make_train_step_pipelined(cfg, optax.sgd(0.1), mesh)
-
-
-def test_pipelined_step_and_decode_refuse_experts_and_rotary(hvd):
-    from horovod_tpu.topology import build_mesh
-
-    mesh = build_mesh(axes=("data", "pipe"), shape=(2, 2),
-                      devices=jax.devices()[:4])
-    with pytest.raises(NotImplementedError, match="positions|n_experts"):
-        tfm.make_train_step_pipelined(OLMOE_TINY, optax.sgd(0.1), mesh)
-    params = tfm.init_params(jax.random.PRNGKey(0), OLMOE_TINY)
-    cache = tfm.init_kv_cache(OLMOE_TINY, 2, 8)
-    token = jnp.zeros((2,), jnp.int32)
-    with pytest.raises(NotImplementedError, match="positions"):
-        tfm.decode_step(params, token, cache, 0, OLMOE_TINY)
-    learned = dataclasses.replace(OLMOE_TINY, positions="learned")
-    with pytest.raises(NotImplementedError, match="n_experts"):
-        tfm.decode_step(tfm.init_params(jax.random.PRNGKey(0), learned),
-                        token, cache, 0, learned)
-
-
-@pytest.mark.parametrize("fields,message", [
-    (dict(positions="alibi"), "positions"),
-    (dict(mlp="relu"), "mlp"),
-    (dict(n_experts=8, experts_per_token=2, d_expert=16), "SwiGLU"),
-    (dict(mlp="swiglu", n_experts=4, experts_per_token=5, d_expert=16),
-     "experts_per_token"),
-    (dict(mlp="swiglu", n_experts=4, experts_per_token=2), "d_expert"),
-    (dict(router_aux_coef=0.01), "n_experts"),
-])
-def test_config_refuses_what_it_cannot_mean(fields, message):
-    with pytest.raises(ValueError, match=message):
-        tfm.TransformerConfig(**fields)
 
 
 # --- the default is today's GPT-2 block --------------------------------------
@@ -708,29 +575,6 @@ def test_default_config_is_todays_gpt2_block(hvd):
         tfm.init_abstract(OLMOE_TINY), tokens).as_text()
     for present in ("sine", "cosine", "stablehlo.sort", "top_k"):
         assert present in moe_text, present
-
-
-def test_assignments_counter_counts_tokens_times_k(hvd):
-    from horovod_tpu import telemetry
-
-    telemetry.configure(True)
-    try:
-        telemetry.reset_for_tests()
-        telemetry.configure(True)
-        params = tfm.init_abstract(OLMOE_TINY)
-        tokens = jax.ShapeDtypeStruct((4, 32), jnp.int32)
-        jax.eval_shape(lambda p, t: tfm.loss_fn(
-            p, t, t, OLMOE_TINY, attention="local"), params, tokens)
-        snapshot = telemetry.metrics_snapshot()
-        series = {k: v for k, v in snapshot.items()
-                  if "hvd_moe_assignments_total" in k}
-        assert series, sorted(snapshot)
-        text = telemetry.render_prometheus()
-        for layer in (0, 1):
-            assert (f'hvd_moe_assignments_total{{layer="{layer}"}} 256'
-                    in text), text
-    finally:
-        telemetry.reset_for_tests()
 
 
 def test_rows_computed_over_needed_gauge_is_the_static_worst_case(hvd):

@@ -28,21 +28,14 @@ from perfbench.reference import ssm_moe_lm as reference
 
 F32_REL = 5e-5
 
+# The table of configurations (tests/test_lm_configs.py).  This row's
+# shared families all run there and not here: this file is the suite's
+# longest chain without them.
+from test_lm_configs import (NEMOTRON_TINY, OLMOE_TINY,  # noqa: E402
+                             nemotron_dims as _dims)
+
 KINDS = {"M": "mamba2", "*": "attention", "E": "mlp"}
 PATTERN = tuple(KINDS[c] for c in "MEMEMEM*EME")
-NEMOTRON_TINY = tfm.TransformerConfig(
-    vocab_size=128, d_model=64, n_heads=4, n_kv_heads=2, n_layers=11,
-    d_ff=0, max_seq=128, dtype=jnp.float32, positions="none", norm_eps=1e-5,
-    tie_embeddings=False, mlp="relu2", n_experts=16, experts_per_token=6,
-    d_expert=64, d_latent=32, d_shared=96, routed_scale=5.0, experts_held=4,
-    experts_held_from=4, layer_types=PATTERN, ssm_heads=4, ssm_head_dim=16,
-    ssm_state=32, ssm_groups=2, ssm_conv_kernel=4, ssm_chunk=32,
-    mtp_layer_types=(KINDS["*"], KINDS["E"]), mtp_loss_coef=0.1)
-OLMOE_TINY = tfm.TransformerConfig(
-    vocab_size=128, d_model=64, n_heads=2, n_layers=2, d_ff=0, max_seq=64,
-    dtype=jnp.float32, positions="rope", qk_norm=True, norm_eps=1e-5,
-    tie_embeddings=False, mlp="swiglu", n_experts=8, experts_per_token=2,
-    d_expert=32, router_aux_coef=0.01, router_z_coef=0.001)
 
 
 NO_SSM = dict(ssm_heads=0, ssm_head_dim=0, ssm_state=0, ssm_groups=0,
@@ -66,15 +59,6 @@ def _batch(cfg, batch=2, seq=128, seed=1):
     toks = jax.random.randint(jax.random.PRNGKey(seed), (batch, seq + 1), 0,
                               cfg.vocab_size)
     return toks[:, :-1], toks[:, 1:]
-
-
-def _dims(cfg):
-    return {"n_heads": cfg.n_heads, "kv_heads": cfg.kv_heads,
-            "ssm_heads": cfg.ssm_heads, "ssm_head_dim": cfg.ssm_head_dim,
-            "ssm_state": cfg.ssm_state, "ssm_groups": cfg.ssm_groups,
-            "eps": cfg.norm_eps, "top_k": cfg.experts_per_token,
-            "routed_scale": cfg.routed_scale,
-            "held_from": cfg.experts_held_from}
 
 
 def _reference(cfg, params, tokens, labels, **kw):
@@ -671,30 +655,6 @@ def test_held_slots_keep_every_held_assignment():
 
 # --- the whole model --------------------------------------------------------
 
-@pytest.mark.parametrize("dtype,loss_rtol,grad_rel", [
-    (jnp.float32, F32_REL, 2e-4), (jnp.bfloat16, 5e-3, 0.4)],
-    ids=["float32", "bfloat16"])
-def test_loss_and_tail_gradients_match_the_reference(dtype, loss_rtol,
-                                                     grad_rel):
-    """The 11-layer pattern with the prediction module: both terms of the
-    loss and the nine gradients the reference can return."""
-    cfg = dataclasses.replace(NEMOTRON_TINY, dtype=dtype)
-    params, (tokens, labels) = _params(cfg), _batch(cfg)
-    with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(lambda p: tfm.loss_fn(
-            p, tokens, labels, cfg, attention="local"))(params)
-        want, want_g, stats = _reference(cfg, params, tokens, labels)
-    assert abs(loss - want) <= loss_rtol * abs(want)
-    got = _checked(grads)
-    assert set(reference.CHECKED) < set(got) == set(want_g)
-    for name in want_g:
-        assert _rel(got[name], want_g[name]) <= grad_rel, name
-    if dtype == jnp.bfloat16:
-        assert max(_rel(got[n], want_g[n]) for n in want_g) > 3e-3
-    # The selection bias chooses and is not trained.
-    assert float(jnp.abs(grads["layers"][1]["router_bias"]).max()) == 0.0
-    assert stats["rows"].shape == (6, 4)
-
 
 def test_the_second_loss_is_the_prediction_modules():
     """Without the module the loss is the first term alone, and the
@@ -710,49 +670,6 @@ def test_the_second_loss_is_the_prediction_modules():
     assert float(both) > float(first)
     np.testing.assert_allclose(more - first, 2 * (both - first), rtol=1e-4)
     assert "mtp" not in tfm.init_params(jax.random.PRNGKey(0), plain)
-
-
-@pytest.mark.parametrize("remat", ("dots", "full"))
-def test_remat_leaves_loss_and_gradients_alone(remat):
-    cfg = NEMOTRON_TINY
-    params, (tokens, labels) = _params(cfg), _batch(cfg, batch=1)
-    f = lambda r: jax.value_and_grad(lambda p: tfm.loss_fn(
-        p, tokens, labels, cfg, attention="local", remat=r))(params)
-    (loss, grads), (want, want_g) = f(remat), f("none")
-    assert abs(loss - want) <= 1e-6 * abs(want)
-    for name, g in _checked(want_g).items():
-        assert _rel(_checked(grads)[name], g) <= 1e-5, name
-
-
-@pytest.mark.parametrize("devices", (1, 4))
-def test_train_step_takes_the_gradient_of_the_global_batch(hvd, devices):
-    """Through ``make_train_step``, on one device and on a four-device
-    data mesh: loss = the reference's on the whole batch; update = -lr x
-    the reference's gradient of the **global** batch mean."""
-    from horovod_tpu.topology import build_mesh
-
-    cfg, lr = NEMOTRON_TINY, 0.1
-    mesh = build_mesh(axes=("data",), devices=jax.devices()[:devices])
-    optimizer = optax.sgd(lr, momentum=0.9)
-    step, _, _ = tfm.make_train_step(cfg, optimizer, mesh, attention="local",
-                                     donate=False, remat="full")
-    params = _params(cfg)
-    tokens, labels = _batch(cfg, batch=4)
-    new, opt_state, loss = step(params, optimizer.init(params), tokens,
-                                labels)
-    want, want_g, _ = jax.jit(lambda *a: _reference(cfg, *a))(
-        params, tokens, labels)
-    assert abs(loss - want) <= F32_REL * abs(want)
-    after, before = _checked(new), _checked(params)
-    # From zero momentum the slot holds the gradient itself: the one way
-    # to read dt_bias's (values of -7 to -4 beside an update of 1e-6).
-    momentum = _checked(opt_state[0].trace)
-    for name, g in want_g.items():
-        assert _rel(momentum[name], g) <= F32_REL, name
-        if name != "ssm_dt_bias_last":
-            # (after - before) / -lr loses three digits to the
-            # subtraction.
-            assert _rel((after[name] - before[name]) / -lr, g) <= 3e-3, name
 
 
 def test_train_step_over_the_prefix_takes_the_same_gradient(hvd):
@@ -784,36 +701,6 @@ def test_train_step_over_the_prefix_takes_the_same_gradient(hvd):
 
 # --- refusals: never a silent fall back -------------------------------------
 
-def test_segment_ids_and_packed_are_refused_by_name(hvd):
-    from horovod_tpu.topology import build_mesh
-
-    cfg = NEMOTRON_TINY
-    tokens = jnp.zeros((2, 128), jnp.int32)
-    with pytest.raises(NotImplementedError, match="segment_ids.*mamba2"):
-        jax.eval_shape(lambda p: tfm.forward(
-            p, tokens, cfg, attention="local",
-            segment_ids=jnp.zeros_like(tokens)), tfm.init_abstract(cfg))
-    mesh = build_mesh(axes=("data",), devices=jax.devices()[:2])
-    with pytest.raises(NotImplementedError, match="packed"):
-        tfm.make_train_step(cfg, optax.sgd(0.1), mesh, packed=True)
-    # The prediction module alone refuses them too.
-    no_ssm = dataclasses.replace(
-        cfg, n_layers=2, layer_types=("attention", "mlp"), **NO_SSM)
-    with pytest.raises(NotImplementedError, match="packed.*prediction"):
-        tfm.make_train_step(no_ssm, optax.sgd(0.1), mesh, packed=True)
-
-
-@pytest.mark.parametrize("axis", ("model", "seq"))
-def test_model_and_sequence_axes_are_refused_by_name(hvd, axis):
-    from horovod_tpu.topology import build_mesh
-
-    mesh = build_mesh(axes=("data", axis), shape=(2, 2),
-                      devices=jax.devices()[:4])
-    match = "n_experts" if axis == "model" else "seq_axis"
-    with pytest.raises(NotImplementedError, match=match):
-        tfm.make_train_step(NEMOTRON_TINY, optax.sgd(0.1), mesh,
-                            **{f"{axis}_axis": axis})
-
 
 @pytest.mark.parametrize("field,value", [
     ("n_kv_heads", 1), ("layer_types", ("attention", "mlp")),
@@ -837,44 +724,56 @@ def test_model_axis_decode_and_the_pipelined_builder_refuse_by_name(
         tfm.make_train_step_pipelined(cfg, optax.sgd(0.1), mesh)
     mesh = build_mesh(axes=("data", "model"), shape=(2, 2),
                       devices=jax.devices()[:4])
+    if field == "layer_types":
+        # The parts are asked, not the field: an attention-only and an
+        # MLP-only layer are the GPT-2 block's two parts, each split over
+        # the model axis as it is in a whole layer (the test below).
+        return
     with pytest.raises(NotImplementedError, match=field):
         tfm.make_train_step(cfg, optax.sgd(0.1), mesh, model_axis="model")
 
 
-@pytest.mark.parametrize("fields,error,message", [
-    (dict(ssm_groups=3), ValueError, "ssm_groups"),
-    (dict(ssm_chunk=0), ValueError, "ssm_chunk"),
-    (dict(n_kv_heads=3), ValueError, "n_kv_heads"),
-    (dict(d_latent=0), ValueError, "d_latent"),
-    (dict(experts_held_from=14), ValueError, "experts_held"),
-    (dict(router_aux_coef=0.01), NotImplementedError, "auxiliary"),
-    (dict(mtp_loss_coef=0.0), ValueError, "mtp_loss_coef"),
-    (dict(mtp_layer_types=("sliding",)), ValueError, "mtp_layer_types"),
-    (dict(mlp="swiglu"), ValueError, "relu2"),
-    (dict(layer_types=("attention", "mlp") * 5 + ("mlp",)), ValueError,
-     "ssm_"),
-])
-def test_config_refuses_what_it_cannot_mean(fields, error, message):
-    with pytest.raises(error, match=message):
-        dataclasses.replace(NEMOTRON_TINY, **fields)
+def test_attention_only_and_mlp_only_layers_split_over_the_model_axis(hvd):
+    """A GPT-2 block written as an attention-only and an MLP-only layer
+    takes, on a 2 x 2 data x model mesh, the three steps one device takes
+    on the same batch (the oracle of ``tests/test_parallel.py``), and
+    every leaf ends where it ends there."""
+    from jax.sharding import NamedSharding, PartitionSpec
 
+    from horovod_tpu.topology import build_mesh
 
-def test_specs_and_abstract_params_cover_every_leaf():
-    cfg = NEMOTRON_TINY
-    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
-    specs = tfm.param_specs(cfg, None)
-    assert (jax.tree_util.tree_structure(params)
-            == jax.tree_util.tree_structure(
-                specs, is_leaf=lambda x: isinstance(
-                    x, jax.sharding.PartitionSpec)))
-    # One norm a layer: the mixer's or the feed-forward part's.
-    for layer, kind in zip(params["layers"], cfg.layer_types):
-        norms = {k for k in layer if k.startswith("ln")}
-        assert norms == ({"ln2_scale"} if kind == "mlp" else {"ln1_scale"})
-    assert params["layers"][0]["ssm_w_in"].shape == (64, 64 + 192 + 4)
-    assert params["layers"][1]["w_up"].shape == (4, 32, 64)
-    assert params["layers"][1]["router"].shape == (64, 16)
-    assert params["mtp"]["w_eh"].shape == (128, 64)
+    cfg = tfm.TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=4, d_ff=64,
+        max_seq=32, dtype=jnp.float32,
+        layer_types=("attention", "mlp", "mlp", "attention"))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (4, 33), 0, 64)
+
+    def three_steps(axes, shape, **axis):
+        mesh = build_mesh(axes=axes, shape=shape,
+                          devices=jax.devices()[:int(np.prod(shape))])
+        optimizer = optax.sgd(0.1, momentum=0.9)
+        step, specs, opt_specs = tfm.make_train_step(
+            cfg, optimizer, mesh, attention="local", donate=False, **axis)
+        place = lambda tree, spec: jax.device_put(
+            tree, jax.tree_util.tree_map(
+                lambda s: NamedSharding(mesh, s), spec,
+                is_leaf=lambda x: isinstance(x, PartitionSpec)))
+        params = place(tfm.init_params(jax.random.PRNGKey(0), cfg), specs)
+        opt_state = place(optimizer.init(params), opt_specs)
+        losses = []
+        for _ in range(3):
+            params, opt_state, loss = step(params, opt_state, toks[:, :-1],
+                                           toks[:, 1:])
+            losses.append(float(loss))
+        return losses, params
+
+    got, got_p = three_steps(("data", "model"), (2, 2), model_axis="model")
+    want, want_p = three_steps(("data",), (1,))
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    assert want[-1] < want[0]
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got_p),
+                            jax.tree_util.tree_leaves(want_p)):
+        assert _rel(a, b) <= 1e-5, path
 
 
 def test_published_decay_initialisation_ranges():
