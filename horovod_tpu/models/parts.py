@@ -13,7 +13,8 @@ mixer or feed-forward form is one module and one row.
 
 The parts: :mod:`~horovod_tpu.models.attention` (plain, latent and learned
 sparse attention), :mod:`~horovod_tpu.models.linear_attention`,
-:mod:`~horovod_tpu.models.mamba2`, :mod:`~horovod_tpu.models.mlp` (the
+:mod:`~horovod_tpu.models.mamba2`, :mod:`~horovod_tpu.models.mamba1`,
+:mod:`~horovod_tpu.models.mlp` (the
 dense MLP) and :mod:`~horovod_tpu.models.moe` (softmax-routed,
 sigmoid-routed and latent experts).
 """

@@ -25,7 +25,7 @@ holds, from ``layer_types`` (one of :data:`LAYER_KINDS` a layer) and the
 fields that select a form.  This file runs the table: parameters, specs,
 the stack, the losses the parts hand back, the step, and the refusals,
 which are asked of the parts.  OLMoE, Olmo-Hybrid, Nemotron-3,
-GLM-4.7-Flash and Keye-VL-2.0's language model are configs, not code
+GLM-4.7-Flash, Keye-VL-2.0's language model and Jamba2 are configs, not code
 here.  ``decode_step`` and the pipelined builder implement the GPT-2 block
 alone and say so by name.
 """
@@ -43,7 +43,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.models import (attention as attention_mod, linear_attention,
-                                mamba2, mlp as mlp_mod, moe, parts)
+                                mamba1, mamba2, mlp as mlp_mod, moe, parts)
 from horovod_tpu.parallel import sequence as seq_mod
 from horovod_tpu.telemetry import scopes
 
@@ -54,6 +54,7 @@ _indexer_proj = attention_mod.indexer_proj
 FULL_ATTENTION = "full_attention"
 LINEAR_ATTENTION = "linear_attention"
 MAMBA2 = "mamba2"
+MAMBA = "mamba"
 ATTENTION_ONLY = "attention"
 MLP_ONLY = "mlp"
 # What a layer of each type holds: its sequence mixer (a row of PARTS, or
@@ -62,6 +63,7 @@ MLP_ONLY = "mlp"
 LAYER_KINDS = {FULL_ATTENTION: ("attention", True),
                LINEAR_ATTENTION: ("linear_attention", True),
                MAMBA2: ("mamba2", False),
+               MAMBA: ("mamba1", True),
                ATTENTION_ONLY: ("attention", False),
                MLP_ONLY: (None, True)}
 # Every part a layer can hold (models/parts.py), in the order their rules
@@ -70,7 +72,8 @@ PARTS = {part.name: part for part in (
     linear_attention.PART, mamba2.PART, attention_mod.ATTENTION, mlp_mod.MLP,
     mlp_mod.MLP_BESIDE_EXPERTS, attention_mod.LATENT_ATTENTION,
     attention_mod.SPARSE_ATTENTION,
-    moe.LATENT_EXPERTS, moe.SIGMOID_EXPERTS, moe.SOFTMAX_EXPERTS)}
+    moe.LATENT_EXPERTS, moe.SIGMOID_EXPERTS, moe.SOFTMAX_EXPERTS,
+    mamba1.PART)}
 # The fields no part owns: the model's sizes, its positions and head, the
 # layers' kinds and the prediction module.
 BLOCK_FIELDS = ("vocab_size", "d_model", "n_heads", "n_layers", "max_seq",
@@ -211,6 +214,15 @@ class TransformerConfig:
     ssm_groups: int = 0
     ssm_conv_kernel: int = 0
     ssm_chunk: int = 0
+    # A "mamba" layer's mixer (models/mamba1.py), before the config's
+    # feed-forward form: ``mamba_inner`` channels with a state of
+    # ``mamba_state`` each and a decay of its own for every pair of them,
+    # the step from a projection of rank ``mamba_dt_rank``, a causal
+    # depthwise convolution of ``mamba_conv_kernel`` taps.
+    mamba_inner: int = 0
+    mamba_state: int = 0
+    mamba_dt_rank: int = 0
+    mamba_conv_kernel: int = 0
     # Multi-token prediction: layers of these types on [norm(embed(x_{t+1}));
     # norm(h_t)] W_eh predict x_{t+2} through the model's own embedding
     # and head; ``mtp_loss_coef`` x their cross-entropy is added.

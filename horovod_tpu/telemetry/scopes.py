@@ -62,6 +62,18 @@ SSM_CONV = "ssm_conv"
 SSM_GATE_NORM = "ssm_gate_norm"
 SSM_OUT = "ssm_out"
 
+# The parts of a Mamba-1 state-space mixer (models/mamba1.py), opened as
+# Mamba-2's are: the selective scan is the layer's route; the norm with
+# the in-projection, the convolution and what makes dt, B and C of its
+# output (x_proj, the three inner norms, dt_proj, softplus) sit under
+# ATTN_QKV; the gate and the out projection under ATTN_OUT.
+ATTN_MAMBA_SCAN = "attn/mamba_scan"
+MAMBA_PROJ = "mamba_proj"
+MAMBA_CONV = "mamba_conv"
+MAMBA_DT_BC = "mamba_dt_bc"
+MAMBA_GATE = "mamba_gate"
+MAMBA_OUT = "mamba_out"
+
 # The parts of latent attention's projections (models/attention.latent_qkv),
 # bare components under ATTN_QKV: the query's way through its latent, the
 # keys' and values' through theirs, and the rotary part with the
@@ -126,8 +138,13 @@ GDN_SCAN_BWD = "gdn_scan_bwd"
 SSM_SCAN_FWD = "ssm_scan_fwd"
 SSM_SCAN_BWD = "ssm_scan_bwd"
 
+# The two selective-scan Pallas kernels (ops/selective_scan.py); they run
+# under ATTN_MAMBA_SCAN.
+MAMBA_SCAN_FWD = "mamba_scan_fwd"
+MAMBA_SCAN_BWD = "mamba_scan_bwd"
+
 # The two short-convolution Pallas kernels (ops/short_conv.py); they run
-# under GDN_CONV and SSM_CONV.
+# under GDN_CONV, SSM_CONV and MAMBA_CONV.
 SHORT_CONV_FWD = "short_conv_fwd"
 SHORT_CONV_BWD = "short_conv_bwd"
 

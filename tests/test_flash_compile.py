@@ -1,5 +1,6 @@
 """The three flash kernels, the two gated-delta-rule kernels, the two
-Mamba-2 scan kernels and the two short-convolution kernels compiled at
+Mamba-2 scan kernels, the two selective-scan kernels and the two
+short-convolution kernels compiled at
 the benchmark's shapes for a v5e that is described and not attached (rehearsal 3 of the
 on-chip-measurement guide; the recipe of
 ``perfbench/tests/test_chip_compile.py``).
@@ -151,14 +152,58 @@ def test_mamba2_scan_kernels_compile_for_the_v5e(one_chip, t, g, r, p,
         assert len(re.findall(rf"%{name}[.\d]* = ", text)) == calls, name
 
 
+# jamba2_t16k's selective scan: 5120 channels (five slabs of 1024) with a
+# state of 16 over 16384 tokens in tiles of 256, ``x`` in bfloat16.
+def test_selective_scan_kernels_compile_for_the_v5e(one_chip):
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import selective_scan as op
+    from horovod_tpu.telemetry import scopes
+
+    bsz, t, channels, n = 1, 16384, 5120, 16
+    tile = op.tiles(t, channels, n)
+    assert tile == 256 and op.vmem_bytes(tile, n) <= op.VMEM_LIMIT
+    assert op.takes(jnp.zeros((bsz, t, 8)), channels, n)
+    rows = channels // op.LANES
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    x = shape(bsz, t, rows, op.LANES, dtype=jnp.bfloat16)
+    wide = shape(bsz, t, rows, op.LANES)
+    a, d = shape(n, rows, op.LANES), shape(rows, op.LANES)
+    scalars = shape(bsz, t * n)
+
+    # The calls themselves, told to compile: the public function reads
+    # the executing mesh, and this process's is the CPU.
+    def fwd_and_grads(x, dt, a, d, b_in, c_in, dy):
+        operands = (x, dt, a, d, b_in, c_in)
+        plain = op._fwd_call(*operands, tile=tile, save_states=False,
+                             interpret=False)
+        y, states = op._fwd_call(*operands, tile=tile, save_states=True,
+                                 interpret=False)
+        return plain, y, op._bwd_call(*operands, dy, states, tile=tile,
+                                      interpret=False)
+
+    text = jax.jit(fwd_and_grads).lower(
+        x, wide, a, d, scalars, scalars, wide).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name, calls in ((scopes.MAMBA_SCAN_FWD, 2),
+                        (scopes.MAMBA_SCAN_BWD, 1)):
+        assert len(re.findall(rf"%{name}[.\d]* = ", text)) == calls, name
+
+
 # The short convolutions of one mixer layer: olmohybrid_t16k's q (or k: 30
 # heads of 96 with the L2 norm, written head-major) and v (30 heads of 192),
-# nemotron3s_t8192's xBC with its bias (three token-major outputs).
+# nemotron3s_t8192's xBC with its bias (three token-major outputs),
+# jamba2_t16k's xs with its bias (one output of the whole width).
 @pytest.mark.parametrize("t,channels,kw,tile", [
     (16384, 2880, dict(head_dim=96, norm_scale=96 ** -0.5), 512),
     (16384, 5760, dict(head_dim=192), 512),
-    (8192, 10240, dict(widths=(8192, 1024, 1024)), 256)],
-    ids=["olmohybrid_q", "olmohybrid_v", "nemotron3s_xBC"])
+    (8192, 10240, dict(widths=(8192, 1024, 1024)), 256),
+    (16384, 5120, dict(widths=(5120,)), None)],
+    ids=["olmohybrid_q", "olmohybrid_v", "nemotron3s_xBC", "jamba2_xs"])
 def test_short_conv_kernels_compile_for_the_v5e(one_chip, t, channels, kw,
                                                 tile):
     import jax
@@ -168,6 +213,9 @@ def test_short_conv_kernels_compile_for_the_v5e(one_chip, t, channels, kw,
     from horovod_tpu.telemetry import scopes
 
     head_dim, widths = kw.get("head_dim"), kw.get("widths")
+    if tile is None:        # whatever the kernels choose at this width
+        tile = op.tiles(t, channels, 4, head_dim, widths)
+        assert tile in (256, 512)
     assert op.tiles(t, channels, 4, head_dim, widths) == tile
     plan = op._Plan(widths or (channels,), head_dim, kw.get("norm_scale"),
                     1e-6, widths is not None)

@@ -1,6 +1,7 @@
 """What every LM configuration must hold, written once and run over a table
-of them: the GPT-2 block and the five tiny configurations that keep the
-shape of OLMoE, Olmo-Hybrid, Nemotron-3, GLM-4.7-Flash and Keye-VL-2.0,
+of them: the GPT-2 block and the six tiny configurations that keep the
+shape of OLMoE, Olmo-Hybrid, Nemotron-3, GLM-4.7-Flash, Keye-VL-2.0 and
+Jamba2,
 each against its plain reference under ``perfbench/reference/``, which
 shares no code with the program.
 
@@ -20,8 +21,8 @@ program's) is built once a configuration (:func:`built`).  The families
 that compile a row's program run **in the row's own file**, which imports
 them from here (``from test_lm_configs import *``) and names its row in
 ``COSTLY_ROWS``: under ``--dist loadfile`` a file is one worker's serial
-chain, and six rows in one chain would be the whole suite's wall clock.
-The families that only trace run here, on all six rows, and so do the
+chain, and seven rows in one chain would be the whole suite's wall clock.
+The families that only trace run here, on all seven rows, and so do the
 costly ones of the rows ``COSTLY_ROWS`` names below.
 """
 
@@ -40,8 +41,8 @@ import pytest
 
 from horovod_tpu.models import linear_attention as la
 from horovod_tpu.models import transformer as tfm
-from perfbench.reference import (dsa_moe_lm, hybrid_lm, lm, mla_moe_lm,
-                                 moe_lm, ssm_moe_lm)
+from perfbench.reference import (dsa_moe_lm, hybrid_lm, lm, mamba1_lm,
+                                 mla_moe_lm, moe_lm, ssm_moe_lm)
 
 __all__ = ["COSTLY", "ROWS", "built", "lm_row", "pytest_generate_tests",
            "rel"]
@@ -94,6 +95,17 @@ KEYE_TINY = tfm.TransformerConfig(
     qk_norm_per_head=True, index_heads=4, index_head_dim=16, index_topk=32,
     indexer_loss_coef=1.0, mlp="swiglu", n_experts=8, experts_per_token=2,
     d_expert=48, norm_topk_prob=True, experts_held=4, experts_held_from=2)
+
+# Three Mamba-1 layers around one attention layer of 4 query heads over
+# ONE key-value head of 16, every layer with the dense SwiGLU MLP, the
+# head tied; 128 inner channels with a state of 4 each, the step from a
+# rank of 8.
+JAMBA_PATTERN = ("mamba", "mamba", "full_attention", "mamba")
+JAMBA_TINY = tfm.TransformerConfig(
+    vocab_size=128, d_model=64, n_heads=4, n_kv_heads=1, n_layers=4,
+    d_ff=96, max_seq=128, dtype=F32, positions="none", norm_eps=1e-6,
+    tie_embeddings=True, mlp="swiglu", layer_types=JAMBA_PATTERN,
+    mamba_inner=128, mamba_state=4, mamba_dt_rank=8, mamba_conv_kernel=4)
 
 
 def rel(got, want):
@@ -174,6 +186,21 @@ def _keye_ref(cfg, params, tokens, labels, **kw):
         names=tuple(dsa_moe_lm.LEAVES), **kw)
 
 
+def jamba_dims(cfg):
+    return {"n_heads": cfg.n_heads, "kv_heads": cfg.kv_heads,
+            "state": cfg.mamba_state, "dt_rank": cfg.mamba_dt_rank,
+            "eps": cfg.norm_eps}
+
+
+def _jamba_ref(cfg, params, tokens, labels, **kw):
+    """Every leaf of the last Mamba layer, of the attention layer and of
+    the last MLP, not only the cell's."""
+    return mamba1_lm.loss_and_tail_grads(
+        params, tokens, labels, dims=jamba_dims(cfg),
+        layer_types=cfg.layer_types, names=tuple(mamba1_lm.LEAVES),
+        stats=True, **kw)
+
+
 def _by_paths(reference, paths):
     return lambda tree, cfg: {name: reference.leaf(tree, path)
                               for name, path in paths(cfg).items()}
@@ -192,6 +219,15 @@ def _rows_and_bias(stats, grads, layers_by_held):
     bias, which chooses and is not trained."""
     assert stats["rows"].shape == layers_by_held
     assert float(jnp.abs(grads["layers"][1]["router_bias"]).max()) == 0.0
+
+
+def _jamba_decays(stats):
+    """The three Mamba layers' decays and steps, as the reference saw
+    them."""
+    assert stats["decay"].shape == stats["delta"].shape == (3, 3)
+    assert 0.0 < float(stats["decay"].min()) <= float(
+        stats["decay"].max()) < 1.0
+    assert float(stats["delta"].min()) > 0.0
 
 
 def _rows_and_kl(stats):
@@ -264,6 +300,8 @@ _GLM_NOT_THE_BLOCKS = ("positions", "head_width", "kv_latent_rank",
 _KEYE_NOT_THE_BLOCKS = _INDEXER + ("positions", "head_width", "n_kv_heads",
                                    "qk_norm_per_head", "n_experts")
 _GDN_BLOCKS = 2 * 2 * 256 // la.BLOCK
+_NO_MAMBA = dict(layer_types=(), mamba_inner=0, mamba_state=0,
+                 mamba_dt_rank=0, mamba_conv_kernel=0)
 
 ROWS = {
     "gpt2": Row(
@@ -572,6 +610,67 @@ ROWS = {
                (dict(q_latent_rank=8, kv_latent_rank=8, rope_dim=8,
                      n_kv_heads=0, qk_norm_per_head=False),
                 NotImplementedError, "indexer beside latent attention"))),
+    "jamba": Row(
+        cfg=JAMBA_TINY, ref=_jamba_ref, seq=128, embed_scale=5.0,
+        checked=_by_paths(mamba1_lm, lambda cfg: mamba1_lm.leaf_paths(
+            cfg.layer_types)),
+        # The one key-value head under four query heads, through the
+        # plain route and through the flash kernels.
+        parity=(("local", F32, "local", 5e-5, 2e-4),
+                ("flash", F32, "flash", 5e-5, 2e-4)),
+        also=lambda stats, grads: _jamba_decays(stats),
+        # The six references that the cell's check must refuse are other
+        # functions at this size too.
+        controls=(
+            ("float8", dict(low_precision=jnp.float8_e4m3fn), "w_down_last",
+             1e-4, 0.02),
+            ("state_reset", dict(reset_every=16), "mamba_a_log_last", 1e-4,
+             0.02),
+            ("one_decay", dict(one_decay=True), "mamba_a_log_last", 1e-4,
+             0.02),
+            ("no_inner_norms", dict(inner_norms=False), "mamba_w_dt_last",
+             1e-4, 0.02),
+            ("no_skip", dict(skip=False), "mamba_d_last", 1e-4, 0.02),
+            ("independent_kv", dict(independent_kv=True), "wk_attn", 1e-4,
+             0.02)),
+        remat_rel=1e-4,
+        # Values of -7 to -2 beside an update of 1e-6, as Nemotron's.
+        unread=("mamba_dt_bias_last", "mamba_a_log_last"),
+        shapes={("layers", 0, "mamba_w_in"): (64, 256),
+                ("layers", 0, "mamba_w_x"): (128, 8 + 4 + 4),
+                ("layers", 0, "mamba_w_dt"): (8, 128),
+                ("layers", 0, "mamba_a_log"): (128, 4),
+                ("layers", 0, "w_down"): (96, 64),
+                ("layers", 2, "wk"): (64, 16)},
+        series=('hvd_mamba_scan_tokens_total{layer="0",path="xla"} 256',
+                'hvd_mamba_saved_state_bytes{layer="3"} '
+                f'{2 * 128 * 4 * 4}',
+                'hvd_short_conv_rows_total{layer="1",path="kernel"} 256'),
+        no_series=('hvd_mamba_scan_tokens_total{layer="2"', "hvd_ssm_",
+                   "hvd_gdn_", "hvd_moe_"),
+        # What perfbench/mamba1_reduce.py reads.
+        scopes=("layer_0/attn/qkv/mamba_proj", "layer_0/attn/qkv/mamba_conv",
+                "layer_0/attn/qkv/mamba_dt_bc", "layer_0/attn/mamba_scan",
+                "layer_3/attn/out/mamba_gate", "layer_3/attn/out/mamba_out",
+                "layer_0/mlp", "layer_2/attn/local_attention"),
+        no_scopes=("layer_2/attn/mamba_scan", "/ssm_", "/gdn_", "/moe_"),
+        refused={"model_axis": ("n_kv_heads", "layer_types"),
+                 "seq_axis": ("layer_types",), "packed": ("layer_types",),
+                 "segment_ids": ("layer_types",),
+                 "decode_step": ("positions", "n_kv_heads", "layer_types",
+                                 "mamba_inner"),
+                 "pipelined": ("positions", "n_kv_heads", "layer_types",
+                               "mamba_inner", "mlp")},
+        alone=tuple(("mamba1", dict(n_kv_heads=0), what, "layer_types")
+                    for what in ("model_axis", "seq_axis", "segment_ids")),
+        rules=((dict(mamba_state=0), ValueError, "mamba_state"),
+               (dict(mamba_dt_rank=0), ValueError, "mamba_dt_rank"),
+               (dict(layer_types=("full_attention",) * 4), ValueError,
+                "mamba_"),
+               (dict(_NO_MAMBA, mamba_inner=128), ValueError,
+                "mean nothing without a 'mamba' entry"),
+               (dict(layer_types=("mamba", "mamba1") * 2), ValueError,
+                "layer_types"))),
 }
 
 

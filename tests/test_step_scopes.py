@@ -294,6 +294,58 @@ def test_nemotron_step_carries_the_vocabulary_and_every_part(
     assert set(ssm_reduce.PARTS) | {ssm_reduce.MTP} <= parts
 
 
+MAMBA1_PARTS = {scopes.MAMBA_PROJ: scopes.ATTN_QKV,
+                scopes.MAMBA_CONV: scopes.ATTN_QKV,
+                scopes.MAMBA_DT_BC: scopes.ATTN_QKV,
+                scopes.MAMBA_GATE: scopes.ATTN_OUT,
+                scopes.MAMBA_OUT: scopes.ATTN_OUT}
+JAMBA = dict(positions="none", tie_embeddings=True, n_kv_heads=1,
+             mlp="swiglu", mamba_inner=128, mamba_state=4, mamba_dt_rank=8,
+             mamba_conv_kernel=4, layer_types=("mamba", "full_attention"))
+
+
+@pytest.mark.parametrize("attention,remat",
+                         [("local", "none"), ("flash", "full")])
+def test_jamba_step_carries_the_vocabulary_and_the_mixers_parts(
+        hvd, attention, remat):
+    """A Mamba-1 layer and a multi-query attention layer, each with the
+    dense MLP: the mixer's five parts open under ``attn/qkv`` and
+    ``attn/out`` and its scan as a route the vocabulary answers with
+    ``layer``; every executed op has a phase and a scope, and
+    ``perfbench/mamba1_reduce.py`` reads each part by name."""
+    from perfbench import mamba1_reduce, moe_reduce
+
+    text = _lm_step_text(attention, remat, False, **JAMBA)
+    # (The ``jax.numpy`` scan recomputes its blocks under every policy.)
+    _check_lm(text, attention, remat, False, recurrence_recomputes=True)
+    names = _op_names(text)
+    marks = ("jvp(", "transpose(") + (
+        ("rematted_computation",) if remat == "full" else ())
+    for part, parent in MAMBA1_PARTS.items():
+        inside = f"{parent}/{part}"
+        for mark in marks:
+            if (part, mark) != (scopes.MAMBA_OUT, "rematted_computation"):
+                assert _under(names, part, inside, mark), (part, mark)
+        assert not _under(names, part, without=(inside,)), part
+    for mark in marks:
+        assert _under(names, scopes.ATTN_MAMBA_SCAN, mark), mark
+    # Layer 0 holds the mixer and layer 1 the attention, each with an MLP.
+    assert not any("mamba_" in n and "layer_1" in n for n in names)
+    assert not any(ROUTE[attention] in n and "layer_0" in n for n in names)
+    for i in range(2):
+        assert _under(names, scopes.MLP, scopes.LAYER % i, "jvp("), i
+    scan = (f"jit(x)/transpose(jvp({scopes.LAYER % 0}))/"
+            f"{scopes.ATTN_MAMBA_SCAN}/while/body/mul")
+    assert scope_reduce.scope_of(scan) == "layer"
+    assert scope_reduce.phase_of(scan) == "bwd"
+    hlo = scope_reduce.parse_hlo(text)
+    parts = set()
+    for name, i in hlo.instructions.items():
+        if i.opcode in HELD:
+            parts.add(mamba1_reduce.part_of(moe_reduce.op_name_of(name, hlo)))
+    assert set(mamba1_reduce.PARTS) <= parts
+
+
 MLA_PARTS = (scopes.MLA_Q, scopes.MLA_KV, scopes.MLA_ROPE)
 SIGMOID_PARTS = MOE_PARTS + (scopes.MOE_SHARED,)
 GLM = dict(positions="rope", tie_embeddings=False, head_width=64,
@@ -475,6 +527,11 @@ def test_the_benchmark_reads_the_same_vocabulary():
     dsa_kernels = {scopes.DSA_INDEX_FWD, scopes.DSA_INDEX_BWD,
                    scopes.DSA_SELECT_KERNEL, scopes.DSA_FWD,
                    scopes.DSA_BWD_DQ, scopes.DSA_BWD_DKV, scopes.DSA_PROBS}
+    # And the Mamba-1 mixer's five parts with its route, read by
+    # ``perfbench/mamba1_reduce.py``, and its scan's two kernels, booked
+    # by the route.
+    mamba1_parts = set(MAMBA1_PARTS) | {scopes.ATTN_MAMBA_SCAN}
+    mamba1_kernels = {scopes.MAMBA_SCAN_FWD, scopes.MAMBA_SCAN_BWD}
     from perfbench import dsa_reduce
     assert dsa_parts == set(dsa_reduce.DSA_PARTS)
     assert set(scope_reduce.KERNEL_NAMES) == kernels
@@ -483,7 +540,17 @@ def test_the_benchmark_reads_the_same_vocabulary():
             == program - kernels - modules - {scopes.LAYER} - moe_parts
             - moe_kernels - gdn_parts - gdn_kernels - ssm_parts
             - ssm_kernels - mla_parts - conv_kernels - norm_kernels
-            - dsa_parts - dsa_kernels)
+            - dsa_parts - dsa_kernels - mamba1_parts - mamba1_kernels)
+    from perfbench import mamba1_reduce
+    assert ({p.rsplit("/", 1)[-1] for p in mamba1_parts}
+            == set(mamba1_reduce.PARTS))
+    for phase, name, kernel in (
+            ("fwd", "jvp(%s)", scopes.MAMBA_SCAN_FWD),
+            ("bwd", "transpose(jvp(%s))", scopes.MAMBA_SCAN_BWD)):
+        call = (f"jit(x)/{name % (scopes.LAYER % 3)}/"
+                f"{scopes.ATTN_MAMBA_SCAN}/{kernel}/pallas_call")
+        assert mamba1_reduce.part_of(call) == mamba1_reduce.SCAN
+        assert scope_reduce.phase_of(call) == phase
     from perfbench import mla_reduce
     assert (mla_parts | moe_parts | {scopes.MOE_SHARED}
             == set(mla_reduce.PARTS))
