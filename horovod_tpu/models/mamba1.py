@@ -42,9 +42,15 @@ Pallas kernels of :mod:`horovod_tpu.ops.short_conv` wherever they can run
 (:func:`conv_path`), else ``causal_conv`` and what follows it as
 ``jax.numpy``.  ``hvd_short_conv_rows_total{path}`` says which was traced.
 
-**The gate** ``y * silu(z)`` has no norm, so it is left to XLA: it fuses
-with the scan's output on its way back to token-major rows and writes the
-out projection's operand once.
+**The gate** ``y * silu(z)`` stands between two layouts: the scan's
+kernels leave ``y`` a token's 1024 channels a register, the out projection
+wants a token a sublane.  The Pallas kernels of
+:mod:`horovod_tpu.ops.mamba_gate` make that move while they gate, wherever
+they can run (:func:`gate_path`: the scan on its kernels' path): ``y`` and
+``z`` are read once and the out projection's operand written once, and the
+backward writes ``dy`` as the scan's backward reads it.  Else
+:func:`gate_xla`, the line as ``jax.numpy``.
+``hvd_mamba_gate_rows_total{path}`` says which was traced.
 
 ``softplus`` runs inside the scan, either way (the kernels read the
 step's pre-activation and keep nothing else of it for the backward).
@@ -73,6 +79,7 @@ from jax import lax
 from horovod_tpu import telemetry
 from horovod_tpu.models import parts
 from horovod_tpu.models.linear_attention import causal_conv
+from horovod_tpu.ops import mamba_gate
 from horovod_tpu.ops import selective_scan as kernels
 from horovod_tpu.ops import short_conv
 from horovod_tpu.parallel._vma import pin_to, vma_of
@@ -184,6 +191,23 @@ def conv_path(u, cfg) -> str:
         u, cfg.mamba_conv_kernel, channels=cfg.mamba_inner) else "xla"
 
 
+def gate_xla(y, z):
+    """``y * silu(z)`` in float32, rounded once to ``z``'s dtype: ``y``
+    [B, T, C] float32, ``z`` [B, T, C] in the model dtype."""
+    return (y * jax.nn.silu(z.astype(jnp.float32))).astype(z.dtype)
+
+
+def gate_path(u, cfg) -> str:
+    """What runs the gate of a layer of ``cfg`` over what is projected
+    from ``u`` [B, T, d], read as :func:`scan_path` reads its:
+    ``"kernel"``, the Pallas kernels of :mod:`horovod_tpu.ops.mamba_gate`,
+    which read ``y`` in the layout the scan's kernels leave it in, so only
+    where those run and for sizes ``mamba_gate.takes`` accepts;
+    ``"xla"``, :func:`gate_xla`."""
+    return "kernel" if scan_path(u, cfg) == "kernel" and mamba_gate.takes(
+        u, cfg.mamba_inner) else "xla"
+
+
 def saved_state_bytes(batch: int, t: int, cfg) -> int:
     """Bytes of states the backward of one layer's scan keeps: the
     float32 state at the start of each tile (each block of
@@ -236,7 +260,9 @@ def mixer(u, layer, cfg):
         y = scan(xs, step, a, b_in, c_in, layer["mamba_d"])
     with jax.named_scope(scopes.ATTN_OUT):
         with jax.named_scope(scopes.MAMBA_GATE):
-            y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(dt)
+            gate = (mamba_gate.mamba_gate if gate_path(u, cfg) == "kernel"
+                    else gate_xla)
+            y = gate(y, z)
         with jax.named_scope(scopes.MAMBA_OUT):
             return y @ layer["mamba_w_out"].astype(dt)
 
@@ -246,7 +272,7 @@ def record_tokens(layer, x, cfg) -> None:
     ``hvd_ssm_chunks_total``): the tokens the scan of layer ``layer``
     walks per step on one device over the batch of its input ``x`` [B, T,
     d], by what runs them (:func:`scan_path`), and the bytes of states
-    its backward keeps."""
+    its backward keeps; and the rows of its convolution and of its gate."""
     if not telemetry.enabled():
         return
     batch, t = x.shape[:2]
@@ -262,6 +288,7 @@ def record_tokens(layer, x, cfg) -> None:
         "layer's scan keeps",
         layer=str(layer)).set(saved_state_bytes(batch, t, cfg))
     short_conv.record_rows(layer, batch * t, conv_path(x, cfg))
+    mamba_gate.record_rows(layer, batch * t, gate_path(x, cfg))
 
 
 # --- the mixer as a part (models/parts.py) ----------------------------------

@@ -143,6 +143,11 @@ SSM_SCAN_BWD = "ssm_scan_bwd"
 MAMBA_SCAN_FWD = "mamba_scan_fwd"
 MAMBA_SCAN_BWD = "mamba_scan_bwd"
 
+# The two Pallas kernels of the Mamba-1 gate (ops/mamba_gate.py); they run
+# under MAMBA_GATE.
+MAMBA_GATE_FWD = "mamba_gate_fwd"
+MAMBA_GATE_BWD = "mamba_gate_bwd"
+
 # The two short-convolution Pallas kernels (ops/short_conv.py); they run
 # under GDN_CONV, SSM_CONV and MAMBA_CONV.
 SHORT_CONV_FWD = "short_conv_fwd"

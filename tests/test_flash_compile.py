@@ -1,6 +1,6 @@
 """The three flash kernels, the two gated-delta-rule kernels, the two
-Mamba-2 scan kernels, the two selective-scan kernels and the two
-short-convolution kernels compiled at
+Mamba-2 scan kernels, the two selective-scan kernels, the two kernels of
+the Mamba-1 gate and the two short-convolution kernels compiled at
 the benchmark's shapes for a v5e that is described and not attached (rehearsal 3 of the
 on-chip-measurement guide; the recipe of
 ``perfbench/tests/test_chip_compile.py``).
@@ -192,6 +192,40 @@ def test_selective_scan_kernels_compile_for_the_v5e(one_chip):
     for name, calls in ((scopes.MAMBA_SCAN_FWD, 2),
                         (scopes.MAMBA_SCAN_BWD, 1)):
         assert len(re.findall(rf"%{name}[.\d]* = ", text)) == calls, name
+
+
+# jamba2_t16k's gate: ``y`` float32 as the scan leaves it (16384 tokens of
+# 40 rows of 128 lanes), ``z`` and ``d out`` bfloat16 [16384, 5120], in
+# tiles of 256 tokens; the strided sublane loads and stores that make the
+# layout move have to be ones Mosaic takes.
+def test_mamba_gate_kernels_compile_for_the_v5e(one_chip):
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import mamba_gate as op
+    from horovod_tpu.telemetry import scopes
+
+    t, channels = 16384, 5120
+    tile = op.tiles(t, channels)
+    assert tile == 256 and op.vmem_bytes(tile, channels) <= op.VMEM_LIMIT
+    assert op.takes(jnp.zeros((1, t, 8), jnp.bfloat16), channels)
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    y = shape(1, t * channels // op.LANES, op.LANES, dtype=jnp.float32)
+    z = shape(1, t, channels)
+
+    # The calls themselves, told to compile: the public function reads
+    # the executing mesh, and this process's is the CPU.
+    def fwd_and_grads(y, z, dout):
+        return (op._fwd_call(y, z, tile=tile, interpret=False),
+                op._bwd_call(y, z, dout, tile=tile, interpret=False))
+
+    text = jax.jit(fwd_and_grads).lower(y, z, z).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for name in (scopes.MAMBA_GATE_FWD, scopes.MAMBA_GATE_BWD):
+        assert len(re.findall(rf"%{name}[.\d]* = ", text)) == 1, name
 
 
 # The short convolutions of one mixer layer: olmohybrid_t16k's q (or k: 30

@@ -645,8 +645,10 @@ ROWS = {
         series=('hvd_mamba_scan_tokens_total{layer="0",path="xla"} 256',
                 'hvd_mamba_saved_state_bytes{layer="3"} '
                 f'{2 * 128 * 4 * 4}',
-                'hvd_short_conv_rows_total{layer="1",path="kernel"} 256'),
-        no_series=('hvd_mamba_scan_tokens_total{layer="2"', "hvd_ssm_",
+                'hvd_short_conv_rows_total{layer="1",path="kernel"} 256',
+                'hvd_mamba_gate_rows_total{layer="3",path="xla"} 256'),
+        no_series=('hvd_mamba_scan_tokens_total{layer="2"',
+                   'hvd_mamba_gate_rows_total{layer="2"', "hvd_ssm_",
                    "hvd_gdn_", "hvd_moe_"),
         # What perfbench/mamba1_reduce.py reads.
         scopes=("layer_0/attn/qkv/mamba_proj", "layer_0/attn/qkv/mamba_conv",

@@ -529,9 +529,10 @@ def test_the_benchmark_reads_the_same_vocabulary():
                    scopes.DSA_BWD_DQ, scopes.DSA_BWD_DKV, scopes.DSA_PROBS}
     # And the Mamba-1 mixer's five parts with its route, read by
     # ``perfbench/mamba1_reduce.py``, and its scan's two kernels, booked
-    # by the route.
+    # by the route, and its gate's two, booked by ``mamba_gate``.
     mamba1_parts = set(MAMBA1_PARTS) | {scopes.ATTN_MAMBA_SCAN}
-    mamba1_kernels = {scopes.MAMBA_SCAN_FWD, scopes.MAMBA_SCAN_BWD}
+    mamba1_kernels = {scopes.MAMBA_SCAN_FWD, scopes.MAMBA_SCAN_BWD,
+                      scopes.MAMBA_GATE_FWD, scopes.MAMBA_GATE_BWD}
     from perfbench import dsa_reduce
     assert dsa_parts == set(dsa_reduce.DSA_PARTS)
     assert set(scope_reduce.KERNEL_NAMES) == kernels
@@ -551,6 +552,15 @@ def test_the_benchmark_reads_the_same_vocabulary():
                 f"{scopes.ATTN_MAMBA_SCAN}/{kernel}/pallas_call")
         assert mamba1_reduce.part_of(call) == mamba1_reduce.SCAN
         assert scope_reduce.phase_of(call) == phase
+    # The gate's kernels are booked where the ``jax.numpy`` line was.
+    for phase, name, kernel in (
+            ("fwd", "jvp(%s)", scopes.MAMBA_GATE_FWD),
+            ("bwd", "transpose(jvp(%s))", scopes.MAMBA_GATE_BWD)):
+        call = (f"jit(x)/{name % (scopes.LAYER % 3)}/{scopes.ATTN_OUT}/"
+                f"{scopes.MAMBA_GATE}/{kernel}/pallas_call")
+        assert mamba1_reduce.part_of(call) == scopes.MAMBA_GATE
+        assert scope_reduce.phase_of(call) == phase
+        assert scope_reduce.scope_of(call) == scopes.ATTN_OUT
     from perfbench import mla_reduce
     assert (mla_parts | moe_parts | {scopes.MOE_SHARED}
             == set(mla_reduce.PARTS))
