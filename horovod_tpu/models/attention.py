@@ -29,8 +29,9 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.models import parts
 from horovod_tpu.models.parts import dense, ones, rmsnorm, whole
-from horovod_tpu.ops import sparse_attention
-from horovod_tpu.ops.flash_attention import flash_attention
+from horovod_tpu.ops import mla_assemble, sparse_attention
+from horovod_tpu.ops.flash_attention import (flash_attention,
+                                             flash_attention_folded)
 from horovod_tpu.parallel import sequence as seq_mod
 from horovod_tpu.parallel import tensor as tp
 from horovod_tpu.telemetry import scopes
@@ -106,7 +107,40 @@ def indexer_proj(u, layer, cfg, positions):
     return qi, ki, w
 
 
-def latent_qkv(h, layer, cfg, positions):
+def assemble_xla(q, up, k_r, positions, heads: int, theta: float):
+    """Latent attention's heads put together, as ``jax.numpy``: ``q`` [...,
+    T, H * hd] as heads of ``[q_n | q_r]``, ``up`` [..., T, H * (nope +
+    hd)] as heads of ``[k_n | v]`` and the one rotary key ``k_r`` [..., T,
+    rope]; ``q_r`` and ``k_r`` rotary at ``positions``, ``k = [k_n |
+    k_r]``.  Returns q, k, v [..., T, heads, hd]."""
+    rope = k_r.shape[-1]
+    hd = q.shape[-1] // heads
+    nope = hd - rope
+    q = q.reshape(q.shape[:-1] + (heads, hd))
+    up = up.reshape(up.shape[:-1] + (heads, nope + hd))
+    k_n, v = up[..., :nope], up[..., nope:]
+    q = jnp.concatenate(
+        [q[..., :nope], rotary(q[..., nope:], positions, theta)], axis=-1)
+    k_r = rotary(k_r[..., None, :], positions, theta)
+    k = jnp.concatenate(
+        [k_n, jnp.broadcast_to(k_r, k_n.shape[:-1] + (rope,))], axis=-1)
+    return q, k, v
+
+
+def assemble_path(h, cfg, ctx) -> str:
+    """What puts the heads of a latent-attention layer of ``cfg`` together
+    from the projections of ``h`` [B, T, d] under ``ctx``: ``"kernel"``,
+    the Pallas kernels of :mod:`horovod_tpu.ops.mla_assemble`, which write
+    q, k, v in the flash kernels' layout, so only on that route and for
+    sizes ``mla_assemble.takes`` accepts (compiled where the mesh that
+    executes ``h`` is TPU, interpreted elsewhere); ``"xla"``,
+    :func:`assemble_xla`, everywhere else."""
+    return "kernel" if mla_assemble.takes(
+        h, cfg.n_heads, cfg.head_dim, cfg.rope_dim) and _flash_route(
+            ctx, h.shape[1]) else "xla"
+
+
+def latent_qkv(h, layer, cfg, positions, path: str = "xla"):
     """Latent attention's q, k, v from the normed input ``h`` [..., T, d],
     in the up-projected form (K and V materialised per head; the absorbed
     form and a cache of latents are decode's: ROADMAP R13): ``c_q =
@@ -115,29 +149,25 @@ def latent_qkv(h, layer, cfg, positions):
     c_kv W_kvb`` per head; ``q_r`` and ``k_r`` rotary at ``positions``,
     ``k_r`` **one head that every head's key ends in** (so its gradient
     sums over the heads); ``k = [k_n | k_r]``.  Returns q, k, v [..., T,
-    heads, head_width] and ``heads * head_width``."""
-    dt, heads, hd, rope = cfg.dtype, cfg.n_heads, cfg.head_dim, cfg.rope_dim
-    nope, rank = hd - rope, cfg.kv_latent_rank
+    heads, head_width] and ``heads * head_width``; with ``path``
+    ``"kernel"`` (:func:`assemble_path`) q, k, v ``[B * heads, T,
+    head_width]``, the flash kernels' layout."""
+    dt, heads, rank = cfg.dtype, cfg.n_heads, cfg.kv_latent_rank
     with jax.named_scope(scopes.MLA_Q):
         c_q = rmsnorm(h @ layer["w_qa"].astype(dt),
                       layer["q_latent_norm_scale"], cfg.norm_eps)
-        q = (c_q @ layer["w_qb"].astype(dt)).reshape(
-            h.shape[:-1] + (heads, hd))
+        q = c_q @ layer["w_qb"].astype(dt)
     with jax.named_scope(scopes.MLA_KV):
         down = h @ layer["w_kva"].astype(dt)
         c_kv = rmsnorm(down[..., :rank], layer["kv_latent_norm_scale"],
                        cfg.norm_eps)
-        up = (c_kv @ layer["w_kvb"].astype(dt)).reshape(
-            h.shape[:-1] + (heads, nope + hd))
-        k_n, v = up[..., :nope], up[..., nope:]
+        up = c_kv @ layer["w_kvb"].astype(dt)
     with jax.named_scope(scopes.MLA_ROPE):
-        q = jnp.concatenate(
-            [q[..., :nope], rotary(q[..., nope:], positions,
-                                   cfg.rope_theta)], axis=-1)
-        k_r = rotary(down[..., None, rank:], positions, cfg.rope_theta)
-        k = jnp.concatenate(
-            [k_n, jnp.broadcast_to(k_r, k_n.shape[:-1] + (rope,))], axis=-1)
-    return q, k, v, heads * hd
+        assemble = (mla_assemble.mla_assemble if path == "kernel"
+                    else assemble_xla)
+        q, k, v = assemble(q, up, down[..., rank:], positions, heads,
+                           cfg.rope_theta)
+    return q, k, v, heads * cfg.head_dim
 
 
 def _share_kv_heads(k, v, n_heads: int):
@@ -187,15 +217,21 @@ def _flash_profitable(t: int) -> bool:
     return t >= min_t
 
 
+def _flash_route(ctx, t: int) -> bool:
+    """Whether ``ctx`` sends ``t`` tokens through the single-device flash
+    kernels."""
+    return ctx.seq_axis is None and (
+        ctx.attention in ("flash", "ring_flash")
+        or (ctx.attention == "auto" and _flash_profitable(t)))
+
+
 def _routed(q, k, v, dh, x, layer, cfg, ctx):
     """``q, k, v`` through the route ``ctx.attention`` names (each opens
     its own ``attn/<route>``), the out projection and the residual."""
     seq_axis, attention, segment_ids = (ctx.seq_axis, ctx.attention,
                                         ctx.segment_ids)
     b, t = q.shape[:2]
-    flash = seq_axis is None and (
-        attention in ("flash", "ring_flash")
-        or (attention == "auto" and _flash_profitable(t)))
+    flash = _flash_route(ctx, t)
     with jax.named_scope(scopes.ATTN_FLASH if flash else scopes.ATTN_QKV):
         k, v = _share_kv_heads(k, v, q.shape[-2])
     if seq_axis is not None:
@@ -345,11 +381,28 @@ def _latent_specs(cfg, model_axis):
 
 
 def _latent_apply(x, layer, cfg, ctx):
+    path = assemble_path(x, cfg, ctx)
     with jax.named_scope(scopes.ATTN_QKV):
         q, k, v, dh = latent_qkv(
             rmsnorm(x, layer["ln1_scale"], cfg.norm_eps), layer, cfg,
-            ctx.positions)
-    return _routed(q, k, v, dh, x, layer, cfg, ctx), {}
+            ctx.positions, path)
+    if path != "kernel":
+        return _routed(q, k, v, dh, x, layer, cfg, ctx), {}
+    # The heads were born in the flash kernels' layout, and the out
+    # projection contracts over (head, width) as they leave it.
+    heads, hd = cfg.n_heads, cfg.head_dim
+    o = flash_attention_folded(q, k, v, heads, True,
+                               segment_ids=ctx.segment_ids)
+    with jax.named_scope(scopes.ATTN_OUT):
+        o = jnp.einsum(
+            "bhtd,hdm->btm", o.reshape((-1, heads) + o.shape[1:]),
+            layer["wo"].astype(cfg.dtype).reshape(heads, hd, -1))
+        return x + o, {}
+
+
+def _latent_record(name, x, layer, cfg, ctx):
+    batch, t = x.shape[:2]
+    mla_assemble.record_rows(name, batch * t, assemble_path(x, cfg, ctx))
 
 
 # Not written: heads of a latent's up-projection over chips, and the
@@ -357,6 +410,7 @@ def _latent_apply(x, layer, cfg, ctx):
 LATENT_ATTENTION = parts.Part(
     name="latent_attention", fields=_LATENT, validate=_latent_validate,
     init=_latent_init, specs=_latent_specs, apply=_latent_apply,
+    record=_latent_record,
     unsupported={"model_axis": ("head_width",) + _LATENT,
                  "seq_axis": ("head_width",) + _LATENT})
 

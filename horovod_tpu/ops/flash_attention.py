@@ -753,3 +753,72 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, do):
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+
+# ---------------------------------------------------------------------------
+# The same kernels for operands that are born in their layout
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def flash_attention_folded(q, k, v, heads: int, causal: bool = True,
+                           scale: Optional[float] = None,
+                           block_q: Optional[int] = None,
+                           block_k: Optional[int] = None,
+                           interpret: Optional[bool] = None,
+                           segment_ids=None):
+    """:func:`flash_attention` on q/k/v ``[B * heads, T, D]``, the layout
+    the kernels work in (head ``h`` of batch row ``b`` at ``b * heads +
+    h``); returns ``[B * heads, T, D]``.  The same three kernels under the
+    same names and the same scope, without the moves from and to ``[B, T,
+    H, D]`` around them: for a caller whose heads are made head-major
+    (:mod:`horovod_tpu.ops.mla_assemble`).  ``segment_ids`` [B, T]."""
+    out, _ = _folded_fwd(q, k, v, heads, causal, scale, block_q, block_k,
+                         interpret, segment_ids)
+    return out
+
+
+def _folded_config(q, scale, block_q, block_k, interpret, segments):
+    _, t, d = q.shape
+    return ((d ** -0.5) if scale is None else scale,
+            *_eff_blocks(t, block_q, block_k, d, segments),
+            _interpret_default(q) if interpret is None else interpret)
+
+
+def _folded_segments(seg, t):
+    return seg.reshape(seg.shape[0], 1, t) if seg is not None else None
+
+
+@jax.named_scope(scopes.ATTN_FLASH)
+def _folded_fwd(q, k, v, heads, causal, scale, block_q, block_k, interpret,
+                segment_ids=None):
+    if q.shape != k.shape or q.shape != v.shape or q.shape[0] % heads:
+        raise ValueError(f"q/k/v [B * {heads}, T, D] must match, got "
+                         f"{q.shape} {k.shape} {v.shape}")
+    scale_, bq, bk, interp = _folded_config(q, scale, block_q, block_k,
+                                            interpret,
+                                            segment_ids is not None)
+    t = q.shape[1]
+    if t % bq or t % bk:
+        raise ValueError(
+            f"sequence length {t} must be divisible by block_q={bq} "
+            f"and block_k={bk} (pad the sequence)")
+    segf = _folded_segments(segment_ids, t)
+    o, m, l = _fwd_parts(q, k, v, segf, segf, heads, causal, scale_, bq, bk,
+                         interp)
+    return o, (q, k, v, o, m, l, segment_ids)
+
+
+@jax.named_scope(scopes.ATTN_FLASH)
+def _folded_bwd(heads, causal, scale, block_q, block_k, interpret, res, do):
+    q, k, v, o, m, l, seg = res
+    scale_, bq, bk, interp = _folded_config(q, scale, block_q, block_k,
+                                            interpret, seg is not None)
+    segf = _folded_segments(seg, q.shape[1])
+    dq, dk, dv = _bwd_parts(q, k, v, o, do, m, l, segf, segf, heads, causal,
+                            scale_, bq, bk, interp)
+    dseg = (np.zeros(seg.shape, jax.dtypes.float0)
+            if seg is not None else None)
+    return dq, dk, dv, dseg
+
+
+flash_attention_folded.defvjp(_folded_fwd, _folded_bwd)

@@ -148,6 +148,11 @@ MAMBA_SCAN_BWD = "mamba_scan_bwd"
 MAMBA_GATE_FWD = "mamba_gate_fwd"
 MAMBA_GATE_BWD = "mamba_gate_bwd"
 
+# The two Pallas kernels of latent attention's assembly
+# (ops/mla_assemble.py); they run under MLA_ROPE.
+MLA_ASSEMBLE_FWD = "mla_assemble_fwd"
+MLA_ASSEMBLE_BWD = "mla_assemble_bwd"
+
 # The two short-convolution Pallas kernels (ops/short_conv.py); they run
 # under GDN_CONV, SSM_CONV and MAMBA_CONV.
 SHORT_CONV_FWD = "short_conv_fwd"

@@ -1,6 +1,7 @@
 """The three flash kernels, the two gated-delta-rule kernels, the two
 Mamba-2 scan kernels, the two selective-scan kernels, the two kernels of
-the Mamba-1 gate and the two short-convolution kernels compiled at
+the Mamba-1 gate, the two of latent attention's assembly and the two
+short-convolution kernels compiled at
 the benchmark's shapes for a v5e that is described and not attached (rehearsal 3 of the
 on-chip-measurement guide; the recipe of
 ``perfbench/tests/test_chip_compile.py``).
@@ -225,6 +226,48 @@ def test_mamba_gate_kernels_compile_for_the_v5e(one_chip):
     text = jax.jit(fwd_and_grads).lower(y, z, z).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     for name in (scopes.MAMBA_GATE_FWD, scopes.MAMBA_GATE_BWD):
+        assert len(re.findall(rf"%{name}[.\d]* = ", text)) == 1, name
+
+
+# glm47flash_t8192's assembly: 20 heads of 256 with 64 rotary over 8192
+# tokens in tiles of 128, and the least widths the kernels take; a head of
+# ``up`` is 448 lanes wide, so the slices at odd heads' lane offsets have to
+# be ones Mosaic takes.
+@pytest.mark.parametrize("t,heads,hd,rope,tile", [(8192, 20, 256, 64, 128),
+                                                  (2048, 4, 128, 64, 128)],
+                         ids=["glm47flash", "least"])
+def test_mla_assemble_kernels_compile_for_the_v5e(one_chip, t, heads, hd,
+                                                  rope, tile):
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import mla_assemble as op
+    from horovod_tpu.telemetry import scopes
+
+    assert op.tiles(t, heads, hd, rope) == tile
+    assert op.vmem_bytes(tile, heads, hd, rope) <= op.VMEM_LIMIT
+    assert op.takes(jnp.zeros((1, t, 8), jnp.bfloat16), heads, hd, rope)
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    table = shape(t, rope // 2, dtype=jnp.float32)
+    folded = shape(heads, t, hd)
+
+    # The calls themselves, told to compile: the public function reads
+    # the executing mesh, and this process's is the CPU.
+    def fwd_and_grads(q_proj, up, k_r, cos, sin, dq, dk, dv):
+        return (op._fwd_call(q_proj, up, k_r, cos, sin, heads=heads,
+                             tile=tile, interpret=False),
+                op._bwd_call(dq, dk, dv, cos, sin, heads=heads, tile=tile,
+                             interpret=False))
+
+    text = jax.jit(fwd_and_grads).lower(
+        shape(1, t, heads * hd), shape(1, t, heads * (2 * hd - rope)),
+        shape(1, t, rope), table, table, folded, folded,
+        folded).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for name in (scopes.MLA_ASSEMBLE_FWD, scopes.MLA_ASSEMBLE_BWD):
         assert len(re.findall(rf"%{name}[.\d]* = ", text)) == 1, name
 
 

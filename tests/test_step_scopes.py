@@ -356,20 +356,34 @@ GLM = dict(positions="rope", tie_embeddings=False, head_width=64,
            mtp_loss_coef=0.1)
 
 
-@pytest.mark.parametrize("attention,remat",
-                         [("local", "none"), ("flash", "full")])
+# Heads of 128 with 64 rotary, the least the assembly's kernels take: on
+# the flash route the heads are put together by ``ops/mla_assemble.py``.
+GLM_WIDE = dict(GLM, head_width=128, rope_dim=64)
+
+
+@pytest.mark.parametrize("attention,remat,fields",
+                         [("local", "none", GLM), ("flash", "full", GLM),
+                          ("flash", "dots", GLM_WIDE)],
+                         ids=("local-none", "flash-full", "flash-dots-wide"))
 def test_glm_step_carries_the_vocabulary_and_every_part(hvd, attention,
-                                                        remat):
+                                                        remat, fields):
     """Latent attention's three parts open under ``attn/qkv``, a leading
     dense layer's MLP under ``mlp/mlp_dense``, the sigmoid-routed layer's
     five parts under ``mlp``, the module under ``mtp``; every executed op
     has a phase and a scope, and ``perfbench/mla_reduce.py`` reads each
-    part by name."""
+    part by name.  With the heads assembled by the kernels (``GLM_WIDE``)
+    all three parts still hold ops, and the kernels are ``mla_rope``'s."""
     from perfbench import mla_reduce, moe_reduce
 
-    text = _lm_step_text(attention, remat, False, **GLM)
-    _check_lm(text, attention, remat, False)
+    text = _lm_step_text(attention, remat, False, **fields)
+    # (``dots`` recomputes what is no matmul's output.)
+    _check_lm(text, attention, remat, False,
+              recurrence_recomputes=remat == "dots")
     names = _op_names(text)
+    if fields is GLM_WIDE:
+        inside = f"{scopes.ATTN_QKV}/{scopes.MLA_ROPE}"
+        assert _under(names, scopes.MLA_ASSEMBLE_FWD, inside, "jvp(")
+        assert _under(names, scopes.MLA_ASSEMBLE_BWD, inside, "transpose(")
     for part in MLA_PARTS:
         inside = f"{scopes.ATTN_QKV}/{part}"
         for mark in ("jvp(", "transpose("):
@@ -533,6 +547,8 @@ def test_the_benchmark_reads_the_same_vocabulary():
     mamba1_parts = set(MAMBA1_PARTS) | {scopes.ATTN_MAMBA_SCAN}
     mamba1_kernels = {scopes.MAMBA_SCAN_FWD, scopes.MAMBA_SCAN_BWD,
                       scopes.MAMBA_GATE_FWD, scopes.MAMBA_GATE_BWD}
+    # Latent attention's assembly: two kernels, booked by ``mla_rope``.
+    mla_kernels = {scopes.MLA_ASSEMBLE_FWD, scopes.MLA_ASSEMBLE_BWD}
     from perfbench import dsa_reduce
     assert dsa_parts == set(dsa_reduce.DSA_PARTS)
     assert set(scope_reduce.KERNEL_NAMES) == kernels
@@ -541,7 +557,8 @@ def test_the_benchmark_reads_the_same_vocabulary():
             == program - kernels - modules - {scopes.LAYER} - moe_parts
             - moe_kernels - gdn_parts - gdn_kernels - ssm_parts
             - ssm_kernels - mla_parts - conv_kernels - norm_kernels
-            - dsa_parts - dsa_kernels - mamba1_parts - mamba1_kernels)
+            - dsa_parts - dsa_kernels - mamba1_parts - mamba1_kernels
+            - mla_kernels)
     from perfbench import mamba1_reduce
     assert ({p.rsplit("/", 1)[-1] for p in mamba1_parts}
             == set(mamba1_reduce.PARTS))
@@ -564,6 +581,15 @@ def test_the_benchmark_reads_the_same_vocabulary():
     from perfbench import mla_reduce
     assert (mla_parts | moe_parts | {scopes.MOE_SHARED}
             == set(mla_reduce.PARTS))
+    # The assembly's kernels are booked where the rotation was.
+    for phase, name, kernel in (
+            ("fwd", "jvp(%s)", scopes.MLA_ASSEMBLE_FWD),
+            ("bwd", "transpose(jvp(%s))", scopes.MLA_ASSEMBLE_BWD)):
+        call = (f"jit(x)/{name % (scopes.LAYER % 3)}/{scopes.ATTN_QKV}/"
+                f"{scopes.MLA_ROPE}/{kernel}/pallas_call")
+        assert mla_reduce.parts_of(call) == [scopes.MLA_ROPE]
+        assert scope_reduce.phase_of(call) == phase
+        assert scope_reduce.scope_of(call) == scopes.ATTN_QKV
     from perfbench import gdn_reduce
     assert ({p.rsplit("/", 1)[-1] for p in gdn_parts}
             == set(gdn_reduce.PARTS))
