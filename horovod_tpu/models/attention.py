@@ -230,6 +230,9 @@ def _routed(q, k, v, dh, x, layer, cfg, ctx):
     its own ``attn/<route>``), the out projection and the residual."""
     seq_axis, attention, segment_ids = (ctx.seq_axis, ctx.attention,
                                         ctx.segment_ids)
+    # ``True``, or the step's own mask (block diffusion's: no sequence
+    # axis and no packing with it, transformer._refuse_beyond_the_data_axis).
+    mask = True if ctx.mask is None else ctx.mask
     b, t = q.shape[:2]
     flash = _flash_route(ctx, t)
     with jax.named_scope(scopes.ATTN_FLASH if flash else scopes.ATTN_QKV):
@@ -263,9 +266,9 @@ def _routed(q, k, v, dh, x, layer, cfg, ctx):
         # 'ring_flash' without a seq axis degenerates to exactly
         # this kernel (a 1-ring's only step is the diagonal one) —
         # the user still measures the algorithm they selected.
-        o = flash_attention(q, k, v, True, segment_ids=segment_ids)
+        o = flash_attention(q, k, v, mask, segment_ids=segment_ids)
     else:
-        o = seq_mod.local_attention(q, k, v, causal=True,
+        o = seq_mod.local_attention(q, k, v, causal=mask,
                                     segment_ids=segment_ids)
     with jax.named_scope(scopes.ATTN_OUT):
         return attn_out(o.reshape(b, t, dh), x, layer, cfg.dtype,

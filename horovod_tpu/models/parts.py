@@ -40,6 +40,7 @@ class Ctx(NamedTuple):
     positions: Any           # [T] int32 of this shard's tokens; None: learned
     tokens: int              # tokens of this shard's batch, B * T
     segment_ids: Any = None  # [B, T] int32 (packing), a mixer's alone
+    mask: Any = None         # softmax attention's, where it is not causal
 
 
 @dataclasses.dataclass(frozen=True, eq=False)

@@ -44,6 +44,7 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.models import (attention as attention_mod, linear_attention,
                                 mamba1, mamba2, mlp as mlp_mod, moe, parts)
+from horovod_tpu.ops.flash_attention import BlockDiffusion
 from horovod_tpu.parallel import sequence as seq_mod
 from horovod_tpu.telemetry import scopes
 
@@ -79,7 +80,7 @@ PARTS = {part.name: part for part in (
 BLOCK_FIELDS = ("vocab_size", "d_model", "n_heads", "n_layers", "max_seq",
                 "dtype", "positions", "rope_theta", "norm_eps",
                 "tie_embeddings", "layer_types", "mtp_layer_types",
-                "mtp_loss_coef")
+                "mtp_loss_coef", "diffusion_block", "mask_token_id")
 
 
 def layer_parts(cfg, i: int, mtp: bool = False):
@@ -246,6 +247,18 @@ class TransformerConfig:
     index_head_dim: int = 0
     index_topk: int = 0
     indexer_loss_coef: float = 0.0
+    # Block-diffusion training (Arriola et al., arXiv:2503.09573), with
+    # ``diffusion_block`` > 0 the length of a block: the step takes clean
+    # tokens [B, L], which of them are noised and each block's rate
+    # (``make_train_step``, :func:`diffusion_noise`), runs the stack once
+    # over the clean sequence and its noised copy (``mask_token_id`` where
+    # noised; both halves at positions 0..L-1) under the mask of
+    # ``ops.flash_attention.BlockDiffusion``, and its loss is the mean
+    # over the L positions of the noised copy's cross-entropy **at** the
+    # noised positions (no shift), each over its block's rate
+    # (:func:`diffusion_loss_fn`).  Every mixer is plain softmax attention.
+    diffusion_block: int = 0
+    mask_token_id: int = -1
 
     def __post_init__(self):
         if self.positions not in ("learned", "rope", "none"):
@@ -271,6 +284,29 @@ class TransformerConfig:
         if self.positions == "rope" and self.head_dim % 2:
             raise ValueError(f"positions='rope' needs an even head_dim, "
                              f"got {self.head_dim}")
+        if self.diffusion_block < 0 or (
+                (self.mask_token_id >= 0) != (self.diffusion_block > 0)):
+            raise ValueError(
+                f"diffusion_block={self.diffusion_block} (a block's "
+                f"length, 0: off) and mask_token_id={self.mask_token_id} "
+                f"(-1: none) come together")
+        if self.diffusion_block:
+            if self.mask_token_id >= self.vocab_size:
+                raise ValueError(
+                    f"mask_token_id={self.mask_token_id} is no row of a "
+                    f"vocabulary of {self.vocab_size}")
+            mixers = {mixer.name if mixer else "no mixer"
+                      for mixer, _ in stack_parts(self)} - {"attention"}
+            refused = (sorted(mixers)
+                       + _off_default(self, ("mtp_layer_types",))
+                       + ["positions='learned'"]
+                       * (self.positions == "learned"))
+            if refused:
+                raise NotImplementedError(
+                    f"diffusion_block={self.diffusion_block} runs layers "
+                    f"of plain softmax attention under rotary or no "
+                    f"positions, without a prediction module: not "
+                    + ", ".join(refused))
 
     @property
     def head_dim(self) -> int:
@@ -325,6 +361,9 @@ def _refuse_beyond_the_data_axis(cfg: TransformerConfig, **arguments) -> None:
     asked = [(part.name, part.unsupported) for part in parts_in_use(cfg)]
     asked.append(("the multi-token-prediction module",
                   parts.everywhere("mtp_layer_types")))
+    # The mask's blocks are counted over one whole, unpacked sequence on
+    # one chip, and the out projection's sum over a model axis is untried.
+    asked.append(("block diffusion", parts.everywhere("diffusion_block")))
     for name, value in arguments.items():
         if value is None or value is False:
             continue
@@ -496,18 +535,27 @@ def forward_with_router_stats(params, tokens, cfg: TransformerConfig,
 
 
 def _hidden_states(params, tokens, cfg: TransformerConfig, model_axis,
-                   seq_axis, attention, segment_ids, remat):
+                   seq_axis, attention, segment_ids, remat, layout=None):
     """``(x, extras, run_layers)``: the last layer's output before the
     final norm; what the loss collects from the layers run so far, a list
     a name (``router_stats``: one :class:`moe.RouterStats` per
     softmax-routed MoE layer; ``index_kl``: every sparse attention
     layer's summed KL of its indexer); and the function that ran the
     stack (``run_layers(x, layers, chosen, label)``), for the
-    multi-token-prediction module to run its own layers by."""
+    multi-token-prediction module to run its own layers by.  ``layout``:
+    ``(positions [T], mask)`` where the tokens are not one causal sequence
+    (:func:`diffusion_loss_fn`)."""
     _refuse_beyond_the_data_axis(cfg, model_axis=model_axis,
                                  seq_axis=seq_axis, segment_ids=segment_ids)
+    if cfg.diffusion_block and layout is None:
+        raise NotImplementedError(
+            f"TransformerConfig.diffusion_block={cfg.diffusion_block}: the "
+            f"model reads a clean sequence beside its noised copy, which "
+            f"make_train_step and diffusion_loss_fn build; forward() runs "
+            f"one causal sequence")
     dt = cfg.dtype
     t_local = tokens.shape[1]
+    mask = None
     with jax.named_scope(scopes.EMBED):
         pos_offset = (lax.axis_index(seq_axis) * t_local) if seq_axis else 0
         if cfg.positions == "learned":
@@ -517,8 +565,11 @@ def _hidden_states(params, tokens, cfg: TransformerConfig, model_axis,
                                           t_local, axis=0)[None]).astype(dt)
         else:
             positions = pos_offset + jnp.arange(t_local)
+            if layout is not None:
+                positions, mask = layout
             x = params["embed"][tokens].astype(dt)
-    ctx = parts.Ctx(model_axis, seq_axis, attention, positions, tokens.size)
+    ctx = parts.Ctx(model_axis, seq_axis, attention, positions, tokens.size,
+                    mask=mask)
     extras = collections.defaultdict(list)
 
     @functools.cache
@@ -614,13 +665,77 @@ def loss_fn(params, tokens, labels, cfg: TransformerConfig,
         with jax.named_scope(scopes.LOSS):
             loss = loss + cfg.indexer_loss_coef * (
                 sum(index_kl) / tokens.size)
+    return _with_router_losses(loss, router_stats, tokens.size, cfg,
+                               batch_axes)
+
+
+def _with_router_losses(loss, router_stats, tokens: int, cfg, batch_axes):
+    """``loss`` plus the softmax routers' load-balancing and z losses over
+    all layers' ``tokens`` together, under their coefficients."""
     if router_stats:
         with jax.named_scope(scopes.LOSS):
             balance, z = moe.router_losses(
-                router_stats, tokens.size * len(router_stats), batch_axes)
+                router_stats, tokens * len(router_stats), batch_axes)
             loss = (loss + cfg.router_aux_coef * balance
                     + cfg.router_z_coef * z)
     return loss
+
+
+def diffusion_noise(key, batch: int, length: int, block: int,
+                    t_min: float = 1e-3):
+    """``(masked [batch, length] bool, rates [batch, length / block]
+    float32)`` for a step of a ``diffusion_block`` config, for a caller
+    with no input layer of its own: each block's rate ~ U[``t_min``, 1]
+    (the linear schedule, clipped below as the block-diffusion paper
+    clips it), each token noised with its block's rate, independently."""
+    k_rates, k_masked = jax.random.split(key)
+    rates = jax.random.uniform(k_rates, (batch, length // block),
+                               jnp.float32, t_min, 1.0)
+    masked = (jax.random.uniform(k_masked, (batch, length), jnp.float32)
+              < jnp.repeat(rates, block, axis=1))
+    return masked, rates
+
+
+def diffusion_loss_fn(params, tokens, masked, rates, cfg: TransformerConfig,
+                      attention="flash", remat="none", batch_axes=()):
+    """The block-diffusion loss over the LOCAL shard: with ``tokens`` [B,
+    L] the clean ids, ``masked`` [B, L] which of them the noised copy
+    hides and ``rates`` [B, L / diffusion_block] each block's rate ``t``,
+    ``(1 / (B L)) sum over masked i of (1 / t_block(i)) x (-log softmax(
+    head(h_i))[tokens_i])``: ``h_i`` the noised copy's last hidden state
+    at ``i`` itself, the weight the linear schedule's.  One pass over ``2
+    L`` positions, the clean sequence first and its noised copy after it
+    (how they are laid out is this function's own business: the mask and
+    the positions it hands the stack say it, and nothing outside reads
+    it), both halves at positions ``0..L-1``; the head runs on the noised
+    half alone, and the clean half's last hidden states feed no loss.
+    Plus the routers' losses over all ``2 L`` positions, as
+    :func:`loss_fn` adds them."""
+    length, block = tokens.shape[1], cfg.diffusion_block
+    if length % block or rates.shape != (tokens.shape[0], length // block):
+        raise ValueError(
+            f"rates {rates.shape} must hold one rate for each block of "
+            f"{block} of tokens {tokens.shape}")
+    with jax.named_scope(scopes.EMBED), jax.named_scope(
+            scopes.DIFFUSION_ASSEMBLE):
+        noised = jnp.where(masked, cfg.mask_token_id, tokens)
+        stream = jnp.concatenate([tokens, noised], axis=1)
+        positions = jnp.tile(jnp.arange(length), 2)
+        weights = jnp.where(masked, 1.0 / jnp.repeat(rates, block, axis=1),
+                            0.0)
+    x, extras, _ = _hidden_states(
+        params, stream, cfg, None, None, attention, None, remat,
+        layout=(positions, BlockDiffusion(length, block)))
+    with jax.named_scope(scopes.HEAD):
+        # (Its gradient's padding is the head's too.)
+        noised_half = x[:, length:]
+    logits = _logits_head(noised_half, params, cfg)
+    with jax.named_scope(scopes.LOSS):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ll = jnp.take_along_axis(logp, tokens[..., None], axis=-1)[..., 0]
+        loss = -jnp.sum(weights * ll) / tokens.size
+    return _with_router_losses(loss, extras["router_stats"], stream.size,
+                               cfg, batch_axes)
 
 
 def make_train_step(cfg: TransformerConfig, optimizer, mesh,
@@ -641,7 +756,10 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
     params with ``jax.device_put``).  ``packed=True`` adds a trailing
     ``segment_ids`` argument ([B, T] int32, sharded like tokens) so
     sequence packing reaches the jitted step on every attention route,
-    including the sequence-parallel ones (see :func:`forward`).
+    including the sequence-parallel ones (see :func:`forward`).  With
+    ``cfg.diffusion_block`` the step is ``step(params, opt_state, tokens,
+    masked, rates)`` and its loss :func:`diffusion_loss_fn`'s
+    (:func:`diffusion_noise` draws the last two).
 
     ``remat`` selects the per-layer rematerialization policy (see
     :func:`_remat_wrap`); ``steps_per_call > 1`` runs that many steps
@@ -691,7 +809,8 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
             f"wire; pass shard_optimizer=True (the plain path's fused "
             f"pmean has no per-bucket wire to compress)")
 
-    def _one_step(params, opt_state, tokens, labels, segment_ids=None):
+    def _update(params, opt_state, loss_of):
+        """One step on the batch whose loss is ``loss_of(params)``."""
         from horovod_tpu import resilience
         from horovod_tpu.parallel._vma import pin_to
         # Differentiate with respect to a copy of the params typed as
@@ -705,9 +824,7 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
         # sum IS the Megatron "f" operator (parallel/tensor.py).
         local_params = jax.tree_util.tree_map(pin_to(set(grad_axes)),
                                               params)
-        loss, grads = jax.value_and_grad(loss_fn)(
-            local_params, tokens, labels, cfg, model_axis, seq_axis,
-            attention, segment_ids, remat, grad_axes)
+        loss, grads = jax.value_and_grad(loss_of)(local_params)
 
         def do_update():
             if zopt is not None:
@@ -736,11 +853,24 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
                              if a))
         return new_params, new_opt, mean_loss
 
+    # The step's arguments keep their names: a trace's buffer assignment
+    # is read by them (perfbench/memory_reduce.py).
+    if cfg.diffusion_block:
+        def _one_step(params, opt_state, tokens, masked, rates):
+            return _update(params, opt_state, lambda p: diffusion_loss_fn(
+                p, tokens, masked, rates, cfg, attention, remat, grad_axes))
+    else:
+        def _one_step(params, opt_state, tokens, labels, segment_ids=None):
+            return _update(params, opt_state, lambda p: loss_fn(
+                p, tokens, labels, cfg, model_axis, seq_axis, attention,
+                segment_ids, remat, grad_axes))
+
     if steps_per_call > 1:
-        def _step(params, opt_state, tokens, labels, segment_ids=None):
+        @functools.wraps(_one_step)
+        def _step(params, opt_state, *batch):
             def body(carry, _):
                 p, o = carry
-                p, o, loss = _one_step(p, o, tokens, labels, segment_ids)
+                p, o, loss = _one_step(p, o, *batch)
                 return (p, o), loss
             (params, opt_state), losses = lax.scan(
                 body, (params, opt_state), None, length=steps_per_call)
@@ -765,9 +895,8 @@ def make_train_step(cfg: TransformerConfig, optimizer, mesh,
             transform_non_params=lambda _leaf: P())
 
     data_spec = P(data_axis, seq_axis) if seq_axis else P(data_axis)
-    in_specs = (specs, opt_specs, data_spec, data_spec)
-    if packed:
-        in_specs = in_specs + (data_spec,)
+    in_specs = (specs, opt_specs) + (data_spec,) * (
+        3 if packed or cfg.diffusion_block else 2)
     step = jax.shard_map(
         _step, mesh=mesh,
         in_specs=in_specs,

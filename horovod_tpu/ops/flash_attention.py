@@ -16,12 +16,13 @@ Layout: ``[B, T, H, D]`` (the repo convention) is folded to
 the innermost grid dimension streams one K/V tile at a time through
 VMEM (Mosaic double-buffers the fetches), while fp32 accumulators and
 the online-softmax m/l state persist across the inner dimension in VMEM
-scratch.  Under a causal mask each kernel specialises a block by where
-it lies relative to the diagonal, which the grid indices say: blocks
-above it are neither computed nor fetched, blocks wholly under it run
-without any mask arithmetic, and only the blocks the diagonal crosses
-are masked — as 2x2 sub-tiles without the upper-right one where the
-blocks are square and at least 256 (``_by_class``).  The backward pass is
+scratch.  Under a mask each kernel specialises a block by what the mask's
+static description says of it from the grid indices (``_by_class``; the
+causal mask, none, block diffusion's): under the causal mask blocks
+above the diagonal are neither computed nor fetched, blocks wholly under
+it run without any mask arithmetic, and only the blocks the diagonal
+crosses are masked — as 2x2 sub-tiles without the upper-right one where
+the blocks are square and at least 256.  The backward pass is
 the standard flash recomputation: a per key-block kernel for dK/dV
 streaming query tiles, and a per query-block kernel for dQ streaming key
 tiles, using the saved row max/denominator.
@@ -33,6 +34,7 @@ is how the CI oracle tests run without a TPU.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 
@@ -86,36 +88,39 @@ def _out_vma(*arrays):
 
 
 # ---------------------------------------------------------------------------
-# Blocks by where they lie relative to the causal diagonal
+# The mask, as one static description
 #
-# The grid indices say which of three classes a (query block, key block)
-# pair belongs to, and each class pays only for what its position needs:
-#   above     every key after every query: not computed, not fetched (the
-#             index maps clamp to the last live block, so Mosaic elides
-#             the DMA);
-#   interior  every key at or before every query: no causal iota, compare
-#             or select;
-#   diagonal  the diagonal crosses the block: masked.  Square blocks of at
-#             least 256 are worked as 2x2 sub-tiles and the upper-right
-#             one, wholly above the diagonal, is not computed.
-# Without ``causal`` every block is interior.  Segment ids add their
-# compare to every class: a document boundary can fall anywhere.
+# A mask says, from the grid indices alone, which of three classes a (query
+# block, key block) pair belongs to, and each class pays only for what its
+# position needs:
+#   skipped   no query of the block reads any key of it: not computed, not
+#             fetched (the index maps point a skipped step at a live block
+#             the kernel fetches anyway, so Mosaic elides the DMA);
+#   interior  every query reads every key: no iota, compare or select;
+#   masked    only some do (called ``diagonal`` in the series, since PR 25):
+#             the rectangles ``tiles`` names are computed under the rule
+#             each one carries.
+# ``flash_attention(..., causal=...)`` takes ``True`` (:data:`CAUSAL`),
+# ``False`` (:data:`FULL`) or a description such as :class:`BlockDiffusion`;
+# the kernels, the index maps and the series ask the description and branch
+# on nothing else.  Segment ids add their compare to every class: a document
+# boundary can fall anywhere.
 # ---------------------------------------------------------------------------
 
 def _splits_diagonal(block_q: int, block_k: int) -> bool:
     return block_q == block_k and block_q >= 256
 
 
-def _diagonal_tiles(block_q: int, block_k: int):
-    """``(row0, rows, col0, cols, masked)`` rectangles a diagonal block
-    computes, in the order the online softmax takes them."""
+def _diagonal_tiles(block_q: int, block_k: int, rule=True):
+    """``(row0, rows, col0, cols, rule)`` rectangles a block on a lower
+    diagonal computes, in the order the online softmax takes them."""
     if not _splits_diagonal(block_q, block_k):
-        return ((0, block_q, 0, block_k, True),)
+        return ((0, block_q, 0, block_k, rule),)
     # Upper rows against the left keys, lower rows against all of them:
     # each row is taken once, so the per-row work (running max and sum,
     # the accumulator's rescaling) is that of a whole block.
     h = block_q // 2
-    return ((0, h, 0, h, True), (h, h, 0, block_k, True))
+    return ((0, h, 0, h, rule), (h, h, 0, block_k, rule))
 
 
 def _block_class(qi, kj, block_q, block_k):
@@ -128,59 +133,368 @@ def _block_class(qi, kj, block_q, block_k):
     return interior, live ^ interior          # interior implies live
 
 
-def _by_class(causal, qi, kj, block_q, block_k, tile_body):
-    """Run ``tile_body(row0, rows, col0, cols, masked)`` over what block
-    (qi, kj) needs: nothing above the diagonal, the whole block unmasked
-    under it, the masked tiles on it."""
-    if not causal:
-        tile_body(0, block_q, 0, block_k, False)
-        return
-    interior, diagonal = _block_class(qi, kj, block_q, block_k)
-
-    @pl.when(interior)
-    def _interior():
-        tile_body(0, block_q, 0, block_k, False)
-
-    @pl.when(diagonal)
-    def _diagonal():
-        for tile in _diagonal_tiles(block_q, block_k):
-            tile_body(*tile)
+def _whole(block_q, block_k):
+    return ((0, block_q, 0, block_k, False),)
 
 
-def block_classes(t: int, block_q: int, block_k: int, causal: bool) -> dict:
+class Full:
+    """No mask: every block interior."""
+
+    def check(self, t, block_q, block_k):
+        pass
+
+    def tiled(self, t: int) -> int:
+        """The length the kernels' blocks have to divide."""
+        return t
+
+    def cases(self, qi, kj, block_q, block_k):
+        """``(condition, tiles)`` pairs, at most one of which holds at a
+        grid step: ``tiles`` (``(row0, rows, col0, cols, rule)`` each,
+        ``rule`` false for an unmasked rectangle) are what block (qi, kj)
+        computes where ``condition`` does; where none does it is skipped.
+        ``True`` stands for a condition that holds everywhere.  Works on
+        grid indices in the kernels and on plain ints in
+        :func:`block_classes`."""
+        return ((True, _whole(block_q, block_k)),)
+
+    def shown(self, rule, qi, kj, tile, block_q, block_k, shape, q_axis):
+        """Bool ``shape``, queries along ``q_axis`` and keys along the
+        other: what rectangle ``tile`` of block (qi, kj) shows under
+        ``rule``."""
+        raise AssertionError("an unmasked block has no rule")
+
+    def may_hide_a_row(self, rule) -> bool:
+        """Whether a rectangle under ``rule`` can hide every key of a row
+        that has met no key yet (the forward kernel's online softmax then
+        guards its exponentials)."""
+        return False
+
+    def kv_map(self, block_q, block_k):
+        """Index map of a K/V block on the (head, query block, key block)
+        grid: a skipped step names a live block, the one last fetched or
+        the next to be."""
+        return lambda bh_, i, j: (bh_, j, 0)
+
+    def q_map(self, block_q, block_k):
+        """The same for a query-side block on the dK+dV kernel's (head,
+        key block, query block) grid."""
+        return lambda bh_, j, i: (bh_, i, 0)
+
+    def needed(self, t: int) -> int:
+        """Score elements a head needs."""
+        return t * t
+
+    def visible(self, q_pos, k_pos):
+        """The mask itself, dense: whether the query at ``q_pos`` reads the
+        key at ``k_pos`` (broadcast), for the ``jax.numpy`` routes and
+        the oracles."""
+        return jnp.ones(jnp.broadcast_shapes(jnp.shape(q_pos),
+                                             jnp.shape(k_pos)), bool)
+
+    def __repr__(self):
+        return f"{type(self).__name__}()"
+
+
+class Causal(Full):
+    """Key ``j`` for query ``i`` iff ``j <= i``: one diagonal, the live keys
+    of a query block a prefix and the live queries of a key block a
+    suffix."""
+
+    def cases(self, qi, kj, block_q, block_k):
+        interior, diagonal = _block_class(qi, kj, block_q, block_k)
+        return ((interior, _whole(block_q, block_k)),
+                (diagonal, _diagonal_tiles(block_q, block_k)))
+
+    def shown(self, rule, qi, kj, tile, block_q, block_k, shape, q_axis):
+        # Visible where query position >= key position.  Query index minus
+        # key index is a constant of the tile shape; only the offset
+        # between the tile's first key and first query depends on the grid
+        # step, and not even that where a split block's tiles sit on the
+        # diagonal block qi == kj (a constant mask there is worth 0.7% of
+        # the kernels' time at T=8192: measured, PERF.md PR 25).
+        r0, _, c0, _, _ = tile
+        off = (c0 - r0 if _splits_diagonal(block_q, block_k)
+               else (kj * block_k + c0) - (qi * block_q + r0))
+        ahead = (lax.broadcasted_iota(jnp.int32, shape, q_axis)
+                 - lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis))
+        return ahead >= off
+
+    def kv_map(self, block_q, block_k):
+        # Last key block with any unmasked entry for query block i.
+        return lambda bh_, i, j: (
+            bh_, jnp.minimum(j, ((i + 1) * block_q - 1) // block_k), 0)
+
+    def q_map(self, block_q, block_k):
+        # First query block that sees key block j.
+        return lambda bh_, j, i: (
+            bh_, jnp.maximum(i, (j * block_k) // block_q), 0)
+
+    def needed(self, t: int) -> int:
+        return t * (t + 1) // 2
+
+    def visible(self, q_pos, k_pos):
+        return k_pos <= q_pos
+
+
+@dataclasses.dataclass(frozen=True, repr=False)
+class BlockDiffusion(Full):
+    """Block-diffusion training's mask (Arriola et al., arXiv:2503.09573)
+    over ``2 * length`` positions: the clean sequence first, its noised
+    copy after it, both in blocks of ``block`` tokens.  With ``beta`` the
+    block of a position within its half, query ``i`` reads key ``j`` iff
+
+    ======  ======  =============================================
+    i       j
+    ======  ======  =============================================
+    clean   clean   ``beta(j) <= beta(i)``   (block-causal)
+    clean   noised  never
+    noised  clean   ``beta(j) < beta(i)``    (finished blocks only)
+    noised  noised  ``beta(j) == beta(i)``   (its own block, both ways)
+    ======  ======  =============================================
+
+    ``length * (length + block)`` of the ``4 * length ** 2`` score
+    elements.  By quadrant of blocks: clean x clean is the causal case
+    rounded up to ``block``; clean x noised is skipped; noised x clean is
+    the causal case without its block diagonal (rule ``"lt"``); noised x
+    noised is one block-diagonal band (rule ``"eq"``).  A noised query
+    block's live keys are a prefix of the clean half plus an isolated run
+    of the noised half, and a clean key block's live queries are two
+    suffixes, one a half: the index maps jump the gaps.  Kernel blocks are
+    multiples of ``block`` and divide ``length``, so none straddles the
+    halves (docs/kernels.md has the picture)."""
+
+    length: int
+    block: int
+
+    def __post_init__(self):
+        if self.block < 1 or self.length % self.block:
+            raise ValueError(f"BlockDiffusion(length={self.length}, "
+                             f"block={self.block}): blocks of `block` "
+                             f"tokens must tile `length`")
+
+    def check(self, t, block_q, block_k):
+        if t != 2 * self.length:
+            raise ValueError(
+                f"{self!r} masks 2 * length = {2 * self.length} positions "
+                f"(the clean sequence, then its noised copy), got {t}")
+        for name, size in (("block_q", block_q), ("block_k", block_k)):
+            if size % self.block or self.length % size:
+                raise ValueError(
+                    f"{name}={size} must be a multiple of {self!r}'s block "
+                    f"and divide its length, so that no kernel block "
+                    f"straddles a diffusion block or the two halves")
+
+    def tiled(self, t: int) -> int:
+        return self.length
+
+    def _splits(self, block_q, block_k):
+        return (_splits_diagonal(block_q, block_k)
+                and (block_q // 2) % self.block == 0)
+
+    def _corners(self, qi, kj, block_q, block_k):
+        """Which half each side lies in, and the block's first and last
+        row and column counted within its half."""
+        n_q, n_k = self.length // block_q, self.length // block_k
+        q_noised, k_noised = qi >= n_q, kj >= n_k
+        q0 = qi * block_q - self.length * q_noised
+        k0 = kj * block_k - self.length * k_noised
+        return (qi < n_q, q_noised, kj < n_k, k_noised,
+                q0, q0 + block_q, k0, k0 + block_k)
+
+    def cases(self, qi, kj, block_q, block_k):
+        b = self.block
+        (q_clean, q_noised, k_clean, k_noised,
+         q0, q1, k0, k1) = self._corners(qi, kj, block_q, block_k)
+        both_clean = q_clean & k_clean
+        finished = q_noised & k_clean
+        own = q_noised & k_noised
+        # Everything is a multiple of ``block``, so ``beta`` compares as
+        # the positions do.
+        le_interior = both_clean & (k1 <= q0 + b)
+        le = both_clean & (k0 < q1) & (k1 > q0 + b)
+        lt_interior = finished & (k1 <= q0)
+        lt = finished & (k0 < q1 - b) & (k1 > q0)
+        overlap = own & (k0 < q1) & (q0 < k1)
+        one_block = block_q == b and block_k == b
+        split, h = self._splits(block_q, block_k), block_q // 2
+
+        def tiles(rule):
+            return (_diagonal_tiles(block_q, block_k, rule) if split
+                    else ((0, block_q, 0, block_k, rule),))
+
+        if one_block:
+            return ((le_interior | lt_interior | overlap,
+                     _whole(block_q, block_k)),)
+        return ((le_interior | lt_interior, _whole(block_q, block_k)),
+                (le, tiles("le")), (lt, tiles("lt")),
+                # Of a 2x2 cut, the two sub-tiles on the band.
+                (overlap, ((0, h, 0, h, "eq"), (h, h, h, h, "eq")) if split
+                 else tiles("eq")))
+
+    def shown(self, rule, qi, kj, tile, block_q, block_k, shape, q_axis):
+        r0, _, c0, _, _ = tile
+        if block_q == block_k:
+            # A masked block of a square blocking starts at the same
+            # place in its half on both sides, a multiple of ``block``:
+            # the rule reads the offsets inside the block alone.
+            q_first, k_first = r0, c0
+        else:
+            _, _, _, _, q0, _, k0, _ = self._corners(qi, kj, block_q,
+                                                     block_k)
+            q_first, k_first = q0 + r0, k0 + c0
+
+        def beta(first, axis):
+            # One row or one column of block numbers: the compare below
+            # is the only work a score element pays.
+            along = [1, 1]
+            along[axis] = shape[axis]
+            pos = first + lax.broadcasted_iota(jnp.int32, along, axis)
+            log2 = self.block.bit_length() - 1
+            return (lax.shift_right_logical(pos, log2)
+                    if self.block == 1 << log2 else pos // self.block)
+
+        q_beta, k_beta = beta(q_first, q_axis), beta(k_first, 1 - q_axis)
+        return {"le": k_beta <= q_beta, "lt": k_beta < q_beta,
+                "eq": k_beta == q_beta}[rule]
+
+    def may_hide_a_row(self, rule) -> bool:
+        # A query of the first block of the noised half reads no clean
+        # key, and the first block it meets is one of these.
+        return rule == "lt"
+
+    def kv_map(self, block_q, block_k):
+        b, n_q, n_k = (self.block, self.length // block_q,
+                       self.length // block_k)
+
+        def kv_map(bh_, i, j):
+            # Clean queries: a prefix of the clean keys.  Noised queries:
+            # a prefix of the clean keys (none for the first block), then
+            # the noised blocks their own rows overlap.
+            i_n = i - n_q
+            last_clean = jnp.where(
+                i < n_q, ((i + 1) * block_q - 1) // block_k,
+                ((i_n + 1) * block_q - b - 1) // block_k)
+            own_first = n_k + (i_n * block_q) // block_k
+            own_last = n_k + ((i_n + 1) * block_q - 1) // block_k
+            return bh_, jnp.where(
+                j <= last_clean, j,
+                jnp.where(i < n_q, last_clean,
+                          jnp.clip(j, own_first, own_last))), 0
+        return kv_map
+
+    def q_map(self, block_q, block_k):
+        b, n_q, n_k = (self.block, self.length // block_q,
+                       self.length // block_k)
+
+        def q_map(bh_, j, i):
+            # A clean key block: a suffix of the clean queries, then a
+            # suffix of the noised ones.  A noised key block: the noised
+            # queries its own rows overlap.
+            j_n = j - n_k
+            clean_first = (j * block_k) // block_q
+            noised_first = n_q + (j * block_k + b) // block_q
+            own_first = n_q + (j_n * block_k) // block_q
+            own_last = n_q + ((j_n + 1) * block_k - 1) // block_q
+            return bh_, jnp.where(
+                j < n_k,
+                jnp.where(i < n_q, jnp.maximum(i, clean_first),
+                          # (none where the last clean block is one
+                          # diffusion block: stay on the last clean query)
+                          jnp.where(noised_first < 2 * n_q,
+                                    jnp.maximum(i, noised_first), n_q - 1)),
+                jnp.clip(i, own_first, own_last)), 0
+        return q_map
+
+    def needed(self, t: int) -> int:
+        return self.length * (self.length + self.block)
+
+    def visible(self, q_pos, k_pos):
+        q_noised, k_noised = q_pos >= self.length, k_pos >= self.length
+        q_beta = (q_pos % self.length) // self.block
+        k_beta = (k_pos % self.length) // self.block
+        return jnp.where(
+            q_noised,
+            jnp.where(k_noised, k_beta == q_beta, k_beta < q_beta),
+            ~k_noised & (k_beta <= q_beta))
+
+    def __repr__(self):
+        return f"BlockDiffusion(length={self.length}, block={self.block})"
+
+
+FULL, CAUSAL = Full(), Causal()
+
+
+def as_mask(causal):
+    """The description ``causal`` spells: ``True`` and ``False`` are the
+    public names of :data:`CAUSAL` and :data:`FULL`."""
+    if isinstance(causal, Full):
+        return causal
+    return CAUSAL if causal else FULL
+
+
+def _checked_mask(causal, t, block_q, block_k, segment_ids):
+    """:func:`as_mask` of ``causal``, held to the call's sizes."""
+    mask = as_mask(causal)
+    if segment_ids is not None and isinstance(mask, BlockDiffusion):
+        raise NotImplementedError(
+            f"segment_ids is not implemented with {mask!r}: packed "
+            f"documents under the block-diffusion mask need its blocks "
+            f"counted from each document's start")
+    mask.check(t, block_q, block_k)
+    return mask
+
+
+def _by_class(mask, qi, kj, block_q, block_k, tile_body):
+    """Run ``tile_body(row0, rows, col0, cols, rule)`` over what block
+    (qi, kj) needs under ``mask``: nothing where it is skipped, the whole
+    block unmasked where it is interior, the masked tiles elsewhere."""
+    for condition, tiles in mask.cases(qi, kj, block_q, block_k):
+        def run(tiles=tiles):
+            for tile in tiles:
+                tile_body(*tile)
+        if condition is True:
+            run()
+        else:
+            pl.when(condition)(run)
+
+
+def block_classes(t: int, block_q: int, block_k: int, causal) -> dict:
     """Grid steps of one head by class, and the score elements they
     compute against the elements attention needs — what
     ``hvd_flash_blocks_total`` and ``hvd_flash_computed_over_needed``
     report (shapes are static, so this is counted when a call is traced)."""
-    num_q, num_k = t // block_q, t // block_k
-    if not causal:
-        return {"skipped": 0, "interior": num_q * num_k, "diagonal": 0,
-                "computed": t * t, "needed": t * t}
-    classes = [_block_class(qi, kj, block_q, block_k)
-               for qi in range(num_q) for kj in range(num_k)]
-    interior = sum(c[0] for c in classes)
-    diagonal = sum(c[1] for c in classes)
-    per_diagonal = sum(rows * cols for _, rows, _, cols, _
-                       in _diagonal_tiles(block_q, block_k))
-    return {"skipped": num_q * num_k - interior - diagonal,
-            "interior": interior, "diagonal": diagonal,
-            "computed": interior * block_q * block_k
-            + diagonal * per_diagonal,
-            "needed": t * (t + 1) // 2}
+    mask = as_mask(causal)
+    mask.check(t, block_q, block_k)
+    out = {"skipped": 0, "interior": 0, "diagonal": 0, "computed": 0,
+           "needed": mask.needed(t)}
+    for qi in range(t // block_q):
+        for kj in range(t // block_k):
+            held = [tiles for condition, tiles
+                    in mask.cases(qi, kj, block_q, block_k) if condition]
+            if not held:
+                out["skipped"] += 1
+                continue
+            (tiles,) = held
+            out["diagonal" if any(tile[4] for tile in tiles)
+                else "interior"] += 1
+            out["computed"] += sum(rows * cols
+                                   for _, rows, _, cols, _ in tiles)
+    return out
 
 
 def _record_blocks(kernel: str, bh: int, t: int, block_q: int,
-                   block_k: int, causal: bool) -> None:
+                   block_k: int, mask) -> None:
     """Trace-time counters of one ``pallas_call`` (like ``hvd_fusion_*``:
     they count what was compiled into the step, not per-step traffic)."""
     if not telemetry.enabled():
         return
-    classes = block_classes(t, block_q, block_k, causal)
+    classes = block_classes(t, block_q, block_k, mask)
     for name in ("skipped", "interior", "diagonal"):
         telemetry.counter(
             "hvd_flash_blocks_total",
             "Grid steps of the traced flash kernels by where the block "
-            "lies relative to the causal diagonal",
+            "lies under the mask: skipped, interior (no mask arithmetic) "
+            "or diagonal (masked)",
             kernel=kernel, **{"class": name}).inc(bh * classes[name])
     telemetry.gauge(
         "hvd_flash_computed_over_needed",
@@ -203,30 +517,21 @@ _NT = ((1,), (1,))      # a @ b.T
 _NN = ((1,), (0,))      # a @ b
 
 
-def _scores(q, k, scale, qi, kj, tile, block_q, block_k, qseg_ref,
+def _scores(q, k, scale, qi, kj, tile, block_q, block_k, mask, qseg_ref,
             kseg_ref, keys_by_rows: bool = False):
-    """Scores of one tile, masked as its class needs: ``[rows, cols]``
+    """Scores of one tile, masked as its rule says: ``[rows, cols]``
     (queries by keys), or its transpose ``[cols, rows]`` for the dK/dV
     kernel, whose matmuls then contract without transposing a score-sized
     operand.  Segment ids ride a [B, 1, T] layout like the m/l rows;
     tokens attend only within their own segment.  ``kseg_ref`` is the
     q-side ref for self-attention; ring attention passes the ROTATED
     K-side ids."""
-    r0, nr, c0, nc, masked = tile
+    r0, nr, c0, nc, rule = tile
     s = (_dot(k, q, _NT) if keys_by_rows else _dot(q, k, _NT)) * scale
-    q_axis, k_axis = (1, 0) if keys_by_rows else (0, 1)
-    if masked:
-        # Visible where query position >= key position.  Query index minus
-        # key index is a constant of the tile shape; only the offset
-        # between the tile's first key and first query depends on the grid
-        # step, and not even that where a split block's tiles sit on the
-        # diagonal block qi == kj (a constant mask there is worth 0.7% of
-        # the kernels' time at T=8192: measured, PERF.md PR 25).
-        off = (c0 - r0 if _splits_diagonal(block_q, block_k)
-               else (kj * block_k + c0) - (qi * block_q + r0))
-        ahead = (lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
-                 - lax.broadcasted_iota(jnp.int32, s.shape, k_axis))
-        s = jnp.where(ahead >= off, s, NEG_INF)
+    if rule:
+        s = jnp.where(mask.shown(rule, qi, kj, tile, block_q, block_k,
+                                 s.shape, 1 if keys_by_rows else 0),
+                      s, NEG_INF)
     if qseg_ref is not None:
         qseg = qseg_ref[0, 0, pl.dslice(qi * block_q + r0, nr)]
         kseg = kseg_ref[0, 0, pl.dslice(kj * block_k + c0, nc)]
@@ -237,7 +542,7 @@ def _scores(q, k, scale, qi, kj, tile, block_q, block_k, qseg_ref,
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
-                block_q: int, block_k: int, num_k: int, causal: bool,
+                block_q: int, block_k: int, num_k: int, mask,
                 scale: float, segments: bool):
     if segments:
         (qseg_ref, kseg_ref, o_ref, m_ref, l_ref, acc_ref, m_scr,
@@ -258,23 +563,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def tile_body(*tile):
-        r0, nr, c0, nc, _ = tile
+        r0, nr, c0, nc, rule = tile
         q = q_ref[0, r0:r0 + nr, :]                      # [nr, D]
         k = k_ref[0, c0:c0 + nc, :]                      # [nc, D]
         v = v_ref[0, c0:c0 + nc, :]
         m = m_scr[r0:r0 + nr, :]                         # [nr, 1]
-        s = _scores(q, k, scale, qi, kj, tile, block_q, block_k, qseg_ref,
-                    kseg_ref)
+        s = _scores(q, k, scale, qi, kj, tile, block_q, block_k, mask,
+                    qseg_ref, kseg_ref)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        if segments:
-            # A row may have met no key of its segment yet (m_new = -inf).
+        if segments or (rule and mask.may_hide_a_row(rule)):
+            # A row may have met no key of its segment, or none the mask
+            # shows it, yet (m_new = -inf).
             safe_m = jnp.where(m_new == NEG_INF, 0.0, m_new)
             p = jnp.where(s == NEG_INF, 0.0, jnp.exp(s - safe_m))
             corr = jnp.where(m == NEG_INF, 0.0, jnp.exp(m - safe_m))
         else:
-            # Every row has seen key 0 after its first block, so m_new is
-            # finite and exp(-inf - m_new) is the zero a select would give
-            # (also for m = -inf on the first block).
+            # Every row has seen a key after its first block (key 0 under
+            # the causal mask), so m_new is finite and exp(-inf - m_new)
+            # is the zero a select would give (also for m = -inf on the
+            # first block).
             p = jnp.exp(s - m_new)
             corr = jnp.exp(m - m_new)
         m_scr[r0:r0 + nr, :] = m_new
@@ -283,7 +590,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
         acc_ref[r0:r0 + nr, :] = (acc_ref[r0:r0 + nr, :] * corr
                                   + _dot(p, v, _NN))
 
-    _by_class(causal, qi, kj, block_q, block_k, tile_body)
+    _by_class(mask, qi, kj, block_q, block_k, tile_body)
 
     @pl.when(kj == num_k - 1)
     def _finalize():
@@ -322,7 +629,7 @@ def _probs(s, lse, segments: bool):
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, m_ref, l_ref,
                    *rest, block_q: int, block_k: int,
-                   num_k: int, causal: bool, scale: float,
+                   num_k: int, mask, scale: float,
                    segments: bool):
     if segments:
         qseg_ref, kseg_ref, dq_ref, acc_ref, lse_ref, di_ref = rest
@@ -349,14 +656,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, m_ref, l_ref,
         do = do_ref[0, r0:r0 + nr, :]
         k = k_ref[0, c0:c0 + nc, :]                      # [nc, D]
         v = v_ref[0, c0:c0 + nc, :]
-        s = _scores(q, k, scale, qi, kj, tile, block_q, block_k, qseg_ref,
-                    kseg_ref)
+        s = _scores(q, k, scale, qi, kj, tile, block_q, block_k, mask,
+                    qseg_ref, kseg_ref)
         p = _probs(s, lse_ref[r0:r0 + nr, :], segments)
         dp = _dot(do, v, _NT)                            # [nr, nc]
         ds = p * (dp - di_ref[r0:r0 + nr, :])
         acc_ref[r0:r0 + nr, :] += _dot(ds, k, _NN)
 
-    _by_class(causal, qi, kj, block_q, block_k, tile_body)
+    _by_class(mask, qi, kj, block_q, block_k, tile_body)
 
     @pl.when(kj == num_k - 1)
     def _finalize():
@@ -365,7 +672,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, m_ref, l_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, m_ref, l_ref,
                     *rest, block_q: int, block_k: int, num_q: int,
-                    causal: bool, scale: float, segments: bool):
+                    mask, scale: float, segments: bool):
     if segments:
         (qseg_ref, kseg_ref, dk_ref, dv_ref, dk_acc_ref,
          dv_acc_ref) = rest
@@ -394,8 +701,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, m_ref, l_ref,
         di = jnp.sum(do.astype(jnp.float32)
                      * o_ref[0, r0:r0 + nr, :].astype(jnp.float32),
                      axis=-1)                            # [nr]
-        s = _scores(q, k, scale, qi, ki, tile, block_q, block_k, qseg_ref,
-                    kseg_ref, keys_by_rows=True)
+        s = _scores(q, k, scale, qi, ki, tile, block_q, block_k, mask,
+                    qseg_ref, kseg_ref, keys_by_rows=True)
         p = _probs(s, lse[None, :], segments)            # [nc, nr]
         dv_acc_ref[c0:c0 + nc, :] += _dot(p, do, _NN)    # [nc, D]
         dp = _dot(v, do, _NT)                            # [nc, nr]
@@ -403,7 +710,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, m_ref, l_ref,
         dk_acc_ref[c0:c0 + nc, :] += _dot(ds, q, _NN)
 
     # Query blocks strictly left of this key block see none of it.
-    _by_class(causal, qi, ki, block_q, block_k, tile_body)
+    _by_class(mask, qi, ki, block_q, block_k, tile_body)
 
     @pl.when(qi == num_q - 1)
     def _finalize():
@@ -414,18 +721,6 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, m_ref, l_ref,
 # ---------------------------------------------------------------------------
 # pallas_call plumbing
 # ---------------------------------------------------------------------------
-
-def _causal_kv_map(block_q, block_k):
-    # Last key block with any unmasked entry for query block i.
-    return lambda bh_, i, j: (
-        bh_, jnp.minimum(j, ((i + 1) * block_q - 1) // block_k), 0)
-
-
-def _causal_q_map(block_q, block_k):
-    # First query block that sees key block j.
-    return lambda bh_, j, i: (
-        bh_, jnp.maximum(i, (j * block_k) // block_q), 0)
-
 
 def _check_shapes(q, k, v, block_q, block_k):
     if q.shape != k.shape or q.shape != v.shape:
@@ -462,17 +757,16 @@ def _seg_spec(t, h):
 # step costs).
 
 @functools.cache
-def _fwd_call(bh, t, d, dtype, h, causal, scale, block_q, block_k,
+def _fwd_call(bh, t, d, dtype, h, mask, scale, block_q, block_k,
               interpret, segments, vma):
     num_k = t // block_k
     kernel = functools.partial(_fwd_kernel, block_q=block_q,
-                               block_k=block_k, num_k=num_k, causal=causal,
+                               block_k=block_k, num_k=num_k, mask=mask,
                                scale=scale, segments=segments)
-    # Causal: masked steps (above the diagonal) clamp the K/V block index
-    # to the last live block — same index as the preceding step, so Mosaic
+    # A skipped step names the K/V block of a live step beside it (under
+    # the causal mask the last live one, the preceding step's), so Mosaic
     # elides the DMA instead of fetching a tile whose work pl.when skips.
-    kv_map = (_causal_kv_map(block_q, block_k) if causal
-              else (lambda bh_, i, j: (bh_, j, 0)))
+    kv_map = mask.kv_map(block_q, block_k)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda bh_, i, j: (bh_, i, 0)),
         pl.BlockSpec((1, block_k, d), kv_map),
@@ -515,11 +809,12 @@ def _fwd_parts(qf, kf, vf, qsegf, ksegf, h, causal, scale, block_q,
     ``qsegf``/``ksegf`` are [B, 1, T] (pass the same array for
     self-attention)."""
     bh, t, d = qf.shape
+    mask = _checked_mask(causal, t, block_q, block_k, qsegf)
     operands = [qf, kf, vf]
     if qsegf is not None:
         operands += [qsegf, ksegf]
-    _record_blocks(scopes.FLASH_FWD, bh, t, block_q, block_k, causal)
-    return _fwd_call(bh, t, d, qf.dtype, h, causal, scale, block_q, block_k,
+    _record_blocks(scopes.FLASH_FWD, bh, t, block_q, block_k, mask)
+    return _fwd_call(bh, t, d, qf.dtype, h, mask, scale, block_q, block_k,
                      interpret, qsegf is not None,
                      _out_vma(*operands))(*operands)
 
@@ -542,14 +837,13 @@ def _fwd(q, k, v, seg, causal, scale, block_q, block_k, interpret):
 
 
 @functools.cache
-def _bwd_dq_call(bh, t, d, dtype, h, causal, scale, block_q, block_k,
+def _bwd_dq_call(bh, t, d, dtype, h, mask, scale, block_q, block_k,
                  interpret, segments, vma):
     num_k = t // block_k
     kernel = functools.partial(_bwd_dq_kernel, block_q=block_q,
-                               block_k=block_k, num_k=num_k, causal=causal,
+                               block_k=block_k, num_k=num_k, mask=mask,
                                scale=scale, segments=segments)
-    kv_map = (_causal_kv_map(block_q, block_k) if causal
-              else (lambda bh_, i, j: (bh_, j, 0)))
+    kv_map = mask.kv_map(block_q, block_k)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda bh_, i, j: (bh_, i, 0)),
         pl.BlockSpec((1, block_k, d), kv_map),
@@ -578,14 +872,13 @@ def _bwd_dq_call(bh, t, d, dtype, h, causal, scale, block_q, block_k,
 
 
 @functools.cache
-def _bwd_dkv_call(bh, t, d, dtype, h, causal, scale, block_q, block_k,
+def _bwd_dkv_call(bh, t, d, dtype, h, mask, scale, block_q, block_k,
                   interpret, segments, vma):
     num_q = t // block_q
     kernel = functools.partial(_bwd_dkv_kernel, block_q=block_q,
-                               block_k=block_k, num_q=num_q, causal=causal,
+                               block_k=block_k, num_q=num_q, mask=mask,
                                scale=scale, segments=segments)
-    q_map = (_causal_q_map(block_q, block_k) if causal
-             else (lambda bh_, j, i: (bh_, i, 0)))
+    q_map = mask.q_map(block_q, block_k)
     in_specs = [
         pl.BlockSpec((1, block_q, d), q_map),
         pl.BlockSpec((1, block_k, d), lambda bh_, j, i: (bh_, j, 0)),
@@ -623,14 +916,15 @@ def _bwd_parts(qf, kf, vf, of, dof, m, l, qsegf, ksegf, h, causal, scale,
     accumulated m/l — the per-block contributions are then the exact
     global-softmax gradients (p recomputed as exp(s − m)/l)."""
     bh, t, d = qf.shape
+    mask = _checked_mask(causal, t, block_q, block_k, qsegf)
     operands = [qf, kf, vf, of, dof, m, l]
     if qsegf is not None:
         operands += [qsegf, ksegf]
-    config = (bh, t, d, qf.dtype, h, causal, scale, block_q, block_k,
+    config = (bh, t, d, qf.dtype, h, mask, scale, block_q, block_k,
               interpret, qsegf is not None, _out_vma(*operands))
-    _record_blocks(scopes.FLASH_BWD_DQ, bh, t, block_q, block_k, causal)
+    _record_blocks(scopes.FLASH_BWD_DQ, bh, t, block_q, block_k, mask)
     dq = _bwd_dq_call(*config)(*operands)
-    _record_blocks(scopes.FLASH_BWD_DKV, bh, t, block_q, block_k, causal)
+    _record_blocks(scopes.FLASH_BWD_DKV, bh, t, block_q, block_k, mask)
     dk, dv = _bwd_dkv_call(*config)(*operands)
     return dq, dk, dv
 
@@ -649,7 +943,7 @@ def _bwd(causal, scale, block_q, block_k, interpret, res, do):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def flash_attention(q, k, v, causal: bool = True,
+def flash_attention(q, k, v, causal=True,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
@@ -673,8 +967,13 @@ def flash_attention(q, k, v, causal: bool = True,
     shapes of {256, 512, 1024}² compile inside the default scoped VMEM
     and 1024² is again the fastest, 1.13× over 512².
 
+    ``causal``: ``True``, ``False`` or a description of another mask
+    (:class:`BlockDiffusion`), static like the block sizes; the kernels
+    skip, run unmasked or mask each block as the description says.
+
     ``segment_ids`` ([B, T] int32) enables sequence packing: tokens
-    attend only within their own segment (composes with ``causal``) —
+    attend only within their own segment (composes with ``causal`` as a
+    bool) —
     the block-sparse masking XLA's fused attention cannot express, and
     the reason the kernel scaffold exists (docs/kernels.md).
     """
@@ -738,8 +1037,8 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
     d = q.shape[-1]
     scale_ = (d ** -0.5) if scale is None else scale
     interp = _interpret_default(q) if interpret is None else interpret
-    bq, bk = _eff_blocks(q.shape[1], block_q, block_k, d,
-                         segment_ids is not None)
+    bq, bk = _eff_blocks(as_mask(causal).tiled(q.shape[1]), block_q,
+                         block_k, d, segment_ids is not None)
     return _fwd(q, k, v, segment_ids, causal, scale_, bq, bk, interp)
 
 
@@ -748,7 +1047,8 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, do):
     t, d = res[0].shape[1], res[0].shape[-1]
     scale_ = (d ** -0.5) if scale is None else scale
     interp = _interpret_default(res[0]) if interpret is None else interpret
-    bq, bk = _eff_blocks(t, block_q, block_k, d, res[6] is not None)
+    bq, bk = _eff_blocks(as_mask(causal).tiled(t), block_q, block_k, d,
+                         res[6] is not None)
     return _bwd(causal, scale_, bq, bk, interp, res, do)
 
 
@@ -760,7 +1060,7 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def flash_attention_folded(q, k, v, heads: int, causal: bool = True,
+def flash_attention_folded(q, k, v, heads: int, causal=True,
                            scale: Optional[float] = None,
                            block_q: Optional[int] = None,
                            block_k: Optional[int] = None,
@@ -777,10 +1077,11 @@ def flash_attention_folded(q, k, v, heads: int, causal: bool = True,
     return out
 
 
-def _folded_config(q, scale, block_q, block_k, interpret, segments):
+def _folded_config(q, causal, scale, block_q, block_k, interpret, segments):
     _, t, d = q.shape
     return ((d ** -0.5) if scale is None else scale,
-            *_eff_blocks(t, block_q, block_k, d, segments),
+            *_eff_blocks(as_mask(causal).tiled(t), block_q, block_k, d,
+                         segments),
             _interpret_default(q) if interpret is None else interpret)
 
 
@@ -794,8 +1095,8 @@ def _folded_fwd(q, k, v, heads, causal, scale, block_q, block_k, interpret,
     if q.shape != k.shape or q.shape != v.shape or q.shape[0] % heads:
         raise ValueError(f"q/k/v [B * {heads}, T, D] must match, got "
                          f"{q.shape} {k.shape} {v.shape}")
-    scale_, bq, bk, interp = _folded_config(q, scale, block_q, block_k,
-                                            interpret,
+    scale_, bq, bk, interp = _folded_config(q, causal, scale, block_q,
+                                            block_k, interpret,
                                             segment_ids is not None)
     t = q.shape[1]
     if t % bq or t % bk:
@@ -811,8 +1112,9 @@ def _folded_fwd(q, k, v, heads, causal, scale, block_q, block_k, interpret,
 @jax.named_scope(scopes.ATTN_FLASH)
 def _folded_bwd(heads, causal, scale, block_q, block_k, interpret, res, do):
     q, k, v, o, m, l, seg = res
-    scale_, bq, bk, interp = _folded_config(q, scale, block_q, block_k,
-                                            interpret, seg is not None)
+    scale_, bq, bk, interp = _folded_config(q, causal, scale, block_q,
+                                            block_k, interpret,
+                                            seg is not None)
     segf = _folded_segments(seg, q.shape[1])
     dq, dk, dv = _bwd_parts(q, k, v, o, do, m, l, segf, segf, heads, causal,
                             scale_, bq, bk, interp)

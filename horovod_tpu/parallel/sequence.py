@@ -506,21 +506,28 @@ def ulysses_attention(q, k, v, axis_name: str = "seq", causal: bool = True,
 
 
 @jax.named_scope(scopes.ATTN_LOCAL)
-def local_attention(q, k, v, causal: bool = True,
+def local_attention(q, k, v, causal=True,
                     scale: Optional[float] = None, segment_ids=None):
     """Plain single-device attention (the no-SP reference path; also the
     numerical oracle the SP tests compare against).
 
-    ``segment_ids`` ([B, T] int32) enables sequence packing: tokens
-    attend only within their own segment (composes with ``causal``).
+    ``causal``: a bool, or a mask's description as the flash kernels take
+    it (``ops.flash_attention.as_mask``), applied dense.  ``segment_ids``
+    ([B, T] int32) enables sequence packing: tokens attend only within
+    their own segment (composes with ``causal``).
     """
     d = q.shape[-1]
     scale = (d ** -0.5) if scale is None else scale
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     t = q.shape[1]
     allowed = None
-    if causal:
+    from horovod_tpu.ops import flash_attention as fa
+    mask = fa.as_mask(causal)
+    if mask is fa.CAUSAL:
         allowed = jnp.tril(jnp.ones((t, t), bool))[None, None]
+    elif mask is not fa.FULL:
+        pos = jnp.arange(t)
+        allowed = mask.visible(pos[:, None], pos[None, :])[None, None]
     if segment_ids is not None:
         seg_ok = (segment_ids[:, None, :, None] ==
                   segment_ids[:, None, None, :])

@@ -110,6 +110,11 @@ MLP_DENSE = "mlp_dense"
 # knows only the model scopes books its parts with the main stack's.
 MTP = "mtp"
 
+# Block-diffusion training (transformer.diffusion_loss_fn): the noised
+# copy laid beside the clean sequence, the repeated positions and the
+# loss's weights; a bare component under EMBED.
+DIFFUSION_ASSEMBLE = "diffusion_assemble"
+
 # Step scopes: what the step does with the gradients.
 GRAD_MEAN = "grad_mean"
 OPTIMIZER = "optimizer"

@@ -1,4 +1,6 @@
-"""The three flash kernels, the two gated-delta-rule kernels, the two
+"""The three flash kernels (under the causal mask, held to the text they
+lowered to before the mask became a description, and under the
+block-diffusion mask), the two gated-delta-rule kernels, the two
 Mamba-2 scan kernels, the two selective-scan kernels, the two kernels of
 the Mamba-1 gate, the two of latent attention's assembly and the two
 short-convolution kernels compiled at
@@ -67,6 +69,114 @@ def test_kernels_compile_for_the_v5e(one_chip, shape, segments):
         return (out,) + pull(do)
 
     text = jax.jit(fwd_and_grads).lower(x, x, x, x, seg).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in (scopes.FLASH_FWD, scopes.FLASH_BWD_DQ,
+                 scopes.FLASH_BWD_DKV):
+        assert f"%{name}." in text or f"%{name} " in text, name
+
+
+def _mosaic_kernels(lowered_text):
+    """The Mosaic modules of a lowered program's kernels as text without
+    locations (a location names a file and a function, which a refactor
+    moves and no compiler reads)."""
+    import base64
+    import json
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    kernels = []
+    for found in re.finditer(r'backend_config = "((?:[^"\\]|\\.)*)"',
+                             lowered_text):
+        config = json.loads(found.group(1).replace("\\22", '"').replace(
+            "\\5C", "\\"))
+        ctx = mlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            kernels.append(ir.Module.parse(base64.b64decode(
+                config["custom_call_config"]["body"])).operation.get_asm(
+                    enable_debug_info=False))
+    return kernels
+
+
+# sha256 (16 digits) of the forward, dQ and dK+dV kernels under the causal
+# mask as the parent of PR 46 lowered them (jax 0.9.0): the mask became a
+# description there, and the causal instance had to stay the same kernels.
+CAUSAL_KERNELS = {
+    ("t8192", False): ("b9667c54a7895eec", "efa32740fe56ab06",
+                       "ecee4ace4c58b667"),
+    ("t8192", True): ("2528025464324c2a", "3c0d38feccbaffe1",
+                      "9a83e91cb83f2f5a"),
+    ("t2048", False): ("f20a3ed4e8843ec8", "8aeccb61deaf8141",
+                       "16b2b80e904d0482"),
+    ("t2048", True): ("bcfb36482363a47e", "d171228f3812379a",
+                      "4890400eabe69a85"),
+    ("t8192_d256", False): ("3144adc4c799daf7", "3f8275678f606b2b",
+                            "07c87bd97c8d216f"),
+    ("t8192_d256", True): ("cdb3cb7f6874971c", "c31146ee2ffc8fc9",
+                           "2c1de0364af07a56")}
+SHAPES = {"t8192": (1, 8192, 32, 128), "t2048": (4, 2048, 32, 128),
+          "t8192_d256": (1, 8192, 20, 256)}
+
+
+@pytest.mark.parametrize("name,segments", list(CAUSAL_KERNELS),
+                         ids=[f"{n}-{'segment_ids' if s else 'causal'}"
+                              for n, s in CAUSAL_KERNELS])
+def test_the_causal_kernels_lower_as_they_did(one_chip, name, segments):
+    """``causal=True`` through the mask's description gives, operation for
+    operation, the kernels that branched on a bool."""
+    import hashlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.flash_attention import flash_attention
+
+    shape = SHAPES[name]
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    seg = (jax.ShapeDtypeStruct(shape[:2], jnp.int32, sharding=one_chip)
+           if segments else None)
+
+    def fwd_and_grads(q, k, v, do, seg):
+        out, pull = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, True, None, None, None,
+                                            False, seg), q, k, v)
+        return (out,) + pull(do)
+
+    kernels = _mosaic_kernels(
+        jax.jit(fwd_and_grads).lower(x, x, x, x, seg).as_text())
+    assert tuple(hashlib.sha256(k.encode()).hexdigest()[:16]
+                 for k in kernels) == CAUSAL_KERNELS[name, segments]
+
+
+# [B, 2 L, H, D] of sdar30b_bd8k (8192 clean tokens beside their noised
+# copy, blocks of 4, 1024-blocks), and a diffusion block as large as a
+# sub-tile.
+@pytest.mark.parametrize("shape,length,block",
+                         [((1, 16384, 32, 128), 8192, 4),
+                          ((1, 1024, 2, 128), 512, 256)],
+                         ids=["sdar30b_bd8k", "block256"])
+def test_kernels_compile_under_the_block_diffusion_mask(one_chip, shape,
+                                                        length, block):
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.flash_attention import (BlockDiffusion,
+                                                 flash_attention)
+    from horovod_tpu.telemetry import scopes
+
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    mask = BlockDiffusion(length, block)
+
+    def fwd_and_grads(q, k, v, do):
+        out, pull = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, mask, None, None, None,
+                                            False), q, k, v)
+        return (out,) + pull(do)
+
+    text = jax.jit(fwd_and_grads).lower(x, x, x, x).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     for name in (scopes.FLASH_FWD, scopes.FLASH_BWD_DQ,
                  scopes.FLASH_BWD_DKV):

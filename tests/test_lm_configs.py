@@ -1,7 +1,8 @@
 """What every LM configuration must hold, written once and run over a table
-of them: the GPT-2 block and the six tiny configurations that keep the
-shape of OLMoE, Olmo-Hybrid, Nemotron-3, GLM-4.7-Flash, Keye-VL-2.0 and
-Jamba2,
+of them: the GPT-2 block and the seven tiny configurations that keep the
+shape of OLMoE, Olmo-Hybrid, Nemotron-3, GLM-4.7-Flash, Keye-VL-2.0,
+Jamba2 and SDAR (whose batch is clean tokens, which are noised and each
+block's rate, and whose loss is ``diffusion_loss_fn``'s),
 each against its plain reference under ``perfbench/reference/``, which
 shares no code with the program.
 
@@ -41,8 +42,8 @@ import pytest
 
 from horovod_tpu.models import linear_attention as la
 from horovod_tpu.models import transformer as tfm
-from perfbench.reference import (dsa_moe_lm, hybrid_lm, lm, mamba1_lm,
-                                 mla_moe_lm, moe_lm, ssm_moe_lm)
+from perfbench.reference import (bd_moe_lm, dsa_moe_lm, hybrid_lm, lm,
+                                 mamba1_lm, mla_moe_lm, moe_lm, ssm_moe_lm)
 
 __all__ = ["COSTLY", "ROWS", "built", "lm_row", "pytest_generate_tests",
            "rel"]
@@ -107,6 +108,16 @@ JAMBA_TINY = tfm.TransformerConfig(
     tie_embeddings=True, mlp="swiglu", layer_types=JAMBA_PATTERN,
     mamba_inner=128, mamba_state=4, mamba_dt_rank=8, mamba_conv_kernel=4)
 
+# Keye's layer without the indexer, under the block-diffusion objective:
+# blocks of 4, the mask id the last row of the vocabulary.
+SDAR_TINY = tfm.TransformerConfig(
+    vocab_size=128, d_model=64, n_heads=4, n_kv_heads=2, head_width=32,
+    n_layers=2, d_ff=0, max_seq=128, dtype=F32, positions="rope",
+    rope_theta=1e6, norm_eps=1e-6, tie_embeddings=False,
+    qk_norm_per_head=True, mlp="swiglu", n_experts=8, experts_per_token=2,
+    d_expert=48, norm_topk_prob=True, experts_held=4, experts_held_from=2,
+    diffusion_block=4, mask_token_id=127)
+
 
 def rel(got, want):
     got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
@@ -114,8 +125,10 @@ def rel(got, want):
 
 
 # --- each row's reference, under one signature ------------------------------
-# ``(cfg, params, tokens, labels, **controls) -> (loss, {name: gradient},
-# what else it returns)`` and ``(tree, cfg) -> {name: leaf}``.
+# ``(cfg, params, *batch, **controls) -> (loss, {name: gradient}, what
+# else it returns)`` (``batch``: tokens and labels; under block diffusion
+# clean tokens, which are noised and the blocks' rates) and ``(tree, cfg)
+# -> {name: leaf}``.
 
 def _gpt2_ref(cfg, params, tokens, labels):
     return lm.loss_and_tail_grads(params, tokens, labels, cfg.n_heads) + (
@@ -201,6 +214,21 @@ def _jamba_ref(cfg, params, tokens, labels, **kw):
         stats=True, **kw)
 
 
+def sdar_dims(cfg):
+    return {"n_heads": cfg.n_heads, "n_kv_heads": cfg.kv_heads,
+            "head_dim": cfg.head_dim, "eps": cfg.norm_eps,
+            "theta": cfg.rope_theta, "top_k": cfg.experts_per_token,
+            "held_from": cfg.experts_held_from,
+            "block": cfg.diffusion_block, "mask_id": cfg.mask_token_id}
+
+
+def _sdar_ref(cfg, params, tokens, masked, rates, **kw):
+    """Every leaf, the embedding's among them."""
+    return bd_moe_lm.loss_and_tail_grads(
+        params, tokens, masked, rates, dims=sdar_dims(cfg),
+        names=tuple(bd_moe_lm.LEAVES), **kw)
+
+
 def _by_paths(reference, paths):
     return lambda tree, cfg: {name: reference.leaf(tree, path)
                               for name, path in paths(cfg).items()}
@@ -228,6 +256,11 @@ def _jamba_decays(stats):
     assert 0.0 < float(stats["decay"].min()) <= float(
         stats["decay"].max()) < 1.0
     assert float(stats["delta"].min()) > 0.0
+
+
+def _rows_and_masked_share(stats):
+    assert stats["rows"].shape == (2, 4)
+    assert 0.2 < float(stats["masked_share"]) < 0.8
 
 
 def _rows_and_kl(stats):
@@ -299,6 +332,13 @@ _GLM_NOT_THE_BLOCKS = ("positions", "head_width", "kv_latent_rank",
                        "mtp_layer_types")
 _KEYE_NOT_THE_BLOCKS = _INDEXER + ("positions", "head_width", "n_kv_heads",
                                    "qk_norm_per_head", "n_experts")
+_SDAR_NOT_THE_BLOCKS = ("positions", "head_width", "n_kv_heads",
+                        "qk_norm_per_head", "n_experts", "diffusion_block",
+                        "mask_token_id")
+_OBJECTIVE_ALONE = dict(_NO_EXPERTS, d_ff=96, norm_topk_prob=False,
+                        n_kv_heads=0, head_width=0, qk_norm_per_head=False)
+BEYOND_NAMES = ("model_axis", "seq_axis", "packed", "segment_ids",
+                "decode_step", "pipelined")
 _GDN_BLOCKS = 2 * 2 * 256 // la.BLOCK
 _NO_MAMBA = dict(layer_types=(), mamba_inner=0, mamba_state=0,
                  mamba_dt_rank=0, mamba_conv_kernel=0)
@@ -610,6 +650,71 @@ ROWS = {
                (dict(q_latent_rank=8, kv_latent_rank=8, rope_dim=8,
                      n_kv_heads=0, qk_norm_per_head=False),
                 NotImplementedError, "indexer beside latent attention"))),
+    "sdar": Row(
+        cfg=SDAR_TINY, ref=_sdar_ref, seq=64, embed_scale=50.0,
+        checked=_by_paths(bd_moe_lm, lambda cfg: bd_moe_lm.leaf_paths(
+            cfg.n_layers)),
+        # The dense form of the mask, and the flash kernels under it in
+        # the interpreter (blocks of 64: one masked tile a quadrant).
+        parity=(("float32", F32, "local", 5e-5, 5e-5),
+                ("float32-flash", F32, "flash", 5e-5, 5e-5)),
+        also=lambda stats, grads: _rows_and_masked_share(stats),
+        # The seven references that the cell's check must refuse.
+        controls=(
+            ("causal_mask", dict(rule="causal"), "wk_last", 1e-4, 0.02),
+            ("own_clean_block", dict(rule="own_clean_block"), "wk_last",
+             1e-4, 0.02),
+            ("noised_causal", dict(rule="noised_causal"), "wk_last", 1e-4,
+             0.02),
+            ("running_positions", dict(running_positions=True), "wk_last",
+             1e-4, 0.02),
+            ("unweighted", dict(weighted=False), "ln_f_scale", 1e-2, 0.02),
+            ("shifted_labels", dict(shift=1), "ln_f_scale", 1e-4, 0.02),
+            ("float8", dict(low_precision=jnp.float8_e4m3fn), "wo_last",
+             1e-4, 0.02)),
+        step=("full", "flash"), zero=True,
+        shapes={("layers", 1, "q_norm_scale"): (32,),
+                ("layers", 1, "wq"): (64, 128),
+                ("layers", 1, "wk"): (64, 64),
+                ("layers", 1, "w_down"): (4, 48, 64),
+                ("layers", 1, "router"): (64, 8),
+                ("head",): (64, 128)},
+        # Both halves go through every expert layer: 2 x 2 x 64 positions.
+        series=('hvd_moe_experts_held{layer="0"} 4',
+                'hvd_moe_experts_held{layer="1"} 4'),
+        no_series=("hvd_moe_assignments_total", "hvd_ssm_", "hvd_dsa_"),
+        # What perfbench/bd_reduce.py reads.
+        scopes=("embed/diffusion_assemble",
+                "layer_0/attn/qkv/qk_head_norm_rope",
+                "layer_1/attn/local_attention",
+                "layer_1/mlp/moe_router", "layer_1/mlp/moe_experts"),
+        no_scopes=("/dsa_", "/mla_", "/moe_shared", "/mtp"),
+        refused={"model_axis": ("n_experts", "n_kv_heads", "head_width",
+                                "qk_norm_per_head", "diffusion_block"),
+                 "seq_axis": ("head_width", "qk_norm_per_head",
+                              "diffusion_block"),
+                 "packed": ("diffusion_block",),
+                 "segment_ids": ("diffusion_block",),
+                 "decode_step": _SDAR_NOT_THE_BLOCKS,
+                 "pipelined": _SDAR_NOT_THE_BLOCKS},
+        # Without experts or grouped heads of a width of their own: the
+        # objective alone refuses what it does not run under.
+        alone=tuple(("objective", _OBJECTIVE_ALONE, what, "diffusion_block")
+                    for what in BEYOND_NAMES),
+        rules=((dict(mask_token_id=-1), ValueError, "come together"),
+               (dict(diffusion_block=0), ValueError, "come together"),
+               (dict(mask_token_id=128), ValueError, "no row of a vocab"),
+               (dict(diffusion_block=-4), ValueError, "come together"),
+               (dict(positions="learned"), NotImplementedError,
+                "positions='learned'"),
+               (dict(n_layers=1, mtp_layer_types=("full_attention",),
+                     mtp_loss_coef=0.1), NotImplementedError,
+                "mtp_layer_types"),
+               (dict(layer_types=("full_attention", "mlp")),
+                NotImplementedError, "no mixer"),
+               (dict(index_heads=4, index_head_dim=16, index_topk=32,
+                     indexer_loss_coef=1.0), NotImplementedError,
+                "sparse_attention"))),
     "jamba": Row(
         cfg=JAMBA_TINY, ref=_jamba_ref, seq=128, embed_scale=5.0,
         checked=_by_paths(mamba1_lm, lambda cfg: mamba1_lm.leaf_paths(
@@ -678,6 +783,10 @@ ROWS = {
 
 # --- what is built once a row -----------------------------------------------
 
+def _loss_fn(cfg):
+    return tfm.diffusion_loss_fn if cfg.diffusion_block else tfm.loss_fn
+
+
 class Built:
     """A row's parameters, batch, reference values and program values,
     each computed on first use and kept for the process."""
@@ -694,8 +803,16 @@ class Built:
 
     @functools.cache
     def batch(self, sequences=4):
-        """``(tokens, labels)``; four sequences is the least the step
-        splits over four devices."""
+        """``(tokens, labels)``, or under block diffusion ``(tokens,
+        masked, rates)`` with no token the mask's; four sequences is the
+        least the step splits over four devices."""
+        if self.cfg.diffusion_block:
+            toks = jax.random.randint(
+                jax.random.PRNGKey(1), (sequences, self.row.seq), 0,
+                self.cfg.mask_token_id)
+            return (toks,) + tfm.diffusion_noise(
+                jax.random.PRNGKey(2), sequences, self.row.seq,
+                self.cfg.diffusion_block)
         toks = jax.random.randint(jax.random.PRNGKey(1),
                                   (sequences, self.row.seq + 1), 0,
                                   self.cfg.vocab_size)
@@ -712,11 +829,11 @@ class Built:
 
     @functools.cache
     def program(self, remat="none", dtype=F32, attention="local"):
-        """``(loss, gradients)`` of the program's ``loss_fn``."""
+        """``(loss, gradients)`` of the program's loss."""
         cfg = dataclasses.replace(self.cfg, dtype=dtype)
         with jax.default_matmul_precision("highest"):
-            return jax.jit(jax.value_and_grad(lambda p, t, l: tfm.loss_fn(
-                p, t, l, cfg, attention=attention, remat=remat)))(
+            return jax.jit(jax.value_and_grad(lambda p, *batch: _loss_fn(
+                cfg)(p, *batch, cfg, attention=attention, remat=remat)))(
                     self.params, *self.batch())
 
     def checked(self, tree):
@@ -886,9 +1003,16 @@ def test_specs_and_abstract_params_cover_every_leaf(lm_row, case):
 
 
 def _traced(lm_row):
-    tokens = jax.ShapeDtypeStruct((2, lm_row.row.seq), jnp.int32)
-    return (lambda p, t: tfm.loss_fn(p, t, t, lm_row.cfg, attention="local"),
-            tfm.init_abstract(lm_row.cfg), tokens)
+    """``(loss, abstract parameters, abstract batch)`` on two sequences."""
+    cfg, tokens = lm_row.cfg, jax.ShapeDtypeStruct((2, lm_row.row.seq),
+                                                   jnp.int32)
+    batch = (tokens, tokens)
+    if cfg.diffusion_block:
+        batch = (tokens, jax.ShapeDtypeStruct(tokens.shape, bool),
+                 jax.ShapeDtypeStruct(
+                     (2, lm_row.row.seq // cfg.diffusion_block), F32))
+    return (lambda p, *b: _loss_fn(cfg)(p, *b, cfg, attention="local"),
+            tfm.init_abstract(cfg), batch)
 
 
 @family(one)
@@ -900,8 +1024,8 @@ def test_trace_time_series_count_what_was_traced(hvd, lm_row, case):
     telemetry.reset_for_tests()
     telemetry.configure(True)
     try:
-        loss, params, tokens = _traced(lm_row)
-        jax.eval_shape(loss, params, tokens)
+        loss, params, batch = _traced(lm_row)
+        jax.eval_shape(loss, params, *batch)
         text = telemetry.render_prometheus()
         for series in lm_row.row.series:
             assert series in text, (series, text)
@@ -916,16 +1040,15 @@ def test_scopes_name_the_parts(hvd, lm_row, case):
     """The lowered loss carries the scopes the per-layer metrics read
     (``perfbench/*_reduce.py``), and none of a part the row does not
     hold."""
-    loss, params, tokens = _traced(lm_row)
-    text = jax.jit(loss).lower(params, tokens).as_text(debug_info=True)
+    loss, params, batch = _traced(lm_row)
+    text = jax.jit(loss).lower(params, *batch).as_text(debug_info=True)
     for scope in lm_row.row.scopes:
         assert scope in text, scope
     for scope in lm_row.row.no_scopes:
         assert scope not in text, scope
 
 
-BEYOND = ("model_axis", "seq_axis", "packed", "segment_ids", "decode_step",
-          "pipelined")
+BEYOND = BEYOND_NAMES
 
 
 def _beyond_the_data_axis(what, cfg, seq):

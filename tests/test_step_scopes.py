@@ -89,7 +89,12 @@ def _lm_step_text(attention, remat, shard_optimizer, seq_axis=None,
     opt_state = jax.eval_shape(
         step.init if shard_optimizer else optimizer.init, params)
     tokens = jax.ShapeDtypeStruct((8, 128), jnp.int32)
-    return step.lower(params, opt_state, tokens, tokens).compile().as_text()
+    batch = (tokens, tokens)
+    if cfg.diffusion_block:
+        batch = (tokens, jax.ShapeDtypeStruct((8, 128), bool),
+                 jax.ShapeDtypeStruct((8, 128 // cfg.diffusion_block),
+                                      jnp.float32))
+    return step.lower(params, opt_state, *batch).compile().as_text()
 
 
 ROUTE = {"local": scopes.ATTN_LOCAL, "flash": scopes.ATTN_FLASH,
@@ -413,6 +418,37 @@ def test_glm_step_carries_the_vocabulary_and_every_part(hvd, attention,
     assert _unplaced(text) == []
 
 
+SDAR = dict(positions="rope", tie_embeddings=False, n_kv_heads=1,
+            head_width=64, qk_norm_per_head=True, mlp="swiglu", n_experts=4,
+            experts_per_token=2, d_expert=64, norm_topk_prob=True,
+            experts_held=2, experts_held_from=1, diffusion_block=4,
+            mask_token_id=255)
+
+
+@pytest.mark.parametrize("attention,remat",
+                         [("local", "none"), ("flash", "full")])
+def test_sdar_step_carries_the_vocabulary_and_the_assembly(hvd, attention,
+                                                           remat):
+    """Under block diffusion the step's own assembly of the two streams
+    opens under ``embed/diffusion_assemble`` and nowhere else, the flash
+    kernels sit under their route as ever, every executed op has a phase
+    and a scope, and ``perfbench/bd_reduce.py`` reads each part by name."""
+    from perfbench import bd_reduce, moe_reduce
+
+    text = _lm_step_text(attention, remat, False, **SDAR)
+    _check_lm(text, attention, remat, False)
+    names = _op_names(text)
+    # (``embed`` is the outermost scope here, so jvp wraps it alone.)
+    inside = f"({scopes.EMBED})/{scopes.DIFFUSION_ASSEMBLE}/"
+    assert _under(names, scopes.DIFFUSION_ASSEMBLE, inside)
+    assert not _under(names, scopes.DIFFUSION_ASSEMBLE, without=(inside,))
+    assert not any(f"/{scopes.MTP}/" in n or "/dsa_" in n for n in names)
+    hlo = scope_reduce.parse_hlo(text)
+    parts = {bd_reduce.part_of(moe_reduce.op_name_of(name, hlo))
+             for name, i in hlo.instructions.items() if i.opcode in HELD}
+    assert set(bd_reduce.PARTS) - {bd_reduce.ASSEMBLE} <= parts
+
+
 @pytest.mark.parametrize("attention", ("ring", "ulysses"))
 def test_sequence_routes_open_their_own_scope(hvd, attention):
     text = _lm_step_text(attention, "none", False, seq_axis="seq")
@@ -549,6 +585,12 @@ def test_the_benchmark_reads_the_same_vocabulary():
                       scopes.MAMBA_GATE_FWD, scopes.MAMBA_GATE_BWD}
     # Latent attention's assembly: two kernels, booked by ``mla_rope``.
     mla_kernels = {scopes.MLA_ASSEMBLE_FWD, scopes.MLA_ASSEMBLE_BWD}
+    # Block diffusion's assembly of the two streams, a sub-scope of
+    # ``embed`` read by ``perfbench/bd_reduce.py``.
+    bd_parts = {scopes.DIFFUSION_ASSEMBLE}
+    from perfbench import bd_reduce
+    assert bd_parts | {scopes.QK_HEAD_NORM_ROPE} | moe_parts == set(
+        bd_reduce.PARTS)
     from perfbench import dsa_reduce
     assert dsa_parts == set(dsa_reduce.DSA_PARTS)
     assert set(scope_reduce.KERNEL_NAMES) == kernels
@@ -558,7 +600,7 @@ def test_the_benchmark_reads_the_same_vocabulary():
             - moe_kernels - gdn_parts - gdn_kernels - ssm_parts
             - ssm_kernels - mla_parts - conv_kernels - norm_kernels
             - dsa_parts - dsa_kernels - mamba1_parts - mamba1_kernels
-            - mla_kernels)
+            - mla_kernels - bd_parts)
     from perfbench import mamba1_reduce
     assert ({p.rsplit("/", 1)[-1] for p in mamba1_parts}
             == set(mamba1_reduce.PARTS))
