@@ -40,8 +40,10 @@ step, as the layer of before did, and a batch past the prefix costs what
 it cost: ``nemotron3s_t8192`` with every expert layer forced past it,
 835.8 ms a step against the parent's 839.3; PERF.md, PR 36).  On the
 prefix a token's rows cannot be listed in a static shape, so the rows go
-out by one gather of the prefix and come back by **one sum into the
-tokens, a scatter-add** (:func:`_head_ffn`).
+out by token and come back by **one sum into the tokens**
+(:func:`_head_ffn`): one gather of the prefix, and the kernels of
+:mod:`horovod_tpu.ops.moe_rows`, a DMA a live row (PERF.md, PR 48), where
+they take the rows; else a scatter-add.
 Decided again on the chip at the prefix of ``nemotron3s_t8192``, 11,264
 rows of 1,024 into 8,192 tokens (PERF.md, PR 36): the sum takes 0.54 ms
 (``moe_combine`` of a traced step 9.4), a gather of all 65,536 slots that
@@ -80,6 +82,7 @@ from horovod_tpu import telemetry
 from horovod_tpu.models import parts
 from horovod_tpu.models.parts import dense, ones, rmsnorm, whole
 from horovod_tpu.ops import grouped_matmul as gmm
+from horovod_tpu.ops import moe_rows
 from horovod_tpu.ops.grouped_matmul import (grouped_matmul,
                                             worst_matmul_rows)
 from horovod_tpu.telemetry import scopes
@@ -219,20 +222,23 @@ def _lowered_once(matmul, x, w, group_sizes):
     return matmul(x, w, group_sizes)
 
 
-def _experts(x, live, group_sizes, layer, act: str, dtype):
+def _experts(x, live, group_sizes, layer, act: str, dtype,
+             tail_is_read: bool = True):
     """The held experts on the sorted rows ``x`` [m, d] -> [m, d];
     ``live`` [m, 1] bool marks the rows that are some held expert's (None:
-    all of them)."""
+    all of them).  ``tail_is_read`` False: nothing reads the result past
+    the live rows, forward or backward, so it is left as the last matmul
+    leaves it there (undefined), without a pass to zero it."""
 
-    def matmul(x, w):
+    def matmul(x, w, tail_is_read=True):
         if live is None:
             return grouped_matmul(x, w, group_sizes)
         # A select, not a product: its gradient is a select too, so the
         # operand's gradient (a grouped matmul's result as well) is
         # zeroed before anything multiplies it.
         x = jnp.where(live, x, 0)
-        return jnp.where(
-            live, _lowered_once(grouped_matmul, x, w, group_sizes), 0)
+        out = _lowered_once(grouped_matmul, x, w, group_sizes)
+        return jnp.where(live, out, 0) if tail_is_read else out
 
     if act == "swiglu":
         w_gate, w_up, w_down = _expert_operands(layer)
@@ -247,7 +253,7 @@ def _experts(x, live, group_sizes, layer, act: str, dtype):
             jax.nn.relu(up.astype(jnp.float32))).astype(dtype)
     else:
         raise ValueError(f"act={act!r}: expected 'swiglu' or 'relu2'")
-    return matmul(hidden, w_down)
+    return matmul(hidden, w_down, tail_is_read)
 
 
 def _every_slot_ffn(act: str, dtype, masked: bool, h, slot_w, order,
@@ -277,19 +283,33 @@ def _head_ffn(rows: int, act: str, dtype, h, slot_w, order, group_sizes,
     """:func:`experts_ffn` over the first ``rows`` sorted places, which
     hold every held row (the caller's promise; ``rows`` static, fewer
     than ``N * s``).  A token's places among them cannot be listed in a
-    static shape, so the rows go out by one gather of ``rows``
-    (:func:`_rows_of`) and come back by one sum into the tokens, a
-    scatter-add of ``rows``, each the other's gradient: nothing here is
-    ``N * s`` long but ``order``."""
+    static shape, so the rows go out by one gather of ``rows`` and come
+    back by one sum into the tokens, each the other's gradient: nothing
+    here is ``N * s`` long but ``order``.  Where the kernels of
+    :mod:`horovod_tpu.ops.moe_rows` take the rows (``moe_rows.takes``:
+    read from ``h``, never set) the sum, forward and as the gather's
+    gradient, costs what the batch's live rows cost; else it is a
+    scatter-add of ``rows`` (:func:`_rows_of`'s gradient too)."""
     n, s = slot_w.shape
+    kernels = moe_rows.takes(h, s)
     with jax.named_scope(scopes.MOE_DISPATCH):
         head = order[:rows]
         token = head // s
-        live = (jnp.arange(rows) < jnp.sum(group_sizes))[:, None]
-        x = _rows_of(n, h, token)
+        places, held = jnp.arange(rows), jnp.sum(group_sizes)
+        live = (places < held)[:, None]
+        if kernels:
+            lists = moe_rows.by_token(head, held, n * s)
+            x = moe_rows.rows_by_token(s, h, token, lists, held)
+        else:
+            x = _rows_of(n, h, token)
     with jax.named_scope(scopes.MOE_EXPERTS):
-        out = _experts(x, live, group_sizes, layer, act, dtype)
+        # The kernels' sum reads no row past the live count, and its
+        # gradient is zero there.
+        out = _experts(x, live, group_sizes, layer, act, dtype,
+                       tail_is_read=not kernels)
     with jax.named_scope(scopes.MOE_COMBINE):
+        if kernels:
+            return moe_rows.sum_by_token(out, slot_w, head, lists, held)
         weighted = (out.astype(jnp.float32)
                     * _take(slot_w.reshape(-1), head)[:, None])
         return jax.ops.segment_sum(weighted, token,
@@ -752,9 +772,29 @@ def _applies(ffn, extras):
     return apply
 
 
+def moves_path(x, cfg):
+    """What moves the rows of a layer of ``cfg`` whose input is ``x``
+    [..., d], as :func:`_head_ffn` decides it (``moe_rows.takes`` on the
+    rows the experts see: the latent ones where there is a latent width):
+    ``"kernel"`` or ``"xla"``; None where the layer holds every expert
+    (its moves are :func:`_every_slot_ffn`'s gathers)."""
+    slots = min(cfg.experts_per_token, cfg.held_experts)
+    tokens = x.size // x.shape[-1]
+    if rows_prefix(tokens, cfg.experts_per_token, cfg.held_experts,
+                   cfg.n_experts) >= tokens * slots:
+        return None
+    width = cfg.d_latent if cfg.mlp == "relu2" else cfg.d_model
+    rows = jnp.broadcast_to(x.reshape(-1, x.shape[-1])[:, :1],
+                            (tokens, width)).astype(cfg.dtype)
+    return "kernel" if moe_rows.takes(rows, slots) else "xla"
+
+
 def _record(name, x, layer, cfg, ctx):
     record_held(name, ctx.tokens, cfg)
     record_weight_copies(name, layer)
+    path = moves_path(x, cfg) if telemetry.enabled() else None
+    if path:
+        moe_rows.record_moves(name, path)
     if cfg.held_experts == cfg.n_experts:
         # What lands on a share is data.
         record_assignments(name, ctx.tokens * cfg.experts_per_token,

@@ -133,6 +133,14 @@ MOE_GMM = "moe_gmm"
 MOE_GMM_NT = "moe_gmm_nt"
 MOE_TGMM = "moe_tgmm"
 
+# The two Pallas kernels of a share's sum into the tokens
+# (ops/moe_rows.py): the layout of the expert rows as tiles a row and the
+# sum itself.  They run under MOE_COMBINE (the combine) and under
+# MOE_DISPATCH in the backward pass (the gather's gradient is the same
+# sum).
+MOE_ROW_TILES = "moe_row_tiles"
+MOE_ROWS_BACK = "moe_rows_back"
+
 # The two gated-delta-rule Pallas kernels (ops/gated_delta_rule.py); they
 # run under ATTN_GDN_SCAN.
 GDN_SCAN_FWD = "gdn_scan_fwd"

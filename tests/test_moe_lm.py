@@ -474,6 +474,36 @@ def test_bf16_train_step_reads_the_float32_masters_on_four_devices(hvd):
         assert np.abs(np.asarray(after - before)).max() > 1e-6, name
 
 
+# sha256 (16 digits) of the tiny every-expert-held step's StableHLO (no
+# locations) as the parent of PR 48 lowered it (jax 0.9.0): the kernels of
+# a share's sum (ops/moe_rows.py) stand in ``_head_ffn`` alone, and a chip
+# that holds every expert runs ``_every_slot_ffn``, which that PR did not
+# edit.
+EVERY_EXPERT_HELD_STEP = {"float32": "838e0818a4c51936",
+                          "bfloat16": "c6dcd4f03937ff4a"}
+
+
+@pytest.mark.parametrize("dtype", list(EVERY_EXPERT_HELD_STEP))
+def test_every_expert_held_lowers_the_step_it_did(hvd, dtype):
+    import hashlib
+
+    from horovod_tpu.topology import build_mesh
+
+    cfg = dataclasses.replace(OLMOE_TINY, dtype=jnp.dtype(dtype))
+    assert cfg.held_experts == cfg.n_experts
+    mesh = build_mesh(axes=("data",), devices=jax.devices()[:4])
+    optimizer = optax.sgd(0.1)
+    step, _, _ = tfm.make_train_step(cfg, optimizer, mesh,
+                                     attention="local", donate=False)
+    params = tfm.init_abstract(cfg)
+    tokens = jax.ShapeDtypeStruct((8, 16), jnp.int32)
+    text = step.lower(params, jax.eval_shape(optimizer.init, params), tokens,
+                      tokens).as_text()
+    assert "moe_row" not in text
+    assert (hashlib.sha256(text.encode()).hexdigest()[:16]
+            == EVERY_EXPERT_HELD_STEP[dtype])
+
+
 def test_sequence_axis_offsets_the_rotary_positions(hvd):
     """Two sequence shards (ring attention) see positions 0..T/2-1 and
     T/2..T-1; the loss is the single-device loss."""

@@ -597,6 +597,51 @@ def test_an_ordinary_batch_makes_no_pass_over_the_bound(recompute):
             "pallas_call") == 2 + 2 + 2 * 2
 
 
+def test_an_ordinary_batch_on_the_kernels_sums_by_token():
+    """The same layer with rows that the kernels of ``ops/moe_rows.py``
+    take (bf16, a latent width of one tile of float32): the ordinary
+    branch holds no scatter-add of rows, its sums (the combine's, and
+    the gather's gradient) are the kernel pair, and forward nothing
+    float32 is the prefix's rows by the rows' width; the overflow branch
+    is the layer of before."""
+    from horovod_tpu.ops import moe_rows
+    from horovod_tpu.telemetry import scopes
+
+    cfg, layer, u = _expert_layer(dataclasses.replace(
+        NEMOTRON_TINY, dtype=jnp.bfloat16, d_latent=1024, n_experts=512,
+        experts_per_token=22, experts_held=8, experts_held_from=8))
+    u = jnp.tile(u, (32, 1)).astype(jnp.bfloat16)
+    prefix = moe.rows_prefix(u.shape[0], 22, 8, 512)
+    assert moe.moves_path(u, cfg) == "kernel"
+    block = lambda layer, u: u + moe.latent_moe_ffn(u, layer, cfg)[0]
+
+    def sums_and_scatters(eqns):
+        return ([e.params["name"] for e in eqns
+                 if e.primitive.name == "pallas_call"
+                 and e.params["name"].startswith("moe_row")],
+                [e for e in eqns if e.primitive.name == "scatter-add"
+                 and len(e.outvars[0].aval.shape) == 2
+                 and ("moe_dispatch" in str(e.source_info.name_stack)
+                      or "moe_combine" in str(e.source_info.name_stack))])
+
+    jaxpr = jax.make_jaxpr(jax.grad(lambda layer, u: jnp.sum(jnp.sin(
+        block(layer, u).astype(jnp.float32))), (0, 1)))(layer, u).jaxpr
+    kernels, scatters = sums_and_scatters(list(_eqns(jaxpr, 1)))
+    # Forward; the backward computes the form again (its sum is then dead
+    # code) and sums the gather's gradient.
+    assert sorted(kernels) == 3 * [scopes.MOE_ROW_TILES] + 3 * [
+        scopes.MOE_ROWS_BACK] and not scatters
+    kernels, scatters = sums_and_scatters(list(_eqns(jaxpr, 0)))
+    assert not kernels and not scatters            # gathers both ways
+    forward = list(_eqns(jax.make_jaxpr(block)(layer, u).jaxpr, 1))
+    assert not [v for e in forward for v in e.outvars
+                if v.aval.dtype == jnp.float32
+                and v.aval.shape == (prefix, 1024)]
+    assert [v for e in forward for v in e.outvars
+            if v.aval.shape == (prefix, 1024 // moe_rows.LANES,
+                                moe_rows.LANES)]
+
+
 @pytest.mark.parametrize("layer_of", ("softmax_swiglu", "latent_relu2"))
 def test_every_expert_held_is_one_pass(layer_of):
     """Where the chip holds every expert the prefix is the bound: one
