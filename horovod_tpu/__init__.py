@@ -28,6 +28,12 @@ Quick start (single host, all local TPU chips)::
     step = hvd.make_training_step(loss_fn, optimizer, mesh)
 """
 
+import time as _time
+
+# The ``import`` phase span (telemetry/spans.py, "Start-up"): this file
+# top to bottom, on telemetry.clock, closed at the last line.
+_import_t0 = _time.monotonic()
+
 from horovod_tpu import basics as _basics
 from horovod_tpu.basics import (
     init,
@@ -91,7 +97,7 @@ from horovod_tpu.ops.collective import (
 from horovod_tpu.ops.compression import Compression, resolve_codec
 from horovod_tpu import checkpoint  # noqa: F401  (hvd.checkpoint.save/restore)
 from horovod_tpu import telemetry  # noqa: F401  (hvd.telemetry.counter/...)
-from horovod_tpu.telemetry import metrics_snapshot
+from horovod_tpu.telemetry import metrics_snapshot, startup_report
 from horovod_tpu.parallel.data import (
     DistributedOptimizer,
     DistributedGradientTape,
@@ -139,7 +145,7 @@ __all__ = [
     "reducescatter", "alltoall", "alltoall_ragged",
     "synchronize", "poll", "join",
     # observability
-    "telemetry", "metrics_snapshot",
+    "telemetry", "metrics_snapshot", "startup_report",
     # training
     "Compression", "resolve_codec", "checkpoint",
     "DistributedOptimizer", "DistributedGradientTape", "make_training_step",
@@ -150,3 +156,5 @@ __all__ = [
     # resilience
     "resilience", "StepGuard", "warm_restore", "report_progress",
 ]
+
+telemetry.record_phase("import", _import_t0, telemetry.clock())

@@ -28,6 +28,7 @@ PJRT distributed runtime over DCN; the launcher provides
 from __future__ import annotations
 
 import atexit
+import contextlib
 import os
 import threading
 from typing import NamedTuple, Optional, Sequence, Tuple
@@ -102,9 +103,14 @@ def init(comm=None, ranks: Optional[Sequence[int]] = None) -> None:
          ``run/gloo_run.py:211-254``)
       3. ``jax.process_index()``/``jax.process_count()`` (TPU pod metadata)
     """
-    with _state.lock:
+    # The start-up's host spans and the compile ledger (telemetry/spans.py,
+    # "Start-up"); the ledger's listeners are registered once a process.
+    from horovod_tpu import telemetry
+    telemetry.listen_to_jax()
+    with _state.lock, contextlib.ExitStack() as stack:
         if _state.initialized:
             return
+        phase = stack.enter_context(telemetry.span("init"))
 
         coord = config.env_raw("HOROVOD_COORDINATOR_ADDR")
         if coord and config.env_str("HOROVOD_JAX_DISTRIBUTED", "0") == "1":
@@ -113,11 +119,12 @@ def init(comm=None, ranks: Optional[Sequence[int]] = None) -> None:
             # jax call that would initialize the XLA backend, so no
             # jax.process_count() guard here.  CPU multi-process testing
             # instead uses the native TCP runtime for data movement.
-            jax.distributed.initialize(
-                coordinator_address=coord,
-                num_processes=_env_int("HOROVOD_SIZE", 1),
-                process_id=_env_int("HOROVOD_RANK", 0),
-            )
+            with telemetry.span("init/distributed", coordinator=coord):
+                jax.distributed.initialize(
+                    coordinator_address=coord,
+                    num_processes=_env_int("HOROVOD_SIZE", 1),
+                    process_id=_env_int("HOROVOD_RANK", 0),
+                )
 
         if comm is not None and hasattr(comm, "Get_rank"):
             _state.rank = comm.Get_rank()
@@ -153,8 +160,13 @@ def init(comm=None, ranks: Optional[Sequence[int]] = None) -> None:
             # for this process (docs/running.md, "Ranks and chips").
             rank = _env_int("HOROVOD_RANK", None)
             size = _env_int("HOROVOD_SIZE", None)
-            _state.rank = jax.process_index() if rank is None else rank
-            _state.size = jax.process_count() if size is None else size
+            if rank is None or size is None:
+                # The first call that starts the XLA backend, unless the
+                # caller's jax.devices() did: the TPU runtime's 7-12 s.
+                with telemetry.span("init/backend"):
+                    index, count = jax.process_index(), jax.process_count()
+            _state.rank = index if rank is None else rank
+            _state.size = count if size is None else size
             _state.local_rank = _env_int("HOROVOD_LOCAL_RANK", _state.rank)
             _state.local_size = _env_int("HOROVOD_LOCAL_SIZE", _state.size)
             _state.cross_rank = _env_int("HOROVOD_CROSS_RANK",
@@ -183,7 +195,8 @@ def init(comm=None, ranks: Optional[Sequence[int]] = None) -> None:
                 local_size=_state.local_size,
             )
             try:
-                runtime.start()
+                with telemetry.span("init/native"):
+                    runtime.start()
             except Exception:
                 # Leave the process cleanly un-initialized so a corrected
                 # re-init is possible (the reference instead falls back to a
@@ -193,14 +206,13 @@ def init(comm=None, ranks: Optional[Sequence[int]] = None) -> None:
             _state.runtime = runtime
 
         _state.initialized = True
+        phase.attrs.update(rank=_state.rank, size=_state.size)
         log.debug("initialized: rank=%d size=%d local_rank=%d local_size=%d",
                   _state.rank, _state.size, _state.local_rank,
                   _state.local_size)
 
     # Record the coordination epoch this rank is operating under — after a
-    # failover the merged metrics must show every rank on the new epoch
-    # (lazy import keeps telemetry out of the minimal init path).
-    from horovod_tpu import telemetry
+    # failover the merged metrics must show every rank on the new epoch.
     telemetry.gauge(
         "hvd_coord_epoch",
         "Coordinator lease epoch this process is operating under").set(

@@ -34,10 +34,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 import horovod_tpu as hvd
 from horovod_tpu.ops.fusion import fused_pytree_mean
+from horovod_tpu import telemetry
 from horovod_tpu.telemetry import scopes
 from horovod_tpu.topology import build_mesh, data_axis, mesh_size
 
 
+@telemetry.span("make_train_step", step=scopes.TRAIN_STEP)
 def make_train_step(model, optimizer, mesh, axis_name: Optional[str] = None,
                     steps_per_call: int = 1):
     """One SPMD training step for a flax model with BatchNorm state.

@@ -22,12 +22,14 @@ sigmoid-routed and latent experts).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Mapping, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from horovod_tpu import telemetry
 from horovod_tpu.telemetry import scopes
 
 
@@ -71,6 +73,23 @@ class Part:
     unsupported: Mapping[str, Tuple[str, ...]] = dataclasses.field(
         default_factory=dict)
     check_vma: bool = True
+
+    def __post_init__(self):
+        # Every trace of the body is counted and timed by part name: the
+        # start-up's ``trace_part/<name>`` (telemetry/spans.py).  Host
+        # clock reads around the Python that traces; nothing traced
+        # changes.
+        body = self.apply
+
+        @functools.wraps(body)
+        def apply(x, layer, cfg, ctx):
+            began = telemetry.clock()
+            try:
+                return body(x, layer, cfg, ctx)
+            finally:
+                telemetry.part_traced(self.name, telemetry.clock() - began)
+
+        object.__setattr__(self, "apply", apply)
 
 
 def everywhere(*fields):

@@ -46,6 +46,7 @@ from horovod_tpu.models import (attention as attention_mod, linear_attention,
                                 mamba1, mamba2, mlp as mlp_mod, moe, parts)
 from horovod_tpu.ops.flash_attention import BlockDiffusion
 from horovod_tpu.parallel import sequence as seq_mod
+from horovod_tpu import telemetry
 from horovod_tpu.telemetry import scopes
 
 # What perfbench/ reads here, under the names they always had.
@@ -738,6 +739,7 @@ def diffusion_loss_fn(params, tokens, masked, rates, cfg: TransformerConfig,
                                cfg, batch_axes)
 
 
+@telemetry.span("make_train_step", step=scopes.LM_TRAIN_STEP)
 def make_train_step(cfg: TransformerConfig, optimizer, mesh,
                     data_axis: str = "data",
                     model_axis: Optional[str] = None,
@@ -1174,6 +1176,7 @@ def split_pipeline_params(params, n_stages: int, virtual: int = 1):
     return {"base": base, "stacked": stack_layer_params(params, n_stages)}
 
 
+@telemetry.span("make_train_step", step=scopes.LM_PIPELINED_TRAIN_STEP)
 def make_train_step_pipelined(cfg: TransformerConfig, optimizer, mesh,
                               data_axis: Optional[str] = "data",
                               pipe_axis: str = "pipe",

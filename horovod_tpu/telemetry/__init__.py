@@ -37,6 +37,7 @@ allocates nothing and mutates nothing (asserted by
 from __future__ import annotations
 
 import atexit
+import importlib
 import os
 import time
 from typing import Dict, Optional
@@ -52,6 +53,22 @@ from horovod_tpu.telemetry.registry import (  # noqa: F401  (re-export)
 )
 
 clock = time.monotonic   # one clock for every duration metric + timeline
+
+# The span module by importlib, not ``from ... import spans``: the
+# :func:`spans` accessor below shadows the submodule as a package
+# attribute, so an attribute-based import would grab the function.  It
+# imports this package back, for ``clock`` above and the accessors below,
+# which it reads at call time.
+_spans_module = importlib.import_module("horovod_tpu.telemetry.spans")
+# The start-up's host side (spans.py, "Start-up"): the one host-span
+# primitive and the one report, kept whatever the environment says.
+span = _spans_module.span
+startup_report = _spans_module.startup_report
+# What feeds the report from the few sites that cannot open a span.
+record_phase = _spans_module.record_phase
+part_traced = _spans_module.part_traced
+listen_to_jax = _spans_module.listen_to_jax
+cache_found = _spans_module.cache_found
 
 _ENV_VARS = ("HOROVOD_METRICS", "HOROVOD_METRICS_PORT",
              "HOROVOD_METRICS_FILE", "HOROVOD_METRICS_RPC")
@@ -125,12 +142,7 @@ def _configure_from_env() -> None:
             rank=int(os.environ.get("HOROVOD_RANK", "0") or 0))
 
     if _spans is None:
-        # importlib, not ``from ... import spans``: the :func:`spans`
-        # accessor below shadows the submodule as a package attribute,
-        # so an attribute-based import would grab the function.
-        import importlib
-        _spans = importlib.import_module(
-            "horovod_tpu.telemetry.spans").configured_recorder()
+        _spans = _spans_module.configured_recorder()
 
 
 def _at_exit() -> None:
@@ -154,11 +166,8 @@ def _at_exit() -> None:
                 pass
         # Span export runs BEFORE the metrics push so the recorder's
         # hvd_trace_* totals land in this rank's metrics snapshot.
-        # (importlib: the spans() accessor shadows the submodule.)
-        import importlib
-        spans_mod = importlib.import_module("horovod_tpu.telemetry.spans")
         try:
-            spans_mod.export_at_exit(_spans)
+            _spans_module.export_at_exit(_spans)
         except Exception:
             pass  # exit path: tracing must never mask the job's rc
         _spans = None

@@ -57,8 +57,10 @@ PHASE_BUCKET = {
 }
 
 # Request-scoped phases: correlated by unique name, not by occurrence —
-# never part of a collective step.
-REQUEST_PHASES = frozenset({"rpc", "route", "decode", "broadcast"})
+# never part of a collective step.  ``startup`` is a rank's phase spans
+# (spans.span: init, build_mesh, ...), which no other rank's answer to.
+REQUEST_PHASES = frozenset({"rpc", "route", "decode", "broadcast",
+                            "startup"})
 
 
 def analyze(reports: Dict[int, dict], top_k: int = 5) -> dict:

@@ -192,6 +192,9 @@ DSA_PROBS = "dsa_probs"
 LM_TRAIN_STEP = "hvd_lm_train_step"
 LM_PIPELINED_TRAIN_STEP = "hvd_lm_pipelined_train_step"
 TRAIN_STEP = "hvd_train_step"
+# The compile ledger (telemetry/spans.py) gives a program's rows under
+# one of these ``role = step``.
+STEP_NAMES = frozenset({LM_TRAIN_STEP, LM_PIPELINED_TRAIN_STEP, TRAIN_STEP})
 
 
 def named(fn, name: str):

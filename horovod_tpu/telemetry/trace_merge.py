@@ -100,9 +100,12 @@ def spans_doc_to_events(doc: dict, apply_offset: bool = True,
             "ph": "X", "pid": rank, "tid": tid,
             "ts": int(t0 * 1e6),
             "dur": max(int((t1 - t0) * 1e6), 1),
-            "args": {"trace_id": s.get("trace_id"),
-                     "phase": s.get("phase"), "seq": s.get("seq"),
-                     "bytes": s.get("bytes", 0)},
+            # A phase span's optional fields ride along (its parent's
+            # seq, its attributes).
+            "args": dict({"trace_id": s.get("trace_id"),
+                          "phase": s.get("phase"), "seq": s.get("seq"),
+                          "bytes": s.get("bytes", 0)},
+                         **{k: s[k] for k in ("parent", "attrs") if k in s}),
         })
     return events
 

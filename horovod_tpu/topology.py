@@ -20,6 +20,8 @@ import numpy as np
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
+from horovod_tpu import telemetry
+
 
 def build_mesh(axes: Sequence[str] = ("data",),
                shape: Optional[Tuple[int, ...]] = None,
@@ -33,6 +35,15 @@ def build_mesh(axes: Sequence[str] = ("data",),
       the trailing axes to ICI) and any failure there is raised; only
       CPU devices are reshaped in rank order.
     """
+    with telemetry.span("build_mesh", axes=tuple(axes)) as phase:
+        mesh = _build_mesh(axes, shape, devices)
+        phase.attrs.update(shape=tuple(mesh.devices.shape),
+                           devices=int(mesh.devices.size),
+                           platform=mesh.devices.flat[0].platform)
+        return mesh
+
+
+def _build_mesh(axes, shape, devices) -> Mesh:
     devices = list(jax.devices()) if devices is None else list(devices)
     n = len(devices)
     axes = tuple(axes)
@@ -68,7 +79,7 @@ def build_mesh(axes: Sequence[str] = ("data",),
         warnings.warn(
             f"build_mesh: shape {shape} covers {want} of {n} available "
             f"devices; using the first {want} (rank-order prefix)",
-            stacklevel=2)
+            stacklevel=3)
         devices = devices[:want]
         n = want
     if want != n:

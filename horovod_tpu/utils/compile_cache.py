@@ -25,7 +25,15 @@ def enable_compile_cache() -> str:
     """
     import jax
 
+    from horovod_tpu import telemetry
+
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir",
                           os.path.join(CHECKOUT, ".jax_cache"))
-    return jax.config.jax_compilation_cache_dir
+    directory = jax.config.jax_compilation_cache_dir
+    # What the cache holds now, against its cap (JAX's own option, -1
+    # where JAX_COMPILATION_CACHE_MAX_SIZE is not set): a cell whose
+    # programs outgrow the cap compiles everything on every run.
+    cap = jax.config.jax_compilation_cache_max_size
+    telemetry.cache_found(directory, cap if cap >= 0 else None)
+    return directory
