@@ -29,7 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.models import parts
 from horovod_tpu.models.parts import dense, ones, rmsnorm, whole
-from horovod_tpu.ops import mla_assemble, sparse_attention
+from horovod_tpu.ops import mla_assemble, qk_assemble, sparse_attention
 from horovod_tpu.ops.flash_attention import (flash_attention,
                                              flash_attention_folded)
 from horovod_tpu.parallel import sequence as seq_mod
@@ -53,12 +53,36 @@ def rotary(x, positions, theta: float):
                            axis=-1).astype(x.dtype)
 
 
-def qkv_proj(x, layer, cfg, model_axis, positions=None, normed=None):
+def qk_path(h, cfg, ctx, sparse: bool = False) -> str:
+    """What norms a head at a time, rotates and lays out the heads of a
+    plain (``sparse``: a sparse) attention layer of ``cfg`` from the
+    projections of ``h`` [B, T, d] under ``ctx``: ``"kernel"``, the Pallas
+    kernels of :mod:`horovod_tpu.ops.qk_assemble`, which write q, k, v in
+    the attention kernels' layout, so only where those consume them (the
+    single-device flash route; the sparse route by its kernels), for a
+    per-head norm with rotary positions, no model axis and sizes
+    ``qk_assemble.takes`` accepts; ``"xla"``, :func:`qkv_proj`'s own
+    lines, everywhere else."""
+    consumed = (sparse_attention.path(h) == "kernel" if sparse
+                else _flash_route(ctx, h.shape[1]))
+    return "kernel" if (
+        cfg.qk_norm_per_head and cfg.positions == "rope"
+        and ctx.model_axis is None and consumed and qk_assemble.takes(
+            h, cfg.n_heads, cfg.kv_heads, cfg.head_dim)) else "xla"
+
+
+def qkv_proj(x, layer, cfg, model_axis, positions=None, normed=None,
+             path: str = "xla", share_kv: bool = False):
     """rmsnorm -> q/k/v projections -> (QK-norm) -> head split ->
     (rotary at ``positions`` [T]) (shared by forward, decode_step and
     forward_pipelined so the projection math cannot drift).  Returns q,
     k, v with a trailing [heads, head_dim] split.  ``normed``: the normed
-    ``x`` where the caller has it already (it hands it to an indexer too)."""
+    ``x`` where the caller has it already (it hands it to an indexer too).
+    With ``path`` ``"kernel"`` (:func:`qk_path`) q ``[B * heads, T,
+    head_dim]`` and k, v ``[B * kv_heads, T, head_dim]``, the attention
+    kernels' layout (``share_kv``: each key-value head once a query head
+    that reads it, ``[B * heads, T, head_dim]``, as :func:`_share_kv_heads`
+    makes them for the flash kernels)."""
     dt = cfg.dtype
     h = (rmsnorm(x, layer["ln1_scale"], cfg.norm_eps) if normed is None
          else normed)
@@ -70,6 +94,13 @@ def qkv_proj(x, layer, cfg, model_axis, positions=None, normed=None):
         q = rmsnorm(q, layer["q_norm_scale"], cfg.norm_eps)
         k = rmsnorm(k, layer["k_norm_scale"], cfg.norm_eps)
     dh = q.shape[-1]
+    if path == "kernel":
+        with jax.named_scope(scopes.QK_HEAD_NORM_ROPE):
+            q, k, v = qk_assemble.qk_assemble(
+                q, k, v, layer["q_norm_scale"], layer["k_norm_scale"],
+                positions, cfg.n_heads, cfg.rope_theta, cfg.norm_eps,
+                repeat=share_kv)
+        return q, k, v, dh
 
     def heads(a):
         return a.reshape(a.shape[:-1] + (a.shape[-1] // cfg.head_dim,
@@ -275,6 +306,29 @@ def _routed(q, k, v, dh, x, layer, cfg, ctx):
                         ctx.model_axis)
 
 
+def _folded_out(o, x, layer, cfg):
+    """The out projection and the residual for ``o`` as the attention
+    kernels leave it, ``[B * heads, T, head_dim]`` (or any shape of the
+    same bytes that ends ``[T, head_dim]``): it contracts over (head,
+    width)."""
+    heads, hd = cfg.n_heads, cfg.head_dim
+    with jax.named_scope(scopes.ATTN_OUT):
+        o = jnp.einsum(
+            "bhtd,hdm->btm", o.reshape((-1, heads) + o.shape[-2:]),
+            layer["wo"].astype(cfg.dtype).reshape(heads, hd, -1))
+        return x + o
+
+
+def _folded_flash(q, k, v, x, layer, cfg, ctx):
+    """The flash route for heads born in the flash kernels' layout, q, k,
+    v ``[B * heads, T, head_dim]``: the kernels under the step's mask and
+    :func:`_folded_out`."""
+    mask = True if ctx.mask is None else ctx.mask
+    o = flash_attention_folded(q, k, v, cfg.n_heads, mask,
+                               segment_ids=ctx.segment_ids)
+    return _folded_out(o, x, layer, cfg)
+
+
 # --- plain attention --------------------------------------------------------
 
 def _validate(cfg, used):
@@ -308,9 +362,20 @@ def _specs(cfg, model_axis):
 
 
 def _apply(x, layer, cfg, ctx):
+    path = qk_path(x, cfg, ctx)
     with jax.named_scope(scopes.ATTN_QKV):
-        q, k, v, dh = qkv_proj(x, layer, cfg, ctx.model_axis, ctx.positions)
-    return _routed(q, k, v, dh, x, layer, cfg, ctx), {}
+        q, k, v, dh = qkv_proj(x, layer, cfg, ctx.model_axis, ctx.positions,
+                               path=path, share_kv=True)
+    if path != "kernel":
+        return _routed(q, k, v, dh, x, layer, cfg, ctx), {}
+    return _folded_flash(q, k, v, x, layer, cfg, ctx), {}
+
+
+def _record(name, x, layer, cfg, ctx, sparse: bool = False):
+    if cfg.qk_norm_per_head:
+        batch, t = x.shape[:2]
+        qk_assemble.record_rows(name, batch * t,
+                                qk_path(x, cfg, ctx, sparse))
 
 
 # QK-norm's statistics span the whole projection, which the model axis
@@ -322,6 +387,7 @@ ATTENTION = parts.Part(
     name="attention",
     fields=("n_kv_heads", "qk_norm", "qk_norm_per_head", "head_width"),
     validate=_validate, init=_init, specs=_specs, apply=_apply,
+    record=_record,
     unsupported={"model_axis": _NOT_SPLIT,
                  "seq_axis": ("head_width", "qk_norm_per_head")})
 
@@ -391,16 +457,7 @@ def _latent_apply(x, layer, cfg, ctx):
             ctx.positions, path)
     if path != "kernel":
         return _routed(q, k, v, dh, x, layer, cfg, ctx), {}
-    # The heads were born in the flash kernels' layout, and the out
-    # projection contracts over (head, width) as they leave it.
-    heads, hd = cfg.n_heads, cfg.head_dim
-    o = flash_attention_folded(q, k, v, heads, True,
-                               segment_ids=ctx.segment_ids)
-    with jax.named_scope(scopes.ATTN_OUT):
-        o = jnp.einsum(
-            "bhtd,hdm->btm", o.reshape((-1, heads) + o.shape[1:]),
-            layer["wo"].astype(cfg.dtype).reshape(heads, hd, -1))
-        return x + o, {}
+    return _folded_flash(q, k, v, x, layer, cfg, ctx), {}
 
 
 def _latent_record(name, x, layer, cfg, ctx):
@@ -461,15 +518,20 @@ def _sparse_specs(cfg, model_axis):
 def _sparse_apply(x, layer, cfg, ctx):
     # The route of its own: the indexer chooses each query's keys
     # (ops/sparse_attention.py), whatever ``ctx.attention`` says.
+    path = qk_path(x, cfg, ctx, sparse=True)
+    folded = path == "kernel"
     with jax.named_scope(scopes.ATTN_QKV):
         u = rmsnorm(x, layer["ln1_scale"], cfg.norm_eps)
         q, k, v, dh = qkv_proj(x, layer, cfg, ctx.model_axis, ctx.positions,
-                               normed=u)
+                               normed=u, path=path)
         qi, ki, w = indexer_proj(u, layer, cfg, ctx.positions)
     with jax.named_scope(scopes.ATTN_FLASH):
         o, kl = sparse_attention.dsa_attention(
             q, k, v, qi, ki, w, topk=cfg.index_topk,
-            index_scale=(cfg.index_heads * cfg.index_head_dim) ** -0.5)
+            index_scale=(cfg.index_heads * cfg.index_head_dim) ** -0.5,
+            folded=folded)
+    if folded:
+        return _folded_out(o, x, layer, cfg), {"index_kl": jnp.sum(kl)}
     with jax.named_scope(scopes.ATTN_OUT):
         return (attn_out(o.reshape(o.shape[:2] + (dh,)), x, layer, cfg.dtype,
                          ctx.model_axis), {"index_kl": jnp.sum(kl)})
@@ -477,6 +539,7 @@ def _sparse_apply(x, layer, cfg, ctx):
 
 def _sparse_record(name, x, layer, cfg, ctx):
     sparse_attention.record_path(sparse_attention.path(x))
+    _record(name, x, layer, cfg, ctx, sparse=True)
 
 
 # Not written: a query's selected keys lie on other chips under a sequence

@@ -56,17 +56,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from horovod_tpu import telemetry
 from horovod_tpu.ops.grouped_matmul import _interpret, _vma
-from horovod_tpu.ops.selective_scan import LANES, VMEM_LIMIT
+# (TILE and VMEM_LIMIT: what ``tiles`` is held to, named here for its
+# readers.)
+from horovod_tpu.ops.head_major import (COMPILER_PARAMS, TILE, rotate,  # noqa: F401
+                                        tables, token_tile)
+from horovod_tpu.ops.selective_scan import LANES, VMEM_LIMIT  # noqa: F401
 from horovod_tpu.telemetry import scopes
-
-# Tokens a grid step holds at most (docs/kernels.md, "Latent attention's
-# assembly"), and the least: a 16-bit dtype's sublane tile.
-TILE = 128
-ROWS = 16
 
 _F32 = jnp.float32
 
@@ -84,22 +82,17 @@ def vmem_bytes(tile: int, heads: int, hd: int, rope: int,
 
 def tiles(t: int, heads: int, hd: int, rope: int, itemsize: int = 2):
     """Tokens a grid step holds for ``t`` tokens of ``heads`` heads of
-    ``hd`` (``rope`` of them rotary): the largest power of two from
-    :data:`ROWS` up to :data:`TILE` that divides ``t`` and that
-    :data:`VMEM_LIMIT` holds.  None where the kernels cannot run these
-    sizes: a head has to be whole registers wide (the flash kernels'
-    blocks), the rotary part an even tail of at most one register and not
-    the whole head, the length whole sublane tiles of a 16-bit dtype."""
+    ``hd`` (``rope`` of them rotary): :func:`head_major.token_tile`'s of
+    :func:`vmem_bytes`, up to :data:`TILE` and within :data:`VMEM_LIMIT`.
+    None where the kernels cannot run these sizes: a head has to be whole
+    registers wide (the flash kernels' blocks), the rotary part an even
+    tail of at most one register and not the whole head, the length whole
+    sublane tiles of a 16-bit dtype."""
     if (heads <= 0 or hd <= 0 or hd % LANES or rope <= 0 or rope % 2
-            or rope > LANES or rope >= hd or t <= 0 or t % ROWS):
+            or rope > LANES or rope >= hd):
         return None
-    tile = TILE
-    while tile >= ROWS:
-        if t % tile == 0 and vmem_bytes(tile, heads, hd, rope,
-                                        itemsize) <= VMEM_LIMIT:
-            return tile
-        tile //= 2
-    return None
+    return token_tile(
+        t, lambda tile: vmem_bytes(tile, heads, hd, rope, itemsize))
 
 
 def takes(h, heads: int, hd: int, rope: int) -> bool:
@@ -113,15 +106,6 @@ def takes(h, heads: int, hd: int, rope: int) -> bool:
             and not (_interpret(h) and _vma(h)))
 
 
-def _rotate(x, cos, sin):
-    """``rotary``'s lines on the float32 ``x`` [tile, rope]; the inverse
-    (its transpose) is the same with ``-sin``."""
-    half = x.shape[-1] // 2
-    x1, x2 = x[:, :half], x[:, half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1)
-
-
 def _fwd_kernel(q_ref, up_ref, kr_ref, cos_ref, sin_ref, qo_ref, ko_ref,
                 vo_ref):
     heads, _, hd = qo_ref.shape
@@ -129,11 +113,11 @@ def _fwd_kernel(q_ref, up_ref, kr_ref, cos_ref, sin_ref, qo_ref, ko_ref,
     nope = hd - rope
     cos, sin = cos_ref[...], sin_ref[...]
     dt = qo_ref.dtype
-    k_r = _rotate(kr_ref[...].astype(_F32), cos, sin).astype(dt)
+    k_r = rotate(kr_ref[...].astype(_F32), cos, sin).astype(dt)
     for h in range(heads):
         at, up_at = h * hd, h * (nope + hd)
         qo_ref[h, :, :nope] = q_ref[:, at:at + nope]
-        qo_ref[h, :, nope:] = _rotate(
+        qo_ref[h, :, nope:] = rotate(
             q_ref[:, at + nope:at + hd].astype(_F32), cos, sin).astype(dt)
         ko_ref[h, :, :nope] = up_ref[:, up_at:up_at + nope]
         ko_ref[h, :, nope:] = k_r
@@ -151,12 +135,12 @@ def _bwd_kernel(dq_ref, dk_ref, dv_ref, cos_ref, sin_ref, dqp_ref, dup_ref,
     for h in range(heads):
         at, up_at = h * hd, h * (nope + hd)
         dqp_ref[:, at:at + nope] = dq_ref[h, :, :nope]
-        dqp_ref[:, at + nope:at + hd] = _rotate(
+        dqp_ref[:, at + nope:at + hd] = rotate(
             dq_ref[h, :, nope:].astype(_F32), cos, sin).astype(dt)
         dup_ref[:, up_at:up_at + nope] = dk_ref[h, :, :nope]
         dup_ref[:, up_at + nope:up_at + nope + hd] = dv_ref[h]
         d_kr = d_kr + dk_ref[h, :, nope:].astype(_F32)
-    dkr_ref[...] = _rotate(d_kr, cos, sin)
+    dkr_ref[...] = rotate(d_kr, cos, sin)
 
 
 def _specs(tile: int, heads: int, hd: int, rope: int):
@@ -167,11 +151,6 @@ def _specs(tile: int, heads: int, hd: int, rope: int):
 
     return (rows, pl.BlockSpec((heads, tile, hd), lambda b, t: (b, t, 0)),
             pl.BlockSpec((tile, rope // 2), lambda b, t: (t, 0)))
-
-
-_COMPILER_PARAMS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel"),
-    vmem_limit_bytes=VMEM_LIMIT)
 
 
 # The calls are jitted with what is static among their arguments, and
@@ -194,7 +173,7 @@ def _fwd_call(q_proj, up, k_r, cos, sin, *, heads: int, tile: int,
         in_specs=[rows(wide), rows(up.shape[-1]), rows(rope), table, table],
         out_specs=[folded, folded, folded],
         interpret=interpret, name=scopes.MLA_ASSEMBLE_FWD,
-        compiler_params=_COMPILER_PARAMS,
+        compiler_params=COMPILER_PARAMS,
     )(q_proj, up, k_r, cos, sin)
 
 
@@ -217,20 +196,12 @@ def _bwd_call(dq, dk, dv, cos, sin, *, heads: int, tile: int,
         in_specs=[folded, folded, folded, table, table],
         out_specs=[rows(wide), rows(up_wide), rows(rope)],
         interpret=interpret, name=scopes.MLA_ASSEMBLE_BWD,
-        compiler_params=_COMPILER_PARAMS,
+        compiler_params=COMPILER_PARAMS,
     )(dq, dk, dv, cos, sin)
 
 
-def _tables(positions, rope: int, theta: float):
-    """``cos`` and ``sin`` [T, rope / 2] of ``rotary``'s angles, float32."""
-    half = rope // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=_F32) / half)
-    angles = positions.astype(_F32)[:, None] * inv_freq[None, :]
-    return jnp.cos(angles), jnp.sin(angles)
-
-
 def _forward(q_proj, up, k_r, positions, heads, rope, theta, tile):
-    cos, sin = _tables(positions, rope, theta)
+    cos, sin = tables(positions, rope, theta)
     return tuple(_fwd_call(q_proj, up, k_r, cos, sin, heads=heads, tile=tile,
                            interpret=_interpret(q_proj)))
 
@@ -246,7 +217,7 @@ def _assemble_fwd(q_proj, up, k_r, positions, heads, rope, theta, tile):
 
 def _assemble_bwd(heads, rope, theta, tile, positions, cotangents):
     dq, dk, dv = cotangents
-    cos, sin = _tables(positions, rope, theta)
+    cos, sin = tables(positions, rope, theta)
     d_q, d_up, d_kr = _bwd_call(dq, dk, dv, cos, sin, heads=heads, tile=tile,
                                 interpret=_interpret(dq))
     return (d_q, d_up, d_kr.astype(dq.dtype),

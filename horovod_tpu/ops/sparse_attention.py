@@ -905,21 +905,18 @@ def _fold_kv(k):
     return k.transpose(0, 2, 1, 3).reshape(b * h, t, d)
 
 
-def _unfold_kv(x, b):
-    bh, t, d = x.shape
-    return x.reshape(b, bh // b, t, d).transpose(0, 2, 1, 3)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
-def masked_attention(q, k, v, scores, lse_i, mask, mask_t, topk: int,
-                     scale: float, interpret: bool):
+def masked_attention_folded(q, k, v, scores, lse_i, mask, mask_t, topk: int,
+                            scale: float, interpret: bool):
     """Softmax attention over the keys ``mask`` [B, T, T] int8 selects and
-    the indexer's loss a query, by the Pallas kernels: q [B, T, H, D], k/v
-    [B, T, Hkv, D]; ``scores`` [B, T, T] float32 the indexer's, ``lse_i``
-    [B, T] their log-sum-exp over each row's selection (:func:`select`'s
-    second result; a constant here); ``mask_t`` the mask's transpose (the
-    dK/dV kernel reads it keys by rows); rows under ``topk`` select every
-    earlier key.  Returns ``(o [B, T, H, D], kl [B, T])``, ``kl[t] =
+    the indexer's loss a query, by the Pallas kernels, on operands in the
+    kernels' layout: q [B * Hkv, G, T, D] (the ``G`` query heads of a
+    key-value head together; the bytes of ``[B * H, T, D]``), k/v [B * Hkv,
+    T, D]; ``scores`` [B, T, T] float32 the indexer's, ``lse_i`` [B, T]
+    their log-sum-exp over each row's selection (:func:`select`'s second
+    result; a constant here); ``mask_t`` the mask's transpose (the dK/dV
+    kernel reads it keys by rows); rows under ``topk`` select every
+    earlier key.  Returns ``(o [B * Hkv, G, T, D], kl [B, T])``, ``kl[t] =
     KL(mean over the heads of the attention's probabilities || softmax
     over the selection of scores[t, .])``.
 
@@ -932,17 +929,16 @@ def masked_attention(q, k, v, scores, lse_i, mask, mask_t, topk: int,
                        interpret)[0]
 
 
-def _masked_fwd(q, k, v, scores, lse_i, mask, mask_t, topk, scale,
+def _masked_fwd(qf, kf, vf, scores, lse_i, mask, mask_t, topk, scale,
                 interpret):
-    b, t, h, d = q.shape
-    hkv = k.shape[2]
-    group = h // hkv
+    bh, group, t, d = qf.shape
+    b = mask.shape[0]
+    hkv = bh // b
     block = attention_block(t)
-    qf, kf, vf = _fold_q(q, hkv), _fold_kv(k), _fold_kv(v)
     operands = (qf, kf, vf, mask)
-    _record_tiles(scopes.DSA_FWD, b * hkv, t, block, block, topk)
-    of, lse = _fwd_call(b * hkv, hkv, group, t, d, q.dtype, scale, block,
-                        block, topk, interpret, _out_vma(*operands))(*operands)
+    _record_tiles(scopes.DSA_FWD, bh, t, block, block, topk)
+    of, lse = _fwd_call(bh, hkv, group, t, d, qf.dtype, scale, block, block,
+                        topk, interpret, _out_vma(*operands))(*operands)
     with jax.named_scope(scopes.DSA_INDEX_LOSS):
         # Nothing the backward pass needs comes out of this call: under
         # jax.checkpoint the recomputed forward holds it as dead code.
@@ -950,18 +946,16 @@ def _masked_fwd(q, k, v, scores, lse_i, mask, mask_t, topk, scale,
         _record_pass(scopes.DSA_PROBS)
         kl = _probs_call(b, hkv, group, t, d, scale, block, block, interpret,
                          _out_vma(*operands))(*operands)[:, 0] + lse_i
-    return ((_unfold_q(of, b), kl),
+    return ((of, kl),
             (qf, kf, vf, of, lse, scores, lse_i, mask, mask_t))
 
 
 def _masked_bwd(topk, scale, interpret, res, cotangents):
-    do, dkl = cotangents
+    dof, dkl = cotangents
     qf, kf, vf, of, lse, scores, lse_i, mask, mask_t = res
     bh, group, t, d = qf.shape
-    b = mask.shape[0]
-    hkv = bh // b
+    hkv = bh // mask.shape[0]
     block = attention_block(t)
-    dof = _fold_q(do, hkv)
     delta = jnp.sum(dof.astype(jnp.float32) * of.astype(jnp.float32),
                     axis=-1)                             # [BH, G, T]
     config = (bh, hkv, group, t, d, qf.dtype, scale, block, block, topk,
@@ -976,11 +970,23 @@ def _masked_bwd(topk, scale, interpret, res, cotangents):
     dk, dv = _bwd_dkv_call(*config, _out_vma(*operands, mask_t))(
         *operands, mask_t)
     nothing = np.zeros(mask.shape, jax.dtypes.float0)
-    return (_unfold_q(dq, b), _unfold_kv(dk, b), _unfold_kv(dv, b), g,
-            jnp.zeros_like(lse_i), nothing, nothing)
+    return dq, dk, dv, g, jnp.zeros_like(lse_i), nothing, nothing
 
 
-masked_attention.defvjp(_masked_fwd, _masked_bwd)
+masked_attention_folded.defvjp(_masked_fwd, _masked_bwd)
+
+
+def masked_attention(q, k, v, scores, lse_i, mask, mask_t, topk: int,
+                     scale: float, interpret: bool):
+    """:func:`masked_attention_folded` for q [B, T, H, D], k/v [B, T, Hkv,
+    D]: the moves to the kernels' layout, the same kernels under the same
+    gradient rule, and the move back.  Returns ``(o [B, T, H, D], kl [B,
+    T])``."""
+    hkv = k.shape[2]
+    of, kl = masked_attention_folded(
+        _fold_q(q, hkv), _fold_kv(k), _fold_kv(v), scores, lse_i, mask,
+        mask_t, topk, scale, interpret)
+    return _unfold_q(of, q.shape[0]), kl
 
 
 # ---------------------------------------------------------------------------
@@ -1017,7 +1023,7 @@ def indexer_selection(qi, ki, w, *, topk: int, index_scale: float,
 
 def dsa_attention(q, k, v, qi, ki, w, *, topk: int, index_scale: float,
                   kernels: Optional[bool] = None,
-                  interpret: Optional[bool] = None):
+                  interpret: Optional[bool] = None, folded: bool = False):
     """Sparse attention and its indexer's loss.
 
     q [B, T, H, D], k/v [B, T, Hkv, D]: the attention's operands; qi [B, T,
@@ -1027,10 +1033,16 @@ def dsa_attention(q, k, v, qi, ki, w, *, topk: int, index_scale: float,
     keys, and each query's ``KL(head-mean probabilities || softmax of the
     indexer's scores)`` over them.  ``o``'s gradient reaches q, k and v
     alone, ``kl``'s qi, ki and w alone.  ``kernels`` (default: on a TPU)
-    picks the Pallas kernels over their ``jax.numpy`` forms."""
+    picks the Pallas kernels over their ``jax.numpy`` forms.  ``folded``:
+    q [B * H, T, D] and k/v [B * Hkv, T, D] were born in the kernels'
+    layout (:mod:`horovod_tpu.ops.qk_assemble`), and ``o`` leaves in it;
+    the kernels' alone."""
     scale = q.shape[-1] ** -0.5
     if kernels is None:
         kernels = path(q) == "kernel"
+    if folded and not kernels:
+        raise ValueError("folded operands are the kernels': the jax.numpy "
+                         "forms take [B, T, H, D]")
     interp = _interpret_default(q) if interpret is None else interpret
     scores, mask, lse_i = _selection(qi, ki, w, topk, index_scale, kernels,
                                      interp)
@@ -1042,5 +1054,10 @@ def dsa_attention(q, k, v, qi, ki, w, *, topk: int, index_scale: float,
     with jax.named_scope(scopes.DSA_SELECT):
         mask_t = jnp.swapaxes(mask, 1, 2)
     with jax.named_scope(scopes.DSA_FLASH):
-        return masked_attention(q, k, v, scores, lse_i, mask, mask_t, topk,
-                                scale, interp)
+        if not folded:
+            return masked_attention(q, k, v, scores, lse_i, mask, mask_t,
+                                    topk, scale, interp)
+        o, kl = masked_attention_folded(
+            q.reshape((k.shape[0], -1) + q.shape[1:]), k, v, scores, lse_i,
+            mask, mask_t, topk, scale, interp)
+        return o.reshape(q.shape), kl

@@ -166,6 +166,11 @@ MAMBA_GATE_BWD = "mamba_gate_bwd"
 MLA_ASSEMBLE_FWD = "mla_assemble_fwd"
 MLA_ASSEMBLE_BWD = "mla_assemble_bwd"
 
+# The two Pallas kernels of plain attention's assembly
+# (ops/qk_assemble.py); they run under QK_HEAD_NORM_ROPE.
+QK_ASSEMBLE_FWD = "qk_assemble_fwd"
+QK_ASSEMBLE_BWD = "qk_assemble_bwd"
+
 # The two short-convolution Pallas kernels (ops/short_conv.py); they run
 # under GDN_CONV, SSM_CONV and MAMBA_CONV.
 SHORT_CONV_FWD = "short_conv_fwd"

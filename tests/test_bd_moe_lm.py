@@ -193,6 +193,39 @@ def _batch(cfg, batch=2, seq=32, seed=1):
         jax.random.PRNGKey(seed + 1), batch, seq, cfg.diffusion_block)
 
 
+def test_heads_born_in_the_kernels_layout_give_the_same_step(monkeypatch):
+    """The tiny step at the least width ``qk_assemble`` takes (heads of a
+    register, bfloat16), the flash route: with the heads normed, rotated
+    and laid out by the kernels (in the interpreter; named in the lowered
+    text beside the three flash kernels, and no repeat of K and V outside
+    them) the loss and every checked leaf are ``qkv_proj``'s lines' within
+    this table's bfloat16 tolerances (3e-3 the loss; a leaf far inside its
+    0.3)."""
+    from horovod_tpu.ops import qk_assemble
+    from horovod_tpu.telemetry import scopes
+
+    cfg = dataclasses.replace(SDAR_TINY, head_width=128, dtype=jnp.bfloat16)
+    params, batch = _params(cfg), _batch(cfg)
+
+    def step():
+        return jax.jit(jax.value_and_grad(
+            lambda p: tfm.diffusion_loss_fn(p, *batch, cfg, "flash")))
+
+    text = step().lower(params).as_text(debug_info=True)
+    for name in (scopes.QK_ASSEMBLE_FWD, scopes.QK_ASSEMBLE_BWD,
+                 scopes.FLASH_FWD, scopes.FLASH_BWD_DQ, scopes.FLASH_BWD_DKV):
+        assert name in text, name
+    got, got_grads = step()(params)
+    monkeypatch.setattr(qk_assemble, "takes", lambda *a: False)
+    assert scopes.QK_ASSEMBLE_FWD not in step().lower(params).as_text(
+        debug_info=True)
+    want, want_grads = step()(params)
+    assert np.isfinite(float(got)) and _rel(got, want) <= 3e-3
+    for name, path in reference.leaf_paths(cfg.n_layers).items():
+        assert _rel(reference.leaf(got_grads, path),
+                    reference.leaf(want_grads, path)) <= 2e-2, name
+
+
 def test_the_doubled_stream_is_the_objective_block_by_block():
     """One pass over 2 L positions gives what K passes give, one for each
     block over [the clean blocks before it; the block noised] with no

@@ -318,6 +318,76 @@ def test_the_route_by_kernels_is_the_route_by_jnp(monkeypatch):
         assert _rel(g, w) <= 2e-5, name
 
 
+def test_the_folded_entry_is_the_entry_by_heads_bit_for_bit(monkeypatch):
+    """``masked_attention_folded`` on operands in the kernels' layout (as
+    ``qk_assemble`` writes them) against ``masked_attention`` on [B, T, H,
+    D]: the same kernels on the same bytes, so ``o``, the rows' KL and the
+    four gradients are equal, not close."""
+    monkeypatch.setattr(sa, "attention_block", lambda t: 64)
+    x = _operands(4)
+    b, hkv = x["q"].shape[0], x["k"].shape[2]
+    scores, mask, lse_i = _tied_selection(x)
+    rest = (lse_i, mask, jnp.swapaxes(mask, 1, 2), 32, 32 ** -0.5, True)
+    rows = jax.random.uniform(jax.random.PRNGKey(5), (2, 128)) + 0.5
+
+    (o, kl), pull = jax.vjp(
+        lambda q, k, v, s: sa.masked_attention(q, k, v, s, *rest),
+        x["q"], x["k"], x["v"], scores)
+    (of, klf), pull_folded = jax.vjp(
+        lambda q, k, v, s: sa.masked_attention_folded(q, k, v, s, *rest),
+        sa._fold_q(x["q"], hkv), sa._fold_kv(x["k"]), sa._fold_kv(x["v"]),
+        scores)
+    assert of.shape == (b * hkv, 2, 128, 32)
+    assert bool(jnp.all(sa._unfold_q(of, b) == o))
+    assert bool(jnp.all(klf == kl))
+    dq, dk, dv, ds = pull((x["ct"], rows))
+    dqf, dkf, dvf, dsf = pull_folded((sa._fold_q(x["ct"], hkv), rows))
+    assert bool(jnp.all(dqf == sa._fold_q(dq, hkv)))
+    assert bool(jnp.all(dkf == sa._fold_kv(dk)))
+    assert bool(jnp.all(dvf == sa._fold_kv(dv)))
+    assert bool(jnp.all(dsf == ds))
+    with pytest.raises(ValueError, match="folded operands"):
+        sa.dsa_attention(x["q"], x["k"], x["v"], x["qi"], x["ki"], x["w"],
+                         topk=32, index_scale=0.125, kernels=False,
+                         folded=True)
+
+
+def test_heads_born_in_the_kernels_layout_give_the_same_step(monkeypatch):
+    """The tiny step at the least width ``qk_assemble`` takes (heads of a
+    register, bfloat16) with the sparse route's kernels traced in the
+    interpreter: with the heads normed, rotated and laid out by the
+    assembly's kernels and handed to the folded entry (named in the
+    lowered text beside the seven ``dsa_*`` kernels) the loss and every
+    checked leaf are ``qkv_proj``'s lines' through the entry by heads,
+    within this table's bfloat16 tolerances (3e-3 the loss; a leaf far
+    inside its 0.3)."""
+    from horovod_tpu.ops import qk_assemble
+    from horovod_tpu.telemetry import scopes
+
+    cfg = dataclasses.replace(KEYE_TINY, head_width=128, dtype=jnp.bfloat16)
+    params, (tokens, labels) = _params(cfg), _batch(cfg)
+    monkeypatch.setattr(sa, "path", lambda x: "kernel")
+    monkeypatch.setattr(sa, "attention_block", lambda t: 64)
+
+    def step():
+        return jax.jit(jax.value_and_grad(
+            lambda p: tfm.loss_fn(p, tokens, labels, cfg)))
+
+    text = step().lower(params).as_text(debug_info=True)
+    for name in (scopes.QK_ASSEMBLE_FWD, scopes.QK_ASSEMBLE_BWD,
+                 scopes.DSA_FWD, scopes.DSA_BWD_DQ, scopes.DSA_BWD_DKV):
+        assert name in text, name
+    got, got_grads = step()(params)
+    monkeypatch.setattr(qk_assemble, "takes", lambda *a: False)
+    assert scopes.QK_ASSEMBLE_FWD not in step().lower(params).as_text(
+        debug_info=True)
+    want, want_grads = step()(params)
+    assert np.isfinite(float(got)) and _rel(got, want) <= 3e-3
+    for name, path in reference.leaf_paths(cfg.n_layers).items():
+        assert _rel(reference.leaf(got_grads, path),
+                    reference.leaf(want_grads, path)) <= 2e-2, name
+
+
 def _kernel_calls(jaxpr, found):
     """Every ``pallas_call`` of ``jaxpr`` and of the jaxprs inside it by
     name, and under ``"float32 [B, T, T]"`` the primitives of the

@@ -381,6 +381,54 @@ def test_mla_assemble_kernels_compile_for_the_v5e(one_chip, t, heads, hd,
         assert len(re.findall(rf"%{name}[.\d]* = ", text)) == 1, name
 
 
+# sdar30b_bd8k's and keyevl2_t16k's assembly: 32 heads over 4 key-value
+# heads of 128 over 16384 positions in tiles of 512, each key-value head
+# written once (the sparse route's kernels read the group in place) and once
+# a query head (the flash kernels want equal counts), and the least widths.
+@pytest.mark.parametrize("t,heads,kv_heads,hd,copies", [
+    (16384, 32, 4, 128, 1), (16384, 32, 4, 128, 8), (2048, 2, 1, 256, 2)],
+    ids=["keyevl2", "sdar30b", "least"])
+def test_qk_assemble_kernels_compile_for_the_v5e(one_chip, t, heads,
+                                                 kv_heads, hd, copies):
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import qk_assemble as op
+    from horovod_tpu.ops.selective_scan import VMEM_LIMIT
+    from horovod_tpu.telemetry import scopes
+
+    tile = op.tiles(t, heads, kv_heads, hd)
+    assert tile == op.TILE == 512
+    assert op.vmem_bytes(tile, heads, kv_heads, hd) <= VMEM_LIMIT
+    assert op.takes(jnp.zeros((1, t, 8), jnp.bfloat16), heads, kv_heads, hd)
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    table = shape(t, hd // 2, dtype=jnp.float32)
+    scale = shape(1, hd, dtype=jnp.float32)
+    q_proj, kv_proj = shape(1, t, heads * hd), shape(1, t, kv_heads * hd)
+    dq, dkv = shape(heads, t, hd), shape(kv_heads * copies, t, hd)
+
+    # The calls themselves, told to compile: the public function reads
+    # the executing mesh, and this process's is the CPU.
+    def fwd_and_grads(q_proj, k_proj, v_proj, q_scale, k_scale, cos, sin,
+                      dq, dk, dv):
+        return (op._fwd_call(q_proj, k_proj, v_proj, q_scale, k_scale, cos,
+                             sin, heads=heads, copies=copies, eps=1e-6,
+                             tile=tile, interpret=False),
+                op._bwd_call(dq, dk, dv, q_proj, k_proj, q_scale, k_scale,
+                             cos, sin, eps=1e-6, tile=tile,
+                             interpret=False))
+
+    text = jax.jit(fwd_and_grads).lower(
+        q_proj, kv_proj, kv_proj, scale, scale, table, table, dq, dkv,
+        dkv).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for name in (scopes.QK_ASSEMBLE_FWD, scopes.QK_ASSEMBLE_BWD):
+        assert len(re.findall(rf"%{name}[.\d]* = ", text)) == 1, name
+
+
 # The short convolutions of one mixer layer: olmohybrid_t16k's q (or k: 30
 # heads of 96 with the L2 norm, written head-major) and v (30 heads of 192),
 # nemotron3s_t8192's xBC with its bias (three token-major outputs),

@@ -970,6 +970,28 @@ def test_train_step_takes_the_gradient_of_the_global_batch(hvd, lm_row,
                        g) <= row.step_rel, name
 
 
+@family(lambda row: [(remat, remat) for remat in ("none", "dots", "full")])
+def test_the_tiny_rows_keep_qkv_projs_own_lines(lm_row, case, monkeypatch):
+    """Every row's heads are narrower than a register, so plain attention's
+    assembly kernels (``ops/qk_assemble.py``) refuse them whatever else
+    holds (bfloat16, the flash route, no model axis): the rows' programs
+    are ``qkv_proj``'s own lines in their own order (PR 50 lowered
+    ``make_train_step`` of every row under the three ``remat`` at the
+    parent and at the change: the text hashed equal, PERF.md)."""
+    from horovod_tpu.ops import qk_assemble
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("the assembly's kernels on a tiny row")
+
+    monkeypatch.setattr(qk_assemble, "qk_assemble", refuse)
+    cfg = dataclasses.replace(lm_row.cfg, dtype=BF16)
+    assert cfg.head_dim % 128
+    jax.eval_shape(
+        lambda p, *batch: _loss_fn(cfg)(p, *batch, cfg, attention="flash",
+                                        remat=case),
+        tfm.init_abstract(cfg), *lm_row.batch())
+
+
 def one(row):
     return [("", None)]
 

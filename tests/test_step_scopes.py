@@ -586,6 +586,9 @@ def test_the_benchmark_reads_the_same_vocabulary():
                       scopes.MAMBA_GATE_FWD, scopes.MAMBA_GATE_BWD}
     # Latent attention's assembly: two kernels, booked by ``mla_rope``.
     mla_kernels = {scopes.MLA_ASSEMBLE_FWD, scopes.MLA_ASSEMBLE_BWD}
+    # Plain attention's assembly: two kernels, booked by
+    # ``qk_head_norm_rope``.
+    qk_kernels = {scopes.QK_ASSEMBLE_FWD, scopes.QK_ASSEMBLE_BWD}
     # Block diffusion's assembly of the two streams, a sub-scope of
     # ``embed`` read by ``perfbench/bd_reduce.py``.
     bd_parts = {scopes.DIFFUSION_ASSEMBLE}
@@ -601,7 +604,7 @@ def test_the_benchmark_reads_the_same_vocabulary():
             - moe_kernels - gdn_parts - gdn_kernels - ssm_parts
             - ssm_kernels - mla_parts - conv_kernels - norm_kernels
             - dsa_parts - dsa_kernels - mamba1_parts - mamba1_kernels
-            - mla_kernels - bd_parts)
+            - mla_kernels - qk_kernels - bd_parts)
     from perfbench import mamba1_reduce
     assert ({p.rsplit("/", 1)[-1] for p in mamba1_parts}
             == set(mamba1_reduce.PARTS))
@@ -631,6 +634,17 @@ def test_the_benchmark_reads_the_same_vocabulary():
         call = (f"jit(x)/{name % (scopes.LAYER % 3)}/{scopes.ATTN_QKV}/"
                 f"{scopes.MLA_ROPE}/{kernel}/pallas_call")
         assert mla_reduce.parts_of(call) == [scopes.MLA_ROPE]
+        assert scope_reduce.phase_of(call) == phase
+        assert scope_reduce.scope_of(call) == scopes.ATTN_QKV
+    # Plain attention's are booked where the per-head norm and the
+    # rotation were, by both reducers that read that part.
+    for phase, name, kernel in (
+            ("fwd", "jvp(%s)", scopes.QK_ASSEMBLE_FWD),
+            ("bwd", "transpose(jvp(%s))", scopes.QK_ASSEMBLE_BWD)):
+        call = (f"jit(x)/{name % (scopes.LAYER % 3)}/{scopes.ATTN_QKV}/"
+                f"{scopes.QK_HEAD_NORM_ROPE}/{kernel}/pallas_call")
+        assert bd_reduce.part_of(call) == scopes.QK_HEAD_NORM_ROPE
+        assert dsa_reduce.part_of(call) == scopes.QK_HEAD_NORM_ROPE
         assert scope_reduce.phase_of(call) == phase
         assert scope_reduce.scope_of(call) == scopes.ATTN_QKV
     from perfbench import gdn_reduce
