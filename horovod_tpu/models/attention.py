@@ -213,12 +213,13 @@ def _share_kv_heads(k, v, n_heads: int):
     return jnp.repeat(k, group, axis=-2), jnp.repeat(v, group, axis=-2)
 
 
-def attn_out(o_flat, x, layer, dt, model_axis):
-    """Output projection (row-parallel psum under TP) + residual."""
-    o = o_flat @ layer["wo"].astype(dt)
+def attn_out(o_flat, x, layer, cfg, model_axis):
+    """Output projection (row-parallel psum under TP), the sandwich's
+    second norm (``post_norm``) + residual."""
+    o = o_flat @ layer["wo"].astype(cfg.dtype)
     if model_axis:
         o = lax.psum(o, model_axis)
-    return x + o
+    return x + parts.post_normed(o, layer, "ln1_post_scale", cfg)
 
 
 _flash_declined_shapes: set = set()
@@ -302,8 +303,7 @@ def _routed(q, k, v, dh, x, layer, cfg, ctx):
         o = seq_mod.local_attention(q, k, v, causal=mask,
                                     segment_ids=segment_ids)
     with jax.named_scope(scopes.ATTN_OUT):
-        return attn_out(o.reshape(b, t, dh), x, layer, cfg.dtype,
-                        ctx.model_axis)
+        return attn_out(o.reshape(b, t, dh), x, layer, cfg, ctx.model_axis)
 
 
 def _folded_out(o, x, layer, cfg):
@@ -316,7 +316,7 @@ def _folded_out(o, x, layer, cfg):
         o = jnp.einsum(
             "bhtd,hdm->btm", o.reshape((-1, heads) + o.shape[-2:]),
             layer["wo"].astype(cfg.dtype).reshape(heads, hd, -1))
-        return x + o
+        return x + parts.post_normed(o, layer, "ln1_post_scale", cfg)
 
 
 def _folded_flash(q, k, v, x, layer, cfg, ctx):
@@ -350,6 +350,8 @@ def _init(k, cfg):
     if cfg.qk_norm_per_head:
         layer.update(q_norm_scale=ones(cfg.head_dim),
                      k_norm_scale=ones(cfg.head_dim))
+    if cfg.post_norm:
+        layer.update(ln1_post_scale=ones(d))
     return layer
 
 
@@ -358,6 +360,8 @@ def _specs(cfg, model_axis):
     specs = dict(whole("ln1_scale"), wq=col, wk=col, wv=col, wo=row)
     if cfg.qk_norm or cfg.qk_norm_per_head:
         specs.update(whole("q_norm_scale", "k_norm_scale"))
+    if cfg.post_norm:
+        specs.update(whole("ln1_post_scale"))
     return specs
 
 
@@ -468,7 +472,8 @@ def _latent_record(name, x, layer, cfg, ctx):
 # Not written: heads of a latent's up-projection over chips, and the
 # shared rotary key under the ring and Ulysses routes.
 LATENT_ATTENTION = parts.Part(
-    name="latent_attention", fields=_LATENT, validate=_latent_validate,
+    name="latent_attention", fields=_LATENT,
+    validate=parts.refuses_post_norm(_latent_validate, "latent attention"),
     init=_latent_init, specs=_latent_specs, apply=_latent_apply,
     record=_latent_record,
     unsupported={"model_axis": ("head_width",) + _LATENT,
@@ -533,7 +538,7 @@ def _sparse_apply(x, layer, cfg, ctx):
     if folded:
         return _folded_out(o, x, layer, cfg), {"index_kl": jnp.sum(kl)}
     with jax.named_scope(scopes.ATTN_OUT):
-        return (attn_out(o.reshape(o.shape[:2] + (dh,)), x, layer, cfg.dtype,
+        return (attn_out(o.reshape(o.shape[:2] + (dh,)), x, layer, cfg,
                          ctx.model_axis), {"index_kl": jnp.sum(kl)})
 
 
@@ -546,7 +551,9 @@ def _sparse_record(name, x, layer, cfg, ctx):
 # axis, a selection that stays inside a document, and the indexer's
 # weights over a model axis.
 SPARSE_ATTENTION = parts.Part(
-    name="sparse_attention", fields=_SPARSE, validate=_sparse_validate,
+    name="sparse_attention", fields=_SPARSE,
+    validate=parts.refuses_post_norm(_sparse_validate,
+                                     "learned sparse attention"),
     init=_sparse_init, specs=_sparse_specs, apply=_sparse_apply,
     record=_sparse_record,
     unsupported={"model_axis": _NOT_SPLIT + _SPARSE,

@@ -464,7 +464,8 @@ def _validate(cfg, used):
 # and the convolution's mask at a document boundary are ROADMAP R11; every
 # leaf is whole on every chip.
 PART = parts.Part(
-    name="linear_attention", fields=_FIELDS, validate=_validate,
+    name="linear_attention", fields=_FIELDS,
+    validate=parts.refuses_post_norm(_validate, "the gated delta rule"),
     init=lambda k, cfg: dict(init_layer(k[0], cfg, parts.dense),
                              ln1_scale=parts.ones(cfg.d_model)),
     specs=lambda cfg, model_axis: parts.whole("ln1_scale", *LEAVES),

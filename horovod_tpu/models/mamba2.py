@@ -366,7 +366,8 @@ def _validate(cfg, used):
 # As the linear mixer: the state crosses the sequence in order, and every
 # leaf is whole on every chip.
 PART = parts.Part(
-    name="mamba2", fields=_FIELDS, validate=_validate,
+    name="mamba2", fields=_FIELDS,
+    validate=parts.refuses_post_norm(_validate, "a Mamba-2 mixer"),
     init=lambda k, cfg: dict(init_layer(k[0], cfg, parts.dense),
                              ln1_scale=parts.ones(cfg.d_model)),
     specs=lambda cfg, model_axis: parts.whole("ln1_scale", *LEAVES),

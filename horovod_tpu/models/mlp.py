@@ -19,8 +19,8 @@ from horovod_tpu.telemetry import scopes
 
 def mlp_block(x, layer, cfg, model_axis):
     """rmsnorm -> dense MLP (gelu, or SwiGLU) -> row-parallel psum ->
-    residual (shared by the training forward and the KV-cache decode so
-    the two cannot drift)."""
+    (the sandwich's second norm, ``post_norm``) -> residual (shared by the
+    training forward and the KV-cache decode so the two cannot drift)."""
     dt = cfg.dtype
     h = rmsnorm(x, layer["ln2_scale"], cfg.norm_eps)
     hi = tp.region_input(h, model_axis) if model_axis else h
@@ -33,7 +33,7 @@ def mlp_block(x, layer, cfg, model_axis):
         dn = u @ layer["w_down"].astype(dt)
     if model_axis:
         dn = lax.psum(dn, model_axis)
-    return x + dn
+    return x + parts.post_normed(dn, layer, "ln2_post_scale", cfg)
 
 
 def _validate(cfg, used):
@@ -57,12 +57,16 @@ def _validate_beside(cfg, used):
             f"{cfg.mlp!r}: the leading dense MLP is SwiGLU")
 
 
+def _norms(cfg):
+    return ("ln2_scale",) + ("ln2_post_scale",) * cfg.post_norm
+
+
 def _init(k, cfg):
     d, f = cfg.d_model, cfg.d_ff
+    norms = {name: ones(d) for name in _norms(cfg)}
     if cfg.mlp == "gelu":
-        return dict(ln2_scale=ones(d), w1=dense(k[4], (d, f)),
-                    w2=dense(k[5], (f, d)))
-    return dict(ln2_scale=ones(d), w_gate=dense(k[4], (d, f)),
+        return dict(norms, w1=dense(k[4], (d, f)), w2=dense(k[5], (f, d)))
+    return dict(norms, w_gate=dense(k[4], (d, f)),
                 w_up=dense(parts.ffn_keys(k)[0], (d, f)),
                 w_down=dense(k[5], (f, d)))
 
@@ -70,8 +74,8 @@ def _init(k, cfg):
 def _specs(cfg, model_axis):
     col, row = P(None, model_axis), P(model_axis, None)
     if cfg.mlp == "gelu":
-        return dict(whole("ln2_scale"), w1=col, w2=row)
-    return dict(whole("ln2_scale"), w_gate=col, w_up=col, w_down=row)
+        return dict(whole(*_norms(cfg)), w1=col, w2=row)
+    return dict(whole(*_norms(cfg)), w_gate=col, w_up=col, w_down=row)
 
 
 def _apply(x, layer, cfg, ctx):
@@ -91,7 +95,8 @@ MLP = parts.Part(name="mlp", fields=("d_ff", "mlp"), validate=_validate,
 # Beside experts it is whole on every chip, like them.
 MLP_BESIDE_EXPERTS = parts.Part(
     name="mlp_beside_experts", fields=("dense_layers",),
-    validate=_validate_beside, init=_init,
+    validate=parts.refuses_post_norm(
+        _validate_beside, "a dense MLP beside experts"), init=_init,
     specs=lambda cfg, model_axis: whole("ln2_scale", "w_gate", "w_up",
                                         "w_down"),
     apply=_apply_beside, unsupported={"model_axis": ("dense_layers",)})

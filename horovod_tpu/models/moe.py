@@ -815,7 +815,8 @@ SOFTMAX_EXPERTS = parts.Part(
     fields=("n_experts", "experts_per_token", "d_expert", "norm_topk_prob",
             "experts_held", "experts_held_from", "router_aux_coef",
             "router_z_coef"),
-    validate=_validate, init=_init,
+    validate=parts.refuses_post_norm(_validate, "softmax-routed experts"),
+    init=_init,
     specs=lambda cfg, model_axis: whole(*_SOFTMAX_LEAVES),
     apply=_applies(moe_ffn, lambda stats: {"router_stats": stats}),
     **_EXPERTS)
@@ -823,7 +824,9 @@ SOFTMAX_EXPERTS = parts.Part(
 # The sigmoid router has no auxiliary loss: nothing for the loss to collect.
 SIGMOID_EXPERTS = parts.Part(
     name="sigmoid_experts", fields=("d_shared", "routed_scale"),
-    validate=_validate_sigmoid, init=_init_sigmoid,
+    validate=parts.refuses_post_norm(_validate_sigmoid,
+                                     "sigmoid-routed experts"),
+    init=_init_sigmoid,
     specs=lambda cfg, model_axis: whole(
         *_SOFTMAX_LEAVES, "router_bias", "w_shared_gate", "w_shared_up",
         "w_shared_down"),
@@ -831,7 +834,8 @@ SIGMOID_EXPERTS = parts.Part(
 
 LATENT_EXPERTS = parts.Part(
     name="latent_experts", fields=("d_latent",),
-    validate=_validate_latent, init=_init_latent,
+    validate=parts.refuses_post_norm(_validate_latent, "latent experts"),
+    init=_init_latent,
     specs=lambda cfg, model_axis: whole(
         "ln2_scale", "router", "router_bias", "w_latent_in", "w_latent_out",
         "w_up", "w_down", "w_shared_up", "w_shared_down"),

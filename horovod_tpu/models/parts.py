@@ -134,6 +134,29 @@ def normed_mixer(mixer, proj_scope: str, out_scope: str):
     return apply
 
 
+def post_normed(y, layer, name: str, cfg):
+    """A branch's output ``y`` through the sandwich's second norm (the
+    leaf ``name``, ``cfg.post_norm``) before the residual add; ``y``
+    itself without it."""
+    if not cfg.post_norm:
+        return y
+    with jax.named_scope(scopes.POST_NORM):
+        return rmsnorm(y, layer[name], cfg.norm_eps)
+
+
+def refuses_post_norm(validate, what: str):
+    """``validate`` of a part that does not write the sandwich's second
+    norm: it refuses ``post_norm`` by name where it is used."""
+    def checked(cfg, used):
+        validate(cfg, used)
+        if used and cfg.post_norm:
+            raise NotImplementedError(
+                f"post_norm=True: the second norm of a sandwich-normed "
+                f"branch is written for plain attention and the dense "
+                f"MLP, not for {what}")
+    return checked
+
+
 def rmsnorm(x, scale, eps):
     # Stats in f32; output in the INPUT dtype.  The scale param is f32,
     # and without the cast it silently promoted every rmsnorm output —

@@ -26,8 +26,9 @@ fields that select a form.  This file runs the table: parameters, specs,
 the stack, the losses the parts hand back, the step, and the refusals,
 which are asked of the parts.  OLMoE, Olmo-Hybrid, Nemotron-3,
 GLM-4.7-Flash, Keye-VL-2.0's language model and Jamba2 are configs, not code
-here.  ``decode_step`` and the pipelined builder implement the GPT-2 block
-alone and say so by name.
+here; so is a stack run several times on the same weights with a readout
+after every pass (``loops``).  ``decode_step`` and the pipelined builder
+implement the GPT-2 block alone and say so by name.
 """
 
 from __future__ import annotations
@@ -81,7 +82,8 @@ PARTS = {part.name: part for part in (
 BLOCK_FIELDS = ("vocab_size", "d_model", "n_heads", "n_layers", "max_seq",
                 "dtype", "positions", "rope_theta", "norm_eps",
                 "tie_embeddings", "layer_types", "mtp_layer_types",
-                "mtp_loss_coef", "diffusion_block", "mask_token_id")
+                "mtp_loss_coef", "diffusion_block", "mask_token_id",
+                "post_norm", "loops", "exit_entropy_coef")
 
 
 def layer_parts(cfg, i: int, mtp: bool = False):
@@ -260,6 +262,26 @@ class TransformerConfig:
     # (:func:`diffusion_loss_fn`).  Every mixer is plain softmax attention.
     diffusion_block: int = 0
     mask_token_id: int = -1
+    # Sandwich norm: a second RMSNorm, with a scale of its own
+    # (``ln1_post_scale``, ``ln2_post_scale``), on the output of the plain
+    # attention part and of the dense MLP before the residual add, ``x +
+    # RMSNorm(branch(RMSNorm(x)))``.
+    post_norm: bool = False
+    # ``loops`` > 1: the stack runs that many times over the SAME layers
+    # (arXiv:2510.25741), the final norm after every pass, its output both
+    # that pass's readout and the next pass's input.  Every readout goes
+    # through the one head and an exit gate ``lambda_t = sigmoid(h w_g +
+    # b_g)`` (``exit_gate_w``, ``exit_gate_b``; float32); a token leaves
+    # after pass t with ``p_t = lambda_t prod_{j<t} (1 - lambda_j)``, the
+    # last pass taking what is left, and the loss is the mean over tokens
+    # of ``sum_t p_t xent_t - exit_entropy_coef H(p)`` (:func:`loss_fn`).
+    # The passes are written out one after another in the program (``loops
+    # x n_layers`` layer bodies, each readout after its pass): one
+    # ``lax.scan`` over them is a quarter of the code and of the compile
+    # and the same step time, and its body's heap packs worse, 1.3 GiB
+    # more at ten layers of the cell's sizes (PERF.md, PR 51).
+    loops: int = 1
+    exit_entropy_coef: float = 0.0
 
     def __post_init__(self):
         if self.positions not in ("learned", "rope", "none"):
@@ -308,6 +330,23 @@ class TransformerConfig:
                     f"of plain softmax attention under rotary or no "
                     f"positions, without a prediction module: not "
                     + ", ".join(refused))
+        if self.loops < 1:
+            raise ValueError(
+                f"loops={self.loops}: passes over the stack, 1 or more")
+        if self.loops == 1 and self.exit_entropy_coef:
+            raise ValueError(
+                f"exit_entropy_coef={self.exit_entropy_coef} means nothing "
+                f"without loops > 1")
+        if self.loops > 1:
+            # The module reads the last layer's un-normed output once; the
+            # block-diffusion loss reads one head on half the positions.
+            refused = _off_default(self, ("mtp_layer_types",
+                                          "diffusion_block"))
+            if refused:
+                raise NotImplementedError(
+                    f"loops={self.loops} reads the head and the exit gate "
+                    f"after every pass of one causal sequence: not "
+                    f"implemented with " + ", ".join(refused))
 
     @property
     def head_dim(self) -> int:
@@ -383,8 +422,12 @@ def _refuse_beyond_the_data_axis(cfg: TransformerConfig, **arguments) -> None:
 # the last three, the pipelined builder: any other field of the config
 # that is set is refused by name, whichever configuration added it.
 _PIPELINED_FIELDS = ("vocab_size", "d_model", "n_heads", "n_layers", "d_ff",
-                     "max_seq", "dtype", "norm_eps", "rope_theta")
+                     "max_seq", "dtype", "norm_eps", "rope_theta",
+                     "post_norm")
 _DECODE_FIELDS = _PIPELINED_FIELDS + ("qk_norm", "tie_embeddings", "mlp")
+# (A looped stack: decode_step would keep a cache a pass and layer and
+# leave by the exit rule, the pipelined builder run a circular schedule;
+# neither is written, and ``loops`` is refused by name: ROADMAP R17.)
 
 
 def _refuse_all_but(cfg: TransformerConfig, where: str, implemented) -> None:
@@ -432,6 +475,9 @@ def init_params(rng, cfg: TransformerConfig):
         params["pos"] = dense(keys[1], (cfg.max_seq, d), scale=0.02)
     if not cfg.tie_embeddings:
         params["head"] = dense(jax.random.fold_in(keys[1], 1), (d, v))
+    if cfg.loops > 1:
+        params["exit_gate_w"] = dense(jax.random.fold_in(keys[1], 3), (d, 1))
+        params["exit_gate_b"] = jnp.zeros((1,), jnp.float32)
     return params
 
 
@@ -460,15 +506,19 @@ def param_specs(cfg: TransformerConfig, model_axis: Optional[str]):
         specs["pos"] = P()
     if not cfg.tie_embeddings:
         specs["head"] = P()
+    if cfg.loops > 1:
+        specs.update(exit_gate_w=P(), exit_gate_b=P())
     return specs
 
 
 @jax.named_scope(scopes.HEAD)
-def _logits_head(x, params, cfg):
+def _logits_head(x, params, cfg, normed: bool = False):
     """Final rmsnorm + projection onto the vocabulary, by the transposed
-    embedding or the untied ``head`` (shared fwd/decode)."""
+    embedding or the untied ``head`` (shared fwd/decode).  ``normed``: ``x``
+    has been through the final norm already (a looped stack's state)."""
     dt = cfg.dtype
-    x = _rmsnorm(x, params["ln_f_scale"], cfg.norm_eps)
+    if not normed:
+        x = _rmsnorm(x, params["ln_f_scale"], cfg.norm_eps)
     w = params["embed"].T if cfg.tie_embeddings else params["head"]
     return (x @ w.astype(dt)).astype(jnp.float32)
 
@@ -530,22 +580,28 @@ def forward_with_router_stats(params, tokens, cfg: TransformerConfig,
     K-side ids with the K/V blocks, Ulysses all-gathers them (int32 per
     token) after its head scatter.
     """
-    x, extras, _ = _hidden_states(params, tokens, cfg, model_axis, seq_axis,
-                                  attention, segment_ids, remat)
-    return _logits_head(x, params, cfg), extras["router_stats"]
+    x, extras, _, _ = _hidden_states(params, tokens, cfg, model_axis,
+                                     seq_axis, attention, segment_ids, remat)
+    return (_logits_head(x, params, cfg, normed=cfg.loops > 1),
+            extras["router_stats"])
 
 
 def _hidden_states(params, tokens, cfg: TransformerConfig, model_axis,
-                   seq_axis, attention, segment_ids, remat, layout=None):
-    """``(x, extras, run_layers)``: the last layer's output before the
-    final norm; what the loss collects from the layers run so far, a list
-    a name (``router_stats``: one :class:`moe.RouterStats` per
+                   seq_axis, attention, segment_ids, remat, layout=None,
+                   readout=None):
+    """``(x, extras, run_layers, readouts)``: the last layer's output
+    before the final norm; what the loss collects from the layers run so
+    far, a list a name (``router_stats``: one :class:`moe.RouterStats` per
     softmax-routed MoE layer; ``index_kl``: every sparse attention
     layer's summed KL of its indexer); and the function that ran the
     stack (``run_layers(x, layers, chosen, label)``), for the
     multi-token-prediction module to run its own layers by.  ``layout``:
     ``(positions [T], mask)`` where the tokens are not one causal sequence
-    (:func:`diffusion_loss_fn`)."""
+    (:func:`diffusion_loss_fn`).  With ``cfg.loops`` > 1 the stack runs
+    that many times and ``x`` is the last pass's state **after** the final
+    norm; ``readouts[t]`` is ``readout(h)`` of pass ``t``'s normed state
+    ``h`` [B, T, d], taken right after its pass (None with one pass or
+    without ``readout``); ``extras`` lists every pass's layers in turn."""
     _refuse_beyond_the_data_axis(cfg, model_axis=model_axis,
                                  seq_axis=seq_axis, segment_ids=segment_ids)
     if cfg.diffusion_block and layout is None:
@@ -596,8 +652,35 @@ def _hidden_states(params, tokens, cfg: TransformerConfig, model_axis,
                 part.record(label % i, x, layer, cfg, ctx)
         return x
 
-    x = run_layers(x, params["layers"], stack_parts(cfg))
-    return x, extras, run_layers
+    chosen = stack_parts(cfg)
+    if cfg.loops == 1:
+        return (run_layers(x, params["layers"], chosen), extras, run_layers,
+                None)
+
+    telemetry.gauge("hvd_lm_loops", "Passes of the most recently traced "
+                    "looped stack over its layers").set(cfg.loops)
+    readouts = []
+    for t in range(cfg.loops):
+        with jax.named_scope(scopes.LOOP % t):
+            x = run_layers(x, params["layers"], chosen)
+            with jax.named_scope(scopes.LOOP_NORM):
+                x = _rmsnorm(x, params["ln_f_scale"], cfg.norm_eps)
+        readouts.append(readout(x) if readout else None)
+    return x, extras, run_layers, readouts
+
+
+def _log_likelihood(logits, labels):
+    """``log softmax(logits)[labels]`` a token: the one cross-entropy
+    formula, under whatever scope the caller holds."""
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    return jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+
+
+@jax.named_scope(scopes.LOSS)
+def token_xent(logits, labels):
+    """Next-token cross-entropy a token, ``[..., T]`` float32: what
+    :func:`xent` is the mean of."""
+    return -_log_likelihood(logits, labels)
 
 
 @jax.named_scope(scopes.LOSS)
@@ -606,8 +689,7 @@ def xent(logits, labels, counted=None):
     the plain and pipelined training steps and the oracle tests), over
     the positions where ``counted`` (bool, broadcast against ``labels``)
     holds; over all of them without it."""
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+    ll = _log_likelihood(logits, labels)
     if counted is None:
         return -jnp.mean(ll)
     counted = jnp.broadcast_to(counted, ll.shape)
@@ -649,11 +731,24 @@ def loss_fn(params, tokens, labels, cfg: TransformerConfig,
     load-balancing loss and ``router_z_coef`` x the router z-loss over
     all layers' tokens together.  ``batch_axes``: the mesh axes the batch
     is split over, so that the mean of the shards' losses is the global
-    batch's loss (:func:`moe.router_losses`)."""
-    x, extras, run_layers = _hidden_states(
+    batch's loss (:func:`moe.router_losses`).  With ``cfg.loops`` > 1 the
+    cross-entropy's place is taken by :func:`exit_mixture` of every pass's
+    (``TransformerConfig.loops``)."""
+    readout = None
+    if cfg.loops > 1:
+        # A recomputed block of its own a pass: [B, T, vocab] float32
+        # logits do not outlive their readout.
+        read = _remat_wrap(functools.partial(_exit_readout, cfg=cfg), remat)
+        heads = {name: leaf for name, leaf in params.items()
+                 if name in ("embed", "head", "exit_gate_w", "exit_gate_b")}
+        readout = lambda h: read(h, heads, labels)
+    x, extras, run_layers, readouts = _hidden_states(
         params, tokens, cfg, model_axis, seq_axis, attention, segment_ids,
-        remat)
-    loss = xent(_logits_head(x, params, cfg), labels)
+        remat, readout=readout)
+    if cfg.loops > 1:
+        loss = exit_mixture(*zip(*readouts), cfg.exit_entropy_coef)
+    else:
+        loss = xent(_logits_head(x, params, cfg), labels)
     if cfg.mtp_layer_types:
         ahead = _mtp_loss(params, x, labels, cfg, run_layers)
         with jax.named_scope(scopes.LOSS):
@@ -668,6 +763,48 @@ def loss_fn(params, tokens, labels, cfg: TransformerConfig,
                 sum(index_kl) / tokens.size)
     return _with_router_losses(loss, router_stats, tokens.size, cfg,
                                batch_axes)
+
+
+def _exit_readout(h, heads, labels, cfg):
+    """What one pass of a looped stack leaves of its normed state ``h``
+    [B, T, d]: the next-token cross-entropy a token through the model's
+    one head, and the exit gate's pre-activation (float32, exact: a sum
+    on the vector unit, no matmul pass), both ``[B, T]`` float32."""
+    losses = token_xent(_logits_head(h, heads, cfg, normed=True), labels)
+    with jax.named_scope(scopes.HEAD), jax.named_scope(scopes.EXIT_GATE):
+        gates = (jnp.sum(h.astype(jnp.float32) * heads["exit_gate_w"][:, 0],
+                         axis=-1) + heads["exit_gate_b"][0])
+    return losses, gates
+
+
+def exit_log_probs(gates):
+    """``log p`` [loops, ...] of the exit distribution from the gates'
+    pre-activations ``g`` [loops, ...], ``lambda_t = sigmoid(g_t)``: ``p_t
+    = lambda_t S_{t-1}`` with ``S_t = prod_{j<=t} (1 - lambda_j)``, the
+    last pass taking what is left, ``p_L = S_{L-1}`` (its own gate is
+    unused), so that the passes' sum to one.  In logarithms: ``log S_t =
+    -sum_{j<=t} softplus(g_j)``, ``log lambda_t = -softplus(-g_t)``."""
+    stay = -jnp.cumsum(jax.nn.softplus(gates), axis=0)
+    before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]])
+    return jnp.concatenate(
+        [(-jax.nn.softplus(-gates) + before)[:-1], before[-1:]])
+
+
+@jax.named_scope(scopes.LOSS)
+@jax.named_scope(scopes.EXIT_MIX)
+def exit_mixture(losses, gates, entropy_coef: float):
+    """The expected-exit loss of a looped stack (arXiv:2510.25741, first
+    stage): with ``losses`` and ``gates`` (a pass each, [B, T]) every pass's
+    cross-entropy and gate pre-activation a token and ``p`` the exit
+    distribution (:func:`exit_log_probs`), the mean over tokens of
+    ``sum_t p_t losses_t - entropy_coef H(p)``, ``H(p) = -sum_t p_t log
+    p_t``.  The gate learns through ``p`` and ``H``, the language model
+    through every pass's cross-entropy under its weight."""
+    losses, gates = jnp.stack(losses), jnp.stack(gates)
+    log_p = exit_log_probs(gates)
+    p = jnp.exp(log_p)
+    return jnp.mean(jnp.sum(p * losses, axis=0)
+                    + entropy_coef * jnp.sum(p * log_p, axis=0))
 
 
 def _with_router_losses(loss, router_stats, tokens: int, cfg, batch_axes):
@@ -724,7 +861,7 @@ def diffusion_loss_fn(params, tokens, masked, rates, cfg: TransformerConfig,
         positions = jnp.tile(jnp.arange(length), 2)
         weights = jnp.where(masked, 1.0 / jnp.repeat(rates, block, axis=1),
                             0.0)
-    x, extras, _ = _hidden_states(
+    x, extras, _, _ = _hidden_states(
         params, stream, cfg, None, None, attention, None, remat,
         layout=(positions, BlockDiffusion(length, block)))
     with jax.named_scope(scopes.HEAD):
@@ -732,8 +869,7 @@ def diffusion_loss_fn(params, tokens, masked, rates, cfg: TransformerConfig,
         noised_half = x[:, length:]
     logits = _logits_head(noised_half, params, cfg)
     with jax.named_scope(scopes.LOSS):
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        ll = jnp.take_along_axis(logp, tokens[..., None], axis=-1)[..., 0]
+        ll = _log_likelihood(logits, tokens)
         loss = -jnp.sum(weights * ll) / tokens.size
     return _with_router_losses(loss, extras["router_stats"], stream.size,
                                cfg, batch_axes)
@@ -989,7 +1125,7 @@ def decode_step(params, token, cache, pos, cfg: TransformerConfig,
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bht,bthd->bhd", p,
                        cv.astype(jnp.float32)).astype(dt)
-        x = attention_mod.attn_out(o.reshape(b, dh), x, layer, dt,
+        x = attention_mod.attn_out(o.reshape(b, dh), x, layer, cfg,
                                    model_axis)
         x = mlp_mod.mlp_block(x, layer, cfg, model_axis)
     return _logits_head(x, params, cfg), new_cache
@@ -1136,8 +1272,8 @@ def _pipe_stage_fn(cfg: TransformerConfig):
         bb, tt = q.shape[:2]
         o = seq_mod.local_attention(q, k, v, causal=True)
         with jax.named_scope(scopes.ATTN_OUT):
-            x = attention_mod.attn_out(o.reshape(bb, tt, dh), x, lp, dt,
-                                           None)
+            x = attention_mod.attn_out(o.reshape(bb, tt, dh), x, lp, cfg,
+                                       None)
         with jax.named_scope(scopes.MLP):
             x = mlp_mod.mlp_block(x, lp, cfg, None)
         # attention computes in f32; pin the carried activation to the

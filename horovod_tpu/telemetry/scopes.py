@@ -115,6 +115,28 @@ MTP = "mtp"
 # loss's weights; a bare component under EMBED.
 DIFFUSION_ASSEMBLE = "diffusion_assemble"
 
+# A stack run several times on the same weights (``TransformerConfig.loops``
+# > 1, transformer._hidden_states): LOOP % t around pass ``t``.  A layer's
+# scopes nest under it (".../loop_2/layer_0/attn/qkv/..."), so a reader
+# that knows only the model scopes still answers "attn/qkv", "mlp".
+# LOOP_NORM, right under the loop's scope and under no layer's, is the
+# final norm after every pass, whose output is both the readout and the
+# next pass's input.
+LOOP = "loop_%d"
+LOOP_NORM = "loop_norm"
+
+# The second norm of a sandwich-normed branch (``post_norm``), on the
+# branch's output before the residual add: a bare component under ATTN_OUT
+# and under MLP.
+POST_NORM = "post_norm"
+
+# The readouts of a looped stack (transformer.loss_fn): the exit gate's
+# pre-activation, a bare component under HEAD; and under LOSS the exit
+# distribution, the weighted sum of the passes' cross-entropies and the
+# entropy term.
+EXIT_GATE = "exit_gate"
+EXIT_MIX = "exit_mix"
+
 # Step scopes: what the step does with the gradients.
 GRAD_MEAN = "grad_mean"
 OPTIMIZER = "optimizer"

@@ -1,8 +1,9 @@
 """What every LM configuration must hold, written once and run over a table
-of them: the GPT-2 block and the seven tiny configurations that keep the
+of them: the GPT-2 block and the eight tiny configurations that keep the
 shape of OLMoE, Olmo-Hybrid, Nemotron-3, GLM-4.7-Flash, Keye-VL-2.0,
-Jamba2 and SDAR (whose batch is clean tokens, which are noised and each
-block's rate, and whose loss is ``diffusion_loss_fn``'s),
+Jamba2, SDAR (whose batch is clean tokens, which are noised and each
+block's rate, and whose loss is ``diffusion_loss_fn``'s) and Ouro (a
+stack run four times on the same weights),
 each against its plain reference under ``perfbench/reference/``, which
 shares no code with the program.
 
@@ -43,7 +44,8 @@ import pytest
 from horovod_tpu.models import linear_attention as la
 from horovod_tpu.models import transformer as tfm
 from perfbench.reference import (bd_moe_lm, dsa_moe_lm, hybrid_lm, lm,
-                                 mamba1_lm, mla_moe_lm, moe_lm, ssm_moe_lm)
+                                 looped_lm, mamba1_lm, mla_moe_lm, moe_lm,
+                                 ssm_moe_lm)
 
 __all__ = ["COSTLY", "ROWS", "built", "lm_row", "pytest_generate_tests",
            "rel"]
@@ -117,6 +119,16 @@ SDAR_TINY = tfm.TransformerConfig(
     qk_norm_per_head=True, mlp="swiglu", n_experts=8, experts_per_token=2,
     d_expert=48, norm_topk_prob=True, experts_held=4, experts_held_from=2,
     diffusion_block=4, mask_token_id=127)
+
+# One sandwich-normed rotary SwiGLU layer of 2 heads of 32, run four times
+# on the same weights (four layer bodies to compile: a second layer would
+# double every test's time and show nothing more), the head untied, an
+# exit gate after every pass.
+OURO_TINY = tfm.TransformerConfig(
+    vocab_size=128, d_model=64, n_heads=2, n_layers=1, d_ff=96, max_seq=64,
+    dtype=F32, positions="rope", rope_theta=1e6, norm_eps=1e-6,
+    tie_embeddings=False, mlp="swiglu", post_norm=True, loops=4,
+    exit_entropy_coef=0.05)
 
 
 def rel(got, want):
@@ -227,6 +239,33 @@ def _sdar_ref(cfg, params, tokens, masked, rates, **kw):
     return bd_moe_lm.loss_and_tail_grads(
         params, tokens, masked, rates, dims=sdar_dims(cfg),
         names=tuple(bd_moe_lm.LEAVES), **kw)
+
+
+def ouro_dims(cfg):
+    return {"n_heads": cfg.n_heads, "eps": cfg.norm_eps,
+            "theta": cfg.rope_theta, "loops": cfg.loops,
+            "beta": cfg.exit_entropy_coef}
+
+
+def _ouro_ref(cfg, params, tokens, labels, **kw):
+    """Every leaf, the embedding's and the gate's among them."""
+    paths = looped_lm.every_leaf(params)
+    return looped_lm.loss_and_grads(
+        params, tokens, labels, dims=ouro_dims(cfg), names=tuple(paths),
+        paths=paths, **kw)
+
+
+def _every_leaf(tree, cfg):
+    return {name: looped_lm.leaf(tree, path)
+            for name, path in looped_lm.every_leaf(tree).items()}
+
+
+def _exit_shares(stats):
+    """The four passes' mean exit probabilities sum to one, and every
+    pass carries weight."""
+    assert stats["p_mean"].shape == stats["l_mean"].shape == (4,)
+    assert abs(float(stats["p_mean"].sum()) - 1.0) < 1e-6
+    assert float(stats["p_mean"].min()) > 0.05
 
 
 def _by_paths(reference, paths):
@@ -342,6 +381,7 @@ BEYOND_NAMES = ("model_axis", "seq_axis", "packed", "segment_ids",
 _GDN_BLOCKS = 2 * 2 * 256 // la.BLOCK
 _NO_MAMBA = dict(layer_types=(), mamba_inner=0, mamba_state=0,
                  mamba_dt_rank=0, mamba_conv_kernel=0)
+_LOOP_ALONE = dict(_GPT2_BUT_THE_LAYERS, post_norm=False)
 
 ROWS = {
     "gpt2": Row(
@@ -778,6 +818,66 @@ ROWS = {
                 "mean nothing without a 'mamba' entry"),
                (dict(layer_types=("mamba", "mamba1") * 2), ValueError,
                 "layer_types"))),
+    "ouro": Row(
+        cfg=OURO_TINY, ref=_ouro_ref, seq=64, checked=_every_leaf,
+        parity=(("local", F32, "local", 5e-5, 5e-5),
+                ("flash", F32, "flash", 5e-5, 5e-5)),
+        also=lambda stats, grads: _exit_shares(stats),
+        # The nine references that the cell's check must refuse.  A cut
+        # between the passes leaves the loss alone (-1: any gap passes).
+        controls=(
+            ("three_passes", dict(loops=3), "layers.0.wk", 1e-4, 0.02),
+            ("cut_passes", dict(cut_passes=True), "layers.0.wk", -1.0,
+             0.02),
+            ("norm_at_readouts", dict(norm_carried=False), "layers.0.wk",
+             1e-4, 0.02),
+            ("no_post_norms", dict(post_norms=False),
+             "layers.0.ln2_post_scale", 1e-4, 0.02),
+            ("uniform_exit", dict(uniform_exit=True), "exit_gate_w", 1e-4,
+             0.02),
+            ("no_entropy", dict(entropy=False), "exit_gate_w", 1e-4, 0.02),
+            ("last_unnormalised", dict(last_takes_rest=False),
+             "exit_gate_w", 1e-4, 0.02),
+            ("last_pass_only", dict(last_pass_only=True), "layers.0.wk",
+             1e-4, 0.02),
+            ("float8", dict(low_precision=jnp.float8_e4m3fn),
+             "layers.0.w_down", 1e-4, 0.02)),
+        step=("full", "flash"), zero=True,
+        shapes={("exit_gate_w",): (64, 1), ("exit_gate_b",): (1,),
+                ("layers", 0, "ln1_post_scale"): (64,),
+                ("layers", 0, "ln2_post_scale"): (64,),
+                ("layers", 0, "w_down"): (96, 64), ("head",): (64, 128)},
+        series=("hvd_lm_loops 4",),
+        no_series=("hvd_moe_", "hvd_gdn_", "hvd_ssm_", "hvd_dsa_"),
+        # What perfbench/loop_reduce.py reads.
+        scopes=("loop_3/layer_0/attn/qkv", "layer_0/attn/out/post_norm",
+                "layer_0/mlp/post_norm", "loop_norm", "head/exit_gate",
+                "loss/exit_mix"),
+        no_scopes=("/moe_", "/mtp", "/mla_"),
+        # The loop touches neither axis nor packing: they run
+        # (tests/test_looped_lm.py holds them to the single-device step).
+        refused={"decode_step": ("positions", "loops"),
+                 "pipelined": ("positions", "loops", "mlp",
+                               "tie_embeddings")},
+        # The GPT-2 block run four times: the loop alone refuses.
+        alone=tuple(("loop", _LOOP_ALONE, what, "loops")
+                    for what in ("decode_step", "pipelined")),
+        rules=((dict(loops=0), ValueError, "loops=0"),
+               (dict(loops=1), ValueError, "means nothing without loops"),
+               (dict(mtp_layer_types=("full_attention",),
+                     mtp_loss_coef=0.1), NotImplementedError,
+                "loops=4.*mtp_layer_types"),
+               (dict(diffusion_block=4, mask_token_id=127),
+                NotImplementedError, "loops=4.*diffusion_block"),
+               (dict(n_experts=8, experts_per_token=2, d_expert=32),
+                NotImplementedError, "post_norm=True.*softmax-routed"),
+               (dict(head_width=32, q_latent_rank=24, kv_latent_rank=16,
+                     rope_dim=8), NotImplementedError,
+                "post_norm=True.*latent attention"),
+               (dict(layer_types=("mamba2",), ssm_heads=4,
+                     ssm_head_dim=16, ssm_state=32, ssm_groups=2,
+                     ssm_conv_kernel=4, ssm_chunk=32), NotImplementedError,
+                "post_norm=True.*Mamba-2"))),
 }
 
 

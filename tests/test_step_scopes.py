@@ -449,6 +449,70 @@ def test_sdar_step_carries_the_vocabulary_and_the_assembly(hvd, attention,
     assert set(bd_reduce.PARTS) - {bd_reduce.ASSEMBLE} <= parts
 
 
+OURO = dict(positions="rope", tie_embeddings=False, mlp="swiglu",
+            post_norm=True, loops=4, exit_entropy_coef=0.05)
+
+
+@pytest.mark.parametrize("attention,remat",
+                         [("flash", "full"), ("local", "none")])
+def test_looped_step_carries_the_vocabulary_under_the_loop(hvd, attention,
+                                                           remat):
+    """A stack run four times: every model scope nests under its pass's
+    (``loop_<t>``), so the fixed vocabulary still answers; the sandwich's
+    second norms, the carried final norm, the gate and the mixture open
+    their own components; and ``perfbench/loop_reduce.py`` books every
+    executed op to one part, those the fixed rules leave without a scope
+    to the loop's carry."""
+    from perfbench import loop_reduce, moe_reduce
+
+    text = _lm_step_text(attention, remat, False, **OURO)
+    names = _op_names(text)
+    loop = scopes.LOOP % 3
+    for scope in (scopes.ATTN_QKV, ROUTE[attention], scopes.ATTN_OUT,
+                  scopes.MLP, scopes.LOOP_NORM):
+        assert _under(names, scope, loop, "jvp(", without=("transpose(",))
+        assert _under(names, scope, loop, "transpose("), scope
+    # The readouts stand outside the loop's scope, a block a pass.
+    for scope in (scopes.HEAD, scopes.LOSS):
+        assert _under(names, scope, "jvp(", without=("transpose(",)), scope
+        assert _under(names, scope, "transpose("), scope
+        assert not _under(names, scope, loop), scope
+    for part, homes in ((scopes.POST_NORM, (scopes.ATTN_OUT, scopes.MLP)),
+                        (scopes.EXIT_GATE, (scopes.HEAD,)),
+                        (scopes.EXIT_MIX, (scopes.LOSS,))):
+        # (``jvp(loss)/exit_mix``: an outermost scope closes a bracket.)
+        held = [n for n in names if _under([n], part)]
+        for home in homes:
+            inside = re.compile(re.escape(home) + r"\)*/" + part)
+            assert any(inside.search(n) for n in held), (home, part)
+            held = [n for n in held if not inside.search(n)]
+        assert not held, part
+    if attention == "flash":
+        for kernel in (scopes.FLASH_FWD, scopes.FLASH_BWD_DQ,
+                       scopes.FLASH_BWD_DKV):
+            assert _under(names, kernel, scopes.ATTN_FLASH, loop), kernel
+    hlo = scope_reduce.parse_hlo(text)
+    parts = {name: loop_reduce.part_of(name, hlo)
+             for name, i in hlo.instructions.items() if i.opcode in HELD}
+    # (On the CPU the flash kernels run in the interpreter: no custom call
+    # carries their name, and their time would be the glue's.)
+    assert set(loop_reduce.PARTS) - {"flash", "qk_glue", "exit",
+                                     "carry"} <= set(parts.values())
+    # Written out, the passes have no carry to copy or stack: what is
+    # booked there is the sums of a shared leaf's gradient over the passes
+    # (and the cast of a recomputed block's input).
+    assert {moe_reduce.op_name_of(name, hlo).rsplit("/", 1)[1]
+            for name, part in parts.items()
+            if part == "carry"} <= {"add_any", "remat2"}
+    # What the fixed rules cannot place is the loop's own: those sums and
+    # the final norm after every pass, which stands under no model scope
+    # (and the CPU's fusions booked by the cast of a recomputed block's
+    # input, the last pass's norm inside one of them).
+    assert {parts[name] for name, _, _ in _unplaced(text)
+            if not moe_reduce.op_name_of(name, hlo).endswith("/remat2")
+            } <= {"carry", "norm"}
+
+
 @pytest.mark.parametrize("attention", ("ring", "ulysses"))
 def test_sequence_routes_open_their_own_scope(hvd, attention):
     text = _lm_step_text(attention, "none", False, seq_axis="seq")
@@ -592,6 +656,40 @@ def test_the_benchmark_reads_the_same_vocabulary():
     # Block diffusion's assembly of the two streams, a sub-scope of
     # ``embed`` read by ``perfbench/bd_reduce.py``.
     bd_parts = {scopes.DIFFUSION_ASSEMBLE}
+    # A looped stack's scopes (the loop's own, the carried norm, the
+    # sandwich's second norm, the gate and the mixture), read by
+    # ``perfbench/loop_reduce.py``.
+    loop_parts = {scopes.LOOP, scopes.LOOP_NORM,
+                  scopes.POST_NORM, scopes.EXIT_GATE, scopes.EXIT_MIX}
+    from perfbench import loop_reduce
+    for inside, part in (
+            (f"{scopes.LOOP % 1}/{scopes.LAYER % 1}/{scopes.ATTN_OUT}"
+             f"/{scopes.POST_NORM}/mul", "norm"),
+            (f"{scopes.LOOP % 2}/{scopes.LAYER % 0}/{scopes.MLP}/"
+             f"{scopes.POST_NORM}/mul", "norm"),
+            (f"{scopes.LOOP % 1}/{scopes.LOOP_NORM}/rsqrt", "norm"),
+            (f"{scopes.LOOP % 1}/{scopes.HEAD}/{scopes.EXIT_GATE}"
+             f"/reduce_sum", "exit"),
+            (f"{scopes.LOSS}/{scopes.EXIT_MIX}/exp", "exit"),
+            (f"{scopes.LOOP % 1}/{scopes.HEAD}/dot_general", "head"),
+            (f"{scopes.LOOP % 3}/{scopes.LOSS}/reduce_max", "head"),
+            (f"{scopes.LOOP % 1}/{scopes.LAYER % 1}/"
+             f"{scopes.ATTN_QKV}/dot_general", "attn"),
+            (f"{scopes.LOOP % 1}/{scopes.LAYER % 1}/{scopes.MLP}"
+             f"/dot_general", "mlp"),
+            (f"{scopes.LOOP % 1}/add_any", "carry"),
+            (f"{scopes.EMBED}/gather", "other"),
+            (f"{scopes.OPTIMIZER}/mul", "other")):
+        for phase, wrap in (("fwd", "jvp(%s)"), ("bwd", "transpose(jvp(%s))")):
+            head, _, rest = inside.partition("/")
+            call = f"jit(x)/{wrap % head}/{rest}"
+            assert loop_reduce.part_of_name(call) == part, call
+            if part != "other":
+                assert scope_reduce.phase_of(call) == phase, call
+    # The sum of a shared leaf's partial gradients, under no scope.
+    assert loop_reduce.part_of_name(
+        "jit(x)/transpose(jvp(jvp()))/add_any") == "carry"
+    assert loop_reduce.part_of_name("jit(x)/jvp()/add") == "other"
     from perfbench import bd_reduce
     assert bd_parts | {scopes.QK_HEAD_NORM_ROPE} | moe_parts == set(
         bd_reduce.PARTS)
@@ -604,7 +702,7 @@ def test_the_benchmark_reads_the_same_vocabulary():
             - moe_kernels - gdn_parts - gdn_kernels - ssm_parts
             - ssm_kernels - mla_parts - conv_kernels - norm_kernels
             - dsa_parts - dsa_kernels - mamba1_parts - mamba1_kernels
-            - mla_kernels - qk_kernels - bd_parts)
+            - mla_kernels - qk_kernels - bd_parts - loop_parts)
     from perfbench import mamba1_reduce
     assert ({p.rsplit("/", 1)[-1] for p in mamba1_parts}
             == set(mamba1_reduce.PARTS))
