@@ -59,6 +59,15 @@ but does not weigh, the chosen scores renormalised and scaled
 latent width between two dense projections; a shared expert on the hidden
 state, added for every token.
 
+**A sigmoid-routed share no wider than the choice** (8 held of a choice
+of 22 in ``nemotron3s_t8192``) needs no index of the choice, only whether
+each held expert is in it and the sum of the chosen scores: its router is
+a membership mask (:mod:`horovod_tpu.ops.router_choice`, ``lax.top_k``'s
+set by counting) and products and row sums of it
+(:func:`route_sigmoid_held`), with no sort, gather or scatter
+(:func:`choice_path`, read from the shapes; PERF.md, PR 52).  Everywhere
+else the choice is ``lax.top_k``'s indices.
+
 **SwiGLU experts under the sigmoid router** (:func:`sigmoid_moe_ffn`;
 DeepSeek-V3's layer as GLM-4.7-Flash runs it): the same router and the
 same shared expert beside SwiGLU experts on the hidden state itself, no
@@ -82,7 +91,7 @@ from horovod_tpu import telemetry
 from horovod_tpu.models import parts
 from horovod_tpu.models.parts import dense, ones, rmsnorm, whole
 from horovod_tpu.ops import grouped_matmul as gmm
-from horovod_tpu.ops import moe_rows
+from horovod_tpu.ops import moe_rows, router_choice
 from horovod_tpu.ops.grouped_matmul import (grouped_matmul,
                                             worst_matmul_rows)
 from horovod_tpu.telemetry import scopes
@@ -520,21 +529,92 @@ def moe_ffn(h, layer, cfg):
     return y.reshape(h.shape), stats
 
 
+def sigmoid_scores(h, router_w):
+    """``sigmoid(h W_r)`` [N, E] in float32, the matmul at precision
+    ``highest``, as :func:`route`'s."""
+    logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    return jax.nn.sigmoid(logits)
+
+
 def route_sigmoid(h, router_w, bias, k: int, scale: float):
     """``h`` [N, d], ``router_w`` [d, E], ``bias`` [E] -> ``(top_w [N, k]
     f32, top_i [N, k] int32)``: scores ``s = sigmoid(h W_r)`` in float32
-    over all ``E`` (the matmul at precision ``highest``, as
-    :func:`route`'s), the ``k`` experts with the largest ``s + bias``
-    (``bias`` chooses and carries no gradient), and weights ``scale *
-    s[chosen] / (sum of s[chosen] + 1e-20)``: the sum runs over all ``k``
-    whether this chip holds them or not."""
-    logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
-                     precision=lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
+    over all ``E`` (:func:`sigmoid_scores`), the ``k`` experts with the
+    largest ``s + bias`` (``bias`` chooses and carries no gradient), and
+    weights ``scale * s[chosen] / (sum of s[chosen] + 1e-20)``: the sum
+    runs over all ``k`` whether this chip holds them or not."""
+    scores = sigmoid_scores(h, router_w)
     _, top_i = lax.top_k(scores + lax.stop_gradient(bias), k)
     chosen = jnp.take_along_axis(scores, top_i, axis=-1)
     return (scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True)
                               + 1e-20), top_i)
+
+
+def route_sigmoid_held(h, router_w, bias, k: int, scale: float, first: int,
+                       held: int, kernel: bool):
+    """:func:`route_sigmoid` and :func:`held_slots` for a share no wider
+    than the choice (``held <= k``), without an index: ``(slot_w [N, held]
+    f32, slot_e [N, held] int32, rows [held] int32)``.  Of a token's ``k``
+    choices such a share needs whether each held expert is among them and
+    the sum of the chosen scores, so the choice is a membership mask ``m``
+    [N, E] (:func:`router_choice.chosen`, ``lax.top_k``'s set exactly;
+    ``kernel`` False: from ``lax.top_k`` itself), and the rest is slices,
+    products and row sums: held expert ``j`` has slot ``j``, with weight
+    ``scale * s_j m_j / (sum_e s_e m_e + 1e-20)`` and expert ``j`` if
+    chosen, else "not here"; ``rows`` counts the chosen a held expert.
+    :func:`experts_ffn` sorts the slots stably by expert and a token has
+    at most one slot an expert, so every group holds the rows
+    :func:`held_slots`' hold, in the same order.  The gradient with
+    respect to ``s`` is elementwise with two row sums; the mask and the
+    bias carry none."""
+    scores = sigmoid_scores(h, router_w)
+    mask = (router_choice.chosen if kernel else router_choice.chosen_xla)(
+        scores + lax.stop_gradient(bias), k)
+    total = jnp.sum(scores * mask, axis=-1, keepdims=True) + 1e-20
+    here = slice(first, first + held)
+    mask = mask[:, here]
+    slot_e = jnp.where(mask > 0, jnp.arange(held, dtype=jnp.int32), held)
+    return (scale * scores[:, here] * mask / total, slot_e,
+            jnp.sum(mask, axis=0).astype(jnp.int32))
+
+
+def choice_path(x, layer, cfg) -> str:
+    """How a layer of ``cfg`` with the parameters ``layer`` and the input
+    ``x`` [..., d] finds a token's experts: ``"top_k"`` (``lax.top_k``'s
+    indices: every softmax-routed layer, every layer that holds all its
+    experts, and a share wider than the choice, whose ``k`` compact slots
+    :func:`experts_ffn` needs), or, where a sigmoid-routed share is no
+    wider than the choice, the mask of :func:`route_sigmoid_held`:
+    ``"threshold_kernel"`` where :func:`router_choice.takes` accepts the
+    scores, else ``"threshold_xla"``."""
+    held = cfg.held_experts
+    if ("router_bias" not in layer or held == cfg.n_experts
+            or held > cfg.experts_per_token):
+        return "top_k"
+    keys = jnp.broadcast_to(
+        x.reshape(-1, x.shape[-1])[:, :1].astype(jnp.float32),
+        (x.size // x.shape[-1], cfg.n_experts))
+    return ("threshold_kernel" if router_choice.takes(keys)
+            else "threshold_xla")
+
+
+def _sigmoid_share(flat, layer, cfg):
+    """The sigmoid router's choice among ``flat`` [N, d] as
+    :func:`experts_ffn` takes it on this chip: ``(slot_w, slot_e,
+    group_sizes, prefix)``, by :func:`choice_path`."""
+    k = cfg.experts_per_token
+    path = choice_path(flat, layer, cfg)
+    if path == "top_k":
+        top_w, top_i = route_sigmoid(flat, layer["router"],
+                                     layer["router_bias"], k,
+                                     cfg.routed_scale)
+        return this_chips_share(top_w, top_i, cfg)
+    held = cfg.held_experts
+    return (*route_sigmoid_held(
+        flat, layer["router"], layer["router_bias"], k, cfg.routed_scale,
+        cfg.experts_held_from, held, path == "threshold_kernel"),
+        rows_prefix(flat.shape[0], k, held, cfg.n_experts))
 
 
 def latent_moe_ffn(u, layer, cfg):
@@ -546,10 +626,7 @@ def latent_moe_ffn(u, layer, cfg):
     dt = cfg.dtype
     flat = u.reshape(-1, u.shape[-1])
     with jax.named_scope(scopes.MOE_ROUTER):
-        top_w, top_i = route_sigmoid(
-            flat, layer["router"], layer["router_bias"],
-            cfg.experts_per_token, cfg.routed_scale)
-        slot_w, slot_e, rows, prefix = this_chips_share(top_w, top_i, cfg)
+        slot_w, slot_e, rows, prefix = _sigmoid_share(flat, layer, cfg)
     with jax.named_scope(scopes.MOE_LATENT):
         latent = flat @ layer["w_latent_in"].astype(dt)
     routed = experts_ffn(latent, slot_w, slot_e, rows, layer, dt,
@@ -572,10 +649,7 @@ def sigmoid_moe_ffn(u, layer, cfg):
     dt = cfg.dtype
     flat = u.reshape(-1, u.shape[-1])
     with jax.named_scope(scopes.MOE_ROUTER):
-        top_w, top_i = route_sigmoid(
-            flat, layer["router"], layer["router_bias"],
-            cfg.experts_per_token, cfg.routed_scale)
-        slot_w, slot_e, rows, prefix = this_chips_share(top_w, top_i, cfg)
+        slot_w, slot_e, rows, prefix = _sigmoid_share(flat, layer, cfg)
     routed = experts_ffn(flat, slot_w, slot_e, rows, layer, dt,
                          act="swiglu", prefix=prefix)
     with jax.named_scope(scopes.MOE_SHARED):
@@ -611,6 +685,23 @@ def record_held(layer: int, tokens: int, cfg) -> None:
         "runs over while a batch's held rows fit them (a batch with more "
         "takes the bound's): the bound where every expert is held",
         layer=str(layer)).set(rows_prefix(tokens, k, held, cfg.n_experts))
+
+
+def record_router(layer: int, x, weights, cfg) -> None:
+    """Trace-time series beside :func:`record_held` (what was compiled
+    into the step): how layer ``layer`` (parameters ``weights``, input
+    ``x``) finds a token's experts (:func:`choice_path`)."""
+    if not telemetry.enabled():
+        return
+    telemetry.counter(
+        "hvd_moe_router_choices_total",
+        "Routers of the traced MoE layer by how a token's experts are "
+        "found (path: top_k = lax.top_k's indices | threshold_kernel = "
+        "the membership mask of the same set from the kernel moe_choose, "
+        "on a sigmoid-routed share no wider than the choice | "
+        "threshold_xla = that mask from lax.top_k where the kernel does "
+        "not take the scores)",
+        layer=str(layer), path=choice_path(x, weights, cfg)).inc()
 
 
 def record_assignments(layer: int, assignments: int, experts: int) -> None:
@@ -791,6 +882,7 @@ def moves_path(x, cfg):
 
 def _record(name, x, layer, cfg, ctx):
     record_held(name, ctx.tokens, cfg)
+    record_router(name, x, layer, cfg)
     record_weight_copies(name, layer)
     path = moves_path(x, cfg) if telemetry.enabled() else None
     if path:

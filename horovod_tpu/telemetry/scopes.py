@@ -163,6 +163,10 @@ MOE_TGMM = "moe_tgmm"
 MOE_ROW_TILES = "moe_row_tiles"
 MOE_ROWS_BACK = "moe_rows_back"
 
+# The Pallas kernel of the sigmoid router's choice on a narrow share
+# (ops/router_choice.py); it runs under MOE_ROUTER.
+MOE_CHOOSE = "moe_choose"
+
 # The two gated-delta-rule Pallas kernels (ops/gated_delta_rule.py); they
 # run under ATTN_GDN_SCAN.
 GDN_SCAN_FWD = "gdn_scan_fwd"

@@ -177,10 +177,12 @@ def test_the_trace_time_series_count_every_pass(hvd):
 # parent wrote, for one configuration of each kind.  (The whole train
 # step of every row under the three ``remat``, 24 programs, and
 # ``init_params`` hashed equal too: PERF.md, PR 51.)  A PR that changes a
-# row's program on purpose takes the new digest.
+# row's program on purpose takes the new digest: ``nemotron``'s is PR 52's
+# (its share, 4 experts of a choice of 6, routes by the membership mask;
+# b1117effe96bac67 at the parent), and the seven others held through it.
 PARENTS_TEXT = {
     "gpt2": "6aa8718098cb07e6", "olmoe": "ddbc442ecc7eec77",
-    "hybrid": "7c17eb526f81d722", "nemotron": "b1117effe96bac67",
+    "hybrid": "7c17eb526f81d722", "nemotron": "06ddf462d64a1006",
     "glm": "ab683f72e196effb", "keye": "c496bd636f46a3b6",
     "sdar": "0b2f69d61413b55f", "jamba": "c3f5e469d6347354"}
 

@@ -591,10 +591,15 @@ def test_an_ordinary_batch_makes_no_pass_over_the_bound(recompute):
     assert not _as_long_as_the_bound(ordinary, bound)
     assert _as_long_as_the_bound(overflow, bound)
     for taken in (ordinary, overflow):
+        kernels = [e.params["name"] for e in taken
+                   if e.primitive.name == "pallas_call"]
+        # The router's choice, outside the conditional: its mask is kept
+        # for the backward pass, or computed again with the block.
+        chooses = kernels.count("moe_choose")
+        assert chooses == 1 + recompute
         # 2 products forward; the backward computes them again and their
         # 2 + 2 gradients.
-        assert [e.primitive.name for e in taken].count(
-            "pallas_call") == 2 + 2 + 2 * 2
+        assert len(kernels) - chooses == 2 + 2 + 2 * 2
 
 
 def test_an_ordinary_batch_on_the_kernels_sums_by_token():
@@ -696,6 +701,102 @@ def test_held_slots_keep_every_held_assignment():
                if e < 4}
         assert got == want
         assert float(slot_w[row][slot_e[row] == 4].sum()) == 0.0
+
+
+def _routers_series(cfg, layer, u):
+    """What ``record_router`` says of the layer, as Prometheus text."""
+    from horovod_tpu import telemetry
+
+    telemetry.reset_for_tests()
+    telemetry.configure(True)
+    try:
+        moe.record_router("x", u, layer, cfg)
+        return telemetry.render_prometheus()
+    finally:
+        telemetry.reset_for_tests()
+
+
+# The rehearsal shapes (16 experts, 4 held from 4, top-6: the mask from
+# ``lax.top_k``, 16 experts being no lane group) and the cell's own
+# (8 of 512 from 40, top-22, whole lane groups of tokens: the kernel).
+NARROW_SHARES = {
+    "rehearsal": (dict(), 64, "threshold_xla"),
+    "cell": (dict(n_experts=512, experts_per_token=22, experts_held=8,
+                  experts_held_from=40), 128, "threshold_kernel")}
+
+
+@pytest.mark.parametrize("shape", sorted(NARROW_SHARES))
+def test_a_share_no_wider_than_the_choice_routes_by_the_mask(shape,
+                                                             monkeypatch):
+    """The dense form's slots, rows, layer output and gradients are
+    ``route_sigmoid`` + ``held_slots``' to float32 rounding: the same
+    set, the sum of the chosen scores added in another order."""
+    fields, tokens, path = NARROW_SHARES[shape]
+    cfg, layer, u = _expert_layer(dataclasses.replace(NEMOTRON_TINY,
+                                                      **fields))
+    u = jnp.tile(u, (tokens // 64, 1)) + 0.1 * jax.random.normal(
+        jax.random.PRNGKey(5), (tokens, cfg.d_model))
+    layer = dict(layer, router_bias=0.05 * jax.random.normal(
+        jax.random.PRNGKey(6), (cfg.n_experts,)))
+    held, k = cfg.held_experts, cfg.experts_per_token
+    assert held <= k and moe.choice_path(u, layer, cfg) == path
+    text = _routers_series(cfg, layer, u)
+    assert ('hvd_moe_router_choices_total{layer="x",path="%s"} 1' % path
+            in text), text
+
+    slot_w, slot_e, rows, prefix = moe._sigmoid_share(u, layer, cfg)
+    top_w, top_i = moe.route_sigmoid(u, layer["router"],
+                                     layer["router_bias"], k,
+                                     cfg.routed_scale)
+    want_w, want_e, want_rows, want_prefix = moe.this_chips_share(
+        top_w, top_i, cfg)
+    assert prefix == want_prefix and slot_w.shape == want_w.shape
+    np.testing.assert_array_equal(rows, want_rows)
+    assert int(rows.sum()) > 0
+    # Held expert j has slot j; the index form's slots are sorted.
+    by_expert = np.zeros((tokens, held + 1), np.float32)
+    np.put_along_axis(by_expert, np.asarray(want_e), np.asarray(want_w), 1)
+    np.testing.assert_allclose(slot_w, by_expert[:, :held], rtol=1e-6)
+    np.testing.assert_array_equal(
+        slot_e, np.where(by_expert[:, :held] > 0, np.arange(held), held))
+
+    def loss_and_grads():
+        return jax.value_and_grad(lambda layer, u: jnp.sum(jnp.sin(
+            moe.latent_moe_ffn(u, layer, cfg)[0])), (0, 1))(layer, u)
+
+    loss, (d_layer, d_u) = loss_and_grads()
+    monkeypatch.setattr(moe, "choice_path", lambda *a: "top_k")
+    want, (want_layer, want_u) = loss_and_grads()
+    np.testing.assert_allclose(loss, want, rtol=1e-6)
+    assert _rel(d_u, want_u) <= 1e-6
+    assert float(jnp.abs(d_layer["router"]).max()) > 0
+    for name, g in d_layer.items():
+        if name in ("router_bias", "ln2_scale"):   # chooses only; the norm
+            assert float(jnp.abs(g).max()) == 0.0  # is outside the layer
+        else:
+            assert _rel(g, want_layer[name]) <= 1e-6, name
+
+
+@pytest.mark.parametrize("fields,why", [
+    (dict(experts_held=8), "a share wider than the choice (8 > 6)"),
+    (dict(experts_held=0, experts_held_from=0), "every expert held")])
+def test_elsewhere_the_sigmoid_router_is_top_ks_indices(fields, why):
+    cfg, layer, u = _expert_layer(dataclasses.replace(NEMOTRON_TINY,
+                                                      **fields))
+    assert moe.choice_path(u, layer, cfg) == "top_k", why
+    assert ('hvd_moe_router_choices_total{layer="x",path="top_k"} 1'
+            in _routers_series(cfg, layer, u))
+    names = {e.primitive.name for e in _eqns(jax.make_jaxpr(
+        lambda layer, u: moe.latent_moe_ffn(u, layer, cfg)[0])(
+            layer, u).jaxpr)}
+    assert "top_k" in names
+
+
+def test_the_softmax_router_is_top_ks_indices():
+    cfg = OLMOE_TINY
+    layer = tfm.init_params(jax.random.PRNGKey(0), cfg)["layers"][0]
+    u = jnp.zeros((64, cfg.d_model))
+    assert moe.choice_path(u, layer, cfg) == "top_k"
 
 
 # --- the whole model --------------------------------------------------------

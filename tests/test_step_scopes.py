@@ -606,7 +606,8 @@ def test_the_benchmark_reads_the_same_vocabulary():
     # vocabulary does not hold them and does not need to.
     moe_parts = set(MOE_PARTS)
     moe_kernels = {scopes.MOE_GMM, scopes.MOE_GMM_NT, scopes.MOE_TGMM,
-                   scopes.MOE_ROW_TILES, scopes.MOE_ROWS_BACK}
+                   scopes.MOE_ROW_TILES, scopes.MOE_ROWS_BACK,
+                   scopes.MOE_CHOOSE}
     # Likewise the linear-attention layer's parts, read by
     # ``perfbench/gdn_reduce.py``: four sub-scopes of ``attn/qkv`` and
     # ``attn/out``, and the recurrence's route, which the fixed
