@@ -11,7 +11,14 @@
 * :data:`SPARSE_ATTENTION` — plain attention's projections beside an
   indexer that chooses the ``index_topk`` keys each query reads and learns
   from its own loss (:mod:`horovod_tpu.ops.sparse_attention`), a route of
-  its own.
+  its own;
+* :data:`CCA_ATTENTION` — compressed convolutional attention
+  (arXiv:2510.04476): q and k projected into a latent narrower than the
+  hidden size and mixed along the sequence by two short causal
+  convolutions, the mean of the unmixed q and k added back, half of the
+  value heads from the previous token, an L2 norm a head with a learned
+  temperature on the keys, rotary over a head's first ``rotary_dims``
+  (:func:`cca_qkv`), through the same routes.
 
 :func:`qkv_proj` and :func:`attn_out` are also what ``decode_step`` and
 the pipelined stage run, so the three cannot drift.
@@ -27,6 +34,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from horovod_tpu import telemetry
 from horovod_tpu.models import parts
 from horovod_tpu.models.parts import dense, ones, rmsnorm, whole
 from horovod_tpu.ops import mla_assemble, qk_assemble, sparse_attention
@@ -257,9 +265,10 @@ def _flash_route(ctx, t: int) -> bool:
         or (ctx.attention == "auto" and _flash_profitable(t)))
 
 
-def _routed(q, k, v, dh, x, layer, cfg, ctx):
+def _routed(q, k, v, dh, x, layer, cfg, ctx, out=attn_out):
     """``q, k, v`` through the route ``ctx.attention`` names (each opens
-    its own ``attn/<route>``), the out projection and the residual."""
+    its own ``attn/<route>``), the out projection and the residual
+    (``out``: :func:`attn_out`, or a part's own with its arguments)."""
     seq_axis, attention, segment_ids = (ctx.seq_axis, ctx.attention,
                                         ctx.segment_ids)
     # ``True``, or the step's own mask (block diffusion's: no sequence
@@ -303,7 +312,7 @@ def _routed(q, k, v, dh, x, layer, cfg, ctx):
         o = seq_mod.local_attention(q, k, v, causal=mask,
                                     segment_ids=segment_ids)
     with jax.named_scope(scopes.ATTN_OUT):
-        return attn_out(o.reshape(b, t, dh), x, layer, cfg, ctx.model_axis)
+        return out(o.reshape(b, t, dh), x, layer, cfg, ctx.model_axis)
 
 
 def _folded_out(o, x, layer, cfg):
@@ -559,3 +568,179 @@ SPARSE_ATTENTION = parts.Part(
     unsupported={"model_axis": _NOT_SPLIT + _SPARSE,
                  "seq_axis": ("head_width", "qk_norm_per_head") + _SPARSE,
                  "segment_ids": ("index_topk",)})
+
+
+# --- compressed convolutional attention -------------------------------------
+
+def cca_convolutions(c, layer, head_dim: int, dtype):
+    """Both causal convolutions of CCA over the packed ``c`` [B, T, C]
+    (``C`` = heads x ``head_dim``, query and key-value heads together) ->
+    [B, T, C / head_dim, head_dim] float32: the depthwise one, ``c1[t] =
+    w0 * c[t-1] + w1 * c[t] + b`` a channel in float32 (``cca_dw_w`` [2,
+    C], ``cca_dw_b``), then the one grouped by head, ``c2[t] = c1[t-1]
+    C0_j + c1[t] C1_j + b'`` (``cca_gw_w`` [2, heads, head_dim, head_dim],
+    ``cca_gw_b``), both taps as ONE matmul a head of ``[c1[t-1] | c1[t]]``
+    and ``[C0_j; C1_j]`` in ``dtype``.  Rows before the first are zero
+    (:func:`parts.shifted`)."""
+    f32 = jnp.float32
+    c, taps = c.astype(f32), layer["cca_dw_w"]
+    c1 = (taps[0] * parts.shifted(c) + taps[1] * c
+          + layer["cca_dw_b"]).astype(dtype)
+    c1 = c1.reshape(c1.shape[:-1] + (-1, head_dim))
+    mats = jnp.concatenate(list(layer["cca_gw_w"].astype(dtype)), axis=-2)
+    both = jnp.concatenate([parts.shifted(c1), c1], axis=-1)
+    return (jnp.einsum("bthd,hde->bthe", both, mats).astype(f32)
+            + layer["cca_gw_b"].reshape(c1.shape[-2:]))
+
+
+def cca_qkv(u, layer, cfg, positions):
+    """CCA's q, k, v from the normed input ``u`` [B, T, d], with ``H``
+    query heads on ``G`` key-value heads of ``D``, ``g(h) = h // (H / G)``:
+
+    * ``q~ = u W_q`` [B, T, H, D], ``k~ = u W_k`` [B, T, G, D];
+    * the mix, over ``c = [q~ | k~]`` [B, T, (H + G) D]: a causal depthwise
+      convolution of two taps, ``c1[t] = w0 * c[t-1] + w1 * c[t] + b`` a
+      channel, then a causal convolution of two taps grouped by head,
+      ``c2[t] = c1[t-1] C0_j + c1[t] C1_j + b'`` with ``C0_j``, ``C1_j`` [D,
+      D] a head ``j`` of the ``H + G``; rows before the first are zero
+      (:func:`parts.shifted`); ``(q', k') = c2``;
+    * the mean: ``m_q[h] = (q~[h] + k~[g(h)]) / 2``, ``m_k[j]`` the mean of
+      ``m_q`` over the query heads of group ``j``; ``q = q' + m_q``, ``k =
+      k' + m_k``;
+    * the values: the first ``G / 2`` heads are ``u[t] W_v_now``, the
+      others ``u[t-1] W_v_prev`` (the shift taken after the product: a
+      zero row maps to a zero row);
+    * ``q <- sqrt(D) q / |q|``, ``k <- sqrt(D) exp(tau_j) k / |k|`` a head
+      (``tau``: ``k_temp`` [G]), then rotary at ``positions`` over the
+      first ``cfg.rotary_dims`` of every head (0: all of them).
+
+    bf16 operands with float32 accumulation in the matmuls; the depthwise
+    taps, the mean, the norms' statistics, the temperature and the
+    rotation in float32.  Returns q [B, T, H, D] and k, v [B, T, G, D]."""
+    dt, f32 = cfg.dtype, jnp.float32
+    heads, groups, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    per_group = heads // groups
+
+    def split(a):
+        return a.reshape(a.shape[:-1] + (a.shape[-1] // hd, hd))
+
+    q0 = u @ layer["wq"].astype(dt)
+    k0 = u @ layer["wk"].astype(dt)
+    v_now = u @ layer["wv_now"].astype(dt)
+    v_prev = u @ layer["wv_prev"].astype(dt)
+    with jax.named_scope(scopes.CCA_MIX):
+        c2 = cca_convolutions(jnp.concatenate([q0, k0], axis=-1), layer,
+                              hd, dt)
+        q0, k0 = split(q0.astype(f32)), split(k0.astype(f32))
+        m_q = 0.5 * (q0 + jnp.repeat(k0, per_group, axis=-2))
+        m_k = jnp.mean(m_q.reshape(m_q.shape[:-2] + (groups, per_group, hd)),
+                       axis=-2)
+        q = c2[..., :heads, :] + m_q
+        k = c2[..., heads:, :] + m_k
+        v = jnp.concatenate([split(v_now), parts.shifted(split(v_prev))],
+                            axis=-2)
+    with jax.named_scope(scopes.CCA_NORM_ROPE):
+        def unit(a):
+            return a * lax.rsqrt(
+                jnp.sum(a * a, axis=-1, keepdims=True) / hd + 1e-30)
+
+        q = unit(q)
+        k = unit(k) * jnp.exp(layer["k_temp"])[:, None]
+        turned = cfg.rotary_dims or hd
+        q, k = (jnp.concatenate(
+            [rotary(a[..., :turned], positions, cfg.rope_theta),
+             a[..., turned:]], axis=-1).astype(dt) for a in (q, k))
+    return q, k, v
+
+
+def _cca_validate(cfg, used):
+    if not cfg.cca_taps:
+        if cfg.rotary_dims:
+            raise ValueError(
+                f"rotary_dims={cfg.rotary_dims} (a head's first dims that "
+                f"rotary turns) means nothing without cca_taps")
+        return
+    if tuple(cfg.cca_taps) != (2, 2):
+        raise NotImplementedError(
+            f"cca_taps={cfg.cca_taps!r}: the two causal convolutions are "
+            f"written with two taps each, (2, 2)")
+    if cfg.positions != "rope" or cfg.rotary_dims % 2 or not (
+            0 <= cfg.rotary_dims <= cfg.head_dim):
+        raise ValueError(
+            f"cca_taps: the heads are rotary over their first rotary_dims "
+            f"(0: all): it needs positions='rope' and an even rotary_dims "
+            f"up to head_dim={cfg.head_dim}, got {cfg.positions!r} and "
+            f"{cfg.rotary_dims}")
+    if cfg.kv_heads % 2:
+        raise ValueError(
+            f"cca_taps: half of the key-value heads carry the previous "
+            f"token's value: n_kv_heads={cfg.kv_heads} must be even")
+    if (cfg.qk_norm or cfg.qk_norm_per_head or cfg.latent_attention
+            or cfg.sparse_attention):
+        raise NotImplementedError(
+            "cca_taps: compressed convolutional attention norms its heads "
+            "itself and is no latent or sparse attention: qk_norm, "
+            "qk_norm_per_head, kv_latent_rank and index_topk are not "
+            "implemented with it")
+
+
+def _cca_init(k, cfg):
+    d, hd = cfg.d_model, cfg.head_dim
+    wide, d_kv = cfg.n_heads * hd, cfg.kv_heads * hd
+    mixed, both = wide + d_kv, cfg.n_heads + cfg.kv_heads
+    k_mix = jax.random.split(jax.random.fold_in(k[1], 1), 5)
+    return dict(
+        ln1_scale=ones(d), wq=dense(k[0], (d, wide)),
+        wk=dense(k[1], (d, d_kv)), wv_now=dense(k[2], (d, d_kv // 2)),
+        wv_prev=dense(jax.random.fold_in(k[2], 1), (d, d_kv // 2)),
+        wo=dense(k[3], (wide, d)),
+        # [tap, channel], tap 0 on the previous row; [tap, head, in, out].
+        cca_dw_w=dense(k_mix[0], (2, mixed), scale=0.5 ** 0.5),
+        cca_dw_b=dense(k_mix[1], (mixed,), scale=0.02),
+        cca_gw_w=dense(k_mix[2], (2, both, hd, hd), scale=(2 * hd) ** -0.5),
+        cca_gw_b=dense(k_mix[3], (mixed,), scale=0.02),
+        k_temp=dense(k_mix[4], (cfg.kv_heads,), scale=0.1),
+        **parts.merge_init(k[3], "merge1", cfg))
+
+
+_CCA_LEAVES = ("ln1_scale", "wq", "wk", "wv_now", "wv_prev", "wo",
+               "cca_dw_w", "cca_dw_b", "cca_gw_w", "cca_gw_b", "k_temp")
+
+
+def _cca_specs(cfg, model_axis):
+    return whole(*_CCA_LEAVES, *(parts.merge_names("merge1")
+                                 * cfg.residual_scaling))
+
+
+def _cca_out(o_flat, x, layer, cfg, model_axis):
+    """The out projection and the (scaled) residual merge."""
+    return parts.merged(x, o_flat @ layer["wo"].astype(cfg.dtype), layer,
+                        "merge1", cfg)
+
+
+def _cca_apply(x, layer, cfg, ctx):
+    with jax.named_scope(scopes.ATTN_QKV):
+        q, k, v = cca_qkv(rmsnorm(x, layer["ln1_scale"], cfg.norm_eps),
+                          layer, cfg, ctx.positions)
+    return _routed(q, k, v, cfg.n_heads * cfg.head_dim, x, layer, cfg, ctx,
+                   out=_cca_out), {}
+
+
+def _cca_record(name, x, layer, cfg, ctx):
+    if telemetry.enabled():
+        telemetry.counter(
+            "hvd_cca_rows_total",
+            "Token rows the traced compressed-convolutional-attention "
+            "layer mixes along the sequence per step on one device",
+            layer=str(name)).inc(x.shape[0] * x.shape[1])
+
+
+# Not written: the previous shard's last rows for the shift and the
+# convolutions under a sequence axis (a halo of one and two rows), a
+# document's boundary under packing, and heads over a model axis.
+CCA_ATTENTION = parts.Part(
+    name="cca_attention", fields=("cca_taps", "rotary_dims"),
+    validate=parts.refuses_post_norm(
+        _cca_validate, "compressed convolutional attention"),
+    init=_cca_init, specs=_cca_specs, apply=_cca_apply, record=_cca_record,
+    unsupported=parts.everywhere("cca_taps"), scaled_merge=True)

@@ -73,6 +73,21 @@ DeepSeek-V3's layer as GLM-4.7-Flash runs it): the same router and the
 same shared expert beside SwiGLU experts on the hidden state itself, no
 latent width between.
 
+**One expert a token or a skip, under an MLP router**
+(:func:`zaya_moe_ffn`; ZAYA1, arXiv:2511.17127): the router is a down
+projection, the previous expert layer's router state added a channel, a
+norm and a small MLP (:func:`route_mlp`) over the experts **and a skip**,
+one choice a token by argmax under a selection bias.  The state is handed
+from expert layer to expert layer beside ``x`` (``Part.carries``).  The
+skip is a choice after the last expert that no chip holds
+(:func:`router_choices`): to :func:`held_slots` it is "not here" like
+another chip's expert, and its term ``p_c u``, which multiplies no matrix,
+is added by the chip whose token it is.  With one slot a token,
+:func:`rows_bound` is the token count and four times a uniform router's
+rows pass it, so the layer works on the whole buffer
+(:func:`_every_slot_ffn`, masked), half of whose rows are dead where half
+of the experts are held.
+
 Not here: the exchange of rows between chips that hold different experts
 (ROADMAP R2).  The one-expert-per-chip, capacity-dropping ``all_to_all``
 demo is :mod:`horovod_tpu.parallel.expert`.
@@ -492,17 +507,27 @@ def held_slots(top_w, top_i, first: int, held: int):
     return jnp.where(key < held, top_w, 0.0), key.astype(jnp.int32)
 
 
+def router_choices(cfg) -> int:
+    """What a token's router ranks: the ``n_experts`` and, under the MLP
+    router (``router_width``), the skip, a choice after the last expert
+    that **no** chip holds: to :func:`held_slots` it is "not here" on
+    every chip, so the held range, :func:`rows_bound` and
+    :func:`rows_prefix` speak of the real experts alone."""
+    return cfg.n_experts + (1 if cfg.router_width else 0)
+
+
 def this_chips_share(top_w, top_i, cfg, counts=None):
     """A router's choice as :func:`experts_ffn` takes it on this chip:
     ``(slot_w, slot_e, group_sizes, prefix)``.  Where the chip holds every
-    expert that is the choice itself and ``prefix`` is None (``counts``:
-    the router's assignments per expert, where it has them already).
-    Where it holds a share (``cfg.experts_held``): the held slots
-    (:func:`held_slots`), the rows each held expert receives and the rows
-    of the buffer's head that hold an ordinary batch's
+    choice (:func:`router_choices`) that is the choice itself and
+    ``prefix`` is None (``counts``: the router's assignments per expert,
+    where it has them already).  Where it holds a share
+    (``cfg.experts_held``, or any range under a router with a skip): the
+    held slots (:func:`held_slots`), the rows each held expert receives
+    and the rows of the buffer's head that hold an ordinary batch's
     (:func:`rows_prefix`, static)."""
     held = cfg.held_experts
-    share = held < cfg.n_experts
+    share = held < router_choices(cfg)
     if share:
         top_w, top_i = held_slots(top_w, top_i, cfg.experts_held_from, held)
     if share or counts is None:
@@ -510,7 +535,7 @@ def this_chips_share(top_w, top_i, cfg, counts=None):
             top_i.reshape(-1, 1) == jnp.arange(held)[None, :], axis=0,
             dtype=jnp.int32)
     prefix = (rows_prefix(top_i.shape[0], cfg.experts_per_token, held,
-                          cfg.n_experts) if share else None)
+                          router_choices(cfg)) if share else None)
     return top_w, top_i, counts, prefix
 
 
@@ -587,7 +612,10 @@ def choice_path(x, layer, cfg) -> str:
     :func:`experts_ffn` needs), or, where a sigmoid-routed share is no
     wider than the choice, the mask of :func:`route_sigmoid_held`:
     ``"threshold_kernel"`` where :func:`router_choice.takes` accepts the
-    scores, else ``"threshold_xla"``."""
+    scores, else ``"threshold_xla"``; ``"argmax"`` under the MLP router,
+    whose one choice a token is an argmax."""
+    if cfg.router_width:
+        return "argmax"
     held = cfg.held_experts
     if ("router_bias" not in layer or held == cfg.n_experts
             or held > cfg.experts_per_token):
@@ -659,6 +687,62 @@ def sigmoid_moe_ffn(u, layer, cfg):
     return (routed + shared).reshape(u.shape), rows
 
 
+def route_mlp(u, state, layer, cfg):
+    """The MLP router with a state carried from expert layer to expert
+    layer (ZAYA1, arXiv:2511.17127): ``u`` [N, d], ``state`` [N, w] (the
+    previous expert layer's, None in the first) -> ``(p_c [N] f32, c [N]
+    int32, state' [N, w] f32)``.  ``r = u W_d + b_d + gamma * state`` (the
+    last term absent without ``state``) is what the next layer receives;
+    ``z = W_3 gelu(W_2 gelu(W_1 RMSNorm(r) + b_1) + b_2)`` over the
+    ``n_experts + 1`` choices (the last is the skip), exact ``gelu``; ``p
+    = softmax(z)``; ``c = argmax(p + bias)`` (``router_bias`` chooses and
+    carries no gradient; of equal sums the lower index wins) and ``p_c``
+    its probability.  All in float32, the matmuls at precision ``highest``
+    (0.66 M of the layer's 13.2 M active matrix parameters; a bf16 router
+    flips choices: :func:`route`)."""
+    f32 = jnp.float32
+    dot = functools.partial(jnp.dot, precision=lax.Precision.HIGHEST)
+    with jax.named_scope(scopes.ROUTER_STATE):
+        r = dot(u.astype(f32), layer["router_down"]) + layer[
+            "router_down_bias"]
+        if state is not None:
+            r = r + layer["router_state_scale"] * state
+    with jax.named_scope(scopes.ROUTER_MLP):
+        h = rmsnorm(r, layer["router_norm_scale"], cfg.norm_eps)
+        h = jax.nn.gelu(dot(h, layer["router_w1"]) + layer["router_b1"],
+                        approximate=False)
+        h = jax.nn.gelu(dot(h, layer["router_w2"]) + layer["router_b2"],
+                        approximate=False)
+        p = jax.nn.softmax(dot(h, layer["router_w3"]), axis=-1)
+        c = jnp.argmax(p + lax.stop_gradient(layer["router_bias"]), axis=-1)
+        p_c = jnp.take_along_axis(p, c[:, None], axis=-1)[:, 0]
+    return p_c, c.astype(jnp.int32), r
+
+
+def zaya_moe_ffn(u, state, layer, cfg):
+    """One SwiGLU expert a token of ``n_experts`` and a skip, under the
+    MLP router, on ``u`` [..., d] with the previous expert layer's router
+    ``state`` [..., w] (or None): ``(y [..., d], state')``.  ``y = p_c
+    expert_c(u)`` for a real expert ``c`` that this chip holds, nothing
+    for one it does not, and ``p_c u`` for the skip, which multiplies no
+    matrix and is computed by the chip whose token it is."""
+    dt = cfg.dtype
+    flat = u.reshape(-1, u.shape[-1])
+    with jax.named_scope(scopes.MOE_ROUTER):
+        p_c, c, new = route_mlp(
+            flat, None if state is None else state.reshape(
+                -1, state.shape[-1]), layer, cfg)
+        slot_w, slot_e, rows, prefix = this_chips_share(
+            p_c[:, None], c[:, None], cfg)
+    routed = experts_ffn(flat, slot_w, slot_e, rows, layer, dt,
+                         act="swiglu", prefix=prefix)
+    with jax.named_scope(scopes.MOE_SKIP):
+        skipped = (jnp.where(c == cfg.n_experts, p_c, 0.0)[:, None]
+                   * flat.astype(jnp.float32)).astype(dt)
+    return ((routed + skipped).reshape(u.shape),
+            new.reshape(u.shape[:-1] + new.shape[-1:]))
+
+
 def record_held(layer: int, tokens: int, cfg) -> None:
     """Trace-time series beside ``hvd_moe_assignments_total``: the routed
     experts layer ``layer`` holds on this chip, the static bound on the
@@ -684,7 +768,8 @@ def record_held(layer: int, tokens: int, cfg) -> None:
         "Rows of the buffer's head that the traced MoE layer's row work "
         "runs over while a batch's held rows fit them (a batch with more "
         "takes the bound's): the bound where every expert is held",
-        layer=str(layer)).set(rows_prefix(tokens, k, held, cfg.n_experts))
+        layer=str(layer)).set(
+            rows_prefix(tokens, k, held, router_choices(cfg)))
 
 
 def record_router(layer: int, x, weights, cfg) -> None:
@@ -700,7 +785,8 @@ def record_router(layer: int, x, weights, cfg) -> None:
         "the membership mask of the same set from the kernel moe_choose, "
         "on a sigmoid-routed share no wider than the choice | "
         "threshold_xla = that mask from lax.top_k where the kernel does "
-        "not take the scores)",
+        "not take the scores | argmax = the one choice a token of the MLP "
+        "router)",
         layer=str(layer), path=choice_path(x, weights, cfg)).inc()
 
 
@@ -852,6 +938,81 @@ def _init_latent(k, cfg):
         w_shared_down=dense(jax.random.fold_in(k_shared, 1), (s, d)))
 
 
+def _validate_zaya(cfg, used):
+    if not cfg.router_width:
+        return
+    if cfg.router_width < 0 or not cfg.n_experts or cfg.mlp != "swiglu":
+        raise ValueError(
+            f"router_width={cfg.router_width} is the width of the MLP "
+            f"router of SwiGLU experts: it needs n_experts and "
+            f"mlp='swiglu'")
+    if cfg.experts_per_token != 1:
+        raise NotImplementedError(
+            f"router_width: the MLP router makes one choice a token (an "
+            f"expert or the skip), not experts_per_token="
+            f"{cfg.experts_per_token}")
+    refused = [f"{name}={getattr(cfg, name)!r}" for name in (
+        "d_shared", "dense_layers", "norm_topk_prob", "router_aux_coef",
+        "router_z_coef", "mtp_layer_types") if getattr(cfg, name)]
+    if cfg.loops > 1:
+        refused.append(f"loops={cfg.loops}")
+    if refused:
+        # The state is handed from one expert layer to the next of ONE
+        # pass over ONE stack; the router has no auxiliary loss.
+        raise NotImplementedError(
+            "router_width: the MLP router with its carried state is not "
+            "implemented with " + ", ".join(refused))
+
+
+def _init_zaya(k, cfg):
+    d, e, w = cfg.d_model, cfg.d_expert, cfg.router_width
+    k_up, k_router = parts.ffn_keys(k)
+    kr = jax.random.split(k_router, 9)
+    small = lambda key, shape, mean=0.0, std=0.02: (
+        mean + std * jax.random.normal(key, shape, jnp.float32))
+    return dict(
+        ln2_scale=ones(d),
+        router_down=dense(kr[0], (d, w)),
+        router_down_bias=small(kr[1], (w,)),
+        # The previous layer's state a channel; unused (and its gradient
+        # zero) in the first expert layer the stack runs.
+        router_state_scale=small(kr[2], (w,), 1.0, 0.1),
+        router_norm_scale=ones(w),
+        router_w1=dense(kr[3], (w, w)), router_b1=small(kr[4], (w,)),
+        router_w2=dense(kr[5], (w, w)), router_b2=small(kr[6], (w,)),
+        router_w3=dense(kr[7], (w, cfg.n_experts + 1)),
+        # Chooses and is not trained: its gradient is zero.
+        router_bias=small(kr[8], (cfg.n_experts + 1,), 0.0, 0.01),
+        w_gate=_stacked(k[4], (d, e), cfg),
+        w_up=_stacked(k_up, (d, e), cfg),
+        w_down=_stacked(k[5], (e, d), cfg),
+        **parts.merge_init(k[5], "merge2", cfg))
+
+
+_ZAYA_LEAVES = ("ln2_scale", "router_down", "router_down_bias",
+                "router_state_scale", "router_norm_scale", "router_w1",
+                "router_b1", "router_w2", "router_b2", "router_w3",
+                "router_bias", "w_gate", "w_up", "w_down")
+
+
+def _zaya_apply(x, layer, cfg, ctx, carried):
+    with jax.named_scope(scopes.MLP):
+        y, state = zaya_moe_ffn(
+            rmsnorm(x, layer["ln2_scale"], cfg.norm_eps), carried[0], layer,
+            cfg)
+        return parts.merged(x, y, layer, "merge2", cfg), {}, (state,)
+
+
+def _zaya_record(name, x, layer, cfg, ctx):
+    _record(name, x, layer, cfg, ctx)
+    if telemetry.enabled():
+        telemetry.gauge(
+            "hvd_moe_router_state_width",
+            "Channels of the router state the traced MoE layer takes from "
+            "the previous expert layer and hands to the next",
+            layer=str(name)).set(cfg.router_width)
+
+
 def _applies(ffn, extras):
     """rmsnorm -> ``ffn`` -> residual, under ``mlp``; ``extras(stats)`` is
     what the loss collects of the second thing ``ffn`` returns."""
@@ -872,7 +1033,7 @@ def moves_path(x, cfg):
     slots = min(cfg.experts_per_token, cfg.held_experts)
     tokens = x.size // x.shape[-1]
     if rows_prefix(tokens, cfg.experts_per_token, cfg.held_experts,
-                   cfg.n_experts) >= tokens * slots:
+                   router_choices(cfg)) >= tokens * slots:
         return None
     width = cfg.d_latent if cfg.mlp == "relu2" else cfg.d_model
     rows = jnp.broadcast_to(x.reshape(-1, x.shape[-1])[:, :1],
@@ -887,7 +1048,7 @@ def _record(name, x, layer, cfg, ctx):
     path = moves_path(x, cfg) if telemetry.enabled() else None
     if path:
         moe_rows.record_moves(name, path)
-    if cfg.held_experts == cfg.n_experts:
+    if cfg.held_experts == router_choices(cfg):
         # What lands on a share is data.
         record_assignments(name, ctx.tokens * cfg.experts_per_token,
                            cfg.n_experts)
@@ -932,3 +1093,16 @@ LATENT_EXPERTS = parts.Part(
         "ln2_scale", "router", "router_bias", "w_latent_in", "w_latent_out",
         "w_up", "w_down", "w_shared_up", "w_shared_down"),
     apply=_applies(latent_moe_ffn, lambda rows: {}), **_EXPERTS)
+
+# One SwiGLU expert a token or a skip, under the MLP router whose state is
+# handed from expert layer to expert layer.
+ZAYA_EXPERTS = parts.Part(
+    name="zaya_experts", fields=("router_width",),
+    validate=parts.refuses_post_norm(
+        _validate_zaya, "experts under the MLP router"),
+    init=_init_zaya,
+    specs=lambda cfg, model_axis: whole(
+        *_ZAYA_LEAVES, *(parts.merge_names("merge2")
+                         * cfg.residual_scaling)),
+    apply=_zaya_apply, carries=("router_state",), scaled_merge=True,
+    **dict(_EXPERTS, record=_zaya_record))

@@ -11,12 +11,19 @@ what it cannot run under.  The step keeps one table of them
 (``transformer.layer_parts``); it branches on nothing else, so a new
 mixer or feed-forward form is one module and one row.
 
-The parts: :mod:`~horovod_tpu.models.attention` (plain, latent and learned
-sparse attention), :mod:`~horovod_tpu.models.linear_attention`,
+The parts: :mod:`~horovod_tpu.models.attention` (plain, latent, learned
+sparse and compressed convolutional attention),
+:mod:`~horovod_tpu.models.linear_attention`,
 :mod:`~horovod_tpu.models.mamba2`, :mod:`~horovod_tpu.models.mamba1`,
 :mod:`~horovod_tpu.models.mlp` (the
 dense MLP) and :mod:`~horovod_tpu.models.moe` (softmax-routed,
-sigmoid-routed and latent experts).
+sigmoid-routed and latent experts, and one expert a token or a skip under
+an MLP router).
+
+Two things cross the seam beside ``x`` and ``x + y``: what a part hands
+from layer to layer (``Part.carries``: the MLP router's state), which the
+step threads through its recomputed blocks as an argument and a result;
+and the scaled residual merge (:func:`merged`, ``residual_scaling``).
 """
 
 from __future__ import annotations
@@ -27,6 +34,7 @@ from typing import Any, Callable, Mapping, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu import telemetry
@@ -61,7 +69,13 @@ class Part:
     for each of ``model_axis``, ``seq_axis`` and ``segment_ids``, its
     fields that it cannot run under that argument with (one that selects
     the part, where it implements none of it).  ``check_vma``: its body
-    types under ``shard_map``'s checker of what varies over which axis."""
+    types under ``shard_map``'s checker of what varies over which axis.
+    ``carries``: what it takes from the previous layer that holds the same
+    part and hands to the next, beside ``x`` (``("router_state",)``): its
+    body is then ``apply(x, layer, cfg, ctx, carried) -> (x, extras,
+    carried)`` with ``carried`` a tuple in that order, ``None`` for what
+    no earlier layer of the stack has handed on.  ``scaled_merge``: its
+    residual is :func:`merged`, so it runs under ``residual_scaling``."""
 
     name: str
     fields: Tuple[str, ...]
@@ -73,6 +87,8 @@ class Part:
     unsupported: Mapping[str, Tuple[str, ...]] = dataclasses.field(
         default_factory=dict)
     check_vma: bool = True
+    carries: Tuple[str, ...] = ()
+    scaled_merge: bool = False
 
     def __post_init__(self):
         # Every trace of the body is counted and timed by part name: the
@@ -82,10 +98,10 @@ class Part:
         body = self.apply
 
         @functools.wraps(body)
-        def apply(x, layer, cfg, ctx):
+        def apply(x, layer, cfg, ctx, *carried):
             began = telemetry.clock()
             try:
-                return body(x, layer, cfg, ctx)
+                return body(x, layer, cfg, ctx, *carried)
             finally:
                 telemetry.part_traced(self.name, telemetry.clock() - began)
 
@@ -142,6 +158,53 @@ def post_normed(y, layer, name: str, cfg):
         return y
     with jax.named_scope(scopes.POST_NORM):
         return rmsnorm(y, layer[name], cfg.norm_eps)
+
+
+# The four vectors of one scaled residual merge, by what they multiply or
+# shift (the stream's scale and bias, the branch's scale and bias), each
+# with the mean and the standard deviation it is drawn with: off their
+# neutral values, so that a check on seeded weights sees every one.
+MERGE_LEAVES = (("res_scale", 1.0, 0.1), ("res_bias", 0.0, 0.02),
+                ("out_scale", 1.0, 0.1), ("out_bias", 0.0, 0.02))
+
+
+def merge_names(prefix: str):
+    """The leaves of the merge ``prefix`` (``"merge1"``: the mixer's,
+    ``"merge2"``: the feed-forward form's), in :data:`MERGE_LEAVES`'
+    order."""
+    return tuple(f"{prefix}_{name}" for name, _, _ in MERGE_LEAVES)
+
+
+def merge_init(key, prefix: str, cfg):
+    """The merge's leaves with ``cfg.residual_scaling`` (none without)."""
+    if not cfg.residual_scaling:
+        return {}
+    keys = jax.random.split(jax.random.fold_in(key, 7), len(MERGE_LEAVES))
+    return {f"{prefix}_{name}": mean + std * jax.random.normal(
+                k, (cfg.d_model,), jnp.float32)
+            for (name, mean, std), k in zip(MERGE_LEAVES, keys)}
+
+
+def merged(x, y, layer, prefix: str, cfg):
+    """The residual stream ``x`` and a branch's output ``y`` put together:
+    ``x + y``, or with ``cfg.residual_scaling`` ``a_r * (x + b_r) + a_o *
+    (y + b_o)`` with the four vectors of the merge ``prefix``
+    (:func:`merge_names`), in float32, back in ``x``'s dtype."""
+    if not cfg.residual_scaling:
+        return x + y
+    with jax.named_scope(scopes.RES_SCALE):
+        a_r, b_r, a_o, b_o = (layer[name] for name in merge_names(prefix))
+        return (a_r * (x.astype(jnp.float32) + b_r)
+                + a_o * (y.astype(jnp.float32) + b_o)).astype(x.dtype)
+
+
+def shifted(a, axis: int = 1):
+    """``a`` one place later along ``axis`` (the sequence's), a zero row
+    first: row ``t`` holds ``a[t - 1]``.  The one ``t - 1`` of the short
+    causal convolutions and of the value shift."""
+    pad = [(0, 0)] * a.ndim
+    pad[axis] = (1, 0)
+    return lax.slice_in_dim(jnp.pad(a, pad), 0, a.shape[axis], axis=axis)
 
 
 def refuses_post_norm(validate, what: str):

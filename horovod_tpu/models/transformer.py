@@ -25,8 +25,8 @@ holds, from ``layer_types`` (one of :data:`LAYER_KINDS` a layer) and the
 fields that select a form.  This file runs the table: parameters, specs,
 the stack, the losses the parts hand back, the step, and the refusals,
 which are asked of the parts.  OLMoE, Olmo-Hybrid, Nemotron-3,
-GLM-4.7-Flash, Keye-VL-2.0's language model and Jamba2 are configs, not code
-here; so is a stack run several times on the same weights with a readout
+GLM-4.7-Flash, Keye-VL-2.0's language model, Jamba2 and ZAYA1 are configs,
+not code here; so is a stack run several times on the same weights with a readout
 after every pass (``loops``).  ``decode_step`` and the pipelined builder
 implement the GPT-2 block alone and say so by name.
 """
@@ -76,14 +76,15 @@ PARTS = {part.name: part for part in (
     mlp_mod.MLP_BESIDE_EXPERTS, attention_mod.LATENT_ATTENTION,
     attention_mod.SPARSE_ATTENTION,
     moe.LATENT_EXPERTS, moe.SIGMOID_EXPERTS, moe.SOFTMAX_EXPERTS,
-    mamba1.PART)}
+    mamba1.PART, attention_mod.CCA_ATTENTION, moe.ZAYA_EXPERTS)}
 # The fields no part owns: the model's sizes, its positions and head, the
 # layers' kinds and the prediction module.
 BLOCK_FIELDS = ("vocab_size", "d_model", "n_heads", "n_layers", "max_seq",
                 "dtype", "positions", "rope_theta", "norm_eps",
                 "tie_embeddings", "layer_types", "mtp_layer_types",
                 "mtp_loss_coef", "diffusion_block", "mask_token_id",
-                "post_norm", "loops", "exit_entropy_coef")
+                "post_norm", "loops", "exit_entropy_coef",
+                "residual_scaling", "logit_scale")
 
 
 def layer_parts(cfg, i: int, mtp: bool = False):
@@ -95,6 +96,7 @@ def layer_parts(cfg, i: int, mtp: bool = False):
     if mixer == "attention":
         mixer = ("latent_attention" if cfg.latent_attention
                  else "sparse_attention" if cfg.sparse_attention
+                 else "cca_attention" if cfg.cca_taps
                  else "attention")
     if not has_ffn:
         ffn = None
@@ -102,6 +104,8 @@ def layer_parts(cfg, i: int, mtp: bool = False):
         ffn = "mlp"
     elif not mtp and i < cfg.dense_layers:
         ffn = "mlp_beside_experts"
+    elif cfg.router_width:
+        ffn = "zaya_experts"
     elif cfg.mlp == "relu2":
         ffn = "latent_experts"
     else:
@@ -282,6 +286,35 @@ class TransformerConfig:
     # more at ten layers of the cell's sizes (PERF.md, PR 51).
     loops: int = 1
     exit_entropy_coef: float = 0.0
+    # Compressed convolutional attention (arXiv:2510.04476;
+    # models/attention.py, ``cca_qkv``) in place of plain attention, with
+    # ``cca_taps`` the taps of its two causal convolutions along the
+    # sequence, ``(2, 2)``: q and k are projected to ``n_heads`` and
+    # ``n_kv_heads`` heads of ``head_width`` (narrower together than
+    # ``d_model``), mixed by a depthwise and a head-grouped convolution,
+    # the mean of the unmixed q and k added back, half of the value heads
+    # read from the previous token, q and k L2-normed a head with a learned
+    # temperature a key-value head, and rotary over the first
+    # ``rotary_dims`` of every head (0: the whole head).
+    cca_taps: Tuple[int, ...] = ()
+    rotary_dims: int = 0
+    # ``router_width`` > 0: the experts' router is an MLP of that width
+    # (models/moe.py, ``route_mlp``) fed by the token and by the previous
+    # expert layer's router state, which every expert layer hands to the
+    # next beside ``x``; it makes ONE choice a token among ``n_experts``
+    # SwiGLU experts and a skip (``p u``, no matrix), under a selection
+    # bias that chooses and does not weigh.
+    router_width: int = 0
+    # ``x <- a_r * (x + b_r) + a_o * (branch(RMSNorm(x)) + b_o)`` in place
+    # of ``x + branch``, four vectors of ``d_model`` a part
+    # (``parts.merged``), for the parts that write it (``Part.scaled_merge``).
+    residual_scaling: bool = False
+    # A constant the logits are multiplied by (T5's ``d_model ** -0.5`` on a
+    # tied head; the ``logit_scale`` / ``output_multiplier`` of later tied
+    # models): a tied head reads the embedding's own scale, and with the
+    # constant here and not in ``ln_f_scale`` that leaf stays near 1, where
+    # an optimizer's step is small beside it.  1.0: no multiply.
+    logit_scale: float = 1.0
 
     def __post_init__(self):
         if self.positions not in ("learned", "rope", "none"):
@@ -304,6 +337,13 @@ class TransformerConfig:
         used = parts_in_use(self)
         for part in PARTS.values():
             part.validate(self, part in used)
+        plain = [part.name for part in used if not part.scaled_merge]
+        if self.residual_scaling and plain:
+            raise NotImplementedError(
+                f"residual_scaling=True: the scaled residual merge is "
+                f"written for compressed convolutional attention and the "
+                f"experts under the MLP router, not for "
+                + ", ".join(plain))
         if self.positions == "rope" and self.head_dim % 2:
             raise ValueError(f"positions='rope' needs an even head_dim, "
                              f"got {self.head_dim}")
@@ -519,6 +559,9 @@ def _logits_head(x, params, cfg, normed: bool = False):
     dt = cfg.dtype
     if not normed:
         x = _rmsnorm(x, params["ln_f_scale"], cfg.norm_eps)
+    if cfg.logit_scale != 1.0:
+        # On the [.., d] side of the product, in float32.
+        x = (x.astype(jnp.float32) * cfg.logit_scale).astype(x.dtype)
     w = params["embed"].T if cfg.tie_embeddings else params["head"]
     return (x @ w.astype(dt)).astype(jnp.float32)
 
@@ -631,23 +674,33 @@ def _hidden_states(params, tokens, cfg: TransformerConfig, model_axis,
 
     @functools.cache
     def block(part):
-        """A part's block, norm to residual, recomputed by itself."""
+        """A part's block, norm to residual, recomputed by itself.  What
+        the part carries from layer to layer (``Part.carries``) is an
+        argument and a result of the block beside ``x``: saved as the
+        block's input, never recomputed across layers."""
         return _remat_wrap(
-            lambda x, layer, segment_ids: part.apply(
-                x, layer, cfg, ctx._replace(segment_ids=segment_ids)), remat)
+            lambda x, layer, segment_ids, *carried: part.apply(
+                x, layer, cfg, ctx._replace(segment_ids=segment_ids),
+                *carried), remat)
 
     def run_layers(x, layers, chosen, label="%d"):
         """``x`` through ``layers``, each holding the parts ``chosen``
         names for it; ``label % i`` names layer ``i`` in the trace-time
-        series."""
+        series.  ``carried``: what the parts hand from layer to layer
+        beside ``x``, by name; nothing before the first that hands it."""
+        carried = {}
         for i, (layer, (mixer, ffn)) in enumerate(zip(layers, chosen)):
             with jax.named_scope(scopes.LAYER % i):
                 # Packing is the mixer's business alone.
                 for part, ids in ((mixer, segment_ids), (ffn, None)):
-                    if part:
-                        x, extra = block(part)(x, layer, ids)
-                        for name, value in extra.items():
-                            extras[name].append(value)
+                    if not part:
+                        continue
+                    taken = [tuple(map(carried.get, part.carries))] * bool(
+                        part.carries)
+                    x, extra, *handed = block(part)(x, layer, ids, *taken)
+                    carried.update(zip(part.carries, *handed))
+                    for name, value in extra.items():
+                        extras[name].append(value)
             for part in filter(None, (mixer, ffn)):
                 part.record(label % i, x, layer, cfg, ctx)
         return x

@@ -137,6 +137,29 @@ POST_NORM = "post_norm"
 EXIT_GATE = "exit_gate"
 EXIT_MIX = "exit_mix"
 
+# The parts of compressed convolutional attention (models/attention.py,
+# ``cca_attention``), bare components under ATTN_QKV: CCA_MIX, both causal
+# convolutions over the packed q and k, the mean of the pre-convolution q
+# and k added back, and the value shift; CCA_NORM_ROPE, the L2 norm a
+# head, the keys' temperature, the rotation of a head's first
+# ``rotary_dims`` and the head layout.
+CCA_MIX = "cca_mix"
+CCA_NORM_ROPE = "cca_norm_rope"
+
+# The parts of an MLP router with a state carried from expert layer to
+# expert layer (models/moe.py, ``zaya_experts``), bare components under
+# MOE_ROUTER: ROUTER_STATE, the down projection plus the previous layer's
+# state a channel; ROUTER_MLP, the norm, the MLP, the softmax and the
+# choice.  MOE_SKIP, a bare component under MLP beside the four parts
+# above: the term of the tokens whose choice is the skip.
+ROUTER_STATE = "router_state"
+ROUTER_MLP = "router_mlp"
+MOE_SKIP = "moe_skip"
+
+# The scaled residual merge (``residual_scaling``, parts.merged): a bare
+# component under ATTN_OUT and under MLP, as POST_NORM is.
+RES_SCALE = "res_scale"
+
 # Step scopes: what the step does with the gradients.
 GRAD_MEAN = "grad_mean"
 OPTIMIZER = "optimizer"

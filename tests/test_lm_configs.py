@@ -1,9 +1,11 @@
 """What every LM configuration must hold, written once and run over a table
-of them: the GPT-2 block and the eight tiny configurations that keep the
+of them: the GPT-2 block and the nine tiny configurations that keep the
 shape of OLMoE, Olmo-Hybrid, Nemotron-3, GLM-4.7-Flash, Keye-VL-2.0,
 Jamba2, SDAR (whose batch is clean tokens, which are noised and each
-block's rate, and whose loss is ``diffusion_loss_fn``'s) and Ouro (a
-stack run four times on the same weights),
+block's rate, and whose loss is ``diffusion_loss_fn``'s), Ouro (a
+stack run four times on the same weights) and ZAYA1 (compressed
+convolutional attention, an MLP router whose state goes from layer to
+layer, scaled merges),
 each against its plain reference under ``perfbench/reference/``, which
 shares no code with the program.
 
@@ -43,9 +45,9 @@ import pytest
 
 from horovod_tpu.models import linear_attention as la
 from horovod_tpu.models import transformer as tfm
-from perfbench.reference import (bd_moe_lm, dsa_moe_lm, hybrid_lm, lm,
-                                 looped_lm, mamba1_lm, mla_moe_lm, moe_lm,
-                                 ssm_moe_lm)
+from perfbench.reference import (bd_moe_lm, cca_moe_lm, dsa_moe_lm,
+                                 hybrid_lm, lm, looped_lm, mamba1_lm,
+                                 mla_moe_lm, moe_lm, ssm_moe_lm)
 
 __all__ = ["COSTLY", "ROWS", "built", "lm_row", "pytest_generate_tests",
            "rel"]
@@ -129,6 +131,20 @@ OURO_TINY = tfm.TransformerConfig(
     dtype=F32, positions="rope", rope_theta=1e6, norm_eps=1e-6,
     tie_embeddings=False, mlp="swiglu", post_norm=True, loops=4,
     exit_entropy_coef=0.05)
+
+
+# Three layers of compressed convolutional attention (4 query heads on 2
+# key-value heads of 8: a latent of 32 + 16 on a hidden size of 64, the
+# first 4 dims of a head rotary) and one expert a token of 8 (4 held from
+# 2) or the skip under the MLP router of width 16, whose state goes through
+# all three; scaled merges; the head tied.
+ZAYA_TINY = tfm.TransformerConfig(
+    vocab_size=128, d_model=64, n_heads=4, n_kv_heads=2, head_width=8,
+    n_layers=3, d_ff=0, max_seq=64, dtype=F32, positions="rope",
+    rope_theta=5e6, norm_eps=1e-5, tie_embeddings=True, mlp="swiglu",
+    n_experts=8, experts_per_token=1, d_expert=32, experts_held=4,
+    experts_held_from=2, cca_taps=(2, 2), rotary_dims=4, router_width=16,
+    residual_scaling=True)
 
 
 def rel(got, want):
@@ -255,6 +271,51 @@ def _ouro_ref(cfg, params, tokens, labels, **kw):
         paths=paths, **kw)
 
 
+def zaya_dims(cfg):
+    return {"n_heads": cfg.n_heads, "n_kv_heads": cfg.kv_heads,
+            "head_dim": cfg.head_dim, "rotary_dims": cfg.rotary_dims,
+            "eps": cfg.norm_eps, "theta": cfg.rope_theta,
+            "n_experts": cfg.n_experts, "held_from": cfg.experts_held_from}
+
+
+def _zaya_ref(cfg, params, tokens, labels, **kw):
+    """Every leaf the loss moves, the embedding's among them."""
+    paths = cca_moe_lm.trained_leaves(params)
+    return cca_moe_lm.loss_and_grads(
+        params, tokens, labels, dims=zaya_dims(cfg), names=tuple(paths),
+        paths=paths, **kw)
+
+
+def _zaya_leaves(tree, cfg):
+    return {name: cca_moe_lm.leaf(tree, path)
+            for name, path in cca_moe_lm.trained_leaves(tree).items()}
+
+
+def _zaya_placed(params, cfg):
+    """The held experts placed as the benchmark's adapter places them:
+    those whose loads add up to a uniform router's rows."""
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (256,), 0,
+                                cfg.vocab_size)
+    placed = cca_moe_lm.level_placement(params, tokens, dims=zaya_dims(cfg))
+    return dict(params, layers=[cca_moe_lm.place(layer, found) for
+                                layer, found in zip(params["layers"],
+                                                    placed)])
+
+
+def _zaya_rows_and_skips(stats, grads):
+    """The held experts of every layer and the skip receive rows; the
+    selection biases and the first layer's gamma are not trained."""
+    assert stats["rows"].shape == (3, 4) and stats["skips"].shape == (3,)
+    assert int(stats["rows"].sum(1).min()) > 0
+    assert int(stats["skips"].sum()) > 0
+    for layer in grads["layers"]:
+        assert float(jnp.abs(layer["router_bias"]).max()) == 0.0
+    assert float(jnp.abs(
+        grads["layers"][0]["router_state_scale"]).max()) == 0.0
+    assert float(jnp.abs(
+        grads["layers"][1]["router_state_scale"]).max()) > 0.0
+
+
 def _every_leaf(tree, cfg):
     return {name: looped_lm.leaf(tree, path)
             for name, path in looped_lm.every_leaf(tree).items()}
@@ -335,6 +396,8 @@ class Row:
     step_rel: float = 3e-3
     # Leaves the program's own update cannot be read from (see the step).
     unread: Tuple = ()
+    # ``(params, cfg) -> params``, after the embedding's scale.
+    prepare: Callable = lambda params, cfg: params
     shapes: Mapping = dataclasses.field(default_factory=dict)
     series: Tuple = ()
     no_series: Tuple = ()
@@ -382,6 +445,12 @@ _GDN_BLOCKS = 2 * 2 * 256 // la.BLOCK
 _NO_MAMBA = dict(layer_types=(), mamba_inner=0, mamba_state=0,
                  mamba_dt_rank=0, mamba_conv_kernel=0)
 _LOOP_ALONE = dict(_GPT2_BUT_THE_LAYERS, post_norm=False)
+_ZAYA_NOT_THE_BLOCKS = ("positions", "n_kv_heads", "head_width",
+                        "n_experts", "cca_taps", "rotary_dims",
+                        "router_width", "residual_scaling")
+_CCA_ALONE = dict(_NO_EXPERTS, router_width=0, d_ff=96,
+                  residual_scaling=False)
+_ROUTER_ALONE = dict(cca_taps=(), rotary_dims=0, residual_scaling=False)
 
 ROWS = {
     "gpt2": Row(
@@ -878,6 +947,101 @@ ROWS = {
                      ssm_head_dim=16, ssm_state=32, ssm_groups=2,
                      ssm_conv_kernel=4, ssm_chunk=32), NotImplementedError,
                 "post_norm=True.*Mamba-2"))),
+    "zaya": Row(
+        cfg=ZAYA_TINY, ref=_zaya_ref, seq=64, embed_scale=20.0,
+        checked=_zaya_leaves, prepare=_zaya_placed,
+        parity=(("local", F32, "local", 5e-5, 5e-5),
+                ("flash", F32, "flash", 5e-5, 5e-5)),
+        also=_zaya_rows_and_skips,
+        # The thirteen references that the cell's check must refuse.  The
+        # gradient cut between the routers leaves the loss alone (-1: any
+        # gap passes).
+        controls=(
+            ("no_mix", dict(mix=False), "layers.0.wq", 1e-4, 0.02),
+            ("no_mean", dict(mean=False), "layers.0.wq", 1e-4, 0.02),
+            ("no_value_shift", dict(value_shift=False), "layers.0.wv_prev",
+             1e-4, 0.02),
+            ("no_l2_norm", dict(l2_norm=False), "layers.2.k_temp", 1e-4,
+             0.02),
+            ("rotary_whole", dict(rotary_whole=True), "layers.0.wk", 1e-4,
+             0.02),
+            ("cut_state", dict(cut_state=True), "layers.0.router_down",
+             -1.0, 0.02),
+            ("gamma_zero", dict(carry=False), "layers.0.router_down", 1e-4,
+             0.02),
+            ("skip_nothing", dict(skip_term=False),
+             "layers.2.merge2_out_scale", 1e-4, 0.02),
+            ("no_skip_choice", dict(skip_choice=False),
+             "layers.2.router_w3", 1e-4, 0.02),
+            ("bias_weighs", dict(bias_weighs=True), "layers.2.router_w3",
+             1e-4, 0.02),
+            ("unweighted", dict(weighted=False), "layers.2.router_w3",
+             1e-4, 0.02),
+            ("plain_add", dict(scaled_merge=False),
+             "layers.2.merge2_out_scale", 1e-4, 0.02),
+            ("float8", dict(low_precision=jnp.float8_e4m3fn),
+             "layers.2.w_down", 1e-4, 0.02)),
+        step=("full", "flash"),
+        shapes={("layers", 0, "wq"): (64, 32), ("layers", 0, "wk"): (64, 16),
+                ("layers", 0, "wv_prev"): (64, 8),
+                ("layers", 0, "cca_dw_w"): (2, 48),
+                ("layers", 0, "cca_gw_w"): (2, 6, 8, 8),
+                ("layers", 0, "k_temp"): (2,),
+                ("layers", 1, "router_down"): (64, 16),
+                ("layers", 1, "router_w3"): (16, 9),
+                ("layers", 1, "router_bias"): (9,),
+                ("layers", 1, "w_down"): (4, 32, 64),
+                ("layers", 2, "merge2_out_scale"): (64,)},
+        series=('hvd_cca_rows_total{layer="0"} 128',
+                'hvd_moe_router_state_width{layer="2"} 16',
+                'hvd_moe_router_choices_total{layer="1",path="argmax"} 1',
+                'hvd_moe_experts_held{layer="0"} 4',
+                'hvd_moe_rows_bound{layer="0"} 128',
+                'hvd_moe_rows_prefix{layer="0"} 128'),
+        no_series=("hvd_moe_assignments_total", "hvd_ssm_", "hvd_dsa_",
+                   'path="top_k"'),
+        # What perfbench/cca_reduce.py reads.
+        scopes=("layer_0/attn/qkv/cca_mix", "layer_0/attn/qkv/cca_norm_rope",
+                "layer_1/mlp/moe_router/router_state",
+                "layer_1/mlp/moe_router/router_mlp", "layer_1/mlp/moe_skip",
+                "layer_2/attn/out/res_scale", "layer_2/mlp/res_scale",
+                "layer_2/mlp/moe_experts", "layer_2/attn/local_attention"),
+        no_scopes=("/mla_", "/dsa_", "/moe_shared", "/mtp",
+                   "qk_head_norm_rope"),
+        refused={"model_axis": ("cca_taps", "n_experts"),
+                 "seq_axis": ("cca_taps",), "packed": ("cca_taps",),
+                 "segment_ids": ("cca_taps",),
+                 "decode_step": _ZAYA_NOT_THE_BLOCKS,
+                 "pipelined": _ZAYA_NOT_THE_BLOCKS + ("mlp",)},
+        # The mixer without the experts, and the MLP router under plain
+        # attention: each alone refuses by its own field.
+        alone=tuple(("cca", _CCA_ALONE, what, "cca_taps")
+                    for what in BEYOND_NAMES)
+        + tuple(("router", _ROUTER_ALONE, what, "router_width")
+                for what in ("decode_step", "pipelined")),
+        rules=((dict(cca_taps=(3, 2)), NotImplementedError, "two taps"),
+               (dict(rotary_dims=5), ValueError, "even rotary_dims"),
+               (dict(positions="none"), ValueError, "positions='rope'"),
+               (dict(n_kv_heads=1), ValueError, "must be even"),
+               (dict(cca_taps=(), residual_scaling=False), ValueError,
+                "means nothing without cca_taps"),
+               (dict(qk_norm_per_head=True), NotImplementedError,
+                "norms its heads itself"),
+               (dict(experts_per_token=2), NotImplementedError,
+                "one choice a token"),
+               (dict(loops=2, exit_entropy_coef=0.05), NotImplementedError,
+                "carried state.*loops=2"),
+               (dict(mtp_layer_types=("full_attention",), mtp_loss_coef=0.1),
+                NotImplementedError, "carried state.*mtp_layer_types"),
+               (dict(d_shared=32), NotImplementedError,
+                "carried state.*d_shared"),
+               (dict(router_width=0), NotImplementedError,
+                "residual_scaling=True.*softmax_experts"),
+               (dict(cca_taps=(), rotary_dims=0), NotImplementedError,
+                "residual_scaling=True.*not for attention"),
+               (dict(post_norm=True), NotImplementedError,
+                "post_norm=True.*compressed convolutional"),
+               (dict(mlp="gelu"), ValueError, "SwiGLU"))),
 }
 
 
@@ -899,7 +1063,7 @@ class Built:
     def params(self):
         params = tfm.init_params(jax.random.PRNGKey(0), self.cfg)
         params["embed"] = params["embed"] * self.row.embed_scale
-        return params
+        return self.row.prepare(params, self.cfg)
 
     @functools.cache
     def batch(self, sequences=4):

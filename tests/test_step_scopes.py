@@ -75,9 +75,9 @@ def _lm_step_text(attention, remat, shard_optimizer, seq_axis=None,
     from horovod_tpu.models import transformer as tfm
     from horovod_tpu.topology import build_mesh
 
-    cfg = tfm.TransformerConfig(vocab_size=256, d_model=64, n_heads=2,
-                                n_layers=2, d_ff=128, max_seq=128,
-                                dtype=jnp.bfloat16, **fields)
+    cfg = tfm.TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=2, max_seq=128,
+        dtype=jnp.bfloat16, **{"n_heads": 2, "d_ff": 128, **fields})
     axes = ("data", seq_axis) if seq_axis else ("data",)
     mesh = build_mesh(axes=axes, shape=(2, 2) if seq_axis else None,
                       devices=jax.devices()[:4])
@@ -513,6 +513,54 @@ def test_looped_step_carries_the_vocabulary_under_the_loop(hvd, attention,
             } <= {"carry", "norm"}
 
 
+ZAYA = dict(positions="rope", n_heads=4, n_kv_heads=2, head_width=8,
+            d_ff=0, mlp="swiglu", n_experts=4, experts_per_token=1, d_expert=64,
+            experts_held=2, experts_held_from=1, cca_taps=(2, 2),
+            rotary_dims=4, router_width=16, residual_scaling=True)
+
+
+@pytest.mark.parametrize("attention,remat",
+                         [("local", "none"), ("flash", "full")])
+def test_zaya_step_carries_the_vocabulary_and_its_parts(hvd, attention,
+                                                        remat):
+    """Compressed convolutional attention opens ``cca_mix`` and
+    ``cca_norm_rope`` under ``attn/qkv`` and nowhere else, the MLP router
+    ``router_state`` and ``router_mlp`` under ``mlp/moe_router``, the skip
+    ``moe_skip`` under ``mlp``, the scaled merge ``res_scale`` under
+    ``attn/out`` and under ``mlp``; every executed op has a phase and a
+    scope, and ``perfbench/cca_reduce.py`` books each to one part."""
+    from perfbench import cca_reduce
+
+    text = _lm_step_text(attention, remat, False, **ZAYA)
+    _check_lm(text, attention, remat, False)
+    names = _op_names(text)
+    for part, homes in (
+            (scopes.CCA_MIX, (scopes.ATTN_QKV,)),
+            (scopes.CCA_NORM_ROPE, (scopes.ATTN_QKV,)),
+            (scopes.ROUTER_STATE, (scopes.MOE_ROUTER,)),
+            (scopes.ROUTER_MLP, (scopes.MOE_ROUTER,)),
+            (scopes.MOE_SKIP, (scopes.MLP,)),
+            (scopes.RES_SCALE, (scopes.ATTN_OUT, scopes.MLP))):
+        held = [n for n in names if _under([n], part)]
+        assert held, part
+        for home in homes:
+            inside = re.compile(re.escape(home) + r"\)*/" + part)
+            assert any(inside.search(n) for n in held), (home, part)
+            held = [n for n in held if not inside.search(n)]
+        assert not held, (part, held[:3])
+        assert _under(names, part, "jvp(", without=("transpose(",)), part
+        assert _under(names, part, "transpose("), part
+    assert _under(names, scopes.MOE_ROUTER, scopes.MLP)
+    assert not any("/dsa_" in n or "/mla_" in n or scopes.QK_HEAD_NORM_ROPE
+                   in n for n in names)
+    hlo = scope_reduce.parse_hlo(text)
+    parts = {name: cca_reduce.part_of(name, hlo)
+             for name, i in hlo.instructions.items() if i.opcode in HELD}
+    # (On the CPU the flash kernels run in the interpreter: no custom call
+    # carries their name.)
+    assert set(cca_reduce.PARTS) - {"flash"} <= set(parts.values())
+
+
 @pytest.mark.parametrize("attention", ("ring", "ulysses"))
 def test_sequence_routes_open_their_own_scope(hvd, attention):
     text = _lm_step_text(attention, "none", False, seq_axis="seq")
@@ -662,6 +710,22 @@ def test_the_benchmark_reads_the_same_vocabulary():
     # ``perfbench/loop_reduce.py``.
     loop_parts = {scopes.LOOP, scopes.LOOP_NORM,
                   scopes.POST_NORM, scopes.EXIT_GATE, scopes.EXIT_MIX}
+    # Compressed convolutional attention's two parts under ``attn/qkv``,
+    # the MLP router's two under ``mlp/moe_router``, the skip's term and
+    # the scaled merge, read by ``perfbench/cca_reduce.py``.
+    cca_parts = {scopes.CCA_MIX, scopes.CCA_NORM_ROPE, scopes.ROUTER_STATE,
+                 scopes.ROUTER_MLP, scopes.MOE_SKIP, scopes.RES_SCALE}
+    from perfbench import cca_reduce
+    assert cca_reduce._MIX.search(
+        f"jit(x)/jvp({scopes.LAYER % 1})/{scopes.ATTN_QKV}/"
+        f"{scopes.CCA_MIX}/dot_general")
+    assert cca_reduce._MERGE.search(
+        f"jit(x)/transpose(jvp({scopes.LAYER % 1}))/{scopes.MLP}/"
+        f"{scopes.RES_SCALE}/mul")
+    assert cca_reduce._ROUTER.search(
+        f"jit(x)/jvp({scopes.LAYER % 0})/{scopes.MLP}/{scopes.MOE_ROUTER}/"
+        f"{scopes.ROUTER_MLP}/erf")
+    assert cca_reduce.MARK == scopes.CCA_MIX
     from perfbench import loop_reduce
     for inside, part in (
             (f"{scopes.LOOP % 1}/{scopes.LAYER % 1}/{scopes.ATTN_OUT}"
@@ -703,7 +767,7 @@ def test_the_benchmark_reads_the_same_vocabulary():
             - moe_kernels - gdn_parts - gdn_kernels - ssm_parts
             - ssm_kernels - mla_parts - conv_kernels - norm_kernels
             - dsa_parts - dsa_kernels - mamba1_parts - mamba1_kernels
-            - mla_kernels - qk_kernels - bd_parts - loop_parts)
+            - mla_kernels - qk_kernels - bd_parts - loop_parts - cca_parts)
     from perfbench import mamba1_reduce
     assert ({p.rsplit("/", 1)[-1] for p in mamba1_parts}
             == set(mamba1_reduce.PARTS))
